@@ -24,15 +24,15 @@ sys.path.insert(0, REPO)
 def run_one(impl: str) -> dict:
     import jax
 
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(REPO, ".cache", "jax-bench"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from shuffle_exchange_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from bench import bench_train, chip_peak_flops
     from shuffle_exchange_tpu.models import Transformer, TransformerConfig
 
     dev = jax.devices()[0]
-    peak = chip_peak_flops(dev, jax.default_backend())
+    peak = chip_peak_flops(dev)
     mcfg = TransformerConfig(
         vocab_size=32768, d_model=1024, n_layers=8, n_heads=8,
         n_kv_heads=2, max_seq_len=2048, activation="swiglu",

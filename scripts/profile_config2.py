@@ -86,9 +86,9 @@ def main():
 
     import jax
 
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(REPO, ".cache", "jax-bench"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from shuffle_exchange_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import shuffle_exchange_tpu as sxt
     from bench import hbm_bytes, host_sync, pick_config2

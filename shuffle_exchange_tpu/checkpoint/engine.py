@@ -205,14 +205,18 @@ class OrbaxCheckpointEngine(CheckpointEngine):
         if target is None:
             # Host-side restore (consolidation CLI, single-process tools):
             # the checkpoint may have been written from any device layout, so
-            # rebuild an abstract target from metadata placed on the local
-            # device instead of replaying the original sharding.
+            # rebuild an abstract target from metadata placed on the HOST
+            # (the CPU backend exists beside any accelerator) instead of
+            # replaying the original sharding: a whole checkpoint piled on
+            # accelerator 0 need not fit there, and a host-side tool has
+            # no business holding a chip.
             # orbax-API drift: Checkpointer.metadata() returns the metadata
             # tree directly on 0.7.x; newer releases wrap it in a
             # StepMetadata whose ``item_metadata`` holds the tree
             meta = self._ckptr.metadata(path)
             meta = getattr(meta, "item_metadata", meta)
-            sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+            sharding = jax.sharding.SingleDeviceSharding(
+                jax.local_devices(backend="cpu")[0])
 
             def to_abstract(m):
                 return jax.ShapeDtypeStruct(tuple(m.shape), m.dtype, sharding=sharding)

@@ -233,14 +233,11 @@ class ContextParallelConfig(ConfigModel):
     kernel when the shape gate passes, "pallas" forces it (errors
     surface), "xla" keeps the jnp chunked online-softmax.
 
-    Composition on jax 0.4.x (this box): CP x pipe is a committed
-    ConfigError (scripts/repro_wire_nesting_xla_check.py — the ring
-    region cannot nest in the pipeline's manual region without
-    first-class jax.shard_map), as is CP x the ZeRO++ quantized wire
-    (scripts/repro_wire_nesting_xla_check.py from the other direction);
-    CP x pipe x tensor is rejected on every jax (spmd_partitioner_util
-    CHECK, scripts/repro_seq_pipe_tensor_xla_check.py). CP x fsdp/data
-    (ZeRO 1-3) composes everywhere.
+    Composition: CP x fsdp/data (ZeRO 1-3) and CP x pipe compose. CP x
+    the ZeRO++ quantized wire is a ConfigError (the ring's manual region
+    cannot nest inside the wire region, nor the other way round), and so
+    is CP x pipe x tensor (XLA's partial-manual partitioner CHECK-fails
+    on the doubly-nested region with a live tensor axis).
 
     With ``remat_policy: save_flash_lse`` the ring's per-hop checkpoint
     saves exactly the kernel's own (out, lse) residuals, so the backward

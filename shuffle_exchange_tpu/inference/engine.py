@@ -530,29 +530,21 @@ class InferenceEngine:
 
     def _maybe_fused_qkv(self, lw, y, cos, sin, positions):
         """Fused QKV+bias+RoPE for a 1-token step; None -> use the XLA
-        path (not enabled, T > 1, or this layer's weights aren't dense)."""
+        path (not enabled, T > 1, or this layer's weights aren't dense).
+        A kernel that was selected runs or raises."""
         cfg = self._mcfg
         if not (self._fuse_qkv and self._decode_kernel == "pallas"
                 and y.shape[1] == 1):
             return None
         from ..ops import fused_decode as fd
-        from ..utils.logging import warning_once
 
         args = self._fused_qkv_args(lw, cos, sin, positions)
         if args is None:
             return None
         cosr, sinr, bias = args
-        try:
-            q, k, v = fd.fused_qkv_rope(
-                y[:, 0], lw["wq"], lw["wk"], lw["wv"], cos=cosr, sin=sinr,
-                n_heads=cfg.n_heads, kv_heads=cfg.kv_heads, **bias)
-        except Exception as e:
-            # sxt: ignore[SXT005] exception class + model dims: both fixed per process, bounded dedup
-            warning_once(f"fused decode: QKV kernel failed with "
-                         f"{type(e).__name__} (D={y.shape[-1]}, "
-                         f"H={cfg.n_heads}, KV={cfg.kv_heads}); using the "
-                         "XLA path")
-            return None
+        q, k, v = fd.fused_qkv_rope(
+            y[:, 0], lw["wq"], lw["wk"], lw["wv"], cos=cosr, sin=sinr,
+            n_heads=cfg.n_heads, kv_heads=cfg.kv_heads, **bias)
         return q[:, None], k[:, None], v[:, None]
 
     def _maybe_fused_ffn(self, lw, resid, y_src, apply_norm: bool):
@@ -584,17 +576,11 @@ class InferenceEngine:
         # along as a shape-correct dummy
         ln_w = lw["ln2_w"] if apply_norm else lw["ln1_w"]
         ln_b = lw.get("ln2_b") if apply_norm else None
-        try:
-            out = fd.fused_mlp(
-                resid[:, 0], y_src[:, 0], ln_w, ln_b,
-                lw["w_up"], lw["w_down"], wg, norm=cfg.norm,
-                eps=cfg.norm_eps, activation=cfg.activation,
-                apply_norm=apply_norm, **kw)
-        except Exception as e:
-            # sxt: ignore[SXT005] exception class name only — a handful of distinct messages at worst
-            warning_once(f"fused decode: MLP kernel failed with "
-                         f"{type(e).__name__}; using the XLA path")
-            return None
+        out = fd.fused_mlp(
+            resid[:, 0], y_src[:, 0], ln_w, ln_b,
+            lw["w_up"], lw["w_down"], wg, norm=cfg.norm,
+            eps=cfg.norm_eps, activation=cfg.activation,
+            apply_norm=apply_norm, **kw)
         return out[:, None]
 
     def _prefill(self, params, ids, prompt_len, cache: KVCache):

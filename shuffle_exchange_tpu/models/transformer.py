@@ -882,34 +882,9 @@ class Transformer:
         # CONTEXT mesh (whose outer axes are typed Manual), not the
         # concrete topology mesh.
         manual = {"data", "fsdp", "seq"} | ({"tensor"} if head_ax else set())
-        from ..parallel.mesh import constraint_mesh, native_shard_map
+        from ..parallel.mesh import constraint_mesh
         from ..parallel.mesh import shard_map as _shard_map
 
-        if not native_shard_map():
-            # jax 0.4.x partial-manual lowering: an all-to-all/all-gather
-            # inside a region that still has a LIVE (size > 1) auto axis
-            # trips an XLA SPMD-partitioner CHECK (a process abort, not an
-            # exception — see parallel/mesh.py::native_shard_map). Live
-            # auto axes here: expert always; pipe when this region nests in
-            # the pipeline's; tensor when heads don't split. Fall back to
-            # replicated attention (correct, not sequence-parallel) rather
-            # than abort the process.
-            live_auto = [ax for ax, n in mesh.shape.items()
-                         if n > 1 and ax not in manual]
-            if live_auto:
-                from ..utils.logging import warning_once
-
-                # sxt: ignore[SXT005] live_auto derives from the mesh shape, fixed per process
-                warning_once(
-                    "sequence-parallel attention: jax 0.4.x cannot lower "
-                    f"the Ulysses/ring region with live auto axes "
-                    f"{sorted(live_auto)} (XLA partial-manual CHECK); "
-                    "attention runs replicated for this config — upgrade "
-                    "jax for SP x " + "/".join(sorted(live_auto)))
-                out = causal_attention(q, k, v,
-                                       attention_impl=cfg.attention_impl,
-                                       alibi=alibi, causal=cfg.causal)
-                return out[:, :T0] if pad else out
         out = _shard_map(sp_fn, mesh=constraint_mesh(mesh),
                          in_specs=(spec, spec, spec),
                          out_specs=spec, axis_names=manual)(q, k, v)

@@ -114,24 +114,21 @@ def _gather_expert_sharded(params, expert_axis: str = "expert"):
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from ..parallel.mesh import (constraint_mesh, get_topology,
-                                     topology_is_initialized)
+    from ..parallel.mesh import (constraint_mesh, get_topology,
+                                 topology_is_initialized)
 
-        if not topology_is_initialized():
-            return params
-        mesh = get_topology().mesh
-        if mesh.shape.get(expert_axis, 1) == 1:
-            return params
-        rep = NamedSharding(constraint_mesh(mesh), P())
-        # tree.map (not a dict comprehension) so QuantizedMatrix expert
-        # leaves pin BOTH children (q + scales) — a constraint on the
-        # wrapper node would be structure-mismatched, and skipping it
-        # would re-open the ragged_dot mispartition this gather fixes
-        return jax.tree.map(
-            lambda v: jax.lax.with_sharding_constraint(v, rep), params)
-    except Exception:
+    if not topology_is_initialized():
         return params
+    mesh = get_topology().mesh
+    if mesh.shape.get(expert_axis, 1) == 1:
+        return params
+    rep = NamedSharding(constraint_mesh(mesh), P())
+    # tree.map (not a dict comprehension) so QuantizedMatrix expert
+    # leaves pin BOTH children (q + scales) — a constraint on the
+    # wrapper node would be structure-mismatched, and skipping it
+    # would re-open the ragged_dot mispartition this gather fixes
+    return jax.tree.map(
+        lambda v: jax.lax.with_sharding_constraint(v, rep), params)
 
 
 def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu"):
@@ -357,23 +354,20 @@ def _constrain_expert(t, expert_axis, mesh):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax.sharding import NamedSharding
+    from jax.sharding import NamedSharding
 
-        if mesh is None:
-            from ..parallel.mesh import topology_is_initialized, get_topology
+    if mesh is None:
+        from ..parallel.mesh import topology_is_initialized, get_topology
 
-            if not topology_is_initialized():
-                return t
-            mesh = get_topology().mesh
-        if mesh.shape.get(expert_axis, 1) == 1:
+        if not topology_is_initialized():
             return t
-        from ..parallel.mesh import constraint_mesh
-
-        return jax.lax.with_sharding_constraint(
-            t, NamedSharding(constraint_mesh(mesh), P(expert_axis, None, None)))
-    except Exception:
+        mesh = get_topology().mesh
+    if mesh.shape.get(expert_axis, 1) == 1:
         return t
+    from ..parallel.mesh import constraint_mesh
+
+    return jax.lax.with_sharding_constraint(
+        t, NamedSharding(constraint_mesh(mesh), P(expert_axis, None, None)))
 
 
 def residual_moe(gate_w, expert_params, dense_params, coef_w, x, activation: str = "swiglu",

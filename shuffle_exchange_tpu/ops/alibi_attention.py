@@ -48,34 +48,6 @@ def _vma_of(*arrs):
     return vma
 
 
-def _sds(shape, dtype, vma):
-    """jax-version compat ShapeDtypeStruct: older jax (<= 0.4.x) has no
-    ``vma=`` kwarg (and no vma checking in shard_map either, so dropping
-    it there is correct, not lossy)."""
-    import jax
-
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:
-        return jax.ShapeDtypeStruct(shape, dtype)
-
-
-def _compiler_params(**kw):
-    """jax-version compat: ``pltpu.CompilerParams`` (new) vs
-    ``pltpu.TPUCompilerParams`` (<= 0.4.x)."""
-    from .fused_decode import _compiler_params as _cp
-
-    return _cp(**kw)
-
-
-def _finite(x):
-    """Compat for Mosaic on older jax (no is_finite lowering): these
-    kernels only ever introduce -inf sentinels, so > -inf is exact."""
-    import jax.numpy as jnp
-
-    return x > -jnp.inf
-
-
 def _block_visible(qi, ki, bq, bkv, off, causal):
     """Does kv block ki contribute to q block qi? (the grid-level half of
     the causal mask — shared by fwd/dq/dkv so the three kernels can never
@@ -124,9 +96,9 @@ def _alibi_fwd_kernel(slope_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_run = l_ref[:, :1]
         m_blk = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_run, m_blk)
-        m_safe = jnp.where(_finite(m_new), m_new, 0.0)
-        p = jnp.where(_finite(s), jnp.exp(s - m_safe), 0.0)
-        corr = jnp.where(_finite(m_run), jnp.exp(m_run - m_safe), 0.0)
+        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.where(jnp.isfinite(s), jnp.exp(s - m_safe), 0.0)
+        corr = jnp.where(jnp.isfinite(m_run), jnp.exp(m_run - m_safe), 0.0)
         l_new = l_run * corr + p.sum(-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -139,7 +111,7 @@ def _alibi_fwd_kernel(slope_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         out = acc_ref[...] / jnp.maximum(l, 1e-30)
         o_ref[...] = out.reshape(o_ref.shape).astype(o_ref.dtype)
         m = m_ref[:, :1]
-        lse = jnp.where(_finite(m), m + jnp.log(jnp.maximum(l, 1e-30)), -jnp.inf)
+        lse = jnp.where(jnp.isfinite(m), m + jnp.log(jnp.maximum(l, 1e-30)), -jnp.inf)
         lse_ref[...] = lse.reshape(lse_ref.shape)   # [1,1,bq,1] trailing-1
 
 
@@ -166,7 +138,7 @@ def _score_grads(slope, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     if causal:
         q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
         s = jnp.where(q_pos + off >= kv_pos, s, -jnp.inf)
-    p = jnp.where(_finite(s), jnp.exp(s - lse), 0.0)
+    p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse), 0.0)
     dp = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     ds = p * (dp - delta)
@@ -320,15 +292,15 @@ def _alibi_flash_fwd_impl(q, k, v, slopes, causal: bool, interpret: bool):
             pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            _sds((B, H, T, D), q.dtype, _vma_of(q, k, v)),
-            _sds((B, H, T, 1), jnp.float32, _vma_of(q, k, v)),
+            jax.ShapeDtypeStruct((B, H, T, D), q.dtype, vma=_vma_of(q, k, v)),
+            jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32, vma=_vma_of(q, k, v)),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, D), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -412,9 +384,9 @@ def _flash_bwd_impl(q, k, v, slopes, out, lse, g, g_lse, causal, interpret,
             pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=_sds((B, H, T, D), q.dtype, _vma_of(q, k, v, g)),
+        out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype, vma=_vma_of(q, k, v, g)),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -425,8 +397,8 @@ def _flash_bwd_impl(q, k, v, slopes, out, lse, g, g_lse, causal, interpret,
         pl.BlockSpec((1, 1, bkv, D), lambda b, h, j, i: (b, h, j, 0)),
     ]
     dkv_out_shape = [
-        _sds((B, H, S, D), k.dtype, _vma_of(q, k, v, g)),
-        _sds((B, H, S, D), v.dtype, _vma_of(q, k, v, g)),
+        jax.ShapeDtypeStruct((B, H, S, D), k.dtype, vma=_vma_of(q, k, v, g)),
+        jax.ShapeDtypeStruct((B, H, S, D), v.dtype, vma=_vma_of(q, k, v, g)),
     ]
     if need_dslope:
         # dslope partials per kv block: accumulation only crosses the q
@@ -437,8 +409,8 @@ def _flash_bwd_impl(q, k, v, slopes, out, lse, g, g_lse, causal, interpret,
         dkv_out_specs.append(
             pl.BlockSpec((1, 1, 1, 8, 128), lambda b, h, j, i: (b, h, j, 0, 0)))
         dkv_out_shape.append(
-            _sds((B, H, S // bkv, 8, 128), jnp.float32,
-                 _vma_of(q, k, v, g)))
+            jax.ShapeDtypeStruct((B, H, S // bkv, 8, 128), jnp.float32,
+                                 vma=_vma_of(q, k, v, g)))
     dkv_res = pl.pallas_call(
         functools.partial(_alibi_dkv_kernel, bq=bq, bkv=bkv, off=off,
                           scale=scale, causal=causal,
@@ -456,7 +428,7 @@ def _flash_bwd_impl(q, k, v, slopes, out, lse, g, g_lse, causal, interpret,
         out_shape=dkv_out_shape,
         scratch_shapes=[pltpu.VMEM((bkv, D), jnp.float32),
                         pltpu.VMEM((bkv, D), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

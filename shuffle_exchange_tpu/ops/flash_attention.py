@@ -308,7 +308,8 @@ def flash_attention_remat(q, k, v, causal: bool = True, interpret: bool = False)
     if t_pad or s_pad:
         pad4 = lambda x, p: jnp.pad(x, ((0, 0), (0, p), (0, 0), (0, 0)))
         q, k, v = pad4(q, t_pad), pad4(k, s_pad), pad4(v, s_pad)
-    out, _ = flash_attention_lse(q, k, v, causal, interpret)
+    out = _per_shard(lambda q, k, v: flash_attention_lse(
+        q, k, v, causal, interpret)[0], q, k, v)
     return out[:, :t0] if t_pad else out
 
 
@@ -327,7 +328,9 @@ def flash_attention(q, k, v, causal: bool = True, impl: str = "auto", segment_id
             from .alibi_attention import alibi_flash_attention, alibi_kernel_ok
 
             if alibi_kernel_ok(q, k, causal):
-                return alibi_flash_attention(q, k, v, alibi_slopes, causal)
+                # the slope vector is indexed by global head: heads stay whole
+                return _per_shard(lambda q, k, v: alibi_flash_attention(
+                    q, k, v, alibi_slopes, causal), q, k, v, shard_heads=False)
         if impl in ("pallas", "chunked"):
             warning_once("alibi attention uses the jnp reference path")
         return reference_attention(q, k, v, causal=causal, segment_ids=segment_ids,
@@ -347,11 +350,25 @@ def flash_attention(q, k, v, causal: bool = True, impl: str = "auto", segment_id
                 return reference_attention(q, k, v, causal=causal, segment_ids=segment_ids)
         return chunked_attention(q, k, v, chunk_size=chunk, causal=causal)
     if impl == "pallas" or (impl == "auto" and _pallas_ok(q, k, causal)):
-        try:
-            return pallas_attention(q, k, v, causal=causal, segment_ids=segment_ids)
-        except Exception as e:  # pragma: no cover
-            if impl == "pallas":
-                raise
-            # sxt: ignore[SXT005] exception class name only — bounded dedup cardinality
-            warning_once(f"pallas flash attention unavailable ({type(e).__name__}); using reference")
+        # selected means it runs or raises: a broken kernel must not turn
+        # into a slow correct run on the reference that nobody notices
+        if segment_ids is None:
+            return _per_shard(lambda q, k, v: pallas_attention(
+                q, k, v, causal=causal), q, k, v)
+        return _per_shard(lambda q, k, v, seg: pallas_attention(
+            q, k, v, causal=causal, segment_ids=seg), q, k, v, segment_ids)
     return reference_attention(q, k, v, causal=causal, segment_ids=segment_ids)
+
+
+def _per_shard(kernel, q, k, v, *rows, shard_heads: bool = True):
+    """Run an attention kernel on each device's block of q/k/v [B, T, H, D]
+    (``parallel.mesh.shard_kernel``): batch over the data-like axes, heads
+    over ``tensor`` when both head counts divide. ``rows`` are extra
+    [B, ...] operands (segment ids) split with the batch."""
+    from ..parallel.mesh import kernel_activation_spec, shard_kernel
+
+    heads = dict(heads_dim=2, head_counts=(k.shape[2],)) if shard_heads else {}
+    spec = kernel_activation_spec(q.shape, **heads)
+    row = kernel_activation_spec((q.shape[0], 1))
+    return shard_kernel(kernel, (spec, spec, spec) + (row,) * len(rows),
+                        spec)(q, k, v, *rows)

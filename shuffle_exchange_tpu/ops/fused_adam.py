@@ -119,14 +119,22 @@ class PallasAdamState(NamedTuple):
     nu: any
 
 
-def pallas_adamw(learning_rate, b1=0.9, b2=0.999, eps=1e-8, weight_decay=0.0):
+def pallas_adamw(learning_rate, b1=0.9, b2=0.999, eps=1e-8, weight_decay=0.0,
+                 leaf_specs=None):
     """optax.GradientTransformation whose update runs the fused kernel.
 
     Note: returns *updates* (new_p - p) so it composes with
     ``optax.apply_updates`` like any transformation; XLA folds the add away.
+
+    ``leaf_specs``: the PartitionSpec of every parameter leaf (the engine's
+    master shardings). In a program that spans several devices each device
+    then runs the kernel on its own shard of p/g/m/v
+    (``parallel.mesh.shard_kernel``: XLA cannot partition a Mosaic kernel);
+    the update is elementwise, so any layout is right.
     """
     import jax
     import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
 
     def init(params):
         zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
@@ -138,16 +146,32 @@ def pallas_adamw(learning_rate, b1=0.9, b2=0.999, eps=1e-8, weight_decay=0.0):
 
     def update(grads, state, params=None):
         assert params is not None, "pallas_adamw needs params (AdamW decoupled decay)"
+        # the schedule is read at the number of updates ALREADY made, as
+        # optax's scale_by_learning_rate does (and as the reference steps
+        # its scheduler after the optimizer): a warm-up's first step is
+        # lr(0). Only the bias correction counts this update in. "FusedAdam"
+        # is this on a TPU and optax.adamw elsewhere: the two must agree.
         count = state.count + 1
-        lr = learning_rate(count) if callable(learning_rate) else learning_rate
+        lr = (learning_rate(state.count) if callable(learning_rate)
+              else learning_rate)
 
-        def leaf(p, g, m, v):
-            new_p, new_m, new_v = fused_adamw_update(
-                p, g, m, v, lr=lr, b1=b1, b2=b2, eps=eps,
-                weight_decay=weight_decay, step=count)
+        from ..parallel.mesh import shard_kernel
+
+        def kernel(p, g, m, v, lr, count):
+            return fused_adamw_update(p, g, m, v, lr=lr, b1=b1, b2=b2, eps=eps,
+                                      weight_decay=weight_decay, step=count)
+
+        def leaf(p, g, m, v, spec=None):
+            spec = spec if spec is not None else P(*([None] * p.ndim))
+            new_p, new_m, new_v = shard_kernel(
+                kernel, (spec,) * 4 + (P(), P()), (spec,) * 3)(
+                    p, g, m, v, jnp.asarray(lr, jnp.float32), count)
             return (new_p.astype(jnp.float32) - p.astype(jnp.float32)), new_m, new_v
 
-        out = jax.tree_util.tree_map(leaf, params, grads, state.mu, state.nu)
+        trees = (params, grads, state.mu, state.nu)
+        if leaf_specs is not None:
+            trees += (leaf_specs,)
+        out = jax.tree_util.tree_map(leaf, *trees)
         treedef = jax.tree_util.tree_structure(params)
         leaves = jax.tree_util.tree_leaves(out, is_leaf=lambda x: isinstance(x, tuple) and len(x) == 3)
         updates = jax.tree_util.tree_unflatten(treedef, [l[0] for l in leaves])

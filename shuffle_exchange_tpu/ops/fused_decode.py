@@ -54,17 +54,37 @@ FUSABLE_ACTIVATIONS = ("swiglu", "silu", "relu", "gelu_new",
                        "gelu_pytorch_tanh")
 
 
-def _compiler_params(**kw):
-    """jax-version compat: ``pltpu.CompilerParams`` (new) vs
-    ``pltpu.TPUCompilerParams`` (<= 0.4.x); unknown fields are dropped so
-    the same call site lowers under either."""
-    import dataclasses
+# Mosaic's default scoped-VMEM limit on v5e is 16 MiB; the chip has 128.
+# Each kernel sums its own buffers (pipelined blocks count twice: they are
+# double-buffered) and asks for that plus headroom, so a wide model at a
+# large decode batch compiles instead of dying in "Ran out of memory in
+# memory space vmem". Past _VMEM_CAP the kernel raises at trace time.
+_VMEM_FLOOR = 32 << 20
+_VMEM_CAP = 100 << 20
+# most a kernel streams per grid step (both pipeline buffers of every
+# weight block): past this the block shrinks rather than the limit grows
+_W_STREAM_BYTES = 24 << 20
 
-    from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    names = {f.name for f in dataclasses.fields(cls)}
-    return cls(**{k: v for k, v in kw.items() if k in names})
+def _vmem_limit(need_bytes: int, what: str) -> int:
+    """``vmem_limit_bytes`` for a kernel whose buffers total ``need_bytes``."""
+    limit = max(_VMEM_FLOOR, need_bytes * 3 // 2)
+    if limit > _VMEM_CAP:
+        raise ValueError(
+            f"{what}: buffers need {need_bytes / 2**20:.1f} MiB of VMEM "
+            f"(cap {_VMEM_CAP >> 20} MiB) - the decode batch is too large "
+            f"for the fused kernels; lower serving.max_running or set "
+            f'decode_kernel: "xla"')
+    return limit
+
+
+def _nbytes(shape, dtype) -> int:
+    import numpy as np
+
+    n = np.dtype(dtype).itemsize
+    for d in shape:
+        n *= int(d)
+    return n
 
 
 def _pad_rows(x, rows: int):
@@ -127,6 +147,29 @@ def _rope_flat(x, cos_f, sin_f, head_dim: int, rd2: int):
 # ---------------------------------------------------------------------------
 
 
+# rows moved per pool DMA: one (8, 128) HBM tile per kv head. Mosaic
+# refuses a DMA slice that is not whole tiles, and a single bf16 row is
+# not even contiguous (the (2, 1) sub-tiling interleaves row pairs), so
+# the append is a read-modify-write of the 8-row group holding the slot.
+_APPEND_ROWS = 8
+
+
+def qkv_append_route(pool_shape, pool_dtype) -> str:
+    """How :func:`fused_qkv_rope_pallas` appends into a pool of this
+    geometry: ``"dma"`` (in-kernel, in place) when the pool's rows are whole
+    HBM tiles - head_dim a multiple of 128 lanes, block_size a multiple of
+    8 rows, 2- or 4-byte storage - else ``"scatter"`` (the same rows written
+    by an XLA scatter after the kernel; Mosaic cannot slice a lane-padded
+    HBM array at all, which is what a Dh=64 pool is)."""
+    import numpy as np
+
+    bs, Dh = pool_shape[-2:]
+    if (Dh % 128 == 0 and bs % _APPEND_ROWS == 0
+            and np.dtype(pool_dtype).itemsize in (2, 4)):
+        return "dma"
+    return "scatter"
+
+
 def fused_qkv_rope_pallas(y, wq, wk, wv, bq=None, bk=None, bv=None,
                           cos=None, sin=None, *, n_heads: int, kv_heads: int,
                           pool_k=None, pool_v=None, blk=None, off=None,
@@ -138,10 +181,11 @@ def fused_qkv_rope_pallas(y, wq, wk, wv, bq=None, bk=None, bv=None,
     y [B, D] (normalized hidden); wq [D, H*Dh]; wk/wv [D, KV*Dh]; biases
     flat [N]; cos/sin [B, rd/2] rope rows at each sequence's position
     (None = no RoPE). Returns (q [B, H, Dh], k [B, KV, Dh], v [B, KV, Dh])
-    — plus, when ``pool_k``/``pool_v`` ([nblk, KV, bs, Dh], or the stacked
+    - plus, when ``pool_k``/``pool_v`` ([nblk, KV, bs, Dh], or the stacked
     [L, ...] pool with ``layer``) and per-sequence ``blk``/``off`` indices
     are given, the pool pair with row (blk[b], :, off[b], :) overwritten
-    (``input_output_aliases``: the caller's buffer is updated, not copied).
+    (``input_output_aliases``: the caller's buffer is updated, not copied;
+    see :func:`qkv_append_route` for the two ways the rows get there).
 
     Weights stream through VMEM once (grid over D); accumulation f32.
     """
@@ -156,12 +200,14 @@ def fused_qkv_rope_pallas(y, wq, wk, wv, bq=None, bk=None, bv=None,
     H, KV = n_heads, kv_heads
     Dh = Nq // H
     assert Nq == H * Dh and Nkv == KV * Dh, (y.shape, wq.shape, wk.shape)
-    append = pool_k is not None
-    pooled = append and pool_k.ndim == 5
+    pooled = pool_k is not None and pool_k.ndim == 5
     if pooled and layer is None:
         raise ValueError("stacked [L, ...] pool needs a layer index")
+    append = (pool_k is not None
+              and qkv_append_route(pool_k.shape, pool_k.dtype) == "dma")
     has_rope = cos is not None
     rd2 = cos.shape[-1] if has_rope else 0
+    rg = _APPEND_ROWS
 
     Bp = max(8, -(-B // 8) * 8)
     yp = _pad_rows(y, Bp)
@@ -202,14 +248,15 @@ def fused_qkv_rope_pallas(y, wq, wk, wv, bq=None, bk=None, bv=None,
         if has_rope:
             cq_ref, sq_ref, ck_ref, sk_ref, *rest = rest
         if append:
-            pk_in, pv_in, *rest = rest
+            _pk_in, _pv_in, *rest = rest
             q_out, k_out, v_out, pk_out, pv_out = rest[:5]
             rest = rest[5:]
         else:
             q_out, k_out, v_out = rest[:3]
             rest = rest[3:]
         qacc, kacc, vacc = rest[:3]
-        sems = rest[3] if append else None
+        if append:
+            kst, vst, sems = rest[3:6]
         kstep = pl.program_id(0)
 
         @pl.when(kstep == 0)
@@ -239,29 +286,47 @@ def fused_qkv_rope_pallas(y, wq, wk, wv, bq=None, bk=None, bv=None,
             q_out[...] = qv.astype(q_out.dtype)
             k_out[...] = kv_.astype(k_out.dtype)
             v_out[...] = vv.astype(v_out.dtype)
-            if append:
-                lyr = scalars[2][0] if pooled else None
+            if not append:
+                return
+            lead = (scalars[2][0],) if pooled else ()
+
+            def group(pool_ref, b):
+                # the aligned _APPEND_ROWS-row group of block blk[b] that
+                # holds slot off[b], all kv heads: [KV, rg, Dh]
+                g0 = pl.multiple_of((scalars[1][b] // rg) * rg, rg)
+                return pool_ref.at[lead + (scalars[0][b], slice(None),
+                                           pl.ds(g0, rg), slice(None))]
+
+            def both_ways(to_vmem: bool):
                 copies = []
                 for b in range(B):
-                    bb = scalars[0][b]
-                    ob = scalars[1][b]
-                    for h in range(KV):
-                        if pooled:
-                            kdst = pk_out.at[lyr, bb, h, pl.ds(ob, 1), :]
-                            vdst = pv_out.at[lyr, bb, h, pl.ds(ob, 1), :]
-                        else:
-                            kdst = pk_out.at[bb, h, pl.ds(ob, 1), :]
-                            vdst = pv_out.at[bb, h, pl.ds(ob, 1), :]
-                        ksrc = k_out.at[pl.ds(b, 1), pl.ds(h * Dh, Dh)]
-                        vsrc = v_out.at[pl.ds(b, 1), pl.ds(h * Dh, Dh)]
+                    for i, (pool_ref, st) in enumerate(((pk_out, kst),
+                                                        (pv_out, vst))):
+                        src, dst = group(pool_ref, b), st.at[b]
+                        if not to_vmem:
+                            src, dst = dst, src
                         copies.append(pltpu.make_async_copy(
-                            ksrc, kdst, sems.at[0, b, h]))
-                        copies.append(pltpu.make_async_copy(
-                            vsrc, vdst, sems.at[1, b, h]))
+                            src, dst, sems.at[i, b]))
                 for c in copies:
                     c.start()
                 for c in copies:
                     c.wait()
+
+            both_ways(to_vmem=True)
+            # the rows land exactly as k_out/v_out hold them (rounded to
+            # the activation dtype first, as the XLA append does)
+            knew = kv_.astype(k_out.dtype).astype(jnp.float32)
+            vnew = vv.astype(v_out.dtype).astype(jnp.float32)
+            row = jax.lax.broadcasted_iota(jnp.int32, (rg, Dh), 0)
+            for b in range(B):
+                hit = row == scalars[1][b] % rg
+                for h in range(KV):
+                    cols = slice(h * Dh, (h + 1) * Dh)
+                    for st, new in ((kst, knew), (vst, vnew)):
+                        st[b, h] = jnp.where(
+                            hit, new[b:b + 1, cols],
+                            st[b, h].astype(jnp.float32)).astype(st.dtype)
+            both_ways(to_vmem=False)
 
     y_spec = pl.BlockSpec((Bp, bk_blk), lambda k, *_: (0, k))
     w_specs = [pl.BlockSpec((bk_blk, Nq), lambda k, *_: (k, 0)),
@@ -274,6 +339,7 @@ def fused_qkv_rope_pallas(y, wq, wk, wv, bq=None, bk=None, bv=None,
     if has_rope:
         in_specs += [full((Bp, Nq)), full((Bp, Nq)),
                      full((Bp, Nkv)), full((Bp, Nkv))]
+    N = Nq + 2 * Nkv
     out_shapes = [jax.ShapeDtypeStruct((Bp, Nq), y.dtype),
                   jax.ShapeDtypeStruct((Bp, Nkv), y.dtype),
                   jax.ShapeDtypeStruct((Bp, Nkv), y.dtype)]
@@ -281,14 +347,21 @@ def fused_qkv_rope_pallas(y, wq, wk, wv, bq=None, bk=None, bv=None,
     scratch = [pltpu.VMEM((Bp, Nq), jnp.float32),
                pltpu.VMEM((Bp, Nkv), jnp.float32),
                pltpu.VMEM((Bp, Nkv), jnp.float32)]
+    vmem = (2 * (_nbytes((Bp, bk_blk), y.dtype) + _nbytes((bk_blk, N), wq.dtype)
+                 + _nbytes((Bp, N), y.dtype))
+            + _nbytes((Bp, N), jnp.float32)
+            + (2 * 2 * _nbytes((Bp, N), jnp.float32) if has_rope else 0))
     aliases = {}
     if append:
-        any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        any_spec = pl.BlockSpec(memory_space=pl.ANY)
         in_specs += [any_spec, any_spec]
         out_shapes += [jax.ShapeDtypeStruct(pool_k.shape, pool_k.dtype),
                        jax.ShapeDtypeStruct(pool_v.shape, pool_v.dtype)]
         out_specs += [any_spec, any_spec]
-        scratch.append(pltpu.SemaphoreType.DMA((2, B, KV)))
+        scratch += [pltpu.VMEM((B, KV, rg, Dh), pool_k.dtype),
+                    pltpu.VMEM((B, KV, rg, Dh), pool_v.dtype),
+                    pltpu.SemaphoreType.DMA((2, B))]
+        vmem += 2 * _nbytes((B, KV, rg, Dh), pool_k.dtype)
         # operand order: scalar prefetch args come first in the alias count
         base = n_prefetch + len(in_specs) - 2
         aliases = {base: 3, base + 1: 4}
@@ -306,14 +379,25 @@ def fused_qkv_rope_pallas(y, wq, wk, wv, bq=None, bk=None, bv=None,
         out_shape=out_shapes,
         input_output_aliases=aliases,
         interpret=interpret,
-        compiler_params=_compiler_params(has_side_effects=append),
+        compiler_params=pltpu.CompilerParams(
+            has_side_effects=append,
+            vmem_limit_bytes=_vmem_limit(vmem, "fused QKV")),
     )(*scalar_in, yp, wq, wk, wv, *bias_in, *rope_in, *pool_in)
     q3 = outs[0][:B].reshape(B, H, Dh)
     k3 = outs[1][:B].reshape(B, KV, Dh)
     v3 = outs[2][:B].reshape(B, KV, Dh)
     if append:
         return q3, k3, v3, outs[3], outs[4]
-    return q3, k3, v3
+    if pool_k is None:
+        return q3, k3, v3
+    # "scatter" route: advanced (blk, off) indices around the KV slice
+    # address [B, KV, Dh] rows, matching k3/v3 (inference/paged.py
+    # append_token_kv, minus the block-table lookup the caller already did)
+    at = ((layer,) if pooled else ()) + (jnp.asarray(blk, jnp.int32),
+                                         slice(None),
+                                         jnp.asarray(off, jnp.int32))
+    return (q3, k3, v3, pool_k.at[at].set(k3.astype(pool_k.dtype)),
+            pool_v.at[at].set(v3.astype(pool_v.dtype)))
 
 
 # ---------------------------------------------------------------------------
@@ -490,7 +574,7 @@ def fused_paged_decode_attention_pallas(q, ck, cv, block_table, kv_len, *,
         out_shape=[jax.ShapeDtypeStruct((B, nsplit, H, Dh), jnp.float32),
                    jax.ShapeDtypeStruct((B, nsplit, H, 1), jnp.float32),
                    jax.ShapeDtypeStruct((B, nsplit, H, 1), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(bt, kvl, *layer_in, q3, ck, cv, *scales_in, *slopes_in)
@@ -559,8 +643,18 @@ def fused_mlp_pallas(resid, y_src, ln_w, ln_b, w_up, w_down, w_gate=None,
     Bp = max(8, -(-B // 8) * 8)
     rp = _pad_rows(resid, Bp)
     yp = _pad_rows(y_src, Bp)
+    n_w = 3 if gated else 2
+    # the streamed weight blocks are double-buffered; halve the F-chunk
+    # (down to one lane tile) while a step's worth passes _W_STREAM_BYTES
     bf = _pick_block(F, block_f)
+    while bf > 128 and 2 * n_w * _nbytes((D, bf), w_up.dtype) > _W_STREAM_BYTES:
+        bf = _pick_block(F, bf // 2)
     nf = F // bf
+    vmem = (2 * n_w * _nbytes((D, bf), w_up.dtype)          # weight blocks
+            + 2 * 3 * _nbytes((Bp, D), resid.dtype)         # resid, y, out
+            + _nbytes((Bp, D), resid.dtype)                 # yn scratch
+            + _nbytes((Bp, D), jnp.float32)                 # acc scratch
+            + 3 * _nbytes((Bp, bf), jnp.float32))           # u, g, act
     lnw = ln_w.reshape(1, D)
     lnb = (ln_b.reshape(1, D) if (apply_norm and norm == "layernorm"
                                   and hasattr(ln_b, "reshape"))
@@ -627,6 +721,8 @@ def fused_mlp_pallas(resid, y_src, ln_w, ln_b, w_up, w_down, w_gate=None,
         scratch_shapes=[pltpu.VMEM((Bp, D), resid.dtype),
                         pltpu.VMEM((Bp, D), jnp.float32)],
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(vmem, "fused MLP")),
     )(rp, yp, lnw, lnb, *weights, *bias_in)
     return out[:B]
 
@@ -756,6 +852,14 @@ def fused_mlp_quant_pallas(resid, y_src, ln_w, ln_b, w_up, w_down,
             in_specs += [spec, s_up_spec]
             operands += [qm.q, qm.scales.reshape(D // gs, 1, -1)]
 
+    # dequantized blocks are f32 in VMEM whatever the storage width
+    vmem = (2 * 3 * _nbytes((Bp, D), resid.dtype)           # resid, y, out
+            + _nbytes((Bp, D), resid.dtype)                 # yn scratch
+            + _nbytes((Bp, D), jnp.float32)                 # acc scratch
+            + 4 * _nbytes((Bp, bf), jnp.float32)            # g/u acc, act
+            + 2 * (2 * _nbytes((gs, bf), jnp.float32)       # up/gate blocks
+                   + _nbytes((bf, D), jnp.float32))         # down block
+            + 2 * _nbytes((bf, D), jnp.float32))            # its dequant
     out = pl.pallas_call(
         kernel,
         grid=(nf, nk),
@@ -767,6 +871,8 @@ def fused_mlp_quant_pallas(resid, y_src, ln_w, ln_b, w_up, w_down,
                         pltpu.VMEM((Bp, bf), jnp.float32),
                         pltpu.VMEM((Bp, D), jnp.float32)],
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(vmem, "fused quantized MLP")),
     )(*operands)
     return out[:B]
 

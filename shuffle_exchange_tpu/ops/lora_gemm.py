@@ -36,8 +36,6 @@ TPU variant is lowering-gated in tests/test_mosaic_lowering.py.
 
 from __future__ import annotations
 
-from .fused_decode import _compiler_params
-
 
 def lora_delta_oracle(x, a_stack, b_stack, slots):
     """XLA gather path: x [B, T, D], a_stack [S, D, R], b_stack [S, R, N],
@@ -94,7 +92,7 @@ def lora_delta_pallas(x, a_stack, b_stack, slots, *, interpret: bool = False):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, N), x.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(slots.astype(jnp.int32), x, a_stack, b_stack)

@@ -34,48 +34,54 @@ def _build_dir() -> str:
     return CSRC_DIR
 
 
+_SOURCES = ("aio.cc", "cpu_optim.cc", "packbits.cc", "sxt_native.h")
+# No -march=native: the library is built where it is first used but may be
+# loaded elsewhere (a copy of the tree on another machine's CPU).
+_CXX = ("g++", "-O3", "-std=c++17", "-fPIC", "-Wall", "-fopenmp", "-shared")
+
+
 def _compile() -> Optional[str]:
-    out_dir = _build_dir()
-    so_path = os.path.join(out_dir, "libsxt_native.so")
-    srcs = [os.path.join(CSRC_DIR, f) for f in ("aio.cc", "cpu_optim.cc", "packbits.cc")]
-    hdr = os.path.join(CSRC_DIR, "sxt_native.h")
-    if not all(os.path.exists(s) for s in srcs + [hdr]):
+    """Path of the built library, building it if no build of THESE sources
+    with THESE flags exists. The library is named by the digest of both, so
+    staleness is decided by content: copying the tree may reset every mtime,
+    and a library left behind by other sources is simply not this one."""
+    import hashlib
+
+    paths = [os.path.join(CSRC_DIR, f) for f in _SOURCES]
+    if not all(os.path.exists(p) for p in paths):
         return None
+    digest = hashlib.sha256(" ".join(_CXX).encode())
+    for path in paths:
+        with open(path, "rb") as f:
+            digest.update(f.read())
+    out_dir = _build_dir()
+    so_path = os.path.join(out_dir,
+                           f"libsxt_native.{digest.hexdigest()[:16]}.so")
     if os.path.exists(so_path):
-        newest_src = max(os.path.getmtime(p) for p in srcs + [hdr])
-        if os.path.getmtime(so_path) >= newest_src:
-            return so_path
+        return so_path
     # Build to a per-PID temp name and os.rename into place: rename is atomic
     # on the same filesystem, so concurrent processes (multiple local ranks,
     # parallel test runs, a shared NFS cache) never dlopen a half-written .so
     # or clobber each other mid-build.
     tmp_path = os.path.join(out_dir, f".libsxt_native.{os.getpid()}.tmp.so")
-    for archflag in ("-march=native", ""):
-        cmd = ["g++", "-O3", "-std=c++17", "-fPIC", "-Wall", "-fopenmp"]
-        if archflag:
-            cmd.append(archflag)
-        cmd += ["-shared", "-o", tmp_path] + srcs
-        try:
-            res = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
-        except (OSError, subprocess.TimeoutExpired) as e:
-            logger.warning(f"native build failed to launch: {e}")
-            return None
-        if res.returncode == 0:
-            try:
-                os.rename(tmp_path, so_path)
-            except OSError as e:
-                logger.warning(f"native build rename failed: {e}")
-                if os.path.exists(so_path):  # another process won the race
-                    return so_path
-                return None
-            return so_path
-        logger.warning(f"native build failed ({' '.join(cmd[:2])}...): {res.stderr[-500:]}")
-    if os.path.exists(tmp_path):
-        try:
+    cmd = list(_CXX) + ["-o", tmp_path] + [p for p in paths if p.endswith(".cc")]
+    try:
+        res = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        logger.warning(f"native build failed to launch: {e}")
+        return None
+    if res.returncode != 0:
+        logger.warning(f"native build failed ({' '.join(cmd[:2])}...): "
+                       f"{res.stderr[-500:]}")
+        if os.path.exists(tmp_path):
             os.remove(tmp_path)
-        except OSError:
-            pass
-    return None
+        return None
+    try:
+        os.rename(tmp_path, so_path)
+    except OSError as e:
+        logger.warning(f"native build rename failed: {e}")
+        return so_path if os.path.exists(so_path) else None
+    return so_path
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:

@@ -357,26 +357,12 @@ def paged_extend_attention(q, ck, cv, block_table, start, nnew, *,
     vq, vs = kv_parts(cv)
     if impl == "pallas" or (impl == "auto" and pallas_enabled()
                             and q.shape[2] % kq.shape[1] == 0):
-        try:
-            return paged_extend_attention_pallas(q, kq, vq, block_table,
-                                                 start, nnew,
-                                                 alibi_slopes=alibi_slopes,
-                                                 k_scale=ks, v_scale=vs)
-        except Exception as e:
-            if impl == "pallas":
-                raise
-            from ..utils.logging import warning_once
-
-            # a silent per-step degrade to the gather path hides real
-            # kernel regressions (ADVICE r5 #3) — say so once, with enough
-            # shape context to reproduce
-            # sxt: ignore[SXT005] shape context is deliberate (ADVICE r5 #3) and bounded by the shape-bin ladder
-            warning_once(
-                "paged_extend_attention: Pallas kernel failed with "
-                f"{type(e).__name__} (q={tuple(q.shape)} "
-                f"kv_pool={tuple(kq.shape)} "
-                f"table={tuple(block_table.shape)}); falling back to the "
-                "gather path, which materializes the layer's KV")
+        # selected means it runs or raises: a degrade to the gather path
+        # (which materializes the layer's KV) would hide a broken kernel
+        return paged_extend_attention_pallas(q, kq, vq, block_table,
+                                             start, nnew,
+                                             alibi_slopes=alibi_slopes,
+                                             k_scale=ks, v_scale=vs)
     from ..inference.engine import extend_attention
     from ..inference.paged import gather_kv
 
@@ -403,34 +389,15 @@ def paged_decode_attention(q, ck, cv, block_table, kv_len, *,
     vq, vs = kv_parts(cv)
     pooled = kq.ndim == 5
     if pooled and layer is None:
-        # validate BEFORE dispatch: the auto path's except would swallow
-        # the kernel's informative error and the gather fallback would
-        # crash opaquely on a None index
         raise ValueError("stacked [L, nblk, KV, bs, Dh] pool needs a "
                          "layer index (layer=...)")
     kv_heads = kq.shape[2] if pooled else kq.shape[1]
     if impl == "pallas" or (impl == "auto" and pallas_enabled()
                             and q.shape[2] % kv_heads == 0):
-        try:
-            return paged_decode_attention_pallas(q, kq, vq, block_table,
-                                                 kv_len, layer=layer,
-                                                 alibi_slopes=alibi_slopes,
-                                                 k_scale=ks, v_scale=vs)
-        except Exception as e:
-            if impl == "pallas":
-                raise
-            from ..utils.logging import warning_once
-
-            # the bare except also swallows stacked-pool kernel failures —
-            # exactly the whole-layer KV copy the pooled mode exists to
-            # avoid (ADVICE r5 #3); make the degrade visible once
-            # sxt: ignore[SXT005] shape context is deliberate (ADVICE r5 #3) and bounded by the shape-bin ladder
-            warning_once(
-                "paged_decode_attention: Pallas kernel failed with "
-                f"{type(e).__name__} (q={tuple(q.shape)} "
-                f"kv_pool={tuple(kq.shape)} pooled={pooled} "
-                f"table={tuple(block_table.shape)}); falling back to the "
-                "gather path, which materializes the layer's KV")
+        return paged_decode_attention_pallas(q, kq, vq, block_table,
+                                             kv_len, layer=layer,
+                                             alibi_slopes=alibi_slopes,
+                                             k_scale=ks, v_scale=vs)
     from ..inference.paged import gather_kv
     from ..inference.engine import decode_attention
 

@@ -20,22 +20,24 @@ def rmsnorm_reference(x, weight, eps: float = 1e-5):
     return (out * weight.astype(jnp.float32)).astype(x.dtype)
 
 
-def _use_pallas(x) -> bool:
+def rmsnorm(x, weight, eps: float = 1e-5, residual=None):
+    """RMSNorm with optional fused residual input: norm(x + residual) * w.
+    The Pallas kernel where the backend is a TPU and the row is whole lane
+    tiles, else the jnp form; a kernel that was selected runs or raises."""
     from .dispatch import pallas_enabled
 
-    return pallas_enabled()
-
-
-def rmsnorm(x, weight, eps: float = 1e-5, residual=None):
-    """RMSNorm with optional fused residual input: norm(x + residual) * w."""
     if residual is not None:
         x = x + residual
-    if _use_pallas(x) and x.shape[-1] % 128 == 0:
-        try:
-            return _rmsnorm_vjp(x, weight, eps)
-        except Exception:  # pragma: no cover - fallback safety
-            return rmsnorm_reference(x, weight, eps)
-    return rmsnorm_reference(x, weight, eps)
+    if not (pallas_enabled() and x.shape[-1] % 128 == 0):
+        return rmsnorm_reference(x, weight, eps)
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.mesh import kernel_activation_spec, shard_kernel
+
+    rows = kernel_activation_spec(x.shape,
+                                  seq_dim=1 if x.ndim >= 3 else None)
+    return shard_kernel(lambda x, w: _rmsnorm_vjp(x, w, eps),
+                        (rows, P(None)), rows)(x, weight)
 
 
 _VJP_CACHE = {}
@@ -95,7 +97,11 @@ def _rmsnorm_pallas(x, weight, eps):
     for s in orig_shape[:-1]:
         rows *= s
     x2 = x.reshape(rows, d)
-    block_rows = 256 if rows >= 256 else rows
+    # in and out blocks are both double-buffered: 4 x block bytes must stay
+    # well inside Mosaic's 16 MiB default scoped VMEM (256 rows of f32 at
+    # d 4096 is 4 MiB a block and was refused by 16 KiB)
+    fit = max(8, (2 << 20) // (d * x.dtype.itemsize) // 8 * 8)
+    block_rows = min(rows, 256, fit)
 
     def kernel(x_ref, w_ref, o_ref):
         xv = x_ref[:].astype(jnp.float32)

@@ -170,7 +170,6 @@ def sparse_attention(q, k, v, config: Optional[SparsityConfig] = None, causal: b
         elem_np = elem_np & np.tril(np.ones((T, S), bool), k=S - T)
 
     if impl in ("auto", "splash"):
-        from ..utils.logging import warning_once
         from .dispatch import pallas_enabled
         from .flash_attention import splash_attention_gqa
 
@@ -181,16 +180,10 @@ def sparse_attention(q, k, v, config: Optional[SparsityConfig] = None, causal: b
                 f"impl='splash' needs D%64==0, T/S%128==0 and no fully-masked "
                 f"query row (got T={T}, S={S}, D={D})")
         if eligible and (impl == "splash" or pallas_enabled()):
-            try:
-                return splash_attention_gqa(q, k, v, causal=False,
-                                            mask_np=elem_np,
-                                            interpret=impl == "splash" and not pallas_enabled())
-            except Exception as e:  # pragma: no cover - fallback safety
-                if impl == "splash":
-                    raise
-                # sxt: ignore[SXT005] exception class name only — bounded dedup cardinality
-                warning_once(f"splash blocksparse unavailable "
-                             f"({type(e).__name__}); dense-mask fallback")
+            # selected means it runs or raises (no dense-mask rescue)
+            return splash_attention_gqa(q, k, v, causal=False,
+                                        mask_np=elem_np,
+                                        interpret=impl == "splash" and not pallas_enabled())
 
     n_rep = H // k.shape[2]
     k = _repeat_kv(k, n_rep)

@@ -23,6 +23,8 @@ sensitive, per-layer) ride ICI while pipe/data (less frequent) may cross DCN.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -104,7 +106,17 @@ class MeshTopology:
             mesh_config = MeshConfig()
         spec = resolve_axis_sizes(mesh_config, len(devices))
         shape = tuple(spec.sizes[ax] for ax in AXIS_ORDER)
-        dev_array = np.asarray(devices).reshape(shape)
+        if devices[0].platform == "tpu" and len(devices) > 1:
+            # jax.devices() is in id order, which on a 2x2 torus puts
+            # diagonal chips next to each other; mesh_utils lays the logical
+            # axes along the physical ones, so the innermost live axis
+            # rides neighbouring ICI links
+            from jax.experimental import mesh_utils
+
+            dev_array = mesh_utils.create_device_mesh(
+                shape, devices=list(devices), allow_split_physical_axes=True)
+        else:
+            dev_array = np.asarray(devices).reshape(shape)
         mesh = jax.sharding.Mesh(dev_array, AXIS_ORDER)
         log_dist(f"Mesh built: {dict(zip(AXIS_ORDER, shape))} over {len(devices)} devices", ranks=[0])
         return cls(mesh)
@@ -210,70 +222,32 @@ def reset_topology() -> None:
 
 # Reference-compatible getter names (utils/groups.py:57-749).
 
-def native_shard_map() -> bool:
-    """True when this jax exposes the first-class ``jax.shard_map`` (>= 0.5),
-    whose partial-manual lowering handles collectives with live (size > 1)
-    auto axes. The 0.4.x ``jax.experimental.shard_map`` fallback lowers
-    FULL-manual regions (and partial-manual regions whose auto axes are all
-    size 1) correctly, but a collective inside a partial-manual region with
-    a live auto axis trips an XLA SPMD-partitioner CHECK
-    (spmd_partitioner.cc:512 IsManualSubgroup) — a process abort, not an
-    exception — so callers must gate statically on this, never probe."""
-    import jax
-
-    return hasattr(jax, "shard_map")
-
-
 def shard_map(f, mesh=None, in_specs=None, out_specs=None, axis_names=None,
               check_vma: bool = True):
-    """``jax.shard_map``-compatible facade that also runs on jax 0.4.x.
-
-    ``axis_names`` is the set of MANUAL axes (partial-manual region);
-    None means every mesh axis is manual. On 0.4.x this maps onto
-    ``jax.experimental.shard_map.shard_map``'s complementary ``auto=`` set
-    and ``check_vma`` onto ``check_rep``. See :func:`native_shard_map` for
-    the 0.4.x lowering limits.
-    """
+    """``jax.shard_map`` with ``axis_names`` (the MANUAL axes of a
+    partial-manual region; None = every mesh axis) taken as any iterable."""
     import jax
 
-    if native_shard_map():
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    all_axes = frozenset(mesh.axis_names)
-    manual = frozenset(axis_names) if axis_names is not None else all_axes
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=bool(check_vma), auto=all_axes - manual)
+    kw = {}
+    if axis_names is not None:
+        kw["axis_names"] = frozenset(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
-def _abstract_mesh_ctx():
+def _manual_axes() -> frozenset:
+    """Mesh axes that are manual at this point of the trace (empty outside
+    any shard_map region)."""
     import jax
 
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        return None  # jax 0.4.x: no trace-context abstract mesh
-    try:
-        return get()
-    except Exception:
-        return None
+    ctx = jax.sharding.get_abstract_mesh()
+    return frozenset(name for name, t in zip(ctx.axis_names, ctx.axis_types)
+                     if t == jax.sharding.AxisType.Manual)
 
 
 def inside_manual_region() -> bool:
-    """True when tracing inside a (partial-)manual shard_map region.
-    On jax 0.4.x (no abstract-mesh trace context) this returns False."""
-    import jax
-
-    ctx = _abstract_mesh_ctx()
-    if ctx is None or not getattr(ctx, "axis_names", ()):
-        return False
-    try:
-        return any(t == jax.sharding.AxisType.Manual for t in ctx.axis_types)
-    except Exception:
-        return False
+    """True when tracing inside a (partial-)manual shard_map region."""
+    return bool(_manual_axes())
 
 
 def constraint_mesh(default=None):
@@ -282,21 +256,102 @@ def constraint_mesh(default=None):
     Inside a (partial-)manual region, constraints must be built on the
     CONTEXT abstract mesh (whose enclosing axes are typed Manual) — a
     NamedSharding over the concrete topology mesh (all-Auto) trips the
-    mesh-equality check. Outside any region — and always on jax 0.4.x,
-    where nested shard_maps take the concrete mesh — returns ``default``
-    (or the topology mesh)."""
+    mesh-equality check. Outside any region returns ``default`` (or the
+    topology mesh)."""
     import jax
 
-    ctx = _abstract_mesh_ctx()
-    if ctx is not None and getattr(ctx, "axis_names", ()):
-        try:
-            if any(t == jax.sharding.AxisType.Manual for t in ctx.axis_types):
-                return ctx
-        except Exception:
-            pass
+    if _manual_axes():
+        return jax.sharding.get_abstract_mesh()
     if default is not None:
         return default
     return get_topology().mesh
+
+
+# ----------------------------------------------------------------------
+# Pallas kernels inside programs that span several devices
+# ----------------------------------------------------------------------
+
+_KERNEL_MESH: contextvars.ContextVar = contextvars.ContextVar(
+    "sxt_kernel_mesh", default=None)
+
+
+@contextlib.contextmanager
+def kernel_mesh(mesh):
+    """While tracing under this, Pallas kernel call sites wrap themselves in
+    a shard_map over ``mesh`` (:func:`shard_kernel`). The training engine
+    enters it around the loss and the optimizer update of its mesh-wide
+    programs; a serving engine's one-device programs never do, so a live
+    training topology in the same process does not reach into them. A
+    context variable, so a thread that traces concurrently sees its own."""
+    token = _KERNEL_MESH.set(mesh)
+    try:
+        yield
+    finally:
+        _KERNEL_MESH.reset(token)
+
+
+def kernel_activation_spec(shape, seq_dim: Optional[int] = None,
+                           heads_dim: Optional[int] = None,
+                           head_counts: Sequence[int] = ()):
+    """PartitionSpec of a batch-major activation inside a kernel's
+    shard_map, following the training layout: dim 0 over the live data-like
+    axes (``data``, ``fsdp``), ``seq_dim`` over ``seq`` and ``heads_dim``
+    over ``tensor`` - each only where the axis is live and divides the
+    dimension (and every count in ``head_counts``, for GQA's two head
+    numbers). A dimension left out is whole on every device: more work than
+    needed, never a wrong answer."""
+    from jax.sharding import PartitionSpec
+
+    mesh = _KERNEL_MESH.get()
+    spec = [None] * len(shape)
+    if mesh is None or len(shape) < 2:
+        return PartitionSpec(*spec)
+    axes = tuple(ax for ax in ZERO_AXES if mesh.shape[ax] > 1)
+    if axes and shape[0] % int(np.prod([mesh.shape[ax] for ax in axes])) == 0:
+        spec[0] = axes
+    sp, tp = mesh.shape["seq"], mesh.shape["tensor"]
+    if seq_dim is not None and sp > 1 and shape[seq_dim] % sp == 0:
+        spec[seq_dim] = "seq"
+    if heads_dim is not None and tp > 1 and all(
+            h % tp == 0 for h in (shape[heads_dim], *head_counts)):
+        spec[heads_dim] = "tensor"
+    return PartitionSpec(*spec)
+
+
+def shard_kernel(fn, in_specs, out_specs):
+    """``fn`` (a Pallas kernel call), made safe inside a program that spans
+    several devices. XLA cannot partition a Mosaic kernel: there the call
+    must sit in a shard_map that is manual over EVERY mesh axis, each device
+    running the kernel on its own block as the specs lay them out. Outside
+    :func:`kernel_mesh`, or on a one-device mesh, ``fn`` is returned as it
+    is. Inside an enclosing (partial-)manual region only the remaining axes
+    are taken, on the region's own mesh, and the specs lose the axes that
+    are manual already (their blocks are local by then)."""
+    mesh = _KERNEL_MESH.get()
+    if mesh is None or mesh.size == 1:
+        return fn
+    import jax
+    from jax.sharding import PartitionSpec
+
+    taken = _manual_axes()
+    free = [ax for ax in mesh.axis_names if ax not in taken]
+    if not free:
+        return fn
+
+    def keep(spec):
+        def entry(e):
+            if e is None:
+                return None
+            axes = tuple(a for a in ((e,) if isinstance(e, str) else e)
+                         if a not in taken)
+            return axes or None
+        return PartitionSpec(*(entry(e) for e in spec))
+
+    is_spec = lambda x: isinstance(x, PartitionSpec)
+    return shard_map(fn, mesh=constraint_mesh(mesh),
+                     in_specs=jax.tree.map(keep, in_specs, is_leaf=is_spec),
+                     out_specs=jax.tree.map(keep, out_specs, is_leaf=is_spec),
+                     axis_names=free, check_vma=False)
 
 
 def get_data_parallel_world_size() -> int:

@@ -133,9 +133,9 @@ def spmd_pipeline(stage_fn: Callable, x_micro, *, n_stages: int, axis_name: str 
     x_micro: [n_micro, mb, ...] microbatched stage-0 inputs (replicated over
       the pipe axis; only stage 0 reads them).
     stage_index: this device's stage number. Callers inside a PARTIAL-manual
-      region should thread it as a P(axis_name)-sharded arange operand:
-      ``lax.axis_index`` there lowers to a PartitionId instruction that jax
-      0.4.x's SPMD partitioner rejects when auto axes are still live.
+      region thread it as a P(axis_name)-sharded arange operand, not
+      ``lax.axis_index`` (which lowers to a PartitionId instruction the SPMD
+      partitioner has rejected with auto axes still live).
 
     Returns (outputs [n_micro, mb, ...] — valid on the LAST stage, zeros
     elsewhere; aux — sum of stage_fn aux over all (stage, microbatch) pairs,
@@ -175,11 +175,8 @@ def spmd_pipeline(stage_fn: Callable, x_micro, *, n_stages: int, axis_name: str 
     outputs0 = jnp.zeros_like(x_micro)
     # The aux carry is [1], not a 0-d scalar: when the aux genuinely
     # participates in the gradient (a mixed-MoE stack's load-balance loss),
-    # grad-of-shard_map on jax 0.4.x saves the scan carry as region
-    # residuals and assigns each a stacked-over-devices spec on dim 0 — a
-    # rank-0 residual has no dim 0 and the transpose dies in _check_names
-    # (_SpecError). Dense stacks never hit this (their constant-zero aux is
-    # pruned as a symbolic-zero cotangent before residuals are chosen).
+    # grad-of-shard_map can save the scan carry as a region residual with a
+    # stacked-over-devices spec on dim 0, which a rank-0 residual has not.
     carry0 = (state0, outputs0, jnp.zeros((1,), jnp.float32))
     (state, outputs, aux), _ = jax.lax.scan(tick, carry0, jnp.arange(n_ticks))
     return outputs, aux[0]
@@ -324,35 +321,6 @@ class PipelinedModel:
         inputs = inputs.reshape(n_micro, mb, T)
         labels = labels.reshape(n_micro, mb, T)
         mesh = _current_mesh()
-        # jax 0.4.x cannot lower ppermute inside a PARTIAL-manual region
-        # that still has a live (size > 1) auto axis — an XLA SPMD-
-        # partitioner CHECK abort, not an exception (parallel/mesh.py::
-        # native_shard_map). The pipeline region there must be FLAT: manual
-        # over pipe AND the batch axes, with the microbatch dim sharded
-        # in-region and the CE reduced by explicit psums. This is also the
-        # region shape the ZeRO++ quantized wire composes with (the engine
-        # wraps this same body in its own flat region to make the gradient
-        # reduction ride the s8 wire — runtime/engine.py qg/qz3 pipe path).
-        from .mesh import native_shard_map
-
-        flat = not native_shard_map()
-        dp_world = int(mesh.shape.get("data", 1) * mesh.shape.get("fsdp", 1))
-        if flat:
-            bad = [ax for ax in ("tensor", "expert", "seq")
-                   if int(mesh.shape.get(ax, 1)) > 1]
-            if bad:
-                raise ConfigError(
-                    "pipeline parallelism with a live "
-                    f"{'/'.join(bad)} axis needs jax >= 0.5 (first-class "
-                    "jax.shard_map): the 0.4.x partial-manual lowering "
-                    "CHECK-fails on the pipeline's ppermute with live auto "
-                    "axes, and the flat manual region cannot absorb "
-                    "auto-partitioned model axes")
-            if mb % dp_world:
-                raise ConfigError(
-                    f"pipeline microbatch {mb} not divisible by "
-                    f"data*fsdp={dp_world} (flat pipeline region shards the "
-                    "microbatch dim in-region)")
         # Re-constrain params to their model (pipe/tensor) specs before the
         # manual region: any extra ZeRO axis on the masters is all-gathered
         # OUT HERE by XLA (one gather per stage-local stack — the PP analog
@@ -384,41 +352,16 @@ class PipelinedModel:
                 pad_idx += rows + [L_total] * (S_sz - len(rows))
             keep_flags = jnp.asarray(keep)
             layer_ids = jnp.asarray(pad_idx, jnp.int32)
-            # pad rows: id == n_layers -> per-layer flags off
-            if not flat:
-                # native shard_map (jax >= 0.5): gather the padded
-                # [S * stage_size] stack out here and shard it over
-                # "pipe" — each device holds only its stage's rows
-                def pad_stack(a):
-                    zero_row = jnp.zeros((1,) + a.shape[1:], a.dtype)
-                    return jnp.concatenate([a, zero_row])[layer_ids]
+            # pad rows: id == n_layers -> per-layer flags off. Gather the
+            # padded [S * stage_size] stack out here and shard it over
+            # "pipe" — each device holds only its stage's rows
+            def pad_stack(a):
+                zero_row = jnp.zeros((1,) + a.shape[1:], a.dtype)
+                return jnp.concatenate([a, zero_row])[layer_ids]
 
-                layer_params = jax.tree_util.tree_map(pad_stack,
-                                                      layer_params)
-        # jax 0.4.x only (the flat region): an in-graph concatenate+gather
-        # that PRODUCES a P("pipe") region operand is silently
-        # mis-partitioned when a live batch axis shares the flat manual
-        # region — wrong VALUES, no error (the even path is unaffected
-        # because its stacks enter the region ungathered). Ship the RAW
-        # [L] stacks replicated there instead and gather each stage's
-        # rows INSIDE the manual region, where layer_ids
-        # (P("pipe")-sharded) is this stage's local row map and the
-        # gather is a purely local op. Memory cost (full stack resident
-        # per pipe device) is confined to uneven-on-0.4.x.
-        uneven_replicated = (not self._even) and flat
-        layer_specs = jax.tree_util.tree_map(
-            lambda _: P() if uneven_replicated else P(self.axis_name),
-            layer_params)
-        if uneven_replicated:
-            # replicated float region inputs ride in at fp32 like
-            # other_params below (same convert-feeds-replicated-input
-            # partitioner hazard), re-cast inside the region
-            layer_dtypes = jax.tree_util.tree_map(
-                lambda v: v.dtype, layer_params)
-            layer_params = jax.tree_util.tree_map(
-                lambda v: (v.astype(jnp.float32)
-                           if jnp.issubdtype(v.dtype, jnp.floating) else v),
-                layer_params)
+            layer_params = jax.tree_util.tree_map(pad_stack, layer_params)
+        layer_specs = jax.tree_util.tree_map(lambda _: P(self.axis_name),
+                                             layer_params)
 
         # XLA's partial-manual partitioner CHECK-fails when a convert feeds a
         # replicated (P()) shard_map input whose cotangent must psum over the
@@ -434,23 +377,8 @@ class PipelinedModel:
                   inputs, labels):
             other_params = jax.tree_util.tree_map(
                 lambda v, d: v.astype(d), other_params, other_dtypes)
-            if uneven_replicated:
-                # this stage's padded row block, gathered locally from the
-                # replicated raw stacks (see the 0.4.x note above):
-                # layer_ids holds the stage's global row ids, n_layers
-                # selecting the appended zero (identity-masked) pad row
-                layer_params = jax.tree_util.tree_map(
-                    lambda v, d: v.astype(d), layer_params, layer_dtypes)
-
-                def gather_stage(a):
-                    zero_row = jnp.zeros((1,) + a.shape[1:], a.dtype)
-                    return jnp.concatenate([a, zero_row])[layer_ids]
-
-                layer_params = jax.tree_util.tree_map(gather_stage,
-                                                      layer_params)
             # this device's stage number, threaded as a P("pipe")-sharded
-            # operand (see spmd_pipeline: axis_index lowers to PartitionId,
-            # which jax 0.4.x rejects under partial-manual)
+            # operand (see spmd_pipeline)
             my_stage = stage_ids[0]
             # Embed per microbatch (cheap gather; runs on every stage but
             # only stage 0's result is consumed — its cotangent is zero
@@ -474,9 +402,7 @@ class PipelinedModel:
             stage = my_stage
 
             sp = _current_mesh().shape.get("seq", 1)
-            if sp > 1 or flat:
-                # (flat mode: keep the collective schedule uniform across
-                # the whole region — same rendezvous argument as seq)
+            if sp > 1:
                 # seq x pipe (round 5): with an auto "seq" axis live inside
                 # this region, the CE contains seq-group collectives; a
                 # stage-VARYING lax.cond would run them only on the last
@@ -507,31 +433,18 @@ class PipelinedModel:
         from .mesh import shard_map as _shard_map
 
         stage_ids = jnp.arange(S, dtype=jnp.int32)
-        if flat:
-            manual = {self.axis_name, "data", "fsdp"}
-            batch_spec = P(None, ("data", "fsdp"))
-            part_spec = P((self.axis_name, "data", "fsdp"))
-        else:
-            manual = {self.axis_name}
-            batch_spec = P()
-            part_spec = P(self.axis_name)
+        part_spec = P(self.axis_name)
         fn = _shard_map(
             inner, mesh=mesh,
             in_specs=(layer_specs,
                       P() if isinstance(keep_flags, tuple) else P(self.axis_name),
-                      P(self.axis_name), P(self.axis_name), P(),
-                      batch_spec, batch_spec),
+                      P(self.axis_name), P(self.axis_name), P(), P(), P()),
             out_specs=(part_spec, part_spec, part_spec),
-            axis_names=manual, check_vma=False)
+            axis_names={self.axis_name}, check_vma=False)
         nll_parts, count_parts, aux_parts = fn(layer_params, keep_flags,
                                                layer_ids, stage_ids,
                                                other_params, inputs, labels)
         nll_sum, count, aux = nll_parts.sum(), count_parts.sum(), aux_parts.sum()
-        # flat mode: every (data,fsdp) shard contributes a copy of the aux
-        # (each computed on its batch shard); average them back to the
-        # full-batch coefficient scale.
-        if flat and dp_world > 1:
-            aux = aux / dp_world
         ce = nll_sum / jnp.maximum(count, 1.0)
         # aux summed layers×micros; dense model sums layers on the full
         # batch, so average over microbatches to keep the coefficient scale.
@@ -545,7 +458,7 @@ class PipelinedModel:
         runtime/engine.py qg/qz3 pipe paths — wraps exactly this body so the
         gradient reduction can ride the s8 collectives; nesting this class's
         own shard_map there CHECK-fails XLA's partitioner from either
-        direction, scripts/repro_wire_nesting_xla_check.py).
+        direction).
 
         ``params``: model-structured tree whose ``layers`` stacks are THIS
         STAGE's rows ([L/S, ...]; even partitions only) and whose other
@@ -600,7 +513,7 @@ class PipelinedModel:
                                      stage_index=stage)
 
         # uniform collective schedule (every stage runs the CE, masked) —
-        # same rendezvous argument as the flat loss above
+        # same rendezvous argument as loss() under a live seq axis
         nll_all, count_all = _stage_ce(model, other_params, outputs, labels)
         is_last = (stage == S - 1).astype(jnp.float32)
         nll_sum = jax.lax.psum(nll_all * is_last, self.axis_name)

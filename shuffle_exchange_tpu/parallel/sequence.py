@@ -292,11 +292,8 @@ def ring_attention(q, k, v, axis_name: str = "seq", causal: bool = True,
     l0 = jnp.zeros((B, H, Tq), jnp.float32)
     # The chunk scan's carry must already be device-varying over the seq
     # axis (its outputs are), or shard_map's vma check rejects the scan.
-    # (jax 0.4.x has no pcast and no vma checking — skip the cast there.)
-    _pcast = getattr(jax.lax, "pcast", None)
-    if _pcast is not None:
-        acc0, m0, l0 = (_pcast(t, (axis_name,), to="varying")
-                        for t in (acc0, m0, l0))
+    acc0, m0, l0 = (jax.lax.pcast(t, (axis_name,), to="varying")
+                    for t in (acc0, m0, l0))
 
     carry = (acc0, m0, l0)
     kv = (k, v)
@@ -358,10 +355,6 @@ def _ring_attention_kernel(q, k, v, axis_name: str, causal: bool,
             def skip_branch(q, kb, vb):
                 # constants must carry the same varying-axes set as the
                 # kernel branches' outputs or cond rejects the branch types
-                # (jax 0.4.x: no vma tracking — constants pass as-is)
-                if getattr(jax.lax, "pcast", None) is None:
-                    return (jnp.zeros(q.shape, q.dtype),
-                            jnp.full((B, H, Tq), -jnp.inf, jnp.float32))
                 vma = frozenset()
                 for t in (q, kb, vb):
                     vma = vma | jax.typeof(t).vma

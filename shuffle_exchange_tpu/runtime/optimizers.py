@@ -28,11 +28,14 @@ def _split_wd(params_fn: Optional[Callable] = None):
 
 
 def build_optimizer(optimizer_config, lr_schedule, gradient_clipping: float = 0.0,
-                    weight_decay_mask: Optional[Any] = None) -> optax.GradientTransformation:
+                    weight_decay_mask: Optional[Any] = None,
+                    leaf_specs: Optional[Any] = None) -> optax.GradientTransformation:
     """Build the optax chain: [clip_by_global_norm] -> update rule (lr = schedule).
 
     Loss-scale unscaling and overflow skipping are handled by the engine
     around this transformation (they need the loss-scale state).
+    ``leaf_specs``: PartitionSpec per parameter leaf, for update rules that
+    run as a Pallas kernel on each device's shard (``ops/fused_adam.py``).
     """
     if optimizer_config is None:
         raise ConfigError("No optimizer section in config and no client optimizer provided")
@@ -76,7 +79,8 @@ def build_optimizer(optimizer_config, lr_schedule, gradient_clipping: float = 0.
             # HBM read/write of p/m/v per step instead of optax's op chain.
             from ..ops.fused_adam import pallas_adamw
 
-            tx = pallas_adamw(schedule, b1=b1, b2=b2, eps=eps, weight_decay=wd)
+            tx = pallas_adamw(schedule, b1=b1, b2=b2, eps=eps, weight_decay=wd,
+                              leaf_specs=leaf_specs)
         elif adam_w_mode or lowered == "adamw":
             tx = optax.adamw(schedule, b1=b1, b2=b2, eps=eps, weight_decay=wd, mask=weight_decay_mask)
         else:

@@ -419,7 +419,7 @@ class ProcessReplicaRouter:
                deadline_s: Optional[float] = None, sampling=None,
                adapter_id: Optional[str] = None) -> int:
         """Place one request; returns its fleet-wide uid. Raises the
-        threaded taxonomy: LoadShedError past the shed bound,
+        threaded router's error classes: LoadShedError past the shed bound,
         NoActiveReplicaError with zero survivors, and the aggregated
         per-replica refusals when nobody can take it."""
         from ..inference.scheduler import ServingRequest

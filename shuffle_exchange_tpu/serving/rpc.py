@@ -14,7 +14,7 @@ msgpack would be marginally tighter but is not in the image; JSON + raw
 tail keeps the dependency surface at stdlib + numpy and the planes
 uncopied on the wire (ISSUE 17 constraint: no new deps).
 
-Failure taxonomy (what the router's health machine consumes):
+Failure classes (what the router's health machine consumes):
 
 - :class:`RpcTimeout`        — the peer ACCEPTED the connection but never
   answered inside ``timeout_s``: the SIGSTOP/hung-process shape. The
@@ -338,7 +338,7 @@ class RpcClient:
              timeout_s: Optional[float] = None
              ) -> Tuple[dict, List[np.ndarray]]:
         """One request/response exchange; returns ``(result, planes)``.
-        Raises the taxonomy: :class:`RpcTimeout` (reachable, no answer),
+        Raises one of: :class:`RpcTimeout` (reachable, no answer),
         :class:`RpcConnectionLost` (refused/reset/EOF),
         :class:`RpcRemoteError` (handler raised),
         :class:`RpcProtocolError` (non-frame bytes)."""

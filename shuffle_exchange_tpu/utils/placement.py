@@ -3,9 +3,8 @@
 On the CPU backend, ``jax.device_put`` of an aligned numpy array can
 zero-copy: the resulting jax.Array aliases the host buffer instead of
 owning a copy. That alias is fine for read-only use, but an executable
-with ``donate_argnums`` deserialized from the persistent compilation
-cache will reuse the buffer as scratch/output (jax 0.4.x) — and once the
-numpy side is garbage-collected, the program is writing through freed
+with ``donate_argnums`` may reuse the buffer as scratch/output — and once
+the numpy side is garbage-collected, the program is writing through freed
 memory: silently corrupted training state, and eventually a segfault.
 
 The resilience suite's bit-exact crash→resume cycles exposed this on the
@@ -34,30 +33,16 @@ def owned_device_put(arr, sharding):
 
 
 def cache_safe_donate_argnums(argnums):
-    """``donate_argnums`` to actually pass to ``jax.jit``.
+    """``donate_argnums`` to pass to ``jax.jit``: the one seam every donating
+    program of the package goes through (sxt-check rule SXT002 pins call
+    sites to it).
 
-    jax 0.4.x CPU: an executable deserialized from the persistent
-    compilation cache races donated-buffer frees — the runtime releases the
-    donated inputs while the (aliasing-info-less) deserialized program is
-    still reading them. The result is nondeterministic corruption of
-    whatever reuses the freed pages (observed: garbage/NaN training state
-    after a checkpoint restore, then segfaults — found by the resilience
-    suite's bit-exact crash→resume cycles). When that combination is
-    active, donation is disabled: one extra buffer copy per step on a CPU
-    host beats silently corrupted training state. TPU/GPU backends keep
-    donation (and its HBM savings) unconditionally."""
-    import jax
-
-    try:
-        cache_dir = jax.config.jax_compilation_cache_dir
-    except AttributeError:
-        cache_dir = None
-    if cache_dir and jax.default_backend() == "cpu":
-        from .logging import warning_once
-
-        warning_once(
-            "persistent compilation cache + CPU backend: disabling jit "
-            "input donation (jax 0.4.x deserialized executables race "
-            "donated-buffer frees, corrupting memory)")
-        return ()
+    It used to drop donation on the CPU backend while the persistent
+    compilation cache was on, for a runtime that freed donated inputs under
+    a deserialized executable. On the installed jax (0.9.0) that race did
+    not reproduce: with donation on, the resilience suite's bit-exact
+    crash->resume cycles passed three times running on a warm cache
+    (PR 23). Nothing is dropped now; if those cycles ever fail with
+    garbage/NaN diffs, this is the place to look first. ROADMAP D1 retires
+    the seam together with the rule."""
     return tuple(argnums)

@@ -163,7 +163,7 @@ class TestSpace:
 
     def test_tier_knobs_overlay_roundtrip(self):
         """Candidate -> overlay -> InferenceConfig -> candidate carries
-        the tier point (the PR 13 tunnel-window contract: the winner's
+        the tier point (the PR 13 one-chip-window contract: the winner's
         knobs replay verbatim from its overlay)."""
         c = ServingCandidate(token_budget=32, spill_enabled=True,
                              hot_block_fraction=0.25, prefetch_depth=2)

@@ -76,23 +76,6 @@ class TestConfig:
                                                 "use_kernel": "cuda"}},
                           world_size=2)
 
-    def test_cp_times_pipe_rejected_on_04x(self, devices8):
-        """CP x pipe on jax 0.4.x: the ring's manual region cannot nest in
-        the pipeline's manual stage region — a targeted ConfigError names
-        the committed repro instead of an XLA CHECK-abort."""
-        from shuffle_exchange_tpu.parallel.mesh import native_shard_map
-
-        if native_shard_map():
-            pytest.skip("jax >= 0.5: CP x pipe composes natively")
-        reset_topology()
-        with pytest.raises(ConfigError, match="context_parallel.*pipe"):
-            sxt.initialize(
-                model=Transformer(_mcfg()),
-                config=_train_cfg(context_parallel={"degree": 2},
-                                  pipeline_parallel_size=2,
-                                  mesh={"pipe": 2, "seq": 2, "data": -1}))
-        reset_topology()
-
 
 # ---------------------------------------------------------------------------
 # Engine routing + parity

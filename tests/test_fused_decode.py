@@ -95,39 +95,68 @@ def test_fused_qkv_bias_no_rope_parity():
     np.testing.assert_allclose(np.asarray(v), np.asarray(vr), atol=1e-5, rtol=1e-5)
 
 
-def test_fused_qkv_append_writes_pool_in_place():
+@pytest.mark.parametrize("Dh,dtype,pooled,route", [
+    (32, "float32", False, "scatter"),     # lane-padded rows: XLA scatter
+    (128, "float32", False, "dma"),        # whole-tile rows: in-kernel RMW
+    (128, "bfloat16", False, "dma"),
+    (128, "bfloat16", True, "dma"),        # stacked [L, ...] pool + layer
+    (64, "bfloat16", True, "scatter"),
+])
+def test_fused_qkv_append_writes_pool_in_place(Dh, dtype, pooled, route):
     """The append form must write EXACTLY the new token's rows (blk[b], :,
-    off[b], :) and leave every other pool element untouched — including a
-    block-boundary case (off == 0 of a fresh block)."""
+    off[b], :) and leave every other pool element untouched, by either
+    route - including a block-boundary case (off == 0 of a fresh block)
+    and slots in both 8-row groups of a block (the DMA route rewrites the
+    whole aligned group around the slot)."""
     import jax.numpy as jnp
 
     from shuffle_exchange_tpu.models.transformer import rope_table
-    from shuffle_exchange_tpu.ops.fused_decode import fused_qkv_rope_pallas
+    from shuffle_exchange_tpu.ops.fused_decode import (fused_qkv_rope_pallas,
+                                                       qkv_append_route)
 
     rng = np.random.default_rng(2)
-    B, D, H, KV, Dh, nblk, bs = 3, 128, 4, 2, 32, 7, 16
-    y = jnp.asarray(rng.standard_normal((B, D)), jnp.float32)
-    wq = jnp.asarray(rng.standard_normal((D, H * Dh)) * 0.05, jnp.float32)
-    wk = jnp.asarray(rng.standard_normal((D, KV * Dh)) * 0.05, jnp.float32)
-    wv = jnp.asarray(rng.standard_normal((D, KV * Dh)) * 0.05, jnp.float32)
-    pool_k = jnp.asarray(rng.standard_normal((nblk, KV, bs, Dh)), jnp.float32)
-    pool_v = jnp.asarray(rng.standard_normal((nblk, KV, bs, Dh)), jnp.float32)
-    # pos 16 = first slot of a fresh block (block boundary), 0 = empty seq
-    pos = jnp.asarray([16, 0, 5], jnp.int32)
+    B, D, H, KV, nblk, bs, L = 3, 128, 4, 2, 7, 16, 3
+    dt = jnp.dtype(dtype)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    y = jnp.asarray(rng.standard_normal((B, D)), dt)
+    wq = jnp.asarray(rng.standard_normal((D, H * Dh)) * 0.05, dt)
+    wk = jnp.asarray(rng.standard_normal((D, KV * Dh)) * 0.05, dt)
+    wv = jnp.asarray(rng.standard_normal((D, KV * Dh)) * 0.05, dt)
+    shape = ((L,) if pooled else ()) + (nblk, KV, bs, Dh)
+    pool_k = jnp.asarray(rng.standard_normal(shape), dt)
+    pool_v = jnp.asarray(rng.standard_normal(shape), dt)
+    assert qkv_append_route(pool_k.shape, pool_k.dtype) == route
+    # pos 16 = first slot of a fresh block (block boundary), 0 = empty
+    # seq, 13 = second 8-row group of its block
+    pos = jnp.asarray([16, 0, 13], jnp.int32)
     blk = jnp.asarray([4, 2, 6], jnp.int32)
     off = pos % bs
     cos_t, sin_t = rope_table(64, Dh, 10000.0)
     cos, sin = jnp.take(cos_t, pos, axis=0), jnp.take(sin_t, pos, axis=0)
+    layer = {"layer": jnp.int32(1)} if pooled else {}
 
     q, k, v, pk2, pv2 = fused_qkv_rope_pallas(
         y, wq, wk, wv, cos=cos, sin=sin, n_heads=H, kv_heads=KV,
-        pool_k=pool_k, pool_v=pool_v, blk=blk, off=off, interpret=True)
-    ref_pk, ref_pv = np.array(pool_k), np.array(pool_v)
+        pool_k=pool_k, pool_v=pool_v, blk=blk, off=off, interpret=True,
+        **layer)
+    ref_pk = np.array(pool_k.astype(jnp.float32))
+    ref_pv = np.array(pool_v.astype(jnp.float32))
+    at = (1,) if pooled else ()
     for b in range(B):
-        ref_pk[int(blk[b]), :, int(off[b]), :] = np.asarray(k[b])
-        ref_pv[int(blk[b]), :, int(off[b]), :] = np.asarray(v[b])
-    np.testing.assert_allclose(np.asarray(pk2), ref_pk, atol=1e-5, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(pv2), ref_pv, atol=1e-5, rtol=1e-5)
+        ref_pk[at + (int(blk[b]), slice(None), int(off[b]))] = np.asarray(
+            k[b].astype(jnp.float32))
+        ref_pv[at + (int(blk[b]), slice(None), int(off[b]))] = np.asarray(
+            v[b].astype(jnp.float32))
+    # untouched elements must be bit-identical; the new rows equal k/v
+    np.testing.assert_array_equal(np.asarray(pk2.astype(jnp.float32)), ref_pk)
+    np.testing.assert_array_equal(np.asarray(pv2.astype(jnp.float32)), ref_pv)
+    kr, vr = _qkv_ref(y.astype(jnp.float32), wq.astype(jnp.float32),
+                      wk.astype(jnp.float32), wv.astype(jnp.float32),
+                      cos, sin, H, KV, Dh)[1:]
+    np.testing.assert_allclose(np.asarray(k.astype(jnp.float32)),
+                               np.asarray(kr), atol=tol, rtol=tol)
+    np.testing.assert_allclose(np.asarray(v.astype(jnp.float32)),
+                               np.asarray(vr), atol=tol, rtol=tol)
 
 
 # ---------------------------------------------------------------------------

@@ -401,7 +401,7 @@ def test_lora_composes_with_pipeline(devices8):
         b = _batch(b=32)
         return [float(engine.train_batch(b)) for _ in range(3)]
 
-    # the flat pipeline region (jax 0.4.x) reduces the CE with a different
+    # the pipeline region reduces the CE with a different
     # association than the auto-sharded dense step; lr=1e-2 Adam amplifies
     np.testing.assert_allclose(run({"pipe": 2, "data": -1}),
                                run({"data": -1}), rtol=2e-2)

@@ -80,3 +80,165 @@ def test_comms_logger_records(devices8):
     report = comm.log_summary()
     assert "all_reduce" in report
     comm.comms_logger.enabled = False
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernels inside programs that span several devices (shard_kernel).
+# XLA cannot partition a Mosaic kernel; on the chip the 4-device ZeRO-3 step
+# failed to lower until every kernel call sat in a full-manual shard_map.
+# The kernels themselves need a TPU; what is checked here is the wrapper:
+# same numbers as the unwrapped function, on the layouts training uses.
+# ---------------------------------------------------------------------------
+
+
+def _rms(x, w):
+    import jax
+    import jax.numpy as jnp
+
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + 1e-5) * w
+
+
+def test_shard_kernel_is_the_function_itself_outside_a_kernel_mesh(devices8):
+    from jax.sharding import PartitionSpec as P
+
+    from shuffle_exchange_tpu.parallel.mesh import (kernel_activation_spec,
+                                                    kernel_mesh, shard_kernel)
+
+    assert shard_kernel(_rms, (P(), P()), P()) is _rms
+    assert kernel_activation_spec((8, 16, 32), seq_dim=1) == P(None, None, None)
+    one = MeshTopology.build(MeshConfig(data=1), devices=devices8[:1])
+    with kernel_mesh(one.mesh):       # a one-device mesh needs no wrapping
+        assert shard_kernel(_rms, (P(), P()), P()) is _rms
+    with kernel_mesh(None):           # ensemble engines pass None
+        assert shard_kernel(_rms, (P(), P()), P()) is _rms
+
+
+def test_kernel_activation_spec_follows_the_training_layout(devices8):
+    from jax.sharding import PartitionSpec as P
+
+    from shuffle_exchange_tpu.parallel.mesh import (kernel_activation_spec,
+                                                    kernel_mesh)
+
+    topo = MeshTopology.build(MeshConfig(data=2, fsdp=2, tensor=2),
+                              devices=devices8)
+    with kernel_mesh(topo.mesh):
+        assert kernel_activation_spec((8, 16, 32)) == P(("data", "fsdp"), None, None)
+        # a batch the data-like axes do not divide stays whole
+        assert kernel_activation_spec((6, 16, 32)) == P(None, None, None)
+        # heads over tensor only when BOTH head counts divide (GQA)
+        assert kernel_activation_spec((8, 16, 4, 64), heads_dim=2,
+                                      head_counts=(2,)) == P(
+            ("data", "fsdp"), None, "tensor", None)
+        assert kernel_activation_spec((8, 16, 4, 64), heads_dim=2,
+                                      head_counts=(1,)) == P(
+            ("data", "fsdp"), None, None, None)
+    seq = MeshTopology.build(MeshConfig(data=4, seq=2), devices=devices8)
+    with kernel_mesh(seq.mesh):
+        assert kernel_activation_spec((8, 16, 32), seq_dim=1) == P(
+            ("data",), "seq", None)
+
+
+def test_shard_kernel_matches_the_unwrapped_function_under_jit(devices8):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from shuffle_exchange_tpu.parallel.mesh import (kernel_activation_spec,
+                                                    kernel_mesh, shard_kernel)
+
+    topo = MeshTopology.build(MeshConfig(data=2, fsdp=4), devices=devices8)
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((16, 12, 64)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((64,)), jnp.float32)
+
+    def step(x, w):
+        with kernel_mesh(topo.mesh):
+            rows = kernel_activation_spec(x.shape)
+            fn = shard_kernel(_rms, (rows, P(None)), rows)
+            assert fn is not _rms
+            return fn(x, w)
+
+    xs = jax.device_put(x, topo.batch_sharding())
+    got = jax.jit(step)(xs, w)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(_rms(x, w)),
+                               rtol=1e-6, atol=1e-6)
+    # differentiable straight through the wrapper (training's backward)
+    g = jax.jit(jax.grad(lambda x, w: step(x, w).sum(), argnums=(0, 1)))(xs, w)
+    gr = jax.grad(lambda x, w: _rms(x, w).sum(), argnums=(0, 1))(x, w)
+    for a, b in zip(g, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_shard_kernel_inside_a_partial_manual_region_takes_the_rest(devices8):
+    """Inside a region that is already manual over data/fsdp (the ZeRO++
+    wire, Ulysses) the wrapper takes only the remaining axes and drops the
+    taken ones from the specs - the blocks are local by then."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from shuffle_exchange_tpu.parallel.mesh import (kernel_activation_spec,
+                                                    kernel_mesh, shard_kernel,
+                                                    shard_map)
+
+    topo = MeshTopology.build(MeshConfig(data=2, fsdp=2, tensor=2),
+                              devices=devices8)
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.standard_normal((8, 6, 32)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((32,)), jnp.float32)
+    seen = {}
+
+    def inner(x, w):
+        with kernel_mesh(topo.mesh):
+            rows = kernel_activation_spec(x.shape)
+            fn = shard_kernel(_rms, (rows, P(None)), rows)
+            seen["wrapped"] = fn is not _rms
+            return fn(x, w)
+
+    region = shard_map(inner, mesh=topo.mesh,
+                       in_specs=(P(("data", "fsdp")), P()),
+                       out_specs=P(("data", "fsdp")),
+                       axis_names={"data", "fsdp"}, check_vma=False)
+    got = jax.jit(region)(x, w)
+    assert seen["wrapped"]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(_rms(x, w)),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_pallas_adamw_runs_per_shard_with_leaf_specs(devices8):
+    """The fused-AdamW transformation with the engine's leaf specs, inside a
+    mesh-wide jit: every device updates its own shard and the result is the
+    unsharded update. (On the CPU ``fused_adamw_update`` takes its jnp
+    branch; the plumbing around it is what a chip cannot rehearse.)"""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from shuffle_exchange_tpu.ops.fused_adam import pallas_adamw
+    from shuffle_exchange_tpu.parallel.mesh import kernel_mesh
+
+    topo = MeshTopology.build(MeshConfig(data=2, fsdp=4), devices=devices8)
+    rng = np.random.default_rng(2)
+    params = {"w": jnp.asarray(rng.standard_normal((16, 24)), jnp.float32),
+              "b": jnp.asarray(rng.standard_normal((24,)), jnp.float32)}
+    grads = jax.tree.map(lambda p: p * 0.1 + 0.01, params)
+    specs = {"w": P(("data", "fsdp"), None), "b": P(None)}
+    plain = pallas_adamw(1e-2, weight_decay=0.1)
+    want, _ = plain.update(grads, plain.init(params), params)
+
+    tx = pallas_adamw(1e-2, weight_decay=0.1, leaf_specs=specs)
+
+    def step(g, state, p):
+        with kernel_mesh(topo.mesh):
+            return tx.update(g, state, p)
+
+    place = lambda t: jax.tree.map(
+        lambda x, s: jax.device_put(x, NamedSharding(topo.mesh, s)), t, specs)
+    got, new_state = jax.jit(step)(place(grads), tx.init(place(params)),
+                                   place(params))
+    for k in params:
+        np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]),
+                                   rtol=1e-6, atol=1e-7)
+    assert int(new_state.count) == 1

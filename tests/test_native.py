@@ -19,6 +19,39 @@ def test_native_builds():
     assert native_available()
 
 
+def test_native_library_is_named_by_content_and_built_portably(
+        tmp_path, monkeypatch):
+    """The tree reaches the chip machine as a copy: mtimes are reset and a
+    library built for this CPU would be loaded on another. So the build is
+    portable (no -march=native) and staleness is a question of content: the
+    library's name carries the digest of sources + flags."""
+    import shutil
+    import time
+
+    from shuffle_exchange_tpu.ops.native import builder
+
+    assert not any("march" in flag for flag in builder._CXX)
+    src = tmp_path / "csrc"
+    shutil.copytree(builder.CSRC_DIR, src,
+                    ignore=shutil.ignore_patterns("*.so"))
+    monkeypatch.setattr(builder, "CSRC_DIR", str(src))
+    monkeypatch.delenv("SXT_NATIVE_CACHE", raising=False)
+    first = builder._compile()
+    assert first and os.path.dirname(first) == str(src)
+    built = os.path.getmtime(first)
+    # sources that look NEWER than the library (a fresh copy of the tree)
+    # are still the same sources: no rebuild
+    later = time.time() + 3600
+    for name in builder._SOURCES:
+        os.utime(src / name, (later, later))
+    assert builder._compile() == first and os.path.getmtime(first) == built
+    # other content is another library; the stale one is simply not it
+    with open(src / "packbits.cc", "a") as f:
+        f.write("// edited\n")
+    second = builder._compile()
+    assert second and second != first and os.path.exists(first)
+
+
 # ---------------------------------------------------------------------------
 # aio
 # ---------------------------------------------------------------------------
@@ -101,7 +134,7 @@ def test_adam_native_matches_numpy(adamw):
     p0, grads = _numpy_ref(adam_step)
     pn, mn, vn, bf16n = _run_adam(True, p0, grads, adamw=adamw)
     pf, mf, vf, bf16f = _run_adam(False, p0, grads, adamw=adamw)
-    # fp32 FMA-contraction noise only (-march=native fuses mul+add).
+    # fp32 reassociation/contraction noise only.
     np.testing.assert_allclose(pn, pf, rtol=1e-4, atol=5e-7)
     np.testing.assert_allclose(vn, vf, rtol=1e-4, atol=5e-7)
     # 1-ulp fp32 differences flip bf16 rounding only at half-way points.

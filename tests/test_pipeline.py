@@ -163,7 +163,7 @@ def test_uneven_pipeline_matches_dense(devices8):
     pm = e_pipe.loss_fn.__self__
     assert pm._bounds == [0, 3, 5] and pm.stage_size == 3 and not pm._even
     l_pipe = [float(e_pipe.train_batch(batch)) for _ in range(2)]
-    # the flat pipeline region (jax 0.4.x) reduces the CE with a different
+    # the pipeline region reduces the CE with a different
     # association than the auto-sharded dense step; Adam amplifies the
     # last-bit differences over steps — keep a small trajectory margin
     np.testing.assert_allclose(l_dense, l_pipe, rtol=4e-3)

@@ -135,7 +135,7 @@ def test_ring_attention_kernel_hops_match_reference(devices8, causal, kvh):
         q, k, v, axis_name="seq", causal=causal, use_kernel=True,
         interpret=True),
         mesh=topo.mesh, in_specs=P(None, "seq"), out_specs=P(None, "seq"),
-        check_vma=False)  # 0.4.x: no replication rule for pallas_call
+        check_vma=False)  # no replication rule for pallas_call
     got = jax.jit(fn)(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -159,7 +159,7 @@ def test_ring_attention_kernel_backward(devices8):
         lambda q, k, v: ring_attention(q, k, v, axis_name="seq", causal=True,
                                        use_kernel=True, interpret=True),
         mesh=topo.mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False))  # 0.4.x: no replication rule for pallas_call
+        check_vma=False))  # no replication rule for pallas_call
 
     def loss(q, k, v):
         return f(q, k, v).sum()
@@ -183,15 +183,6 @@ def test_engine_seq_times_pipe_matches_dp(devices8):
     partial-manual over {data,fsdp,seq} and nests inside the pipeline's
     manual-over-pipe stage region (reference runs SP inside PP stages via
     its groups registry, utils/groups.py:633). Trajectory matches plain DP."""
-    from shuffle_exchange_tpu.parallel.mesh import native_shard_map
-
-    if not native_shard_map():
-        import pytest
-
-        pytest.skip("seq x pipe needs jax >= 0.5 nested partial-manual "
-                    "shard_map (0.4.x lowering CHECK-fails; the engine "
-                    "raises a targeted ConfigError there — "
-                    "test_zeropp_wire_meshes pins it)")
     import shuffle_exchange_tpu as sxt
     from shuffle_exchange_tpu.models import Transformer, tiny
     from shuffle_exchange_tpu.parallel import reset_topology
@@ -386,7 +377,7 @@ def test_engine_seq_times_expert_moe_matches_dp(devices8):
     reset_topology()
 
     # bf16 + capacity-dispatch MoE under a resharded mesh: ~1%/step drift
-    # on the CPU backend (replicated-attention fallback on jax 0.4.x)
+    # on the CPU backend
     np.testing.assert_allclose(l_sp, l_dp, rtol=2e-2)
 
 

@@ -67,6 +67,25 @@ def _repetitive_prompts(rng, n=3, period=4, lo=20, hi=28):
     return [(cyc * 12)[:int(rng.integers(lo, hi))] for _ in range(n)]
 
 
+def _cycle_walker(model, params, cyc):
+    """``params`` rebuilt so that greedy decoding from any token of ``cyc``
+    emits the next one, for ever: the layers add nothing to the residual
+    (zero out-projection and down-projection), each cycle token embeds to
+    its own unit vector, and the unembedding of its successor is that
+    vector. The final norm only rescales, so the successor's logit wins."""
+    import jax.numpy as jnp
+
+    layers = dict(params["layers"])
+    for name in ("wo", "w_down"):
+        layers[name] = jnp.zeros_like(layers[name])
+    embed = jnp.zeros_like(params["embed"])
+    unembed = jnp.zeros_like(params["unembed"])
+    for i, tok in enumerate(cyc):
+        embed = embed.at[tok, i].set(1.0)
+        unembed = unembed.at[i, cyc[(i + 1) % len(cyc)]].set(1.0)
+    return {**params, "layers": layers, "embed": embed, "unembed": unembed}
+
+
 class TestParity:
     @pytest.mark.parametrize("k", [1, 2, 4])
     def test_ngram_speculative_matches_sequential_reference(
@@ -275,14 +294,28 @@ class TestOneDispatchAndCompiles:
     def test_steps_per_emitted_token_bar(self, model_and_params):
         """The ISSUE acceptance bar: k=4 self-speculation on a
         repetitive-suffix workload measures < 0.67 decode steps per
-        emitted token per sequence (>= 1.5x fewer steps than k=0)."""
+        emitted token per sequence (>= 1.5x fewer steps than k=0).
+
+        Grounded on a CONSTRUCTED target, not a random model's argmax: the
+        weights below make greedy decoding walk a fixed 4-token cycle, so
+        every n-gram draft is the verifier's own next token and must be
+        accepted. (The bar used to ride the seeded random weights' luck:
+        their greedy output happened to be repetitive enough under jax
+        0.4.37 and missed `base / 1.5` by 0.008 once jax 0.5 flipped
+        `jax_threefry_partitionable` and `PRNGKey(0)` drew other weights.)"""
         model, params = model_and_params
+        cyc = [11, 47, 5, 80]
+        params = _cycle_walker(model, params, cyc)
         rng = np.random.default_rng(5)
-        prompts = _repetitive_prompts(rng, n=3)
+        prompts = [(cyc * 12)[:int(rng.integers(20, 28))] for _ in range(3)]
         eng = InferenceEngineV2(model, params, _icfg(k=4))
         sched = ContinuousBatchingScheduler(eng)
-        sched.serve(prompts, max_new_tokens=40)
+        out = sched.serve(prompts, max_new_tokens=40)
+        for p, toks in zip(prompts, out.values()):
+            at = cyc.index(p[-1])
+            assert toks == [cyc[(at + 1 + i) % 4] for i in range(40)]
         st = sched.stats()["speculative"]
+        assert st["proposed"] > 0 and st["acceptance_rate"] == 1.0, st
         assert st["steps_per_emitted_token"] < 0.67, st
         # the k=0 baseline on the same trace sits near 1.0
         eng0 = InferenceEngineV2(model, params, _icfg(spec=False))

@@ -174,19 +174,6 @@ def test_stage3_wire_loss_parity_with_exact(devices8):
     assert np.isfinite(lq) and abs(lq - lx) / abs(lx) < 0.05
 
 
-def _needs_native_shard_map():
-    """The partial-manual wire with a LIVE tensor/expert auto axis needs
-    jax >= 0.5 (first-class jax.shard_map): the 0.4.x lowering CHECK-aborts
-    on collectives there, so the engine emulates instead (see
-    parallel/mesh.py::native_shard_map)."""
-    from shuffle_exchange_tpu.parallel.mesh import native_shard_map
-
-    if not native_shard_map():
-        pytest.skip("real s8 wire with live tensor/expert auto axes needs "
-                    "jax >= 0.5 partial-manual lowering (engine emulates "
-                    "on 0.4.x)")
-
-
 def _s8_lines(hlo, kind):
     return [l for l in hlo.splitlines() if kind in l and "s8" in l]
 
@@ -207,7 +194,6 @@ def test_stage3_wire_on_tensor_mesh(devices8):
     (coalesced_collectives.py:31 called from stage_1_and_2.py under MP;
     partition_parameters.py:824). tensor=2 x fsdp=4: the compiled step
     still carries s8 gathers AND s8 reduce collectives."""
-    _needs_native_shard_map()
     reset_topology()
     cfg = _base_config(stage=3, zero_quantized_weights=True,
                        zero_quantized_gradients=True)
@@ -243,7 +229,6 @@ def test_stage3_wire_tensor_mesh_loss_parity(devices8):
 def test_qgz_stage2_wire_on_tensor_mesh(devices8):
     """qgZ's hierarchical int8 reduce under TP (stage <= 2): the reference
     reduces quantized with model parallelism active."""
-    _needs_native_shard_map()
     reset_topology()
     cfg = _base_config(stage=2, zero_quantized_gradients=True)
     cfg["mesh"] = {"tensor": 2, "data": -1}
@@ -261,7 +246,6 @@ def test_stage3_wire_on_expert_mesh(devices8):
     placement must survive the partial-manual region (moe/layer.py's
     constraint is try/except-guarded, so a silent drop would only show as
     replicated experts; assert the s8 wire AND a finite decreasing loss)."""
-    _needs_native_shard_map()
     from shuffle_exchange_tpu.models import Transformer as T, tiny_moe
 
     reset_topology()
