@@ -24,6 +24,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from ..profiling import trace
 from ..utils.logging import log_dist, logger
 from .config import InferenceConfig
 from . import sampling
@@ -446,47 +447,57 @@ class InferenceEngine:
         cfg = self._mcfg
         B, T = h.shape[:2]
         H, KV, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-        y = _norm(h, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm, eps=cfg.norm_eps)
-        qkv = None if lora is not None else \
-            self._maybe_fused_qkv(lw, y, cos, sin, positions)
-        if qkv is None:
-            q = y @ lw["wq"]
-            k = y @ lw["wk"]
-            v = y @ lw["wv"]
-            if lora is not None:
-                q = self._lora_add(q, y, lora, "wq")
-                k = self._lora_add(k, y, lora, "wk")
-                v = self._lora_add(v, y, lora, "wv")
-            q = q.reshape(B, T, H, Dh)
-            k = k.reshape(B, T, KV, Dh)
-            v = v.reshape(B, T, KV, Dh)
-            if cfg.attn_qkv_bias:
-                q = q + lw["b_q"].astype(y.dtype).reshape(H, Dh)
-                k = k + lw["b_k"].astype(y.dtype).reshape(KV, Dh)
-                v = v + lw["b_v"].astype(y.dtype).reshape(KV, Dh)
-            if cfg.position == "rope":
-                pc, ps = _rope_rows(cos, sin, positions)
-                q = _apply_rope_batched(q, pc, ps, interleaved=cfg.rope_interleaved)
-                k = _apply_rope_batched(k, pc, ps, interleaved=cfg.rope_interleaved)
-        else:
-            q, k, v = qkv
-        attn, cache_out = attn_fn(q, k, v)
+        with trace.scope("attn_norm"):
+            y = _norm(h, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm, eps=cfg.norm_eps)
+        with trace.scope("attn_qkv"):
+            qkv = None if lora is not None else \
+                self._maybe_fused_qkv(lw, y, cos, sin, positions)
+            if qkv is None:
+                q = y @ lw["wq"]
+                k = y @ lw["wk"]
+                v = y @ lw["wv"]
+                if lora is not None:
+                    q = self._lora_add(q, y, lora, "wq")
+                    k = self._lora_add(k, y, lora, "wk")
+                    v = self._lora_add(v, y, lora, "wv")
+                q = q.reshape(B, T, H, Dh)
+                k = k.reshape(B, T, KV, Dh)
+                v = v.reshape(B, T, KV, Dh)
+                if cfg.attn_qkv_bias:
+                    q = q + lw["b_q"].astype(y.dtype).reshape(H, Dh)
+                    k = k + lw["b_k"].astype(y.dtype).reshape(KV, Dh)
+                    v = v + lw["b_v"].astype(y.dtype).reshape(KV, Dh)
+                if cfg.position == "rope":
+                    pc, ps = _rope_rows(cos, sin, positions)
+                    q = _apply_rope_batched(q, pc, ps, interleaved=cfg.rope_interleaved)
+                    k = _apply_rope_batched(k, pc, ps, interleaved=cfg.rope_interleaved)
+            else:
+                q, k, v = qkv
+        with trace.scope("attn_core"):      # attention and the KV-cache write
+            attn, cache_out = attn_fn(q, k, v)
         return self._block_tail(lw, h, y, attn, lora=lora), cache_out
 
     def _block_tail(self, lw, h, y, attn, lora=None):
         """Output projection + residual(s) + FFN — shared by the XLA and
         fused layer bodies (engine_v2's fused paged step re-enters here
         after its fused attention)."""
+        cfg = self._mcfg
+        B, T = h.shape[:2]
+        with trace.scope("attn_out"):
+            attn_flat = attn.reshape(B, T, cfg.n_heads * cfg.head_dim)
+            attn_out = attn_flat @ lw["wo"]
+            if lora is not None:
+                attn_out = self._lora_add(attn_out, attn_flat, lora, "wo")
+            if cfg.attn_out_bias:
+                attn_out = attn_out + lw["b_o"].astype(attn_out.dtype)
+        # the fused MLP kernel holds its norm, so the second half is one scope
+        with trace.scope("moe" if cfg.n_experts > 0 else "mlp"):
+            return self._ffn_tail(lw, h, y, attn_out)
+
+    def _ffn_tail(self, lw, h, y, attn_out):
         from ..models.transformer import _norm
 
         cfg = self._mcfg
-        B, T = h.shape[:2]
-        attn_flat = attn.reshape(B, T, cfg.n_heads * cfg.head_dim)
-        attn_out = attn_flat @ lw["wo"]
-        if lora is not None:
-            attn_out = self._lora_add(attn_out, attn_flat, lora, "wo")
-        if cfg.attn_out_bias:
-            attn_out = attn_out + lw["b_o"].astype(attn_out.dtype)
         if cfg.parallel_block:
             resid = h + attn_out
             if cfg.parallel_shared_ln:
@@ -495,14 +506,16 @@ class InferenceEngine:
             out = self._maybe_fused_ffn(lw, resid, h, apply_norm=True)
             if out is not None:
                 return out
-            y2 = _norm(h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm,
-                       eps=cfg.norm_eps)
+            with trace.scope("mlp_norm"):
+                y2 = _norm(h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm,
+                           eps=cfg.norm_eps)
             return resid + self._ffn(lw, y2)
         h = h + attn_out
         out = self._maybe_fused_ffn(lw, h, h, apply_norm=True)
         if out is not None:
             return out
-        y2 = _norm(h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm, eps=cfg.norm_eps)
+        with trace.scope("mlp_norm"):
+            y2 = _norm(h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm, eps=cfg.norm_eps)
         return h + self._ffn(lw, y2)
 
     def _fused_qkv_args(self, lw, cos, sin, positions):

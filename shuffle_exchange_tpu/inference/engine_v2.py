@@ -20,6 +20,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..profiling import trace
 from ..utils.invariants import atomic_on_reject
 from ..utils.logging import logger
 from .config import InferenceConfig
@@ -30,6 +31,12 @@ from .paged import (BlockedAllocator,
                     quantize_kv)
 
 
+
+
+def _program_name(key) -> str:
+    """A step program's cache key as the tracer's compile events name it:
+    ``mixed/8/4/2/64/4`` (kind, then the binned shapes)."""
+    return "/".join(map(str, key))
 
 
 def _donate_cache():
@@ -816,8 +823,6 @@ class InferenceEngineV2(InferenceEngine):
         ``(h_new, (ck2, cv2))`` or None to take the XLA path (QKV fusion
         not selected for this model, or quantized attention weights). Once
         selected the kernels run or raise."""
-        import jax.numpy as jnp
-
         from ..models.transformer import _norm
         from ..ops import fused_decode as fd
 
@@ -828,8 +833,24 @@ class InferenceEngineV2(InferenceEngine):
         if args is None:
             return None
         cosr, sinr, bias = args
-        y = _norm(h, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm,
-                  eps=cfg.norm_eps)
+        with trace.scope("attn_norm"):
+            y = _norm(h, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm,
+                      eps=cfg.norm_eps)
+        with trace.scope("attn_qkv"):       # with the new token's KV append
+            q, k, v, ck2, cv2 = self._fused_qkv_append(
+                lw, y, ck, cv, cosr, sinr, bias, pos, btables)
+        with trace.scope("attn_core"):
+            attn = fd.fused_paged_decode_attention(
+                q[:, None], ck2, cv2, btables, pos + 1,
+                alibi_slopes=self._alibi)
+        return self._block_tail(lw, h, y, attn), (ck2, cv2)
+
+    def _fused_qkv_append(self, lw, y, ck, cv, cosr, sinr, bias, pos, btables):
+        import jax.numpy as jnp
+
+        from ..ops import fused_decode as fd
+
+        cfg = self._mcfg
         bs = self.cache.block_size
         if isinstance(ck, tuple):
             # int8/fp8 pool: the in-kernel pool DMA would write raw
@@ -850,10 +871,7 @@ class InferenceEngineV2(InferenceEngine):
                 y[:, 0], lw["wq"], lw["wk"], lw["wv"], cos=cosr, sin=sinr,
                 n_heads=cfg.n_heads, kv_heads=cfg.kv_heads,
                 pool_k=ck, pool_v=cv, blk=blk, off=off, **bias)
-        attn = fd.fused_paged_decode_attention(
-            q[:, None], ck2, cv2, btables, pos + 1,
-            alibi_slopes=self._alibi)
-        return self._block_tail(lw, h, y, attn), (ck2, cv2)
+        return q, k, v, ck2, cv2
 
     # -- host-side scheduling ------------------------------------------
 
@@ -1765,40 +1783,53 @@ class InferenceEngineV2(InferenceEngine):
         dlogits = np.zeros((0, V), np.float32)
         plogits = np.zeros((0, V), np.float32)
         if ddescs and pdescs:
-            Bd, Wd, tok, pos, dtables = self._pack_decode(ddescs, decode_tokens)
-            chunks = [(d, c) for d, (_, c) in zip(pdescs, prefills)]
-            cmax = max(len(c) for _, c in chunks)
-            Bp, C, Wp, ids, start, nnew, ptables = self._pack_chunks(
-                chunks, pad_chunk=self.config.serving.bin_chunk(cmax))
-            fn = self._mixed_fn((Bd, Wd, Bp, C, Wp))
-            ax = ()
-            if self.adapters is not None:
-                ax = (self.adapters.device_operands(),
-                      self._aslots(ddescs, Bd), self._aslots(pdescs, Bp))
-            self.cache, dl, pl = self._pop_moe(
-                fn(self.params, self.cache, tok, pos,
-                   dtables, ids, start, nnew, ptables, *ax))
-            self._program_keys.add(("mixed", Bd, Wd, Bp, C, Wp))
-            dlogits, plogits = np.asarray(dl), np.asarray(pl)
+            with trace.span("serve/pack"):
+                Bd, Wd, tok, pos, dtables = self._pack_decode(ddescs, decode_tokens)
+                chunks = [(d, c) for d, (_, c) in zip(pdescs, prefills)]
+                cmax = max(len(c) for _, c in chunks)
+                Bp, C, Wp, ids, start, nnew, ptables = self._pack_chunks(
+                    chunks, pad_chunk=self.config.serving.bin_chunk(cmax))
+                fn = self._mixed_fn((Bd, Wd, Bp, C, Wp))
+                ax = ()
+                if self.adapters is not None:
+                    ax = (self.adapters.device_operands(),
+                          self._aslots(ddescs, Bd), self._aslots(pdescs, Bp))
+            key = ("mixed", Bd, Wd, Bp, C, Wp)
+            with trace.span("serve/launch", program=_program_name(key)):
+                self.cache, dl, pl = self._pop_moe(
+                    fn(self.params, self.cache, tok, pos,
+                       dtables, ids, start, nnew, ptables, *ax))
+            self._program_keys.add(key)
+            with trace.span("serve/readback"):
+                dlogits, plogits = np.asarray(dl), np.asarray(pl)
         elif ddescs:
-            Bd, Wd, tok, pos, dtables = self._pack_decode(ddescs, decode_tokens)
-            fn = self._paged_decode_fn(Bd)
-            self.cache, dl = self._pop_moe(
-                fn(self.params, self.cache, tok, pos, dtables,
-                   *self._aargs(ddescs, Bd)))
-            self._program_keys.add(("decode", Bd, Wd))
-            dlogits = np.asarray(dl)
+            with trace.span("serve/pack"):
+                Bd, Wd, tok, pos, dtables = self._pack_decode(ddescs, decode_tokens)
+                fn = self._paged_decode_fn(Bd)
+                ax = self._aargs(ddescs, Bd)
+            key = ("decode", Bd, Wd)
+            with trace.span("serve/launch", program=_program_name(key)):
+                self.cache, dl = self._pop_moe(
+                    fn(self.params, self.cache, tok, pos, dtables, *ax))
+            self._program_keys.add(key)
+            with trace.span("serve/readback"):
+                dlogits = np.asarray(dl)
         elif pdescs:
-            chunks = [(d, c) for d, (_, c) in zip(pdescs, prefills)]
-            cmax = max(len(c) for _, c in chunks)
-            Bp, C, Wp, ids, start, nnew, ptables = self._pack_chunks(
-                chunks, pad_chunk=self.config.serving.bin_chunk(cmax))
-            fn = self._extend_fn((Bp, C))
-            self.cache, pl = self._pop_moe(
-                fn(self.params, self.cache, ids, start, nnew,
-                   ptables, *self._aargs(pdescs, Bp)))
-            self._program_keys.add(("extend", Bp, C, Wp))
-            plogits = np.asarray(pl)
+            with trace.span("serve/pack"):
+                chunks = [(d, c) for d, (_, c) in zip(pdescs, prefills)]
+                cmax = max(len(c) for _, c in chunks)
+                Bp, C, Wp, ids, start, nnew, ptables = self._pack_chunks(
+                    chunks, pad_chunk=self.config.serving.bin_chunk(cmax))
+                fn = self._extend_fn((Bp, C))
+                ax = self._aargs(pdescs, Bp)
+            key = ("extend", Bp, C, Wp)
+            with trace.span("serve/launch", program=_program_name(key)):
+                self.cache, pl = self._pop_moe(
+                    fn(self.params, self.cache, ids, start, nnew,
+                       ptables, *ax))
+            self._program_keys.add(key)
+            with trace.span("serve/readback"):
+                plogits = np.asarray(pl)
         else:
             return dlogits, plogits
         self.dispatch_count += 1
@@ -1826,43 +1857,46 @@ class InferenceEngineV2(InferenceEngine):
         dops = pops = sops = ()
         Bd = Wd = Bp = C = Wp = 0
         lora = self.adapters is not None
-        if ddescs:
-            Bd, Wd, tok, pos, dtables = self._pack_decode(ddescs,
-                                                          decode_tokens)
-            dops = (tok, pos, dtables)
+        with trace.span("serve/pack"):
+            if ddescs:
+                Bd, Wd, tok, pos, dtables = self._pack_decode(ddescs,
+                                                              decode_tokens)
+                dops = (tok, pos, dtables)
+                if lora:
+                    dops += (self._aslots(ddescs, Bd),)
+            if pdescs:
+                chunks = [(d, c) for d, (_, c) in zip(pdescs, prefills)]
+                cmax = max(len(c) for _, c in chunks)
+                Bp, C, Wp, ids, start, nnew, ptables = self._pack_chunks(
+                    chunks, pad_chunk=sv.bin_chunk(cmax))
+                pops = (ids, start, nnew, ptables)
+                if lora:
+                    pops += (self._aslots(pdescs, Bp),)
+            schunks = [(d, c) for d, (_, c) in zip(sdescs, speculative)]
+            # verify width off the k ladder: a row carrying j drafts is j+1
+            # tokens; pad to bin_k(max j) + 1 so the warmed server's verify
+            # programs stay bounded exactly like chunk lengths do
+            kmax = max(len(c) for _, c in schunks) - 1
+            Bs, Cs, Ws, sids, sstart, snnew, stables = self._pack_chunks(
+                schunks, pad_chunk=sv.speculative.bin_k(kmax) + 1)
+            sops = (sids, sstart, snnew, stables)
             if lora:
-                dops += (self._aslots(ddescs, Bd),)
-        if pdescs:
-            chunks = [(d, c) for d, (_, c) in zip(pdescs, prefills)]
-            cmax = max(len(c) for _, c in chunks)
-            Bp, C, Wp, ids, start, nnew, ptables = self._pack_chunks(
-                chunks, pad_chunk=sv.bin_chunk(cmax))
-            pops = (ids, start, nnew, ptables)
-            if lora:
-                pops += (self._aslots(pdescs, Bp),)
-        schunks = [(d, c) for d, (_, c) in zip(sdescs, speculative)]
-        # verify width off the k ladder: a row carrying j drafts is j+1
-        # tokens; pad to bin_k(max j) + 1 so the warmed server's verify
-        # programs stay bounded exactly like chunk lengths do
-        kmax = max(len(c) for _, c in schunks) - 1
-        Bs, Cs, Ws, sids, sstart, snnew, stables = self._pack_chunks(
-            schunks, pad_chunk=sv.speculative.bin_k(kmax) + 1)
-        sops = (sids, sstart, snnew, stables)
-        if lora:
-            sops += (self._aslots(sdescs, Bs),)
+                sops += (self._aslots(sdescs, Bs),)
 
-        key = ("spec", Bd, Wd, Bp, C, Wp, Bs, Cs, Ws)
-        fn = self._spec_fn(key)
-        self.cache, dl, pl, sres = self._pop_moe(fn(
-            self.params, self.cache, dops, pops, sops,
-            *((self.adapters.device_operands(),) if lora else ())))
+            key = ("spec", Bd, Wd, Bp, C, Wp, Bs, Cs, Ws)
+            fn = self._spec_fn(key)
+        with trace.span("serve/launch", program=_program_name(key)):
+            self.cache, dl, pl, sres = self._pop_moe(fn(
+                self.params, self.cache, dops, pops, sops,
+                *((self.adapters.device_operands(),) if lora else ())))
         self.dispatch_count += 1
         self._program_keys.add(key)
-        dlogits = (np.asarray(dl) if dl is not None
-                   else np.zeros((0, V), np.float32))
-        plogits = (np.asarray(pl) if pl is not None
-                   else np.zeros((0, V), np.float32))
-        ver, accepted, slast = (np.asarray(x) for x in sres)
+        with trace.span("serve/readback"):
+            dlogits = (np.asarray(dl) if dl is not None
+                       else np.zeros((0, V), np.float32))
+            plogits = (np.asarray(pl) if pl is not None
+                       else np.zeros((0, V), np.float32))
+            ver, accepted, slast = (np.asarray(x) for x in sres)
 
         for i, d in enumerate(ddescs):
             d.seen_tokens += 1
@@ -2232,59 +2266,69 @@ class InferenceEngineV2(InferenceEngine):
         ptoks = np.zeros((0,), np.int32)
         pdone = np.zeros((0,), bool)
         if ddescs and pdescs:
-            Bd, Wd, tok, pos, dtables = self._pack_decode(ddescs,
-                                                          decode_tokens)
-            chunks = [(d, c) for d, (_, c) in zip(pdescs, prefills)]
-            cmax = max(len(c) for _, c in chunks)
-            Bp, C, Wp, ids, start, nnew, ptables = self._pack_chunks(
-                chunks, pad_chunk=self.config.serving.bin_chunk(cmax))
-            dsp = self._sampling_operands(ddescs, Bd)
-            psp = self._sampling_operands(pdescs, Bp)
-            dmask = self._lane_masks(ddescs, [[t] for t in decode_tokens], Bd)
-            pmask = self._lane_masks(pdescs, [c for _, c in prefills], Bp)
-            masked = dmask is not None or pmask is not None
-            key = (("mixed_m" if masked else "mixed"), Bd, Wd, Bp, C, Wp)
-            fn = self._sampled_fn(("s",) + key, self._mixed_sampled_impl)
-            ax = ()
-            if self.adapters is not None:
-                ax = (self.adapters.device_operands(),
-                      self._aslots(ddescs, Bd), self._aslots(pdescs, Bp))
-            self.cache, dt, dd, pt, pd = self._pop_moe(fn(
-                self.params, self.cache, tok, pos, dtables, dsp, dmask,
-                ids, start, nnew, ptables, psp, pmask, *ax))
+            with trace.span("serve/pack"):
+                Bd, Wd, tok, pos, dtables = self._pack_decode(ddescs,
+                                                              decode_tokens)
+                chunks = [(d, c) for d, (_, c) in zip(pdescs, prefills)]
+                cmax = max(len(c) for _, c in chunks)
+                Bp, C, Wp, ids, start, nnew, ptables = self._pack_chunks(
+                    chunks, pad_chunk=self.config.serving.bin_chunk(cmax))
+                dsp = self._sampling_operands(ddescs, Bd)
+                psp = self._sampling_operands(pdescs, Bp)
+                dmask = self._lane_masks(ddescs, [[t] for t in decode_tokens], Bd)
+                pmask = self._lane_masks(pdescs, [c for _, c in prefills], Bp)
+                masked = dmask is not None or pmask is not None
+                key = (("mixed_m" if masked else "mixed"), Bd, Wd, Bp, C, Wp)
+                fn = self._sampled_fn(("s",) + key, self._mixed_sampled_impl)
+                ax = ()
+                if self.adapters is not None:
+                    ax = (self.adapters.device_operands(),
+                          self._aslots(ddescs, Bd), self._aslots(pdescs, Bp))
+            with trace.span("serve/launch", program=_program_name(("s",) + key)):
+                self.cache, dt, dd, pt, pd = self._pop_moe(fn(
+                    self.params, self.cache, tok, pos, dtables, dsp, dmask,
+                    ids, start, nnew, ptables, psp, pmask, *ax))
             self._assert_on_device_sampling(key, (dt, dd, pt, pd))
             self._program_keys.add(key)
-            dtoks, ddone = np.asarray(dt), np.asarray(dd)
-            ptoks, pdone = np.asarray(pt), np.asarray(pd)
+            with trace.span("serve/readback"):
+                dtoks, ddone = np.asarray(dt), np.asarray(dd)
+                ptoks, pdone = np.asarray(pt), np.asarray(pd)
         elif ddescs:
-            Bd, Wd, tok, pos, dtables = self._pack_decode(ddescs,
-                                                          decode_tokens)
-            dsp = self._sampling_operands(ddescs, Bd)
-            dmask = self._lane_masks(ddescs, [[t] for t in decode_tokens], Bd)
-            key = (("decode_m" if dmask is not None else "decode"), Bd, Wd)
-            fn = self._sampled_fn(("s",) + key, self._decode_sampled_impl)
-            self.cache, dt, dd = self._pop_moe(
-                fn(self.params, self.cache, tok, pos, dtables, dsp, dmask,
-                   *self._aargs(ddescs, Bd)))
+            with trace.span("serve/pack"):
+                Bd, Wd, tok, pos, dtables = self._pack_decode(ddescs,
+                                                              decode_tokens)
+                dsp = self._sampling_operands(ddescs, Bd)
+                dmask = self._lane_masks(ddescs, [[t] for t in decode_tokens], Bd)
+                key = (("decode_m" if dmask is not None else "decode"), Bd, Wd)
+                fn = self._sampled_fn(("s",) + key, self._decode_sampled_impl)
+                ax = self._aargs(ddescs, Bd)
+            with trace.span("serve/launch", program=_program_name(("s",) + key)):
+                self.cache, dt, dd = self._pop_moe(
+                    fn(self.params, self.cache, tok, pos, dtables, dsp, dmask,
+                       *ax))
             self._assert_on_device_sampling(key, (dt, dd))
             self._program_keys.add(key)
-            dtoks, ddone = np.asarray(dt), np.asarray(dd)
+            with trace.span("serve/readback"):
+                dtoks, ddone = np.asarray(dt), np.asarray(dd)
         elif pdescs:
-            chunks = [(d, c) for d, (_, c) in zip(pdescs, prefills)]
-            cmax = max(len(c) for _, c in chunks)
-            Bp, C, Wp, ids, start, nnew, ptables = self._pack_chunks(
-                chunks, pad_chunk=self.config.serving.bin_chunk(cmax))
-            psp = self._sampling_operands(pdescs, Bp)
-            pmask = self._lane_masks(pdescs, [c for _, c in prefills], Bp)
-            key = (("extend_m" if pmask is not None else "extend"), Bp, C, Wp)
-            fn = self._sampled_fn(("s",) + key, self._extend_sampled_impl)
-            self.cache, pt, pd = self._pop_moe(
-                fn(self.params, self.cache, ids, start,
-                   nnew, ptables, psp, pmask,
-                   *self._aargs(pdescs, Bp)))
+            with trace.span("serve/pack"):
+                chunks = [(d, c) for d, (_, c) in zip(pdescs, prefills)]
+                cmax = max(len(c) for _, c in chunks)
+                Bp, C, Wp, ids, start, nnew, ptables = self._pack_chunks(
+                    chunks, pad_chunk=self.config.serving.bin_chunk(cmax))
+                psp = self._sampling_operands(pdescs, Bp)
+                pmask = self._lane_masks(pdescs, [c for _, c in prefills], Bp)
+                key = (("extend_m" if pmask is not None else "extend"), Bp, C, Wp)
+                fn = self._sampled_fn(("s",) + key, self._extend_sampled_impl)
+                ax = self._aargs(pdescs, Bp)
+            with trace.span("serve/launch", program=_program_name(("s",) + key)):
+                self.cache, pt, pd = self._pop_moe(
+                    fn(self.params, self.cache, ids, start,
+                       nnew, ptables, psp, pmask, *ax))
             self._assert_on_device_sampling(key, (pt, pd))
             self._program_keys.add(key)
-            ptoks, pdone = np.asarray(pt), np.asarray(pd)
+            with trace.span("serve/readback"):
+                ptoks, pdone = np.asarray(pt), np.asarray(pd)
         else:
             return dtoks, ddone, ptoks, pdone
         self.dispatch_count += 1
@@ -2313,53 +2357,56 @@ class InferenceEngineV2(InferenceEngine):
         dsp = psp = ()
         dmask = pmask = None
         Bd = Wd = Bp = C = Wp = 0
-        if ddescs:
-            Bd, Wd, tok, pos, dtables = self._pack_decode(ddescs,
-                                                          decode_tokens)
-            dops = (tok, pos, dtables)
+        with trace.span("serve/pack"):
+            if ddescs:
+                Bd, Wd, tok, pos, dtables = self._pack_decode(ddescs,
+                                                              decode_tokens)
+                dops = (tok, pos, dtables)
+                if lora:
+                    dops += (self._aslots(ddescs, Bd),)
+                dsp = self._sampling_operands(ddescs, Bd)
+                dmask = self._lane_masks(ddescs, [[t] for t in decode_tokens], Bd)
+            if pdescs:
+                chunks = [(d, c) for d, (_, c) in zip(pdescs, prefills)]
+                cmax = max(len(c) for _, c in chunks)
+                Bp, C, Wp, ids, start, nnew, ptables = self._pack_chunks(
+                    chunks, pad_chunk=sv.bin_chunk(cmax))
+                pops = (ids, start, nnew, ptables)
+                if lora:
+                    pops += (self._aslots(pdescs, Bp),)
+                psp = self._sampling_operands(pdescs, Bp)
+                pmask = self._lane_masks(pdescs, [c for _, c in prefills], Bp)
+            schunks = [(d, c) for d, (_, c) in zip(sdescs, speculative)]
+            kmax = max(len(c) for _, c in schunks) - 1
+            Bs, Cs, Ws, sids, sstart, snnew, stables = self._pack_chunks(
+                schunks, pad_chunk=sv.speculative.bin_k(kmax) + 1)
+            sops = (sids, sstart, snnew, stables)
             if lora:
-                dops += (self._aslots(ddescs, Bd),)
-            dsp = self._sampling_operands(ddescs, Bd)
-            dmask = self._lane_masks(ddescs, [[t] for t in decode_tokens], Bd)
-        if pdescs:
-            chunks = [(d, c) for d, (_, c) in zip(pdescs, prefills)]
-            cmax = max(len(c) for _, c in chunks)
-            Bp, C, Wp, ids, start, nnew, ptables = self._pack_chunks(
-                chunks, pad_chunk=sv.bin_chunk(cmax))
-            pops = (ids, start, nnew, ptables)
-            if lora:
-                pops += (self._aslots(pdescs, Bp),)
-            psp = self._sampling_operands(pdescs, Bp)
-            pmask = self._lane_masks(pdescs, [c for _, c in prefills], Bp)
-        schunks = [(d, c) for d, (_, c) in zip(sdescs, speculative)]
-        kmax = max(len(c) for _, c in schunks) - 1
-        Bs, Cs, Ws, sids, sstart, snnew, stables = self._pack_chunks(
-            schunks, pad_chunk=sv.speculative.bin_k(kmax) + 1)
-        sops = (sids, sstart, snnew, stables)
-        if lora:
-            sops += (self._aslots(sdescs, Bs),)
-        ssp = self._sampling_operands(sdescs, Bs)
+                sops += (self._aslots(sdescs, Bs),)
+            ssp = self._sampling_operands(sdescs, Bs)
 
-        masked = dmask is not None or pmask is not None
-        key = (("spec_m" if masked else "spec"),
-               Bd, Wd, Bp, C, Wp, Bs, Cs, Ws)
-        fn = self._sampled_fn(("s",) + key, self._spec_sampled_impl)
-        self.cache, dres, pres, sres = self._pop_moe(fn(
-            self.params, self.cache, dops, pops, sops, dsp, psp, ssp,
-            dmask, pmask,
-            *((self.adapters.device_operands(),) if lora else ())))
+            masked = dmask is not None or pmask is not None
+            key = (("spec_m" if masked else "spec"),
+                   Bd, Wd, Bp, C, Wp, Bs, Cs, Ws)
+            fn = self._sampled_fn(("s",) + key, self._spec_sampled_impl)
+        with trace.span("serve/launch", program=_program_name(("s",) + key)):
+            self.cache, dres, pres, sres = self._pop_moe(fn(
+                self.params, self.cache, dops, pops, sops, dsp, psp, ssp,
+                dmask, pmask,
+                *((self.adapters.device_operands(),) if lora else ())))
         self.dispatch_count += 1
         self._assert_on_device_sampling(key, (dres, pres, sres))
         self._program_keys.add(key)
-        if dres is not None:
-            dtoks, ddone = np.asarray(dres[0]), np.asarray(dres[1])
-        else:
-            dtoks, ddone = np.zeros((0,), np.int32), np.zeros((0,), bool)
-        if pres is not None:
-            ptoks, pdone = np.asarray(pres[0]), np.asarray(pres[1])
-        else:
-            ptoks, pdone = np.zeros((0,), np.int32), np.zeros((0,), bool)
-        chain, accepted = (np.asarray(x) for x in sres)
+        with trace.span("serve/readback"):
+            if dres is not None:
+                dtoks, ddone = np.asarray(dres[0]), np.asarray(dres[1])
+            else:
+                dtoks, ddone = np.zeros((0,), np.int32), np.zeros((0,), bool)
+            if pres is not None:
+                ptoks, pdone = np.asarray(pres[0]), np.asarray(pres[1])
+            else:
+                ptoks, pdone = np.zeros((0,), np.int32), np.zeros((0,), bool)
+            chain, accepted = (np.asarray(x) for x in sres)
 
         for i, d in enumerate(ddescs):
             d.seen_tokens += 1

@@ -57,6 +57,7 @@ from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from ..monitor import InMemoryMonitor, Monitor
+from ..profiling import trace
 from ..testing import faults, sanitizer
 from ..utils.invariants import atomic_on_reject
 from ..utils.logging import logger
@@ -101,6 +102,12 @@ class ServingRequest:
     prefill_done: int = 0
     generated: List[int] = dataclasses.field(default_factory=list)
     submitted_at: float = 0.0
+    # when the request was DUE on the scheduler's clock: an open-loop client
+    # passes ``t0 + arrival`` (``submit(due_at=)``), so the wait a stalled
+    # submitter imposed counts; defaults to ``submitted_at``.
+    # ``first_scheduled_at``: the first tick that packed any of its tokens
+    due_at: Optional[float] = None
+    first_scheduled_at: Optional[float] = None
     first_token_at: Optional[float] = None
     last_token_at: Optional[float] = None
     finished_at: Optional[float] = None
@@ -169,6 +176,11 @@ class ServingRequest:
     @property
     def done(self) -> bool:
         return self.stopped or len(self.generated) >= self.max_new_tokens
+
+    @property
+    def due(self) -> float:
+        """When the request was due: ``due_at``, else its submission."""
+        return self.submitted_at if self.due_at is None else self.due_at
 
 
 class ContinuousBatchingScheduler:
@@ -279,7 +291,8 @@ class ContinuousBatchingScheduler:
                uid: Optional[int] = None,
                deadline_s: Optional[float] = None,
                sampling: Optional[SamplingParams] = None,
-               adapter_id: Optional[str] = None) -> int:
+               adapter_id: Optional[str] = None,
+               due_at: Optional[float] = None) -> int:
         """Queue one request; returns its uid. Validates against the
         engine's hard caps up front so impossible requests fail at submit
         time with named numbers, not mid-serve. ``deadline_s`` caps the
@@ -290,7 +303,10 @@ class ContinuousBatchingScheduler:
         temperature/top-k/top-p + seed sample in-dispatch off the seeded
         Gumbel chain, EOS/stop sequences end the request at the tick the
         stop hits. None inherits the engine config's ``sampling`` section
-        (whose own default is exactly the historical greedy contract)."""
+        (whose own default is exactly the historical greedy contract).
+        ``due_at``: when the request was due on the scheduler's clock (an
+        open-loop client's ``t0 + arrival``); ``stats()`` measures time to
+        first token and queue wait from it. Default: now."""
         if deadline_s is not None and deadline_s <= 0:
             raise ValueError(f"deadline_s must be > 0, got {deadline_s}")
         if sampling is not None and not isinstance(sampling, SamplingParams):
@@ -350,9 +366,11 @@ class ContinuousBatchingScheduler:
             self._next_uid += 1
         elif uid in self.requests or uid in eng._seqs:
             raise ValueError(f"uid {uid} is already live")
+        now = self.clock()
         r = ServingRequest(uid=uid, prompt=prompt,
                            max_new_tokens=int(max_new_tokens),
-                           submitted_at=self.clock(),
+                           submitted_at=now,
+                           due_at=now if due_at is None else float(due_at),
                            deadline_s=deadline_s,
                            sampling=sampling,
                            adapter_id=adapter_id)
@@ -532,7 +550,27 @@ class ContinuousBatchingScheduler:
 
     def tick(self) -> bool:
         """Pack one token-budget step and execute it as ONE dispatch.
-        Returns True while admitted or queued work remains."""
+        Returns True while admitted or queued work remains.
+
+        Traced as one ``step("serve", n)`` that three spans cover end to
+        end: ``serve/admit`` (everything before the dispatch),
+        ``serve/dispatch`` (the engine call) and ``serve/emit`` (results to
+        monitor events)."""
+        with trace.step("serve", self.ticks):
+            self._phase_span = trace.span("serve/admit")
+            self._phase_span.__enter__()
+            try:
+                return self._tick()
+            finally:
+                self._phase_span.__exit__(None, None, None)
+
+    def _next_phase(self, name: str) -> None:
+        """Close the tick's open span and open the next: the phases abut."""
+        self._phase_span.__exit__(None, None, None)
+        self._phase_span = trace.span(name)
+        self._phase_span.__enter__()
+
+    def _tick(self) -> bool:
         eng, cfg = self.engine, self.cfg
         bs = eng.cache.block_size
 
@@ -879,6 +917,14 @@ class ContinuousBatchingScheduler:
                       for r in decodes) or any(r.sampling is not None
                                                for r, _ in prefills)
         t0 = self.clock()
+        for r, _ in prefills:
+            if r.first_scheduled_at is None:
+                r.first_scheduled_at = t0
+                # a mark in the trace: how long this request queued
+                with trace.span("serve/first_schedule",
+                                wait_ms=1e3 * (t0 - r.due)):
+                    pass
+        self._next_phase("serve/dispatch")
         dtoks = ddone = ptoks = pdone = None
         if sampled:
             out = eng.step_sampled(
@@ -898,6 +944,7 @@ class ContinuousBatchingScheduler:
                 [(r.uid, c) for r, c in prefills])
             sres = []
         tick_s = self.clock() - t0
+        self._next_phase("serve/emit")
         if self.fenced:
             # the health layer declared this replica dead while the
             # dispatch was in flight: its requests were snapshotted and
@@ -1356,10 +1403,10 @@ class ContinuousBatchingScheduler:
             while pending and (arrivals is None
                                or self.clock() - t0 >= arrivals[pending[0][0]]):
                 i, (prompt, mn) = pending.popleft()
-                uids.append(self.submit(prompt, max_new_tokens=mn,
-                                        deadline_s=deadline_s,
-                                        sampling=samplings[i],
-                                        adapter_id=aids[i]))
+                uids.append(self.submit(
+                    prompt, max_new_tokens=mn, deadline_s=deadline_s,
+                    sampling=samplings[i], adapter_id=aids[i],
+                    due_at=None if arrivals is None else t0 + arrivals[i]))
             if not self.tick() and pending and arrivals is not None:
                 # idle: sleep until the next arrival is due (clock() may be
                 # a test fake, so never pass a negative to sleep)
@@ -1381,8 +1428,11 @@ class ContinuousBatchingScheduler:
             return float(np.percentile(xs, q)) if len(xs) else None
 
         done = [r for r in self.requests.values() if r.state == FINISHED]
-        ttft = [r.first_token_at - r.submitted_at for r in done
+        # from the time the request was due: what an open-loop client waits
+        ttft = [r.first_token_at - r.due for r in done
                 if r.first_token_at is not None]
+        wait = [r.first_scheduled_at - r.due for r in done
+                if r.first_scheduled_at is not None]
         tpot = [t for r in done for t in r.tpot_s]
         total = sum(len(r.generated) for r in done)
         span = (max(r.finished_at for r in done)
@@ -1400,6 +1450,8 @@ class ContinuousBatchingScheduler:
             "ttft_p50_s": pct(ttft, 50),
             "ttft_p95_s": pct(ttft, 95),
             "ttft_p99_s": pct(ttft, 99),
+            "queue_wait_p50_s": pct(wait, 50),
+            "queue_wait_p95_s": pct(wait, 95),
             "tpot_p50_s": pct(tpot, 50),
             "tpot_p95_s": pct(tpot, 95),
             "tpot_p99_s": pct(tpot, 99),

@@ -27,6 +27,8 @@ import dataclasses
 import math
 from typing import Any, Dict, Optional, Tuple
 
+from ..profiling import trace
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -530,6 +532,10 @@ class Transformer:
 
     def embed(self, params, input_ids):
         """ids [.., T] -> (x [.., T, D], rope (cos, sin) or (None, None))."""
+        with trace.scope("embed"):
+            return self._embed(params, input_ids)
+
+    def _embed(self, params, input_ids):
         import jax.numpy as jnp
 
         cfg = self.config
@@ -570,17 +576,19 @@ class Transformer:
         if cfg.post_ln:
             y = h   # BERT: sublayer input is unnormalized; LN follows the add
         else:
-            y = _norm(h, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm, eps=cfg.norm_eps)
-        q = (y @ lw["wq"]).reshape(B, T, H, Dh)
-        k = (y @ lw["wk"]).reshape(B, T, KV, Dh)
-        v = (y @ lw["wv"]).reshape(B, T, KV, Dh)
-        if cfg.attn_qkv_bias:
-            q = q + lw["b_q"].astype(dtype).reshape(H, Dh)
-            k = k + lw["b_k"].astype(dtype).reshape(KV, Dh)
-            v = v + lw["b_v"].astype(dtype).reshape(KV, Dh)
-        if cfg.position == "rope":
-            q = apply_rope(q, cos, sin, interleaved=cfg.rope_interleaved)
-            k = apply_rope(k, cos, sin, interleaved=cfg.rope_interleaved)
+            with trace.scope("attn_norm"):
+                y = _norm(h, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm, eps=cfg.norm_eps)
+        with trace.scope("attn_qkv"):
+            q = (y @ lw["wq"]).reshape(B, T, H, Dh)
+            k = (y @ lw["wk"]).reshape(B, T, KV, Dh)
+            v = (y @ lw["wv"]).reshape(B, T, KV, Dh)
+            if cfg.attn_qkv_bias:
+                q = q + lw["b_q"].astype(dtype).reshape(H, Dh)
+                k = k + lw["b_k"].astype(dtype).reshape(KV, Dh)
+                v = v + lw["b_v"].astype(dtype).reshape(KV, Dh)
+            if cfg.position == "rope":
+                q = apply_rope(q, cos, sin, interleaved=cfg.rope_interleaved)
+                k = apply_rope(k, cos, sin, interleaved=cfg.rope_interleaved)
         # Name the KV residuals so remat_policy="offload_kv_host" can park
         # them in host RAM between fwd and bwd (FPDT SequenceChunk offload,
         # reference sequence/fpdt_layer.py:462; XLA schedules the transfers
@@ -597,26 +605,42 @@ class Transformer:
             # GPT-Neo omits the 1/sqrt(Dh) score scaling; the attention
             # internals always divide, so pre-multiply q to net attn_scale
             q = q * jnp.asarray(cfg.attn_scale * math.sqrt(Dh), q.dtype)
-        if cfg.local_attention_window and local is not None:
-            attn = _windowed_attention(q, k, v, cfg.local_attention_window,
-                                       local).reshape(B, T, H * Dh)
-        else:
-            attn = self._attention(q, k, v, alibi).reshape(B, T, H * Dh)
+        with trace.scope("attn_core"):
+            if cfg.local_attention_window and local is not None:
+                attn = _windowed_attention(q, k, v, cfg.local_attention_window,
+                                           local).reshape(B, T, H * Dh)
+            else:
+                attn = self._attention(q, k, v, alibi).reshape(B, T, H * Dh)
         attn = checkpoint_name(attn, "attn")
-        attn_out = attn @ lw["wo"]
-        if cfg.attn_out_bias:
-            attn_out = attn_out + lw["b_o"].astype(dtype)
-        if cfg.post_ln:
-            h = _norm(h + attn_out, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm,
-                      eps=cfg.norm_eps)
-            y2 = h
-        elif cfg.parallel_block:
-            # GPT-J/NeoX/Falcon: h + attn(ln1 h) + mlp(ln2 h or ln1 h)
-            y2 = y if cfg.parallel_shared_ln else _norm(
-                h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm, eps=cfg.norm_eps)
-        else:
-            h = h + attn_out
-            y2 = _norm(h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm, eps=cfg.norm_eps)
+        with trace.scope("attn_out"):
+            attn_out = attn @ lw["wo"]
+            if cfg.attn_out_bias:
+                attn_out = attn_out + lw["b_o"].astype(dtype)
+        with trace.scope("mlp_norm"):
+            if cfg.post_ln:
+                h = _norm(h + attn_out, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm,
+                          eps=cfg.norm_eps)
+                y2 = h
+            elif cfg.parallel_block:
+                # GPT-J/NeoX/Falcon: h + attn(ln1 h) + mlp(ln2 h or ln1 h)
+                y2 = y if cfg.parallel_shared_ln else _norm(
+                    h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm, eps=cfg.norm_eps)
+            else:
+                h = h + attn_out
+                y2 = _norm(h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm, eps=cfg.norm_eps)
+        with trace.scope("moe" if cfg.n_experts > 0 else "mlp"):
+            h, aux = self._ffn(lw, h, y2, attn_out, moe_on)
+        return h, aux
+
+    def _ffn(self, lw, h, y2, attn_out, moe_on):
+        """The block's second half: (MoE or dense) feed-forward on ``y2`` and
+        the residual add. Returns (h, moe_aux)."""
+        import jax
+        import jax.numpy as jnp
+        from jax.ad_checkpoint import checkpoint_name
+
+        cfg = self.config
+        dtype = h.dtype
         aux = jnp.zeros((), jnp.float32)
         if cfg.n_experts > 0:
             from ..moe.layer import moe_layer
@@ -959,7 +983,8 @@ class Transformer:
                 xs = stacked_layers
             if cfg.remat:
                 layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(cfg.remat_policy))
-            x, aux_losses = jax.lax.scan(layer_fn, x, xs)
+            with trace.scope("layers"):      # the scan's own slicing and stacking
+                x, aux_losses = jax.lax.scan(layer_fn, x, xs)
             return x, jnp.sum(aux_losses)
 
         if ltd_mask is not None:
@@ -988,9 +1013,10 @@ class Transformer:
 
         if cfg.remat:
             layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(cfg.remat_policy))
-        x, aux_losses = jax.lax.scan(
-            layer_fn, x, (stacked_layers, active, keep_layers, local_flags,
-                          moe_flags))
+        with trace.scope("layers"):
+            x, aux_losses = jax.lax.scan(
+                layer_fn, x, (stacked_layers, active, keep_layers, local_flags,
+                              moe_flags))
         return x, jnp.sum(aux_losses)
 
     def _unembed(self, params, dtype):
@@ -1018,19 +1044,21 @@ class Transformer:
 
         cfg = self.config
         if not cfg.post_ln:
-            x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm,
-                      eps=cfg.norm_eps)
-        if cfg.mlm_head:
-            # BERT cls head: dense + gelu + LN, tied decoder with own bias
-            x = activation_fn("gelu")(x @ params["mlm_dense_w"].astype(x.dtype)
-                                      + params["mlm_dense_b"].astype(x.dtype))
-            x = _norm(x, params["mlm_ln_w"], params["mlm_ln_b"], cfg.norm,
-                      eps=cfg.norm_eps)
-        w, bias = self._unembed(params, x.dtype)
-        logits = jnp.matmul(x, w, preferred_element_type=jnp.float32)
-        if cfg.mlm_head:
-            logits = logits + params["mlm_bias"].astype(jnp.float32)
-        return logits if bias is None else logits + bias
+            with trace.scope("final_norm"):
+                x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm,
+                          eps=cfg.norm_eps)
+        with trace.scope("loss"):
+            if cfg.mlm_head:
+                # BERT cls head: dense + gelu + LN, tied decoder with own bias
+                x = activation_fn("gelu")(x @ params["mlm_dense_w"].astype(x.dtype)
+                                          + params["mlm_dense_b"].astype(x.dtype))
+                x = _norm(x, params["mlm_ln_w"], params["mlm_ln_b"], cfg.norm,
+                          eps=cfg.norm_eps)
+            w, bias = self._unembed(params, x.dtype)
+            logits = jnp.matmul(x, w, preferred_element_type=jnp.float32)
+            if cfg.mlm_head:
+                logits = logits + params["mlm_bias"].astype(jnp.float32)
+            return logits if bias is None else logits + bias
 
     @staticmethod
     def token_loss(logits, labels):
@@ -1100,8 +1128,9 @@ class Transformer:
         @jax.checkpoint
         def body(carry, xl):
             xch, lch = xl
-            xn = _norm(xch, params["ln_f_w"], params["ln_f_b"], cfg.norm,
-                       eps=cfg.norm_eps)
+            with trace.scope("final_norm"):
+                xn = _norm(xch, params["ln_f_w"], params["ln_f_b"], cfg.norm,
+                           eps=cfg.norm_eps)
             logits = jnp.matmul(xn, w, preferred_element_type=jnp.float32)
             if extra is not None:
                 logits = logits + extra
@@ -1156,8 +1185,9 @@ class Transformer:
             labels = batch["labels"]
             model_ids = ids
         else:
-            labels = ids[:, 1:]
-            model_ids = ids[:, :-1]
+            with trace.scope("embed"):
+                labels = ids[:, 1:]
+                model_ids = ids[:, :-1]
         ltd_mask = None
         if self.config.random_ltd and "ltd_keep_prob" in batch and rng is not None:
             import jax
@@ -1183,13 +1213,16 @@ class Transformer:
             x, rope = self.embed(params, model_ids)
             x, aux = self.stack_apply(params["layers"], x, rope,
                                       ltd_mask=ltd_mask, layer_keep=layer_keep)
-            nll_sum, count = self.chunked_loss(params, x, labels, chunk)
+            with trace.scope("loss"):
+                nll_sum, count = self.chunked_loss(params, x, labels, chunk)
         else:
             logits, aux = self.apply_with_aux(params, model_ids, ltd_mask=ltd_mask,
                                               layer_keep=layer_keep)
-            nll_sum, count = self.token_loss(logits, labels)
-        ce = nll_sum / jnp.maximum(count, 1)
-        return ce + self.config.aux_loss_coef * aux
+            with trace.scope("loss"):
+                nll_sum, count = self.token_loss(logits, labels)
+        with trace.scope("loss"):
+            ce = nll_sum / jnp.maximum(count, 1)
+            return ce + self.config.aux_loss_coef * aux
 
 
 def _remat_policy(name: str):

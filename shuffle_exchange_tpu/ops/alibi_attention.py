@@ -276,6 +276,7 @@ def _alibi_flash_fwd_impl(q, k, v, slopes, causal: bool, interpret: bool):
                                scale=D ** -0.5, causal=causal)
     out, lse = pl.pallas_call(
         kernel,
+        name="sxt_alibi_flash_fwd",
         grid=(B, H, T // bq, S // bkv),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -374,6 +375,7 @@ def _flash_bwd_impl(q, k, v, slopes, out, lse, g, g_lse, causal, interpret,
     dq_t = pl.pallas_call(
         functools.partial(_alibi_dq_kernel, bq=bq, bkv=bkv, off=off,
                           scale=scale, causal=causal),
+        name="sxt_alibi_flash_dq",
         grid=(B, H, T // bq, S // bkv),
         in_specs=common_in + [
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
@@ -415,6 +417,7 @@ def _flash_bwd_impl(q, k, v, slopes, out, lse, g, g_lse, causal, interpret,
         functools.partial(_alibi_dkv_kernel, bq=bq, bkv=bkv, off=off,
                           scale=scale, causal=causal,
                           need_dslope=need_dslope),
+        name="sxt_alibi_flash_dkv",
         grid=(B, H, S // bkv, T // bq),
         in_specs=common_in + [
             pl.BlockSpec((1, 1, bq, D), lambda b, h, j, i: (b, h, i, 0)),

@@ -83,6 +83,7 @@ def fused_adamw_update(p, g, m, v, *, lr, b1=0.9, b2=0.999, eps=1e-8, weight_dec
     bspec = pl.BlockSpec((block, LANES), lambda i: (i, 0))
     new_p, new_m, new_v = pl.pallas_call(
         kernel,
+        name="sxt_fused_adamw",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

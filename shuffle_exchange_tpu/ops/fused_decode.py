@@ -375,6 +375,7 @@ def fused_qkv_rope_pallas(y, wq, wk, wv, bq=None, bk=None, bv=None,
     )
     outs = pl.pallas_call(
         kernel,
+        name="sxt_fused_qkv_rope_append",
         grid_spec=grid_spec,
         out_shape=out_shapes,
         input_output_aliases=aliases,
@@ -570,6 +571,7 @@ def fused_paged_decode_attention_pallas(q, ck, cv, block_table, kv_len, *,
     )
     o_part, m_part, l_part = pl.pallas_call(
         kernel,
+        name="sxt_fused_paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, nsplit, H, Dh), jnp.float32),
                    jax.ShapeDtypeStruct((B, nsplit, H, 1), jnp.float32),
@@ -714,6 +716,7 @@ def fused_mlp_pallas(resid, y_src, ln_w, ln_b, w_up, w_down, w_gate=None,
     weights = ((w_gate, w_up, w_down) if gated else (w_up, w_down))
     out = pl.pallas_call(
         kernel,
+        name="sxt_fused_mlp",
         grid=(nf,),
         in_specs=in_specs,
         out_specs=full((Bp, D)),
@@ -862,6 +865,7 @@ def fused_mlp_quant_pallas(resid, y_src, ln_w, ln_b, w_up, w_down,
             + 2 * _nbytes((bf, D), jnp.float32))            # its dequant
     out = pl.pallas_call(
         kernel,
+        name="sxt_fused_mlp_quant",
         grid=(nf, nk),
         in_specs=in_specs,
         out_specs=full((Bp, D)),

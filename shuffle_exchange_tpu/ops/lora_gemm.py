@@ -90,6 +90,7 @@ def lora_delta_pallas(x, a_stack, b_stack, slots, *, interpret: bool = False):
     )
     return pl.pallas_call(
         kernel,
+        name="sxt_lora_delta",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, N), x.dtype),
         compiler_params=pltpu.CompilerParams(

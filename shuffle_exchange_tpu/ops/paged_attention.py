@@ -206,6 +206,7 @@ def paged_decode_attention_pallas(q, ck, cv, block_table, kv_len, *,
     )
     out = pl.pallas_call(
         kernel,
+        name="sxt_paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, Dh), q.dtype),
         interpret=interpret,
@@ -336,6 +337,7 @@ def paged_extend_attention_pallas(q, ck, cv, block_table, start, nnew, *,
     )
     out = pl.pallas_call(
         kernel,
+        name="sxt_paged_extend_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, GC, Dh), q.dtype),
         interpret=interpret,

@@ -266,6 +266,7 @@ def _quant_matmul_pallas(x, qm: QuantizedMatrix, block_m: int = 256,
     # raw [nk, N] scales fails to lower when nk % 8 != 0
     out = pl.pallas_call(
         kernel,
+        name="sxt_quant_matmul",
         grid=(Mp // bm, N // bn, nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

@@ -111,6 +111,7 @@ def _rmsnorm_pallas(x, weight, eps):
     grid = (pl.cdiv(rows, block_rows),)
     out = pl.pallas_call(
         kernel,
+        name="sxt_rmsnorm",
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         grid=grid,
         in_specs=[

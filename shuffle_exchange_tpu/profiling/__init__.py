@@ -1,11 +1,13 @@
-"""Profiling: flops profiler (reference ``profiling/flops_profiler/``) +
-XLA trace capture (the external-profiler/NVTX analog, SURVEY §5.1)."""
+"""Profiling: the flops profiler (reference ``profiling/flops_profiler/``) and
+the program's tracer (``trace``: host spans, named scopes, step annotations,
+compile events; the external-profiler/NVTX analog, SURVEY §5.1)."""
 
-from .trace import annotate, trace_annotation, xla_trace  # noqa: F401
+from . import trace  # noqa: F401
+from .trace import xla_trace  # noqa: F401
 from .flops_profiler import (FlopsProfiler, compiled_flops, count_params,
                              flops_to_string, get_model_profile, number_to_string,
                              params_breakdown, params_to_string)
 
 __all__ = ["FlopsProfiler", "compiled_flops", "count_params", "flops_to_string",
            "get_model_profile", "number_to_string", "params_breakdown",
-           "params_to_string", "xla_trace", "trace_annotation", "annotate"]
+           "params_to_string", "trace", "xla_trace"]

@@ -1,31 +1,140 @@
-"""XLA/TPU program traces (SURVEY §5.1: the reference leans on external
-profilers — nsys/torch profiler + NVTX ranges, ``utils/nvtx.py``; the
-TPU-native equivalent is the XLA profiler's TensorBoard trace, which
-captures device timelines, HLO op breakdowns, and host activity).
+"""The program's one tracer: what trainer, model, mesh and scheduler report
+about themselves. Four primitives, no config field, no environment variable.
 
-Usage::
+``span(name)``
+    A host span: ``jax.profiler.TraceAnnotation("sxt:" + name)``. Whenever
+    anyone's profiler session runs (``xla_trace`` below, a benchmark's own)
+    the span lands on the host plane of the same ``.xplane.pb`` as the device
+    ops, on the same clock, so an idle gap of the device can be laid against
+    what the host was doing. With no session it is a TraceMe that records
+    nothing. Only after ``keep_spans(True)`` (the engine's
+    ``wall_clock_breakdown``) does a span also append ``(name, t0, t1)`` on
+    ``time.perf_counter`` to a bounded in-memory list.
+``scope(name)``
+    ``jax.named_scope``, for code under ``jit``: it changes the ``op_name``
+    metadata of the ops traced inside it and nothing else, so the device
+    ops of a trace can be summed by the layer of the program they belong to.
+``step(kind, n)``
+    ``jax.profiler.StepTraceAnnotation``: one per ``train_batch`` and one per
+    scheduler tick, so the profiler groups device work by step.
+``compile_events()``
+    The program's own ``jax.monitoring`` listener: one record per backend
+    compilation (or persistent-cache read), stamped with the innermost open
+    span, the program that span was opened for, seconds, cache hit or not and
+    the ``perf_counter`` time. Bounded; always on.
 
-    from shuffle_exchange_tpu.profiling import xla_trace
+``program_ops`` reads a compiled program's HLO text into instruction name ->
+(scope path, opcode, contains a collective): the join for traces whose device
+events carry no op-name stat, and the only way to see a collective inside a
+``fusion``. ``Engine.compile()`` registers it for ``train_step``.
 
-    with xla_trace("traces/step100"):
-        engine.train_batch(batch)           # traced end to end
+    from shuffle_exchange_tpu.profiling import trace
 
-    # or around an annotated region
-    with xla_trace("traces"), trace_annotation("generate"):
-        engine.generate(prompts)
+    with trace.xla_trace("traces/step100"):
+        engine.train_batch(batch)       # sxt: spans + scoped device ops
 
-View with TensorBoard's profile plugin pointed at the log dir.
+View with TensorBoard's profile plugin pointed at the log dir, or read the
+``.xplane.pb`` with ``jax.profiler.ProfileData``.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import functools
+import re
+import threading
+import time
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
+
+PREFIX = "sxt:"
+
+# every scope the package opens under jit, by the layer a reader sums it to.
+# "plumbing" is what belongs to no layer of the model: the layer scan's own
+# slicing and stacking, the masters' cast to the compute dtype, the
+# gradients' cast back and normalization
+SCOPES = {
+    "attn": ("attn_norm", "attn_qkv", "attn_core", "attn_out"),
+    "mlp": ("mlp_norm", "mlp", "moe"),
+    "loss": ("embed", "final_norm", "loss"),
+    "optimizer": ("optimizer", "grad_clip", "weight_mix"),
+    "mesh": ("zero3_gather", "zero3_reduce_scatter"),
+    "plumbing": ("layers", "weight_cast", "grad_normalize"),
+}
+
+_KEEP_MAX = 4096
+_EVENTS_MAX = 256
+
+_kept: Optional[collections.deque] = None       # (name, t0, t1) when kept
+_events: collections.deque = collections.deque(maxlen=_EVENTS_MAX)
+_open = threading.local()                       # .stack: [(name, program)]
+_listening = False
+_cache_hit = threading.local()
+
+
+def _stack() -> list:
+    try:
+        return _open.stack
+    except AttributeError:
+        _open.stack = []
+        return _open.stack
+
+
+class span:
+    """``with span("train/place"):`` - see the module docstring. ``program``
+    names what a compilation inside the span builds (``train_step``, an
+    engine-v2 cache key); inner spans inherit it. ``numbers`` ride along as
+    the event's stats in a profiler session (``span("serve/first_schedule",
+    wait_ms=3.2)``) and nowhere else."""
+
+    __slots__ = ("name", "program", "numbers", "_note", "_t0")
+
+    def __init__(self, name: str, program: Optional[str] = None, **numbers):
+        self.name = name
+        self.program = program
+        self.numbers = numbers
+
+    def __enter__(self):
+        import jax
+
+        if not _listening:
+            _listen()
+        stack = _stack()
+        if self.program is None and stack:
+            self.program = stack[-1][1]
+        stack.append((self.name, self.program))
+        self._note = jax.profiler.TraceAnnotation(PREFIX + self.name,
+                                                  **self.numbers)
+        self._note.__enter__()
+        self._t0 = time.perf_counter() if _kept is not None else 0.0
+        return self
+
+    def __exit__(self, *exc):
+        if _kept is not None:
+            _kept.append((self.name, self._t0, time.perf_counter()))
+        self._note.__exit__(*exc)
+        _stack().pop()
+        return False
+
+
+def scope(name: str):
+    """A named scope for code traced under ``jit`` (``op_name`` metadata)."""
+    import jax
+
+    return jax.named_scope(name)
+
+
+def step(kind: str, n: int):
+    """One training step or scheduler tick, for the profiler's step view."""
+    import jax
+
+    return jax.profiler.StepTraceAnnotation(PREFIX + kind, step_num=int(n))
 
 
 @contextlib.contextmanager
 def xla_trace(logdir: str):
-    """Capture an XLA profiler trace of the enclosed region into
-    ``logdir`` (TensorBoard profile format)."""
+    """An operator's profiler session around a region: device ops with the
+    program's scopes, host plane with its ``sxt:`` spans, into ``logdir``."""
     import jax
 
     jax.profiler.start_trace(logdir)
@@ -35,24 +144,172 @@ def xla_trace(logdir: str):
         jax.profiler.stop_trace()
 
 
-def trace_annotation(name: str):
-    """Named range inside a trace (the reference's ``@instrument_w_nvtx``
-    analog, utils/nvtx.py)."""
+# ---------------------------------------------------------------------------
+# Spans kept in memory (wall_clock_breakdown)
+# ---------------------------------------------------------------------------
+
+
+def keep_spans(on: bool) -> None:
+    """Start (or stop and drop) the in-memory span list."""
+    global _kept
+    if on and _kept is None:
+        _kept = collections.deque(maxlen=_KEEP_MAX)
+    elif not on:
+        _kept = None
+
+
+def kept_spans(clear: bool = False) -> List[Tuple[str, float, float]]:
+    """The spans kept since the last clear, oldest first."""
+    if _kept is None:
+        return []
+    rows = list(_kept)
+    if clear:
+        _kept.clear()
+    return rows
+
+
+def breakdown_line(rows, batch_size: int, step_span: str) -> str:
+    """The ``wall_clock_breakdown`` log line: mean milliseconds per span
+    name, and samples/s from the ``step_span`` rows."""
+    by: Dict[str, List[float]] = {}
+    for name, t0, t1 in rows:
+        by.setdefault(name, []).append(t1 - t0)
+    parts = [f"{n}: {1e3 * sum(v) / len(v):.2f}" for n, v in sorted(by.items())]
+    msg = "time (ms) | " + " | ".join(parts)
+    steps = by.get(step_span)
+    if steps and sum(steps) > 0:
+        msg += f" | samples/s: {batch_size * len(steps) / sum(steps):.2f}"
+    return msg
+
+
+# ---------------------------------------------------------------------------
+# Compile events
+# ---------------------------------------------------------------------------
+
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+_HIT = "/jax/compilation_cache/cache_hits"
+
+
+def _on_duration(event: str, secs: float, **kw) -> None:
+    if event != _COMPILE:
+        return
+    stack = _stack()
+    name, program = stack[-1] if stack else (None, None)
+    hit = getattr(_cache_hit, "seen", False)
+    _cache_hit.seen = False
+    _events.append({"span": name, "program": program or kw.get("fun_name"),
+                    "fun_name": kw.get("fun_name"), "seconds": float(secs),
+                    "cache_hit": bool(hit), "at": time.perf_counter()})
+
+
+def _on_event(event: str, **_) -> None:
+    # the backend-compile duration wraps the persistent-cache lookup, so the
+    # hit is seen before the duration it belongs to
+    if event == _HIT:
+        _cache_hit.seen = True
+
+
+def _listen() -> None:
+    global _listening
     import jax
 
-    return jax.profiler.TraceAnnotation(name)
+    if not _listening:
+        _listening = True
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
 
 
-def annotate(name: str):
-    """Decorator form of :func:`trace_annotation`."""
-    import functools
+def compile_events(since: float = 0.0) -> List[dict]:
+    """Compilations seen so far (``at`` >= ``since`` on ``perf_counter``)."""
+    _listen()
+    return [dict(e) for e in _events if e["at"] >= since]
 
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapper(*a, **k):
-            with trace_annotation(name):
-                return fn(*a, **k)
 
-        return wrapper
+# ---------------------------------------------------------------------------
+# A compiled program's instructions, by scope
+# ---------------------------------------------------------------------------
 
-    return deco
+
+class Op(NamedTuple):
+    scope: str                  # the op_name metadata, "" where there is none
+    opcode: str
+    contains_collective: bool
+
+
+_COLLECTIVE = re.compile(r"^(all-gather|reduce-scatter|all-reduce|"
+                         r"collective-permute|all-to-all)")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+(.*)$")
+# where a new instruction, computation or block end starts (not a continuation)
+_STARTS = re.compile(r"^(\s+(?:ROOT\s+)?%?[\w.\-]+\s+=\s|(?:ENTRY\s+)?%?[\w.\-]+\s.*\{\s*$|\}\s*$|\s*$)")
+_OPCODE = re.compile(r"(?<![\w.%\-])([a-z][a-z0-9\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(r"(?:calls|to_apply|body|condition|branch_computations|"
+                     r"called_computations)=\{?([^,}\s]+(?:,\s*%[\w.\-]+)*)\}?")
+
+_programs: Dict[str, Callable[[], Dict[str, Op]]] = {}
+
+
+def program_ops(compiled) -> Dict[str, Op]:
+    """Instruction name -> ``Op`` for a compiled program (``jit(f).lower(...)
+    .compile()``) or its HLO text. ``contains_collective`` is true if the
+    instruction, or a computation it calls (a fusion's, a while's body), holds
+    an all-gather / reduce-scatter / all-reduce / collective-permute /
+    all-to-all."""
+    text = compiled if isinstance(compiled, str) else compiled.as_text()
+    ops: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {}
+    holds: Dict[str, List[str]] = {}            # computation -> instructions
+    current = None
+    lines = text.splitlines()
+    for i, line in enumerate(lines):
+        m = _INSTRUCTION.match(line)
+        if m and current is not None:
+            name, rest = m.groups()
+            head, _, meta = rest.partition(", metadata={")
+            # a kernel's frontend attributes hold JSON with line breaks: the
+            # instruction's metadata then sits on a following line
+            j = i + 1
+            while not meta and j < len(lines) and not _STARTS.match(lines[j]):
+                _, _, meta = lines[j].partition(", metadata={")
+                j += 1
+            code = _OPCODE.search(head)
+            called = tuple(c.strip().lstrip("%") for g in _CALLED.findall(head)
+                           for c in g.split(","))
+            scope = _OP_NAME.search(meta)
+            ops[name] = (scope.group(1) if scope else "",
+                         code.group(1) if code else "", called)
+            holds[current].append(name)
+            continue
+        m = _COMPUTATION.match(line)
+        if m and "=" not in line.split("(", 1)[0]:
+            current = m.group(1)
+            holds[current] = []
+
+    memo: Dict[str, bool] = {}
+
+    def computation_has(comp: str) -> bool:
+        if comp not in memo:
+            memo[comp] = False                  # a cycle cannot occur in HLO
+            memo[comp] = any(instruction_has(n) for n in holds.get(comp, ()))
+        return memo[comp]
+
+    def instruction_has(name: str) -> bool:
+        _, code, called = ops[name]
+        return bool(_COLLECTIVE.match(code)) or any(
+            computation_has(c) for c in called)
+
+    return {n: Op(s, code, instruction_has(n))
+            for n, (s, code, _) in ops.items()}
+
+
+def register_program(name: str, compiled) -> None:
+    """Keep ``program_ops`` of a compiled program under ``name``. The text is
+    taken now (the executable is not kept alive) and parsed when first read."""
+    text = compiled.as_text()
+    _programs[name] = functools.cache(lambda: program_ops(text))
+
+
+def registered_ops(name: str) -> Optional[Dict[str, Op]]:
+    """``program_ops`` of the program registered as ``name``, or None."""
+    read = _programs.get(name)
+    return read() if read is not None else None

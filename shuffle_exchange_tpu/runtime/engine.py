@@ -5,7 +5,7 @@ Capability parity with the reference's ``DeepSpeedEngine``
 ``forward`` / ``backward`` / ``step`` / ``train_batch`` / ``eval_batch``,
 builds the parallel topology, wraps the optimizer (ZeRO stages as sharding
 policies, fp16 dynamic loss scaling, bf16 fp32-master accumulation), drives
-LR schedules, throughput/wall-clock timers, and the fork's decentralized
+LR schedules, the tracer's spans and scopes, and the fork's decentralized
 weight-sync (§2.1) via ``shuffle_exchange()`` / ``synchronization()`` /
 ``reset_rings()``.
 
@@ -35,22 +35,22 @@ from ..config.config import SXConfig
 from ..config.config_utils import ConfigError
 from ..parallel.mesh import MeshTopology, kernel_mesh
 from ..parallel.mesh import shard_map as _shard_map
+from ..profiling import trace
 from ..utils.logging import log_dist, logger
-from ..utils.timer import (
-    BACKWARD_GLOBAL_TIMER,
-    FORWARD_GLOBAL_TIMER,
-    STEP_GLOBAL_TIMER,
-    TRAIN_BATCH_TIMER,
-    NoopTimer,
-    SynchronizedWallClockTimer,
-    ThroughputTimer,
-)
 from . import loss_scaler as ls
 from .dataloader import DataLoader, RepeatingLoader
 from .lr_schedules import build_schedule
 from .optimizers import build_optimizer, get_base_lr
 from .sync.decentralized import DecentralizedSync, apply_mixing
 from .zero.partitioning import ZeroShardingPolicy
+
+
+def _memory_usage() -> str:
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return (f"mem in_use={stats.get('bytes_in_use', 0) / 2**30:.2f}GB "
+            f"peak={stats.get('peak_bytes_in_use', 0) / 2**30:.2f}GB")
 
 
 class TrainState(NamedTuple):
@@ -502,10 +502,11 @@ class Engine:
         if self._host_opt_wanted:
             self._setup_host_optimizer()
 
-        # --- timers / monitors -----------------------------------------
-        self.timers = SynchronizedWallClockTimer() if config.wall_clock_breakdown else NoopTimer()
-        self.tput_timer = ThroughputTimer(batch_size=config.train_batch_size,
-                                          steps_per_output=config.steps_per_print)
+        # --- tracer / monitors -----------------------------------------
+        # wall_clock_breakdown: the tracer's spans are also kept in memory
+        # and each step span closes on a loss that is ready (profiling/trace.py)
+        if config.wall_clock_breakdown:
+            trace.keep_spans(True)
         # monitor fan-out (reference monitor/monitor.py:30 MonitorMaster;
         # engine event writes runtime/engine.py:2200-2208)
         from ..monitor import MonitorMaster
@@ -844,15 +845,21 @@ class Engine:
         # (straight-through estimation by construction).
         compression_fn = self._compression_fn
 
+        # the forward weights' cast is where ZeRO-3's weights leave their
+        # shards: on the default path XLA gathers what this produces
+        cast_scope = "zero3_gather" if self.zero_stage == 3 else "weight_cast"
+
         def fwd_weights(master, mix, step):
-            p16 = jax.tree_util.tree_map(lambda m: m.astype(dtype), master)
+            with trace.scope(cast_scope):
+                p16 = jax.tree_util.tree_map(lambda m: m.astype(dtype), master)
             # With lora, p16 is the factors-only tree — qwZ applies to the
             # frozen base instead (see fro16_of), not the rank-r factors.
             if qw and not qz3_real and self._lora is None:
                 p16 = jax.tree_util.tree_map(
                     lambda p: quantize_dequantize(p, group_size=cfg.zeropp.group_size).astype(dtype), p16)
             if ensemble:
-                p16 = apply_mixing(p16, mix)
+                with trace.scope("weight_mix"):
+                    p16 = apply_mixing(p16, mix)
             if compression_fn is not None:
                 p16 = compression_fn(p16, step)
             return p16
@@ -893,7 +900,8 @@ class Engine:
         def replica_grads(p16, fro16, micro, rng, scale):
             grad_fn = jax.grad(scaled_loss_fn, has_aux=True)
             g, loss = grad_fn(p16, fro16, micro, rng, scale)
-            g = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), g)
+            with trace.scope(reduce_scope):
+                g = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), g)
             return g, loss
 
         def batch_grads(master, frozen, p16, fro16, micro, rng, scale, step):
@@ -979,10 +987,11 @@ class Engine:
             for dim, e in enumerate(spec):
                 ze = _zentry(e)
                 if ze is not None and _zsize(ze) > 1:
-                    if qw:
-                        return quantized_all_gather(
-                            x, ze, group_size=cfg.zeropp.group_size, axis=dim)
-                    return jax.lax.all_gather(x, ze, axis=dim, tiled=True)
+                    with trace.scope("zero3_gather"):
+                        if qw:
+                            return quantized_all_gather(
+                                x, ze, group_size=cfg.zeropp.group_size, axis=dim)
+                        return jax.lax.all_gather(x, ze, axis=dim, tiled=True)
             return x
 
         def _gather_frozen_in_region(frozen):
@@ -1041,6 +1050,10 @@ class Engine:
             gather_leaf = _gather_zero_sharded
 
             def reduce_leaf(g, spec):
+                with trace.scope("zero3_reduce_scatter"):
+                    return _reduce_leaf(g, spec)
+
+            def _reduce_leaf(g, spec):
                 # flat pipe region: leaves NOT stage-sharded (embed/head/
                 # norms, replicated over "pipe") take partial grads on every
                 # stage — sum them across stages first (fp; the reference
@@ -1278,6 +1291,15 @@ class Engine:
 
             return optax.apply_updates(master, updates), new_o
 
+        def scoped(name, fn):
+            def inner(*args, **kwargs):
+                with trace.scope(name):
+                    return fn(*args, **kwargs)
+
+            return inner
+
+        apply_update = scoped("optimizer", apply_update)
+
         # Non-finite sentinel (resilience layer, beyond the fp16 overflow
         # skip): "skip" folds the guard into the jitted step — the bad
         # update is dropped in-graph at zero host cost; "rollback"/"raise"
@@ -1287,17 +1309,20 @@ class Engine:
         nonfinite_guard = nonfinite_policy != "off"
         skip_nonfinite = nonfinite_policy == "skip"
 
-        def train_step(state: TrainState, batch, mix, rng, lr_mult):
-            p16 = fwd_weights(state.master, mix, state.step)
-            fro16 = fro16_of(state.frozen)
-            scale = state.loss_scale.scale if fp16_cfg.enabled else jnp.asarray(1.0, jnp.float32)
-            grads, loss = accumulate(state.master, state.frozen, p16, fro16,
-                                     batch, rng, scale, state.step)
-            # normalize: mean over gas microbatches + undo loss scale
+        reduce_scope = ("zero3_reduce_scatter" if self.zero_stage == 3
+                        else "grad_normalize")
+
+        def update_state(state, grads, loss, scale, lr_mult):
+            """Everything after the gradients: normalize, guards, the
+            optimizer's update and the new state."""
+            # normalize: mean over gas microbatches + undo loss scale. On the
+            # default ZeRO path this is where the gradients take the masters'
+            # sharding, so XLA's reduce-scatter lands on these ops
             denom = scale * gas
             if prescale and predivide != 1.0:
                 denom = denom * predivide
-            grads = jax.tree_util.tree_map(lambda g: g / denom, grads)
+            with trace.scope(reduce_scope):
+                grads = jax.tree_util.tree_map(lambda g: g / denom, grads)
             if qg and not (qg_real or qz3_real):
                 # numerics emulation only (see qg_real above for the wire
                 # path; the stage-3 streamed wire already carried its own
@@ -1305,7 +1330,8 @@ class Engine:
                 grads = jax.tree_util.tree_map(
                     lambda g: quantize_dequantize(g, group_size=cfg.zeropp.group_size), grads)
             overflow = ls.check_overflow(grads) if fp16_cfg.enabled else jnp.asarray(False)
-            grad_norm = jnp.sqrt(sum(jnp.vdot(g, g) for g in jax.tree_util.tree_leaves(grads))).real
+            with trace.scope("grad_clip"):
+                grad_norm = jnp.sqrt(sum(jnp.vdot(g, g) for g in jax.tree_util.tree_leaves(grads))).real
             # "beyond the fp16 overflow skip": an overflow already has its
             # own handling (skip + halve the loss scale) — it must not look
             # like a non-finite step, or rollback/raise policies would
@@ -1327,6 +1353,18 @@ class Engine:
             new_state = TrainState(master=new_master, opt_state=new_opt, loss_scale=new_scale,
                                    step=state.step + jnp.where(bad, 0, 1).astype(jnp.int32),
                                    frozen=state.frozen)
+            return new_state, overflow, grad_norm, nonfinite
+
+        update_state = scoped("optimizer", update_state)
+
+        def train_step(state: TrainState, batch, mix, rng, lr_mult):
+            p16 = fwd_weights(state.master, mix, state.step)
+            fro16 = fro16_of(state.frozen)
+            scale = state.loss_scale.scale if fp16_cfg.enabled else jnp.asarray(1.0, jnp.float32)
+            grads, loss = accumulate(state.master, state.frozen, p16, fro16,
+                                     batch, rng, scale, state.step)
+            new_state, overflow, grad_norm, nonfinite = update_state(
+                state, grads, loss, scale, lr_mult)
             return new_state, loss, overflow, grad_norm, nonfinite
 
         from ..utils.placement import cache_safe_donate_argnums
@@ -1600,32 +1638,31 @@ class Engine:
         is still in flight, bit-exact with the synchronous path."""
         import jax
 
-        self.tput_timer.start()
-        self.timers(TRAIN_BATCH_TIMER).start()
-        self._join_host_update()   # step N-1's params land here
-        shaped = self._reshape_batch(batch)
-        rng = self._next_rng()
+        with trace.span("train/place"):
+            self._join_host_update()   # step N-1's params land here
+            shaped = self._reshape_batch(batch)
+            rng = self._next_rng()
         t_dispatch = time.perf_counter()
-        grads, loss = self._grads_batch(self._fwd16, shaped, rng)
-        if self._host_pipeline is not None:
-            self._host_pipeline.submit(jax.tree_util.tree_leaves(grads),
-                                       dispatched_at=t_dispatch)
-        else:
-            grad_leaves = [np.asarray(jax.device_get(g), dtype=np.float32)
-                           for g in jax.tree_util.tree_leaves(grads)]
-            self._host_opt.step(grad_leaves)
-            self._fwd16 = self._place_bf16(self._host_opt.bf16_tree())
-        self._post_step(False)
-        if self.monitor.enabled:
-            s = self.global_samples
-            self.monitor.write_events([
-                ("Train/Samples/train_loss", float(loss), s),
-                ("Train/Samples/lr", self.get_lr(), s),
-            ])
-        if self._host_pipeline is not None:
-            self._host_pipeline.mark("step_return")
-        self.timers(TRAIN_BATCH_TIMER).stop()
-        self.tput_timer.stop(global_step=True)
+        with trace.span("train/dispatch", program="grads_batch"):
+            grads, loss = self._grads_batch(self._fwd16, shaped, rng)
+        with trace.span("train/post"):
+            if self._host_pipeline is not None:
+                self._host_pipeline.submit(jax.tree_util.tree_leaves(grads),
+                                           dispatched_at=t_dispatch)
+            else:
+                grad_leaves = [np.asarray(jax.device_get(g), dtype=np.float32)
+                               for g in jax.tree_util.tree_leaves(grads)]
+                self._host_opt.step(grad_leaves)
+                self._fwd16 = self._place_bf16(self._host_opt.bf16_tree())
+            self._post_step(False)
+            if self.monitor.enabled:
+                s = self.global_samples
+                self.monitor.write_events([
+                    ("Train/Samples/train_loss", float(loss), s),
+                    ("Train/Samples/lr", self.get_lr(), s),
+                ])
+            if self._host_pipeline is not None:
+                self._host_pipeline.mark("step_return")
         return loss
 
     def _ensure_opt_resident(self) -> None:
@@ -1687,57 +1724,80 @@ class Engine:
 
         ``batch`` leaves are [train_batch_size, ...]; alternatively pull from
         ``data_iter`` or the engine's own dataloader (reference
-        PipelineEngine.train_batch signature)."""
-        lr_mult = 1.0
-        n_samples = None
-        if batch is None:
-            if data_iter is None and self._dyn_plan is not None:
-                entry = self._dyn_plan[self._dyn_pos % len(self._dyn_plan)]
-                self._dyn_pos += 1
-                batch = self._dyn_collate([self._dyn_dataset[int(i)]
-                                           for i in entry["indices"]])
-                lr_mult = entry["lr_scale"]
-                n_samples = entry["n_real"]
-            elif data_iter is None and self._curriculum_sampler is not None:
-                idx = self._curriculum_sampler.sample(
-                    self.global_steps, self.config.train_batch_size)
-                batch = self._sampled_collate([self._sampled_dataset[int(i)]
-                                               for i in idx])
+        PipelineEngine.train_batch signature).
+
+        Traced as one ``step("train", n)`` holding the spans ``train/fetch``,
+        ``train/place``, ``train/dispatch`` and ``train/post``
+        (profiling/trace.py); under ``wall_clock_breakdown`` the step also
+        waits for its loss (``train/wait``), so its span is a step's time and
+        not a dispatch's."""
+        with trace.step("train", self.global_steps), trace.span("train/batch"):
+            with trace.span("train/fetch"):
+                batch, lr_mult, n_samples = self._fetch_batch(batch, data_iter)
+            from ..testing import faults
+
+            if faults.ACTIVE:
+                faults.maybe_sigterm("sigterm_mid_step", index=self.global_steps)
+                batch = faults.poison_batch(batch, self.global_steps)
+            if self._host_opt is not None:
+                loss = self._host_train_batch(batch)
             else:
-                it = data_iter or self._data_iter
-                if it is None:
-                    raise ConfigError("train_batch needs a batch, a data_iter, or training_data at init")
-                batch = next(it)
-        from ..testing import faults
+                loss = self._device_train_batch(batch, lr_mult, n_samples)
+            if self.config.wall_clock_breakdown:
+                import jax
 
-        if faults.ACTIVE:
-            faults.maybe_sigterm("sigterm_mid_step", index=self.global_steps)
-            batch = faults.poison_batch(batch, self.global_steps)
-        if self._host_opt is not None:
-            return self._host_train_batch(batch)
-        self.tput_timer.start()
-        self.timers(TRAIN_BATCH_TIMER).start()
-        self._ensure_opt_resident()
-        if self._curriculum is not None:
-            self._curriculum_difficulty = self._curriculum.get_difficulty(self.global_steps)
-            if self._curriculum_truncates:
-                from .data_pipeline import curriculum_truncate
+                with trace.span("train/wait"):
+                    jax.block_until_ready(loss)
+        return loss
 
-                batch = curriculum_truncate(batch, self._curriculum_difficulty)
-        if self._ltd is not None:
-            b = len(next(iter(batch.values())))
-            batch = dict(batch)
-            batch["ltd_keep_prob"] = np.full((b,), self._ltd.keep_prob(self.global_steps),
-                                             np.float32)
-        if self.progressive_layer_drop is not None:
-            self.progressive_layer_drop.update_state(self.global_steps)
-            b = len(next(iter(batch.values())))
-            batch = dict(batch)
-            batch["pld_theta"] = np.full(
-                (b,), self.progressive_layer_drop.get_theta(), np.float32)
-        shaped = self._reshape_batch(batch)
-        mix = self._mix_matrix(advance=True)
-        rng = self._next_rng()
+    def _fetch_batch(self, batch, data_iter):
+        """(batch, lr multiplier, real samples or None): the argument, or the
+        next of the dynamic-batching plan, the curriculum sampler, ``data_iter``
+        or the engine's own dataloader."""
+        if batch is not None:
+            return batch, 1.0, None
+        if data_iter is None and self._dyn_plan is not None:
+            entry = self._dyn_plan[self._dyn_pos % len(self._dyn_plan)]
+            self._dyn_pos += 1
+            batch = self._dyn_collate([self._dyn_dataset[int(i)]
+                                       for i in entry["indices"]])
+            return batch, entry["lr_scale"], entry["n_real"]
+        if data_iter is None and self._curriculum_sampler is not None:
+            idx = self._curriculum_sampler.sample(
+                self.global_steps, self.config.train_batch_size)
+            return self._sampled_collate([self._sampled_dataset[int(i)]
+                                          for i in idx]), 1.0, None
+        it = data_iter or self._data_iter
+        if it is None:
+            raise ConfigError("train_batch needs a batch, a data_iter, or training_data at init")
+        return next(it), 1.0, None
+
+    def _device_train_batch(self, batch, lr_mult, n_samples):
+        """The step whose optimizer runs on the device: build the device
+        inputs, dispatch ``train_step``, then the host's bookkeeping."""
+        with trace.span("train/place"):
+            self._ensure_opt_resident()
+            if self._curriculum is not None:
+                self._curriculum_difficulty = self._curriculum.get_difficulty(self.global_steps)
+                if self._curriculum_truncates:
+                    from .data_pipeline import curriculum_truncate
+
+                    batch = curriculum_truncate(batch, self._curriculum_difficulty)
+            if self._ltd is not None:
+                b = len(next(iter(batch.values())))
+                batch = dict(batch)
+                batch["ltd_keep_prob"] = np.full((b,), self._ltd.keep_prob(self.global_steps),
+                                                 np.float32)
+            if self.progressive_layer_drop is not None:
+                self.progressive_layer_drop.update_state(self.global_steps)
+                b = len(next(iter(batch.values())))
+                batch = dict(batch)
+                batch["pld_theta"] = np.full(
+                    (b,), self.progressive_layer_drop.get_theta(), np.float32)
+            shaped = self._reshape_batch(batch)
+            mix = self._mix_matrix(advance=True)
+            rng = self._next_rng()
+            lr_mult_arr = np.asarray(lr_mult, np.float32)
         profiling = (self.flops_profiler is not None
                      and self.global_steps + 1 == self.config.flops_profiler.profile_step)
         if profiling and self.global_steps == 0:
@@ -1745,11 +1805,12 @@ class Engine:
                 "flops_profiler: profile_step=1 measures the first step, whose wall clock "
                 "includes XLA compilation — set profile_step>=2 for steady-state TFLOPS")
         t0 = time.time() if profiling else 0.0
-        lr_mult_arr = np.asarray(lr_mult, np.float32)
         self.resilience.step_begin(self.global_steps)
         try:
-            self.state, loss, overflow, grad_norm, nonfinite = self._train_step(
-                self.state, shaped, mix, rng, lr_mult_arr)
+            # long when it traces or compiles; a dispatch otherwise
+            with trace.span("train/dispatch", program="train_step"):
+                self.state, loss, overflow, grad_norm, nonfinite = self._train_step(
+                    self.state, shaped, mix, rng, lr_mult_arr)
             if self.resilience.watchdog.timeout_s > 0:
                 # dispatch is async: the watchdog must cover device
                 # execution, not just the enqueue
@@ -1762,8 +1823,6 @@ class Engine:
             # rollback restores the last committed checkpoint in place;
             # raise propagates (an ElasticAgent above restarts the worker)
             self.resilience.on_nonfinite(self)
-            self.timers(TRAIN_BATCH_TIMER).stop()
-            self.tput_timer.stop(global_step=True)
             return loss
         if profiling:
             import jax
@@ -1774,37 +1833,35 @@ class Engine:
                                         latency_s=time.time() - t0,
                                         batch_size=(n_samples if n_samples is not None
                                                     else self.config.train_batch_size))
-        self._last_grad_norm = grad_norm
-        self._post_step(overflow, n_samples=n_samples)
-        if self.monitor.enabled:
-            s = self.global_samples
-            self.monitor.write_events([
-                ("Train/Samples/train_loss", float(loss), s),
-                ("Train/Samples/lr", self.get_lr(), s),
-                ("Train/Samples/loss_scale", self.loss_scale(), s),
-            ])
-        self._maybe_swap_out_opt()
-        self._finalize_pending_checkpoint()   # decoupled-writer step-boundary commit
-        self.timers(TRAIN_BATCH_TIMER).stop()
-        self.tput_timer.stop(global_step=True)
+        with trace.span("train/post"):
+            self._last_grad_norm = grad_norm
+            self._post_step(overflow, n_samples=n_samples)
+            if self.monitor.enabled:
+                s = self.global_samples
+                self.monitor.write_events([
+                    ("Train/Samples/train_loss", float(loss), s),
+                    ("Train/Samples/lr", self.get_lr(), s),
+                    ("Train/Samples/loss_scale", self.loss_scale(), s),
+                ])
+            self._maybe_swap_out_opt()
+            self._finalize_pending_checkpoint()   # decoupled-writer step-boundary commit
         return loss
 
     def forward(self, batch, rng=None):
         """Loss for a micro-batch with current forward weights; stashes the
         batch so ``backward()`` can compute grads (API parity: the reference
         returns module outputs; our models fold loss into the step)."""
-        self.timers(FORWARD_GLOBAL_TIMER).start()
         if self._host_opt is not None:
             raise ConfigError("the staged forward/backward/step API is not "
                               "available with the host-resident optimizer "
                               "(cpu offload tier); use train_batch()")
-        if getattr(self, "_offloaded_states", None) is not None:
-            self.reload_states()
-        shaped = self._reshape_batch(batch, gas=1)
-        micro = self._take_micro(shaped)
-        loss = self._eval_step(self.state, micro, self._mix_matrix(), rng or self._next_rng())
-        self._stashed_batch = micro
-        self.timers(FORWARD_GLOBAL_TIMER).stop()
+        with trace.span("train/forward", program="eval_step"):
+            if getattr(self, "_offloaded_states", None) is not None:
+                self.reload_states()
+            shaped = self._reshape_batch(batch, gas=1)
+            micro = self._take_micro(shaped)
+            loss = self._eval_step(self.state, micro, self._mix_matrix(), rng or self._next_rng())
+            self._stashed_batch = micro
         return loss
 
     def _take_micro(self, shaped):
@@ -1820,40 +1877,38 @@ class Engine:
         what matters."""
         import jax
 
-        self.timers(BACKWARD_GLOBAL_TIMER).start()
-        if getattr(self, "_offloaded_states", None) is not None:
-            self.reload_states()
-        if batch is not None:
-            micro = self._take_micro(self._reshape_batch(batch, gas=1))
-        elif self._stashed_batch is not None:
-            micro = self._stashed_batch
-        else:
-            raise ConfigError("backward() without a prior forward() or an explicit batch")
-        grads, loss_val = self._grads_only(self.state, micro, self._mix_matrix(), self._next_rng())
-        if self._accum_grads is None:
-            self._accum_grads = grads
-        else:
-            self._accum_grads = jax.tree_util.tree_map(lambda a, g: a + g, self._accum_grads, grads)
-        self._accum_count += 1
-        self.micro_steps += 1
-        self._stashed_batch = None
-        self.timers(BACKWARD_GLOBAL_TIMER).stop()
+        with trace.span("train/backward", program="grads_only"):
+            if getattr(self, "_offloaded_states", None) is not None:
+                self.reload_states()
+            if batch is not None:
+                micro = self._take_micro(self._reshape_batch(batch, gas=1))
+            elif self._stashed_batch is not None:
+                micro = self._stashed_batch
+            else:
+                raise ConfigError("backward() without a prior forward() or an explicit batch")
+            grads, loss_val = self._grads_only(self.state, micro, self._mix_matrix(), self._next_rng())
+            if self._accum_grads is None:
+                self._accum_grads = grads
+            else:
+                self._accum_grads = jax.tree_util.tree_map(lambda a, g: a + g, self._accum_grads, grads)
+            self._accum_count += 1
+            self.micro_steps += 1
+            self._stashed_batch = None
         return loss_val
 
     def step(self):
         """Apply accumulated gradients (reference engine.step / _take_model_step)."""
         if self._accum_grads is None:
             raise ConfigError("step() with no accumulated gradients; call backward() first")
-        self.timers(STEP_GLOBAL_TIMER).start()
-        self._ensure_opt_resident()
-        if self.ensemble:
-            self.sync.advance()  # staged path: protocol moves once per optimizer step
-        self.state, overflow = self._apply_only(self.state, self._accum_grads, float(self._accum_count))
-        self._accum_grads = None
-        self._accum_count = 0
-        self._post_step(overflow)
-        self._maybe_swap_out_opt()
-        self.timers(STEP_GLOBAL_TIMER).stop()
+        with trace.span("train/step", program="apply_only"):
+            self._ensure_opt_resident()
+            if self.ensemble:
+                self.sync.advance()  # staged path: protocol moves once per optimizer step
+            self.state, overflow = self._apply_only(self.state, self._accum_grads, float(self._accum_count))
+            self._accum_grads = None
+            self._accum_count = 0
+            self._post_step(overflow)
+            self._maybe_swap_out_opt()
 
     def eval_batch(self, batch, rng=None):
         if getattr(self, "_offloaded_states", None) is not None:
@@ -1913,12 +1968,14 @@ class Engine:
         if self.global_steps % self.config.steps_per_print == 0:
             log_dist(f"step={self.global_steps} lr={self.get_lr():.3e} loss_scale={self.loss_scale()}", ranks=[0])
             if self.config.wall_clock_breakdown:
-                self.timers.log([TRAIN_BATCH_TIMER],
-                                memory_breakdown=self.config.memory_breakdown)
-            elif self.config.memory_breakdown:
+                # the spans closed since the last line (this step's own
+                # train/batch is still open)
+                log_dist(trace.breakdown_line(
+                    trace.kept_spans(clear=True), self.config.train_batch_size,
+                    step_span="train/batch"), ranks=[0])
+            if self.config.memory_breakdown:
                 # reference see_memory_usage breadcrumbs (runtime/utils.py)
-                log_dist(f"step={self.global_steps} "
-                         f"{SynchronizedWallClockTimer.memory_usage()}", ranks=[0])
+                log_dist(f"step={self.global_steps} {_memory_usage()}", ranks=[0])
 
     # -- fork control surface (reference stage_1_and_2.py:692-734) ------
 
@@ -1973,7 +2030,10 @@ class Engine:
         lowered = self._train_step.lower(self.state, shaped, self._mix_matrix(),
                                          self._next_rng_peek(),
                                          np.asarray(1.0, np.float32))
-        compiled = lowered.compile()
+        with trace.span("train/compile", program="train_step"):
+            compiled = lowered.compile()
+        # instruction -> scope for readers of a device trace
+        trace.register_program("train_step", compiled)
         log_dist("engine.compile(): train step AOT-compiled", ranks=[0])
         return compiled
 

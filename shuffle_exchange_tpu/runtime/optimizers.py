@@ -17,6 +17,7 @@ from typing import Any, Callable, Optional
 import optax
 
 from ..config.config_utils import ConfigError
+from ..profiling import trace
 from ..utils.logging import log_dist
 
 # type -> (factory, accepted param names)
@@ -114,7 +115,13 @@ def build_optimizer(optimizer_config, lr_schedule, gradient_clipping: float = 0.
     if params:
         log_dist(f"Optimizer {name}: ignoring unsupported params {sorted(params)}", ranks=[0])
     if gradient_clipping and gradient_clipping > 0:
-        tx = optax.chain(optax.clip_by_global_norm(gradient_clipping), tx)
+        clip = optax.clip_by_global_norm(gradient_clipping)
+
+        def clip_update(updates, state, params=None):
+            with trace.scope("grad_clip"):
+                return clip.update(updates, state, params)
+
+        tx = optax.chain(optax.GradientTransformation(clip.init, clip_update), tx)
     return tx
 
 

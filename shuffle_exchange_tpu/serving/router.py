@@ -636,6 +636,8 @@ class ReplicaRouter:
                     max_new_tokens=old.max_new_tokens,
                     generated=list(old.generated),
                     submitted_at=old.submitted_at,
+                    due_at=old.due_at,
+                    first_scheduled_at=old.first_scheduled_at,
                     first_token_at=old.first_token_at,
                     last_token_at=old.last_token_at,
                     tpot_s=list(old.tpot_s),

@@ -1,3 +1,2 @@
 from .logging import logger, log_dist
 from .placement import owned_device_put
-from .timer import SynchronizedWallClockTimer, ThroughputTimer, NoopTimer

@@ -46,9 +46,14 @@ def _isolate_topology():
     that initialized e.g. tensor=2 leaks it into later tests in other files
     (InferenceEngine._place then tries to shard undividable vocab dims)."""
     from shuffle_exchange_tpu.parallel.mesh import reset_topology
+    from shuffle_exchange_tpu.runtime.resilience import uninstall_preemption_hook
 
     reset_topology()
     yield
+    # an engine with a save_dir installs a process-wide SIGTERM hook that
+    # raises SystemExit; left behind, it kills a later test of the same
+    # worker that sends itself SIGTERM (test_sigterm_triggers_drain)
+    uninstall_preemption_hook()
 
 
 @pytest.fixture(autouse=True)
