@@ -1094,8 +1094,39 @@ class Transformer:
         and when the vocab is padded to the 128 lane tile (``_pad_vocab``)
         the pad columns carry a -1e30 additive mask, so their softmax mass
         underflows to exactly zero.
+
+        In an engine's program whose batch is split over ZeRO axes
+        (``parallel.mesh.zero_batch_axes``) the scan runs in a region that is
+        manual over those axes: the weights its body closes over (final norm,
+        unembed or tied embedding, bias) are gathered by hand ONCE before the
+        scan, each device scans its own rows against a whole head, the
+        backward scan's carry is the device's unreduced gradient, and one
+        reduce-scatter per weight follows the scan. Left to XLA's partitioner
+        the gather and the reduction sit in the loop's body, once a chunk
+        (47% of a four-chip ZeRO-3 step, PERF.md PR 27). The sums are then a
+        per-device sum and one psum: the same additions in another order.
         Reference capability: chunked logits loss, sequence/fpdt_layer.py:1137.
         """
+        from ..parallel import mesh as mesh_lib
+
+        unembed = "embed" if self.config.tie_embeddings else "unembed"
+        head = {k: params[k] for k in ("ln_f_w", "ln_f_b", unembed, "unembed_b")
+                if k in params}
+
+        zero_axes = mesh_lib.zero_batch_axes(x.shape[0])
+        if not zero_axes:
+            return self._chunked_loss_scan(head, x, labels, chunk)
+
+        def scan(head, grad_acc, x, labels):
+            return self._chunked_loss_scan(head, x, labels, chunk, grad_acc)
+
+        return mesh_lib.zero_region(scan, (unembed,), zero_axes)(head, x, labels)
+
+    def _chunked_loss_scan(self, params, x, labels, chunk: int, grad_acc=None):
+        """:meth:`chunked_loss` on whole weights and the rows at hand.
+        ``grad_acc`` (in the ZeRO region): float32 stand-ins for the unembed's
+        leaf, to whose cotangent the body sends the weight's gradient, so the
+        backward scan sums it chunk after chunk in float32."""
         import jax
         import jax.numpy as jnp
 
@@ -1115,9 +1146,15 @@ class Transformer:
         V = cfg.vocab_size
         vpad = (-V % 128) if self._pad_vocab() else 0
         w, bias = self._unembed(params, x.dtype)
+        w_acc = None
+        if grad_acc is not None:
+            w_acc = (grad_acc["embed"].T if cfg.tie_embeddings
+                     else grad_acc["unembed"])
         extra = None
         if vpad:
             w = jnp.pad(w, ((0, 0), (0, vpad)))
+            if w_acc is not None:
+                w_acc = jnp.pad(w_acc, ((0, 0), (0, vpad)))
             extra = jnp.where(jnp.arange(V + vpad) < V, 0.0, -1e30
                               ).astype(jnp.float32)
             if bias is not None:
@@ -1131,7 +1168,10 @@ class Transformer:
             with trace.scope("final_norm"):
                 xn = _norm(xch, params["ln_f_w"], params["ln_f_b"], cfg.norm,
                            eps=cfg.norm_eps)
-            logits = jnp.matmul(xn, w, preferred_element_type=jnp.float32)
+            if w_acc is None:
+                logits = jnp.matmul(xn, w, preferred_element_type=jnp.float32)
+            else:
+                logits = _matmul_f32_grad(xn, w, w_acc)
             if extra is not None:
                 logits = logits + extra
             nll, cnt = self.token_loss(logits, lch)
@@ -1223,6 +1263,34 @@ class Transformer:
         with trace.scope("loss"):
             ce = nll_sum / jnp.maximum(count, 1)
             return ce + self.config.aux_loss_coef * aux
+
+
+def _matmul_f32_grad(x, w, w_acc):
+    """``x [B, T, D] @ w [D, V]`` in float32, as the unembed computes it
+    (bf16 operands, float32 accumulation), whose backward leaves ``w``'s
+    gradient in float32 as the matmul produced it and hands it to ``w_acc``
+    (``parallel.mesh.grad_accumulator``): autodiff would round it to ``w``'s
+    dtype first. ``x``'s gradient is autodiff's own."""
+    import jax
+    import jax.numpy as jnp
+
+    def matmul(x, w, w_acc):
+        return jnp.matmul(x, w, preferred_element_type=jnp.float32)
+
+    def fwd(x, w, w_acc):
+        return matmul(x, w, w_acc), (x, w)
+
+    def bwd(res, g):
+        x, w = res
+        dw = jax.lax.dot_general(g, x, (((0, 1), (0, 1)), ((), ())),
+                                 preferred_element_type=jnp.float32).T
+        dx = jax.lax.dot_general(g, w, (((2,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        return dx.astype(x.dtype), None, dw
+
+    f = jax.custom_vjp(matmul)
+    f.defvjp(fwd, bwd)
+    return f(x, w, w_acc)
 
 
 def _remat_policy(name: str):

@@ -26,11 +26,13 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..config.config_utils import ConfigError
+from ..profiling import trace
 from ..utils.logging import log_dist, logger
 
 AXIS_ORDER: Tuple[str, ...] = ("pipe", "data", "fsdp", "expert", "seq", "tensor")
@@ -273,20 +275,27 @@ def constraint_mesh(default=None):
 
 _KERNEL_MESH: contextvars.ContextVar = contextvars.ContextVar(
     "sxt_kernel_mesh", default=None)
+_PARAM_SPECS: contextvars.ContextVar = contextvars.ContextVar(
+    "sxt_param_specs", default=None)
 
 
 @contextlib.contextmanager
-def kernel_mesh(mesh):
+def kernel_mesh(mesh, param_specs=None):
     """While tracing under this, Pallas kernel call sites wrap themselves in
-    a shard_map over ``mesh`` (:func:`shard_kernel`). The training engine
+    a shard_map over ``mesh`` (:func:`shard_kernel`), and the chunked loss
+    gathers its weights by hand (:func:`zero_batch_axes`), each along the dim
+    that ``param_specs`` (top-level parameter name -> the PartitionSpec its
+    master is stored with) shards over a ZeRO axis. The training engine
     enters it around the loss and the optimizer update of its mesh-wide
     programs; a serving engine's one-device programs never do, so a live
     training topology in the same process does not reach into them. A
     context variable, so a thread that traces concurrently sees its own."""
     token = _KERNEL_MESH.set(mesh)
+    specs = _PARAM_SPECS.set(param_specs)
     try:
         yield
     finally:
+        _PARAM_SPECS.reset(specs)
         _KERNEL_MESH.reset(token)
 
 
@@ -339,19 +348,204 @@ def shard_kernel(fn, in_specs, out_specs):
         return fn
 
     def keep(spec):
-        def entry(e):
-            if e is None:
-                return None
-            axes = tuple(a for a in ((e,) if isinstance(e, str) else e)
-                         if a not in taken)
-            return axes or None
-        return PartitionSpec(*(entry(e) for e in spec))
+        return spec_subset(spec, free)
 
     is_spec = lambda x: isinstance(x, PartitionSpec)
     return shard_map(fn, mesh=constraint_mesh(mesh),
                      in_specs=jax.tree.map(keep, in_specs, is_leaf=is_spec),
                      out_specs=jax.tree.map(keep, out_specs, is_leaf=is_spec),
                      axis_names=free, check_vma=False)
+
+
+# ----------------------------------------------------------------------
+# ZeRO-sharded weights inside a region that is manual over the ZeRO axes
+# ----------------------------------------------------------------------
+
+def entry_subset(entry, allowed):
+    """One PartitionSpec entry cut down to its axes in ``allowed`` (None
+    when none is left)."""
+    if entry is None:
+        return None
+    axes = entry if isinstance(entry, tuple) else (entry,)
+    keep = tuple(a for a in axes if a in allowed)
+    if not keep:
+        return None
+    return keep if len(keep) > 1 else keep[0]
+
+
+def spec_subset(spec, allowed):
+    """``spec`` with every entry cut down to its axes in ``allowed``: the
+    in/out spec of a leaf for a region that is manual over ``allowed``."""
+    from jax.sharding import PartitionSpec
+
+    return PartitionSpec(*(entry_subset(e, allowed) for e in spec))
+
+
+def zero_sharded_dim(spec, zero_axes):
+    """(dim, entry) of the first dim of ``spec`` sharded over any of
+    ``zero_axes``, the entry cut down to them; None when no dim is."""
+    for dim, e in enumerate(spec):
+        ze = entry_subset(e, zero_axes)
+        if ze is not None:
+            return dim, ze
+    return None
+
+
+def gather_zero_sharded(x, spec, zero_axes, wire=None):
+    """Inside a region manual over ``zero_axes``: the local block ``x`` of a
+    leaf stored with ``spec``, gathered whole along its ZeRO-sharded dim
+    (other axes of that dim, ``tensor`` say, stay automatic), under the
+    scope ``zero3_gather``. ``wire(x, entry, dim)`` replaces the plain tiled
+    all-gather (the int8 wire of qwZ). A leaf no ZeRO axis shards is
+    returned as it is. The one gather of the engine's streamed ZeRO-3 wire,
+    its LoRA frozen base and the chunked loss's head."""
+    import jax
+
+    hit = zero_sharded_dim(spec, zero_axes)
+    if hit is None:
+        return x
+    dim, entry = hit
+    with trace.scope("zero3_gather"):
+        if wire is not None:
+            return wire(x, entry, dim)
+        return jax.lax.all_gather(x, entry, axis=dim, tiled=True)
+
+
+def _reduce_to_shard(g, spec, zero_axes, dtype):
+    """A gathered leaf's cotangent ``g`` (this device's own unreduced sum)
+    back on its shard: one float32 reduce-scatter under the scope
+    ``zero3_reduce_scatter``, handed on in ``dtype``. A leaf no ZeRO axis
+    shards entered the region whole; the region's transpose sums it."""
+    import jax
+    import jax.numpy as jnp
+
+    hit = zero_sharded_dim(spec, zero_axes)
+    if hit is None:
+        return g.astype(dtype)
+    dim, entry = hit
+    with trace.scope("zero3_reduce_scatter"):
+        return jax.lax.psum_scatter(g.astype(jnp.float32), entry,
+                                    scatter_dimension=dim, tiled=True).astype(dtype)
+
+
+def gather_for_loop(x, spec, zero_axes):
+    """:func:`gather_zero_sharded` as a differentiable unit for a weight
+    that a loop closes over: forward, one all-gather before the loop;
+    backward, the loop's carry is this device's own unreduced sum (in the
+    weight's dtype) and ONE reduce-scatter takes it back to the shard after
+    the loop, whatever the number of trips. Left to XLA's partitioner the
+    gather and the reduction sit in the loop's body, once a trip."""
+    import jax
+
+    def gather(x):
+        return gather_zero_sharded(x, spec, zero_axes)
+
+    def fwd(x):
+        return gather(x), None
+
+    def bwd(_, g):
+        return (_reduce_to_shard(g, spec, zero_axes, g.dtype),)
+
+    gathered = jax.custom_vjp(gather)
+    gathered.defvjp(fwd, bwd)
+    return gathered(x)
+
+
+def grad_accumulator(x, spec, zero_axes):
+    """Float32 zeros in the shape of the gathered leaf, made for their
+    COTANGENT: a loop that sends the leaf's gradient here and not to the
+    (bf16) gathered leaf sums it trip after trip in float32, and backward
+    takes that sum to the shard by :func:`_reduce_to_shard`. The value is
+    never read, so the forward pass drops it."""
+    import jax
+    import jax.numpy as jnp
+
+    whole = jax.eval_shape(lambda x: gather_zero_sharded(x, spec, zero_axes), x)
+
+    def zeros(x):
+        return jnp.zeros(whole.shape, jnp.float32)
+
+    def fwd(x):
+        return zeros(x), None
+
+    def bwd(_, g):
+        return (_reduce_to_shard(g, spec, zero_axes, x.dtype),)
+
+    accumulator = jax.custom_vjp(zeros)
+    accumulator.defvjp(fwd, bwd)
+    return accumulator(x)
+
+
+def zero_batch_axes(batch: int) -> Tuple[str, ...]:
+    """The ZeRO axes that split the ``batch`` rows of an activation in the
+    mesh-wide program being traced, where a region may still take them as
+    manual: those of :func:`kernel_mesh`'s mesh that are larger than 1. Empty
+    outside ``kernel_mesh`` (a one-device program, an ensemble's vmapped
+    replicas), on a mesh with no such axis, inside a region that took one
+    already (the streamed ZeRO-3 wire, the qgZ and flat pipeline regions:
+    their weights are whole), where they do not divide ``batch``, and on a
+    ``seq`` axis larger than 1 (the loss's chunks run along that dim)."""
+    mesh = _KERNEL_MESH.get()
+    if mesh is None:
+        return ()
+    axes = tuple(ax for ax in ZERO_AXES if mesh.shape[ax] > 1)
+    if (not axes or mesh.shape["seq"] > 1 or _manual_axes() & set(ZERO_AXES)
+            or batch % int(np.prod([mesh.shape[ax] for ax in axes]))):
+        return ()
+    return axes
+
+
+def zero_region(fn, accumulated, zero_axes):
+    """``fn(leaves, accumulators, *batch_args) -> sums`` run in a region
+    manual over ``zero_axes`` only: ``leaves`` (a dict of top-level
+    parameters) enter as their ZeRO shards and are gathered once
+    (:func:`gather_for_loop`); those named in ``accumulated`` are gathered
+    plainly and come with a :func:`grad_accumulator` for ``fn`` to direct
+    their gradient to. A leaf no ZeRO axis shards (stage 0, or too small
+    to divide) enters whole, in float32: the region's transpose sums its
+    cotangent over the axes in the dtype it entered with. Each ``batch_arg``
+    enters as this device's rows of dim 0, and every output is summed over
+    the axes. Other axes (``tensor``, ``expert``) stay automatic inside."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec
+
+    given = _PARAM_SPECS.get() or {}
+
+    def spec_of(name):
+        # a leaf the engine gave no spec for (a LoRA-merged weight) enters whole
+        spec = given.get(name)
+        return (spec_subset(spec, zero_axes)
+                if isinstance(spec, PartitionSpec) else PartitionSpec())
+
+    def local(specs, dtypes, leaves, *batch_args):
+        accumulators = {k: grad_accumulator(leaves[k], specs[k], zero_axes)
+                        for k in accumulated}
+        leaves = {k: v.astype(dtypes[k]) for k, v in leaves.items()}
+        # an accumulated leaf's gradient goes by its accumulator alone
+        leaves = {k: (jax.lax.stop_gradient(
+                          gather_zero_sharded(v, specs[k], zero_axes))
+                      if k in accumulated else
+                      gather_for_loop(v, specs[k], zero_axes))
+                  for k, v in leaves.items()}
+        return jax.tree.map(lambda s: jax.lax.psum(s, zero_axes),
+                            fn(leaves, accumulators, *batch_args))
+
+    def region(leaves, *batch_args):
+        specs = {k: spec_of(k) for k in leaves}
+        dtypes = {k: v.dtype for k, v in leaves.items()}
+        leaves = {k: v.astype(jnp.float32)
+                  if zero_sharded_dim(specs[k], zero_axes) is None
+                  and jnp.issubdtype(v.dtype, jnp.floating) else v
+                  for k, v in leaves.items()}
+        rows = PartitionSpec(zero_axes)
+        return shard_map(functools.partial(local, specs, dtypes),
+                         mesh=constraint_mesh(_KERNEL_MESH.get()),
+                         in_specs=(specs,) + (rows,) * len(batch_args),
+                         out_specs=PartitionSpec(), axis_names=zero_axes,
+                         check_vma=False)(leaves, *batch_args)
+
+    return region
 
 
 def get_data_parallel_world_size() -> int:

@@ -14,7 +14,12 @@ TPU-native structure (SURVEY.md §7): the hot path is ONE jitted
 loss-scale bookkeeping, optimizer update, weight mixing — with every array's
 placement given by NamedShardings derived from the ZeRO stage. XLA inserts
 and overlaps the reduce-scatters/all-gathers the reference issues by hand
-(stage_1_and_2.py:1242,2254; stage3.py:1305). The ``forward``/``backward``/
+(stage_1_and_2.py:1242,2254; stage3.py:1305), with two exceptions issued by
+hand here too, once per parameter per step: the int8 wire of ZeRO++
+(``qz3_batch_grads``), and the chunked loss's head (``models/transformer.py
+chunked_loss``: one gather before its scan, one float32 reduce-scatter after
+it, through ``parallel/mesh.py gather_zero_sharded``), which the partitioner
+would otherwise repeat in every chunk. The ``forward``/``backward``/
 ``step`` triple is kept for API parity and stages the same computation.
 
 Decentralized mode: when ``shuffle_exchange`` is enabled, the engine holds
@@ -34,7 +39,10 @@ import numpy as np
 from ..config.config import SXConfig
 from ..config.config_utils import ConfigError
 from ..parallel.mesh import MeshTopology, kernel_mesh
+from ..parallel.mesh import gather_zero_sharded as _mesh_gather_zero_sharded
 from ..parallel.mesh import shard_map as _shard_map
+from ..parallel.mesh import spec_subset as _spec_subset
+from ..parallel.mesh import zero_sharded_dim as _zero_sharded_dim
 from ..profiling import trace
 from ..utils.logging import log_dist, logger
 from . import loss_scaler as ls
@@ -683,8 +691,14 @@ class Engine:
         return None if self.ensemble else self.topology.mesh
 
     def _loss(self, params, batch, rng=None):
-        """``loss_fn`` traced as part of a mesh-wide program."""
-        with kernel_mesh(self._kernel_mesh):
+        """``loss_fn`` traced as part of a mesh-wide program. The forward
+        weights are a cast of the masters, so they reach the loss laid out
+        as the masters are stored: the specs tell the chunked loss which dim
+        of its head to gather."""
+        import jax
+
+        specs = jax.tree_util.tree_map(lambda sh: sh.spec, self.master_shardings)
+        with kernel_mesh(self._kernel_mesh, specs):
             return self.loss_fn(params, batch, rng)
 
     def _build_programs(self) -> None:
@@ -937,37 +951,6 @@ class Engine:
         _zset = frozenset(_zero_axes_all)
         _mset = _zset | ({"pipe"} if pipe_wire else set())
 
-        def _entry_subset(entry, allowed):
-            if entry is None:
-                return None
-            axes = entry if isinstance(entry, tuple) else (entry,)
-            keep = tuple(a for a in axes if a in allowed)
-            if not keep:
-                return None
-            return keep if len(keep) > 1 else keep[0]
-
-        def _zentry(entry):
-            return _entry_subset(entry, _zset)
-
-        def _zsize(zentry):
-            if zentry is None:
-                return 1
-            n = 1
-            for a in (zentry if isinstance(zentry, tuple) else (zentry,)):
-                n *= axis_sizes[a]
-            return n
-
-        def _zspec(spec):
-            from jax.sharding import PartitionSpec as P
-
-            return P(*[_zentry(e) for e in spec])
-
-        def _mspec(spec):
-            """Region in/out spec: manual components (zero axes + pipe)."""
-            from jax.sharding import PartitionSpec as P
-
-            return P(*[_entry_subset(e, _mset) for e in spec])
-
         def _has_pipe(spec):
             for e in spec:
                 if e is None:
@@ -984,15 +967,12 @@ class Engine:
             frozen base — callers cast to the wire dtype beforehand."""
             from ..parallel.compressed import quantized_all_gather
 
-            for dim, e in enumerate(spec):
-                ze = _zentry(e)
-                if ze is not None and _zsize(ze) > 1:
-                    with trace.scope("zero3_gather"):
-                        if qw:
-                            return quantized_all_gather(
-                                x, ze, group_size=cfg.zeropp.group_size, axis=dim)
-                        return jax.lax.all_gather(x, ze, axis=dim, tiled=True)
-            return x
+            def int8_wire(x, entry, dim):
+                return quantized_all_gather(
+                    x, entry, group_size=cfg.zeropp.group_size, axis=dim)
+
+            return _mesh_gather_zero_sharded(x, spec, _zset,
+                                             wire=int8_wire if qw else None)
 
         def _gather_frozen_in_region(frozen):
             """LoRA frozen base inside the wire region: zero-sharded bf16
@@ -1016,7 +996,7 @@ class Engine:
 
             if self._lora is None:
                 return ()
-            return jax.tree_util.tree_map(lambda sh: _zspec(sh.spec),
+            return jax.tree_util.tree_map(lambda sh: _spec_subset(sh.spec, _zset),
                                           self.frozen_shardings)
 
         def qz3_batch_grads(master, frozen, micro, rng, scale, step):
@@ -1062,8 +1042,7 @@ class Engine:
                 # already hold only their own rows.
                 if pipe_wire and not _has_pipe(spec):
                     g = jax.lax.psum(g, "pipe")
-                shard = next(((d, _zentry(e)) for d, e in enumerate(spec)
-                              if _zsize(_zentry(e)) > 1), None)
+                shard = _zero_sharded_dim(spec, _zset)
                 if shard is None:
                     red = (_int8_wire_allreduce(g, zero_axes, wire_group_size)
                            if qg else jax.lax.psum(g, zero_axes))
@@ -1128,7 +1107,9 @@ class Engine:
                     loss = jax.lax.pmean(loss, ax)
                 return g, loss
 
-            mspecs = jax.tree_util.tree_map(_mspec, specs)
+            # region in/out specs: the manual components (zero axes + pipe)
+            mspecs = jax.tree_util.tree_map(
+                lambda spec: _spec_subset(spec, _mset), specs)
             batch_spec = P(zero_axes if len(zero_axes) > 1 else (zero_axes[0] if zero_axes else None))
             stage_ids = jnp.arange(max(pipe_n, 1), dtype=jnp.int32)
             return _shard_map(
