@@ -2305,8 +2305,9 @@ def _config3(peak, hbm, n_chips, hbm_bw=None):
     # compute at these shapes; the index form (scalar slot scatter + row
     # gathers, identical capacity/drop semantics) measured 1.84x faster
     # end-to-end on-chip (23.1% vs 12.5% active-param MFU at bs8x2048).
-    # megablox ragged under the layer scan measured 5.3% — see
-    # scripts/bench_moe_impl.py. Geometry bs32x1024 per the same
+    # megablox ragged under the layer scan measured 5.3% then, with the
+    # kernel's default 128^3 tile (PR 28 found the tile, not the scan, to be
+    # the cause: PERF.md section 6). Geometry bs32x1024 per the same
     # unbilled-attention analysis as config 2. Head geometry matches
     # Mixtral's Dh=128 / G=4 (same reasoning as the config-2 ladder).
     mcfg3 = TransformerConfig(

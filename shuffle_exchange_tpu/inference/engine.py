@@ -158,6 +158,13 @@ class InferenceEngine:
         self.model = model
         self.config = config or InferenceConfig()
         self._mcfg = model.config
+        if getattr(self._mcfg, "qk_norm", False):
+            # the cached decode paths project q and k without the whole-
+            # projection RMSNorm: serving such a model would be silently wrong
+            raise NotImplementedError(
+                "serving a model with q/k RMSNorm (OLMoE) is not implemented "
+                "yet: the inference engines' attention has no q/k norm "
+                "(training through sxt.initialize is; ROADMAP R1)")
         if self._mcfg.position == "alibi":
             from ..models.transformer import alibi_slopes
 
@@ -634,8 +641,8 @@ class InferenceEngine:
                              if n.startswith("moe_")
                              and n != "moe_gate" and not n.startswith("moe_shared")}
             # scanned=True: _ffn runs inside the lax.scan over stacked
-            # layers — "auto" must not pick the megablox ragged path here
-            # (the ~4x scanned-gmm cliff, moe/resolve_moe_impl), same as
+            # layers, where "auto" takes the capacity path
+            # (moe/resolve_moe_impl), same as
             # the training stack_apply call site. Serving (engine_v2) may
             # override impl/capacity_factor from serving.moe and arm a
             # per-layer tap collecting routing counts; both are inert on

@@ -251,8 +251,8 @@ class InferenceEngineV2(InferenceEngine):
         if self._moe_serving:
             mo = cfg.serving.moe
             # "auto" defers to the model config's moe_impl (which itself
-            # resolves scanned "auto" -> capacity, the ~4x scanned-gmm
-            # cliff); an explicit serving impl wins over the model config
+            # resolves scanned "auto" -> capacity, moe/resolve_moe_impl);
+            # an explicit serving impl wins over the model config
             self._moe_impl_override = (None if mo.moe_impl == "auto"
                                        else mo.moe_impl)
             self._moe_cf_override = mo.capacity_factor

@@ -4,7 +4,8 @@ Capability parity with the reference's per-architecture support surface —
 the v1 injection policies/containers (``module_inject/containers/`` gpt2,
 llama/llama2, opt, …) and the v2 engine factory's arch dispatch
 (``inference/v2/engine_factory.py:32,69``: llama, mistral, mixtral, opt,
-phi/phi3, qwen/qwen2, falcon). A reference user points the engine at an HF
+phi/phi3, qwen/qwen2, falcon), plus families the reference does not have
+(olmoe: q/k RMSNorm and dropless fine-grained experts). A reference user points the engine at an HF
 model; here ``from_hf(model_or_path)`` returns ``(Transformer, params)``
 ready for ``sxt.initialize`` / ``init_inference``.
 
@@ -48,6 +49,7 @@ _ARCH_FAMILIES = {
     "GPTNeoForCausalLM": "gptneo",
     "InternLMForCausalLM": "internlm",
     "InternLM2ForCausalLM": "internlm2",
+    "OlmoeForCausalLM": "olmoe",
 }
 
 
@@ -57,7 +59,8 @@ _MODEL_TYPE_FAMILIES = {"llama": "llama", "mistral": "llama", "qwen2": "qwen2",
                         "falcon": "falcon", "bloom": "bloom", "qwen2_moe": "qwen2moe",
                         "bert": "bert", "distilbert": "distilbert",
                         "gpt_neo": "gptneo", "internlm": "internlm",
-                        "internlm2": "internlm2", "megatron": "megatron",
+                        "internlm2": "internlm2", "olmoe": "olmoe",
+                        "megatron": "megatron",
                         "megatron-gpt": "megatron", "megatron_gpt": "megatron"}
 
 
@@ -267,6 +270,24 @@ def config_from_hf(hf_config) -> TransformerConfig:
             moe_shared_expert_ff=cfg.get("shared_expert_intermediate_size", 0),
             aux_loss_coef=cfg.get("router_aux_loss_coef", 0.001),
             capacity_factor=float(cfg.get("capacity_factor", 8.0)), **common)
+    if family == "olmoe":
+        # allenai/OLMoE: llama wiring + RMSNorm over the whole q and k
+        # projections + 64 fine-grained experts routed WITHOUT drops (so
+        # moe_impl is "ragged", the dropless path, in every context: any
+        # capacity is a different function), raw (not renormalised) top-k
+        # weights, HF's all-choices balancing loss
+        if cfg.get("clip_qkv") is not None:
+            raise ValueError(
+                f"olmoe with clip_qkv={cfg['clip_qkv']!r} is not supported "
+                "(the q/k/v clamp is not implemented; OLMoE-1B-7B ships null)")
+        if cfg.get("attention_bias"):
+            raise ValueError("olmoe with attention_bias=true is not supported")
+        return TransformerConfig(
+            qk_norm=True, n_experts=cfg["num_experts"],
+            moe_top_k=cfg.get("num_experts_per_tok", 8),
+            moe_norm_topk=bool(cfg.get("norm_topk_prob", False)),
+            moe_impl="ragged", moe_aux="all_choices",
+            aux_loss_coef=cfg.get("router_aux_loss_coef", 0.01), **common)
     if family == "mixtral":
         return TransformerConfig(
             n_experts=cfg["num_local_experts"], moe_top_k=cfg.get("num_experts_per_tok", 2),
@@ -769,7 +790,7 @@ def params_from_state_dict(sd: Dict[str, Any], config: TransformerConfig,
             p["unembed"] = _np(sd["output_layer.weight"])[:config.vocab_size].T
         return p
 
-    # rope/rmsnorm families: llama / mistral / qwen2 / phi3 / mixtral / internlm
+    # rope/rmsnorm families: llama / mistral / qwen2 / phi3 / mixtral / internlm / olmoe
     p["embed"] = _np(sd["embed_tokens.weight"])
     layers: Dict[str, np.ndarray] = {
         "ln1_w": _stack(sd, "layers.{}.input_layernorm.weight", L),
@@ -798,7 +819,10 @@ def params_from_state_dict(sd: Dict[str, Any], config: TransformerConfig,
             layers["b_v"] = _stack(sd, "layers.{}.self_attn.v_proj.bias", L)
         if config.attn_out_bias:   # internlm v1 bias=True
             layers["b_o"] = _stack(sd, "layers.{}.self_attn.o_proj.bias", L)
-        if family in ("mixtral", "qwen2moe"):
+        if config.qk_norm:         # olmoe: gains over the whole projection
+            layers["q_norm_w"] = _stack(sd, "layers.{}.self_attn.q_norm.weight", L)
+            layers["k_norm_w"] = _stack(sd, "layers.{}.self_attn.k_norm.weight", L)
+        if family in ("mixtral", "qwen2moe", "olmoe"):
             E = config.n_experts
 
             def experts(fmt):
@@ -818,6 +842,7 @@ def params_from_state_dict(sd: Dict[str, Any], config: TransformerConfig,
                 layers["moe_w_gate"] = experts("layers.{}.mlp.experts.{}.gate_proj.weight")
                 layers["moe_w_up"] = experts("layers.{}.mlp.experts.{}.up_proj.weight")
                 layers["moe_w_down"] = experts("layers.{}.mlp.experts.{}.down_proj.weight")
+            if family == "qwen2moe":
                 layers["moe_shared_w_gate"] = _stack(
                     sd, "layers.{}.mlp.shared_expert.gate_proj.weight", L, transpose=True)
                 layers["moe_shared_w_up"] = _stack(

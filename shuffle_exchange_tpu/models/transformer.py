@@ -49,6 +49,8 @@ class TransformerConfig:
     attn_qkv_bias: bool = False                # Qwen2-style q/k/v biases
     attn_out_bias: bool = False                # GPT-2/OPT-style out-proj bias
     pos_offset: int = 0                        # OPT offsets positions by 2
+    qk_norm: bool = False                      # OLMoE: RMSNorm (learned gain) over the WHOLE
+                                               # q and k projections, before heads and RoPE
     # Family structure flags (round 3, HF import breadth — reference
     # module_inject/containers/{gptj,gptneox,bloom}.py + falcon in
     # inference/v2/engine_factory.py):
@@ -94,6 +96,11 @@ class TransformerConfig:
     moe_shared_expert_ff: int = 0              # Qwen2-MoE shared expert (0 = none)
     moe_norm_topk: bool = True                 # renormalize top-k weights (Mixtral);
                                                # False = raw softmax probs (Qwen2-MoE)
+    # "first_choice": DeepSpeed's l_aux (first choice only), summed over
+    # layers. "all_choices": HF load_balancing_loss_func (OLMoE, Mixtral's HF
+    # form): E * sum_e f[e] * P[e] with f counting all k choices and both
+    # means taken over the tokens of ALL layers together.
+    moe_aux: str = "first_choice"
     # Megatron --expert-interval interleaving: per-layer MoE flags, cycled
     # over n_layers; () = every layer is MoE (when n_experts > 0). Dense
     # layers store their FFN in expert slot 0 of the stacked arrays and a
@@ -432,6 +439,9 @@ class Transformer:
             layer["b_v"] = jnp.zeros((L, KV * Dh))
         if cfg.attn_out_bias:
             layer["b_o"] = jnp.zeros((L, D))
+        if cfg.qk_norm:
+            layer["q_norm_w"] = jnp.ones((L, H * Dh))
+            layer["k_norm_w"] = jnp.ones((L, KV * Dh))
         if cfg.n_experts > 0:
             import jax.random as jrandom
 
@@ -512,8 +522,8 @@ class Transformer:
                 return P(*lead, None, "tensor")       # column parallel
             if name in ("wo", "w_down"):
                 return P(*lead, "tensor", None)       # row parallel
-            if name in ("b_up", "b_q", "b_k", "b_v"):
-                return P(*lead, "tensor")  # column-parallel biases
+            if name in ("b_up", "b_q", "b_k", "b_v", "q_norm_w", "k_norm_w"):
+                return P(*lead, "tensor")  # column-parallel biases and gains
             if name == "embed":
                 return P("tensor", None)              # vocab parallel
             if name == "unembed":
@@ -557,7 +567,8 @@ class Transformer:
         return x, rope_table(T, cfg.rotary_dims, cfg.rope_theta)
 
     def layer_apply(self, lw, h, rope, local=None, moe_on=None):
-        """One transformer block. h [B, T, D] -> (h, moe_aux).
+        """One transformer block. h [B, T, D] -> (h, (moe_aux, this layer's
+        router stats or None)): a carry and an output, as ``lax.scan`` wants.
 
         ``local`` (traced bool scalar, GPT-Neo): this layer restricts
         attention to the trailing ``local_attention_window`` positions.
@@ -579,8 +590,16 @@ class Transformer:
             with trace.scope("attn_norm"):
                 y = _norm(h, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm, eps=cfg.norm_eps)
         with trace.scope("attn_qkv"):
-            q = (y @ lw["wq"]).reshape(B, T, H, Dh)
-            k = (y @ lw["wk"]).reshape(B, T, KV, Dh)
+            q, k = y @ lw["wq"], y @ lw["wk"]
+            if cfg.qk_norm:
+                # OLMoE: RMSNorm over the WHOLE projection (all heads
+                # together, one learned gain per column), before the split
+                # into heads and before RoPE. Not a per-head norm.
+                with trace.scope("attn_qk_norm"):
+                    q = _norm(q, lw["q_norm_w"], 0, "rmsnorm", eps=cfg.norm_eps)
+                    k = _norm(k, lw["k_norm_w"], 0, "rmsnorm", eps=cfg.norm_eps)
+            q = q.reshape(B, T, H, Dh)
+            k = k.reshape(B, T, KV, Dh)
             v = (y @ lw["wv"]).reshape(B, T, KV, Dh)
             if cfg.attn_qkv_bias:
                 q = q + lw["b_q"].astype(dtype).reshape(H, Dh)
@@ -629,12 +648,19 @@ class Transformer:
                 h = h + attn_out
                 y2 = _norm(h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm, eps=cfg.norm_eps)
         with trace.scope("moe" if cfg.n_experts > 0 else "mlp"):
-            h, aux = self._ffn(lw, h, y2, attn_out, moe_on)
-        return h, aux
+            h, aux, stats = self._ffn(lw, h, y2, attn_out, moe_on)
+        return h, (aux, stats)
 
     def _ffn(self, lw, h, y2, attn_out, moe_on):
         """The block's second half: (MoE or dense) feed-forward on ``y2`` and
-        the residual add. Returns (h, moe_aux)."""
+        the residual add. Returns (h, moe_aux, stats): ``stats`` is None for
+        a dense model, else this layer's ``expert_tokens`` [E] int32 (the
+        token-choices each expert computed) and ``router_prob`` [E] (mean
+        router probability). ``moe_aux`` is the layer's own balancing loss;
+        for ``moe_aux="all_choices"`` it is divided by the layer count, which
+        is HF's loss exactly at one layer and a per-layer form of it
+        otherwise (``loss_and_stats`` computes the exact cross-layer form
+        from the stats where it has them)."""
         import jax
         import jax.numpy as jnp
         from jax.ad_checkpoint import checkpoint_name
@@ -642,6 +668,7 @@ class Transformer:
         cfg = self.config
         dtype = h.dtype
         aux = jnp.zeros((), jnp.float32)
+        stats = None
         if cfg.n_experts > 0:
             from ..moe.layer import moe_layer
 
@@ -651,16 +678,21 @@ class Transformer:
 
             def moe_branch(y2):
                 # scanned=True: layer_apply always runs under stack_apply's
-                # lax.scan — "auto" must not pick the megablox ragged path
-                # there (the ~4x scanned-gmm cliff, moe/resolve_moe_impl)
+                # lax.scan, where "auto" takes the capacity path
+                # (moe/resolve_moe_impl)
                 res = moe_layer(lw["moe_gate"], expert_params, y2, k=cfg.moe_top_k,
                                 capacity_factor=cfg.capacity_factor, activation=cfg.activation,
                                 impl=cfg.moe_impl, normalize_weights=cfg.moe_norm_topk,
-                                scanned=True)
-                return res.output, res.aux_loss
+                                scanned=True, aux=cfg.moe_aux)
+                aux = res.aux_loss
+                if cfg.moe_aux == "all_choices":
+                    aux = aux / cfg.n_layers
+                return res.output, aux, {
+                    "expert_tokens": res.metadata["expert_counts"].astype(jnp.int32),
+                    "router_prob": res.metadata["router_prob"]}
 
             if moe_on is None:
-                ff, aux = moe_branch(y2)
+                ff, aux, stats = moe_branch(y2)
             else:
                 def dense_branch(y2):
                     # expert slot 0 carries the dense FFN of interleaved
@@ -678,7 +710,9 @@ class Transformer:
                     out = hh @ expert_params["w_down"][0].astype(dtype)
                     if "b_down" in expert_params:
                         out = out + expert_params["b_down"][0].astype(dtype)
-                    return out, jnp.zeros((), jnp.float32)
+                    return out, jnp.zeros((), jnp.float32), {
+                        "expert_tokens": jnp.zeros((cfg.n_experts,), jnp.int32),
+                        "router_prob": jnp.zeros((cfg.n_experts,), jnp.float32)}
 
                 from ..parallel.mesh import inside_manual_region
 
@@ -687,12 +721,11 @@ class Transformer:
                     # around the MoE dispatch CHECK-fails XLA's partitioner;
                     # compute both branches and select — the dense branch
                     # is one FFN, small next to the expert compute
-                    ff_m, aux_m = moe_branch(y2)
-                    ff_d, aux_d = dense_branch(y2)
-                    ff = jnp.where(moe_on, ff_m, ff_d)
-                    aux = jnp.where(moe_on, aux_m, aux_d)
+                    ff, aux, stats = jax.tree.map(
+                        lambda m, d: jnp.where(moe_on, m, d),
+                        moe_branch(y2), dense_branch(y2))
                 else:
-                    ff, aux = jax.lax.cond(moe_on, moe_branch, dense_branch, y2)
+                    ff, aux, stats = jax.lax.cond(moe_on, moe_branch, dense_branch, y2)
             if cfg.moe_shared_expert_ff > 0:
                 # Qwen2-MoE shared expert: a dense swiglu MLP every token
                 # runs, added with a per-token sigmoid gate
@@ -720,7 +753,7 @@ class Transformer:
             h = h + attn_out + ff
         else:
             h = h + ff
-        return h, aux
+        return h, aux, stats
 
     @staticmethod
     def _sp_mesh():
@@ -915,8 +948,10 @@ class Transformer:
         return out[:, :T0] if pad else out
 
     def stack_apply(self, stacked_layers, x, rope, ltd_mask=None,
-                    layer_keep=None, layer_ids=None):
-        """Scan the (sub)stack of layers over x. Returns (x, summed aux).
+                    layer_keep=None, layer_ids=None, with_stats=False):
+        """Scan the (sub)stack of layers over x. Returns (x, summed aux), or
+        with ``with_stats`` (x, summed aux, the layers' router stats stacked
+        [L, E], None for a dense model).
 
         ``ltd_mask`` [B, T] bool (True = keep): random-LTD token freezing
         for the configured middle layers.
@@ -984,8 +1019,9 @@ class Transformer:
             if cfg.remat:
                 layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(cfg.remat_policy))
             with trace.scope("layers"):      # the scan's own slicing and stacking
-                x, aux_losses = jax.lax.scan(layer_fn, x, xs)
-            return x, jnp.sum(aux_losses)
+                x, (aux_losses, stats) = jax.lax.scan(layer_fn, x, xs)
+            aux = jnp.sum(aux_losses)
+            return (x, aux, stats) if with_stats else (x, aux)
 
         if ltd_mask is not None:
             end = cfg.random_ltd_end_layer if cfg.random_ltd_end_layer >= 0 else LG - 1
@@ -1009,15 +1045,18 @@ class Transformer:
                 keep = jnp.logical_or(~act, ltd_mask)[..., None]   # [B,T,1]
                 out = jnp.where(keep, out, h)
             out = jnp.where(keep_l, out, h)
-            return out, jnp.where(keep_l, aux, jnp.zeros_like(aux))
+            # a dropped layer routes nothing: its aux and stats are zero
+            return out, jax.tree.map(
+                lambda a: jnp.where(keep_l, a, jnp.zeros_like(a)), aux)
 
         if cfg.remat:
             layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(cfg.remat_policy))
         with trace.scope("layers"):
-            x, aux_losses = jax.lax.scan(
+            x, (aux_losses, stats) = jax.lax.scan(
                 layer_fn, x, (stacked_layers, active, keep_layers, local_flags,
                               moe_flags))
-        return x, jnp.sum(aux_losses)
+        aux = jnp.sum(aux_losses)
+        return (x, aux, stats) if with_stats else (x, aux)
 
     def _unembed(self, params, dtype):
         """Single source of truth for the unembed projection: (w [D, V],
@@ -1213,6 +1252,14 @@ class Transformer:
         """Next-token cross entropy. batch: {"input_ids": [B,T]} (+ optional
         "labels" already shifted, -100 = ignore; + optional "ltd_keep_prob"
         [B] for the random-LTD schedule)."""
+        return self.loss_and_stats(params, batch, rng)[0]
+
+    def loss_and_stats(self, params, batch, rng=None):
+        """``loss`` and what the step reports beside it: a dict of small
+        arrays, empty for a dense model. An MoE model gives
+        ``moe_expert_tokens`` [L, E] int32, the token-choices each expert of
+        each layer computed on this batch (the engine keeps the last step's:
+        ``Engine.last_step_stats``)."""
         import jax.numpy as jnp
 
         ids = batch["input_ids"]
@@ -1248,21 +1295,30 @@ class Transformer:
             p_keep = 1.0 - (jnp.arange(L, dtype=jnp.float32) / L) * (1.0 - theta)
             layer_keep = jax.random.uniform(sub, (L,)) < p_keep
         B, T = model_ids.shape
+        cfg = self.config
+        x, rope = self.embed(params, model_ids)
+        x, aux, routed = self.stack_apply(params["layers"], x, rope,
+                                          ltd_mask=ltd_mask, layer_keep=layer_keep,
+                                          with_stats=True)
+        stats = {}
+        if routed is not None:
+            stats["moe_expert_tokens"] = routed["expert_tokens"]
+            if cfg.moe_aux == "all_choices":
+                # HF load_balancing_loss_func: the router probabilities and
+                # choices of ALL layers concatenated over tokens, so both
+                # means run over L * B * T rows (every layer has B * T)
+                n_layers = routed["expert_tokens"].shape[0]
+                f = (routed["expert_tokens"].sum(axis=0).astype(jnp.float32)
+                     / (n_layers * B * T))
+                aux = cfg.n_experts * jnp.sum(f * routed["router_prob"].mean(axis=0))
         chunk = self._loss_chunk(B, T)
-        if chunk:
-            x, rope = self.embed(params, model_ids)
-            x, aux = self.stack_apply(params["layers"], x, rope,
-                                      ltd_mask=ltd_mask, layer_keep=layer_keep)
-            with trace.scope("loss"):
-                nll_sum, count = self.chunked_loss(params, x, labels, chunk)
-        else:
-            logits, aux = self.apply_with_aux(params, model_ids, ltd_mask=ltd_mask,
-                                              layer_keep=layer_keep)
-            with trace.scope("loss"):
-                nll_sum, count = self.token_loss(logits, labels)
         with trace.scope("loss"):
+            if chunk:
+                nll_sum, count = self.chunked_loss(params, x, labels, chunk)
+            else:
+                nll_sum, count = self.token_loss(self.head(params, x), labels)
             ce = nll_sum / jnp.maximum(count, 1)
-            return ce + self.config.aux_loss_coef * aux
+            return ce + cfg.aux_loss_coef * aux, stats
 
 
 def _matmul_f32_grad(x, w, w_acc):

@@ -47,19 +47,25 @@ def compute_capacity(num_tokens: int, num_experts: int, k: int, capacity_factor:
 
 
 def topk_select(logits, k: int, normalize_weights: bool = True,
-                train: bool = False, rng=None, noise_std: float = 0.0):
+                train: bool = False, rng=None, noise_std: float = 0.0,
+                aux: str = "first_choice"):
     """The ONE top-k routing rule (iterative argmax — ties broken by
     expert order), shared by the capacity path (topk_gating) and the
     dropless ragged path (moe/layer.expert_mlp_ragged), so the two can
     never diverge on selection/noise/aux semantics.
 
     logits [S, E] -> (idx [S,k] i32, weights [S,k] f32, aux_loss, masks)
-    where masks is the per-choice one-hot list and aux_loss is the
-    reference l_aux on the first choice (moe/sharded_moe.py).
+    where masks is the per-choice one-hot list. ``aux``: "first_choice" is
+    the reference l_aux on the first choice (moe/sharded_moe.py);
+    "all_choices" is HF's ``load_balancing_loss_func`` over these tokens,
+    ``E * sum_{j,e} mean_s(mask_j)[e] * mean_s(gates)[e]`` with all k choices
+    counted (OLMoE, Mixtral's HF form).
     """
     import jax
     import jax.numpy as jnp
 
+    if aux not in ("first_choice", "all_choices"):
+        raise ValueError(f"moe aux must be 'first_choice' or 'all_choices'; got {aux!r}")
     E = logits.shape[-1]
     logits = logits.astype(jnp.float32)
     if train and noise_std > 0.0 and rng is not None:
@@ -76,8 +82,8 @@ def topk_select(logits, k: int, normalize_weights: bool = True,
         masks.append(m)
         masked = jnp.where(m > 0, -jnp.inf, masked)
 
-    # Aux load-balancing loss on the first choice (reference l_aux):
-    aux_loss = E * jnp.sum(gates.mean(axis=0) * masks[0].mean(axis=0))
+    chosen = masks[0] if aux == "first_choice" else sum(masks)
+    aux_loss = E * jnp.sum(gates.mean(axis=0) * chosen.mean(axis=0))
 
     idx = jnp.stack(idxs, axis=1)
     w = jnp.stack(ws, axis=1)
@@ -89,7 +95,8 @@ def topk_select(logits, k: int, normalize_weights: bool = True,
 def topk_gating_compact(logits, k: int = 2, capacity_factor: float = 1.0,
                         min_capacity: int = 4, train: bool = True, rng=None,
                         noise_std: float = 0.0, normalize_weights: bool = True,
-                        drop_tokens: bool = True) -> GateCompact:
+                        drop_tokens: bool = True,
+                        aux: str = "first_choice") -> GateCompact:
     """logits [S, E] -> GateCompact: the ONE capacity-assignment rule
     (selection, buffer positions, drops, weight renormalization, aux loss).
     ``topk_gating`` densifies this into the GShard einsum contract."""
@@ -99,7 +106,8 @@ def topk_gating_compact(logits, k: int = 2, capacity_factor: float = 1.0,
     S, E = logits.shape
     # weights re-normalize AFTER capacity drops below, so take them raw here
     idx, raw_w, aux_loss, masks = topk_select(
-        logits, k, normalize_weights=False, train=train, rng=rng, noise_std=noise_std)
+        logits, k, normalize_weights=False, train=train, rng=rng,
+        noise_std=noise_std, aux=aux)
     gates = raw_w  # per-choice raw gate probabilities [S, k]
 
     capacity = compute_capacity(S, E, k, capacity_factor, min_capacity) if drop_tokens else S
@@ -141,7 +149,8 @@ def topk_gating_compact(logits, k: int = 2, capacity_factor: float = 1.0,
 
 def topk_gating(logits, k: int = 2, capacity_factor: float = 1.0, min_capacity: int = 4,
                 train: bool = True, rng=None, noise_std: float = 0.0,
-                normalize_weights: bool = True, drop_tokens: bool = True) -> GateOutput:
+                normalize_weights: bool = True, drop_tokens: bool = True,
+                aux: str = "first_choice") -> GateOutput:
     """logits [S, E] -> GateOutput. top1/top2 are k=1/2 (reference dispatch
     table moe/sharded_moe.py:587-678 calls into the same machinery).
     Densifies ``topk_gating_compact`` into the [S, E, C] einsum contract."""
@@ -152,7 +161,7 @@ def topk_gating(logits, k: int = 2, capacity_factor: float = 1.0, min_capacity: 
                              min_capacity=min_capacity, train=train, rng=rng,
                              noise_std=noise_std,
                              normalize_weights=normalize_weights,
-                             drop_tokens=drop_tokens)
+                             drop_tokens=drop_tokens, aux=aux)
     S, E = logits.shape
     combine = jnp.zeros((S, E, ca.capacity), jnp.float32)
     for j in range(k):

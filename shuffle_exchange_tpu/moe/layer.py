@@ -103,13 +103,17 @@ def expert_mlp(params, x, activation: str = "swiglu"):
 
 
 def _gather_expert_sharded(params, expert_axis: str = "expert"):
-    """GSPMD on jax 0.4.x mis-partitions ``lax.ragged_dot`` when the RHS
-    is sharded over the group (expert) dim — wrong numerics, not just a
-    slow program (observed on the 8-device CPU mesh: max err ~2.4 vs the
-    replicated reference). Under a live expert axis, pin the stacked
-    expert leaves to replicated inside the trace so XLA inserts an
-    explicit all-gather before the grouped matmuls: weights stay
-    expert-sharded at rest, the ragged math runs on the gathered copy."""
+    """Under a live expert axis, pin the stacked expert leaves to replicated
+    inside the trace so XLA inserts an explicit all-gather before the
+    grouped matmuls: weights stay expert-sharded at rest, the ragged math
+    runs on the gathered copy. Written when GSPMD on jax 0.4.x
+    mis-partitioned ``lax.ragged_dot`` with its RHS sharded over the group
+    (expert) dim (wrong numerics on the 8-device CPU mesh, max err ~2.4).
+    On jax 0.9 (the one installation this code targets) nobody has
+    re-checked whether the partitioner is right without the pin; the
+    megablox ``gmm`` kernel cannot be partitioned by XLA at all, so on a TPU
+    the gather is needed either way. It stays until a cell runs an
+    expert-parallel program (ROADMAP R1)."""
     import jax
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
@@ -131,6 +135,33 @@ def _gather_expert_sharded(params, expert_axis: str = "expert"):
         lambda v: jax.lax.with_sharding_constraint(v, rep), params)
 
 
+def _permuted_rows(x, perm, inverse, k: int = 1):
+    """x [R, M] -> [len(perm), M]: row ``perm[i] // k`` of ``x`` at position
+    i, where ``perm`` is a permutation of R * k items and ``inverse`` its
+    inverse. The backward is a GATHER by ``inverse`` and a sum over each
+    row's k copies. XLA's own transpose of the forward gather is a
+    scatter-add (with colliding rows when k > 1), which a TPU serialises:
+    33 ms of a 363 ms OLMoE step (PERF.md section 6, PR 28)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+    def rows(x, perm, inverse, k):
+        return jnp.take(x, perm // k, axis=0)
+
+    def fwd(x, perm, inverse, k):
+        return rows(x, perm, inverse, k), inverse
+
+    def bwd(k, inverse, g):
+        back = jnp.take(g, inverse, axis=0)
+        return back.reshape(-1, k, g.shape[-1]).sum(axis=1), None, None
+
+    rows.defvjp(fwd, bwd)
+    return rows(x, perm, inverse, k)
+
+
 def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu"):
     """Dropless grouped-GEMM experts (reference cutlass moe_gemm /
     megablocks, SURVEY §2.13): tokens sort by expert and one grouped matmul
@@ -143,21 +174,23 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu"):
     import jax
     import jax.numpy as jnp
 
+    from ..ops.grouped_gemm import grouped_matmul
+    from ..ops.quant_matmul import QuantizedMatrix
+    from ..profiling import trace
+
     params = _gather_expert_sharded(params)
     S, M = xs.shape
     k = topk_idx.shape[1]
     E = params["w_up"].shape[0]
-    flat_e = topk_idx.reshape(-1)                        # [S*k]
-    order = jnp.argsort(flat_e, stable=True)
-    token_of = order // k
-    xsort = jnp.take(xs, token_of, axis=0)               # [S*k, M]
-    group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
-
-    from ..ops.grouped_gemm import grouped_matmul
-    from ..ops.quant_matmul import QuantizedMatrix
-
     dtype = xs.dtype
-    e_sorted = jnp.take(flat_e, order)                   # [S*k] expert per row
+    with trace.scope("moe_dispatch"):
+        flat_e = topk_idx.reshape(-1)                        # [S*k]
+        order = jnp.argsort(flat_e, stable=True)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(S * k, dtype=order.dtype), unique_indices=True)
+        xsort = _permuted_rows(xs, order, inverse, k)        # [S*k, M]
+        group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
+        e_sorted = jnp.take(flat_e, order)                   # [S*k] expert per row
 
     def b(key, t):
         # grouped-GEMM bias epilogue: gather each row's expert bias
@@ -174,17 +207,19 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu"):
         wt = params[key]
         return wt if isinstance(wt, QuantizedMatrix) else wt.astype(dtype)
 
-    up = b("b_up", grouped_matmul(xsort, w("w_up"), group_sizes))
-    if activation == "swiglu":
-        gate = b("b_gate", grouped_matmul(xsort, w("w_gate"), group_sizes))
-        h = jax.nn.silu(gate) * up
-    else:
-        from ..models.transformer import activation_fn
+    with trace.scope("moe_experts"):
+        up = b("b_up", grouped_matmul(xsort, w("w_up"), group_sizes))
+        if activation == "swiglu":
+            gate = b("b_gate", grouped_matmul(xsort, w("w_gate"), group_sizes))
+            h = jax.nn.silu(gate) * up
+        else:
+            from ..models.transformer import activation_fn
 
-        h = activation_fn(activation)(up)
-    out_sorted = b("b_down", grouped_matmul(h, w("w_down"), group_sizes))
-    out_flat = jnp.zeros_like(out_sorted).at[order].set(out_sorted)   # unsort
-    return (out_flat.reshape(S, k, M) * topk_w[..., None].astype(dtype)).sum(axis=1)
+            h = activation_fn(activation)(up)
+        out_sorted = b("b_down", grouped_matmul(h, w("w_down"), group_sizes))
+    with trace.scope("moe_combine"):
+        out_flat = _permuted_rows(out_sorted, inverse, order)   # unsort
+        return (out_flat.reshape(S, k, M) * topk_w[..., None].astype(dtype)).sum(axis=1)
 
 
 class MoEResult(NamedTuple):
@@ -194,15 +229,22 @@ class MoEResult(NamedTuple):
 
 
 def resolve_moe_impl(impl: str, ep_size: int, scanned: bool = False) -> str:
-    """Resolve ``impl="auto"`` to a concrete dispatch path.
+    """Resolve ``impl="auto"`` to a concrete dispatch path. An explicit impl
+    passes through: a model whose source routes every token to all its k
+    experts (OLMoE) gets ``moe_impl="ragged"`` from
+    ``models/hf.config_from_hf``, since any capacity is a different function.
 
     - an expert axis > 1 -> "capacity" (the EP path; XLA inserts the
       all-to-all pair around the sharded dispatch);
     - under a scanned layer stack -> "capacity" even without an expert
-      axis: the Pallas megablox gmm ran the bench step ~4x slower inside
-      a ``lax.scan`` over stacked layer weights (5.3% vs 23.1% active-param
-      MFU on-chip, scripts/bench_moe_impl.py) — the scan context starves
-      the grouped kernel; standalone gmm is fine;
+      axis, for models that were written against GShard capacity semantics
+      (their tests pin it). It is a choice of SEMANTICS now, not of speed:
+      the July "scan cliff" (megablox gmm ~4x slower under a ``lax.scan``)
+      was the kernel's default (128, 128, 128) tile, alone and scanned
+      alike. On a v5e at 131,072 rows x 64 experts x 2048 x 1024 the nine
+      grouped GEMMs of a layer take 480 ms alone and 481 ms under a scan at
+      that tile (5% of the bf16 peak), 35.7 ms and 36.5 ms at
+      ``ops/grouped_gemm._GMM_TILE`` (70%) (chip runs of PR 28, PERF.md 6);
     - otherwise -> "ragged" (dropless grouped-GEMM).
     """
     if impl != "auto":
@@ -216,7 +258,7 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
               activation: str = "swiglu", train: bool = True, rng=None,
               noise_std: float = 0.0, min_capacity: int = 4, expert_axis: str = "expert",
               mesh=None, impl: str = "auto", normalize_weights: bool = True,
-              scanned: bool = False) -> MoEResult:
+              scanned: bool = False, aux: str = "first_choice") -> MoEResult:
     """x [..., M] -> MoEResult. gate_w [M, E].
 
     impl:
@@ -230,18 +272,23 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
         bench shapes — round-5 on-chip profile).
       - "ragged": dropless grouped-GEMM (``expert_mlp_ragged``) — no
         capacity padding FLOPs, no drops; the single-device/data-parallel
-        path (reference cutlass moe_gemm). Perf note (v5e, 2026-07, both
-        measured on-chip): under a ``lax.scan`` over stacked layer weights
-        the Pallas megablox gmm ran the bench step 2.4x SLOWER than the
-        capacity einsums (5.3% vs 12.5% active-param MFU) — measure before
-        picking ragged for a scanned stack; standalone gmm is fine.
+        path (reference cutlass moe_gemm).
       - "auto": capacity when the mesh has an expert axis > 1 OR the layer
-        runs under a scanned stack (``scanned=True`` — the model's
-        ``stack_apply`` passes it; megablox gmm measured ~4x slower there,
-        see ``resolve_moe_impl``); ragged otherwise.
+        runs under a scanned stack (``scanned=True``, see
+        ``resolve_moe_impl``); ragged otherwise.
+
+    ``aux``: which balancing loss ``aux_loss`` is (``gating.topk_select``).
+    Named scopes inside the caller's ``moe``: ``moe_router`` (router matmul,
+    softmax, top-k, aux), ``moe_dispatch`` (sort / slot assignment, gather,
+    group sizes), ``moe_experts`` (the expert matmuls and activation),
+    ``moe_combine`` (unsort, weighting, sum over k). ``metadata`` carries
+    ``expert_counts`` [E] (token-choices each expert computed) and
+    ``router_prob`` [E] (mean router probability, differentiable).
     """
     import jax
     import jax.numpy as jnp
+
+    from ..profiling import trace
 
     if impl not in ("auto", "capacity", "capacity_einsum", "ragged"):
         # validate BEFORE the dispatch chain: an unrecognized string (e.g. a
@@ -255,7 +302,11 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
     M = orig_shape[-1]
     xs = x.reshape(-1, M)
     S = xs.shape[0]
-    logits = (xs.astype(jnp.float32)) @ gate_w.astype(jnp.float32)   # [S, E]
+    with trace.scope("moe_router"):
+        logits = (xs.astype(jnp.float32)) @ gate_w.astype(jnp.float32)   # [S, E]
+        # mean router probability per expert (the gating's own softmax again:
+        # XLA computes it once)
+        prob = jax.nn.softmax(logits, axis=-1).mean(axis=0)
 
     if impl == "auto":
         # the explicit mesh argument wins; fall back to the global topology
@@ -277,23 +328,24 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
         elif ep <= 1 and scanned:
             warning_once(
                 "moe_impl=auto resolved to the capacity (index-dispatch) "
-                "path: this layer runs under a scanned stack, where the "
-                "ragged megablox grouped-GEMM measured ~4x SLOWER on-chip "
-                "(5.3% vs 23.1% active-param MFU, scripts/bench_moe_impl.py)."
-                " Capacity/drop semantics apply (capacity_factor/"
-                "min_capacity; overflow tokens drop) — set "
-                "moe_impl='ragged' to force dropless routing despite the "
-                "perf cliff")
+                "path: this layer runs under a scanned stack. "
+                "Capacity/drop semantics apply "
+                "(capacity_factor/min_capacity; overflow tokens drop) — set "
+                "moe_impl='ragged' for dropless routing (as fast under a scan "
+                "as outside one since ops/grouped_gemm tiles the kernel: "
+                "PERF.md section 6, PR 28)")
     if impl == "ragged":
         from .gating import topk_select
 
-        idx, w, aux, _ = topk_select(logits, k, normalize_weights=normalize_weights,
-                                     train=train, rng=rng, noise_std=noise_std)
+        with trace.scope("moe_router"):
+            idx, w, aux_loss, _ = topk_select(
+                logits, k, normalize_weights=normalize_weights, train=train,
+                rng=rng, noise_std=noise_std, aux=aux)
+            counts = jnp.bincount(idx.reshape(-1), length=gate_w.shape[1])
         out = expert_mlp_ragged(expert_params, xs, idx, w, activation)
-        counts = jnp.bincount(idx.reshape(-1), length=gate_w.shape[1])
-        return MoEResult(out.reshape(orig_shape), aux,
+        return MoEResult(out.reshape(orig_shape), aux_loss,
                          {"expert_counts": counts, "drop_fraction": jnp.zeros(()),
-                          "capacity": S})
+                          "capacity": S, "router_prob": prob})
 
     if impl == "capacity_einsum":
         # the GShard dense-mask contract, kept as the parity oracle: the
@@ -301,14 +353,15 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
         # 2·S·E·C·M flops EACH — ~4x the expert compute at bench shapes
         gate = topk_gating(logits, k=k, capacity_factor=capacity_factor, train=train,
                            rng=rng, noise_std=noise_std, min_capacity=min_capacity,
-                           normalize_weights=normalize_weights)
+                           normalize_weights=normalize_weights, aux=aux)
 
         dispatched = jnp.einsum("sec,sm->ecm", gate.dispatch_mask.astype(xs.dtype), xs)
         dispatched = _constrain_expert(dispatched, expert_axis, mesh)
         expert_out = expert_mlp(expert_params, dispatched, activation)
         expert_out = _constrain_expert(expert_out, expert_axis, mesh)
         combined = jnp.einsum("sec,ecm->sm", gate.combine_weights.astype(xs.dtype), expert_out)
-        return MoEResult(combined.reshape(orig_shape), gate.aux_loss, gate.metadata)
+        return MoEResult(combined.reshape(orig_shape), gate.aux_loss,
+                         {**gate.metadata, "router_prob": prob})
 
     # "capacity": same assignment/drop semantics in index form — dispatch is
     # one scalar scatter (slot -> token id) plus a row gather, combine is a
@@ -322,32 +375,37 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
     # there, set moe_impl="capacity_einsum" to restore the proven wire.
     from .gating import topk_gating_compact
 
-    ca = topk_gating_compact(logits, k=k, capacity_factor=capacity_factor,
-                             train=train, rng=rng, noise_std=noise_std,
-                             min_capacity=min_capacity,
-                             normalize_weights=normalize_weights)
+    with trace.scope("moe_router"):
+        ca = topk_gating_compact(logits, k=k, capacity_factor=capacity_factor,
+                                 train=train, rng=rng, noise_std=noise_std,
+                                 min_capacity=min_capacity,
+                                 normalize_weights=normalize_weights, aux=aux)
     E = gate_w.shape[1]
     C = ca.capacity
-    slot = ca.eidx * C + ca.loc                              # [S, k]
-    trash = E * C                                            # dropped -> trash slot
-    tgt = jnp.where(ca.kept, slot, trash)
-    token_ids = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[:, None], tgt.shape)
-    # kept slots are unique by construction (cumsum buffer positions), so
-    # the scatter never collides; empty slots keep sentinel S -> zero row
-    inv = jnp.full((E * C + 1,), S, jnp.int32).at[tgt.reshape(-1)].set(
-        token_ids.reshape(-1), mode="drop")[:E * C]
-    xs_pad = jnp.concatenate([xs, jnp.zeros((1, M), xs.dtype)], axis=0)
-    dispatched = xs_pad[inv].reshape(E, C, M)
-    dispatched = _constrain_expert(dispatched, expert_axis, mesh)
-    expert_out = expert_mlp(expert_params, dispatched, activation)
-    expert_out = _constrain_expert(expert_out, expert_axis, mesh)
-    eo = expert_out.reshape(E * C, M)
-    gath = eo[jnp.clip(slot, 0, E * C - 1)]                  # [S, k, M]
-    # ca.weights is already zero for dropped choices (the one drop-zeroing
-    # site, topk_gating_compact), so the clipped gather row is harmless
-    w = ca.weights.astype(xs.dtype)
-    combined = (w[..., None] * gath).sum(axis=1)
-    return MoEResult(combined.reshape(orig_shape), ca.aux_loss, ca.metadata)
+    with trace.scope("moe_dispatch"):
+        slot = ca.eidx * C + ca.loc                              # [S, k]
+        trash = E * C                                            # dropped -> trash slot
+        tgt = jnp.where(ca.kept, slot, trash)
+        token_ids = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[:, None], tgt.shape)
+        # kept slots are unique by construction (cumsum buffer positions), so
+        # the scatter never collides; empty slots keep sentinel S -> zero row
+        inv = jnp.full((E * C + 1,), S, jnp.int32).at[tgt.reshape(-1)].set(
+            token_ids.reshape(-1), mode="drop")[:E * C]
+        xs_pad = jnp.concatenate([xs, jnp.zeros((1, M), xs.dtype)], axis=0)
+        dispatched = xs_pad[inv].reshape(E, C, M)
+        dispatched = _constrain_expert(dispatched, expert_axis, mesh)
+    with trace.scope("moe_experts"):
+        expert_out = expert_mlp(expert_params, dispatched, activation)
+    with trace.scope("moe_combine"):
+        expert_out = _constrain_expert(expert_out, expert_axis, mesh)
+        eo = expert_out.reshape(E * C, M)
+        gath = eo[jnp.clip(slot, 0, E * C - 1)]                  # [S, k, M]
+        # ca.weights is already zero for dropped choices (the one drop-zeroing
+        # site, topk_gating_compact), so the clipped gather row is harmless
+        w = ca.weights.astype(xs.dtype)
+        combined = (w[..., None] * gath).sum(axis=1)
+    return MoEResult(combined.reshape(orig_shape), ca.aux_loss,
+                     {**ca.metadata, "router_prob": prob})
 
 
 def _constrain_expert(t, expert_axis, mesh):

@@ -9,8 +9,8 @@ Dispatch:
 - **TPU**: the Pallas megablox ``gmm`` kernel
   (``jax.experimental.pallas.ops.tpu.megablox``) — MXU-tiled, skips empty
   groups, custom VJP (dx via ``gmm(transpose_rhs)``, dw via ``tgmm``).
-  Rows are padded to the 128-row tile and billed to the last group; the
-  pad rows are sliced away by the caller's unsort.
+  Rows are padded to the row tile and billed to the last group; the
+  pad rows are sliced away by the caller's unsort. Tiles: ``_gmm_tiling``.
 - **CPU / fallback**: ``jax.lax.ragged_dot`` (also the numerics oracle).
 
 Shape contract: x [N, K] sorted by group, w [E, K, F], group_sizes [E]
@@ -60,17 +60,41 @@ def grouped_matmul(x, w, group_sizes):
     return jax.lax.ragged_dot(x, w, group_sizes)
 
 
+# (rows, contraction, output) tile of the megablox kernels, forward and
+# backward alike. The kernel's own default is (128, 128, 128): a grid step
+# then multiplies 4 MFLOP, some tens of nanoseconds of MXU work under a
+# fixed per-step cost several times that. Measured on a v5e (PR 28's chip
+# runs, PERF.md section 6) on the nine grouped GEMMs of one OLMoE layer's
+# training step (131,072 rows, 64 experts, 2048 x 1024), even groups /
+# Dirichlet(1) groups: (128, 128, 128) 480 / 508 ms, (256, 512, 512) 57 / 62,
+# (512, 512, 512) 46 / 54, (512, 1024, 512) 40 / 45, (256, 1024, 1024)
+# 40 / 43, (512, 1024, 1024) 35.7 / 41.8 ms (70% / 60% of the bf16 peak);
+# (512, 2048, 1024) and (1024, 1024, 1024) overflow VMEM at compile time.
+_GMM_TILE = (512, 1024, 1024)
+
+
+def _gmm_tiling(n_rows: int, k: int, f: int):
+    """The tile for [n_rows, k] x [E, k, f]: ``_GMM_TILE`` clipped to the
+    problem. The row tile halves until the rows fill it at least once
+    (decode batches are small); rows are then padded to a multiple of it."""
+    tm, tk, tn = _GMM_TILE
+    while tm > 128 and n_rows < tm:
+        tm //= 2
+    return tm, min(tk, k), min(tn, f)
+
+
 def _grouped_matmul_gmm(x, w, group_sizes):
     import jax.numpy as jnp
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     N = x.shape[0]
-    pad = -N % 128
+    tiling = _gmm_tiling(N, w.shape[1], w.shape[2])
+    pad = -N % tiling[0]
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
         # bill pad rows to the last group: they multiply real weights but
         # land in out[N:], which the caller slices away
         group_sizes = group_sizes.at[-1].add(pad)
     out = gmm(x, w, group_sizes.astype(jnp.int32),
-              preferred_element_type=x.dtype)
+              preferred_element_type=x.dtype, tiling=tiling)
     return out[:N] if pad else out

@@ -50,12 +50,15 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 PREFIX = "sxt:"
 
 # every scope the package opens under jit, by the layer a reader sums it to.
+# "attn_qk_norm" nests inside "attn_qkv" and the four "moe_*" scopes inside
+# "moe": a reader that sums by the outer name counts them with it.
 # "plumbing" is what belongs to no layer of the model: the layer scan's own
 # slicing and stacking, the masters' cast to the compute dtype, the
 # gradients' cast back and normalization
 SCOPES = {
-    "attn": ("attn_norm", "attn_qkv", "attn_core", "attn_out"),
-    "mlp": ("mlp_norm", "mlp", "moe"),
+    "attn": ("attn_norm", "attn_qkv", "attn_qk_norm", "attn_core", "attn_out"),
+    "mlp": ("mlp_norm", "mlp", "moe", "moe_router", "moe_dispatch",
+            "moe_experts", "moe_combine"),
     "loss": ("embed", "final_norm", "loss"),
     "optimizer": ("optimizer", "grad_clip", "weight_mix"),
     "mesh": ("zero3_gather", "zero3_reduce_scatter"),

@@ -697,9 +697,23 @@ class Engine:
         of its head to gather."""
         import jax
 
+        return self._loss_and_stats(params, batch, rng)[0]
+
+    def _loss_and_stats(self, params, batch, rng=None):
+        """``_loss`` and the small arrays the model reports beside it (its
+        ``loss_and_stats``: an MoE model's per-layer expert token counts),
+        {} for a loss function that reports none."""
+        import jax
+
+        owner = getattr(self.loss_fn, "__self__", None)
+        with_stats = getattr(owner, "loss_and_stats", None)
+        if with_stats is not None and self.loss_fn != getattr(owner, "loss", None):
+            with_stats = None
         specs = jax.tree_util.tree_map(lambda sh: sh.spec, self.master_shardings)
         with kernel_mesh(self._kernel_mesh, specs):
-            return self.loss_fn(params, batch, rng)
+            if with_stats is not None:
+                return with_stats(params, batch, rng)
+            return self.loss_fn(params, batch, rng), {}
 
     def _build_programs(self) -> None:
         import jax
@@ -907,34 +921,44 @@ class Engine:
                     fro16)
             return fro16
 
-        def scaled_loss_fn(p16, fro16, micro, rng, scale):
-            loss = self._loss(model_params(p16, fro16), micro, rng)
-            return loss * scale.astype(loss.dtype), loss
+        def scaled_loss_stats_fn(p16, fro16, micro, rng, scale):
+            loss, stats = self._loss_and_stats(model_params(p16, fro16), micro, rng)
+            return loss * scale.astype(loss.dtype), (loss, stats)
 
-        def replica_grads(p16, fro16, micro, rng, scale):
-            grad_fn = jax.grad(scaled_loss_fn, has_aux=True)
-            g, loss = grad_fn(p16, fro16, micro, rng, scale)
+        def scaled_loss_fn(p16, fro16, micro, rng, scale):
+            scaled, (loss, _) = scaled_loss_stats_fn(p16, fro16, micro, rng, scale)
+            return scaled, loss
+
+        def replica_grads_stats(p16, fro16, micro, rng, scale):
+            grad_fn = jax.grad(scaled_loss_stats_fn, has_aux=True)
+            g, (loss, stats) = grad_fn(p16, fro16, micro, rng, scale)
             with trace.scope(reduce_scope):
                 g = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), g)
-            return g, loss
+            return g, loss, stats
+
+        def replica_grads(p16, fro16, micro, rng, scale):
+            return replica_grads_stats(p16, fro16, micro, rng, scale)[:2]
 
         def batch_grads(master, frozen, p16, fro16, micro, rng, scale, step):
-            """Gradients for one microbatch; vmapped over replicas in ensemble mode."""
+            """(gradients, loss, model stats) for one microbatch; vmapped over
+            replicas in ensemble mode. Only the plain data/fsdp program
+            carries the model's stats out; the wire regions and the ensemble
+            report none ({})."""
             if ensemble:
                 if qg_real:
                     # replica-axis wire: each replica reduces over its fsdp
                     # slice group on the s8 wire (see qg_ens_batch_grads)
-                    return qg_ens_batch_grads(p16, frozen, micro, rng, scale)
+                    return (*qg_ens_batch_grads(p16, frozen, micro, rng, scale), {})
                 g, loss = jax.vmap(replica_grads, in_axes=(0, None, 0, None, None))(
                     p16, fro16, micro, rng, scale)
-                return g, jnp.mean(loss)
+                return g, jnp.mean(loss), {}
             if qz3_real:
                 # streamed wire differentiates w.r.t. the f32 master shards
                 # directly (the bf16 cast lives inside the per-leaf gather)
-                return qz3_batch_grads(master, frozen, micro, rng, scale, step)
+                return (*qz3_batch_grads(master, frozen, micro, rng, scale, step), {})
             if qg_real:
-                return qg_batch_grads(p16, frozen, micro, rng, scale)
-            return replica_grads(p16, fro16, micro, rng, scale)
+                return (*qg_batch_grads(p16, frozen, micro, rng, scale), {})
+            return replica_grads_stats(p16, fro16, micro, rng, scale)
 
         # -- shared wire-region helpers (qz3 / qg) ----------------------
         # Spec algebra for the manual regions: a leaf's PartitionSpec may
@@ -1236,17 +1260,18 @@ class Engine:
 
             def body(acc, micro_and_key):
                 micro, key = micro_and_key
-                g, loss = batch_grads(master, frozen, p16, fro16, micro, key, scale, step)
+                g, loss, stats = batch_grads(master, frozen, p16, fro16, micro, key, scale, step)
                 acc = jax.tree_util.tree_map(jnp.add, acc, g)
-                return acc, loss
+                return acc, (loss, stats)
 
             keys = jax.random.split(rng, gas)
             if gas == 1:
                 micro = jax.tree_util.tree_map(lambda x: x[0], batch)
-                g, loss = batch_grads(master, frozen, p16, fro16, micro, keys[0], scale, step)
-                return g, loss
-            acc, losses = jax.lax.scan(body, zeros, (batch, keys))
-            return acc, jnp.mean(losses)
+                return batch_grads(master, frozen, p16, fro16, micro, keys[0], scale, step)
+            acc, (losses, stats) = jax.lax.scan(body, zeros, (batch, keys))
+            # the stats are counts: a step's are its microbatches' sum
+            return acc, jnp.mean(losses), jax.tree_util.tree_map(
+                lambda x: x.sum(axis=0), stats)
 
         def apply_update(grads, opt_state, master, lr_mult=None):
             # lr_mult: dynamic-batching LR ratio (reference
@@ -1342,11 +1367,11 @@ class Engine:
             p16 = fwd_weights(state.master, mix, state.step)
             fro16 = fro16_of(state.frozen)
             scale = state.loss_scale.scale if fp16_cfg.enabled else jnp.asarray(1.0, jnp.float32)
-            grads, loss = accumulate(state.master, state.frozen, p16, fro16,
-                                     batch, rng, scale, state.step)
+            grads, loss, stats = accumulate(state.master, state.frozen, p16, fro16,
+                                            batch, rng, scale, state.step)
             new_state, overflow, grad_norm, nonfinite = update_state(
                 state, grads, loss, scale, lr_mult)
-            return new_state, loss, overflow, grad_norm, nonfinite
+            return new_state, loss, overflow, grad_norm, nonfinite, stats
 
         from ..utils.placement import cache_safe_donate_argnums
 
@@ -1370,9 +1395,9 @@ class Engine:
         def grads_only(state: TrainState, micro, mix, rng):
             p16 = fwd_weights(state.master, mix, state.step)
             scale = state.loss_scale.scale if fp16_cfg.enabled else jnp.asarray(1.0, jnp.float32)
-            g, loss = batch_grads(state.master, state.frozen, p16,
-                                  fro16_of(state.frozen), micro, rng, scale,
-                                  state.step)
+            g, loss, _ = batch_grads(state.master, state.frozen, p16,
+                                     fro16_of(state.frozen), micro, rng, scale,
+                                     state.step)
             return g, loss
 
         self._grads_only = jax.jit(grads_only)
@@ -1381,9 +1406,9 @@ class Engine:
             """Whole-batch fp32 grads w.r.t. given forward weights (the
             host-optimizer path, lora-ineligible: the update happens off
             device)."""
-            g, loss = accumulate(p16, (), p16, (), batch, rng,
-                                 jnp.asarray(1.0, jnp.float32),
-                                 jnp.asarray(0, jnp.int32))
+            g, loss, _ = accumulate(p16, (), p16, (), batch, rng,
+                                    jnp.asarray(1.0, jnp.float32),
+                                    jnp.asarray(0, jnp.int32))
             g = jax.tree_util.tree_map(lambda x: x / gas, g)
             return g, loss
 
@@ -1790,7 +1815,8 @@ class Engine:
         try:
             # long when it traces or compiles; a dispatch otherwise
             with trace.span("train/dispatch", program="train_step"):
-                self.state, loss, overflow, grad_norm, nonfinite = self._train_step(
+                (self.state, loss, overflow, grad_norm, nonfinite,
+                 self._last_step_stats) = self._train_step(
                     self.state, shaped, mix, rng, lr_mult_arr)
             if self.resilience.watchdog.timeout_s > 0:
                 # dispatch is async: the watchdog must cover device
@@ -2395,6 +2421,14 @@ class Engine:
         import jax
 
         return float(jax.device_get(norm))
+
+    def last_step_stats(self) -> dict:
+        """What the model reported beside the last ``train_batch``'s loss,
+        as device arrays (no host sync in the step): an MoE model's
+        ``moe_expert_tokens`` [L, E] int32, the token-choices each expert of
+        each layer computed. {} before the first step and for models that
+        report nothing."""
+        return dict(getattr(self, "_last_step_stats", None) or {})
 
     @property
     def train_micro_batch_size_per_gpu(self) -> int:

@@ -276,6 +276,77 @@ def test_qwen2moe_logit_parity():
     _compare(transformers.Qwen2MoeForCausalLM(cfg), _ids(96), rtol=5e-3, atol=5e-3)
 
 
+def _olmoe_model(**kw):
+    cfg = transformers.OlmoeConfig(
+        vocab_size=96, hidden_size=64, intermediate_size=48,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+        num_experts=8, num_experts_per_tok=3, norm_topk_prob=False,
+        max_position_embeddings=64, tie_word_embeddings=False,
+        attention_dropout=0.0, **kw)
+    torch.manual_seed(13)
+    model = transformers.OlmoeForCausalLM(cfg)
+    with torch.no_grad():           # the norms' gains start at 1: move them
+        for name, p in model.named_parameters():
+            if name.endswith("norm.weight"):
+                p.add_(0.1 * torch.randn_like(p))
+    return model
+
+
+def test_olmoe_logit_parity():
+    """OLMoE (allenai/OLMoE-1B-7B, ``model_type: olmoe``): RMSNorm over the
+    whole q and k projections, 8 fine-grained experts top-3 with raw (not
+    renormalised) weights, no shared expert: against transformers' own
+    forward."""
+    model = _olmoe_model()
+    cfg = config_from_hf(model.config)
+    assert (cfg.qk_norm, cfg.moe_impl, cfg.moe_aux, cfg.moe_norm_topk) == (
+        True, "ragged", "all_choices", False)
+    _compare(model, _ids(96), rtol=5e-3, atol=5e-3)
+
+
+def test_olmoe_state_dict_round_trip_and_aux_loss():
+    """The checkpoint-directory layout (config dict + state dict under the
+    source's names) imports leaf for leaf, and the trainer's loss with the
+    balancing term is transformers' ``load_balancing_loss_func`` on the same
+    positions."""
+    import jax
+
+    from shuffle_exchange_tpu.models.hf import params_from_state_dict
+
+    model = _olmoe_model(output_router_logits=True, router_aux_loss_coef=0.5)
+    sd = {k: v for k, v in model.state_dict().items()}
+    assert "model.layers.1.self_attn.q_norm.weight" in sd
+    assert "model.layers.0.mlp.experts.7.down_proj.weight" in sd
+    zoo, params = from_hf((model.config.to_dict(), sd))
+    layers = params["layers"]
+    assert layers["q_norm_w"].shape == (2, 64) and layers["k_norm_w"].shape == (2, 64)
+    assert layers["moe_gate"].shape == (2, 64, 8)
+    assert layers["moe_w_gate"].shape == (2, 8, 64, 48)
+    assert layers["moe_w_down"].shape == (2, 8, 48, 64)
+    np.testing.assert_array_equal(
+        np.asarray(layers["moe_w_up"][1, 5]),
+        sd["model.layers.1.mlp.experts.5.up_proj.weight"].numpy().T)
+    again = params_from_state_dict(sd, zoo.config, "olmoe")
+    for a, b in zip(jax.tree.leaves(again), jax.tree.leaves(
+            {k: v for k, v in params.items()})):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # HF scores all T positions and shifts; the trainer feeds T-1 positions.
+    # Hand HF the same T-1 positions with the labels the trainer uses
+    ids = _ids(96, b=2, t=17)
+    model.eval()
+    with torch.no_grad():
+        out = model(torch.tensor(ids[:, :-1]), output_router_logits=True)
+        logits = out.logits.float()
+        ce = torch.nn.functional.cross_entropy(
+            logits.reshape(-1, 96), torch.tensor(ids[:, 1:]).reshape(-1).long())
+        from transformers.models.olmoe.modeling_olmoe import load_balancing_loss_func
+
+        aux = load_balancing_loss_func(out.router_logits, 8, 3)
+    want = float(ce) + 0.5 * float(aux)
+    got = float(jax.jit(zoo.loss)(params, {"input_ids": ids}))
+    assert abs(got - want) < 2e-4 * want, (got, want, float(aux))
+
+
 def test_bert_mlm_logit_parity():
     """Encoder family (reference module_inject/containers/bert.py): post-LN
     bidirectional blocks + token types + the MLM transform head."""
