@@ -222,7 +222,6 @@ def phase_trainer(meter: CompileMeter, model_cfg, *, model_name: str,
 
     import shuffle_exchange_tpu as sxt
     from shuffle_exchange_tpu.models import Transformer
-    from shuffle_exchange_tpu.ops.dispatch import pallas_enabled
     from shuffle_exchange_tpu.runtime.resilience import uninstall_preemption_hook
 
     mark = meter.mark()
@@ -253,8 +252,7 @@ def phase_trainer(meter: CompileMeter, model_cfg, *, model_name: str,
         "phase": "trainer", "model": model_name, "reduced": reduced,
         "params": n_params, "seq": seq, "batch": batch, "dtype": "bf16",
         "zero_stage": engine.zero_stage, "optimizer": "FusedAdam",
-        "routes": {"attention": attention_route(model_cfg, seq),
-                   "fused_adamw": "pallas" if pallas_enabled() else "xla"},
+        "routes": {"attention": attention_route(model_cfg, seq)},
         "losses": losses, "reference_loss_f32": ref,
         "first_loss_abs_err": abs(losses[0] - ref),
         "resume": {"old_engine": next_old, "fresh_engine": next_new},

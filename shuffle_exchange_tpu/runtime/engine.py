@@ -413,11 +413,7 @@ class Engine:
         else:
             if config.optimizer is None:
                 raise ConfigError("Provide an optimizer: config 'optimizer' section or a client optax transformation")
-            # leaf specs: a Pallas update rule runs on each device's shard
-            # (ensemble replicas vmap the update; their kernels stay unwrapped)
-            self.tx = build_optimizer(
-                config.optimizer, self.lr_schedule, config.gradient_clipping,
-                leaf_specs=None if self.ensemble else master_specs)
+            self.tx = build_optimizer(config.optimizer, self.lr_schedule, config.gradient_clipping)
 
         def init_opt(m):
             if self.ensemble:
@@ -1277,34 +1273,16 @@ class Engine:
             # lr_mult: dynamic-batching LR ratio (reference
             # lr_scheduler_for_variable_batch_size) — the final optax update
             # is linear in lr, so scaling the update IS scaling the lr.
-            def scale_updates(updates):
-                if lr_mult is None:
-                    return updates
-                return jax.tree_util.tree_map(
-                    lambda u: u * lr_mult.astype(u.dtype), updates)
-
-            if ensemble:
-                def upd(g, o, m):
-                    updates, new_o = self.tx.update(g, o, m)
-                    updates = scale_updates(updates)
-                    return jax.tree_util.tree_map(lambda a, u: a + u, m, updates), new_o
-
-                return jax.vmap(upd)(grads, opt_state, master)
-            with kernel_mesh(self._kernel_mesh):
-                updates, new_o = self.tx.update(grads, opt_state, master)
-            updates = scale_updates(updates)
             import optax
 
-            return optax.apply_updates(master, updates), new_o
+            def upd(g, o, m):
+                updates, new_o = self.tx.update(g, o, m)
+                if lr_mult is not None:
+                    updates = jax.tree_util.tree_map(
+                        lambda u: u * lr_mult.astype(u.dtype), updates)
+                return optax.apply_updates(m, updates), new_o
 
-        def scoped(name, fn):
-            def inner(*args, **kwargs):
-                with trace.scope(name):
-                    return fn(*args, **kwargs)
-
-            return inner
-
-        apply_update = scoped("optimizer", apply_update)
+            return (jax.vmap(upd) if ensemble else upd)(grads, opt_state, master)
 
         # Non-finite sentinel (resilience layer, beyond the fp16 overflow
         # skip): "skip" folds the guard into the jitted step — the bad
@@ -1318,50 +1296,64 @@ class Engine:
         reduce_scope = ("zero3_reduce_scatter" if self.zero_stage == 3
                         else "grad_normalize")
 
+        def apply_grads(state, grads, denom, guard, lr_mult=None, emulate_wire=False):
+            """Everything after the gradients, written once: normalize ->
+            overflow -> the optimizer's update -> select -> loss scale ->
+            step. ``guard(grads, overflow) -> (skip, report)`` sees the
+            normalized gradients: a true ``skip`` leaves master, optimizer
+            state and step as they were; ``report`` is handed back."""
+            with trace.scope("optimizer"):
+                # normalize: mean over the microbatches + undo loss scale. On
+                # the default ZeRO path this is where the gradients take the
+                # masters' sharding, so XLA's reduce-scatter lands on these ops
+                with trace.scope(reduce_scope):
+                    grads = jax.tree_util.tree_map(lambda g: g / denom, grads)
+                if emulate_wire:
+                    grads = jax.tree_util.tree_map(
+                        lambda g: quantize_dequantize(g, group_size=cfg.zeropp.group_size), grads)
+                overflow = ls.check_overflow(grads) if fp16_cfg.enabled else jnp.asarray(False)
+                skip, report = guard(grads, overflow)
+                new_master, new_opt = apply_update(grads, state.opt_state, state.master, lr_mult)
+                new_master = _tree_select(skip, state.master, new_master)
+                new_opt = _tree_select(skip, state.opt_state, new_opt)
+                new_scale = ls.update(state.loss_scale, overflow, fp16_cfg)
+                new_state = TrainState(master=new_master, opt_state=new_opt, loss_scale=new_scale,
+                                       step=state.step + jnp.where(skip, 0, 1).astype(jnp.int32),
+                                       frozen=state.frozen)
+            return new_state, overflow, report
+
         def update_state(state, grads, loss, scale, lr_mult):
-            """Everything after the gradients: normalize, guards, the
-            optimizer's update and the new state."""
-            # normalize: mean over gas microbatches + undo loss scale. On the
-            # default ZeRO path this is where the gradients take the masters'
-            # sharding, so XLA's reduce-scatter lands on these ops
+            """The fused train_batch program's update: adds the gradient
+            norm, the non-finite policy and the dynamic-batching lr."""
             denom = scale * gas
             if prescale and predivide != 1.0:
                 denom = denom * predivide
-            with trace.scope(reduce_scope):
-                grads = jax.tree_util.tree_map(lambda g: g / denom, grads)
-            if qg and not (qg_real or qz3_real):
-                # numerics emulation only (see qg_real above for the wire
-                # path; the stage-3 streamed wire already carried its own
-                # rounding — no second round-trip on top)
-                grads = jax.tree_util.tree_map(
-                    lambda g: quantize_dequantize(g, group_size=cfg.zeropp.group_size), grads)
-            overflow = ls.check_overflow(grads) if fp16_cfg.enabled else jnp.asarray(False)
-            with trace.scope("grad_clip"):
-                grad_norm = jnp.sqrt(sum(jnp.vdot(g, g) for g in jax.tree_util.tree_leaves(grads))).real
-            # "beyond the fp16 overflow skip": an overflow already has its
-            # own handling (skip + halve the loss scale) — it must not look
-            # like a non-finite step, or rollback/raise policies would
-            # treat every routine dynamic-loss-scale overflow as fatal.
-            nonfinite = (jnp.logical_not(jnp.isfinite(loss) & jnp.isfinite(grad_norm))
-                         & jnp.logical_not(overflow)
-                         if nonfinite_guard else jnp.asarray(False))
-            bad = (overflow | nonfinite) if skip_nonfinite else overflow
+
+            def guard(grads, overflow):
+                with trace.scope("grad_clip"):
+                    grad_norm = jnp.sqrt(sum(jnp.vdot(g, g) for g in jax.tree_util.tree_leaves(grads))).real
+                # "beyond the fp16 overflow skip": an overflow already has its
+                # own handling (skip + halve the loss scale) — it must not look
+                # like a non-finite step, or rollback/raise policies would
+                # treat every routine dynamic-loss-scale overflow as fatal.
+                nonfinite = (jnp.logical_not(jnp.isfinite(loss) & jnp.isfinite(grad_norm))
+                             & jnp.logical_not(overflow)
+                             if nonfinite_guard else jnp.asarray(False))
+                bad = (overflow | nonfinite) if skip_nonfinite else overflow
+                return bad, (grad_norm, nonfinite)
+
             # lr_mult only participates when dynamic batching is live — the
             # common path skips the O(params) update rescale entirely
             # (_build_programs runs after the dyn-plan setup, so this is a
-            # trace-time constant).
-            new_master, new_opt = apply_update(
-                grads, state.opt_state, state.master,
-                lr_mult if self._dyn_plan is not None else None)
-            new_master = _tree_select(bad, state.master, new_master)
-            new_opt = _tree_select(bad, state.opt_state, new_opt)
-            new_scale = ls.update(state.loss_scale, overflow, fp16_cfg)
-            new_state = TrainState(master=new_master, opt_state=new_opt, loss_scale=new_scale,
-                                   step=state.step + jnp.where(bad, 0, 1).astype(jnp.int32),
-                                   frozen=state.frozen)
+            # trace-time constant). emulate_wire: numerics emulation only
+            # (see qg_real above for the wire path; the stage-3 streamed
+            # wire already carried its own rounding — no second round-trip
+            # on top)
+            new_state, overflow, (grad_norm, nonfinite) = apply_grads(
+                state, grads, denom, guard,
+                lr_mult=lr_mult if self._dyn_plan is not None else None,
+                emulate_wire=bool(qg and not (qg_real or qz3_real)))
             return new_state, overflow, grad_norm, nonfinite
-
-        update_state = scoped("optimizer", update_state)
 
         def train_step(state: TrainState, batch, mix, rng, lr_mult):
             p16 = fwd_weights(state.master, mix, state.step)
@@ -1415,17 +1407,12 @@ class Engine:
         self._grads_batch = jax.jit(grads_batch)
 
         def apply_only(state: TrainState, grads, n_micro):
+            """The staged forward/backward/step path's update: the fp16
+            overflow skip only."""
             scale = state.loss_scale.scale if fp16_cfg.enabled else jnp.asarray(1.0, jnp.float32)
-            denom = scale * n_micro
-            grads = jax.tree_util.tree_map(lambda g: g / denom, grads)
-            overflow = ls.check_overflow(grads) if fp16_cfg.enabled else jnp.asarray(False)
-            new_master, new_opt = apply_update(grads, state.opt_state, state.master)
-            new_master = _tree_select(overflow, state.master, new_master)
-            new_opt = _tree_select(overflow, state.opt_state, new_opt)
-            new_scale = ls.update(state.loss_scale, overflow, fp16_cfg)
-            return TrainState(new_master, new_opt, new_scale,
-                              state.step + jnp.where(overflow, 0, 1).astype(jnp.int32),
-                              state.frozen), overflow
+            new_state, overflow, _ = apply_grads(
+                state, grads, scale * n_micro, lambda grads, overflow: (overflow, None))
+            return new_state, overflow
 
         self._apply_only = jax.jit(apply_only, donate_argnums=donate)
 

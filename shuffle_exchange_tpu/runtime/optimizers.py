@@ -4,10 +4,11 @@ Capability parity with the reference's ``runtime/engine.py:1473``
 (_configure_basic_optimizer): the same ``optimizer.type`` names a reference
 JSON uses (Adam/AdamW/FusedAdam variants, Lamb, Lion, SGD, Adagrad, Muon,
 and the 1-bit family OnebitAdam/ZeroOneAdam/OnebitLamb — see
-``runtime/onebit.py`` for the compressed-momentum update rules). Fused CUDA
-kernels (FusedAdamBuilder etc., §2.13) map to the Pallas fused optimizer in
-``ops/fused_adam.py`` which the engine swaps in for flat-sharded states; the
-optax path here is the reference implementation.
+``runtime/onebit.py`` for the compressed-momentum update rules). The
+reference's Fused*/CPU* names (FusedAdamBuilder etc., §2.13) are accepted as
+names and build the same optax rule as the plain one on every backend: the
+update is elementwise, XLA fuses it on each leaf's own shape and shard, and
+the engine's donated train step makes it in place.
 """
 
 from __future__ import annotations
@@ -29,14 +30,11 @@ def _split_wd(params_fn: Optional[Callable] = None):
 
 
 def build_optimizer(optimizer_config, lr_schedule, gradient_clipping: float = 0.0,
-                    weight_decay_mask: Optional[Any] = None,
-                    leaf_specs: Optional[Any] = None) -> optax.GradientTransformation:
+                    weight_decay_mask: Optional[Any] = None) -> optax.GradientTransformation:
     """Build the optax chain: [clip_by_global_norm] -> update rule (lr = schedule).
 
     Loss-scale unscaling and overflow skipping are handled by the engine
     around this transformation (they need the loss-scale state).
-    ``leaf_specs``: PartitionSpec per parameter leaf, for update rules that
-    run as a Pallas kernel on each device's shard (``ops/fused_adam.py``).
     """
     if optimizer_config is None:
         raise ConfigError("No optimizer section in config and no client optimizer provided")
@@ -72,17 +70,7 @@ def build_optimizer(optimizer_config, lr_schedule, gradient_clipping: float = 0.
     elif lowered in ("adam", "fusedadam", "cpuadam", "adamw"):
         # reference FusedAdam/DeepSpeedCPUAdam both default adam_w_mode=True
         adam_w_mode = params.pop("adam_w_mode", lowered in ("adamw", "fusedadam", "cpuadam"))
-        from ..ops.dispatch import pallas_enabled
-
-        if lowered == "fusedadam" and adam_w_mode and weight_decay_mask is None and pallas_enabled():
-            # The reference's FusedAdamBuilder multi-tensor CUDA kernel
-            # (ops/adam/fused_adam.py:15) maps to the Pallas fused pass: one
-            # HBM read/write of p/m/v per step instead of optax's op chain.
-            from ..ops.fused_adam import pallas_adamw
-
-            tx = pallas_adamw(schedule, b1=b1, b2=b2, eps=eps, weight_decay=wd,
-                              leaf_specs=leaf_specs)
-        elif adam_w_mode or lowered == "adamw":
+        if adam_w_mode or lowered == "adamw":
             tx = optax.adamw(schedule, b1=b1, b2=b2, eps=eps, weight_decay=wd, mask=weight_decay_mask)
         else:
             tx = optax.adam(schedule, b1=b1, b2=b2, eps=eps)

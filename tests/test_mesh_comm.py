@@ -204,41 +204,3 @@ def test_shard_kernel_inside_a_partial_manual_region_takes_the_rest(devices8):
     assert seen["wrapped"]
     np.testing.assert_allclose(np.asarray(got), np.asarray(_rms(x, w)),
                                rtol=1e-6, atol=1e-6)
-
-
-def test_pallas_adamw_runs_per_shard_with_leaf_specs(devices8):
-    """The fused-AdamW transformation with the engine's leaf specs, inside a
-    mesh-wide jit: every device updates its own shard and the result is the
-    unsharded update. (On the CPU ``fused_adamw_update`` takes its jnp
-    branch; the plumbing around it is what a chip cannot rehearse.)"""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding
-    from jax.sharding import PartitionSpec as P
-
-    from shuffle_exchange_tpu.ops.fused_adam import pallas_adamw
-    from shuffle_exchange_tpu.parallel.mesh import kernel_mesh
-
-    topo = MeshTopology.build(MeshConfig(data=2, fsdp=4), devices=devices8)
-    rng = np.random.default_rng(2)
-    params = {"w": jnp.asarray(rng.standard_normal((16, 24)), jnp.float32),
-              "b": jnp.asarray(rng.standard_normal((24,)), jnp.float32)}
-    grads = jax.tree.map(lambda p: p * 0.1 + 0.01, params)
-    specs = {"w": P(("data", "fsdp"), None), "b": P(None)}
-    plain = pallas_adamw(1e-2, weight_decay=0.1)
-    want, _ = plain.update(grads, plain.init(params), params)
-
-    tx = pallas_adamw(1e-2, weight_decay=0.1, leaf_specs=specs)
-
-    def step(g, state, p):
-        with kernel_mesh(topo.mesh):
-            return tx.update(g, state, p)
-
-    place = lambda t: jax.tree.map(
-        lambda x, s: jax.device_put(x, NamedSharding(topo.mesh, s)), t, specs)
-    got, new_state = jax.jit(step)(place(grads), tx.init(place(params)),
-                                   place(params))
-    for k in params:
-        np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]),
-                                   rtol=1e-6, atol=1e-7)
-    assert int(new_state.count) == 1

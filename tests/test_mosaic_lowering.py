@@ -275,14 +275,6 @@ def test_rmsnorm_lowers():
     _tpu_lower(jax.grad(lambda x, w: rmsnorm(x, w).sum(), argnums=(0, 1)), x, w)
 
 
-def test_fused_adam_lowers():
-    from shuffle_exchange_tpu.ops.fused_adam import fused_adamw_update
-
-    p = jnp.zeros((1000, 300), jnp.float32)
-    _tpu_lower(lambda p, g, m, v: fused_adamw_update(
-        p, g, m, v, lr=1e-2, weight_decay=0.1, step=3), p, p, p, p)
-
-
 def test_grouped_gemm_lowers():
     from shuffle_exchange_tpu.ops.grouped_gemm import _grouped_matmul_gmm
 
@@ -520,23 +512,6 @@ def test_rmsnorm_compiles(chip_compile, rows):
     chip_compile(jax.value_and_grad(
         lambda x, w: _rmsnorm_vjp(x, w, 1e-5).sum(), argnums=(0, 1)),
         (rows + (D,), _F32), ((D,), _F32))
-
-
-@pytest.mark.parametrize("shape", [(GPT2["V"], GPT2["D"]),
-                                   (LLAMA["V"] // 4, LLAMA["D"])],
-                         ids=["gpt2-embed", "llama-embed-fsdp4-shard"])
-def test_fused_adamw_compiles(chip_compile, monkeypatch, shape):
-    """The largest leaf the optimizer sees. ``fused_adamw_update`` asks
-    ``pallas_enabled()`` which backend runs; here that is the CPU, so the
-    test answers for it."""
-    from shuffle_exchange_tpu.ops import dispatch
-    from shuffle_exchange_tpu.ops.fused_adam import fused_adamw_update
-
-    monkeypatch.setattr(dispatch, "pallas_enabled", lambda: True)
-    leaf = (shape, _F32)
-    chip_compile(lambda p, g, m, v: fused_adamw_update(
-        p, g, m, v, lr=1e-3, weight_decay=0.1, step=3),
-        leaf, leaf, leaf, leaf, donate=(0, 2, 3))
 
 
 def test_grouped_gemm_fwd_bwd_compiles(chip_compile):

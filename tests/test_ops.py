@@ -42,73 +42,6 @@ def test_gqa_equals_repeated_mha():
     np.testing.assert_allclose(np.asarray(out_gqa), np.asarray(out_full), rtol=1e-5)
 
 
-def test_fused_adam_reference_matches_optax():
-    import jax
-    import jax.numpy as jnp
-    import optax
-
-    from shuffle_exchange_tpu.ops.fused_adam import _reference_update
-
-    rng = np.random.default_rng(0)
-    p = jnp.asarray(rng.normal(size=(64,)), jnp.float32)
-    g = jnp.asarray(rng.normal(size=(64,)), jnp.float32)
-    m = jnp.zeros((64,), jnp.float32)
-    v = jnp.zeros((64,), jnp.float32)
-    lr, wd = 1e-2, 0.1
-    new_p, new_m, new_v = _reference_update(p, g, m, v, lr=lr, b1=0.9, b2=0.999,
-                                            eps=1e-8, weight_decay=wd, step=1)
-    tx = optax.adamw(lr, b1=0.9, b2=0.999, eps=1e-8, weight_decay=wd)
-    state = tx.init(p)
-    updates, _ = tx.update(g, state, p)
-    expected = optax.apply_updates(p, updates)
-    np.testing.assert_allclose(np.asarray(new_p), np.asarray(expected), rtol=1e-5, atol=1e-7)
-
-
-def test_pallas_adamw_reads_the_schedule_like_optax():
-    """``FusedAdam`` is optax.adamw off a TPU and ``pallas_adamw`` on one:
-    the two must read a learning-rate schedule at the same step. (The first
-    chip run found the fused form one step ahead: with a warm-up the first
-    update was lr(1) on the chip and lr(0) = 0 on the CPU.)"""
-    import jax
-    import jax.numpy as jnp
-    import optax
-
-    from shuffle_exchange_tpu.ops.fused_adam import pallas_adamw
-
-    sched = optax.linear_schedule(0.0, 1e-2, transition_steps=2)
-    rng = np.random.default_rng(0)
-    p = {"w": jnp.asarray(rng.normal(size=(8, 16)), jnp.float32)}
-    ours, theirs = pallas_adamw(sched, weight_decay=0.1), optax.adamw(
-        sched, weight_decay=0.1)
-    so, st, po, pt = ours.init(p), theirs.init(p), p, p
-    for step in range(4):
-        g = jax.tree.map(lambda x: jnp.sin(x + step), po)
-        uo, so = ours.update(g, so, po)
-        ut, st = theirs.update(g, st, pt)
-        if step == 0:
-            assert float(jnp.abs(uo["w"]).max()) == 0.0      # lr(0) == 0
-        # the fused form returns new_p - p: a few f32 ulps of |p| apart
-        np.testing.assert_allclose(np.asarray(uo["w"]), np.asarray(ut["w"]),
-                                   rtol=1e-5, atol=5e-7)
-        po, pt = optax.apply_updates(po, uo), optax.apply_updates(pt, ut)
-
-
-def test_pallas_adamw_transformation_trains():
-    import jax.numpy as jnp
-
-    import shuffle_exchange_tpu as sxt
-    from shuffle_exchange_tpu.ops.fused_adam import pallas_adamw
-    from tests.test_engine import _batch, _toy_model
-
-    engine, *_ = sxt.initialize(model=_toy_model(), config={"train_batch_size": 32},
-                                optimizer=pallas_adamw(1e-2, weight_decay=0.01))
-    batch = _batch()
-    l0 = float(engine.train_batch(batch))
-    for _ in range(10):
-        l1 = float(engine.train_batch(batch))
-    assert l1 < l0
-
-
 def test_int8_quant_roundtrip():
     import jax.numpy as jnp
 
