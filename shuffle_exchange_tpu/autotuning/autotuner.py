@@ -57,7 +57,7 @@ def _hbm_bytes_per_device(default: int = 16 * 1024**3) -> int:
 def estimate_step_memory(n_params: int, *, mbs: int, seq_len: int,
                          d_model: int, n_layers: int, vocab_size: int,
                          zero_stage: int, world: int, remat: bool,
-                         loss_chunk: int = 256, tensor: int = 1,
+                         loss_chunk: int = -1, tensor: int = 1,
                          seq_par: int = 1,
                          offload: Optional[str] = None) -> int:
     """First-principles peak-HBM estimate (bytes) for one fused train step.
@@ -66,7 +66,9 @@ def estimate_step_memory(n_params: int, *, mbs: int, seq_len: int,
     (``autotuning/autotuner.py`` model_info path) with TPU specifics: bf16
     forward weights + fp32 master/m/v (ZeRO-sharded over ``world`` when
     stage >= 1), activations ~ per-layer residual+ffn working set (halved
-    by remat to the saved-dots set), chunked-CE logits block. ``tensor``
+    by remat to the saved-dots set), chunked-CE logits block (``loss_chunk``
+    as the model's: positions a chunk, 0 = full logits, < 0 = the model's own
+    rule, which sizes a chunk by its rows under a byte budget). ``tensor``
     divides param/activation terms (mp_size); ``seq_par`` divides only the
     token-dependent terms (activations/logits — params replicate across the
     seq axis); ``offload`` = "cpu"/"nvme" moves master+moments off device
@@ -83,7 +85,12 @@ def estimate_step_memory(n_params: int, *, mbs: int, seq_len: int,
     # activation working set per layer: attn qkv+out (4d) + ffn (~8d) in bf16
     act_per_layer = tokens * d_model * 12 * _BF16 // tensor
     acts = act_per_layer * (2 if remat else n_layers)
-    logits = tokens * vocab_size * _F32 if not loss_chunk else mbs * loss_chunk * vocab_size * _F32
+    if loss_chunk < 0:
+        from ..models.transformer import auto_loss_chunk
+
+        loss_chunk = auto_loss_chunk(mbs, seq_len // seq_par,
+                                     vocab_size + -vocab_size % 128)
+    logits = (mbs * loss_chunk if loss_chunk else tokens) * vocab_size * _F32
     return master_opt + fwd_params + grads + acts + logits
 
 
@@ -272,7 +279,8 @@ class Autotuner:
             n_params, mbs=c.micro_batch_size, seq_len=c.seq_len or self.seq_len,
             d_model=mcfg.d_model, n_layers=mcfg.n_layers, vocab_size=mcfg.vocab_size,
             zero_stage=c.zero_stage, world=self.world // (c.tensor * c.seq_par),
-            remat=remat, tensor=c.tensor, seq_par=c.seq_par, offload=c.offload)
+            remat=remat, loss_chunk=mcfg.loss_chunk, tensor=c.tensor,
+            seq_par=c.seq_par, offload=c.offload)
 
     # -- measurement ---------------------------------------------------
 

@@ -108,9 +108,11 @@ class TransformerConfig:
     moe_layer_pattern: Tuple[bool, ...] = ()
     attention_impl: str = "auto"
     # Chunked vocab CE (reference FPDT chunked logits loss,
-    # sequence/fpdt_layer.py:1137): compute the loss in seq chunks under
-    # remat so [B, T, vocab] logits are never materialized. 0 = full logits;
-    # -1 = auto (chunk when T * vocab is large enough to matter).
+    # sequence/fpdt_layer.py:1137): compute the loss, and its gradient in the
+    # same pass, in seq chunks so [B, T, vocab] logits are never
+    # materialized. 0 = full logits; > 0 = positions a chunk; -1 = auto (chunk
+    # when the full float32 logits pass LOSS_CHUNK_BYTES, in chunks whose
+    # ROWS, batch x positions, stay under it).
     loss_chunk: int = -1
     # Pad the chunked-loss unembed to a 128-multiple vocab (MXU lane tile)
     # with -1e30-masked pad columns. None = auto (TPU, unaligned vocab only).
@@ -1125,52 +1127,73 @@ class Transformer:
 
         return jax.default_backend() == "tpu"
 
-    def chunked_loss(self, params, x, labels, chunk: int):
+    def chunked_loss(self, params, x, labels, chunk: Optional[int] = None):
         """Final-norm + unembed + CE, streamed over seq chunks of ``chunk``
-        tokens under remat: peak logits memory is [B, chunk, vocab] instead
-        of [B, T, vocab] (the dominant activation for big-vocab models).
-        Numerically identical to head()+token_loss() — softmax is per-token,
-        and when the vocab is padded to the 128 lane tile (``_pad_vocab``)
-        the pad columns carry a -1e30 additive mask, so their softmax mass
-        underflows to exactly zero.
+        positions (None: sized by the rows at hand, ``_loss_chunk``): peak
+        logits memory is [B, chunk, vocab] instead of [B, T, vocab] (the
+        dominant activation for big-vocab models). Numerically identical to
+        head()+token_loss(): softmax is per-token, and when the vocab is
+        padded to the 128 lane tile (``_pad_vocab``) the pad columns carry a
+        -1e30 additive mask, so their softmax mass underflows to exactly zero.
+
+        Under ``jax.grad`` the scan that computes a chunk's loss computes its
+        gradient too, from the same logits (``_head_scan``): three matmuls a
+        chunk, nothing recomputed, no second scan. The unembed's gradient
+        sums over the chunks in a float32 carry and leaves through a float32
+        stand-in for the leaf (``parallel.mesh.grad_accumulator``), which
+        casts it ONCE to the leaf's dtype.
 
         In an engine's program whose batch is split over ZeRO axes
         (``parallel.mesh.zero_batch_axes``) the scan runs in a region that is
         manual over those axes: the weights its body closes over (final norm,
         unembed or tied embedding, bias) are gathered by hand ONCE before the
         scan, each device scans its own rows against a whole head, the
-        backward scan's carry is the device's unreduced gradient, and one
-        reduce-scatter per weight follows the scan. Left to XLA's partitioner
-        the gather and the reduction sit in the loop's body, once a chunk
-        (47% of a four-chip ZeRO-3 step, PERF.md PR 27). The sums are then a
-        per-device sum and one psum: the same additions in another order.
+        carries are the device's unreduced gradient, and one reduce-scatter
+        per weight follows the scan. Left to XLA's partitioner the gather and
+        the reduction sit in the loop's body, once a chunk (47% of a four-chip
+        ZeRO-3 step, PERF.md PR 27). The sums are then a per-device sum and
+        one psum: the same additions in another order. The region is a
+        wrapper: the body is the one-device body.
         Reference capability: chunked logits loss, sequence/fpdt_layer.py:1137.
         """
+        return self._chunked_loss(params, x, labels, chunk)[:2]
+
+    def _chunked_loss(self, params, x, labels, chunk):
+        """:meth:`chunked_loss` and, third, (chunks, rows a chunk) of the
+        scan as the device that runs it sees them."""
+        from jax.sharding import PartitionSpec
+
         from ..parallel import mesh as mesh_lib
 
         unembed = "embed" if self.config.tie_embeddings else "unembed"
         head = {k: params[k] for k in ("ln_f_w", "ln_f_b", unembed, "unembed_b")
                 if k in params}
-
-        zero_axes = mesh_lib.zero_batch_axes(x.shape[0])
-        if not zero_axes:
-            return self._chunked_loss_scan(head, x, labels, chunk)
+        scanned = []        # noted while the scan is traced, in the region or not
 
         def scan(head, grad_acc, x, labels):
-            return self._chunked_loss_scan(head, x, labels, chunk, grad_acc)
+            *sums, shape = self._chunked_loss_scan(head, grad_acc, x, labels, chunk)
+            scanned.append(shape)
+            return tuple(sums)
 
-        return mesh_lib.zero_region(scan, (unembed,), zero_axes)(head, x, labels)
+        zero_axes = mesh_lib.zero_batch_axes(x.shape[0])
+        if zero_axes:
+            sums = mesh_lib.zero_region(scan, (unembed,), zero_axes)(head, x, labels)
+        else:
+            # no axis to gather over: the stand-in only casts the sum
+            whole = mesh_lib.grad_accumulator(head[unembed], PartitionSpec(), ())
+            sums = scan(head, {unembed: whole}, x, labels)
+        return (*sums, scanned[0])
 
-    def _chunked_loss_scan(self, params, x, labels, chunk: int, grad_acc=None):
+    def _chunked_loss_scan(self, params, grad_acc, x, labels, chunk):
         """:meth:`chunked_loss` on whole weights and the rows at hand.
-        ``grad_acc`` (in the ZeRO region): float32 stand-ins for the unembed's
-        leaf, to whose cotangent the body sends the weight's gradient, so the
-        backward scan sums it chunk after chunk in float32."""
-        import jax
+        ``grad_acc``: a float32 stand-in for the unembed's leaf, to whose
+        cotangent the scan's float32 sum of the weight's gradient goes."""
         import jax.numpy as jnp
 
         cfg = self.config
         B, T, D = x.shape
+        if chunk is None:
+            chunk = self._loss_chunk(B, T) or T
         n_chunks = -(-T // chunk)
         pad = n_chunks * chunk - T
         if pad:
@@ -1183,57 +1206,41 @@ class Transformer:
         # via the same _unembed as head()): the scan body sees an aligned
         # [D, Vp] matmul; pad columns carry a -1e30 additive mask.
         V = cfg.vocab_size
-        vpad = (-V % 128) if self._pad_vocab() else 0
+        vpad = self._vocab_pad()
         w, bias = self._unembed(params, x.dtype)
-        w_acc = None
-        if grad_acc is not None:
-            w_acc = (grad_acc["embed"].T if cfg.tie_embeddings
-                     else grad_acc["unembed"])
+        w_acc = (grad_acc["embed"].T if cfg.tie_embeddings
+                 else grad_acc["unembed"])
         extra = None
         if vpad:
             w = jnp.pad(w, ((0, 0), (0, vpad)))
-            if w_acc is not None:
-                w_acc = jnp.pad(w_acc, ((0, 0), (0, vpad)))
+            w_acc = jnp.pad(w_acc, ((0, 0), (0, vpad)))
             extra = jnp.where(jnp.arange(V + vpad) < V, 0.0, -1e30
                               ).astype(jnp.float32)
             if bias is not None:
                 extra = extra + jnp.pad(bias, (0, vpad))
         elif bias is not None:
             extra = bias
+        nll_sum, cnt = _head_scan(
+            cfg.norm, cfg.norm_eps, bias is not None,
+            params["ln_f_w"], params["ln_f_b"], w, w_acc, extra, xc, lc)
+        return nll_sum, cnt, (n_chunks, B * chunk)
 
-        @jax.checkpoint
-        def body(carry, xl):
-            xch, lch = xl
-            with trace.scope("final_norm"):
-                xn = _norm(xch, params["ln_f_w"], params["ln_f_b"], cfg.norm,
-                           eps=cfg.norm_eps)
-            if w_acc is None:
-                logits = jnp.matmul(xn, w, preferred_element_type=jnp.float32)
-            else:
-                logits = _matmul_f32_grad(xn, w, w_acc)
-            if extra is not None:
-                logits = logits + extra
-            nll, cnt = self.token_loss(logits, lch)
-            nll_sum, cnt_sum = carry
-            return (nll_sum + nll, cnt_sum + cnt), None
-
-        (nll_sum, cnt), _ = jax.lax.scan(
-            body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)), (xc, lc))
-        return nll_sum, cnt
+    def _vocab_pad(self) -> int:
+        """Columns the chunked loss adds to reach the 128 lane tile."""
+        return -self.config.vocab_size % 128 if self._pad_vocab() else 0
 
     def _loss_chunk(self, B: int, T: int) -> int:
-        """Resolved chunk size: 0 = full logits."""
+        """Positions a chunk for a loss over ``B`` sequences of ``T``
+        positions; 0 = full logits. ``loss_chunk`` >= 0 is that, in
+        positions; auto sizes the chunk by its rows (``auto_loss_chunk``)."""
         if self.config.post_ln or self.config.mlm_head:
             # chunked_loss runs ln_f + plain unembed per chunk; the encoder
             # head shape (no final norm / MLM transform) isn't wired there
             return 0
         c = self.config.loss_chunk
         if c >= 0:
-            return 0 if c == 0 else min(c, T)
-        # auto: chunk when the full fp32 logits would exceed ~256MB
-        if B * T * self.config.vocab_size * 4 <= 256 * 1024 * 1024:
-            return 0
-        return min(256, T)
+            return min(c, T)
+        return auto_loss_chunk(B, T, self.config.vocab_size + self._vocab_pad())
 
     # -- forward -------------------------------------------------------
 
@@ -1256,10 +1263,12 @@ class Transformer:
 
     def loss_and_stats(self, params, batch, rng=None):
         """``loss`` and what the step reports beside it: a dict of small
-        arrays, empty for a dense model. An MoE model gives
-        ``moe_expert_tokens`` [L, E] int32, the token-choices each expert of
-        each layer computed on this batch (the engine keeps the last step's:
-        ``Engine.last_step_stats``)."""
+        int32 arrays, counts that add up over microbatches (the engine keeps
+        the last step's: ``Engine.last_step_stats``). An MoE model gives
+        ``moe_expert_tokens`` [L, E], the token-choices each expert of each
+        layer computed on this batch; a chunked loss ``loss_chunks``, the
+        trips of its scan, and ``loss_rows``, the rows they held, pad rows
+        too, on the device that scanned them (rows a chunk: the quotient)."""
         import jax.numpy as jnp
 
         ids = batch["input_ids"]
@@ -1311,42 +1320,146 @@ class Transformer:
                 f = (routed["expert_tokens"].sum(axis=0).astype(jnp.float32)
                      / (n_layers * B * T))
                 aux = cfg.n_experts * jnp.sum(f * routed["router_prob"].mean(axis=0))
-        chunk = self._loss_chunk(B, T)
         with trace.scope("loss"):
-            if chunk:
-                nll_sum, count = self.chunked_loss(params, x, labels, chunk)
+            if self._loss_chunk(B, T):
+                # sized again on the rows the device that scans them holds
+                nll_sum, count, scanned = self._chunked_loss(params, x, labels, None)
+                stats["loss_chunks"] = jnp.asarray(scanned[0], jnp.int32)
+                stats["loss_rows"] = jnp.asarray(scanned[0] * scanned[1], jnp.int32)
             else:
                 nll_sum, count = self.token_loss(self.head(params, x), labels)
             ce = nll_sum / jnp.maximum(count, 1)
             return ce + cfg.aux_loss_coef * aux, stats
 
 
-def _matmul_f32_grad(x, w, w_acc):
-    """``x [B, T, D] @ w [D, V]`` in float32, as the unembed computes it
-    (bf16 operands, float32 accumulation), whose backward leaves ``w``'s
-    gradient in float32 as the matmul produced it and hands it to ``w_acc``
-    (``parallel.mesh.grad_accumulator``): autodiff would round it to ``w``'s
-    dtype first. ``x``'s gradient is autodiff's own."""
+# The float32 logits one chunk of the loss scan may hold, in bytes: what sizes
+# a chunk (``auto_loss_chunk``) and the threshold over which the loss
+# chunks at all. From the compiled train steps of the benchmark's three cells
+# (PERF.md PR 32): gpt2-medium at 4 x 1024 peaks at 15.4 of a v5e's 16 GB and
+# has no room for more than the 1024 rows of 50,304 columns (206 MB) it had;
+# a Mistral-7B chip of a ZeRO-3 mesh gets 2048 rows of 32,768.
+LOSS_CHUNK_BYTES = 256 * 1024 * 1024
+
+
+def auto_loss_chunk(B: int, T: int, vocab: int) -> int:
+    """Positions a chunk of the chunked loss over ``B`` sequences of ``T``
+    positions and ``vocab`` (padded) columns: 0 (full logits) where the
+    float32 logits ``B x T x vocab x 4`` are within ``LOSS_CHUNK_BYTES``, else
+    the largest power of two whose chunk of them is; at least 1, at most
+    ``T``. A chunk is sized by the ROWS it holds: at 256 rows the head's three
+    matmuls sit on a v5e's ridge and every chunk re-reads the weight's float32
+    gradient sum; 1024-2048 rows are over it (PERF.md PR 32)."""
+    if B * T * vocab * 4 <= LOSS_CHUNK_BYTES:
+        return 0
+    positions = max(1, LOSS_CHUNK_BYTES // (4 * vocab * B))
+    return min(T, 1 << (positions.bit_length() - 1))
+
+
+def _head_logits(xn, w, extra):
+    import jax.numpy as jnp
+
+    with trace.scope("head_logits"):
+        logits = jnp.matmul(xn, w, preferred_element_type=jnp.float32)
+        return logits if extra is None else logits + extra
+
+
+def _head_scan(kind, eps, biased, ln_w, ln_b, w, w_acc, extra, xc, lc):
+    """(nll_sum, count) of final norm + unembed + CE over the chunks ``xc``
+    [n, B, c, D], ``lc`` [n, B, c]; ``w`` [D, Vp] in the compute dtype,
+    ``extra`` [Vp] float32 (bias and pad mask) or None. ``kind``, ``eps``: the
+    final norm's; ``biased``: ``extra`` holds a bias that wants a gradient.
+
+    Not under ``grad``: the plain scan of norm, logits and ``token_loss``.
+    Under ``grad`` (a ``custom_vjp``) the forward scan does the head's whole
+    work once a chunk: from the same logits it takes ``dlogits = (softmax -
+    onehot) * mask``, unscaled, and with it ``dx`` (stacked, the scan's
+    output) and the sums of the weights' gradients in float32 carries. The
+    backward rule only multiplies them by ``nll_sum``'s cotangent. ``w`` gets
+    no cotangent: its gradient [D, Vp] float32 is ``w_acc``'s, a stand-in
+    that hands it to the leaf (``parallel.mesh.grad_accumulator``)."""
     import jax
     import jax.numpy as jnp
 
-    def matmul(x, w, w_acc):
-        return jnp.matmul(x, w, preferred_element_type=jnp.float32)
+    f32 = jnp.float32
 
-    def fwd(x, w, w_acc):
-        return matmul(x, w, w_acc), (x, w)
+    def norm(x, ln_w, ln_b):
+        with trace.scope("final_norm"):
+            return _norm(x, ln_w, ln_b, kind, eps=eps)
 
-    def bwd(res, g):
-        x, w = res
-        dw = jax.lax.dot_general(g, x, (((0, 1), (0, 1)), ((), ())),
-                                 preferred_element_type=jnp.float32).T
-        dx = jax.lax.dot_general(g, w, (((2,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        return dx.astype(x.dtype), None, dw
+    def primal(ln_w, ln_b, w, w_acc, extra, xc, lc):
+        def body(carry, xl):
+            xch, lch = xl
+            nll, cnt = Transformer.token_loss(
+                _head_logits(norm(xch, ln_w, ln_b), w, extra), lch)
+            return (carry[0] + nll, carry[1] + cnt), None
 
-    f = jax.custom_vjp(matmul)
-    f.defvjp(fwd, bwd)
-    return f(x, w, w_acc)
+        return jax.lax.scan(body, (jnp.zeros((), f32), jnp.zeros((), jnp.int32)),
+                            (xc, lc))[0]
+
+    def fwd(ln_w, ln_b, w, w_acc, extra, xc, lc):
+        # float32 norm weights (what ``_norm`` computes with), so that the
+        # norm's vjp hands their gradients out unrounded
+        ln32 = (ln_w.astype(f32), ln_b.astype(f32))
+
+        def body(carry, xl):
+            nll_sum, cnt, dw, dln, dextra = carry
+            xch, lch = xl
+            # the norm takes and gives float32 here (what it computes in):
+            # its vjp then takes dxn as the matmul gave it. dx is rounded to
+            # x's dtype twice, stacked and after the backward rule's scaling,
+            # where autodiff rounds dxn and then dx
+            xn32, norm_vjp = jax.vjp(norm, xch.astype(f32), *ln32)
+            xn = xn32.astype(xch.dtype)
+            logits = _head_logits(xn, w, extra)
+            with trace.scope("head_softmax"):
+                mask = lch >= 0
+                label = jnp.where(mask, lch, 0)[..., None]
+                shifted = logits - logits.max(axis=-1, keepdims=True)
+                e = jnp.exp(shifted)
+                total = e.sum(axis=-1, keepdims=True)
+                nll = (jnp.log(total) - jnp.take_along_axis(shifted, label, axis=-1)
+                       )[..., 0]
+                hot = jnp.arange(logits.shape[-1]) == label
+                dlogits = jnp.where(mask[..., None], e / total - hot, 0.0)
+                # the MXU's operand in both matmuls below: what a
+                # default-precision matmul makes of the float32 cotangent on
+                # the chip. Behind a barrier, so that it is written once: XLA
+                # otherwise fuses this block into each matmul's operand,
+                # which then re-reads the float32 logits and takes exp again
+                # (olmoe-train: dw 30.7 -> 20.1 ms a step, dx 20.9 -> 17.9;
+                # PERF.md PR 32)
+                dl = jax.lax.optimization_barrier(dlogits.astype(xn.dtype))
+            with trace.scope("head_dx"):
+                dxn = jax.lax.dot_general(dl, w, (((2,), (1,)), ((), ())),
+                                          preferred_element_type=f32)
+                dx, *dln_chunk = norm_vjp(dxn)
+            with trace.scope("head_dw"):
+                dw = dw + jax.lax.dot_general(xn, dl, (((0, 1), (0, 1)), ((), ())),
+                                              preferred_element_type=f32)
+                dln = tuple(a + b for a, b in zip(dln, dln_chunk))
+                if biased:
+                    dextra = dextra + dlogits.sum(axis=(0, 1))
+            return ((nll_sum + (nll * mask).sum(), cnt + mask.sum(), dw, dln,
+                     dextra), dx.astype(xch.dtype))
+
+        zeros = lambda a: jnp.zeros(a.shape, f32)
+        (nll_sum, cnt, dw, dln, dextra), dxc = jax.lax.scan(
+            body, (jnp.zeros((), f32), jnp.zeros((), jnp.int32), zeros(w),
+                   tuple(zeros(a) for a in ln32), zeros(extra) if biased else None),
+            (xc, lc))
+        return (nll_sum, cnt), (dxc, dw, dln, dextra, ln_w, ln_b)
+
+    def bwd(res, cotangents):
+        g = cotangents[0]               # of nll_sum; the count's is float0
+        dxc, dw, dln, dextra, ln_w, ln_b = res
+        scaled = lambda a, dtype: (a.astype(f32) * g).astype(dtype)
+        return (scaled(dln[0], ln_w.dtype), scaled(dln[1], ln_b.dtype), None,
+                scaled(dw, f32), scaled(dextra, f32) if biased else None,
+                scaled(dxc, dxc.dtype), None)
+
+    scan = jax.custom_vjp(primal)
+    scan.defvjp(fwd, bwd)
+    return scan(ln_w, ln_b, w, w_acc, extra, xc, lc)
 
 
 def _remat_policy(name: str):

@@ -50,8 +50,9 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 PREFIX = "sxt:"
 
 # every scope the package opens under jit, by the layer a reader sums it to.
-# "attn_qk_norm" nests inside "attn_qkv" and the four "moe_*" scopes inside
-# "moe": a reader that sums by the outer name counts them with it.
+# "attn_qk_norm" nests inside "attn_qkv", the four "moe_*" scopes inside
+# "moe" and the four "head_*" scopes (the chunked loss's scan) inside "loss":
+# a reader that sums by the outer name counts them with it.
 # "plumbing" is what belongs to no layer of the model: the layer scan's own
 # slicing and stacking, the masters' cast to the compute dtype, the
 # gradients' cast back and normalization
@@ -59,7 +60,8 @@ SCOPES = {
     "attn": ("attn_norm", "attn_qkv", "attn_qk_norm", "attn_core", "attn_out"),
     "mlp": ("mlp_norm", "mlp", "moe", "moe_router", "moe_dispatch",
             "moe_experts", "moe_combine"),
-    "loss": ("embed", "final_norm", "loss"),
+    "loss": ("embed", "final_norm", "loss", "head_logits", "head_softmax",
+             "head_dx", "head_dw"),
     "optimizer": ("optimizer", "grad_clip", "weight_mix"),
     "mesh": ("zero3_gather", "zero3_reduce_scatter"),
     "plumbing": ("layers", "weight_cast", "grad_normalize"),

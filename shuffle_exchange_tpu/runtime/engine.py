@@ -2413,8 +2413,10 @@ class Engine:
         """What the model reported beside the last ``train_batch``'s loss,
         as device arrays (no host sync in the step): an MoE model's
         ``moe_expert_tokens`` [L, E] int32, the token-choices each expert of
-        each layer computed. {} before the first step and for models that
-        report nothing."""
+        each layer computed; a chunked loss's ``loss_chunks`` and
+        ``loss_rows`` (the scan's trips and the rows they held on one device,
+        summed over the step's microbatches). {} before the first step and
+        for models that report nothing."""
         return dict(getattr(self, "_last_step_stats", None) or {})
 
     @property

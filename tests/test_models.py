@@ -121,6 +121,107 @@ def test_labels_with_ignore_index():
     assert np.isfinite(l_explicit)
 
 
+# the head, chunked: what each case turns on
+HEAD_CASES = {
+    "tied_padded_layernorm": dict(pad_vocab_logits=True),
+    "untied_rmsnorm": dict(tie_embeddings=False, norm="rmsnorm"),
+    "untied_bias_padded": dict(tie_embeddings=False, unembed_bias=True,
+                               pad_vocab_logits=True),
+    "ignored_labels": dict(tie_embeddings=False),
+    "ragged_chunk": dict(pad_vocab_logits=True),        # 40 positions, chunks of 16
+    "scaled_cotangent": dict(tie_embeddings=False, norm="rmsnorm",
+                             pad_vocab_logits=True),
+}
+HEAD_LEAVES = ("ln_f_w", "ln_f_b", "embed", "unembed", "unembed_b")
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(HEAD_CASES))
+def test_chunked_loss_and_every_gradient_leaf_match_the_full_head(case, dtype):
+    """``chunked_loss`` (loss and gradient from one scan) against ``head()``
+    + ``token_loss()`` under ``jax.grad``: loss, count, ``x``'s gradient and
+    every leaf of the head's."""
+    import jax
+    import jax.numpy as jnp
+
+    T = 40 if case == "ragged_chunk" else 64
+    model = Transformer(tiny(vocab=131, d=64, layers=1, heads=4, seq=64,
+                             **HEAD_CASES[case]))
+    rng = np.random.default_rng(3)
+    params = model.init(jax.random.PRNGKey(0))
+    head = {k: jnp.asarray(params[k] + 0.1 * rng.standard_normal(params[k].shape),
+                           dtype)
+            for k in HEAD_LEAVES if k in params}
+    x = jnp.asarray(rng.standard_normal((4, T, 64)), dtype)
+    labels = rng.integers(0, 131, size=(4, T)).astype(np.int32)
+    if case == "ignored_labels":
+        labels[:, ::3] = -100
+        labels[1] = -100
+    scale = 1024.0 if case == "scaled_cotangent" else 1.0   # loss scaling
+
+    def full(head, x):
+        nll, count = model.token_loss(model.head(head, x), labels)
+        return scale * nll / jnp.maximum(count, 1), count
+
+    def chunked(head, x):
+        nll, count = model.chunked_loss(head, x, labels, 16)
+        return scale * nll / jnp.maximum(count, 1), count
+
+    (want, n_want), g_want = jax.jit(jax.value_and_grad(
+        full, argnums=(0, 1), has_aux=True))(head, x)
+    (got, n_got), g_got = jax.jit(jax.value_and_grad(
+        chunked, argnums=(0, 1), has_aux=True))(head, x)
+    assert int(n_got) == int(n_want) == int((labels >= 0).sum())
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    # bf16: tests/test_chunked_loss_mesh.py's tolerances for a bf16 head
+    rtol, atol = (1e-5, 1e-5 * scale) if dtype == "float32" else (2e-2, 1e-3 * scale)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(g_got)[0],
+                            jax.tree_util.tree_leaves(g_want)):
+        assert a.dtype == b.dtype == jnp.dtype(dtype)
+        if dtype == "bfloat16":     # one rounding of the sum, against one a row
+            atol = max(atol, 2e-2 * float(np.abs(np.asarray(b, np.float32)).max()))
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), rtol=rtol,
+            atol=atol, err_msg=jax.tree_util.keystr(path))
+    # and not under grad: the plain scan
+    np.testing.assert_allclose(float(jax.jit(chunked)(head, x)[0]), float(want),
+                               rtol=1e-5 if dtype == "float32" else 2e-3)
+
+
+# (sequences a device, positions, vocabulary) of the benchmark's three cells
+# and the positions a chunk PERF.md states for them (PR 32)
+CELL_CHUNKS = {"gpt2m-train": ((4, 1024, 50257), 256),
+               "olmoe-train": ((4, 4096, 50304), 256),
+               "mistral7b-zero3-x4": ((1, 4096, 32768), 2048)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_CHUNKS))
+def test_loss_chunk_counts_rows(cell):
+    """Auto sizes a chunk by the rows it holds (sequences x positions) under
+    the byte budget on its float32 logits, not by positions."""
+    from shuffle_exchange_tpu.models import transformer
+
+    (B, T, V), want = CELL_CHUNKS[cell]
+    model = Transformer(tiny(vocab=V, d=64, layers=1, heads=4, seq=T,
+                             pad_vocab_logits=True))
+    assert model._loss_chunk(B, T) == want
+    Vp = V + (-V % 128)
+    for b in (1, 4, 16):
+        chunk = model._loss_chunk(b, T)
+        assert chunk == transformer.auto_loss_chunk(b, T, Vp)
+        if chunk == 0:              # the full logits are under the budget
+            assert b * T * Vp * 4 <= transformer.LOSS_CHUNK_BYTES
+            continue
+        assert 0 < b * chunk * Vp * 4 <= transformer.LOSS_CHUNK_BYTES
+        # and no smaller than it has to be: twice as many rows would not fit
+        assert chunk == T or 2 * b * chunk * Vp * 4 > transformer.LOSS_CHUNK_BYTES
+    # an explicit chunk stays in positions whatever the batch; 0 = full logits
+    explicit = Transformer(tiny(vocab=V, d=64, layers=1, heads=4, seq=T,
+                                loss_chunk=96))
+    assert [explicit._loss_chunk(b, T) for b in (1, 16)] == [96, 96]
+    assert model._loss_chunk(1, 8) == 0     # small enough for full logits
+
+
 def test_padded_vocab_chunked_loss_matches_unpadded():
     """pad_vocab_logits=True (MXU-aligned unembed with -1e30 pad mask) must
     give the same chunked CE as the unpadded form: the pad columns' softmax
