@@ -141,6 +141,17 @@ def initialize(
                 "without ring attention (zoo Transformer models route "
                 "automatically)", cfg.context_parallel.degree)
 
+    # Activation checkpointing: the section's ``enabled`` / ``policy`` are the
+    # zoo model's per-layer remat (``TransformerConfig.remat`` /
+    # ``remat_policy``; models/transformer._remat_policy names the policies).
+    # A model built with remat on keeps its own policy.
+    ac = cfg.activation_checkpointing
+    tcfg = getattr(model, "config", None)
+    if ac.enabled and tcfg is not None and hasattr(tcfg, "remat") and not tcfg.remat:
+        import dataclasses as _dc
+
+        model.config = _dc.replace(tcfg, remat=True, remat_policy=ac.policy)
+
     # Pipeline parallelism: wrap zoo models so the 1F1B microbatch loop runs
     # inside the jitted step (the reference's PipelineEngine path,
     # runtime/pipe/engine.py:338 — here a model wrapper, see parallel/pipeline.py).

@@ -158,6 +158,24 @@ class InferenceEngine:
         self.model = model
         self.config = config or InferenceConfig()
         self._mcfg = model.config
+        if getattr(self._mcfg, "recurrent", False) or len(
+                getattr(self._mcfg, "pattern", ((),))) > 1:
+            # the cached paths scan ONE kind of layer over a KV cache: a
+            # recurrent mixer (Gated DeltaNet) carries a convolution tail and
+            # a [dk, dv] state per head instead, which neither the caches nor
+            # the schedulers hold
+            raise NotImplementedError(
+                "serving a stack with a recurrent mixer (Gated DeltaNet "
+                "layers beside full-attention layers: layer_pattern) is not "
+                "implemented: the inference engines keep one kind of state, a "
+                "KV cache, for one kind of layer (training through "
+                "sxt.initialize is; ROADMAP R-M5)")
+        if getattr(self._mcfg, "experts_held", 0) != getattr(self._mcfg, "n_experts", 0):
+            raise NotImplementedError(
+                "serving one expert-parallel rank's share of the experts "
+                "(n_experts_held) alone is not implemented: the tokens a share "
+                "emits are not the model's (training a share through "
+                "sxt.initialize is; ROADMAP R-M2)")
         if getattr(self._mcfg, "qk_norm", False):
             # the cached decode paths project q and k without the whole-
             # projection RMSNorm: serving such a model would be silently wrong

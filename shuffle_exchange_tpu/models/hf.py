@@ -5,7 +5,9 @@ the v1 injection policies/containers (``module_inject/containers/`` gpt2,
 llama/llama2, opt, …) and the v2 engine factory's arch dispatch
 (``inference/v2/engine_factory.py:32,69``: llama, mistral, mixtral, opt,
 phi/phi3, qwen/qwen2, falcon), plus families the reference does not have
-(olmoe: q/k RMSNorm and dropless fine-grained experts). A reference user points the engine at an HF
+(olmoe: q/k RMSNorm and dropless fine-grained experts; qwen3_next: Gated
+DeltaNet and gated full-attention layers in one stack, configuration only:
+it trains from ``Transformer.init``). A reference user points the engine at an HF
 model; here ``from_hf(model_or_path)`` returns ``(Transformer, params)``
 ready for ``sxt.initialize`` / ``init_inference``.
 
@@ -50,6 +52,7 @@ _ARCH_FAMILIES = {
     "InternLMForCausalLM": "internlm",
     "InternLM2ForCausalLM": "internlm2",
     "OlmoeForCausalLM": "olmoe",
+    "Qwen3NextForCausalLM": "qwen3next",
 }
 
 
@@ -60,6 +63,7 @@ _MODEL_TYPE_FAMILIES = {"llama": "llama", "mistral": "llama", "qwen2": "qwen2",
                         "bert": "bert", "distilbert": "distilbert",
                         "gpt_neo": "gptneo", "internlm": "internlm",
                         "internlm2": "internlm2", "olmoe": "olmoe",
+                        "qwen3_next": "qwen3next",
                         "megatron": "megatron",
                         "megatron-gpt": "megatron", "megatron_gpt": "megatron"}
 
@@ -288,6 +292,59 @@ def config_from_hf(hf_config) -> TransformerConfig:
             moe_norm_topk=bool(cfg.get("norm_topk_prob", False)),
             moe_impl="ragged", moe_aux="all_choices",
             aux_loss_coef=cfg.get("router_aux_loss_coef", 0.01), **common)
+    if family == "qwen3next":
+        # Qwen/Qwen3-Next: a period of (full_attention_interval - 1) Gated
+        # DeltaNet layers and one gated full-attention layer (head_dim its
+        # own key, RoPE on partial_rotary_factor of it, per-head zero-centred
+        # q/k norm), every block norm a zero-centred RMSNorm, every FFN 512
+        # routed experts (softmax over all, top-k renormalised, dropless:
+        # "ragged") plus a sigmoid-gated shared expert, HF's all-choices
+        # balancing loss. ``num_experts_held`` / ``expert_first`` (not the
+        # source's keys): one expert-parallel rank's share of each layer's
+        # experts; ``expert_buffer_factor`` sizes its buffer of held rows and
+        # has to come with it (a share's configuration states its own).
+        # The multi-token-prediction module is not built (HF's causal-LM
+        # class does not load it either).
+        if cfg.get("decoder_sparse_step", 1) != 1 or cfg.get("mlp_only_layers"):
+            raise ValueError("qwen3_next with dense interleaved layers "
+                             "(decoder_sparse_step != 1 / mlp_only_layers) is not supported")
+        if cfg.get("attention_bias"):
+            raise ValueError("qwen3_next with attention_bias=true is not supported")
+        interval = int(cfg.get("full_attention_interval", 4))
+        kinds = cfg.get("layer_types") or [
+            "full_attention" if (i + 1) % interval == 0 else "linear_attention"
+            for i in range(interval)]
+        if cfg["num_hidden_layers"] % interval or list(kinds[:interval]) * (
+                len(kinds) // interval) != list(kinds):
+            raise ValueError("qwen3_next: the layers must be whole periods of "
+                             f"full_attention_interval={interval}")
+        head = int(cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"])
+        share = {}
+        if cfg.get("num_experts_held"):
+            if "expert_buffer_factor" not in cfg:
+                raise ValueError("qwen3_next: num_experts_held needs expert_buffer_factor "
+                                 "(the held rows' buffer as a multiple of the balanced share)")
+            share = dict(n_experts_held=int(cfg["num_experts_held"]),
+                         expert_first=int(cfg.get("expert_first", 0)),
+                         moe_held_rows_factor=float(cfg["expert_buffer_factor"]))
+        common.update(d_ff=cfg["moe_intermediate_size"], norm="rmsnorm_zc")
+        return TransformerConfig(
+            head_size=head,
+            rotary_dim=int(head * cfg.get("partial_rotary_factor", 1.0)),
+            layer_pattern=tuple(
+                ("gated_attn" if kind == "full_attention" else "gdn", "moe")
+                for kind in kinds[:interval]),
+            gdn_key_heads=cfg["linear_num_key_heads"],
+            gdn_value_heads=cfg["linear_num_value_heads"],
+            gdn_key_dim=cfg["linear_key_head_dim"],
+            gdn_value_dim=cfg["linear_value_head_dim"],
+            gdn_conv_kernel=cfg.get("linear_conv_kernel_dim", 4),
+            n_experts=cfg["num_experts"], **share,
+            moe_top_k=cfg.get("num_experts_per_tok", 10),
+            moe_norm_topk=bool(cfg.get("norm_topk_prob", True)),
+            moe_shared_expert_ff=cfg.get("shared_expert_intermediate_size", 0),
+            moe_impl="ragged", moe_aux="all_choices",
+            aux_loss_coef=cfg.get("router_aux_loss_coef", 0.001), **common)
     if family == "mixtral":
         return TransformerConfig(
             n_experts=cfg["num_local_experts"], moe_top_k=cfg.get("num_experts_per_tok", 2),
@@ -322,6 +379,13 @@ def _stack(sd: Dict[str, Any], fmt: str, L: int, transpose: bool = False) -> np.
 def params_from_state_dict(sd: Dict[str, Any], config: TransformerConfig,
                            family: str, megatron_v2: bool = True) -> Dict[str, Any]:
     """Re-lay an HF state dict into the zoo Transformer's stacked format."""
+    if len(config.pattern) > 1:
+        raise NotImplementedError(
+            f"importing {family} weights is not implemented: a stack of "
+            "several layer kinds keeps each kind's leaves under its own name "
+            "(params['layers'][kind]) and no checkpoint of it has been "
+            "loaded yet; config_from_hf and training from Transformer.init "
+            "work (chipbench/QWEN3NEXT.md maps the leaves to the source's names)")
     L = config.n_layers
     sd = {k.removeprefix("transformer.").removeprefix("model.")
            .removeprefix("gpt_neox.").removeprefix("bert.")

@@ -83,7 +83,9 @@ class TransformerConfig:
     attn_scale: float = 0.0                    # 0 = 1/sqrt(Dh); GPT-Neo: 1.0
     local_attention_window: int = 0            # window for "local" layers
     attention_pattern: Tuple[str, ...] = ()    # per-layer "global"/"local",
-                                               # cycled over n_layers
+                                               # cycled over n_layers: a flag
+                                               # of ONE kind of layer (see
+                                               # layer_pattern for kinds)
     dtype: Any = None                          # compute dtype override (engine usually casts)
     remat: bool = False
     remat_policy: str = "dots_saveable"
@@ -104,7 +106,9 @@ class TransformerConfig:
     # Megatron --expert-interval interleaving: per-layer MoE flags, cycled
     # over n_layers; () = every layer is MoE (when n_experts > 0). Dense
     # layers store their FFN in expert slot 0 of the stacked arrays and a
-    # traced per-layer flag selects the dense path inside the scan.
+    # traced per-layer flag selects the dense path inside the scan. Like
+    # attention_pattern a variation of ONE kind of layer (the dense FFN has
+    # an expert's shapes); layers that share no shapes are a layer_pattern.
     moe_layer_pattern: Tuple[bool, ...] = ()
     attention_impl: str = "auto"
     # Chunked vocab CE (reference FPDT chunked logits loss,
@@ -132,6 +136,44 @@ class TransformerConfig:
     # "xla" keeps the jnp chunked online-softmax).
     cp_kv_chunk: int = 1024
     cp_use_kernel: str = "auto"
+    # Width of an attention head; 0 = d_model // n_heads (Qwen3-Next: 256
+    # against 2048 / 16). Read it as ``head_dim``.
+    head_size: int = 0
+    # The stack as a PERIOD of layer kinds, each (mixer, ffn), cycled over
+    # n_layers (which the period must divide); () = one kind, ("attn", "moe"
+    # if n_experts else "mlp"): every model before Qwen3-Next. ``stack_apply``
+    # scans over periods and each kind's parameters are stacked on their own
+    # (``params["layers"][kind]``, [periods, layers of the kind a period, ...];
+    # a one-kind model keeps the flat ``params["layers"]`` [n_layers, ...]).
+    #   mixer "attn"        softmax attention as every flag above shapes it
+    #         "gated_attn"  Qwen3-Next full attention: q and a sigmoid output
+    #                       gate from one projection, per-HEAD q/k RMSNorm
+    #                       (the block norm's kind) before RoPE
+    #         "gdn"         Gated DeltaNet (ops/gated_delta.py), gdn_* below
+    #   ffn   "mlp" | "moe"
+    # ``attention_pattern`` (GPT-Neo) and ``moe_layer_pattern`` (Megatron)
+    # are older and stay as they are: they vary a FLAG of one kind whose
+    # layers share every shape (the other variant lives in the same arrays),
+    # and only a one-kind model may use them. A pattern is for layers that
+    # share no shapes.
+    layer_pattern: Tuple[Tuple[str, str], ...] = ()
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0                       # per key head (q and k)
+    gdn_value_dim: int = 0                     # per value head
+    gdn_conv_kernel: int = 4
+    # One expert-parallel rank's share of a routed layer: the router scores
+    # all ``n_experts``, this model holds ``n_experts_held`` of them (0 = all)
+    # starting at ``expert_first`` and computes only the token-choices that
+    # fall on those (moe/layer.expert_mlp_ragged; dropless impl only). Their
+    # rows go to a buffer of ``moe_held_rows_factor`` x the balanced share
+    # (tokens x k x held / n_experts); a row that does not fit is dropped and
+    # counted (``moe_overflow_rows``). 3 is what ``qwen3next-train`` runs: an
+    # untrained router over Zipf ids reads up to 1.1 x the balanced share
+    # there and nothing overflows (PERF.md section 6, PR 33).
+    n_experts_held: int = 0
+    expert_first: int = 0
+    moe_held_rows_factor: float = 3.0
 
     @property
     def kv_heads(self) -> int:
@@ -143,7 +185,22 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_size or self.d_model // self.n_heads
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def pattern(self) -> Tuple[Tuple[str, str], ...]:
+        """The period of (mixer, ffn) kinds; one entry for a one-kind model."""
+        return tuple(tuple(kind) for kind in self.layer_pattern) or (
+            ("attn", "moe" if self.n_experts > 0 else "mlp"),)
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layer carries a recurrent state instead of a KV cache."""
+        return any(mixer == "gdn" for mixer, _ in self.pattern)
 
     @property
     def ff_dim(self) -> int:
@@ -229,15 +286,40 @@ def _norm(x, weight, bias, kind: str, eps: float = 1e-5):
     import jax.numpy as jnp
 
     x32 = x.astype(jnp.float32)
-    if kind == "rmsnorm":
+    if kind in ("rmsnorm", "rmsnorm_zc"):
         from ..ops.rmsnorm import rmsnorm
 
-        return rmsnorm(x32, weight.astype(jnp.float32), eps=eps).astype(x.dtype)
+        # "rmsnorm_zc": a zero-centred gain, x / rms(x) * (1 + w) (Qwen3-Next)
+        gain = weight.astype(jnp.float32)
+        return rmsnorm(x32, 1.0 + gain if kind == "rmsnorm_zc" else gain,
+                       eps=eps).astype(x.dtype)
     mean = x32.mean(-1, keepdims=True)
     var = x32.var(-1, keepdims=True)
     out = (x32 - mean) * (1.0 / jnp.sqrt(var + eps))
     out = out * weight.astype(jnp.float32) + bias.astype(jnp.float32)
     return out.astype(x.dtype)
+
+
+def _head_norm(x, weight, kind: str, eps: float):
+    """RMSNorm of x [..., H, Dh] per head over its Dh, gain [Dh] shared by the
+    heads (zero-centred for ``kind`` "rmsnorm_zc"), in float32."""
+    import jax.numpy as jnp
+
+    from ..ops.rmsnorm import rmsnorm_reference
+
+    gain = weight.astype(jnp.float32)
+    return rmsnorm_reference(x, 1.0 + gain if kind == "rmsnorm_zc" else gain, eps)
+
+
+def _no_routing_stats(n_experts: int) -> dict:
+    """What a layer that routes nothing reports (a dense layer among routed
+    ones), shaped as a routed layer's stats."""
+    import jax.numpy as jnp
+
+    return {"expert_tokens": jnp.zeros((n_experts,), jnp.int32),
+            "router_prob": jnp.zeros((n_experts,), jnp.float32),
+            "held_rows": jnp.zeros((), jnp.int32),
+            "overflow_rows": jnp.zeros((), jnp.int32)}
 
 
 def rope_table(seq_len: int, head_dim: int, theta: float):
@@ -422,56 +504,21 @@ class Transformer:
             # the offset rows the same way).
             params["pos_embed"] = jax.random.normal(
                 next(keys), (cfg.max_seq_len + cfg.pos_offset, D), jnp.float32) * 0.02
-        # stacked per-layer weights: leading dim L
-        def stack(key, shape, fan_in, scale=1.0):
-            return jax.random.normal(key, (L,) + shape, jnp.float32) * (scale / math.sqrt(fan_in))
-
-        layer = {
-            "ln1_w": jnp.ones((L, D)), "ln1_b": jnp.zeros((L, D)),
-            "wq": stack(next(keys), (D, H * Dh), D),
-            "wk": stack(next(keys), (D, KV * Dh), D),
-            "wv": stack(next(keys), (D, KV * Dh), D),
-            "wo": stack(next(keys), (H * Dh, D), H * Dh, scale=1.0 / math.sqrt(2 * L)),
-        }
-        if not (cfg.parallel_block and cfg.parallel_shared_ln):
-            layer["ln2_w"], layer["ln2_b"] = jnp.ones((L, D)), jnp.zeros((L, D))
-        if cfg.attn_qkv_bias:
-            layer["b_q"] = jnp.zeros((L, H * Dh))
-            layer["b_k"] = jnp.zeros((L, KV * Dh))
-            layer["b_v"] = jnp.zeros((L, KV * Dh))
-        if cfg.attn_out_bias:
-            layer["b_o"] = jnp.zeros((L, D))
-        if cfg.qk_norm:
-            layer["q_norm_w"] = jnp.ones((L, H * Dh))
-            layer["k_norm_w"] = jnp.ones((L, KV * Dh))
-        if cfg.n_experts > 0:
-            import jax.random as jrandom
-
-            from ..moe.layer import init_expert_mlp
-
-            ek = next(keys)
-            per_layer = [init_expert_mlp(k, cfg.n_experts, D, F, cfg.activation)
-                         for k in jrandom.split(ek, L)]
-            layer["moe_gate"] = stack(next(keys), (D, cfg.n_experts), D)
-            for name in per_layer[0]:
-                layer[f"moe_{name}"] = jnp.stack([p[name] for p in per_layer])
-            if cfg.moe_shared_expert_ff > 0:
-                Fs = cfg.moe_shared_expert_ff
-                layer["moe_shared_w_gate"] = stack(next(keys), (D, Fs), D)
-                layer["moe_shared_w_up"] = stack(next(keys), (D, Fs), D)
-                layer["moe_shared_w_down"] = stack(next(keys), (Fs, D), Fs)
-                layer["moe_shared_gate"] = jnp.zeros((L, D, 1))
-        elif cfg.activation == "swiglu":
-            layer["w_gate"] = stack(next(keys), (D, F), D)
-            layer["w_up"] = stack(next(keys), (D, F), D)
-            layer["w_down"] = stack(next(keys), (F, D), F, scale=1.0 / math.sqrt(2 * L))
+        pattern = cfg.pattern
+        if len(pattern) == 1:
+            # one kind: every leaf stacked [L, ...]
+            params["layers"] = self._init_kind(keys, pattern[0], (L,))
         else:
-            layer["w_up"] = stack(next(keys), (D, F), D)
-            layer["w_down"] = stack(next(keys), (F, D), F, scale=1.0 / math.sqrt(2 * L))
-            if cfg.mlp_bias:
-                layer["b_up"] = jnp.zeros((L, F))
-                layer["b_down"] = jnp.zeros((L, D))
-        params["layers"] = layer
+            if L % len(pattern):
+                raise ValueError(f"n_layers {L} is not whole periods of the "
+                                 f"{len(pattern)}-layer pattern")
+            # each kind stacked on its own: [periods, layers of it a period, ...]
+            periods = L // len(pattern)
+            params["layers"] = {
+                name: self._init_kind(
+                    iter(jax.random.split(jax.random.fold_in(next(keys), j), 16)),
+                    kind, (periods, sum(1 for k in pattern if k == kind)))
+                for j, (name, kind) in enumerate(self.kinds().items())}
         if cfg.type_vocab_size > 0:
             params["token_type_embed"] = jax.random.normal(
                 next(keys), (cfg.type_vocab_size, D), jnp.float32) * 0.02
@@ -480,7 +527,8 @@ class Transformer:
         if not cfg.post_ln:
             # post-LN encoders (BERT) normalize inside each block and have
             # no final norm before the head
-            params["ln_f_w"] = jnp.ones((D,))
+            # a zero-centred gain (x * (1 + w)) starts at 0
+            params["ln_f_w"] = (jnp.zeros if cfg.norm == "rmsnorm_zc" else jnp.ones)((D,))
             params["ln_f_b"] = jnp.zeros((D,))
         if cfg.mlm_head:
             params["mlm_dense_w"] = jax.random.normal(next(keys), (D, D), jnp.float32) / math.sqrt(D)
@@ -493,6 +541,117 @@ class Transformer:
                 params["unembed_b"] = jnp.zeros((cfg.vocab_size,))
         return params
 
+    def kinds(self) -> Dict[str, Tuple[str, str]]:
+        """{name under ``params["layers"]``: (mixer, ffn)} for each distinct
+        kind of the pattern, in the order it first appears."""
+        return {name: kind for name, _, kind in self.slots()}
+
+    def slots(self):
+        """The period, slot by slot: [(kind's name, index among the layers of
+        that kind in a period, (mixer, ffn))]."""
+        seen: Dict[str, int] = {}
+        out = []
+        for mixer, ffn in self.config.pattern:
+            name = f"{mixer}_{ffn}"
+            out.append((name, seen.get(name, 0), (mixer, ffn)))
+            seen[name] = seen.get(name, 0) + 1
+        return out
+
+    def _init_kind(self, keys, kind, lead):
+        """One kind's parameters, every leaf with the leading shape ``lead``
+        ((n_layers,) for a one-kind model)."""
+        import jax
+        import jax.numpy as jnp
+
+        cfg = self.config
+        mixer, ffn = kind
+        L, D, H, KV, Dh, F = cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim, cfg.ff_dim
+        n = math.prod(lead)
+
+        def stack(key, shape, fan_in, scale=1.0):
+            return jax.random.normal(key, lead + shape, jnp.float32) * (scale / math.sqrt(fan_in))
+
+        ones = lambda *shape: jnp.ones(lead + shape)
+        zeros = lambda *shape: jnp.zeros(lead + shape)
+        # a zero-centred gain (x * (1 + w)) starts at 0
+        gain = zeros if cfg.norm == "rmsnorm_zc" else ones
+        biased_norm = cfg.norm != "rmsnorm_zc"
+        layer = {"ln1_w": gain(D)}
+        if biased_norm:
+            layer["ln1_b"] = zeros(D)
+        if mixer == "gdn":
+            Hk, Hv = cfg.gdn_key_heads, cfg.gdn_value_heads
+            dk, dv = cfg.gdn_key_dim, cfg.gdn_value_dim
+            conv_dim = 2 * Hk * dk + Hv * dv
+            layer.update({
+                # grouped per key head as the source groups them:
+                # [q dk | k dk | v (Hv/Hk) dv | z (Hv/Hk) dv] and [b | a]
+                "w_qkvz": stack(next(keys), (D, 2 * Hk * dk + 2 * Hv * dv), D),
+                "w_ba": stack(next(keys), (D, 2 * Hv), D),
+                "conv_w": stack(next(keys), (cfg.gdn_conv_kernel, conv_dim),
+                                cfg.gdn_conv_kernel),
+                # the source's own: A = U(0, 16), dt_bias = 1, a plain gain
+                "A_log": jnp.log(jax.random.uniform(
+                    next(keys), lead + (Hv,), jnp.float32, 1e-3, 16.0)),
+                "dt_bias": ones(Hv),
+                "gdn_norm_w": ones(dv),
+                "w_out": stack(next(keys), (Hv * dv, D), Hv * dv,
+                               scale=1.0 / math.sqrt(2 * L)),
+            })
+        else:
+            gated = mixer == "gated_attn"
+            layer.update({
+                # gated: per head [q Dh | gate Dh]
+                "wq": stack(next(keys), (D, H * Dh * (2 if gated else 1)), D),
+                "wk": stack(next(keys), (D, KV * Dh), D),
+                "wv": stack(next(keys), (D, KV * Dh), D),
+                "wo": stack(next(keys), (H * Dh, D), H * Dh, scale=1.0 / math.sqrt(2 * L)),
+            })
+            if cfg.attn_qkv_bias:
+                layer["b_q"] = zeros(H * Dh)
+                layer["b_k"] = zeros(KV * Dh)
+                layer["b_v"] = zeros(KV * Dh)
+            if cfg.attn_out_bias:
+                layer["b_o"] = zeros(D)
+            if gated:                          # per head, over its Dh
+                layer["q_norm_w"], layer["k_norm_w"] = gain(Dh), gain(Dh)
+            elif cfg.qk_norm:
+                layer["q_norm_w"] = ones(H * Dh)
+                layer["k_norm_w"] = ones(KV * Dh)
+        if not (cfg.parallel_block and cfg.parallel_shared_ln):
+            layer["ln2_w"] = gain(D)
+            if biased_norm:
+                layer["ln2_b"] = zeros(D)
+        if ffn == "moe":
+            import jax.random as jrandom
+
+            from ..moe.layer import init_expert_mlp
+
+            ek = next(keys)
+            per_layer = [init_expert_mlp(k, cfg.experts_held, D, F, cfg.activation)
+                         for k in jrandom.split(ek, n)]
+            layer["moe_gate"] = stack(next(keys), (D, cfg.n_experts), D)
+            for name in per_layer[0]:
+                held = jnp.stack([p[name] for p in per_layer])
+                layer[f"moe_{name}"] = held.reshape(lead + held.shape[1:])
+            if cfg.moe_shared_expert_ff > 0:
+                Fs = cfg.moe_shared_expert_ff
+                layer["moe_shared_w_gate"] = stack(next(keys), (D, Fs), D)
+                layer["moe_shared_w_up"] = stack(next(keys), (D, Fs), D)
+                layer["moe_shared_w_down"] = stack(next(keys), (Fs, D), Fs)
+                layer["moe_shared_gate"] = zeros(D, 1)
+        elif cfg.activation == "swiglu":
+            layer["w_gate"] = stack(next(keys), (D, F), D)
+            layer["w_up"] = stack(next(keys), (D, F), D)
+            layer["w_down"] = stack(next(keys), (F, D), F, scale=1.0 / math.sqrt(2 * L))
+        else:
+            layer["w_up"] = stack(next(keys), (D, F), D)
+            layer["w_down"] = stack(next(keys), (F, D), F, scale=1.0 / math.sqrt(2 * L))
+            if cfg.mlp_bias:
+                layer["b_up"] = zeros(F)
+                layer["b_down"] = zeros(D)
+        return layer
+
     # -- partition specs (AutoTP analog) -------------------------------
 
     def partition_specs(self, params) -> Dict[str, Any]:
@@ -503,8 +662,9 @@ class Transformer:
 
         def spec_for(path: Tuple[str, ...], leaf):
             name = path[-1]
-            stacked = path[0] == "layers"
-            lead = (None,) if stacked else ()
+            # a one-kind stack leads with [layers], a kind of a pattern with
+            # [periods, layers of the kind a period]
+            lead = (None,) * (len(path) - 1) if path[0] == "layers" else ()
             if name.startswith("moe_shared"):
                 # shared expert = a dense MLP: column/row parallel like w_*
                 if name in ("moe_shared_w_gate", "moe_shared_w_up"):
@@ -520,11 +680,12 @@ class Transformer:
                 return P(*lead, *base)
             if name == "moe_gate":
                 return P(*lead, None, None)
-            if name in ("wq", "wk", "wv", "w_gate", "w_up"):
+            if name in ("wq", "wk", "wv", "w_gate", "w_up", "w_qkvz", "w_ba"):
                 return P(*lead, None, "tensor")       # column parallel
-            if name in ("wo", "w_down"):
+            if name in ("wo", "w_down", "w_out"):
                 return P(*lead, "tensor", None)       # row parallel
-            if name in ("b_up", "b_q", "b_k", "b_v", "q_norm_w", "k_norm_w"):
+            if name in ("b_up", "b_q", "b_k", "b_v") or (
+                    name in ("q_norm_w", "k_norm_w") and cfg.qk_norm):
                 return P(*lead, "tensor")  # column-parallel biases and gains
             if name == "embed":
                 return P("tensor", None)              # vocab parallel
@@ -568,9 +729,12 @@ class Transformer:
             return x, (None, None)
         return x, rope_table(T, cfg.rotary_dims, cfg.rope_theta)
 
-    def layer_apply(self, lw, h, rope, local=None, moe_on=None):
+    def layer_apply(self, lw, h, rope, local=None, moe_on=None, kind=None,
+                    remat_halves=False):
         """One transformer block. h [B, T, D] -> (h, (moe_aux, this layer's
         router stats or None)): a carry and an output, as ``lax.scan`` wants.
+        ``kind``: the layer's (mixer, ffn) of ``cfg.pattern``; None = the
+        first (a one-kind model's only one).
 
         ``local`` (traced bool scalar, GPT-Neo): this layer restricts
         attention to the trailing ``local_attention_window`` positions.
@@ -582,10 +746,38 @@ class Transformer:
         import jax.numpy as jnp
 
         cfg = self.config
+        mixer, ffn = kind or cfg.pattern[0]
         B, T = h.shape[:2]
         H, KV, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
         cos, sin = rope
         dtype = h.dtype
+        if mixer != "attn":
+            # the pattern's other mixers: no flag of the softmax-attention
+            # family reaches them (config_from_hf builds them; a pre-LN
+            # sequential block). Under remat the block's two halves are
+            # checkpointed EACH (``remat_halves``, set by stack_apply): the
+            # backward then holds the mixer's residuals or the FFN's, never
+            # both, for the same recomputation as one checkpoint a layer
+            # (Qwen3-Next at 16,384 tokens: 1.5 GB of a 16 GB chip, PR 33)
+            mix = self._gdn if mixer == "gdn" else self._gated_attention
+
+            def mixer_half(lw, h):
+                with trace.scope("attn_norm"):
+                    y = _norm(h, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm, eps=cfg.norm_eps)
+                return h + mix(lw, y, rope)
+
+            def ffn_half(lw, h):
+                with trace.scope("mlp_norm"):
+                    y2 = _norm(h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm, eps=cfg.norm_eps)
+                with trace.scope("moe" if ffn == "moe" else "mlp"):
+                    return self._ffn(lw, h, y2, None, moe_on, ffn)
+
+            if remat_halves:
+                policy = _remat_policy(cfg.remat_policy)
+                mixer_half = jax.checkpoint(mixer_half, policy=policy)
+                ffn_half = jax.checkpoint(ffn_half, policy=policy)
+            h, aux, stats = ffn_half(lw, mixer_half(lw, h))
+            return h, (aux, stats)
         if cfg.post_ln:
             y = h   # BERT: sublayer input is unnormalized; LN follows the add
         else:
@@ -649,16 +841,120 @@ class Transformer:
             else:
                 h = h + attn_out
                 y2 = _norm(h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm, eps=cfg.norm_eps)
-        with trace.scope("moe" if cfg.n_experts > 0 else "mlp"):
-            h, aux, stats = self._ffn(lw, h, y2, attn_out, moe_on)
+        with trace.scope("moe" if ffn == "moe" else "mlp"):
+            h, aux, stats = self._ffn(lw, h, y2, attn_out, moe_on, ffn)
         return h, (aux, stats)
 
-    def _ffn(self, lw, h, y2, attn_out, moe_on):
+    def _gated_attention(self, lw, y, rope):
+        """Qwen3-Next's full-attention mixer on the normed block input
+        y [B, T, D] -> [B, T, D]: ``[q | gate]`` per head from one projection,
+        RMSNorm of q and k per HEAD over its ``head_dim`` (the block norm's
+        kind and eps: a zero-centred gain here) before RoPE on the leading
+        ``rotary_dim``, causal GQA attention, ``(o * sigmoid(gate)) Wo``."""
+        import jax
+        from jax.ad_checkpoint import checkpoint_name
+
+        cfg = self.config
+        B, T = y.shape[:2]
+        H, KV, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+        cos, sin = rope
+        with trace.scope("attn_qkv"):
+            qg = (y @ lw["wq"]).reshape(B, T, H, 2 * Dh)
+            q, gate = qg[..., :Dh], qg[..., Dh:]
+            k = (y @ lw["wk"]).reshape(B, T, KV, Dh)
+            v = (y @ lw["wv"]).reshape(B, T, KV, Dh)
+            with trace.scope("attn_qk_norm"):
+                q = _head_norm(q, lw["q_norm_w"], cfg.norm, cfg.norm_eps)
+                k = _head_norm(k, lw["k_norm_w"], cfg.norm, cfg.norm_eps)
+            q = apply_rope(q, cos, sin, interleaved=cfg.rope_interleaved)
+            k = apply_rope(k, cos, sin, interleaved=cfg.rope_interleaved)
+        q = checkpoint_name(q, "q")
+        k = checkpoint_name(k, "kv")
+        v = checkpoint_name(v, "kv")
+        with trace.scope("attn_core"):
+            attn = self._attention(q, k, v, None)
+        attn = checkpoint_name(attn, "attn")
+        with trace.scope("attn_out"):
+            with trace.scope("attn_gate"):
+                attn = attn * jax.nn.sigmoid(gate)
+            return attn.reshape(B, T, H * Dh) @ lw["wo"]
+
+    def _gdn(self, lw, y, rope):
+        """The Gated DeltaNet mixer (``ops/gated_delta.py``) on the normed
+        block input y [B, T, D] -> [B, T, D]; ``rope`` is not used (the
+        convolution and the decay carry the order). Shapes from ``gdn_*``:
+        Hk key heads of dk, Hv value heads of dv, Hv a multiple of Hk.
+        Under the outer scopes of an attention layer (``attn_qkv``,
+        ``attn_core``, ``attn_out``), so that a reader's sums by layer hold,
+        with its own nested inside: ``gdn_conv``, ``gdn_gates`` (beta, the
+        log-decay g, the l2 norms), ``gdn_scan`` (the chunked rule),
+        ``gdn_out_norm``. g, beta, the norms and the rule's state are
+        float32; the projections, the convolution's result and the rule's
+        matmul operands are the compute dtype."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops.gated_delta import (causal_conv1d, gated_delta_chunked,
+                                       l2norm)
+
+        cfg = self.config
+        B, T = y.shape[:2]
+        Hk, Hv = cfg.gdn_key_heads, cfg.gdn_value_heads
+        dk, dv = cfg.gdn_key_dim, cfg.gdn_value_dim
+        rep = Hv // Hk
+        f32 = jnp.float32
+        with trace.scope("attn_qkv"):
+            qkvz = (y @ lw["w_qkvz"]).reshape(B, T, Hk, 2 * dk + 2 * rep * dv)
+            ba = (y @ lw["w_ba"]).reshape(B, T, Hk, 2 * rep)
+            q, k, v, z = jnp.split(qkvz, [dk, 2 * dk, 2 * dk + rep * dv], axis=-1)
+            b, a = ba[..., :rep].reshape(B, T, Hv), ba[..., rep:].reshape(B, T, Hv)
+            with trace.scope("gdn_conv"):
+                # the source's channel order: all q, all k, all v
+                mixed = jnp.concatenate(
+                    [q.reshape(B, T, Hk * dk), k.reshape(B, T, Hk * dk),
+                     v.reshape(B, T, Hv * dv)], axis=-1)
+                mixed = jax.nn.silu(causal_conv1d(mixed, lw["conv_w"]))
+                q, k, v = jnp.split(mixed, [Hk * dk, 2 * Hk * dk], axis=-1)
+            with trace.scope("gdn_gates"):
+                beta = jax.nn.sigmoid(b.astype(f32))
+                g = -jnp.exp(lw["A_log"].astype(f32)) * jax.nn.softplus(
+                    a.astype(f32) + lw["dt_bias"].astype(f32))
+                # each key head serves ``rep`` value heads
+                heads = lambda x: jnp.repeat(x.reshape(B, T, Hk, dk), rep, axis=2)
+                q = (l2norm(heads(q)) * dk ** -0.5).astype(y.dtype)
+                k = l2norm(heads(k)).astype(y.dtype)
+                v = v.reshape(B, T, Hv, dv)
+        with trace.scope("attn_core"):
+            with trace.scope("gdn_scan"):
+                # each device runs the rule on its own rows and heads, like a
+                # kernel (they are independent): left to XLA's partitioner
+                # under ZeRO-3 weights and full remat, the 8-device CPU mesh
+                # computed a wrong forward (loss off by 5e-3, PR 33)
+                from ..parallel.mesh import kernel_activation_spec, shard_kernel
+
+                wide = kernel_activation_spec(q.shape, heads_dim=2)
+                flat = kernel_activation_spec(g.shape, heads_dim=2)
+                o = shard_kernel(
+                    gated_delta_chunked,
+                    (wide, wide, wide, flat, flat), wide)(q, k, v, g, beta)
+        with trace.scope("attn_out"):
+            with trace.scope("gdn_out_norm"):
+                # a plain gain per head, then the z gate: w * o/rms(o) * silu(z)
+                o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                                      + cfg.norm_eps)
+                o = (lw["gdn_norm_w"].astype(f32) * o * jax.nn.silu(
+                    z.reshape(B, T, Hv, dv).astype(f32))).astype(y.dtype)
+            return o.reshape(B, T, Hv * dv) @ lw["w_out"]
+
+    def _ffn(self, lw, h, y2, attn_out, moe_on, ffn=None):
         """The block's second half: (MoE or dense) feed-forward on ``y2`` and
         the residual add. Returns (h, moe_aux, stats): ``stats`` is None for
         a dense model, else this layer's ``expert_tokens`` [E] int32 (the
-        token-choices each expert computed) and ``router_prob`` [E] (mean
-        router probability). ``moe_aux`` is the layer's own balancing loss;
+        token-choices the router gave each of ALL its experts), ``router_prob``
+        [E] (mean router probability), ``held_rows`` (the token-choices the
+        experts held here computed: all of them unless this is a rank's
+        share) and ``overflow_rows`` (held rows that did not fit the buffer
+        and were dropped). ``moe_aux`` is the layer's own balancing loss;
         for ``moe_aux="all_choices"`` it is divided by the layer count, which
         is HF's loss exactly at one layer and a per-layer form of it
         otherwise (``loss_and_stats`` computes the exact cross-layer form
@@ -671,8 +967,8 @@ class Transformer:
         dtype = h.dtype
         aux = jnp.zeros((), jnp.float32)
         stats = None
-        if cfg.n_experts > 0:
-            from ..moe.layer import moe_layer
+        if (ffn or cfg.pattern[0][1]) == "moe":
+            from ..moe.layer import held_buffer_rows, moe_layer
 
             expert_params = {name[4:]: lw[name] for name in lw
                              if name.startswith("moe_")
@@ -682,16 +978,26 @@ class Transformer:
                 # scanned=True: layer_apply always runs under stack_apply's
                 # lax.scan, where "auto" takes the capacity path
                 # (moe/resolve_moe_impl)
+                share = {}
+                if cfg.experts_held != cfg.n_experts:
+                    share = dict(expert_first=cfg.expert_first, buffer_rows=held_buffer_rows(
+                        math.prod(y2.shape[:-1]), cfg.moe_top_k, cfg.experts_held,
+                        cfg.n_experts, cfg.moe_held_rows_factor))
                 res = moe_layer(lw["moe_gate"], expert_params, y2, k=cfg.moe_top_k,
                                 capacity_factor=cfg.capacity_factor, activation=cfg.activation,
                                 impl=cfg.moe_impl, normalize_weights=cfg.moe_norm_topk,
-                                scanned=True, aux=cfg.moe_aux)
+                                scanned=True, aux=cfg.moe_aux, **share)
                 aux = res.aux_loss
                 if cfg.moe_aux == "all_choices":
                     aux = aux / cfg.n_layers
+                counts = res.metadata["expert_counts"].astype(jnp.int32)
                 return res.output, aux, {
-                    "expert_tokens": res.metadata["expert_counts"].astype(jnp.int32),
-                    "router_prob": res.metadata["router_prob"]}
+                    "expert_tokens": counts,
+                    "router_prob": res.metadata["router_prob"],
+                    "held_rows": jnp.asarray(res.metadata.get(
+                        "held_rows", counts.sum()), jnp.int32),
+                    "overflow_rows": jnp.asarray(res.metadata.get(
+                        "overflow_rows", 0), jnp.int32)}
 
             if moe_on is None:
                 ff, aux, stats = moe_branch(y2)
@@ -712,9 +1018,7 @@ class Transformer:
                     out = hh @ expert_params["w_down"][0].astype(dtype)
                     if "b_down" in expert_params:
                         out = out + expert_params["b_down"][0].astype(dtype)
-                    return out, jnp.zeros((), jnp.float32), {
-                        "expert_tokens": jnp.zeros((cfg.n_experts,), jnp.int32),
-                        "router_prob": jnp.zeros((cfg.n_experts,), jnp.float32)}
+                    return out, jnp.zeros((), jnp.float32), _no_routing_stats(cfg.n_experts)
 
                 from ..parallel.mesh import inside_manual_region
 
@@ -729,12 +1033,13 @@ class Transformer:
                 else:
                     ff, aux, stats = jax.lax.cond(moe_on, moe_branch, dense_branch, y2)
             if cfg.moe_shared_expert_ff > 0:
-                # Qwen2-MoE shared expert: a dense swiglu MLP every token
-                # runs, added with a per-token sigmoid gate
-                shared = (jax.nn.silu(y2 @ lw["moe_shared_w_gate"])
-                          * (y2 @ lw["moe_shared_w_up"])) @ lw["moe_shared_w_down"]
-                gate_s = jax.nn.sigmoid(y2 @ lw["moe_shared_gate"])
-                ff = ff + gate_s.astype(ff.dtype) * shared
+                # Qwen2-MoE / Qwen3-Next shared expert: a dense swiglu MLP
+                # every token runs, added with a per-token sigmoid gate
+                with trace.scope("moe_shared"):
+                    shared = (jax.nn.silu(y2 @ lw["moe_shared_w_gate"])
+                              * (y2 @ lw["moe_shared_w_up"])) @ lw["moe_shared_w_down"]
+                    gate_s = jax.nn.sigmoid(y2 @ lw["moe_shared_gate"])
+                    ff = ff + gate_s.astype(ff.dtype) * shared
         elif cfg.activation == "swiglu":
             # Tagged so remat_policy="save_ffn" can keep the two big FFN
             # projections (the bulk of layer FLOPs) out of the backward
@@ -1006,22 +1311,55 @@ class Transformer:
             mp = cfg.moe_layer_pattern
             moe_flags = per_layer_flags(lambda i: mp[i % len(mp)])
 
+        slots = self.slots()
+        if len(slots) > 1 and (ltd_mask is not None or layer_keep is not None
+                               or layer_ids is not None or use_local or mixed_moe):
+            raise NotImplementedError(
+                "a layer_pattern of several kinds runs the plain stack only: "
+                "random-LTD, progressive layer drop, pipeline stages, "
+                "attention_pattern and moe_layer_pattern vary one kind's layers")
         if ltd_mask is None and layer_keep is None and not mixed_moe:
-            if use_local:
-                def layer_fn(h, xs):
-                    lw, loc = xs
-                    return self.layer_apply(lw, h, rope, local=loc)
+            # The scan is over PERIODS of the pattern: the body runs the
+            # period's layers in order, each on its own kind's row (a one-kind
+            # model: a period of one layer, the row is the scan's own slice),
+            # each under its own remat, and hands out per layer what
+            # layer_apply does
+            routed = any(ffn == "moe" for *_, (_, ffn) in slots)
 
-                xs = (stacked_layers, local_flags)
-            else:
-                def layer_fn(h, lw):
-                    return self.layer_apply(lw, h, rope)
+            def run(kind):
+                # a layer of the softmax-attention family is checkpointed
+                # whole; the pattern's other mixers checkpoint their two
+                # halves themselves (layer_apply)
+                whole = kind[0] == "attn"
 
-                xs = stacked_layers
-            if cfg.remat:
-                layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(cfg.remat_policy))
+                def layer_fn(h, lw, loc):
+                    h, (aux, stats) = self.layer_apply(
+                        lw, h, rope, local=loc, kind=kind,
+                        remat_halves=cfg.remat and not whole)
+                    if stats is None and routed:     # a dense layer among routed ones
+                        stats = _no_routing_stats(cfg.n_experts)
+                    return h, (aux, stats)
+
+                if cfg.remat and whole:
+                    return jax.checkpoint(layer_fn, policy=_remat_policy(cfg.remat_policy))
+                return layer_fn
+
+            def period_fn(h, xs):
+                rows, loc = xs if use_local else (xs, None)
+                if len(slots) == 1:
+                    return run(slots[0][2])(h, rows, loc)
+                outs = []
+                for name, i, kind in slots:
+                    h, out = run(kind)(h, jax.tree.map(lambda a: a[i], rows[name]), None)
+                    outs.append(out)
+                return h, jax.tree.map(lambda *a: jnp.stack(a), *outs)
+
+            xs = (stacked_layers, local_flags) if use_local else stacked_layers
             with trace.scope("layers"):      # the scan's own slicing and stacking
-                x, (aux_losses, stats) = jax.lax.scan(layer_fn, x, xs)
+                x, (aux_losses, stats) = jax.lax.scan(period_fn, x, xs)
+            if len(slots) > 1:               # [periods, slots, ...] -> [layers, ...]
+                stats = jax.tree.map(
+                    lambda a: a.reshape((-1,) + a.shape[2:]), stats)
             aux = jnp.sum(aux_losses)
             return (x, aux, stats) if with_stats else (x, aux)
 
@@ -1266,7 +1604,11 @@ class Transformer:
         int32 arrays, counts that add up over microbatches (the engine keeps
         the last step's: ``Engine.last_step_stats``). An MoE model gives
         ``moe_expert_tokens`` [L, E], the token-choices each expert of each
-        layer computed on this batch; a chunked loss ``loss_chunks``, the
+        layer was given on this batch, ``moe_held_rows`` [L], the token-choices
+        the experts held here computed (all, unless ``n_experts_held`` makes
+        this one rank's share), and ``moe_overflow_rows`` [L], held rows that
+        did not fit the share's buffer and were dropped (0 for a model that
+        holds every expert); a chunked loss ``loss_chunks``, the
         trips of its scan, and ``loss_rows``, the rows they held, pad rows
         too, on the device that scanned them (rows a chunk: the quotient)."""
         import jax.numpy as jnp
@@ -1312,6 +1654,8 @@ class Transformer:
         stats = {}
         if routed is not None:
             stats["moe_expert_tokens"] = routed["expert_tokens"]
+            stats["moe_held_rows"] = routed["held_rows"]
+            stats["moe_overflow_rows"] = routed["overflow_rows"]
             if cfg.moe_aux == "all_choices":
                 # HF load_balancing_loss_func: the router probabilities and
                 # choices of ALL layers concatenated over tokens, so both

@@ -162,14 +162,104 @@ def _permuted_rows(x, perm, inverse, k: int = 1):
     return rows(x, perm, inverse, k)
 
 
-def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu"):
+def _rows_or_zeros(x, index):
+    """Rows ``index`` of x [N, M]; a row of zeros where the index is N or more
+    (how the held share marks "nothing here")."""
+    import jax.numpy as jnp
+
+    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
+
+
+def _held_dispatch(xs, order, inverse):
+    """One rank's share, the way in: xs [S, M] -> [R, M], row ``order[r] // k``
+    of xs at position r (zeros where ``order[r]`` is S * k: the position holds
+    nothing). ``inverse`` [S, k]: the position of each token-choice, R where it
+    has none (an absent expert, or dropped). Forward and backward move R rows
+    or S rows at a time, never S * k: the backward sums, choice by choice,
+    the gradient rows of a token's held choices."""
+    import jax
+
+    k = inverse.shape[1]
+
+    @jax.custom_vjp
+    def dispatch(xs, order, inverse):
+        return _rows_or_zeros(xs, order // k)
+
+    def fwd(xs, order, inverse):
+        return dispatch(xs, order, inverse), inverse
+
+    def bwd(inverse, g):
+        return sum(_rows_or_zeros(g, inverse[:, j]) for j in range(k)), None, None
+
+    dispatch.defvjp(fwd, bwd)
+    return dispatch(xs, order, inverse)
+
+
+def _held_combine(out_sorted, weights, order, inverse):
+    """One rank's share, the way back: out[s] = sum over the token's k choices
+    of ``weights[s, j]`` x row ``inverse[s, j]`` of out_sorted [R, M] (zeros
+    for a choice with no position), a choice at a time. The backward gathers
+    R rows of the cotangent for out_sorted (row ``order[r] // k``, scaled by
+    that choice's weight) and takes each weight's gradient as the dot of the
+    cotangent with its expert's row."""
+    import jax
+    import jax.numpy as jnp
+
+    S, k = inverse.shape
+    dtype = out_sorted.dtype
+
+    @jax.custom_vjp
+    def combine(out_sorted, weights, order, inverse):
+        return sum(weights[:, j, None].astype(dtype) * _rows_or_zeros(out_sorted, inverse[:, j])
+                   for j in range(k))
+
+    def fwd(out_sorted, weights, order, inverse):
+        return combine(out_sorted, weights, order, inverse), (out_sorted, weights, order, inverse)
+
+    def bwd(res, g):
+        out_sorted, weights, order, inverse = res
+        # a position that holds nothing reads weight 0 and a row of zeros
+        w_sorted = jnp.take(weights.reshape(-1), order, mode="fill", fill_value=0)
+        d_sorted = w_sorted[:, None].astype(dtype) * _rows_or_zeros(g, order // k)
+        d_weights = jnp.stack(
+            [jnp.sum(g.astype(jnp.float32)
+                     * _rows_or_zeros(out_sorted, inverse[:, j]).astype(jnp.float32), axis=-1)
+             for j in range(k)], axis=1).astype(weights.dtype)
+        return d_sorted, d_weights, None, None
+
+    combine.defvjp(fwd, bwd)
+    return combine(out_sorted, weights, order, inverse)
+
+
+def held_buffer_rows(tokens: int, k: int, held: int, n_experts: int,
+                     factor: float, tile: int = 512) -> int:
+    """Static rows of the buffer one rank's share of a routed layer sorts its
+    held token-choices into: ``factor`` x the balanced share (tokens x k x
+    held / n_experts), up to whole row tiles of the grouped GEMM, at most
+    every token-choice."""
+    balanced = tokens * k * held / n_experts
+    return min(tokens * k, tile * max(1, math.ceil(factor * balanced / tile)))
+
+
+def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu",
+                      expert_first: int = 0, buffer_rows: Optional[int] = None):
     """Dropless grouped-GEMM experts (reference cutlass moe_gemm /
     megablocks, SURVEY §2.13): tokens sort by expert and one grouped matmul
     per projection (``ops/grouped_gemm.py``: Pallas megablox ``gmm`` on
     TPU, ``lax.ragged_dot`` elsewhere) — no capacity padding slots, no
     dropped tokens, ragged group sizes straight onto the MXU.
 
-    xs [S, M]; topk_idx [S, k] int32; topk_w [S, k] f32 -> [S, M].
+    xs [S, M]; topk_idx [S, k] int32; topk_w [S, k] f32 -> (out [S, M],
+    rows computed, rows dropped): S * k and 0 unless ``buffer_rows`` is given.
+
+    ``buffer_rows`` None: ``params`` holds every expert ``topk_idx`` names.
+    Else ONE RANK'S SHARE: ``params`` holds the experts [``expert_first``,
+    ``expert_first`` + held) of the router's, and only the token-choices that
+    fall on those are gathered (into ``buffer_rows`` static rows, sorted by
+    held expert), multiplied and combined; a token-choice on an absent expert
+    adds nothing here (its part of the result is another rank's). Held rows
+    past the buffer are DROPPED, last experts first, and counted. There is
+    no exchange: a rank alone computes its own part of the layer's result.
     """
     import jax
     import jax.numpy as jnp
@@ -183,14 +273,32 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu"):
     k = topk_idx.shape[1]
     E = params["w_up"].shape[0]
     dtype = xs.dtype
+    share = buffer_rows is not None
     with trace.scope("moe_dispatch"):
         flat_e = topk_idx.reshape(-1)                        # [S*k]
+        if share:
+            # absent experts sort behind the held ones, as group E
+            local = flat_e - expert_first
+            flat_e = jnp.where((local >= 0) & (local < E), local, E)
         order = jnp.argsort(flat_e, stable=True)
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(S * k, dtype=order.dtype), unique_indices=True)
-        xsort = _permuted_rows(xs, order, inverse, k)        # [S*k, M]
-        group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
-        e_sorted = jnp.take(flat_e, order)                   # [S*k] expert per row
+        group_sizes = jnp.bincount(flat_e, length=E + share)[:E].astype(jnp.int32)
+        if share:
+            R = buffer_rows
+            ends = jnp.minimum(jnp.cumsum(group_sizes), R)
+            held, fit = group_sizes.sum(), ends[-1]
+            group_sizes = jnp.diff(ends, prepend=0)
+            # positions past the held rows hold nothing; absent and dropped
+            # choices have no position
+            order = jnp.where(jnp.arange(R) < fit, order[:R], S * k)
+            inverse = jnp.where(inverse < fit, inverse, R).reshape(S, k)
+            xsort = _held_dispatch(xs, order, inverse)       # [R, M]
+        else:
+            xsort = _permuted_rows(xs, order, inverse, k)    # [S*k, M]
+        # expert per row, for the bias epilogue (a position that holds
+        # nothing reads some held expert's bias into a row nobody reads)
+        e_sorted = jnp.minimum(jnp.take(flat_e, order, mode="clip"), E - 1)
 
     def b(key, t):
         # grouped-GEMM bias epilogue: gather each row's expert bias
@@ -218,8 +326,11 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu"):
             h = activation_fn(activation)(up)
         out_sorted = b("b_down", grouped_matmul(h, w("w_down"), group_sizes))
     with trace.scope("moe_combine"):
+        if share:
+            return _held_combine(out_sorted, topk_w, order, inverse), fit, held - fit
         out_flat = _permuted_rows(out_sorted, inverse, order)   # unsort
-        return (out_flat.reshape(S, k, M) * topk_w[..., None].astype(dtype)).sum(axis=1)
+        out = (out_flat.reshape(S, k, M) * topk_w[..., None].astype(dtype)).sum(axis=1)
+        return out, S * k, 0
 
 
 class MoEResult(NamedTuple):
@@ -258,7 +369,8 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
               activation: str = "swiglu", train: bool = True, rng=None,
               noise_std: float = 0.0, min_capacity: int = 4, expert_axis: str = "expert",
               mesh=None, impl: str = "auto", normalize_weights: bool = True,
-              scanned: bool = False, aux: str = "first_choice") -> MoEResult:
+              scanned: bool = False, aux: str = "first_choice",
+              expert_first: int = 0, buffer_rows: Optional[int] = None) -> MoEResult:
     """x [..., M] -> MoEResult. gate_w [M, E].
 
     impl:
@@ -276,6 +388,14 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
       - "auto": capacity when the mesh has an expert axis > 1 OR the layer
         runs under a scanned stack (``scanned=True``, see
         ``resolve_moe_impl``); ragged otherwise.
+
+    ``buffer_rows`` (with ``expert_first``): one expert-parallel rank's share.
+    ``gate_w`` routes over all E experts (top-k, weights and balancing loss
+    as for the whole layer), ``expert_params`` holds fewer, and the output is
+    the part of the layer's result that those give
+    (:func:`expert_mlp_ragged`; "ragged" only: the capacity paths dispatch
+    into slots of every expert). ``metadata`` then also carries ``held_rows``
+    (token-choices computed here) and ``overflow_rows`` (held rows dropped).
 
     ``aux``: which balancing loss ``aux_loss`` is (``gating.topk_select``).
     Named scopes inside the caller's ``moe``: ``moe_router`` (router matmul,
@@ -298,6 +418,10 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
             f"moe impl must be one of 'auto', 'capacity', 'capacity_einsum', "
             f"'ragged'; got {impl!r}")
 
+    if buffer_rows is not None and impl != "ragged":
+        raise ValueError(
+            "a rank's share of the experts (buffer_rows) runs the dropless "
+            f"'ragged' impl only; got impl={impl!r}")
     orig_shape = x.shape
     M = orig_shape[-1]
     xs = x.reshape(-1, M)
@@ -342,10 +466,15 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
                 logits, k, normalize_weights=normalize_weights, train=train,
                 rng=rng, noise_std=noise_std, aux=aux)
             counts = jnp.bincount(idx.reshape(-1), length=gate_w.shape[1])
-        out = expert_mlp_ragged(expert_params, xs, idx, w, activation)
-        return MoEResult(out.reshape(orig_shape), aux_loss,
-                         {"expert_counts": counts, "drop_fraction": jnp.zeros(()),
-                          "capacity": S, "router_prob": prob})
+        meta = {"expert_counts": counts, "drop_fraction": jnp.zeros(()),
+                "capacity": S, "router_prob": prob}
+        out, rows, dropped = expert_mlp_ragged(
+            expert_params, xs, idx, w, activation,
+            expert_first=expert_first, buffer_rows=buffer_rows)
+        if buffer_rows is not None:
+            meta.update(held_rows=rows, overflow_rows=dropped,
+                        drop_fraction=dropped / (S * k), capacity=buffer_rows)
+        return MoEResult(out.reshape(orig_shape), aux_loss, meta)
 
     if impl == "capacity_einsum":
         # the GShard dense-mask contract, kept as the parity oracle: the

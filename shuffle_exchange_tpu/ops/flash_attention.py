@@ -199,8 +199,13 @@ def _pallas_ok(q, k, causal: bool = True) -> bool:
         return False
     b, t, h, d = q.shape
     s = k.shape[1]
-    # Verified on-chip: the kernel handles head_dim 64 and 128 (fwd+bwd
-    # parity vs the jnp oracle). Ragged seq lengths are padded up to the
+    # Verified on-chip: head_dim 64 and 128 (fwd+bwd parity vs the jnp
+    # oracle), and head_dim 256 with 16 query heads over 2 KV heads at 8192
+    # positions (PR 33, the cell qwen3next-train: the splash MQA kernels'
+    # forward and both backward kernels; the attention layer's q/k/v/o
+    # gradients sit with every other leaf inside the float32 reference's bf16
+    # band, PERF.md section 6). Other multiples of 64 are admitted untried.
+    # Ragged seq lengths are padded up to the
     # 128-wide block inside pallas_attention — but only the causal path can
     # do that mask-free, so non-causal keeps the exact-multiple requirement.
     if not (d % 64 == 0 and t >= 128 and s >= 128):

@@ -50,16 +50,22 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 PREFIX = "sxt:"
 
 # every scope the package opens under jit, by the layer a reader sums it to.
-# "attn_qk_norm" nests inside "attn_qkv", the four "moe_*" scopes inside
+# "attn_qk_norm" nests inside "attn_qkv", the "moe_*" scopes inside
 # "moe" and the four "head_*" scopes (the chunked loss's scan) inside "loss":
-# a reader that sums by the outer name counts them with it.
+# a reader that sums by the outer name counts them with it. A Gated DeltaNet
+# layer opens the attention layer's outer scopes and its own inside them:
+# "gdn_conv" and "gdn_gates" inside "attn_qkv", "gdn_scan" (the chunked rule)
+# inside "attn_core", "gdn_out_norm" inside "attn_out"; a gated attention
+# layer's "attn_gate" sits inside "attn_out"; the shared expert runs under
+# "moe_shared" inside "moe".
 # "plumbing" is what belongs to no layer of the model: the layer scan's own
 # slicing and stacking, the masters' cast to the compute dtype, the
 # gradients' cast back and normalization
 SCOPES = {
-    "attn": ("attn_norm", "attn_qkv", "attn_qk_norm", "attn_core", "attn_out"),
+    "attn": ("attn_norm", "attn_qkv", "attn_qk_norm", "attn_core", "attn_out",
+             "attn_gate", "gdn_conv", "gdn_gates", "gdn_scan", "gdn_out_norm"),
     "mlp": ("mlp_norm", "mlp", "moe", "moe_router", "moe_dispatch",
-            "moe_experts", "moe_combine"),
+            "moe_experts", "moe_combine", "moe_shared"),
     "loss": ("embed", "final_norm", "loss", "head_logits", "head_softmax",
              "head_dx", "head_dw"),
     "optimizer": ("optimizer", "grad_clip", "weight_mix"),

@@ -2412,8 +2412,12 @@ class Engine:
     def last_step_stats(self) -> dict:
         """What the model reported beside the last ``train_batch``'s loss,
         as device arrays (no host sync in the step): an MoE model's
-        ``moe_expert_tokens`` [L, E] int32, the token-choices each expert of
-        each layer computed; a chunked loss's ``loss_chunks`` and
+        ``moe_expert_tokens`` [L, E] int32, the token-choices the router gave
+        each expert of each layer, ``moe_held_rows`` [L], those the experts
+        held here computed (all of them unless the model is one
+        expert-parallel rank's share, ``n_experts_held``) and
+        ``moe_overflow_rows`` [L], held rows that did not fit the share's
+        buffer and were dropped; a chunked loss's ``loss_chunks`` and
         ``loss_rows`` (the scan's trips and the rows they held on one device,
         summed over the step's microbatches). {} before the first step and
         for models that report nothing."""

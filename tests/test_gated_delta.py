@@ -1,0 +1,134 @@
+"""``ops/gated_delta.py``: the chunked (matrix-product) gated delta rule that
+trains against the token-by-token recurrence, outputs and every gradient, at
+small sizes in float32; the causal depthwise convolution and the l2 norm
+against their definitions."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from shuffle_exchange_tpu.ops import gated_delta as gd
+
+CASES = {
+    # name: (B, T, H, dk, dv, chunk, decay, beta)
+    "one_chunk": (1, 64, 1, 16, 16, 64, "mild", "mid"),
+    "two_chunks": (1, 128, 2, 16, 8, 64, "mild", "mid"),
+    "ragged_tail": (2, 150, 3, 16, 16, 64, "mild", "mid"),
+    "shorter_than_a_chunk": (2, 37, 2, 8, 16, 64, "mild", "mid"),
+    "strong_decay": (1, 130, 2, 16, 16, 64, "strong", "mid"),
+    "zero_decay": (2, 96, 2, 16, 16, 32, "zero", "mid"),
+    "beta_zero": (1, 100, 2, 16, 16, 64, "mild", "zero"),
+    "beta_one": (1, 100, 2, 16, 16, 64, "mild", "one"),
+    "small_chunk": (2, 50, 4, 8, 8, 16, "mild", "mid"),
+}
+
+
+def inputs(name):
+    B, T, H, dk, dv, chunk, decay, beta_kind = CASES[name]
+    ks = jax.random.split(jax.random.PRNGKey(sorted(CASES).index(name)), 5)
+    q = gd.l2norm(jax.random.normal(ks[0], (B, T, H, dk))) * dk ** -0.5
+    k = gd.l2norm(jax.random.normal(ks[1], (B, T, H, dk)))
+    v = jax.random.normal(ks[2], (B, T, H, dv))
+    g = {"mild": -0.2 * jax.random.uniform(ks[3], (B, T, H)),
+         "strong": -20.0 * jax.random.uniform(ks[3], (B, T, H)),
+         "zero": jnp.zeros((B, T, H))}[decay]
+    beta = {"mid": jax.nn.sigmoid(jax.random.normal(ks[4], (B, T, H))),
+            "zero": jnp.zeros((B, T, H)), "one": jnp.ones((B, T, H))}[beta_kind]
+    return (q, k, v, g, beta), chunk
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_chunked_equals_recurrent_outputs(name):
+    args, chunk = inputs(name)
+    want = gd.gated_delta_recurrent(*args)
+    with jax.default_matmul_precision("highest"):
+        got = gd.gated_delta_chunked(*args, chunk=chunk)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_chunked_equals_recurrent_gradients(name):
+    args, chunk = inputs(name)
+    probe = jax.random.normal(jax.random.PRNGKey(99),
+                              args[2].shape[:3] + (args[2].shape[-1],))
+
+    def scalar(fn):
+        return lambda *a: jnp.sum(fn(*a) * probe)
+
+    want = jax.grad(scalar(gd.gated_delta_recurrent), argnums=range(5))(*args)
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(scalar(lambda *a: gd.gated_delta_chunked(*a, chunk=chunk)),
+                       argnums=range(5))(*args)
+    for a, b, leaf in zip(got, want, "q k v g beta".split()):
+        assert bool(jnp.all(jnp.isfinite(a))), leaf
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-4, err_msg=leaf)
+
+
+def test_the_state_forgets_under_strong_decay():
+    """exp(g) ~ 0: every output is beta_t (q_t . k_t) v_t, its own token's."""
+    (q, k, v, _, beta), chunk = inputs("two_chunks")
+    g = jnp.full(beta.shape, -60.0)
+    got = gd.gated_delta_chunked(q, k, v, g, beta, chunk=chunk)
+    own = (beta * jnp.sum(q * k, axis=-1))[..., None] * v
+    np.testing.assert_allclose(got, own, rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_a_bf16_trainers_rule_carries_its_state_in_float32(seed):
+    """bf16 q, k and v at a memory of tens to hundreds of tokens: the chunked
+    rule (S in float32, rounded only as an operand) stays within 1% of the
+    float32 recurrence in the output and every gradient; the recurrence with
+    S rounded to bf16 after every token is over half as far again on each
+    (three to five times at the benchmark's size, PERF.md section 6, PR 33)."""
+    from shuffle_exchange_tpu.models import reference_qwen3next as ref
+
+    B, T, H, d = 1, 512, 4, 64
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    low = lambda x: x.astype(jnp.bfloat16)
+    q = low(gd.l2norm(jax.random.normal(ks[0], (B, T, H, d))) * d ** -0.5)
+    k = low(gd.l2norm(jax.random.normal(ks[1], (B, T, H, d))))
+    v = low(jax.nn.silu(jax.random.normal(ks[2], (B, T, H, d))))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[3], (B, T, H)))
+    memory = jnp.exp(jax.random.uniform(ks[4], (H,), minval=np.log(16.), maxval=np.log(256.)))
+    g = -jax.nn.softplus(jax.random.normal(ks[5], (B, T, H)) + 1.0) / (1.3133 * memory)
+    cotangent = jax.random.normal(ks[6], (B, T, H, d))
+
+    def answers(rule):
+        o, back = jax.vjp(lambda *a: rule(*a).astype(jnp.float32), q, k, v, g, beta)
+        return [x.astype(jnp.float32) for x in (o,) + back(cotangent)]
+
+    up = lambda x: x.astype(jnp.float32)
+    recurrence = lambda bits: lambda q, k, v, g, beta: ref.delta_rule(
+        up(q), up(k), up(v), g, beta, state_bits=bits)
+    with jax.default_matmul_precision("highest"):
+        exact, rounded = answers(recurrence(None)), answers(recurrence((8, 7)))
+    ours = answers(gd.gated_delta_chunked)
+    gap = lambda a, b: float(jnp.linalg.norm((a - b).ravel()) / jnp.linalg.norm(b.ravel()))
+    for mine, theirs, want, part in zip(ours, rounded, exact, "o q k v g beta".split()):
+        assert gap(mine, want) < 0.01 and 1.5 * gap(mine, want) < gap(theirs, want), part
+
+
+@pytest.mark.parametrize("width", [2, 4])
+def test_causal_conv_is_the_definition(width):
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 5))
+    w = jax.random.normal(jax.random.PRNGKey(1), (width, 5))
+    got = gd.causal_conv1d(x, w)
+    want = np.zeros(x.shape, np.float32)
+    for t in range(9):
+        for j in range(width):
+            s = t - (width - 1) + j
+            if s >= 0:
+                want[:, t] += np.asarray(w[j]) * np.asarray(x[:, s])
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    # nothing of a later position reaches an earlier one
+    bumped = gd.causal_conv1d(x.at[:, 6].add(1.0), w)
+    np.testing.assert_array_equal(bumped[:, :6], got[:, :6])
+
+
+def test_l2norm():
+    x = jax.random.normal(jax.random.PRNGKey(0), (3, 7))
+    np.testing.assert_allclose(
+        gd.l2norm(x), x / np.sqrt((np.asarray(x) ** 2).sum(-1, keepdims=True) + 1e-6),
+        rtol=1e-6)
