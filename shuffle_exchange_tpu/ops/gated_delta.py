@@ -13,7 +13,20 @@ and a write strength ``beta_t`` in [0, 1]::
 ``gated_delta_recurrent`` is that, one token at a time: the plain form, kept
 for the tests (the trainer never runs it). ``gated_delta_chunked`` is the form
 that trains: chunks of ``chunk`` tokens, everything inside a chunk as matrix
-products, and a ``lax.scan`` over the chunks that carries ``S``.
+products, and a walk over the chunks that carries ``S``. It has two bodies
+with the same arithmetic, chosen by what the code can observe
+(``kernel_route``: backend and shape, as ``ops/dispatch.py`` states it):
+
+  Pallas kernels  on a TPU backend where dk and dv are whole lane tiles (128),
+                  ``chunk`` is ``CHUNK`` and q, k, v are all bf16 or all
+                  float32: ``gdn_rule_fwd`` / ``gdn_rule_fwd_keep`` /
+                  ``gdn_rule_bwd`` behind one ``jax.custom_vjp``. The CPU
+                  suite drives the same kernels through the interpreter
+                  (``SXT_FUSED_INTERPRET=1``). Selected, they run or raise.
+  XLA ops         everywhere else (the CPU, the 8-device CPU mesh, narrow
+                  heads, other chunk sizes): einsums and a ``lax.scan`` over
+                  the chunks. The off-TPU path, and the kernels' oracle
+                  beside the recurrence.
 
 The chunked form, for one chunk of C tokens with ``gamma_i = sum_{t<=i} g_t``
 (cumulative inside the chunk) and ``S0`` the state at the chunk's start::
@@ -45,13 +58,34 @@ Operations the chunked form REQUIRES per head and chunk (2 x m x n x k per
 product; what ``chipbench/arith_hybrid.py`` counts, forward): K_beta K^T and
 Q K^T 2 x 2 C^2 dk; T's ten C^3 products (five squarings, five factors);
 W and U 2 C^2 (dk + dv); W S0, (Q e^gamma) S0 and K^T V_new 3 x 2 C dk dv;
-scores x V_new 2 C^2 dv. The backward is autodiff through all of it (about
-twice the forward) except T's, which is ``-T^T dT T^T``. The two parts that
-are parallel over chunks (everything up to W and U; the scores and O) are
-computed AGAIN in the backward instead of kept; the scan is not.
+scores x V_new 2 C^2 dv. The kernels take T in six products, not ten (see
+``_inverse``); the count above is the yardstick's and stays.
+
+What the kernels keep where. A grid step is one chunk of ``_HEADS_A_STEP``
+heads of one row; the grid's last axis walks the chunks in order and S
+[dk, dv] float32 stays in a VMEM scratch from one step to the next. Every
+[C, C] matrix (the decay, A, its powers, T, the scores), K_beta, V_beta, W,
+U and V_new live in VMEM only. HBM sees q, k, v as [B, H, T, d] (XLA's
+transpose of the mixer's [B, T, H, d], which it folds into the producing
+fusion), gamma and beta as rows of C numbers, and o. The forward that a
+backward follows (``gdn_rule_fwd_keep``) also writes each chunk's starting
+state S0 [B, H, N, dk, dv] float32 (537 MB a layer at 2 x 8,192 tokens x 32
+heads), which is all the backward needs beside the inputs; the pass of a
+``jax.checkpoint`` that keeps nothing runs ``gdn_rule_fwd``, which writes o
+alone. The backward kernel walks the chunks from the last to the first with
+dS in VMEM, computes the chunk's own matrices AGAIN from q, k, v, gamma,
+beta and S0 (T included: kept, 134 MB a layer of [C, C] float32 would go
+through HBM to save 2.2 ms of a layer's backward; measured and left out,
+PERF.md PR 34), passes cotangents through the casts unrounded, and takes
+``dA = -T^T dT T^T``. The XLA form's backward is autodiff through all of it
+(about twice the forward) except T's, which is that same formula; its two
+parts that are parallel over chunks are computed again under
+``jax.checkpoint``, its scan is not.
 """
 
 from __future__ import annotations
+
+import functools
 
 # Tokens a chunk of the chunked form: 64, where T = (I + A)^-1 is six products
 # of 64 x 64 and the scan over chunks has 128 trips at 8k tokens (ISSUE 33
@@ -144,26 +178,38 @@ def _unit_lower_inverse(A):
     return inverse(A)
 
 
+def _whole_chunks(q, k, v, g, beta, chunk):
+    """The five padded along T to a multiple of ``chunk`` with tokens that
+    write nothing (beta 0, g 0)."""
+    import jax.numpy as jnp
+
+    pad = -q.shape[1] % chunk
+    if not pad:
+        return q, k, v, g, beta
+    tail = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+    return tuple(tail(a) for a in (q, k, v, g, beta))
+
+
 def gated_delta_chunked(q, k, v, g, beta, chunk: int = CHUNK):
     """The same function as :func:`gated_delta_recurrent` in the chunked
-    (matrix-product) form of the module docstring; differentiable (autodiff
-    through the products and the scan over chunks). Same shapes;
+    (matrix-product) form of the module docstring; differentiable (the
+    kernels' own backward where :func:`kernel_route` chooses them, else
+    autodiff through the products and the scan over chunks). Same shapes;
     o [B, T, H, dv] float32. T need not divide by ``chunk``: the tail is
     padded with tokens that write nothing (beta 0, g 0) and cut off."""
     import jax
     import jax.numpy as jnp
 
+    route = kernel_route(q, k, v, chunk)
+    if route != "xla":
+        return _gated_delta_pallas(q, k, v, g, beta, interpret=route == "interpret")
     f32 = jnp.float32
     mxu = q.dtype
     B, T, H, dk = q.shape
     dv = v.shape[-1]
     C = chunk
-    pad = -T % C
-    if pad:
-        p4 = lambda a: jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        p3 = lambda a: jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
-        q, k, v, g, beta = p4(q), p4(k), p4(v), p3(g), p3(beta)
-    N = (T + pad) // C
+    q, k, v, g, beta = _whole_chunks(q, k, v, g, beta, C)
+    N = q.shape[1] // C
 
     def prod(spec, a, b):
         return jnp.einsum(spec, a.astype(mxu), b.astype(mxu),
@@ -225,5 +271,403 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = CHUNK):
         body, jnp.zeros((B, H, dk, dv), f32),
         (lead(W), lead(U), lead(k_tail), lead(q_head), lead(decay_last)))
     o = outputs(q, k, g, jnp.moveaxis(v_new, 0, 2), jnp.moveaxis(inter, 0, 2))
-    o = o.transpose(0, 2, 3, 1, 4).reshape(B, N * C, H, dv)
-    return o[:, :T] if pad else o
+    return o.transpose(0, 2, 3, 1, 4).reshape(B, N * C, H, dv)[:, :T]
+
+
+# ----------------------------------------------------------------------
+# The chunked form as Pallas kernels (what a TPU runs)
+# ----------------------------------------------------------------------
+
+# (row, head) pairs a grid step: their chains of small products are
+# independent, so the MXU works on one while another's result drains
+_HEADS_A_STEP = 8
+
+
+def kernel_route(q, k, v, chunk: int = CHUNK) -> str:
+    """Which form ``gated_delta_chunked`` runs, from what it can observe:
+    "pallas" on a TPU backend (``ops/dispatch.pallas_enabled``) at an
+    eligible shape (dk and dv whole lane tiles, chunk ``CHUNK``, q, k and v
+    all bf16 or all float32), "interpret" at such a shape under
+    ``SXT_FUSED_INTERPRET=1`` (the CPU suite's way to the same kernels),
+    else "xla"."""
+    import jax.numpy as jnp
+
+    from .dispatch import interpret_forced, pallas_enabled
+
+    eligible = (chunk == CHUNK and q.shape[-1] % 128 == 0
+                and v.shape[-1] % 128 == 0 and q.dtype == k.dtype == v.dtype
+                and q.dtype in (jnp.bfloat16, jnp.float32))
+    if not eligible:
+        return "xla"
+    if interpret_forced():
+        return "interpret"
+    return "pallas" if pallas_enabled() else "xla"
+
+
+def _gated_delta_pallas(q, k, v, g, beta, interpret: bool = False):
+    """``gated_delta_chunked`` through the kernels. q, k and v go in as
+    [B, H, T, d] and o comes out so (read as [B, T, H * d] lane blocks they
+    cost a relayout XLA does not fold away: 74 ms a step in
+    ``qwen3next-train``, PERF.md PR 34); gamma (the cumulative log-decay
+    inside each chunk) and beta go in as [B, H / G, N, G, C], a row of C
+    numbers a chunk and head. The padding, the cumulative sum and the
+    transposes are XLA's, and so are their gradients."""
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    B, T, H, _ = q.shape
+    C = CHUNK
+    q, k, v, g, beta = _whole_chunks(q, k, v, g, beta, C)
+    N = q.shape[1] // C
+    G = next(n for n in (_HEADS_A_STEP, 4, 2, 1) if H % n == 0)
+    rows = lambda a: a.reshape(B, N, C, H // G, G).transpose(0, 3, 1, 4, 2)
+    gamma = jnp.cumsum(g.astype(f32).reshape(B, N, C, H), axis=2)
+    wide = lambda a: jnp.swapaxes(a, 1, 2)                      # [B, H, T, d]
+    o = _delta_core(G, interpret)(wide(q), wide(k), wide(v), rows(gamma),
+                                  rows(beta.astype(f32)))
+    return jnp.swapaxes(o, 1, 2)[:, :T]
+
+
+@functools.lru_cache(maxsize=None)
+def _delta_core(G: int, interpret: bool):
+    """The rule on whole chunks as one ``jax.custom_vjp``: (q, k, v
+    [B, H, T, d], gamma, beta [B, H / G, N, G, C]) -> o [B, H, T, dv]
+    float32. Undifferentiated (and in the pass of a ``jax.checkpoint`` that
+    keeps nothing) the forward kernel writes o alone; differentiated it also
+    writes each chunk's starting state S0 [B, H, N, dk, dv] float32, all the
+    backward kernel needs beside the inputs."""
+    import jax
+
+    # each launch under its own jit, built once: a program that calls the
+    # rule in several layers then traces and lowers each kernel once, not
+    # once a layer (0.8 s a layer and lowering of the train step otherwise:
+    # 9 s of ``setup_s`` in ``qwen3next-train``, PERF.md PR 34)
+    launch = lambda fn, **static: jax.jit(functools.partial(
+        fn, G=G, interpret=interpret, **static))
+    forward, forward_keep = (launch(_forward, keep=keep) for keep in (False, True))
+    backward = launch(_backward)
+
+    @jax.custom_vjp
+    def core(q, k, v, gamma, beta):
+        return forward(q, k, v, gamma, beta)[0]
+
+    def fwd(q, k, v, gamma, beta):
+        o, s0 = forward_keep(q, k, v, gamma, beta)
+        return o, (q, k, v, gamma, beta, s0)
+
+    def bwd(kept, do):
+        return backward(*kept, do)
+
+    # optimize_remat: the pass of a jax.checkpoint that keeps nothing runs
+    # ``core`` (o alone), not ``fwd`` with its S0 thrown away
+    core.defvjp(fwd, bwd, optimize_remat=True)
+    return core
+
+
+def _blocks(G, chunk_at):
+    """The block specs of a grid step (row b, head group h, step n) that
+    works on chunk ``chunk_at(n)``: ``wide(d)`` for q, k, v, o [B, H, T, d],
+    ``flat`` for gamma and beta [B, H / G, N, G, C], ``state(dk, dv)`` for S0
+    [B, H, N, dk, dv]."""
+    from jax.experimental import pallas as pl
+
+    C = CHUNK
+    wide = lambda d: pl.BlockSpec((1, G, C, d), lambda b, h, n: (b, h, chunk_at(n), 0))
+    flat = pl.BlockSpec((1, 1, 1, G, C), lambda b, h, n: (b, h, chunk_at(n), 0, 0))
+    state = lambda dk, dv: pl.BlockSpec(
+        (1, G, 1, dk, dv), lambda b, h, n: (b, h, chunk_at(n), 0, 0))
+    return wide, flat, state
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=64 * 1024 * 1024)
+
+
+def _forward(q, k, v, gamma, beta, G, interpret, keep):
+    """The forward kernel's launch -> [o], or [o, S0] where ``keep``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    B, H, Tp, dk = q.shape
+    dv, N = v.shape[-1], Tp // CHUNK
+    wide, flat, state = _blocks(G, lambda n: n)
+    out_shape = [jax.ShapeDtypeStruct((B, H, Tp, dv), f32)]
+    out_specs = [wide(dv)]
+    if keep:
+        out_shape.append(jax.ShapeDtypeStruct((B, H, N, dk, dv), f32))
+        out_specs.append(state(dk, dv))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, G=G, keep=keep),
+        grid=(B, H // G, N),
+        in_specs=[wide(dk), wide(dk), wide(dv), flat, flat],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((G, dk, dv), f32)],
+        compiler_params=_compiler_params(), interpret=interpret,
+        name="gdn_rule_fwd_keep" if keep else "gdn_rule_fwd",
+    )(q, k, v, gamma, beta)
+
+
+def _backward(q, k, v, gamma, beta, s0, do, G, interpret):
+    """The backward kernel's launch -> [dq, dk, dv, dgamma, dbeta]."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    B, H, Tp, dk = q.shape
+    dv, N = v.shape[-1], Tp // CHUNK
+    # the sweep runs over the chunks from the last to the first
+    wide, flat, state = _blocks(G, lambda n: N - 1 - n)
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, G=G),
+        grid=(B, H // G, N),
+        in_specs=[wide(dk), wide(dk), wide(dv), flat, flat, state(dk, dv), wide(dv)],
+        out_specs=[wide(dk), wide(dk), wide(dv), flat, flat],
+        out_shape=[like(q), like(k), like(v), like(gamma), like(beta)],
+        scratch_shapes=[pltpu.VMEM((G, dk, dv), f32)],
+        compiler_params=_compiler_params(), interpret=interpret,
+        name="gdn_rule_bwd",
+    )(q, k, v, gamma, beta, s0, do.astype(f32))
+
+
+def _each(fn, *lists):
+    """[fn(a, b, ...) for a, b, ... in zip(*lists)]: one stage over the heads."""
+    return [fn(*xs) for xs in zip(*lists)]
+
+
+_NN = (((1,), (0,)), ((), ()))      # a b
+_NT = (((1,), (1,)), ((), ()))      # a b^T
+_TN = (((0,), (0,)), ((), ()))      # a^T b
+
+
+def _products(mxu):
+    """(prod, exact), each over one of the three contractions above. ``prod``:
+    operands rounded to ``mxu``, a float32 accumulator. ``exact``: float32
+    operands at float32 accuracy, which is what the inverse takes (and every
+    product where ``mxu`` is float32 itself): what ``Precision.HIGHEST`` is
+    on a TPU, six bf16 products of the operands' three bf16 parts (hi hi,
+    hi mid, mid hi, mid mid, hi lo, lo hi; float32 accumulator), written out
+    as ONE product over the six parts laid side by side along the
+    contraction, so the MXU takes packed bf16 rows and adds the six up
+    itself. (Mosaic's own float32 product pushes float32 rows part by part
+    and pops six results: 3.4 times the MXU instructions, and those are
+    what the forward kernel waits for.)"""
+    import jax
+    import jax.numpy as jnp
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+
+    def parts(x):
+        hi = x.astype(bf16)
+        rest = x - hi.astype(f32)
+        mid = rest.astype(bf16)
+        return hi, mid, (rest - mid.astype(f32)).astype(bf16)
+
+    def exact(a, b, dims=_NN):
+        (along_a,), (along_b,) = dims[0]
+        (ah, am, al), (bh, bm, bl) = parts(a), parts(b)
+        return jax.lax.dot_general(
+            jnp.concatenate([ah, am, ah, am, ah, al], axis=along_a),
+            jnp.concatenate([bh, bh, bm, bm, bl, bh], axis=along_b),
+            dims, preferred_element_type=f32)
+
+    def prod(a, b, dims=_NN):
+        return jax.lax.dot_general(a.astype(mxu), b.astype(mxu), dims,
+                                   preferred_element_type=f32)
+
+    return (exact if mxu == f32 else prod), exact
+
+
+def _inverse(A, eye, exact):
+    """[(I + A)^-1 for A in ``A``], A [C, C] float32 strictly lower
+    triangular, ``eye`` the [C, C] diagonal mask: ...(I + A^4)(I + A^2)(I - A).
+    The factors commute, and taken from the left P^2 and P T share P, so one
+    product of P with [T | P] (128 lanes, the MXU's width) gives both: six
+    products where the XLA form's order takes ten."""
+    import jax.numpy as jnp
+
+    C = A[0].shape[-1]
+    P = [-a for a in A]
+    T = [jnp.where(eye, 1.0, 0.0) + p for p in P]
+    P = _each(exact, P, P)
+    power = 2
+    while 2 * power < C:
+        R = _each(lambda p, t: exact(p, jnp.concatenate([t, p], axis=1)), P, T)
+        T = _each(lambda t, r: t + r[:, :C], T, R)
+        P = [r[:, C:] for r in R]
+        power *= 2
+    return _each(lambda t, p: t + exact(p, t), T, P)
+
+
+def _within_chunk(q, k, v, grow, brow, mxu):
+    """Everything of one chunk that does not depend on the state, as the
+    module docstring writes and rounds it, for several heads at once: lists
+    (one entry a head) of q, k [C, dk], v [C, dv] in ``mxu`` and grow
+    (gamma), brow (beta) [1, C] float32 -> a list of namespaces. Written
+    stage by stage over the heads, not head by head: the heads' chains of
+    small products are independent, and side by side in the program they
+    overlap on the MXUs. All of it stays in VMEM."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    C = CHUNK
+    prod, exact = _products(mxu)
+    i = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    eye, lower, strict = i == j, i >= j, i > j
+    heads = range(len(q))
+    # a row [1, C] as a column [C, 1]: through the diagonal
+    col = lambda row: jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+    gcol, bcol = _each(col, grow), _each(col, brow)
+    last = [g[:, C - 1:C] for g in grow]                        # [1, 1]
+    # exp only of the exponents that are used (<= 0), as in the XLA form
+    decay = _each(lambda gc, gr: jnp.where(
+        lower, jnp.exp(jnp.where(lower, gc - gr, 0.0)), 0.0), gcol, grow)
+    head = _each(jnp.exp, gcol)                                  # [C, 1]
+    tail = _each(lambda l, gc: jnp.exp(l - gc), last, gcol)
+    qf, kf, vf = ([x.astype(f32) for x in xs] for xs in (q, k, v))
+    kb, vb = _each(jnp.multiply, kf, bcol), _each(jnp.multiply, vf, bcol)
+    kk = _each(lambda a, b: prod(a, b, _NT), kb, k)
+    qk = _each(lambda a, b: prod(a, b, _NT), q, k)
+    T = _inverse(_each(lambda kk, d: jnp.where(strict, kk * d, 0.0), kk, decay),
+                 eye, exact)
+    kg = _each(jnp.multiply, kb, head)
+    W = _each(lambda t, x: prod(t, x).astype(mxu), T, kg)
+    U = _each(prod, T, vb)
+    return [types.SimpleNamespace(
+        eye=eye, strict=strict, qf=qf[h], kf=kf[h], vf=vf[h], bcol=bcol[h],
+        head=head[h], tail=tail[h], carry=jnp.exp(last[h]), decay=decay[h],
+        # [1, 1] -> [dk, dv] in two steps with the exp between them: Mosaic
+        # broadcasts along one of sublanes and lanes at a time
+        carry_col=jnp.exp(jnp.broadcast_to(last[h], (k[h].shape[1], 1))),
+        kb=kb[h], vb=vb[h], kg=kg[h], kk=kk[h], qk=qk[h], T=T[h], W=W[h],
+        U=U[h], qg=(qf[h] * head[h]).astype(mxu),
+        kt=(kf[h] * tail[h]).astype(mxu)) for h in heads]
+
+
+def _chunk_heads(q_ref, k_ref, v_ref, gamma_ref, beta_ref, G):
+    """``_within_chunk`` of the G heads of a grid step's blocks."""
+    heads = range(G)
+    return _within_chunk(
+        [q_ref[0, h] for h in heads],
+        [k_ref[0, h] for h in heads],
+        [v_ref[0, h] for h in heads],
+        [gamma_ref[0, 0, 0, h:h + 1, :] for h in heads],
+        [beta_ref[0, 0, 0, h:h + 1, :] for h in heads], q_ref.dtype)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, o_ref, *rest,
+                G, keep):
+    """One chunk of G heads of one row; the grid's last axis walks the
+    chunks in order and ``S`` [G, dk, dv] float32 carries each head's state
+    from one to the next in VMEM."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    S = rest[-1]
+    mxu = q_ref.dtype
+    prod, _ = _products(mxu)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        S[...] = jnp.zeros_like(S)
+
+    xs = _chunk_heads(q_ref, k_ref, v_ref, gamma_ref, beta_ref, G)
+    S0 = [S[h] for h in range(G)]
+    if keep:
+        for h in range(G):
+            rest[0][0, h, 0] = S0[h]
+    S_op = [s.astype(mxu) for s in S0]
+    v_new = [x.U - prod(x.W, s) for x, s in zip(xs, S_op)]
+    for h, x in enumerate(xs):
+        S[h] = S0[h] * x.carry_col + prod(x.kt, v_new[h], _TN)
+    for h, x in enumerate(xs):
+        o_ref[0, h] = prod(x.qg, S_op[h]) + prod(x.qk * x.decay, v_new[h])
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, s0_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dgamma_ref, dbeta_ref, dS, *, G):
+    """The same chunk's gradients; the grid's last axis walks the chunks
+    from the last to the first and ``dS`` carries the state's cotangent. The
+    chunk's own matrices are computed again from q, k, v, gamma, beta and
+    the kept S0; casts pass a cotangent through unrounded, and
+    ``dA = -T^T dT T^T`` as in the XLA form."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    C = CHUNK
+    mxu = q_ref.dtype
+    prod, exact = _products(mxu)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dS[...] = jnp.zeros_like(dS)
+
+    rowsum = lambda a: jnp.sum(a, axis=1, keepdims=True)        # [C, 1]
+    total = lambda a: jnp.sum(a, axis=0, keepdims=True)         # [1, n]
+    at_last = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1) == C - 1
+    heads = range(G)
+    xs = _chunk_heads(q_ref, k_ref, v_ref, gamma_ref, beta_ref, G)
+    q = [q_ref[0, h] for h in heads]
+    k = [k_ref[0, h] for h in heads]
+    do = [do_ref[0, h] for h in heads]
+    S0, dS1 = [s0_ref[0, h, 0] for h in heads], [dS[h] for h in heads]
+    # stage by stage over the heads, as in ``_within_chunk``
+    S_op = [s.astype(mxu) for s in S0]
+    v_new = _each(lambda x, s: x.U - prod(x.W, s), xs, S_op)
+    # o = qg S0 + scores v_new;  S1 = carry S0 + kt^T v_new
+    dv_new = _each(lambda x, do, ds: prod(x.qk * x.decay, do, _TN) + prod(x.kt, ds),
+                  xs, do, dS1)
+    for h, x in enumerate(xs):
+        dS[h] = (dS1[h] * x.carry_col + prod(x.qg, do[h], _TN)
+                 - prod(x.W, dv_new[h], _TN))
+    dscores = _each(lambda do, vn: prod(do, vn, _NT), do, v_new)
+    dqg = _each(lambda do, s: prod(do, s, _NT), do, S_op)
+    dkt = _each(lambda vn, ds: prod(vn, ds, _NT), v_new, dS1)
+    dcarry = _each(lambda ds, s: total(rowsum(ds * s)), dS1, S0)         # [1, 1]
+    # v_new = U - W S0;  W = T kg, U = T vb, T = (I + A)^-1
+    dW = _each(lambda dvn, s: -prod(dvn, s, _NT), dv_new, S_op)
+    dT = _each(lambda x, dw, dvn: prod(dw, x.kg, _NT) + prod(dvn, x.vb, _NT),
+              xs, dW, dv_new)
+    dkg = _each(lambda x, dw: prod(x.T, dw, _TN), xs, dW)
+    dvb = _each(lambda x, dvn: prod(x.T, dvn, _TN), xs, dv_new)
+    dA = _each(lambda x, dt: exact(dt, x.T, _NT), xs, dT)
+    dA = _each(lambda x, y: jnp.where(x.strict, -exact(x.T, y, _TN), 0.0), xs, dA)
+    # A = strict(kk decay), scores = qk decay (decay is 0 above the diagonal)
+    dkk = _each(lambda x, da: da * x.decay, xs, dA)
+    dqk = _each(lambda x, ds: ds * x.decay, xs, dscores)
+    # d(gamma_i - gamma_j)
+    ddiff = _each(lambda x, da, ds: (da * x.kk + ds * x.qk) * x.decay, xs, dA, dscores)
+    dkb = _each(lambda x, dkk, dkg, k: prod(dkk, k) + dkg * x.head, xs, dkk, dkg, k)
+    for h, x in enumerate(xs):
+        dq_ref[0, h] = (prod(dqk[h], k[h]) + dqg[h] * x.head).astype(dq_ref.dtype)
+    for h, x in enumerate(xs):
+        dk_ref[0, h] = (
+            prod(dkk[h], x.kb, _TN) + prod(dqk[h], q[h], _TN) + dkt[h] * x.tail
+            + dkb[h] * x.bcol).astype(dk_ref.dtype)
+    for h, x in enumerate(xs):
+        dv_ref[0, h] = (dvb[h] * x.bcol).astype(dv_ref.dtype)
+    # a column [C, 1] as a row [1, C]: through the diagonal
+    row = lambda c: total(jnp.where(xs[0].eye, c, 0.0))
+    for h, x in enumerate(xs):
+        dbeta_ref[0, 0, 0, h:h + 1, :] = row(
+            rowsum(dkb[h] * x.kf) + rowsum(dvb[h] * x.vf))
+        dtail = rowsum(dkt[h] * x.kf) * x.tail                          # [C, 1]
+        dgcol = (rowsum(ddiff[h]) - dtail
+                 + (rowsum(dkg[h] * x.kb) + rowsum(dqg[h] * x.qf)) * x.head)
+        dlast = total(dtail) + dcarry[h] * x.carry
+        dgamma_ref[0, 0, 0, h:h + 1, :] = (
+            row(dgcol) - total(ddiff[h]) + jnp.where(at_last, dlast, 0.0))

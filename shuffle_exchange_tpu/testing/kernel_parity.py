@@ -349,10 +349,49 @@ def _fused_decode(rng) -> Iterator[dict]:
         resid + (jax.nn.silu(yn @ deq[0]) * (yn @ deq[1])) @ deq[2], 1e-1)
 
 
+def _gated_delta(rng) -> Iterator[dict]:
+    """The gated delta rule's kernels (``ops/gated_delta.py``: forward, the
+    forward that keeps S0, backward) against the token-by-token recurrence:
+    o and the five gradients under a seeded cotangent. float32 inputs take
+    every product at float32 accuracy and agree closely; bf16 inputs are the
+    trainer's, and their distance is the rounding of the operands. Four
+    chunks and a ragged fifth, a memory of tens of tokens."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.gated_delta import (_gated_delta_pallas, gated_delta_recurrent,
+                                   l2norm)
+
+    B, T, H, d = 2, 300, 8, 128
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    q = l2norm(normal(B, T, H, d)) * d ** -0.5
+    k = l2norm(normal(B, T, H, d))
+    v = jax.nn.silu(normal(B, T, H, d))
+    g = -jax.nn.softplus(normal(B, T, H)) / 30.0
+    beta = jax.nn.sigmoid(normal(B, T, H))
+    cotangent = normal(B, T, H, d)
+
+    def answers(rule, *args):
+        o, back = jax.vjp(lambda *a: rule(*a).astype(jnp.float32), *args)
+        return (o,) + back(cotangent)
+
+    up = lambda x: x.astype(jnp.float32)
+    recurrence = lambda q, k, v, g, beta: gated_delta_recurrent(
+        up(q), up(k), up(v), g, beta)
+    for dtype, tols in [(jnp.float32, (1e-5, 1e-3, 5e-4, 1e-5, 5e-4, 5e-4)),
+                        (jnp.bfloat16, (2e-3, 2e-1, 3e-2, 5e-3, 2e-2, 2e-2))]:
+        args = (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta)
+        got = jax.jit(lambda *a: answers(_gated_delta_pallas, *a))(*args)
+        want = jax.jit(lambda *a: answers(recurrence, *a))(*args)
+        for part, a, b, tol in zip(("o", "dq", "dk", "dv", "dg", "dbeta"),
+                                   got, want, tols):
+            yield _check(f"gated-delta-{jnp.dtype(dtype).name}-{part}", a, b, tol)
+
+
 def run(seed: int = 0) -> Iterator[dict]:
     """All parity checks, one record each. One seeded generator feeds them
     in this order, so a record's inputs do not depend on which passed."""
     rng = np.random.default_rng(seed)
     for group in (_attention, _rmsnorm, _paged, _matmuls,
-                  _alibi_flash, _fused_decode):
+                  _alibi_flash, _fused_decode, _gated_delta):
         yield from group(rng)

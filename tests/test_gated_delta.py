@@ -1,7 +1,9 @@
 """``ops/gated_delta.py``: the chunked (matrix-product) gated delta rule that
 trains against the token-by-token recurrence, outputs and every gradient, at
-small sizes in float32; the causal depthwise convolution and the l2 norm
-against their definitions."""
+small sizes in float32; the same for the Pallas kernels a TPU runs, here
+through the interpreter (``SXT_FUSED_INTERPRET=1``) at the smallest shapes
+they take; the causal depthwise convolution and the l2 norm against their
+definitions."""
 
 import jax
 import jax.numpy as jnp
@@ -108,6 +110,162 @@ def test_a_bf16_trainers_rule_carries_its_state_in_float32(seed):
     gap = lambda a, b: float(jnp.linalg.norm((a - b).ravel()) / jnp.linalg.norm(b.ravel()))
     for mine, theirs, want, part in zip(ours, rounded, exact, "o q k v g beta".split()):
         assert gap(mine, want) < 0.01 and 1.5 * gap(mine, want) < gap(theirs, want), part
+
+
+# -- the Pallas kernels, through the interpreter ------------------------------
+
+KERNEL_CASES = {
+    # name: (B, T, H, decay, beta); dk = dv = 128, chunk 64
+    "one_chunk": (1, 64, 1, "mild", "mid"),
+    "several_chunks": (1, 192, 2, "mild", "mid"),
+    "ragged_tail": (2, 150, 2, "mild", "mid"),
+    "shorter_than_a_chunk": (2, 37, 2, "mild", "mid"),
+    "strong_decay": (2, 150, 2, "strong", "mid"),
+    "zero_decay": (2, 150, 2, "zero", "mid"),
+    "beta_zero": (2, 150, 2, "mild", "zero"),
+    "beta_one": (2, 150, 2, "mild", "one"),
+}
+PARTS = ("o", "dq", "dk", "dv", "dg", "dbeta")
+
+
+def kernel_inputs(name, dtype):
+    """((q, k, v, g, beta), cotangent): q, k, v rounded to ``dtype`` as a
+    mixer hands them over, the rest float32."""
+    B, T, H, decay, beta_kind = KERNEL_CASES[name]
+    d = 128
+    ks = jax.random.split(jax.random.PRNGKey(100 + sorted(KERNEL_CASES).index(name)), 6)
+    q = gd.l2norm(jax.random.normal(ks[0], (B, T, H, d))) * d ** -0.5
+    k = gd.l2norm(jax.random.normal(ks[1], (B, T, H, d)))
+    v = jax.nn.silu(jax.random.normal(ks[2], (B, T, H, d)))
+    g = {"mild": -0.2 * jax.random.uniform(ks[3], (B, T, H)),
+         "strong": -20.0 * jax.random.uniform(ks[3], (B, T, H)),
+         "zero": jnp.zeros((B, T, H))}[decay]
+    beta = {"mid": jax.nn.sigmoid(jax.random.normal(ks[4], (B, T, H))),
+            "zero": jnp.zeros((B, T, H)), "one": jnp.ones((B, T, H))}[beta_kind]
+    return ((q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta),
+            jax.random.normal(ks[5], (B, T, H, d)))
+
+
+def answers(rule):
+    """(o, dq, dk, dv, dg, dbeta) of ``rule`` under a cotangent, float32, as
+    one jitted program (one compilation a shape)."""
+    def both(args, cotangent):
+        o, back = jax.vjp(lambda *a: rule(*a).astype(jnp.float32), *args)
+        return tuple(x.astype(jnp.float32) for x in (o,) + back(cotangent))
+    return jax.jit(both)
+
+
+# traced under ``interpreted`` only: the route is chosen while tracing
+kernel_answers = answers(lambda *a: gd.gated_delta_chunked(*a))
+xla_answers = answers(lambda *a: gd.gated_delta_chunked(*a))
+recurrent_answers = answers(lambda q, k, v, g, beta: gd.gated_delta_recurrent(
+    q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32), g, beta))
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
+
+
+def calls(fn, *args):
+    """The names of the ``pallas_call``s in ``fn``'s jaxpr, with the number
+    of results each has. Traced anew every time (jax caches a trace by the
+    function it was given, and the route is chosen while tracing)."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append((eqn.params["name"], len(eqn.outvars)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jax.make_jaxpr(lambda *a: fn(*a))(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("why, q, v, chunk, forced, want", [
+    ("eligible", (1, 64, 2, 128), (1, 64, 2, 128), 64, True, "interpret"),
+    ("wider_heads", (1, 64, 2, 256), (1, 64, 2, 128), 64, True, "interpret"),
+    ("off_a_tpu", (1, 64, 2, 128), (1, 64, 2, 128), 64, False, "xla"),
+    ("narrow_keys", (1, 64, 2, 16), (1, 64, 2, 128), 64, True, "xla"),
+    ("narrow_values", (1, 64, 2, 128), (1, 64, 2, 64), 64, True, "xla"),
+    ("another_chunk", (1, 64, 2, 128), (1, 64, 2, 128), 32, True, "xla"),
+])
+def test_the_kernel_is_chosen_by_backend_and_shape(monkeypatch, why, q, v, chunk,
+                                                   forced, want):
+    if forced:
+        monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
+    q, v = jnp.zeros(q, jnp.bfloat16), jnp.zeros(v, jnp.bfloat16)
+    assert gd.kernel_route(q, q, v, chunk) == want
+
+
+@pytest.mark.parametrize("dtype, want", [
+    (jnp.bfloat16, "interpret"), (jnp.float32, "interpret"), (jnp.float16, "xla")])
+def test_the_kernel_takes_bf16_and_float32(interpreted, dtype, want):
+    x = jnp.zeros((1, 64, 2, 128), dtype)
+    assert gd.kernel_route(x, x, x) == want
+    assert gd.kernel_route(x, x.astype(jnp.float32), x) == (
+        want if dtype == jnp.float32 else "xla")
+
+
+def test_gated_delta_chunked_reaches_the_kernels(interpreted):
+    """Through the entry itself: undifferentiated, the forward kernel with o
+    alone; differentiated, the one that also keeps S0, and the backward."""
+    args, cotangent = kernel_inputs("ragged_tail", jnp.bfloat16)
+    assert calls(gd.gated_delta_chunked, *args) == [("gdn_rule_fwd", 1)]
+    both = lambda *a: jax.vjp(gd.gated_delta_chunked, *a)[1](cotangent)
+    assert calls(both, *args) == [("gdn_rule_fwd_keep", 2), ("gdn_rule_bwd", 5)]
+    # the pass of a jax.checkpoint that keeps nothing writes o alone too
+    remat = lambda *a: jax.vjp(jax.checkpoint(gd.gated_delta_chunked), *a)[1](cotangent)
+    assert calls(remat, *args) == [("gdn_rule_fwd", 1), ("gdn_rule_fwd_keep", 2),
+                                   ("gdn_rule_bwd", 5)]
+
+
+def test_off_a_tpu_the_xla_form_runs():
+    args, _ = kernel_inputs("ragged_tail", jnp.bfloat16)
+    assert calls(gd.gated_delta_chunked, *args) == []
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_CASES))
+def test_kernels_equal_recurrent_in_float32(interpreted, name):
+    """o and all five gradients; float32 inputs take every product at
+    float32 accuracy, so the kernels agree with the recurrence as the XLA
+    form does at HIGHEST."""
+    inputs = kernel_inputs(name, jnp.float32)
+    got, want = kernel_answers(*inputs), recurrent_answers(*inputs)
+    assert got[0].shape == want[0].shape and got[0].dtype == jnp.float32
+    for a, b, part in zip(got, want, PARTS):
+        assert bool(jnp.all(jnp.isfinite(a))), part
+        tol = dict(rtol=2e-4, atol=2e-5) if part == "o" else dict(rtol=2e-3, atol=2e-4)
+        np.testing.assert_allclose(a, b, err_msg=part, **tol)
+
+
+@pytest.mark.parametrize("name", sorted(set(KERNEL_CASES) - {"beta_zero"}))
+def test_kernels_read_no_further_from_the_recurrence_than_the_xla_form(
+        interpreted, monkeypatch, name):
+    """bf16 q, k and v: each of o, dq, dk, dv, dg, dbeta as a share of the
+    recurrence's norm (the benchmark's ``state_gaps``); the kernels' worst
+    part at most 1.15 times the XLA form's on the same numbers."""
+    inputs = kernel_inputs(name, jnp.bfloat16)
+    want = recurrent_answers(*inputs)
+    got = kernel_answers(*inputs)
+    monkeypatch.delenv("SXT_FUSED_INTERPRET")
+    xla = xla_answers(*inputs)
+    gap = lambda a, b: float(jnp.linalg.norm((a - b).ravel()) / jnp.linalg.norm(b.ravel()))
+    ours = {part: gap(a, b) for a, b, part in zip(got, want, PARTS)}
+    theirs = {part: gap(a, b) for a, b, part in zip(xla, want, PARTS)}
+    assert max(ours.values()) <= 1.15 * max(theirs.values()), (ours, theirs)
+    assert max(ours.values()) < 0.01, ours
+
+
+def test_kernels_write_nothing_where_beta_is_zero(interpreted):
+    """beta 0: no token writes, the state stays 0 and so does o; every
+    gradient but beta's is 0 and all are finite (bf16 inputs)."""
+    got = kernel_answers(*kernel_inputs("beta_zero", jnp.bfloat16))
+    for a, part in zip(got, PARTS):
+        assert bool(jnp.all(jnp.isfinite(a))), part
+        if part != "dbeta":
+            assert float(jnp.max(jnp.abs(a))) == 0.0, part
 
 
 @pytest.mark.parametrize("width", [2, 4])

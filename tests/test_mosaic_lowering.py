@@ -305,6 +305,29 @@ def test_lora_grouped_gemm_lowers():
                    x, a, b, slots)
 
 
+def _gated_delta_vjp(q, k, v, g, beta, cotangent):
+    """o and the five gradients through the rule's kernels themselves (the
+    dispatching entry takes the XLA form off a TPU)."""
+    from shuffle_exchange_tpu.ops.gated_delta import _gated_delta_pallas
+
+    o, back = jax.vjp(_gated_delta_pallas, q, k, v, g, beta)
+    return (o,) + back(cotangent)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_gated_delta_rule_lowers(dtype):
+    """Forward (o alone), the forward that keeps S0, and the backward; a
+    ragged tail; bf16 and float32 operands."""
+    from shuffle_exchange_tpu.ops.gated_delta import _gated_delta_pallas
+
+    B, T, H, d = 2, 150, 8, 128
+    wide = jnp.zeros((B, T, H, d), dtype)
+    flat = jnp.zeros((B, T, H), jnp.float32)
+    _tpu_lower(_gated_delta_pallas, wide, wide, wide, flat, flat)
+    _tpu_lower(_gated_delta_vjp, wide, wide, wide, flat, flat,
+               jnp.zeros((B, T, H, d), jnp.float32))
+
+
 @pytest.mark.parametrize("store", [jnp.int8, jnp.float8_e4m3fn])
 def test_paged_kernels_quantized_kv_lower(store):
     """kv_cache_dtype int8/fp8 (ISSUE 6): every streaming kernel that
@@ -558,3 +581,17 @@ def test_lora_and_alibi_kernels_compile(chip_compile):
     chip_compile(jax.grad(lambda q, k, v: alibi_flash_attention(
         q, k, v, slopes, True, False).astype(_F32).sum(), argnums=(0, 1, 2)),
         q, q, q)
+
+
+
+def test_gated_delta_rule_compiles(chip_compile):
+    """The three kernels at the shape ``qwen3next-train`` runs them: two
+    rows of 8,192 tokens, 32 heads of 128, bf16 with float32 g and beta."""
+    from shuffle_exchange_tpu.ops.gated_delta import _gated_delta_pallas
+
+    wide, flat = ((2, 8192, 32, 128), _BF16), ((2, 8192, 32), _F32)
+    chip_compile(_gated_delta_pallas, wide, wide, wide, flat, flat)
+    compiled = chip_compile(_gated_delta_vjp, wide, wide, wide, flat, flat,
+                            ((2, 8192, 32, 128), _F32))
+    text = compiled.as_text()
+    assert "gdn_rule_fwd_keep" in text and "gdn_rule_bwd" in text

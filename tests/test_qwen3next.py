@@ -158,6 +158,40 @@ def test_the_trainer_through_initialize(case):
     assert max(worst.values()) < 5e-3, worst
 
 
+def test_the_trainer_runs_the_rules_kernels_where_the_heads_are_lane_wide(monkeypatch):
+    """DeltaNet heads of 128 (the published width): under
+    ``SXT_FUSED_INTERPRET=1`` the train step's rule is the Pallas kernels
+    (interpreted), inside the period scan, the half-block's remat, ZeRO-3 and
+    the 8-device mesh's ``shard_kernel``; its first loss and first gradient
+    are the XLA form's. 80 tokens: a ragged second chunk."""
+    hf = dict(HF, num_hidden_layers=4, linear_num_key_heads=1,
+              linear_num_value_heads=2, linear_key_head_dim=128,
+              linear_value_head_dim=128)
+    ids = np.random.default_rng(11).integers(0, 256, (8, 81)).astype(np.int32)
+
+    def first_step():
+        model = Transformer(config_from_hf(hf))
+        engine = sxt.initialize(
+            model=model, params=driver.initial_params(model, 7),
+            config={"train_batch_size": 8, "steps_per_print": 10 ** 9,
+                    "optimizer": {"type": "FusedAdam", "params": {"lr": 1e-3}},
+                    "activation_checkpointing": {"enabled": True, "policy": "full"},
+                    "zero_optimization": {"stage": 3}}, seed=0)[0]
+        text = engine.compile({"input_ids": ids}).as_text()
+        loss = float(engine.train_batch({"input_ids": ids}))
+        return loss, driver.first_moment(engine.state.opt_state), text
+
+    xla_loss, xla_moment, xla_text = first_step()
+    monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
+    loss, moment, text = first_step()
+    assert "gdn_rule_bwd" in text and "gdn_rule_bwd" not in xla_text
+    assert abs(loss - xla_loss) < 1e-5
+    # (a leaf no token reached has a zero gradient in both)
+    worst = {k: v for k, v in gaps(moment, xla_moment).items() if v == v}
+    assert len(worst) > 20 and max(worst.values()) < 1e-3, worst
+    assert all(not np.any(np.asarray(moment[k])) for k in set(moment) - set(worst))
+
+
 def _patched(monkeypatch, name, fn):
     monkeypatch.setattr(ref, name, fn)
 
