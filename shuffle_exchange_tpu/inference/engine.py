@@ -170,6 +170,15 @@ class InferenceEngine:
                 "implemented: the inference engines keep one kind of state, a "
                 "KV cache, for one kind of layer (training through "
                 "sxt.initialize is; ROADMAP R-M5)")
+        if getattr(self._mcfg, "latent", False) or getattr(self._mcfg, "lead_layers", 0):
+            raise NotImplementedError(
+                "serving a model with latent attention (MLA: mixer 'mla', "
+                "DeepSeek-V3 / kanana-2) is not implemented: the engines have "
+                "no latent paged cache (one latent and one rotary key a token "
+                "instead of k and v), no prefill over it and no absorbed "
+                "decode path, and they scan ONE kind of layer (this stack "
+                "starts with leading layers of another) (training through "
+                "sxt.initialize is; ROADMAP R-M4)")
         if getattr(self._mcfg, "experts_held", 0) != getattr(self._mcfg, "n_experts", 0):
             raise NotImplementedError(
                 "serving one expert-parallel rank's share of the experts "

@@ -53,6 +53,7 @@ _ARCH_FAMILIES = {
     "InternLM2ForCausalLM": "internlm2",
     "OlmoeForCausalLM": "olmoe",
     "Qwen3NextForCausalLM": "qwen3next",
+    "DeepseekV3ForCausalLM": "deepseekv3",
 }
 
 
@@ -64,6 +65,7 @@ _MODEL_TYPE_FAMILIES = {"llama": "llama", "mistral": "llama", "qwen2": "qwen2",
                         "gpt_neo": "gptneo", "internlm": "internlm",
                         "internlm2": "internlm2", "olmoe": "olmoe",
                         "qwen3_next": "qwen3next",
+                        "deepseek_v3": "deepseekv3",
                         "megatron": "megatron",
                         "megatron-gpt": "megatron", "megatron_gpt": "megatron"}
 
@@ -77,6 +79,21 @@ def _family(cfg: Dict[str, Any]) -> str:
         raise ValueError(f"Unsupported HF architecture {archs or cfg.get('model_type')!r}; "
                          f"supported: {sorted(set(_ARCH_FAMILIES.values()))}")
     return family
+
+
+def _held_share(cfg: Dict[str, Any], family: str) -> Dict[str, Any]:
+    """``num_experts_held`` / ``expert_first`` / ``expert_buffer_factor`` (not
+    the source's keys: one expert-parallel rank's share of each layer's
+    experts) as ``TransformerConfig`` fields; {} for a model that holds all.
+    A share states its own buffer."""
+    if not cfg.get("num_experts_held"):
+        return {}
+    if "expert_buffer_factor" not in cfg:
+        raise ValueError(f"{family}: num_experts_held needs expert_buffer_factor "
+                         "(the held rows' buffer as a multiple of the balanced share)")
+    return dict(n_experts_held=int(cfg["num_experts_held"]),
+                expert_first=int(cfg.get("expert_first", 0)),
+                moe_held_rows_factor=float(cfg["expert_buffer_factor"]))
 
 
 def config_from_hf(hf_config) -> TransformerConfig:
@@ -319,14 +336,7 @@ def config_from_hf(hf_config) -> TransformerConfig:
             raise ValueError("qwen3_next: the layers must be whole periods of "
                              f"full_attention_interval={interval}")
         head = int(cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"])
-        share = {}
-        if cfg.get("num_experts_held"):
-            if "expert_buffer_factor" not in cfg:
-                raise ValueError("qwen3_next: num_experts_held needs expert_buffer_factor "
-                                 "(the held rows' buffer as a multiple of the balanced share)")
-            share = dict(n_experts_held=int(cfg["num_experts_held"]),
-                         expert_first=int(cfg.get("expert_first", 0)),
-                         moe_held_rows_factor=float(cfg["expert_buffer_factor"]))
+        share = _held_share(cfg, "qwen3_next")
         common.update(d_ff=cfg["moe_intermediate_size"], norm="rmsnorm_zc")
         return TransformerConfig(
             head_size=head,
@@ -345,6 +355,69 @@ def config_from_hf(hf_config) -> TransformerConfig:
             moe_shared_expert_ff=cfg.get("shared_expert_intermediate_size", 0),
             moe_impl="ragged", moe_aux="all_choices",
             aux_loss_coef=cfg.get("router_aux_loss_coef", 0.001), **common)
+    if family == "deepseekv3":
+        # deepseek_v3 as kanana-2-30b-a3b ships it: latent attention (MLA)
+        # WITHOUT query compression (q straight from the block input; the
+        # latent of kv_lora_rank and one rotary key a token; RoPE on
+        # qk_rope_head_dim dims stored as adjacent pairs), first_k_dense_replace
+        # leading dense layers of intermediate_size, then routed layers: a
+        # sigmoid router whose e_score_correction_bias selects and is not
+        # weighed (noaux_tc, one group), weights normalised over the chosen
+        # and scaled, dropless ("ragged"), n_shared_experts ungated shared
+        # experts as ONE SwiGLU, no balancing loss. ``num_experts_held`` /
+        # ``expert_first`` / ``expert_buffer_factor`` as for qwen3_next. What
+        # is not written here is refused by name.
+        refused = {
+            "q_lora_rank": cfg.get("q_lora_rank") is not None,
+            "rope_scaling": cfg.get("rope_scaling") is not None,
+            "n_group": int(cfg.get("n_group") or 1) > 1
+            or int(cfg.get("topk_group") or 1) > 1,
+            "topk_method": cfg.get("topk_method", "noaux_tc") != "noaux_tc",
+            "scoring_func": cfg.get("scoring_func", "sigmoid") != "sigmoid",
+            "attention_bias": bool(cfg.get("attention_bias")),
+            "moe_layer_freq": int(cfg.get("moe_layer_freq", 1)) != 1,
+        }
+        for key, bad in refused.items():
+            if bad:
+                raise ValueError(
+                    f"deepseek_v3 with {key}={cfg.get(key)!r} is not supported "
+                    "(written down: no query compression, no RoPE scaling and "
+                    "its attention-scale correction, one router group, "
+                    "noaux_tc over sigmoid scores, no attention bias, every "
+                    "layer after the leading dense ones routed)")
+        dc, dr = cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"]
+        lead = int(cfg.get("first_k_dense_replace", 0))
+        if not 0 <= lead < cfg["num_hidden_layers"]:
+            raise ValueError(f"deepseek_v3: first_k_dense_replace={lead} leaves no "
+                             f"routed layer of {cfg['num_hidden_layers']}")
+        share = _held_share(cfg, "deepseek_v3")
+        alpha = float(cfg.get("aux_loss_alpha") or 0.0)
+        common.update(d_ff=cfg["moe_intermediate_size"], n_kv_heads=None)
+        return TransformerConfig(
+            head_size=dc + dr, rotary_dim=dr,
+            rope_interleaved=bool(cfg.get("rope_interleave", True)),
+            mla_kv_rank=cfg["kv_lora_rank"], mla_qk_content_dim=dc,
+            mla_qk_rope_dim=dr, mla_v_dim=cfg["v_head_dim"],
+            layer_pattern=(("mla", "moe"),),
+            lead_layers=lead, lead_kind=("mla", "mlp") if lead else (),
+            dense_ff=cfg["intermediate_size"],
+            n_experts=cfg["n_routed_experts"], **share,
+            moe_top_k=cfg["num_experts_per_tok"],
+            moe_norm_topk=bool(cfg.get("norm_topk_prob", True)),
+            moe_score="sigmoid", moe_select_bias=True,
+            moe_weight_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+            # the aux-free update's speed (the DeepSeek-V3 report's gamma) is
+            # a training setting, in no published config.json: a key of this
+            # repository's, 0 (the bias held fixed) without it
+            moe_bias_update_rate=float(cfg.get("bias_update_speed", 0.0)),
+            moe_shared_expert_ff=(cfg["moe_intermediate_size"]
+                                  * int(cfg.get("n_shared_experts") or 0)),
+            moe_shared_gate="none", moe_impl="ragged",
+            # the complementary sequence-wise balance loss: deepseek-ai's own
+            # config keys (``seq_aux``, ``aux_loss_alpha``); a config.json
+            # without them (transformers' modelling code computes none): none
+            moe_aux="sequence" if alpha and cfg.get("seq_aux", True) else "none",
+            aux_loss_coef=alpha, **common)
     if family == "mixtral":
         return TransformerConfig(
             n_experts=cfg["num_local_experts"], moe_top_k=cfg.get("num_experts_per_tok", 2),
@@ -379,6 +452,13 @@ def _stack(sd: Dict[str, Any], fmt: str, L: int, transpose: bool = False) -> np.
 def params_from_state_dict(sd: Dict[str, Any], config: TransformerConfig,
                            family: str, megatron_v2: bool = True) -> Dict[str, Any]:
     """Re-lay an HF state dict into the zoo Transformer's stacked format."""
+    if config.latent or config.lead_layers:
+        raise NotImplementedError(
+            f"importing {family} weights is not implemented: the latent-"
+            "attention leaves (mla_*) and the leading layers (params['lead']) "
+            "have their own names and no checkpoint of them has been loaded "
+            "yet; config_from_hf and training from Transformer.init work "
+            "(chipbench/KANANA2.md maps the leaves to the source's names)")
     if len(config.pattern) > 1:
         raise NotImplementedError(
             f"importing {family} weights is not implemented: a stack of "

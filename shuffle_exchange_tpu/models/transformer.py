@@ -101,7 +101,9 @@ class TransformerConfig:
     # "first_choice": DeepSpeed's l_aux (first choice only), summed over
     # layers. "all_choices": HF load_balancing_loss_func (OLMoE, Mixtral's HF
     # form): E * sum_e f[e] * P[e] with f counting all k choices and both
-    # means taken over the tokens of ALL layers together.
+    # means taken over the tokens of ALL layers together. "sequence":
+    # DeepSeek-V3's sequence-wise balance loss, a layer's own, summed over the
+    # routed layers (``aux_loss_coef`` is its alpha). "none": no loss.
     moe_aux: str = "first_choice"
     # Megatron --expert-interval interleaving: per-layer MoE flags, cycled
     # over n_layers; () = every layer is MoE (when n_experts > 0). Dense
@@ -174,6 +176,46 @@ class TransformerConfig:
     n_experts_held: int = 0
     expert_first: int = 0
     moe_held_rows_factor: float = 3.0
+    # Latent attention (DeepSeek-V2/V3 MLA; mixer "mla" of a layer_pattern or
+    # of lead_kind): q [H x (content + rope)] straight from the block input
+    # (no query compression), ONE down-projection to a latent of
+    # ``mla_kv_rank`` and one rotary key of ``mla_qk_rope_dim`` a token, an
+    # RMSNorm on the latent, an up-projection to per-head content keys and
+    # values. Scores are (content + rope) wide, values ``mla_v_dim``; RoPE
+    # (``rotary_dim`` = the rope dims, ``rope_interleaved`` as the source
+    # stores them) turns the rope dims only. ``head_size`` = content + rope.
+    mla_kv_rank: int = 0
+    mla_qk_content_dim: int = 0
+    mla_qk_rope_dim: int = 0
+    mla_v_dim: int = 0
+    # Leading layers: ``lead_layers`` layers of ``lead_kind`` (mixer, ffn)
+    # come before the periods of ``layer_pattern`` (DeepSeek's
+    # first_k_dense_replace: dense layers before the routed ones). They are
+    # stacked on their own (``params["lead"]`` [lead_layers, ...]) and run in
+    # a scan of their own; ``n_layers`` counts them, and the rest must be
+    # whole periods. 0 = none: every model before PR 35.
+    lead_layers: int = 0
+    lead_kind: Tuple[str, str] = ()
+    # Width of a dense ("mlp") layer's FFN where the model also has experts
+    # of width ``d_ff`` (0 = d_ff too).
+    dense_ff: int = 0
+    # How a shared expert joins: "sigmoid" = through a per-token sigmoid gate
+    # (Qwen2-MoE, Qwen3-Next), "none" = added as it is (DeepSeek-V3).
+    moe_shared_gate: str = "sigmoid"
+    # The router's score (gating.topk_select): "softmax" over the experts, or
+    # "sigmoid" of each logit (DeepSeek-V3). ``moe_select_bias``: a per-expert
+    # bias added to the scores for the CHOICE only, not weighed
+    # (``moe_select_bias`` leaf; the aux-free balancing buffer: no gradient).
+    # ``moe_weight_scale`` multiplies the combine weights. The bias is a
+    # BUFFER, not a weight: the optimizer's update of it is thrown away
+    # (``Transformer.update_buffers``) and after each step it moves by
+    # ``moe_bias_update_rate`` (DeepSeek-V3's bias update speed gamma; 0: held
+    # fixed) towards balance: down where an expert of the step's batch got
+    # more than the mean of the token-choices, up where it got fewer.
+    moe_score: str = "softmax"
+    moe_select_bias: bool = False
+    moe_weight_scale: float = 1.0
+    moe_bias_update_rate: float = 0.0
 
     @property
     def kv_heads(self) -> int:
@@ -198,9 +240,34 @@ class TransformerConfig:
             ("attn", "moe" if self.n_experts > 0 else "mlp"),)
 
     @property
+    def kinds_used(self) -> Tuple[Tuple[str, str], ...]:
+        """Every (mixer, ffn) some layer has: the leading kind and the period's."""
+        lead = (tuple(self.lead_kind),) if self.lead_layers else ()
+        return lead + self.pattern
+
+    @property
     def recurrent(self) -> bool:
         """Some layer carries a recurrent state instead of a KV cache."""
-        return any(mixer == "gdn" for mixer, _ in self.pattern)
+        return any(mixer == "gdn" for mixer, _ in self.kinds_used)
+
+    @property
+    def latent(self) -> bool:
+        """Some layer attends through a latent (MLA) instead of k and v."""
+        return any(mixer == "mla" for mixer, _ in self.kinds_used)
+
+    @property
+    def routed_layers(self) -> int:
+        """Layers whose FFN is routed: the rows of the routing counters."""
+        if self.n_experts <= 0:
+            return 0
+        period = self.pattern
+        periods = (self.n_layers - self.lead_layers) // len(period)
+        lead = self.lead_layers if self.lead_layers and self.lead_kind[1] == "moe" else 0
+        return lead + periods * sum(1 for _, ffn in period if ffn == "moe")
+
+    @property
+    def dense_ff_dim(self) -> int:
+        return self.dense_ff or self.ff_dim
 
     @property
     def ff_dim(self) -> int:
@@ -311,15 +378,19 @@ def _head_norm(x, weight, kind: str, eps: float):
     return rmsnorm_reference(x, 1.0 + gain if kind == "rmsnorm_zc" else gain, eps)
 
 
-def _no_routing_stats(n_experts: int) -> dict:
+def _no_routing_stats(n_experts: int, weights: bool = False) -> dict:
     """What a layer that routes nothing reports (a dense layer among routed
-    ones), shaped as a routed layer's stats."""
+    ones), shaped as a routed layer's stats (``weights``: of a router with a
+    selection bias, which also reports ``expert_weight``)."""
     import jax.numpy as jnp
 
-    return {"expert_tokens": jnp.zeros((n_experts,), jnp.int32),
-            "router_prob": jnp.zeros((n_experts,), jnp.float32),
-            "held_rows": jnp.zeros((), jnp.int32),
-            "overflow_rows": jnp.zeros((), jnp.int32)}
+    out = {"expert_tokens": jnp.zeros((n_experts,), jnp.int32),
+           "router_prob": jnp.zeros((n_experts,), jnp.float32),
+           "held_rows": jnp.zeros((), jnp.int32),
+           "overflow_rows": jnp.zeros((), jnp.int32)}
+    if weights:
+        out["expert_weight"] = jnp.zeros((n_experts,), jnp.float32)
+    return out
 
 
 def rope_table(seq_len: int, head_dim: int, theta: float):
@@ -505,6 +576,17 @@ class Transformer:
             params["pos_embed"] = jax.random.normal(
                 next(keys), (cfg.max_seq_len + cfg.pos_offset, D), jnp.float32) * 0.02
         pattern = cfg.pattern
+        if cfg.lead_layers:
+            # the leading layers, stacked on their own [lead_layers, ...], from
+            # a key of their own: the draws of the layers that follow do not
+            # move with them
+            if not 0 < cfg.lead_layers < L or len(cfg.lead_kind) != 2:
+                raise ValueError(f"lead_layers {cfg.lead_layers} of kind "
+                                 f"{cfg.lead_kind!r} in a stack of {L} layers")
+            params["lead"] = self._init_kind(
+                iter(jax.random.split(jax.random.fold_in(rng, 0x1EAD), 16)),
+                tuple(cfg.lead_kind), (cfg.lead_layers,))
+            L = L - cfg.lead_layers
         if len(pattern) == 1:
             # one kind: every leaf stacked [L, ...]
             params["layers"] = self._init_kind(keys, pattern[0], (L,))
@@ -598,6 +680,20 @@ class Transformer:
                 "w_out": stack(next(keys), (Hv * dv, D), Hv * dv,
                                scale=1.0 / math.sqrt(2 * L)),
             })
+        elif mixer == "mla":
+            r, dc, dr, dv = (cfg.mla_kv_rank, cfg.mla_qk_content_dim,
+                             cfg.mla_qk_rope_dim, cfg.mla_v_dim)
+            layer.update({
+                # per head [content dc | rope dr]
+                "mla_wq": stack(next(keys), (D, H * (dc + dr)), D),
+                # [latent r | the ONE rotary key dr]
+                "mla_wkv_a": stack(next(keys), (D, r + dr), D),
+                "mla_kv_norm_w": ones(r),
+                # per head [content key dc | value dv]
+                "mla_wkv_b": stack(next(keys), (r, H * (dc + dv)), r),
+                "mla_wo": stack(next(keys), (H * dv, D), H * dv,
+                                scale=1.0 / math.sqrt(2 * L)),
+            })
         else:
             gated = mixer == "gated_attn"
             layer.update({
@@ -631,6 +727,10 @@ class Transformer:
             per_layer = [init_expert_mlp(k, cfg.experts_held, D, F, cfg.activation)
                          for k in jrandom.split(ek, n)]
             layer["moe_gate"] = stack(next(keys), (D, cfg.n_experts), D)
+            if cfg.moe_select_bias:
+                # the aux-free balancing buffer: selects, is not weighed, gets
+                # no gradient (gating.topk_select); 0 = no bias
+                layer["moe_select_bias"] = zeros(cfg.n_experts)
             for name in per_layer[0]:
                 held = jnp.stack([p[name] for p in per_layer])
                 layer[f"moe_{name}"] = held.reshape(lead + held.shape[1:])
@@ -639,12 +739,18 @@ class Transformer:
                 layer["moe_shared_w_gate"] = stack(next(keys), (D, Fs), D)
                 layer["moe_shared_w_up"] = stack(next(keys), (D, Fs), D)
                 layer["moe_shared_w_down"] = stack(next(keys), (Fs, D), Fs)
-                layer["moe_shared_gate"] = zeros(D, 1)
+                if cfg.moe_shared_gate == "sigmoid":
+                    layer["moe_shared_gate"] = zeros(D, 1)
+                elif cfg.moe_shared_gate != "none":
+                    raise ValueError("moe_shared_gate must be 'sigmoid' or 'none'; "
+                                     f"got {cfg.moe_shared_gate!r}")
         elif cfg.activation == "swiglu":
+            F = cfg.dense_ff_dim
             layer["w_gate"] = stack(next(keys), (D, F), D)
             layer["w_up"] = stack(next(keys), (D, F), D)
             layer["w_down"] = stack(next(keys), (F, D), F, scale=1.0 / math.sqrt(2 * L))
         else:
+            F = cfg.dense_ff_dim
             layer["w_up"] = stack(next(keys), (D, F), D)
             layer["w_down"] = stack(next(keys), (F, D), F, scale=1.0 / math.sqrt(2 * L))
             if cfg.mlp_bias:
@@ -664,7 +770,7 @@ class Transformer:
             name = path[-1]
             # a one-kind stack leads with [layers], a kind of a pattern with
             # [periods, layers of the kind a period]
-            lead = (None,) * (len(path) - 1) if path[0] == "layers" else ()
+            lead = (None,) * (len(path) - 1) if path[0] in ("layers", "lead") else ()
             if name.startswith("moe_shared"):
                 # shared expert = a dense MLP: column/row parallel like w_*
                 if name in ("moe_shared_w_gate", "moe_shared_w_up"):
@@ -672,6 +778,8 @@ class Transformer:
                 if name == "moe_shared_w_down":
                     return P(*lead, "tensor", None)
                 return P(*lead, None, None)      # the scalar gate
+            if name == "moe_select_bias":
+                return P(*lead, None)
             if name.startswith("moe_") and name != "moe_gate":
                 # single source of truth for expert sharding lives in moe/layer.py
                 from ..moe.layer import expert_partition_specs
@@ -680,9 +788,10 @@ class Transformer:
                 return P(*lead, *base)
             if name == "moe_gate":
                 return P(*lead, None, None)
-            if name in ("wq", "wk", "wv", "w_gate", "w_up", "w_qkvz", "w_ba"):
+            if name in ("wq", "wk", "wv", "w_gate", "w_up", "w_qkvz", "w_ba",
+                        "mla_wq", "mla_wkv_b"):
                 return P(*lead, None, "tensor")       # column parallel
-            if name in ("wo", "w_down", "w_out"):
+            if name in ("wo", "w_down", "w_out", "mla_wo"):
                 return P(*lead, "tensor", None)       # row parallel
             if name in ("b_up", "b_q", "b_k", "b_v") or (
                     name in ("q_norm_w", "k_norm_w") and cfg.qk_norm):
@@ -759,7 +868,8 @@ class Transformer:
             # backward then holds the mixer's residuals or the FFN's, never
             # both, for the same recomputation as one checkpoint a layer
             # (Qwen3-Next at 16,384 tokens: 1.5 GB of a 16 GB chip, PR 33)
-            mix = self._gdn if mixer == "gdn" else self._gated_attention
+            mix = {"gdn": self._gdn, "gated_attn": self._gated_attention,
+                   "mla": self._mla}[mixer]
 
             def mixer_half(lw, h):
                 with trace.scope("attn_norm"):
@@ -879,6 +989,53 @@ class Transformer:
                 attn = attn * jax.nn.sigmoid(gate)
             return attn.reshape(B, T, H * Dh) @ lw["wo"]
 
+    def _mla(self, lw, y, rope):
+        """The latent-attention mixer (DeepSeek-V2/V3 MLA without query
+        compression) on the normed block input y [B, T, D] -> [B, T, D].
+        ``q = y Wq`` per head [content dc | rope dr]; ``[c | k_r] = y Wkv_a``:
+        the latent (``mla_kv_rank`` wide) and ONE rotary key a token, shared
+        by all heads; ``[k_c | v] = RMSNorm(c) Wkv_b`` per head. RoPE turns
+        q's rope dims and k_r (pairs in place: ``rope_interleaved``), the key
+        is ``[k_c | k_r]``, scores are dc + dr wide (scaled by its root),
+        values ``mla_v_dim``. Its own scopes nest in ``attn_qkv``: ``mla_q``,
+        ``mla_kv_down``, ``mla_kv_norm``, ``mla_kv_up``, ``mla_rope`` (the
+        rotation, k_r's broadcast over the heads, the concatenations)."""
+        import jax.numpy as jnp
+        from jax.ad_checkpoint import checkpoint_name
+
+        cfg = self.config
+        B, T = y.shape[:2]
+        H = cfg.n_heads
+        r, dc, dr, dv = (cfg.mla_kv_rank, cfg.mla_qk_content_dim,
+                         cfg.mla_qk_rope_dim, cfg.mla_v_dim)
+        cos, sin = rope
+        with trace.scope("attn_qkv"):
+            with trace.scope("mla_q"):
+                q = (y @ lw["mla_wq"]).reshape(B, T, H, dc + dr)
+            with trace.scope("mla_kv_down"):
+                down = y @ lw["mla_wkv_a"]
+                c, k_r = down[..., :r], down[..., r:]
+            with trace.scope("mla_kv_norm"):
+                c = _norm(c, lw["mla_kv_norm_w"], 0, "rmsnorm", eps=cfg.norm_eps)
+            with trace.scope("mla_kv_up"):
+                kv = (c @ lw["mla_wkv_b"]).reshape(B, T, H, dc + dv)
+                k_c, v = kv[..., :dc], kv[..., dc:]
+            with trace.scope("mla_rope"):
+                q_r = apply_rope(q[..., dc:], cos, sin, interleaved=cfg.rope_interleaved)
+                k_r = apply_rope(k_r[:, :, None, :], cos, sin,
+                                 interleaved=cfg.rope_interleaved)
+                q = jnp.concatenate([q[..., :dc], q_r], axis=-1)
+                k = jnp.concatenate(
+                    [k_c, jnp.broadcast_to(k_r, (B, T, H, dr))], axis=-1)
+        q = checkpoint_name(q, "q")
+        k = checkpoint_name(k, "kv")
+        v = checkpoint_name(v, "kv")
+        with trace.scope("attn_core"):
+            attn = self._attention(q, k, v, None)            # [B, T, H, dv]
+        attn = checkpoint_name(attn, "attn")
+        with trace.scope("attn_out"):
+            return attn.reshape(B, T, H * dv) @ lw["mla_wo"]
+
     def _gdn(self, lw, y, rope):
         """The Gated DeltaNet mixer (``ops/gated_delta.py``) on the normed
         block input y [B, T, D] -> [B, T, D]; ``rope`` is not used (the
@@ -972,7 +1129,8 @@ class Transformer:
 
             expert_params = {name[4:]: lw[name] for name in lw
                              if name.startswith("moe_")
-                             and name != "moe_gate" and not name.startswith("moe_shared")}
+                             and name not in ("moe_gate", "moe_select_bias")
+                             and not name.startswith("moe_shared")}
 
             def moe_branch(y2):
                 # scanned=True: layer_apply always runs under stack_apply's
@@ -986,18 +1144,23 @@ class Transformer:
                 res = moe_layer(lw["moe_gate"], expert_params, y2, k=cfg.moe_top_k,
                                 capacity_factor=cfg.capacity_factor, activation=cfg.activation,
                                 impl=cfg.moe_impl, normalize_weights=cfg.moe_norm_topk,
-                                scanned=True, aux=cfg.moe_aux, **share)
+                                scanned=True, aux=cfg.moe_aux, score=cfg.moe_score,
+                                select_bias=lw.get("moe_select_bias"),
+                                weight_scale=cfg.moe_weight_scale, **share)
                 aux = res.aux_loss
                 if cfg.moe_aux == "all_choices":
                     aux = aux / cfg.n_layers
                 counts = res.metadata["expert_counts"].astype(jnp.int32)
-                return res.output, aux, {
+                stats = {
                     "expert_tokens": counts,
                     "router_prob": res.metadata["router_prob"],
                     "held_rows": jnp.asarray(res.metadata.get(
                         "held_rows", counts.sum()), jnp.int32),
                     "overflow_rows": jnp.asarray(res.metadata.get(
                         "overflow_rows", 0), jnp.int32)}
+                if "expert_weight" in res.metadata:
+                    stats["expert_weight"] = res.metadata["expert_weight"]
+                return res.output, aux, stats
 
             if moe_on is None:
                 ff, aux, stats = moe_branch(y2)
@@ -1018,7 +1181,8 @@ class Transformer:
                     out = hh @ expert_params["w_down"][0].astype(dtype)
                     if "b_down" in expert_params:
                         out = out + expert_params["b_down"][0].astype(dtype)
-                    return out, jnp.zeros((), jnp.float32), _no_routing_stats(cfg.n_experts)
+                    return out, jnp.zeros((), jnp.float32), _no_routing_stats(
+                        cfg.n_experts, cfg.moe_select_bias)
 
                 from ..parallel.mesh import inside_manual_region
 
@@ -1033,13 +1197,16 @@ class Transformer:
                 else:
                     ff, aux, stats = jax.lax.cond(moe_on, moe_branch, dense_branch, y2)
             if cfg.moe_shared_expert_ff > 0:
-                # Qwen2-MoE / Qwen3-Next shared expert: a dense swiglu MLP
-                # every token runs, added with a per-token sigmoid gate
+                # the shared expert: a dense swiglu MLP every token runs,
+                # added through a per-token sigmoid gate (Qwen2-MoE,
+                # Qwen3-Next) or as it is (DeepSeek-V3: moe_shared_gate "none")
                 with trace.scope("moe_shared"):
                     shared = (jax.nn.silu(y2 @ lw["moe_shared_w_gate"])
                               * (y2 @ lw["moe_shared_w_up"])) @ lw["moe_shared_w_down"]
-                    gate_s = jax.nn.sigmoid(y2 @ lw["moe_shared_gate"])
-                    ff = ff + gate_s.astype(ff.dtype) * shared
+                    if cfg.moe_shared_gate == "sigmoid":
+                        shared = jax.nn.sigmoid(
+                            y2 @ lw["moe_shared_gate"]).astype(ff.dtype) * shared
+                    ff = ff + shared
         elif cfg.activation == "swiglu":
             # Tagged so remat_policy="save_ffn" can keep the two big FFN
             # projections (the bulk of layer FLOPs) out of the backward
@@ -1255,10 +1422,16 @@ class Transformer:
         return out[:, :T0] if pad else out
 
     def stack_apply(self, stacked_layers, x, rope, ltd_mask=None,
-                    layer_keep=None, layer_ids=None, with_stats=False):
+                    layer_keep=None, layer_ids=None, with_stats=False,
+                    lead=None):
         """Scan the (sub)stack of layers over x. Returns (x, summed aux), or
-        with ``with_stats`` (x, summed aux, the layers' router stats stacked
-        [L, E], None for a dense model).
+        with ``with_stats`` (x, summed aux, the ROUTED layers' router stats
+        stacked [routed layers, E], None for a dense model; a dense layer
+        among routed ones routes nothing and has no row).
+
+        ``lead``: the leading layers' parameters (``params["lead"]``, stacked
+        [lead_layers, ...]) of a model that has them (``cfg.lead_layers``):
+        they run first, in a scan of their own, each as ``cfg.lead_kind``.
 
         ``ltd_mask`` [B, T] bool (True = keep): random-LTD token freezing
         for the configured middle layers.
@@ -1312,20 +1485,25 @@ class Transformer:
             moe_flags = per_layer_flags(lambda i: mp[i % len(mp)])
 
         slots = self.slots()
-        if len(slots) > 1 and (ltd_mask is not None or layer_keep is not None
-                               or layer_ids is not None or use_local or mixed_moe):
+        plain = not (ltd_mask is not None or layer_keep is not None
+                     or layer_ids is not None or use_local or mixed_moe)
+        if (len(slots) > 1 or cfg.lead_layers) and not plain:
             raise NotImplementedError(
                 "a layer_pattern of several kinds runs the plain stack only: "
                 "random-LTD, progressive layer drop, pipeline stages, "
-                "attention_pattern and moe_layer_pattern vary one kind's layers")
+                "attention_pattern and moe_layer_pattern vary one kind's layers"
+                " (and leading layers run before the plain stack only)")
+        if cfg.lead_layers and lead is None:
+            raise NotImplementedError(
+                f"this model starts with {cfg.lead_layers} leading layer(s) of "
+                f"kind {tuple(cfg.lead_kind)}: stack_apply needs them as "
+                "lead=params['lead'] (pipeline stages do not hand them over)")
         if ltd_mask is None and layer_keep is None and not mixed_moe:
             # The scan is over PERIODS of the pattern: the body runs the
             # period's layers in order, each on its own kind's row (a one-kind
             # model: a period of one layer, the row is the scan's own slice),
             # each under its own remat, and hands out per layer what
-            # layer_apply does
-            routed = any(ffn == "moe" for *_, (_, ffn) in slots)
-
+            # layer_apply does (the router stats of the routed layers only)
             def run(kind):
                 # a layer of the softmax-attention family is checkpointed
                 # whole; the pattern's other mixers checkpoint their two
@@ -1333,12 +1511,9 @@ class Transformer:
                 whole = kind[0] == "attn"
 
                 def layer_fn(h, lw, loc):
-                    h, (aux, stats) = self.layer_apply(
+                    return self.layer_apply(
                         lw, h, rope, local=loc, kind=kind,
                         remat_halves=cfg.remat and not whole)
-                    if stats is None and routed:     # a dense layer among routed ones
-                        stats = _no_routing_stats(cfg.n_experts)
-                    return h, (aux, stats)
 
                 if cfg.remat and whole:
                     return jax.checkpoint(layer_fn, policy=_remat_policy(cfg.remat_policy))
@@ -1348,19 +1523,35 @@ class Transformer:
                 rows, loc = xs if use_local else (xs, None)
                 if len(slots) == 1:
                     return run(slots[0][2])(h, rows, loc)
-                outs = []
+                auxs, routed = [], []
                 for name, i, kind in slots:
-                    h, out = run(kind)(h, jax.tree.map(lambda a: a[i], rows[name]), None)
-                    outs.append(out)
-                return h, jax.tree.map(lambda *a: jnp.stack(a), *outs)
+                    h, (aux, stats) = run(kind)(
+                        h, jax.tree.map(lambda a: a[i], rows[name]), None)
+                    auxs.append(aux)
+                    if stats is not None:
+                        routed.append(stats)
+                return h, (jnp.stack(auxs), jax.tree.map(
+                    lambda *a: jnp.stack(a), *routed) if routed else None)
 
+            if cfg.lead_layers:
+                # the leading layers first, in a scan of their own
+                lead_fn = run(tuple(cfg.lead_kind))
+                with trace.scope("layers"):
+                    x, (lead_aux, lead_stats) = jax.lax.scan(
+                        lambda h, lw: lead_fn(h, lw, None), x, lead)
             xs = (stacked_layers, local_flags) if use_local else stacked_layers
             with trace.scope("layers"):      # the scan's own slicing and stacking
                 x, (aux_losses, stats) = jax.lax.scan(period_fn, x, xs)
-            if len(slots) > 1:               # [periods, slots, ...] -> [layers, ...]
+            if len(slots) > 1 and stats is not None:
+                # [periods, routed slots, ...] -> [routed layers, ...]
                 stats = jax.tree.map(
                     lambda a: a.reshape((-1,) + a.shape[2:]), stats)
             aux = jnp.sum(aux_losses)
+            if cfg.lead_layers:
+                aux = aux + jnp.sum(lead_aux)
+                if lead_stats is not None:           # routed leading layers
+                    stats = lead_stats if stats is None else jax.tree.map(
+                        lambda a, b: jnp.concatenate([a, b]), lead_stats, stats)
             return (x, aux, stats) if with_stats else (x, aux)
 
         if ltd_mask is not None:
@@ -1395,6 +1586,12 @@ class Transformer:
             x, (aux_losses, stats) = jax.lax.scan(
                 layer_fn, x, (stacked_layers, active, keep_layers, local_flags,
                               moe_flags))
+        if mixed_moe and stats is not None and layer_ids is None:
+            # a dense layer routes nothing: its all-zero row is no routed
+            # layer's and enters no count over them
+            mp = cfg.moe_layer_pattern
+            on = [i for i in range(L) if mp[i % len(mp)]]
+            stats = jax.tree.map(lambda a: a[jnp.asarray(on)], stats)
         aux = jnp.sum(aux_losses)
         return (x, aux, stats) if with_stats else (x, aux)
 
@@ -1586,12 +1783,50 @@ class Transformer:
         """input_ids [B, T] -> logits [B, T, vocab] (fp32)."""
         return self.apply_with_aux(params, input_ids)[0]
 
+    @staticmethod
+    def _lead_of(params) -> dict:
+        """``stack_apply``'s ``lead`` argument, where the model has leading
+        layers (a stack without them is called as it always was)."""
+        return {"lead": params["lead"]} if "lead" in params else {}
+
     def apply_with_aux(self, params, input_ids, ltd_mask=None, layer_keep=None):
         """Returns (logits, moe_aux_loss) — aux is 0 for dense models."""
         x, rope = self.embed(params, input_ids)
         x, aux = self.stack_apply(params["layers"], x, rope, ltd_mask=ltd_mask,
-                                  layer_keep=layer_keep)
+                                  layer_keep=layer_keep, **self._lead_of(params))
         return self.head(params, x), aux
+
+    def update_buffers(self, old, new, stats):
+        """The masters after a step, given those before it (``old``), the
+        optimizer's (``new``) and the step's ``loss_and_stats`` stats: what
+        the trainer keeps (``runtime/engine``'s train step calls this where a
+        model has it). A model without a selection bias: ``new`` as it is. The
+        selection bias is a buffer no gradient reaches, so the optimizer's
+        decay of it is dropped, and it takes DeepSeek-V3's aux-free update
+        instead: ``old + moe_bias_update_rate x sign(mean load - load)`` per
+        routed layer, from the step's own ``moe_expert_tokens``."""
+        import jax.numpy as jnp
+
+        cfg = self.config
+        if not cfg.moe_select_bias:
+            return new
+        if len(cfg.pattern) > 1:
+            raise NotImplementedError(
+                "a selection bias in a pattern of several layer kinds: "
+                "update_buffers maps the routing counters' rows to a one-kind "
+                "stack (with leading layers) only")
+        lead = cfg.lead_layers if cfg.lead_layers and cfg.lead_kind[1] == "moe" else 0
+        out = dict(new)
+        for top, rows in (("lead", slice(0, lead)), ("layers", slice(lead, None))):
+            if "moe_select_bias" not in new.get(top, {}):
+                continue
+            bias = old[top]["moe_select_bias"]
+            if cfg.moe_bias_update_rate and "moe_expert_tokens" in stats:
+                load = stats["moe_expert_tokens"][rows].astype(jnp.float32)
+                bias = bias + cfg.moe_bias_update_rate * jnp.sign(
+                    load.mean(axis=-1, keepdims=True) - load).astype(bias.dtype)
+            out[top] = {**new[top], "moe_select_bias": bias}
+        return out
 
     def loss(self, params, batch, rng=None):
         """Next-token cross entropy. batch: {"input_ids": [B,T]} (+ optional
@@ -1601,14 +1836,17 @@ class Transformer:
 
     def loss_and_stats(self, params, batch, rng=None):
         """``loss`` and what the step reports beside it: a dict of small
-        int32 arrays, counts that add up over microbatches (the engine keeps
+        arrays, sums that add up over microbatches (the engine keeps
         the last step's: ``Engine.last_step_stats``). An MoE model gives
-        ``moe_expert_tokens`` [L, E], the token-choices each expert of each
-        layer was given on this batch, ``moe_held_rows`` [L], the token-choices
+        ``moe_expert_tokens`` [routed layers, E] int32, the token-choices each
+        expert of each ROUTED layer was given on this batch (a dense layer
+        has no row), ``moe_held_rows`` [routed layers], the token-choices
         the experts held here computed (all, unless ``n_experts_held`` makes
-        this one rank's share), and ``moe_overflow_rows`` [L], held rows that
-        did not fit the share's buffer and were dropped (0 for a model that
-        holds every expert); a chunked loss ``loss_chunks``, the
+        this one rank's share), and ``moe_overflow_rows`` [routed layers],
+        held rows that did not fit the share's buffer and were dropped (0 for
+        a model that holds every expert); a router with a selection bias also
+        ``moe_expert_weight`` [routed layers, E] float32, the sum of the
+        weights of each expert's token-choices; a chunked loss ``loss_chunks``, the
         trips of its scan, and ``loss_rows``, the rows they held, pad rows
         too, on the device that scanned them (rows a chunk: the quotient)."""
         import jax.numpy as jnp
@@ -1650,12 +1888,14 @@ class Transformer:
         x, rope = self.embed(params, model_ids)
         x, aux, routed = self.stack_apply(params["layers"], x, rope,
                                           ltd_mask=ltd_mask, layer_keep=layer_keep,
-                                          with_stats=True)
+                                          with_stats=True, **self._lead_of(params))
         stats = {}
         if routed is not None:
             stats["moe_expert_tokens"] = routed["expert_tokens"]
             stats["moe_held_rows"] = routed["held_rows"]
             stats["moe_overflow_rows"] = routed["overflow_rows"]
+            if "expert_weight" in routed:
+                stats["moe_expert_weight"] = routed["expert_weight"]
             if cfg.moe_aux == "all_choices":
                 # HF load_balancing_loss_func: the router probabilities and
                 # choices of ALL layers concatenated over tokens, so both

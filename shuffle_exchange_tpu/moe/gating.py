@@ -48,7 +48,8 @@ def compute_capacity(num_tokens: int, num_experts: int, k: int, capacity_factor:
 
 def topk_select(logits, k: int, normalize_weights: bool = True,
                 train: bool = False, rng=None, noise_std: float = 0.0,
-                aux: str = "first_choice"):
+                aux: str = "first_choice", score: str = "softmax",
+                select_bias=None, weight_scale: float = 1.0, sequences: int = 1):
     """The ONE top-k routing rule (iterative argmax — ties broken by
     expert order), shared by the capacity path (topk_gating) and the
     dropless ragged path (moe/layer.expert_mlp_ragged), so the two can
@@ -59,21 +60,43 @@ def topk_select(logits, k: int, normalize_weights: bool = True,
     the reference l_aux on the first choice (moe/sharded_moe.py);
     "all_choices" is HF's ``load_balancing_loss_func`` over these tokens,
     ``E * sum_{j,e} mean_s(mask_j)[e] * mean_s(gates)[e]`` with all k choices
-    counted (OLMoE, Mixtral's HF form).
+    counted (OLMoE, Mixtral's HF form); "none": no balancing loss (0);
+    "sequence": DeepSeek-V3's complementary sequence-wise balance loss, the
+    mean over the ``sequences`` the S tokens form (contiguous, equally long)
+    of ``sum_e f_e P_e`` with ``f_e = E / (k T) x`` the sequence's
+    token-choices of expert e and ``P_e`` the sequence's mean of the scores
+    normalised over ALL experts (its coefficient alpha is the caller's).
+
+    ``score``: what an expert's logit becomes, "softmax" over the experts or
+    "sigmoid" of each alone (DeepSeek-V3). ``select_bias`` [E]: added to the
+    scores for the CHOICE only (DeepSeek-V3's ``e_score_correction_bias``,
+    the aux-free balancing buffer): the weights are the scores without it,
+    and no gradient reaches it. ``weight_scale`` multiplies the weights
+    (after the normalisation over the chosen, whose floor is then 1e-20 as
+    the source's). The defaults are the softmax router every caller had.
     """
     import jax
     import jax.numpy as jnp
 
-    if aux not in ("first_choice", "all_choices"):
-        raise ValueError(f"moe aux must be 'first_choice' or 'all_choices'; got {aux!r}")
+    if aux not in ("first_choice", "all_choices", "none", "sequence"):
+        raise ValueError("moe aux must be 'first_choice', 'all_choices', "
+                         f"'sequence' or 'none'; got {aux!r}")
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"moe score must be 'softmax' or 'sigmoid'; got {score!r}")
     E = logits.shape[-1]
     logits = logits.astype(jnp.float32)
     if train and noise_std > 0.0 and rng is not None:
         logits = logits + noise_std * jax.random.normal(rng, logits.shape, jnp.float32)
-    gates = jax.nn.softmax(logits, axis=-1)
+    if score == "softmax":
+        gates = jax.nn.softmax(logits, axis=-1)
+        ranked = logits              # softmax keeps the logits' order
+    else:
+        gates = ranked = jax.nn.sigmoid(logits)
+    if select_bias is not None:
+        ranked = gates + jax.lax.stop_gradient(select_bias.astype(jnp.float32))
 
     idxs, ws, masks = [], [], []
-    masked = logits
+    masked = ranked
     for _ in range(k):
         idx = jnp.argmax(masked, axis=-1)
         m = jax.nn.one_hot(idx, E, dtype=jnp.float32)
@@ -82,13 +105,24 @@ def topk_select(logits, k: int, normalize_weights: bool = True,
         masks.append(m)
         masked = jnp.where(m > 0, -jnp.inf, masked)
 
-    chosen = masks[0] if aux == "first_choice" else sum(masks)
-    aux_loss = E * jnp.sum(gates.mean(axis=0) * chosen.mean(axis=0))
+    if aux == "none":
+        aux_loss = jnp.zeros((), jnp.float32)
+    elif aux == "sequence":
+        per_seq = lambda a: a.reshape(sequences, -1, E).mean(axis=1)     # [B, E]
+        share = gates / gates.sum(axis=-1, keepdims=True)
+        aux_loss = jnp.mean(jnp.sum(
+            per_seq(sum(masks)) * (E / k) * per_seq(share), axis=-1))
+    else:
+        chosen = masks[0] if aux == "first_choice" else sum(masks)
+        aux_loss = E * jnp.sum(gates.mean(axis=0) * chosen.mean(axis=0))
 
     idx = jnp.stack(idxs, axis=1)
     w = jnp.stack(ws, axis=1)
     if normalize_weights and k > 1:
-        w = w / jnp.maximum(w.sum(axis=1, keepdims=True), 1e-9)
+        floor = 1e-9 if score == "softmax" else 1e-20
+        w = w / jnp.maximum(w.sum(axis=1, keepdims=True), floor)
+    if weight_scale != 1.0:
+        w = w * weight_scale
     return idx, w, aux_loss, masks
 
 

@@ -370,7 +370,9 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
               noise_std: float = 0.0, min_capacity: int = 4, expert_axis: str = "expert",
               mesh=None, impl: str = "auto", normalize_weights: bool = True,
               scanned: bool = False, aux: str = "first_choice",
-              expert_first: int = 0, buffer_rows: Optional[int] = None) -> MoEResult:
+              expert_first: int = 0, buffer_rows: Optional[int] = None,
+              score: str = "softmax", select_bias=None,
+              weight_scale: float = 1.0) -> MoEResult:
     """x [..., M] -> MoEResult. gate_w [M, E].
 
     impl:
@@ -398,12 +400,19 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
     (token-choices computed here) and ``overflow_rows`` (held rows dropped).
 
     ``aux``: which balancing loss ``aux_loss`` is (``gating.topk_select``).
+    ``score`` ("softmax" | "sigmoid"), ``select_bias`` [E] (selects, is not
+    weighed, gets no gradient) and ``weight_scale``: the router's other forms
+    (``gating.topk_select``; DeepSeek-V3's is sigmoid, biased, scaled, with
+    ``aux="sequence"`` or ``"none"``), on the dropless "ragged" impl only: the capacity paths
+    renormalise after their drops, which these forms have not been held to.
     Named scopes inside the caller's ``moe``: ``moe_router`` (router matmul,
     softmax, top-k, aux), ``moe_dispatch`` (sort / slot assignment, gather,
     group sizes), ``moe_experts`` (the expert matmuls and activation),
     ``moe_combine`` (unsort, weighting, sum over k). ``metadata`` carries
     ``expert_counts`` [E] (token-choices each expert computed) and
-    ``router_prob`` [E] (mean router probability, differentiable).
+    ``router_prob`` [E] (mean router probability, differentiable); with a
+    ``select_bias`` also ``expert_weight`` [E], the sum of the weights of each
+    expert's token-choices.
     """
     import jax
     import jax.numpy as jnp
@@ -422,15 +431,23 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
         raise ValueError(
             "a rank's share of the experts (buffer_rows) runs the dropless "
             f"'ragged' impl only; got impl={impl!r}")
+    plain_router = (score == "softmax" and select_bias is None
+                    and weight_scale == 1.0 and aux != "sequence")
+    if not plain_router and impl != "ragged":
+        raise ValueError(
+            "a sigmoid router, a selection bias, a weight scale or the "
+            "sequence-wise balance loss run the dropless 'ragged' impl only; "
+            f"got impl={impl!r}")
     orig_shape = x.shape
     M = orig_shape[-1]
     xs = x.reshape(-1, M)
     S = xs.shape[0]
     with trace.scope("moe_router"):
         logits = (xs.astype(jnp.float32)) @ gate_w.astype(jnp.float32)   # [S, E]
-        # mean router probability per expert (the gating's own softmax again:
-        # XLA computes it once)
-        prob = jax.nn.softmax(logits, axis=-1).mean(axis=0)
+        # mean router score per expert (the gating's own softmax or sigmoid
+        # again: XLA computes it once)
+        prob = (jax.nn.sigmoid(logits) if score == "sigmoid"
+                else jax.nn.softmax(logits, axis=-1)).mean(axis=0)
 
     if impl == "auto":
         # the explicit mesh argument wins; fall back to the global topology
@@ -462,12 +479,20 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
         from .gating import topk_select
 
         with trace.scope("moe_router"):
-            idx, w, aux_loss, _ = topk_select(
+            idx, w, aux_loss, masks = topk_select(
                 logits, k, normalize_weights=normalize_weights, train=train,
-                rng=rng, noise_std=noise_std, aux=aux)
+                rng=rng, noise_std=noise_std, aux=aux, score=score,
+                select_bias=select_bias, weight_scale=weight_scale,
+                sequences=math.prod(orig_shape[:-2]))
             counts = jnp.bincount(idx.reshape(-1), length=gate_w.shape[1])
         meta = {"expert_counts": counts, "drop_fraction": jnp.zeros(()),
                 "capacity": S, "router_prob": prob}
+        if select_bias is not None:
+            # what each expert's choices weigh in all: a bias that entered the
+            # weights as well as the choice shows here and in no count
+            with trace.scope("moe_router"):
+                meta["expert_weight"] = jax.lax.stop_gradient(sum(
+                    (m * w[:, j:j + 1]).sum(axis=0) for j, m in enumerate(masks)))
         out, rows, dropped = expert_mlp_ragged(
             expert_params, xs, idx, w, activation,
             expert_first=expert_first, buffer_rows=buffer_rows)

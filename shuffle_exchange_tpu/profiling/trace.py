@@ -57,13 +57,18 @@ PREFIX = "sxt:"
 # "gdn_conv" and "gdn_gates" inside "attn_qkv", "gdn_scan" (the chunked rule)
 # inside "attn_core", "gdn_out_norm" inside "attn_out"; a gated attention
 # layer's "attn_gate" sits inside "attn_out"; the shared expert runs under
-# "moe_shared" inside "moe".
+# "moe_shared" inside "moe". A latent-attention (MLA) layer's "mla_q",
+# "mla_kv_down", "mla_kv_norm", "mla_kv_up" and "mla_rope" (rotation, the one
+# rotary key's broadcast over the heads, the concatenations) nest inside
+# "attn_qkv" (its attention kernel takes scores and values at their own
+# widths: nothing is padded, so there is no padding scope).
 # "plumbing" is what belongs to no layer of the model: the layer scan's own
 # slicing and stacking, the masters' cast to the compute dtype, the
 # gradients' cast back and normalization
 SCOPES = {
     "attn": ("attn_norm", "attn_qkv", "attn_qk_norm", "attn_core", "attn_out",
-             "attn_gate", "gdn_conv", "gdn_gates", "gdn_scan", "gdn_out_norm"),
+             "attn_gate", "gdn_conv", "gdn_gates", "gdn_scan", "gdn_out_norm",
+             "mla_q", "mla_kv_down", "mla_kv_norm", "mla_kv_up", "mla_rope"),
     "mlp": ("mlp_norm", "mlp", "moe", "moe_router", "moe_dispatch",
             "moe_experts", "moe_combine", "moe_shared"),
     "loss": ("embed", "final_norm", "loss", "head_logits", "head_softmax",

@@ -1355,6 +1355,15 @@ class Engine:
                 emulate_wire=bool(qg and not (qg_real or qz3_real)))
             return new_state, overflow, grad_norm, nonfinite
 
+        # a model's buffers (leaves of the master tree that are no weights:
+        # the optimizer's update of them is not kept) are the model's to
+        # carry over a step, from the step's own stats: Transformer's
+        # selection bias. Where the model reports no stats (the wire regions,
+        # an ensemble) it is not asked.
+        owner = getattr(self.loss_fn, "__self__", None)
+        update_buffers = (getattr(owner, "update_buffers", None)
+                          if not (ensemble or qz3_real or qg_real) else None)
+
         def train_step(state: TrainState, batch, mix, rng, lr_mult):
             p16 = fwd_weights(state.master, mix, state.step)
             fro16 = fro16_of(state.frozen)
@@ -1363,6 +1372,9 @@ class Engine:
                                             batch, rng, scale, state.step)
             new_state, overflow, grad_norm, nonfinite = update_state(
                 state, grads, loss, scale, lr_mult)
+            if update_buffers is not None:
+                new_state = new_state._replace(master=update_buffers(
+                    state.master, new_state.master, stats))
             return new_state, loss, overflow, grad_norm, nonfinite, stats
 
         from ..utils.placement import cache_safe_donate_argnums
@@ -1412,6 +1424,10 @@ class Engine:
             scale = state.loss_scale.scale if fp16_cfg.enabled else jnp.asarray(1.0, jnp.float32)
             new_state, overflow, _ = apply_grads(
                 state, grads, scale * n_micro, lambda grads, overflow: (overflow, None))
+            if update_buffers is not None:
+                # no stats on this path: a buffer stays as it was
+                new_state = new_state._replace(master=update_buffers(
+                    state.master, new_state.master, {}))
             return new_state, overflow
 
         self._apply_only = jax.jit(apply_only, donate_argnums=donate)
@@ -2412,12 +2428,15 @@ class Engine:
     def last_step_stats(self) -> dict:
         """What the model reported beside the last ``train_batch``'s loss,
         as device arrays (no host sync in the step): an MoE model's
-        ``moe_expert_tokens`` [L, E] int32, the token-choices the router gave
-        each expert of each layer, ``moe_held_rows`` [L], those the experts
-        held here computed (all of them unless the model is one
-        expert-parallel rank's share, ``n_experts_held``) and
-        ``moe_overflow_rows`` [L], held rows that did not fit the share's
-        buffer and were dropped; a chunked loss's ``loss_chunks`` and
+        ``moe_expert_tokens`` [routed layers, E] int32, the token-choices the
+        router gave each expert of each routed layer, ``moe_held_rows``
+        [routed layers], those the experts held here computed (all of them
+        unless the model is one expert-parallel rank's share,
+        ``n_experts_held``), ``moe_overflow_rows`` [routed layers], held rows
+        that did not fit the share's buffer and were dropped, and of a router
+        with a selection bias ``moe_expert_weight`` [routed layers, E]
+        float32, the summed weights of each expert's token-choices; a chunked
+        loss's ``loss_chunks`` and
         ``loss_rows`` (the scan's trips and the rows they held on one device,
         summed over the step's microbatches). {} before the first step and
         for models that report nothing."""
