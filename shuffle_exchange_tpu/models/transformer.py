@@ -867,7 +867,20 @@ class Transformer:
             # checkpointed EACH (``remat_halves``, set by stack_apply): the
             # backward then holds the mixer's residuals or the FFN's, never
             # both, for the same recomputation as one checkpoint a layer
-            # (Qwen3-Next at 16,384 tokens: 1.5 GB of a 16 GB chip, PR 33)
+            # (Qwen3-Next at 16,384 tokens: 1.5 GB of a 16 GB chip, PR 33).
+            # What a layer keeps between the passes: each half's input
+            # (B x T x D) and, where the mixer's attention takes a splash
+            # route, the kernel's own ``out`` and ``logsumexp`` (B x H x T
+            # x (Dv + 2) x 2 bytes in bf16: 136 MB at kanana-2's 2 x 32 x
+            # 8192 x 128), the one result of the half that its replay would
+            # only rebuild: the backward kernels start from them and the
+            # forward kernel runs once a layer and step, not twice (PR 36).
+            # That is under every policy, "full" too, which therefore keeps
+            # twice what it kept for these mixers (kanana-2 at 48 layers and
+            # 16,384 tokens a chip: 13.0 GB where it kept 6.4); policy "none"
+            # (``jax.checkpoint``'s default) keeps nothing and recomputes.
+            # ``gdn`` has no such kernel; "stock_flash", "reference" and the
+            # ring's hop kernels name nothing and recompute
             mix = {"gdn": self._gdn, "gated_attn": self._gated_attention,
                    "mla": self._mla}[mixer]
 
@@ -884,7 +897,8 @@ class Transformer:
 
             if remat_halves:
                 policy = _remat_policy(cfg.remat_policy)
-                mixer_half = jax.checkpoint(mixer_half, policy=policy)
+                mixer_half = jax.checkpoint(
+                    mixer_half, policy=_keeping_splash_residuals(policy))
                 ffn_half = jax.checkpoint(ffn_half, policy=policy)
             h, aux, stats = ffn_half(lw, mixer_half(lw, h))
             return h, (aux, stats)
@@ -2046,10 +2060,38 @@ def _head_scan(kind, eps, biased, ln_w, ln_b, w, w_acc, extra, xc, lc):
     return scan(ln_w, ln_b, w, w_acc, extra, xc, lc)
 
 
+def _keeping_splash_residuals(policy):
+    """``policy`` (of ``_remat_policy``) for a mixer half under per-half
+    remat: what ``policy`` keeps (or offloads) and the splash attention
+    kernels' own residuals ``out`` and ``logsumexp`` (``ops/flash_attention
+    .SPLASH_RESIDUALS``, named inside the kernel's forward rule), so that the
+    half's replay enters the backward kernels from saved state. A half
+    without a splash kernel holds no such name and keeps what ``policy``
+    keeps. ``None`` ("none") stays ``None``."""
+    from ..ops.flash_attention import SPLASH_RESIDUALS
+
+    if policy is None:
+        return None
+
+    # (not ``save_from_both_policies``: it takes policies that answer with a
+    # bool, and "offload_kv_host" answers Recompute / Offloadable)
+    def both(prim, *avals, **params):
+        if prim.name == "name" and params["name"] == SPLASH_RESIDUALS:
+            return True
+        return policy(prim, *avals, **params)
+
+    return both
+
+
 def _remat_policy(name: str):
     import jax
 
     policies = {
+        # no policy: ``jax.checkpoint``'s default keeps NOTHING, the splash
+        # kernels' residuals included (``_keeping_splash_residuals`` leaves
+        # None alone): the least a remat'ed layer can hold. "full" and
+        # "nothing_saveable" are that for the ``attn`` family and ``gdn``;
+        # an ``mla`` / ``gated_attn`` half keeps the residuals under them
         "none": None,
         "full": jax.checkpoint_policies.nothing_saveable,
         "nothing_saveable": jax.checkpoint_policies.nothing_saveable,
@@ -2083,7 +2125,13 @@ def _remat_policy(name: str):
         # residuals themselves (this policy) is what removes it; cost is
         # out[B,T,H,D] bf16 + lse[B,H,T] f32 per layer. Requires the model
         # to route attention through the lse kernel (Transformer._attention
-        # does this automatically under this policy).
+        # does this automatically under this policy). The ``attn`` family's
+        # route (one checkpoint a layer, head_dim 64/128). The pattern's own
+        # mixers (``mla``, ``gated_attn``: per-half remat, splash kernels)
+        # reach the same end under EVERY policy here but "none" (the
+        # offloading one too: its answers are not booleans), with no policy
+        # of their own: ``_keeping_splash_residuals`` adds the splash
+        # kernels' residual name to the mixer half's policy.
         "save_flash_lse": jax.checkpoint_policies.save_only_these_names(
             "flash_out", "flash_lse"),
     }

@@ -29,6 +29,14 @@ from ..utils.logging import warning_once
 # keep them in sync by construction, not by copy.
 BLOCK_CANDIDATES = (1024, 512, 384, 256, 128)
 
+# The checkpoint name of the splash kernels' own residuals (out, logsumexp),
+# given inside their custom-vjp forward rule. A ``jax.checkpoint`` whose
+# policy lists it (a mixer half under per-half remat: models/transformer.py
+# layer_apply) enters the backward kernels from saved state, and the forward
+# kernel is dead code in the replay. Outside a checkpoint, or under a policy
+# that does not list it, the name is the identity.
+SPLASH_RESIDUALS = "splash_residuals"
+
 
 def _forced_block(env_var: str, n: int, itemsize: int) -> int:
     """Parse + clamp a block-size override env var: 0 when unset/invalid/
@@ -176,8 +184,9 @@ def splash_attention_gqa(q, k, v, causal: bool = True, segment_ids=None,
     else:
         head_mask = (sa.CausalMask((T, S)) if causal else sa.FullMask((T, S)))
     mask = sa.MultiHeadMask([head_mask for _ in range(G)])
-    kernel = sa.make_splash_mqa_single_device(mask, block_sizes=block_sizes,
-                                              interpret=interpret)
+    kernel = sa.make_splash_mqa_single_device(
+        mask, block_sizes=block_sizes, interpret=interpret,
+        residual_checkpoint_name=SPLASH_RESIDUALS)
 
     scale = D ** -0.5
     q5 = (q * scale).reshape(B, T, KV, G, D).transpose(0, 2, 3, 1, 4)  # [B,KV,G,T,D]

@@ -8,6 +8,8 @@ is gated hostless in ``tests/test_mosaic_lowering.py``.
 """
 
 import dataclasses
+import functools
+import importlib
 
 import numpy as np
 import pytest
@@ -15,8 +17,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import shuffle_exchange_tpu.models.transformer as tr
 from shuffle_exchange_tpu.models import Transformer, tiny
-from shuffle_exchange_tpu.models.transformer import _remat_policy
+from shuffle_exchange_tpu.models.transformer import (TransformerConfig,
+                                                      _remat_policy)
+
+fa = importlib.import_module("shuffle_exchange_tpu.ops.flash_attention")   # the module, not the function
 
 
 def _cfg(policy):
@@ -135,3 +141,195 @@ def test_activation_checkpointing_config_accepts_named_policies():
                                      "policy": "save_flash_lse"},
     })
     assert cfg.activation_checkpointing.policy == "save_flash_lse"
+
+
+# -- per-half remat keeps the splash kernels' own residuals (PR 36) ---------
+#
+# The pattern's mixers (``mla``, ``gated_attn``) run the splash kernels, whose
+# forward rule names ``out`` and ``logsumexp`` (``SPLASH_RESIDUALS``); under
+# per-half remat the mixer half's policy keeps that name, so the replay's
+# forward kernel is dead code. The CPU's "reference" route would test nothing
+# (PRs 29-30): the tests steer the splash route itself onto the CPU,
+# interpreted.
+
+_BLOCK = dict(vocab_size=64, d_model=64, n_layers=2, max_seq_len=257,
+              activation="swiglu", norm="rmsnorm", position="rope",
+              rope_theta=1e6, norm_eps=1e-6, tie_embeddings=False,
+              remat=True, remat_policy="full")
+MIXERS = {
+    # scores 192 (128 + 64 rotary) / values 128, MHA: "splash_own_v"
+    "mla": dict(n_heads=2, head_size=192, rotary_dim=64, rope_interleaved=True,
+                mla_kv_rank=32, mla_qk_content_dim=128, mla_qk_rope_dim=64,
+                mla_v_dim=128, layer_pattern=(("mla", "mlp"),)),
+    # 4 query heads over 2 KV heads: "splash"
+    "gated_attn": dict(n_heads=4, n_kv_heads=2, head_size=64,
+                       layer_pattern=(("gated_attn", "mlp"),)),
+    "gdn": dict(n_heads=4, head_size=16, layer_pattern=(("gdn", "mlp"),),
+                gdn_key_heads=2, gdn_value_heads=4, gdn_key_dim=16,
+                gdn_value_dim=16),
+    # the softmax family's layer, checkpointed whole, GQA: "splash"
+    "attn": dict(n_heads=4, n_kv_heads=2, head_size=64),
+}
+
+
+@pytest.fixture
+def splash_on_cpu(monkeypatch):
+    """Every shape takes its Pallas attention route, the splash kernels
+    interpreted (steered here, not through an option of the program)."""
+    monkeypatch.setattr(fa, "_pallas_ok", lambda q, k, causal=True: True)
+    monkeypatch.setattr(fa, "splash_attention_gqa", functools.partial(
+        fa.splash_attention_gqa, interpret=True))
+
+
+def _as_the_parent(monkeypatch, name_too=True):
+    """The mixer half's policy without the name; ``name_too``: and a kernel
+    that names nothing (the program before PR 36)."""
+    monkeypatch.setattr(tr, "_keeping_splash_residuals", lambda policy: policy)
+    if name_too:
+        monkeypatch.setattr(fa, "SPLASH_RESIDUALS", None)
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs in its parameters."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def _launches(jaxpr, kernel):
+    return sum(1 for e in _equations(jaxpr) if e.primitive.name == "pallas_call"
+               and e.params["name"] == kernel)
+
+
+def _shape(jaxpr):
+    """The program modulo its ``name`` equations."""
+    return [(e.primitive.name, [str(v.aval) for v in e.outvars])
+            for e in _equations(jaxpr) if e.primitive.name != "name"]
+
+
+def _model_step(mixer, policy="full"):
+    block = dict(_BLOCK, remat_policy=policy)
+    model = Transformer(TransformerConfig(**block, **MIXERS[mixer]))
+    params = model.init(jax.random.PRNGKey(0))
+    batch = {"input_ids": np.random.default_rng(0).integers(
+        0, 64, (2, 257)).astype(np.int32)}
+    return jax.value_and_grad(lambda p: model.loss(p, batch)), params
+
+
+@pytest.mark.parametrize("mixer, policy", [
+    ("mla", "full"), ("gated_attn", "full"), ("gdn", "full"), ("attn", "full"),
+    # a policy that answers Offloadable / Recompute, not a bool (k and v are
+    # named "kv" in both mixers): the union is the program's own
+    ("mla", "offload_kv_host"), ("gated_attn", "offload_kv_host"),
+    ("mla", "dots_saveable"), ("gated_attn", "save_attn_seams"),
+    # no policy at all: jax's default keeps nothing, the literal minimum
+    ("mla", "none"), ("gated_attn", "none")])
+def test_per_half_remat_keeps_the_splash_kernels_residuals(
+        mixer, policy, monkeypatch, splash_on_cpu):
+    """A mixer half with a splash route (``mla``, ``gated_attn``) under every
+    kind of policy: ONE forward launch a layer in the gradient's program
+    where the policy without the name holds two, loss and every gradient leaf
+    bit-equal; under "none" (no policy: nothing is kept) two, as before. A
+    ``gdn`` half (no attention kernel) and a layer of the softmax family (one
+    checkpoint a layer, its policy untouched) are the program they were
+    before the name existed."""
+    step, params = _model_step(mixer, policy)
+    jaxpr = jax.make_jaxpr(step)(params).jaxpr
+    if mixer in ("gdn", "attn"):
+        text = jax.jit(step).lower(params).as_text()
+        with monkeypatch.context() as parent:
+            _as_the_parent(parent)
+            step, params = _model_step(mixer, policy)
+            assert _shape(jax.make_jaxpr(step)(params).jaxpr) == _shape(jaxpr)
+            if mixer == "gdn":
+                # (the interpreter's helper functions are numbered by the
+                # state of jax's caches: no text to compare for "attn")
+                assert jax.jit(step).lower(params).as_text() == text
+        # the softmax family's splash route was there to be named, and its
+        # policy does not list the name: forward and replay
+        assert _launches(jaxpr, "splash_mqa_fwd_residuals") == (
+            2 if mixer == "attn" else 0)
+        return
+    loss, grads = jax.jit(step)(params)
+    with monkeypatch.context() as parent:
+        _as_the_parent(parent, name_too=False)
+        step, params = _model_step(mixer, policy)
+        parent_jaxpr = jax.make_jaxpr(step)(params).jaxpr
+        parent_loss, parent_grads = jax.jit(step)(params)
+    # the two layers are one scan body: launches a layer
+    assert _launches(jaxpr, "splash_mqa_fwd_residuals") == (
+        2 if policy == "none" else 1)
+    assert _launches(parent_jaxpr, "splash_mqa_fwd_residuals") == 2
+    for kernel in ("splash_mqa_dq_no_residuals", "splash_mqa_dkv_no_residuals"):
+        assert _launches(jaxpr, kernel) == _launches(parent_jaxpr, kernel) == 1
+    assert float(loss) == float(parent_loss) and np.isfinite(float(loss))
+    leaves = jax.tree_util.tree_leaves_with_path(grads)
+    assert len(leaves) > 8
+    for (path, a), b in zip(leaves, jax.tree.leaves(parent_grads)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), str(path))
+    # (a bias leaf the block does not use has a zero gradient in both)
+    assert sum(bool(np.any(np.asarray(a))) for _, a in leaves) > 8
+
+
+@pytest.mark.parametrize("around", ["no_checkpoint", "dots_saveable",
+                                    "offload_kv_host"])
+def test_the_splash_residuals_name_is_inert_elsewhere(around, monkeypatch):
+    """``splash_attention_gqa`` outside any checkpoint, and inside one whose
+    policy does not list the name: the program it gave (modulo the ``name``
+    equations), the values it gave."""
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (1, 256, 4, 64), jnp.float32)
+    k, v = (jax.random.normal(kk, (1, 256, 2, 64), jnp.float32) for kk in ks[1:])
+
+    def grads():
+        def body(q, k, v):
+            return jnp.sum(fa.splash_attention_gqa(
+                q, k, v, causal=True, interpret=True) ** 2)
+
+        if around != "no_checkpoint":
+            body = jax.checkpoint(body, policy=_remat_policy(around))
+        f = jax.value_and_grad(body, argnums=(0, 1, 2))
+        return jax.make_jaxpr(f)(q, k, v).jaxpr, jax.jit(f)(q, k, v)
+
+    jaxpr, got = grads()
+    monkeypatch.setattr(fa, "SPLASH_RESIDUALS", None)
+    parent_jaxpr, want = grads()
+    names = [e for e in _equations(jaxpr) if e.primitive.name == "name"]
+    assert names and not any(
+        e.primitive.name == "name" for e in _equations(parent_jaxpr))
+    assert _shape(jaxpr) == _shape(parent_jaxpr)
+    assert _launches(jaxpr, "splash_mqa_fwd_residuals") == (
+        1 if around == "no_checkpoint" else 2)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("mixer", ["mla", "gated_attn"])
+def test_the_trainer_runs_the_forward_kernel_once_a_layer(
+        mixer, splash_on_cpu, devices8):
+    """The same through ``sxt.initialize``: ``activation_checkpointing`` with
+    policy "full" from the train_config, ZeRO-3, the kernels inside the
+    8-device mesh's ``shard_kernel``: the policy reads the name through the
+    shard_map, and the step trains."""
+    import shuffle_exchange_tpu as sxt
+
+    block = {k: v for k, v in _BLOCK.items() if not k.startswith("remat")}
+    model = Transformer(TransformerConfig(**block, **MIXERS[mixer]))
+    ids = np.random.default_rng(0).integers(0, 64, (8, 257)).astype(np.int32)
+    engine = sxt.initialize(
+        model=model,
+        config={"train_batch_size": 8, "steps_per_print": 10 ** 9,
+                "optimizer": {"type": "FusedAdam", "params": {"lr": 1e-3}},
+                "activation_checkpointing": {"enabled": True, "policy": "full"},
+                "zero_optimization": {"stage": 3}}, seed=0)[0]
+    assert model.config.remat and model.config.remat_policy == "full"
+    jaxpr = jax.make_jaxpr(engine._train_step)(
+        engine.state, engine._reshape_batch({"input_ids": ids}),
+        engine._mix_matrix(), engine._next_rng_peek(),
+        np.asarray(1.0, np.float32)).jaxpr
+    assert any(e.primitive.name == "shard_map" for e in _equations(jaxpr))
+    for kernel in ("splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals",
+                   "splash_mqa_dkv_no_residuals"):
+        assert _launches(jaxpr, kernel) == 1, kernel
+    assert np.isfinite(float(engine.train_batch({"input_ids": ids})))
