@@ -26,7 +26,10 @@ about themselves. Four primitives, no config field, no environment variable.
 ``program_ops`` reads a compiled program's HLO text into instruction name ->
 (scope path, opcode, contains a collective): the join for traces whose device
 events carry no op-name stat, and the only way to see a collective inside a
-``fusion``. ``Engine.compile()`` registers it for ``train_step``.
+``fusion``. ``Engine.compile()`` registers it for ``train_step``, with the
+compiler's own sizing of the program (``registered_memory``: the peak that
+decides whether a step fits). ``phase_of`` reads the pass an op ran in
+(forward, recompute, backward, update) off the same scope path.
 
     from shuffle_exchange_tpu.profiling import trace
 
@@ -77,6 +80,13 @@ SCOPES = {
     "mesh": ("zero3_gather", "zero3_reduce_scatter"),
     "plumbing": ("layers", "weight_cast", "grad_normalize"),
 }
+
+# the passes of a train step, as ``phase_of`` reads them off an op_name path
+PHASES = ("forward", "recompute", "backward", "update", "other")
+# gradients the program takes by hand inside a forward rule, where no
+# ``transpose(`` marks them: the chunked loss's ``custom_vjp`` computes the
+# head's dx and dw in the pass of its loss (PR 32)
+_BACKWARD_SCOPES = ("head_dx", "head_dw")
 
 _KEEP_MAX = 4096
 _EVENTS_MAX = 256
@@ -246,6 +256,56 @@ def compile_events(since: float = 0.0) -> List[dict]:
 # ---------------------------------------------------------------------------
 
 
+# ``transpose(jvp(layers))`` -> ("transpose(jvp(", "layers")
+_WRAPPED = re.compile(r"^((?:\w+\()*)(.*?)\)*$")
+
+
+@functools.lru_cache(maxsize=None)
+def phase_of(op_name: str) -> str:
+    """The pass of the train step an op belongs to, read off its ``op_name``
+    path (``Op.scope``): one of ``PHASES``, first match wins.
+
+    ``update``     a component is one of ``SCOPES["optimizer"]``
+                   (``jit(train_step)/optimizer/optimizer/reshape``);
+    ``recompute``  a component is ``rematted_computation``: what
+                   ``jax.checkpoint`` replays inside the backward pass
+                   (``.../transpose(jvp(loss))/while/body/closed_call/
+                   checkpoint/rematted_computation/final_norm/div``). A
+                   ``custom_vjp``'s forward rule replayed there
+                   (``gdn_rule_fwd_keep``, the splash forward under a policy
+                   that does not keep its residuals) is ``recompute`` too;
+    ``backward``   a component is wrapped in ``transpose(``
+                   (``.../transpose(jvp(layers))/while/body/closed_call/mlp/
+                   dot_general``), the ``checkpoint``'s own transposed ops
+                   included; or is a scope the program names for a gradient
+                   it takes by hand inside a forward rule (``.../jvp(loss)/
+                   while/body/closed_call/head_dw/dot_general``);
+    ``forward``    a component is wrapped in ``jvp(``
+                   (``jit(train_step)/jvp(layers)/while/body/closed_call/
+                   attn_qkv/add``);
+    ``other``      the rest: the masters' cast and gather, the gradients'
+                   reduce-scatter and normalisation outside ``optimizer``,
+                   constants, ops with no ``op_name``.
+
+    A ``fusion`` carries the ONE ``op_name`` XLA leaves on it (as with
+    scopes): where the compiler fuses a replayed producer into a backward
+    consumer the whole fusion counts as that one op's pass."""
+    wrappers, names = set(), set()
+    for part in op_name.split("/"):
+        head, name = _WRAPPED.match(part).groups()
+        wrappers.update(head.split("("))
+        names.add(name)
+    if names.intersection(SCOPES["optimizer"]):
+        return "update"
+    if "rematted_computation" in names:
+        return "recompute"
+    if "transpose" in wrappers or names.intersection(_BACKWARD_SCOPES):
+        return "backward"
+    if "jvp" in wrappers:
+        return "forward"
+    return "other"
+
+
 class Op(NamedTuple):
     scope: str                  # the op_name metadata, "" where there is none
     opcode: str
@@ -263,7 +323,8 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLED = re.compile(r"(?:calls|to_apply|body|condition|branch_computations|"
                      r"called_computations)=\{?([^,}\s]+(?:,\s*%[\w.\-]+)*)\}?")
 
-_programs: Dict[str, Callable[[], Dict[str, Op]]] = {}
+# name -> (reader of the instruction table, the compiler's sizes or None)
+_programs: Dict[str, Tuple[Callable[[], Dict[str, Op]], Optional[dict]]] = {}
 
 
 def program_ops(compiled) -> Dict[str, Op]:
@@ -319,13 +380,41 @@ def program_ops(compiled) -> Dict[str, Op]:
 
 
 def register_program(name: str, compiled) -> None:
-    """Keep ``program_ops`` of a compiled program under ``name``. The text is
-    taken now (the executable is not kept alive) and parsed when first read."""
+    """Keep ``program_ops`` of a compiled program under ``name``, and the
+    compiler's sizing of it (``registered_memory``). Text and sizes are taken
+    now (the executable is not kept alive); the text is parsed when first
+    read."""
     text = compiled.as_text()
-    _programs[name] = functools.cache(lambda: program_ops(text))
+    _programs[name] = (functools.cache(lambda: program_ops(text)),
+                       _memory_of(compiled))
+
+
+def _memory_of(compiled) -> Optional[dict]:
+    """``memory_analysis()`` as plain integers, bytes on one device; None
+    where the backend has no analysis (jax then returns None)."""
+    m = compiled.memory_analysis()
+    if m is None:
+        return None
+    peak = getattr(m, "peak_memory_in_bytes", None)
+    return {"argument": int(m.argument_size_in_bytes),
+            "output": int(m.output_size_in_bytes),
+            "alias": int(m.alias_size_in_bytes),
+            "temp": int(m.temp_size_in_bytes),
+            "generated_code": int(m.generated_code_size_in_bytes),
+            "peak": int(peak) if peak else None}
 
 
 def registered_ops(name: str) -> Optional[Dict[str, Op]]:
     """``program_ops`` of the program registered as ``name``, or None."""
-    read = _programs.get(name)
-    return read() if read is not None else None
+    entry = _programs.get(name)
+    return entry[0]() if entry is not None else None
+
+
+def registered_memory(name: str) -> Optional[dict]:
+    """The compiler's sizes of the program registered as ``name``, in bytes
+    on one device: ``argument``, ``output``, ``alias``, ``temp``,
+    ``generated_code`` and ``peak`` (XLA's ``peak_memory_in_bytes``: what the
+    step needs at its fullest, arguments included; None where this backend's
+    analysis has none). None where nothing is registered under ``name``."""
+    entry = _programs.get(name)
+    return dict(entry[1]) if entry is not None and entry[1] else None

@@ -57,8 +57,16 @@ def _memory_usage() -> str:
     import jax
 
     stats = jax.local_devices()[0].memory_stats() or {}
-    return (f"mem in_use={stats.get('bytes_in_use', 0) / 2**30:.2f}GB "
+    line = (f"mem in_use={stats.get('bytes_in_use', 0) / 2**30:.2f}GB "
             f"peak={stats.get('peak_bytes_in_use', 0) / 2**30:.2f}GB")
+    # memory_stats() can miss a program's temporaries: where compile() has
+    # registered the step, the compiler's own sizing of it decides what fits
+    step = trace.registered_memory("train_step")
+    if step:
+        peak = step["peak"]
+        line += (" step_peak=" + (f"{peak / 2**30:.2f}GB" if peak else "n/a")
+                 + f" step_temp={step['temp'] / 2**30:.2f}GB")
+    return line
 
 
 class TrainState(NamedTuple):

@@ -595,3 +595,32 @@ def test_gated_delta_rule_compiles(chip_compile):
                             ((2, 8192, 32, 128), _F32))
     text = compiled.as_text()
     assert "gdn_rule_fwd_keep" in text and "gdn_rule_bwd" in text
+
+
+def test_tracer_reads_a_chip_compiled_programs_peak_and_passes(
+        chip_compile, monkeypatch):
+    """What ``trace.register_program`` keeps of a program compiled for the
+    chip: XLA's peak is the program at its fullest, arguments included (the
+    CPU backend's reads BELOW ``temp``), and a kernel replayed under
+    ``jax.checkpoint`` reads ``recompute`` off its own path."""
+    import collections
+
+    from shuffle_exchange_tpu.ops.flash_attention import pallas_attention
+    from shuffle_exchange_tpu.profiling import trace
+
+    def loss(q, k, v):
+        attend = jax.checkpoint(lambda q, k, v: pallas_attention(q, k, v, causal=True))
+        return attend(q, k, v).astype(_F32).sum()
+
+    g = GEOMS["gpt2-125m"]
+    q = ((1, 1024, g["H"], g["Dh"]), _BF16)
+    compiled = chip_compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    monkeypatch.setattr(trace, "_programs", {})
+    trace.register_program("probe", compiled)
+    sizes = trace.registered_memory("probe")
+    assert type(sizes["peak"]) is int
+    assert sizes["peak"] >= sizes["temp"] and sizes["peak"] >= sizes["argument"] > 0
+    kernels = collections.Counter(
+        trace.phase_of(op.scope) for op in trace.registered_ops("probe").values()
+        if op.scope.endswith("pallas_call"))
+    assert set(kernels) == {"recompute", "backward"}, kernels

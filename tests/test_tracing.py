@@ -3,7 +3,10 @@ clock, named scopes on the compiled train step, compile events by program and
 span, the scheduler's due and first-scheduled times. CPU only: counts, names
 and containment, never a rate."""
 
+import collections
 import glob
+import gzip
+import json
 import os
 import re
 
@@ -12,7 +15,7 @@ import pytest
 
 import shuffle_exchange_tpu as sxt
 from shuffle_exchange_tpu.models import Transformer
-from shuffle_exchange_tpu.models.transformer import tiny
+from shuffle_exchange_tpu.models.transformer import TransformerConfig, tiny
 from shuffle_exchange_tpu.profiling import trace
 
 KNOWN = {name for names in trace.SCOPES.values() for name in names}
@@ -25,16 +28,17 @@ def _no_kept_spans():
     trace.keep_spans(False)
 
 
-def make_engine(**extra):
+def make_engine(model=None, **extra):
     config = {"optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
               "gradient_clipping": 1.0, "train_batch_size": 8,
               "steps_per_print": 10 ** 9, **extra}
-    return sxt.initialize(model=Transformer(tiny()), config=config, seed=3)[0]
+    return sxt.initialize(model=Transformer(model or tiny()), config=config,
+                          seed=3)[0]
 
 
-def ids(batch=8, seq=17, seed=0):
+def ids(batch=8, seq=17, seed=0, vocab=256):
     rng = np.random.default_rng(seed)
-    return {"input_ids": rng.integers(0, 256, (batch, seq)).astype(np.int32)}
+    return {"input_ids": rng.integers(0, vocab, (batch, seq)).astype(np.int32)}
 
 
 def scopes_of(op_name):
@@ -147,6 +151,220 @@ def test_most_of_the_step_is_under_a_scope(step_ops):
     assert len(named) > 100
     assert len(bare) < 0.2 * len(named), sorted(
         {(o.opcode, o.scope) for o in bare})[:20]
+
+
+# -- the pass an op ran in ------------------------------------------------
+
+# real paths, from the two tables cut on the chip (PR 26:
+# chipbench/tests/data/*.scoped.json.gz) and from the held-share cells' steps
+_L = "jit(train_step)/jvp(layers)/while/body/closed_call/"
+_B = "jit(train_step)/transpose(jvp(layers))/while/body/closed_call/"
+_H = "jit(train_step)/transpose(jvp(loss))/while/body/closed_call/checkpoint/"
+PATHS = [
+    (_L + "attn_qkv/add", "forward"),
+    (_L + "attn_core/jit(flash_attention)/pallas_call", "forward"),
+    (_L + "attn_core/shard_map/vmap(vmap(jit(_splash_attention)))/"
+     "splash_mqa_fwd_residuals/splash_mqa_fwd_residuals/pallas_call", "forward"),
+    (_L + "attn_norm/shard_map/sxt_rmsnorm/pallas_call", "forward"),
+    ("jit(train_step)/jvp(embed)/jit(_take)/gather", "forward"),
+    ("jit(train_step)/jvp(loss)/while", "forward"),
+    ("jit(train_step)/jvp()/reduce_sum", "forward"),
+    (_B + "checkpoint/dot_general", "backward"),
+    (_B + "mlp/dot_general", "backward"),
+    (_B + "attn_core/transpose", "backward"),       # the primitive, not a wrapper
+    (_B + "attn_core/jit(flash_attention)/flash_mha_bwd_dq_block_q_major=1024"
+     "_block_k_major=1024_block_k=1024/pallas_call", "backward"),
+    (_B + "attn_norm/shard_map/psum", "backward"),
+    (_H + "neg", "backward"),
+    (_H + "final_norm/shard_map/rsqrt", "backward"),
+    ("jit(train_step)/transpose(jvp(embed))/jit(_take)/scatter-add", "backward"),
+    # the chunked loss takes the head's gradients in the pass of its loss
+    ("jit(train_step)/jvp(loss)/while/body/closed_call/head_dw/dot_general",
+     "backward"),
+    ("jit(train_step)/jvp(loss)/while/body/closed_call/head_dx/"
+     "transpose(jvp(final_norm))/mul", "backward"),
+    ("jit(train_step)/jvp(loss)/while/body/closed_call/head_softmax/exp",
+     "forward"),
+    (_H + "rematted_computation/final_norm/div", "recompute"),
+    (_H + "rematted_computation/final_norm/shard_map/sxt_rmsnorm/pallas_call",
+     "recompute"),
+    (_H + "rematted_computation/jit(log_softmax)/reduce_sum", "recompute"),
+    # a custom_vjp's forward rule, replayed under the layer's checkpoint
+    (_B + "checkpoint/rematted_computation/attn_core/gdn_scan/gdn_rule_fwd_keep"
+     "/pallas_call", "recompute"),
+    ("jit(train_step)/optimizer/optimizer/reshape", "update"),
+    ("jit(train_step)/optimizer/optimizer/reshape;"
+     "jit(train_step)/optimizer/optimizer/reshape", "update"),
+    ("jit(train_step)/optimizer/optimizer/shard_map/sxt_fused_adamw/pallas_call",
+     "update"),
+    ("jit(train_step)/optimizer/grad_clip/dot_general", "update"),
+    ("jit(train_step)/optimizer/jit(_where)/select_n", "update"),
+    ("jit(train_step)/optimizer/zero3_reduce_scatter/div", "update"),
+    ("jit(train_step)/weight_mix/dot_general", "update"),
+    ("jit(train_step)/zero3_gather/convert_element_type", "other"),
+    ("jit(train_step)/zero3_reduce_scatter/convert_element_type", "other"),
+    ("jit(train_step)/final_norm/broadcast_in_dim", "other"),
+    ("jit(train_step)/attn_core/shard_map/vmap(vmap(jit(_splash_attention)))/"
+     "broadcast_in_dim", "other"),
+    ("broadcast.33", "other"),
+    ("", "other"),
+]
+
+
+@pytest.mark.parametrize("path, phase", PATHS,
+                         ids=[f"{i}-{p}" for i, (_, p) in enumerate(PATHS)])
+def test_phase_of_reads_the_pass_off_a_real_path(path, phase):
+    assert phase in trace.PHASES
+    assert trace.phase_of(path) == phase
+
+
+@pytest.mark.parametrize("table, want", [
+    ("train_one_step", {"forward": 38, "backward": 43, "recompute": 8,
+                        "update": 5, "other": 4}),
+    ("zero3_x4_one_step", {"forward": 36, "backward": 46, "recompute": 6,
+                           "update": 5, "other": 15})])
+def test_phase_of_splits_the_scope_paths_of_a_chip_table(table, want):
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench", "tests", "data",
+        table + ".scoped.json.gz")
+    with gzip.open(path, "rt") as f:
+        scopes = json.load(f)["scopes"]
+    assert collections.Counter(map(trace.phase_of, scopes)) == want
+    assert len(scopes) == sum(want.values())
+
+
+_BLOCK = dict(vocab_size=64, d_model=64, n_layers=2, max_seq_len=64,
+              activation="swiglu", norm="rmsnorm", position="rope",
+              rope_theta=1e6, norm_eps=1e-6, tie_embeddings=False)
+MODELS = {
+    "attn": lambda: tiny(),
+    "mla": lambda: TransformerConfig(
+        **_BLOCK, n_heads=2, head_size=192, rotary_dim=64,
+        rope_interleaved=True, mla_kv_rank=32, mla_qk_content_dim=128,
+        mla_qk_rope_dim=64, mla_v_dim=128, layer_pattern=(("mla", "mlp"),)),
+    "gated_attn": lambda: TransformerConfig(
+        **_BLOCK, n_heads=4, n_kv_heads=2, head_size=64,
+        layer_pattern=(("gated_attn", "mlp"),)),
+}
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["no_remat", "full"])
+@pytest.mark.parametrize("mixer", sorted(MODELS))
+def test_every_pass_of_a_compiled_step_is_found(mixer, remat):
+    """The markers ``phase_of`` reads are jax's own names: a jax that renames
+    ``rematted_computation`` or ``transpose(jvp(`` fails here, loudly, and
+    not as a metric that reads 0 on the chip."""
+    from shuffle_exchange_tpu.parallel.mesh import reset_topology
+
+    reset_topology()
+    extra = {"activation_checkpointing": {"enabled": True, "policy": "full"}
+             } if remat else {}
+    engine = make_engine(model=MODELS[mixer](), **extra)
+    engine.compile(ids(vocab=64))
+    ops = trace.registered_ops("train_step")
+    seen = collections.Counter(trace.phase_of(op.scope) for op in ops.values())
+    want = set(trace.PHASES) - (set() if remat else {"recompute"})
+    assert {p for p, n in seen.items() if n} == want, seen
+    # a scan's body (the layers, under remat their replay) is differentiated
+    # whole: every op of it is forward, replayed or backward
+    body = [op.scope for op in ops.values()
+            if op.scope.startswith("jit(train_step)/") and "/while/body/" in op.scope]
+    assert len(body) > 100
+    lost = {p for p in body
+            if trace.phase_of(p) not in ("forward", "recompute", "backward")}
+    assert not lost, sorted(lost)[:10]
+    # and everything under the optimizer's scopes is the update
+    for op in ops.values():
+        if set(scopes_of(op.scope)) & set(trace.SCOPES["optimizer"]):
+            assert trace.phase_of(op.scope) == "update", op.scope
+    by = collections.defaultdict(set)
+    for op in ops.values():
+        for name in scopes_of(op.scope):
+            by[name].add(trace.phase_of(op.scope))
+    for name in ("attn_qkv", "attn_core", "mlp"):
+        assert {"forward", "backward"} <= by[name], (name, by[name])
+        assert ("recompute" in by[name]) == remat, (name, by[name])
+
+
+# -- what the compiled step holds -----------------------------------------
+
+
+def test_registered_memory_is_the_compilers_sizing(monkeypatch):
+    from shuffle_exchange_tpu.parallel.mesh import reset_topology
+
+    monkeypatch.setattr(trace, "_programs", {})
+    assert trace.registered_memory("train_step") is None    # before compile()
+    assert trace.registered_ops("train_step") is None
+    reset_topology()
+    engine = make_engine()
+    compiled = engine.compile(ids())
+    sizes = trace.registered_memory("train_step")
+    assert set(sizes) == {"argument", "output", "alias", "temp",
+                          "generated_code", "peak"}
+    analysis = compiled.memory_analysis()
+    for key, attr in (("argument", "argument_size_in_bytes"),
+                      ("output", "output_size_in_bytes"),
+                      ("alias", "alias_size_in_bytes"),
+                      ("temp", "temp_size_in_bytes"),
+                      ("generated_code", "generated_code_size_in_bytes")):
+        assert type(sizes[key]) is int and sizes[key] == getattr(analysis, attr)
+    # the state is donated: what the step is handed it hands back
+    assert sizes["argument"] > 0 and sizes["alias"] > 0 and sizes["temp"] > 0
+    assert sizes["peak"] is None or (type(sizes["peak"]) is int
+                                     and sizes["peak"] >= sizes["alias"])
+    # a copy: a reader cannot change what the tracer keeps
+    sizes["peak"] = -1
+    assert trace.registered_memory("train_step")["peak"] != -1
+    assert trace.registered_memory("no_such_program") is None
+
+
+class _NoAnalysis:
+    """A backend whose executables have no memory analysis."""
+
+    def as_text(self):
+        return HLO
+
+    def memory_analysis(self):
+        return None
+
+
+def test_a_backend_without_analysis_registers_ops_and_no_memory(monkeypatch):
+    monkeypatch.setattr(trace, "_programs", {})
+    trace.register_program("p", _NoAnalysis())
+    assert trace.registered_memory("p") is None
+    assert len(trace.registered_ops("p")) == 12
+
+
+@pytest.mark.parametrize("compiled", [False, True],
+                         ids=["before_compile", "after_compile"])
+def test_memory_breakdown_line_holds_the_steps_peak(compiled, monkeypatch, caplog):
+    import logging
+
+    from shuffle_exchange_tpu.parallel.mesh import reset_topology
+
+    monkeypatch.setattr(trace, "_programs", {})
+    reset_topology()
+    engine = make_engine(memory_breakdown=True, steps_per_print=1)
+    if compiled:
+        engine.compile(ids())
+    lg = logging.getLogger("shuffle_exchange_tpu")
+    lg.addHandler(caplog.handler)
+    old = lg.level
+    lg.setLevel(logging.INFO)
+    try:
+        engine.train_batch(ids())
+    finally:
+        lg.removeHandler(caplog.handler)
+        lg.setLevel(old)
+    line, = [r.getMessage() for r in caplog.records
+             if "mem in_use=" in r.getMessage()]
+    assert "in_use=" in line and " peak=" in line
+    assert ("step_peak=" in line) == compiled, line
+    if compiled:
+        sizes = trace.registered_memory("train_step")
+        assert f"step_temp={sizes['temp'] / 2**30:.2f}GB" in line
+        if sizes["peak"]:
+            assert f"step_peak={sizes['peak'] / 2**30:.2f}GB" in line
 
 
 HLO = """HloModule jit_train_step, entry_computation_layout={(f32[8]{0})->f32[2]{0}}
