@@ -170,65 +170,145 @@ def _rows_or_zeros(x, index):
     return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
 
 
-def _held_dispatch(xs, order, inverse):
-    """One rank's share, the way in: xs [S, M] -> [R, M], row ``order[r] // k``
-    of xs at position r (zeros where ``order[r]`` is S * k: the position holds
-    nothing). ``inverse`` [S, k]: the position of each token-choice, R where it
-    has none (an absent expert, or dropped). Forward and backward move R rows
-    or S rows at a time, never S * k: the backward sums, choice by choice,
-    the gradient rows of a token's held choices."""
-    import jax
+# Rows of the token-ordered buffer one banded product sums at a time
+# (_run_sums). On a v5e 128 to 512 read the same to 5% (PERF.md section 6, PR 38).
+_RUN_BLOCK = 256
 
+
+def _run_halo(k: int) -> int:
+    """Rows of the next block a block's product reads too: a token's run of at
+    most k rows that starts in a block ends within k - 1 rows of the next."""
+    return -(-(k - 1) // 8) * 8
+
+
+def _held_runs(order, inverse, fit):
+    """The held share's positions in TOKEN order, for the two sums over a
+    token's held choices (the combine, the dispatch's backward).
+
+    ``order`` [R]: the token-choice at each position (S * k where it holds
+    nothing); ``inverse`` [S, k]: the position of each token-choice (R: none);
+    ``fit``: positions that hold something. Sorting the R positions by their
+    token-choice makes a token's held choices ONE contiguous run of at most k
+    rows. Returns ``runs`` [blocks, B + H] int32, the positions in that order
+    cut into blocks of B rows, each with the first H rows of the next (R where
+    there is no position: a row of zeros), and ``read`` [S]: the row of the
+    run sums (:func:`_run_sums`) where token s's run starts; for a token with
+    no held choice a row whose run is empty. One block more than the positions
+    fill, so that such a row exists."""
+    import jax.numpy as jnp
+
+    R = order.shape[0]
     k = inverse.shape[1]
-
-    @jax.custom_vjp
-    def dispatch(xs, order, inverse):
-        return _rows_or_zeros(xs, order // k)
-
-    def fwd(xs, order, inverse):
-        return dispatch(xs, order, inverse), inverse
-
-    def bwd(inverse, g):
-        return sum(_rows_or_zeros(g, inverse[:, j]) for j in range(k)), None, None
-
-    dispatch.defvjp(fwd, bwd)
-    return dispatch(xs, order, inverse)
+    H = _run_halo(k)
+    B = max(H, min(_RUN_BLOCK, -(-R // 8) * 8))
+    nb = R // B + 1
+    by_token = jnp.argsort(order, stable=True).astype(jnp.int32)
+    by_token = jnp.where(jnp.arange(R) < fit, by_token, R)
+    by_token = jnp.concatenate([by_token, jnp.full(((nb + 1) * B - R,), R, jnp.int32)])
+    runs = jnp.concatenate([by_token[:nb * B].reshape(nb, B),
+                            by_token[B:].reshape(nb, B)[:, :H]], axis=1)
+    count = (inverse < R).sum(axis=1, dtype=jnp.int32)
+    read = jnp.where(count > 0, jnp.cumsum(count) - count, nb * B - 1)
+    return runs, read
 
 
-def _held_combine(out_sorted, weights, order, inverse):
-    """One rank's share, the way back: out[s] = sum over the token's k choices
-    of ``weights[s, j]`` x row ``inverse[s, j]`` of out_sorted [R, M] (zeros
-    for a choice with no position), a choice at a time. The backward gathers
-    R rows of the cotangent for out_sorted (row ``order[r] // k``, scaled by
-    that choice's weight) and takes each weight's gradient as the dot of the
-    cotangent with its expert's row."""
+def _run_sums(rows, runs, read, order, k: int, weights=None):
+    """out[s] = the sum of ``rows`` [R, M] over the positions of token s's held
+    choices, each times its choice's weight where ``weights`` [S * k] is given:
+    [S, M]. ``runs``, ``read``: :func:`_held_runs`.
+
+    R row lookups bring the rows into token order (R x (1 + H / B), with the
+    halo), a banded 0/1 (or weight) matrix a block sums every run from each of
+    its rows on in ONE product (float32 accumulation, rounded once; bf16
+    operands are exact in it), and S lookups read each token's sum where its
+    run starts. On a v5e at R 30,720, S 16,384, width 2048, k 10: 2.1-2.4 ms
+    against 7.6 for k lookups a token; a k-row window a token 35-42, log2(k)
+    shifted adds 6-10, k shifted adds 7-13 (PERF.md section 6, PR 38)."""
     import jax
     import jax.numpy as jnp
 
-    S, k = inverse.shape
+    nb, BH = runs.shape
+    B = BH - _run_halo(k)
+    M = rows.shape[1]
+    dtype = rows.dtype
+    # S * k where there is no position, as ``order`` has it: "token" S, weight 0
+    choice = jnp.take(order, runs, mode="fill", fill_value=read.shape[0] * k)
+    token = choice // k
+    # band[b, i, j]: row j of block b (and its halo) holds the token of row i, at or after it
+    band = ((token[:, :B, None] == token[:, None, :])
+            & (jnp.arange(B)[:, None] <= jnp.arange(BH)[None, :]))
+    if weights is None:
+        band = band.astype(dtype)
+    else:
+        w = jnp.take(weights, choice, mode="fill", fill_value=0)
+        band = jnp.where(band, w[:, None, :], 0).astype(dtype)
+    # what the grouped GEMM leaves at a position that holds nothing is not
+    # zeros: those rows are read as zeros, not multiplied by zero
+    ordered = _rows_or_zeros(rows, runs.reshape(-1)).reshape(nb, BH, M)
+    sums = jnp.einsum(
+        "bij,bjm->bim", band, ordered, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None)
+    return jnp.take(sums.astype(dtype).reshape(nb * B, M), read, axis=0, mode="clip")
+
+
+def _held_dispatch(xs, order, k: int, runs, read):
+    """One rank's share, the way in: xs [S, M] -> [R, M], row ``order[r] // k``
+    of xs at position r (zeros where ``order[r]`` is S * k: the position holds
+    nothing). The forward looks up R rows. The backward sums the gradient rows
+    of a token's held choices over the token-ordered positions
+    (:func:`_run_sums`: R + S lookups), never k lookups a token."""
+    import jax
+
+    @jax.custom_vjp
+    def dispatch(xs, order, runs, read):
+        return _rows_or_zeros(xs, order // k)
+
+    def fwd(xs, order, runs, read):
+        return dispatch(xs, order, runs, read), (order, runs, read)
+
+    def bwd(res, g):
+        order, runs, read = res
+        return _run_sums(g, runs, read, order, k), None, None, None
+
+    dispatch.defvjp(fwd, bwd)
+    return dispatch(xs, order, runs, read)
+
+
+def _held_combine(out_sorted, weights, order, inverse, runs, read):
+    """One rank's share, the way back: out[s] = sum over the token's k choices
+    of ``weights[s, j]`` x row ``inverse[s, j]`` of out_sorted [R, M] (nothing
+    for a choice with no position), summed over the token-ordered positions
+    (:func:`_run_sums`: R + S row lookups). The backward looks up R rows of the
+    cotangent ONCE (row ``order[r] // k``): scaled by the position's weight
+    they are out_sorted's gradient, and their float32 dot with the position's
+    own row of out_sorted is that choice's weight gradient, which goes to
+    [S, k] as a lookup of R scalars (zero where a choice has no position)."""
+    import jax
+    import jax.numpy as jnp
+
+    k = inverse.shape[1]
     dtype = out_sorted.dtype
 
     @jax.custom_vjp
-    def combine(out_sorted, weights, order, inverse):
-        return sum(weights[:, j, None].astype(dtype) * _rows_or_zeros(out_sorted, inverse[:, j])
-                   for j in range(k))
+    def combine(out_sorted, weights, order, inverse, runs, read):
+        return _run_sums(out_sorted, runs, read, order, k, weights.reshape(-1))
 
-    def fwd(out_sorted, weights, order, inverse):
-        return combine(out_sorted, weights, order, inverse), (out_sorted, weights, order, inverse)
+    def fwd(out_sorted, weights, order, inverse, runs, read):
+        return (combine(out_sorted, weights, order, inverse, runs, read),
+                (out_sorted, weights, order, inverse))
 
     def bwd(res, g):
         out_sorted, weights, order, inverse = res
         # a position that holds nothing reads weight 0 and a row of zeros
         w_sorted = jnp.take(weights.reshape(-1), order, mode="fill", fill_value=0)
-        d_sorted = w_sorted[:, None].astype(dtype) * _rows_or_zeros(g, order // k)
-        d_weights = jnp.stack(
-            [jnp.sum(g.astype(jnp.float32)
-                     * _rows_or_zeros(out_sorted, inverse[:, j]).astype(jnp.float32), axis=-1)
-             for j in range(k)], axis=1).astype(weights.dtype)
-        return d_sorted, d_weights, None, None
+        g_rows = _rows_or_zeros(g, order // k)
+        d_sorted = w_sorted[:, None].astype(dtype) * g_rows
+        d_w_sorted = jnp.sum(g_rows.astype(jnp.float32) * out_sorted.astype(jnp.float32), axis=-1)
+        d_weights = jnp.take(d_w_sorted, inverse, mode="fill", fill_value=0)
+        return d_sorted, d_weights.astype(weights.dtype), None, None, None, None
 
     combine.defvjp(fwd, bwd)
-    return combine(out_sorted, weights, order, inverse)
+    return combine(out_sorted, weights, order, inverse, runs, read)
 
 
 def held_buffer_rows(tokens: int, k: int, held: int, n_experts: int,
@@ -260,6 +340,14 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu",
     adds nothing here (its part of the result is another rank's). Held rows
     past the buffer are DROPPED, last experts first, and counted. There is
     no exchange: a rank alone computes its own part of the layer's result.
+
+    The share moves the rows it holds, R = ``buffer_rows`` positions or S
+    tokens a pass, never k lookups a token: of rows of width M, the way in R
+    (and R again where the layer is replayed); the way back R (1.06 R with the
+    runs' halo) into token order + S; backward R for the combine (one lookup
+    serves ``out_sorted``'s gradient and the weights') and R + S for the
+    dispatch: 5 R + 2 S where the parent's per-choice form looked up
+    3 R + 3 k S (:func:`_held_runs`, :func:`_run_sums`).
     """
     import jax
     import jax.numpy as jnp
@@ -293,7 +381,8 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu",
             # choices have no position
             order = jnp.where(jnp.arange(R) < fit, order[:R], S * k)
             inverse = jnp.where(inverse < fit, inverse, R).reshape(S, k)
-            xsort = _held_dispatch(xs, order, inverse)       # [R, M]
+            runs, read = _held_runs(order, inverse, fit)
+            xsort = _held_dispatch(xs, order, k, runs, read)         # [R, M]
         else:
             xsort = _permuted_rows(xs, order, inverse, k)    # [S*k, M]
         # expert per row, for the bias epilogue (a position that holds
@@ -327,7 +416,8 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu",
         out_sorted = b("b_down", grouped_matmul(h, w("w_down"), group_sizes))
     with trace.scope("moe_combine"):
         if share:
-            return _held_combine(out_sorted, topk_w, order, inverse), fit, held - fit
+            return (_held_combine(out_sorted, topk_w, order, inverse, runs, read),
+                    fit, held - fit)
         out_flat = _permuted_rows(out_sorted, inverse, order)   # unsort
         out = (out_flat.reshape(S, k, M) * topk_w[..., None].astype(dtype)).sum(axis=1)
         return out, S * k, 0

@@ -140,6 +140,39 @@ def test_holding_every_expert_is_the_parents_layer_bit_for_bit(dtype):
         np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_a_share_of_every_expert_and_every_row_is_the_whole_layer(dtype):
+    """``held == all`` keeps the parent's code (above). The held-share code
+    given every expert and a buffer for every token-choice computes the same
+    layer another way (a token's sum in float32, rounded once): equal to the
+    compute dtype's rounding, value and gradients."""
+    from shuffle_exchange_tpu.moe.gating import topk_select
+    from shuffle_exchange_tpu.moe.layer import expert_mlp_ragged, init_expert_mlp
+
+    E, D, F, k, S = 16, 64, 32, 10, 96
+    params = jax.tree.map(lambda a: a.astype(dtype),
+                          init_expert_mlp(jax.random.PRNGKey(0), E, D, F))
+    xs = jax.random.normal(jax.random.PRNGKey(1), (S, D)).astype(dtype)
+    idx, w, _, _ = topk_select(jax.random.normal(jax.random.PRNGKey(2), (S, E)), k)
+    mix = jax.random.normal(jax.random.PRNGKey(3), (S, D))
+
+    def both(**share):
+        def loss(p, x, w):
+            out, rows, dropped = expert_mlp_ragged(p, x, idx, w, **share)
+            return (out.astype(jnp.float32) * mix).sum(), (rows, dropped)
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(params, xs, w)
+
+    (got, (rows, dropped)), d_got = both(buffer_rows=S * k)
+    (want, _), d_want = both()
+    assert (int(rows), int(dropped)) == (S * k, 0)
+    step = 2.0 ** -7 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(float(got), float(want), rtol=step)
+    for a, b in zip(jax.tree.leaves(d_got), jax.tree.leaves(d_want)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, rtol=step, atol=step * np.abs(b).max())
+
+
 def test_activation_checkpointing_reaches_the_model():
     import shuffle_exchange_tpu as sxt
 
