@@ -158,6 +158,15 @@ class InferenceEngine:
         self.model = model
         self.config = config or InferenceConfig()
         self._mcfg = model.config
+        mixers = {mixer for mixer, _ in getattr(self._mcfg, "kinds_used", ())}
+        if "swa" in mixers:
+            raise NotImplementedError(
+                "serving a stack of window and full attention kinds (mixer "
+                "'swa' beside 'attn': layer_pattern) with per-kind head counts "
+                "and RoPE tables is not implemented: the inference engines "
+                "keep ONE uniform KV pool (one head count, one table, every "
+                "key kept) for one kind of layer, with no window to evict by "
+                "(training through sxt.initialize is; ROADMAP R-M3)")
         if getattr(self._mcfg, "recurrent", False) or len(
                 getattr(self._mcfg, "pattern", ((),))) > 1:
             # the cached paths scan ONE kind of layer over a KV cache: a
@@ -444,7 +453,10 @@ class InferenceEngine:
             return x, (None, None), positions
         if cfg.position == "alibi":
             return x, (None, None), positions
-        cos, sin = rope_table(self.config.max_seq_len, cfg.rotary_dims, cfg.rope_theta)
+        # the model's own table (YaRN where it states one); a stack with a
+        # second table (mixer "swa") is refused in __init__
+        cos, sin = rope_table(self.config.max_seq_len, cfg.rotary_dims, cfg.rope_theta,
+                              cfg.rope_yarn)
         return x, (cos, sin), positions
 
     def _lora_add(self, base, x, lora, target):

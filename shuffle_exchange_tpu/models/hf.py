@@ -22,6 +22,7 @@ a local checkpoint directory — no network access is assumed.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Tuple
 
 import numpy as np
@@ -66,6 +67,7 @@ _MODEL_TYPE_FAMILIES = {"llama": "llama", "mistral": "llama", "qwen2": "qwen2",
                         "internlm2": "internlm2", "olmoe": "olmoe",
                         "qwen3_next": "qwen3next",
                         "deepseek_v3": "deepseekv3",
+                        "laguna": "laguna",
                         "megatron": "megatron",
                         "megatron-gpt": "megatron", "megatron_gpt": "megatron"}
 
@@ -94,6 +96,116 @@ def _held_share(cfg: Dict[str, Any], family: str) -> Dict[str, Any]:
     return dict(n_experts_held=int(cfg["num_experts_held"]),
                 expert_first=int(cfg.get("expert_first", 0)),
                 moe_held_rows_factor=float(cfg["expert_buffer_factor"]))
+
+
+def _laguna_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerConfig:
+    """poolside's ``model_type: laguna`` as Laguna-XS.2 ships it: window
+    (``sliding_attention``) and full-attention layers in one stack
+    (``layer_types``), each type with its own query-head count
+    (``num_attention_heads_per_layer``) over the same KV heads and its own
+    RoPE (``rope_parameters`` by type: the full layers' YaRN over
+    ``partial_rotary_factor`` of a head, the window layers' plain table),
+    leading ``dense`` layers of ``intermediate_size`` then ``sparse`` ones
+    (``mlp_layer_types``): a sigmoid router whose chosen scores are
+    renormalised and scaled by ``moe_routed_scaling_factor``, dropless
+    ("ragged"), one ungated shared expert. ``gating: true`` is read as "the
+    feed-forward blocks are gated (SwiGLU)": the published parameter count
+    needs three matrices an expert. Only the first ``num_hidden_layers``
+    entries of the three lists are read (a cut in depth keeps them whole).
+    ``num_experts_held`` / ``expert_first`` / ``expert_buffer_factor`` as for
+    qwen3_next; ``aux_loss_alpha`` (not the source's key): the sequence-wise
+    balance loss. What is not written here is refused by name."""
+    L = int(cfg["num_hidden_layers"])
+    types = list(cfg.get("layer_types") or ["full_attention"] * L)[:L]
+    ffns = list(cfg.get("mlp_layer_types") or ["sparse"] * L)[:L]
+    heads = list(cfg.get("num_attention_heads_per_layer")
+                 or [cfg["num_attention_heads"]] * L)[:L]
+    ropes = cfg.get("rope_parameters") or {}
+    refused = {
+        "gating": cfg.get("gating", True) is not True,
+        "attention_bias": bool(cfg.get("attention_bias")),
+        "moe_apply_router_weight_on_input": bool(cfg.get("moe_apply_router_weight_on_input")),
+        "moe_router_logit_softcapping": bool(cfg.get("moe_router_logit_softcapping")),
+        "rope_scaling": cfg.get("rope_scaling") is not None,
+        "norm_topk_prob": cfg.get("norm_topk_prob", True) is not True,
+        "layer_types": min(len(types), len(ffns), len(heads)) < L or not set(
+            types) <= {"full_attention", "sliding_attention"},
+        "mlp_layer_types": not set(ffns) <= {"dense", "sparse"},
+    }
+    for key, bad in refused.items():
+        if bad:
+            raise ValueError(
+                f"laguna with {key}={cfg.get(key)!r} is not supported (written "
+                "down: gated feed-forward blocks and no other gate, no bias, the "
+                "router's weight on the output, no logit soft cap, RoPE stated "
+                "per layer type in rope_parameters, the chosen scores "
+                "renormalised, layers of full_attention / sliding_attention and "
+                "dense / sparse named for every layer)")
+    for kind, rp in ropes.items():
+        if not isinstance(rp, dict):         # e.g. original_max_position_embeddings
+            continue
+        allowed = ("default", "yarn") if kind == "full_attention" else ("default",)
+        if rp.get("rope_type", "default") not in allowed:
+            raise ValueError(
+                f"laguna with rope_parameters[{kind!r}] rope_type="
+                f"{rp.get('rope_type')!r} is not supported (written down: "
+                "'default', and 'yarn' on the full-attention layers)")
+    kinds = [("attn" if t == "full_attention" else "swa",
+              "mlp" if f == "dense" else "moe") for t, f in zip(types, ffns)]
+    lead = next((i for i, f in enumerate(ffns) if f != "dense"), L)
+    if lead >= L or len(set(kinds[:lead])) > 1:
+        raise ValueError(f"laguna: {lead} leading dense layer(s) of kinds "
+                         f"{sorted(set(kinds[:lead]))} in a stack of {L}: the leading "
+                         "layers are of one kind and routed layers follow")
+    rest = kinds[lead:]
+    # the shortest period the layers after the leading ones repeat; a stack
+    # that ends part of the way into its pattern (the published 40 layers: the
+    # leading one, nine periods of four and three window layers) is ONE period
+    # of its whole length: it runs, unrolled, at a compile time that grows
+    # with the depth (ROADMAP R-M3)
+    period = next(p for p in range(1, len(rest) + 1) if len(rest) % p == 0
+                  and rest[:p] * (len(rest) // p) == rest)
+    per_mixer = {m: {h for (mm, _), h in zip(kinds, heads) if mm == m}
+                 for m in ("attn", "swa")}
+    if any(len(h) > 1 for h in per_mixer.values()):
+        raise ValueError("laguna: num_attention_heads_per_layer varies within a "
+                         f"layer type ({per_mixer}): one count a type is implemented")
+    head = int(cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"])
+    full = ropes.get("full_attention", {})
+    window = ropes.get("sliding_attention", {})
+    yarn = ()
+    if full.get("rope_type") == "yarn":
+        factor = float(full["factor"])
+        scale = full.get("attention_factor")
+        yarn = (factor, float(full["original_max_position_embeddings"]),
+                float(full.get("beta_fast", 32)), float(full.get("beta_slow", 1)),
+                float(scale if scale is not None else 0.1 * math.log(factor) + 1.0))
+    swa = {}
+    if per_mixer["swa"]:
+        swa = dict(swa_window=int(cfg["sliding_window"]),
+                   swa_heads=int(next(iter(per_mixer["swa"]))),
+                   swa_rope_theta=float(window.get("rope_theta", 10000.0)),
+                   swa_rotary_dim=int(head * float(window.get("partial_rotary_factor", 1.0))))
+    alpha = float(cfg.get("aux_loss_alpha") or 0.0)
+    common.update(
+        d_ff=cfg["moe_intermediate_size"],
+        n_heads=int(next(iter(per_mixer["attn"] or per_mixer["swa"]))),
+        rope_theta=float(full.get("rope_theta", cfg.get("rope_theta", 10000.0))))
+    return TransformerConfig(
+        head_size=head,
+        rotary_dim=int(head * float(full.get(
+            "partial_rotary_factor", cfg.get("partial_rotary_factor", 1.0)))),
+        rope_yarn=yarn, **swa,
+        layer_pattern=tuple(rest[:period]),
+        lead_layers=lead, lead_kind=kinds[0] if lead else (),
+        dense_ff=cfg["intermediate_size"],
+        n_experts=cfg["num_experts"], **_held_share(cfg, "laguna"),
+        moe_top_k=cfg["num_experts_per_tok"], moe_norm_topk=True,
+        moe_score="sigmoid",
+        moe_weight_scale=float(cfg.get("moe_routed_scaling_factor", 1.0)),
+        moe_shared_expert_ff=int(cfg.get("shared_expert_intermediate_size") or 0),
+        moe_shared_gate="none", moe_impl="ragged",
+        moe_aux="sequence" if alpha else "none", aux_loss_coef=alpha, **common)
 
 
 def config_from_hf(hf_config) -> TransformerConfig:
@@ -418,6 +530,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
             # without them (transformers' modelling code computes none): none
             moe_aux="sequence" if alpha and cfg.get("seq_aux", True) else "none",
             aux_loss_coef=alpha, **common)
+    if family == "laguna":
+        return _laguna_config(cfg, common)
     if family == "mixtral":
         return TransformerConfig(
             n_experts=cfg["num_local_experts"], moe_top_k=cfg.get("num_experts_per_tok", 2),

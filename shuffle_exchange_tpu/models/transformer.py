@@ -23,7 +23,9 @@ RMSNorm, SwiGLU, GQA) families plus tiny test sizes.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -84,8 +86,13 @@ class TransformerConfig:
     local_attention_window: int = 0            # window for "local" layers
     attention_pattern: Tuple[str, ...] = ()    # per-layer "global"/"local",
                                                # cycled over n_layers: a flag
-                                               # of ONE kind of layer (see
-                                               # layer_pattern for kinds)
+                                               # of ONE kind of layer over a
+                                               # dense [T, T] mask (GPT-Neo,
+                                               # hf.py's import of it; short
+                                               # contexts). Window layers
+                                               # with shapes of their own,
+                                               # through the kernels: mixer
+                                               # "swa" of a layer_pattern
     dtype: Any = None                          # compute dtype override (engine usually casts)
     remat: bool = False
     remat_policy: str = "dots_saveable"
@@ -148,6 +155,11 @@ class TransformerConfig:
     # (``params["layers"][kind]``, [periods, layers of the kind a period, ...];
     # a one-kind model keeps the flat ``params["layers"]`` [n_layers, ...]).
     #   mixer "attn"        softmax attention as every flag above shapes it
+    #                       (a one-kind model); among several kinds plain
+    #                       causal RoPE GQA (``_gqa``: n_heads, the model's
+    #                       table, no flag of the family)
+    #         "swa"         the same over a window, a kind of its own:
+    #                       swa_window / swa_heads / swa_rope_* below
     #         "gated_attn"  Qwen3-Next full attention: q and a sigmoid output
     #                       gate from one projection, per-HEAD q/k RMSNorm
     #                       (the block norm's kind) before RoPE
@@ -216,10 +228,42 @@ class TransformerConfig:
     moe_select_bias: bool = False
     moe_weight_scale: float = 1.0
     moe_bias_update_rate: float = 0.0
+    # Windowed softmax attention as a KIND of layer (mixer "swa" of a
+    # layer_pattern, beside "attn" layers that see everything before them):
+    # key j is visible to query i iff 0 <= i - j < ``swa_window`` (itself and
+    # the window - 1 before it). The kind has its own head count
+    # (``swa_heads`` query heads over the model's ``kv_heads``; 0 = n_heads)
+    # and so its own projection shapes, and its own RoPE table
+    # (``swa_rope_theta``, 0 = rope_theta; ``swa_rotary_dim`` leading dims of
+    # a head, 0 = all), built once outside the layer scans. The window reaches
+    # the attention kernels as a local causal mask whose empty blocks they
+    # skip (ops/flash_attention ``window``): no [T, T] mask exists at any size.
+    # (``local_attention_window`` / ``attention_pattern`` above are GPT-Neo's
+    # older form: a FLAG of one kind over a dense mask.)
+    swa_window: int = 0
+    swa_heads: int = 0
+    swa_rope_theta: float = 0.0
+    swa_rotary_dim: int = 0
+    # YaRN on the model's own RoPE table (the "attn" layers'; ``rope_table``):
+    # (factor, original_max_position_embeddings, beta_fast, beta_slow,
+    # attention_factor), () = unscaled. The inverse frequencies are blended
+    # between interpolation (/ factor) and extrapolation by the ramp
+    # transformers' ``_compute_yarn_parameters`` builds over the ``rotary_dims``
+    # rotated dims, and cos and sin carry the attention factor.
+    rope_yarn: Tuple[float, ...] = ()
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def several_kinds(self) -> bool:
+        """The stack has layers of more than one (mixer, ffn) kind."""
+        return len(set(self.kinds_used)) > 1
+
+    def heads_of(self, mixer: str) -> int:
+        """Query heads of a layer whose mixer is ``mixer``."""
+        return self.swa_heads or self.n_heads if mixer == "swa" else self.n_heads
 
     @property
     def rotary_dims(self) -> int:
@@ -393,13 +437,45 @@ def _no_routing_stats(n_experts: int, weights: bool = False) -> dict:
     return out
 
 
-def rope_table(seq_len: int, head_dim: int, theta: float):
+def yarn_inv_freq(head_dim: int, theta: float, factor: float, original_max: float,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """YaRN's inverse frequencies [head_dim / 2] (numpy float32), as
+    transformers' ``_compute_yarn_parameters`` (truncate: true) gives them: a
+    frequency that turns more than ``beta_fast`` times over the original
+    context is kept (extrapolation), one that turns fewer than ``beta_slow``
+    times is divided by ``factor`` (interpolation), a linear ramp over the
+    pair index between."""
+    import numpy as np
+
+    def correction_dim(rotations):
+        return (head_dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    pos = theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim)
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low) / (high - low), 0, 1)
+    # ramp 0: extrapolate (the plain frequency), 1: interpolate (/ factor)
+    return ((1.0 / (factor * pos)) * ramp + (1.0 / pos) * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_table(seq_len: int, head_dim: int, theta: float, yarn=()):
+    """(cos, sin) [seq_len, head_dim / 2] of ``head_dim`` rotated dims.
+    ``yarn``: ``TransformerConfig.rope_yarn`` (factor, original context,
+    beta_fast, beta_slow, attention_factor); the attention factor multiplies
+    cos and sin, so q and k each carry it. () = the plain table."""
     import jax.numpy as jnp
 
-    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if yarn:
+        freqs = jnp.asarray(yarn_inv_freq(head_dim, theta, *yarn[:4]))
+    else:
+        freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
     t = jnp.arange(seq_len, dtype=jnp.float32)
     angles = jnp.outer(t, freqs)  # [T, D/2]
-    return jnp.cos(angles), jnp.sin(angles)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    return (cos * yarn[4], sin * yarn[4]) if yarn else (cos, sin)
 
 
 def apply_rope(x, cos, sin, interleaved: bool = False):
@@ -504,25 +580,29 @@ def decode_fusion_eligibility(cfg: "TransformerConfig",
 
 
 def causal_attention(q, k, v, attention_impl: str = "auto", alibi=None,
-                     causal: bool = True):
+                     causal: bool = True, window: int = 0):
     """q: [B,T,H,D], k/v: [B,T,Hkv,D] → [B,T,H,D]. fp32 softmax.
 
     Dispatches to the Pallas flash kernel on TPU (ops/flash_attention);
     jnp reference elsewhere. ``alibi`` = per-head slopes [H] (BLOOM).
-    ``causal=False`` = bidirectional (encoder models)."""
-    import jax.numpy as jnp
-
+    ``causal=False`` = bidirectional (encoder models). ``window`` > 0: a
+    query sees itself and the ``window - 1`` keys before it (mixer "swa")."""
     from ..ops.flash_attention import flash_attention
 
     return flash_attention(q, k, v, causal=causal, impl=attention_impl,
-                           alibi_slopes=alibi)
+                           alibi_slopes=alibi, window=window)
 
 
 def _windowed_attention(q, k, v, window: int, local_flag):
     """Causal attention with a conditional trailing window (GPT-Neo local
     layers, reference containers/gptneo.py). ``local_flag`` is a traced
     bool — True restricts key j to i - j < window — so global and local
-    layers share one scanned program."""
+    layers share one scanned program. A FLAG of one kind of layer over a
+    dense [T, T] mask that no kernel reads: GPT-Neo's form (``hf.py``'s
+    import of it) and short contexts only. A model whose window layers are a
+    kind of their own (their own shapes, long sequences) states mixer "swa"
+    in its ``layer_pattern`` and goes through the kernels
+    (``Transformer._gqa``, ``ops/flash_attention`` ``window``)."""
     import jax
     import jax.numpy as jnp
 
@@ -647,7 +727,8 @@ class Transformer:
 
         cfg = self.config
         mixer, ffn = kind
-        L, D, H, KV, Dh, F = cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim, cfg.ff_dim
+        L, D, H, KV, Dh, F = (cfg.n_layers, cfg.d_model, cfg.heads_of(mixer),
+                              cfg.kv_heads, cfg.head_dim, cfg.ff_dim)
         n = math.prod(lead)
 
         def stack(key, shape, fan_in, scale=1.0):
@@ -836,7 +917,17 @@ class Transformer:
                       eps=cfg.norm_eps)
         if cfg.position in ("learned", "alibi"):
             return x, (None, None)
-        return x, rope_table(T, cfg.rotary_dims, cfg.rope_theta)
+        return x, self.rope_for("attn", T)
+
+    def rope_for(self, mixer: str, seq_len: int):
+        """The (cos, sin) table the layers of mixer ``mixer`` rotate by: the
+        model's own (``rope_theta``, ``rotary_dim``, ``rope_yarn``) or the
+        window kind's (``swa_rope_theta``, ``swa_rotary_dim``, unscaled)."""
+        cfg = self.config
+        if mixer == "swa":
+            return rope_table(seq_len, cfg.swa_rotary_dim or cfg.head_dim,
+                              cfg.swa_rope_theta or cfg.rope_theta)
+        return rope_table(seq_len, cfg.rotary_dims, cfg.rope_theta, cfg.rope_yarn)
 
     def layer_apply(self, lw, h, rope, local=None, moe_on=None, kind=None,
                     remat_halves=False):
@@ -860,10 +951,11 @@ class Transformer:
         H, KV, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
         cos, sin = rope
         dtype = h.dtype
-        if mixer != "attn":
-            # the pattern's other mixers: no flag of the softmax-attention
-            # family reaches them (config_from_hf builds them; a pre-LN
-            # sequential block). Under remat the block's two halves are
+        if mixer != "attn" or cfg.several_kinds:
+            # the pattern's other mixers, and "attn" itself in a stack of
+            # several kinds (plain GQA there: ``_gqa``): no flag of the
+            # softmax-attention family reaches them (config_from_hf builds
+            # them; a pre-LN sequential block). Under remat the block's two halves are
             # checkpointed EACH (``remat_halves``, set by stack_apply): the
             # backward then holds the mixer's residuals or the FFN's, never
             # both, for the same recomputation as one checkpoint a layer
@@ -882,7 +974,8 @@ class Transformer:
             # ``gdn`` has no such kernel; "stock_flash", "reference" and the
             # ring's hop kernels name nothing and recompute
             mix = {"gdn": self._gdn, "gated_attn": self._gated_attention,
-                   "mla": self._mla}[mixer]
+                   "mla": self._mla, "attn": self._gqa,
+                   "swa": functools.partial(self._gqa, mixer="swa")}[mixer]
 
             def mixer_half(lw, h):
                 with trace.scope("attn_norm"):
@@ -968,6 +1061,56 @@ class Transformer:
         with trace.scope("moe" if ffn == "moe" else "mlp"):
             h, aux, stats = self._ffn(lw, h, y2, attn_out, moe_on, ffn)
         return h, (aux, stats)
+
+    def _gqa(self, lw, y, rope, mixer="attn"):
+        """Plain GQA softmax attention as a mixer of a stack of several kinds,
+        on the normed block input y [B, T, D] -> [B, T, D]: ``q = y Wq``
+        [H x Dh], ``k = y Wk``, ``v = y Wv`` [KV x Dh], RoPE by ``rope`` (the
+        kind's own table: ``rope_for``), causal attention, ``o Wo``. Mixer
+        "attn": the model's ``n_heads``, every earlier key visible. Mixer
+        "swa": ``swa_heads`` heads and the ``swa_window`` keys up to the
+        query's own, with its own scopes nested in the attention layer's
+        (``swa_qkv`` and ``swa_rope`` in ``attn_qkv``, ``swa_core`` in
+        ``attn_core``, ``swa_out`` in ``attn_out``); an "attn" layer that
+        rotates by a YaRN table does so under ``rope_yarn``. None of the
+        softmax family's flags reaches this form (biases, q/k norm, ALiBi,
+        post-LN, a parallel block: a one-kind model's, ``layer_apply``)."""
+        from jax.ad_checkpoint import checkpoint_name
+
+        cfg = self.config
+        flags = [f for f in ("attn_qkv_bias", "attn_out_bias", "qk_norm", "post_ln",
+                             "parallel_block", "attn_scale", "local_attention_window")
+                 if getattr(cfg, f)]
+        if flags or cfg.position != "rope" or not cfg.causal:
+            raise NotImplementedError(
+                f"a stack of several kinds runs mixer {mixer!r} as plain causal "
+                f"RoPE GQA; this configuration sets {flags or cfg.position!r}")
+        windowed = mixer == "swa"
+        if windowed and cfg.swa_window <= 0:
+            raise ValueError("mixer 'swa' needs swa_window > 0")
+        B, T = y.shape[:2]
+        H, KV, Dh = cfg.heads_of(mixer), cfg.kv_heads, cfg.head_dim
+        cos, sin = rope
+        # a window layer's own scope inside each of the attention layer's
+        own = lambda name: trace.scope(name) if name else contextlib.nullcontext()
+        swa = lambda part: own("swa_" + part if windowed else None)
+        with trace.scope("attn_qkv"):
+            with swa("qkv"):
+                q = (y @ lw["wq"]).reshape(B, T, H, Dh)
+                k = (y @ lw["wk"]).reshape(B, T, KV, Dh)
+                v = (y @ lw["wv"]).reshape(B, T, KV, Dh)
+            with own("swa_rope" if windowed else "rope_yarn" if cfg.rope_yarn else None):
+                q = apply_rope(q, cos, sin, interleaved=cfg.rope_interleaved)
+                k = apply_rope(k, cos, sin, interleaved=cfg.rope_interleaved)
+        q = checkpoint_name(q, "q")
+        k = checkpoint_name(k, "kv")
+        v = checkpoint_name(v, "kv")
+        with trace.scope("attn_core"), swa("core"):
+            attn = self._attention(q, k, v, None,
+                                   window=cfg.swa_window if windowed else 0)
+        attn = checkpoint_name(attn, "attn")
+        with trace.scope("attn_out"), swa("out"):
+            return attn.reshape(B, T, H * Dh) @ lw["wo"]
 
     def _gated_attention(self, lw, y, rope):
         """Qwen3-Next's full-attention mixer on the normed block input
@@ -1254,8 +1397,10 @@ class Transformer:
         topo = get_topology()
         return topo.size("seq"), topo.mesh
 
-    def _attention(self, q, k, v, alibi):
+    def _attention(self, q, k, v, alibi, window: int = 0):
         """Core attention, sequence-parallel when the mesh has a "seq" axis.
+        ``window`` > 0 (mixer "swa"): a query sees itself and the
+        ``window - 1`` keys before it; one device's sequences only.
 
         Ulysses (reference DistributedAttention, sequence/layer.py:331)
         engaged via shard_map inside the jitted step: activations shard
@@ -1266,9 +1411,14 @@ class Transformer:
         alibi_sp_ok below for the replicated-fallback cases."""
         cfg = self.config
         sp, mesh = self._sp_mesh()
+        if window and sp > 1:
+            raise NotImplementedError(
+                "windowed attention (mixer 'swa') under a sequence-parallel "
+                "mesh is not implemented: neither Ulysses' local kernel call "
+                "nor the ring's hops carry a window")
         if (cfg.remat and cfg.remat_policy == "save_flash_lse"
                 and alibi is None and sp <= 1 and cfg.causal
-                and not cfg.local_attention_window):
+                and not cfg.local_attention_window and not window):
             # save_flash_lse: route through the lse-emitting kernel so the
             # policy has residuals to save — the stock flash kernel's
             # custom-vjp residuals are anonymous, which is exactly why
@@ -1329,7 +1479,7 @@ class Transformer:
                 "axis, or bidirectional) — attention stays replicated")
         if sp <= 1 or (alibi is not None and not alibi_sp_ok):
             return causal_attention(q, k, v, attention_impl=cfg.attention_impl,
-                                    alibi=alibi, causal=cfg.causal)
+                                    alibi=alibi, causal=cfg.causal, window=window)
         import functools as ft
 
         import jax
@@ -1518,15 +1668,19 @@ class Transformer:
             # model: a period of one layer, the row is the scan's own slice),
             # each under its own remat, and hands out per layer what
             # layer_apply does (the router stats of the routed layers only)
+            # a RoPE table a kind, built here, once, outside the scans
+            ropes = {"swa": self.rope_for("swa", x.shape[-2])} if any(
+                mixer == "swa" for mixer, _ in cfg.kinds_used) else {}
+
             def run(kind):
                 # a layer of the softmax-attention family is checkpointed
-                # whole; the pattern's other mixers checkpoint their two
-                # halves themselves (layer_apply)
-                whole = kind[0] == "attn"
+                # whole; the pattern's other mixers (and "attn" among several
+                # kinds) checkpoint their two halves themselves (layer_apply)
+                whole = kind[0] == "attn" and not cfg.several_kinds
 
                 def layer_fn(h, lw, loc):
                     return self.layer_apply(
-                        lw, h, rope, local=loc, kind=kind,
+                        lw, h, ropes.get(kind[0], rope), local=loc, kind=kind,
                         remat_halves=cfg.remat and not whole)
 
                 if cfg.remat and whole:

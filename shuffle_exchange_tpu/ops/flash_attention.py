@@ -97,8 +97,10 @@ def _repeat_kv(k, n_rep: int):
 
 
 def reference_attention(q, k, v, causal: bool = True, segment_ids=None,
-                        alibi_slopes=None):
-    """q [B,T,H,D], k/v [B,S,Hkv,D] -> [B,T,H,D]; fp32 softmax.
+                        alibi_slopes=None, window: int = 0):
+    """q [B,T,H,D], k/v [B,S,Hkv,D] -> [B,T,H,D]; fp32 softmax. ``window``
+    > 0: key j is visible to query i iff 0 <= i - j < window (a dense mask:
+    the oracle's form, and the route of shapes no kernel takes).
 
     ``alibi_slopes`` [H]: adds slope_h * j to key position j (BLOOM ALiBi;
     per-query-row softmax shift-invariance makes the absolute form equal to
@@ -119,6 +121,8 @@ def reference_attention(q, k, v, causal: bool = True, segment_ids=None,
     if causal:
         t, s = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((t, s), bool), k=s - t)
+        if window:
+            mask = mask & ~jnp.tril(jnp.ones((t, s), bool), k=s - t - window)
         logits = jnp.where(mask[None, None], logits, -1e30)
     if segment_ids is not None:
         seg_mask = segment_ids[:, None, :, None] == segment_ids[:, None, None, :]
@@ -127,14 +131,60 @@ def reference_attention(q, k, v, causal: bool = True, segment_ids=None,
     return jnp.einsum("bhts,bshd->bthd", probs, v)
 
 
+def window_block(n: int, window: int, itemsize: int = 2) -> int:
+    """The q and kv block of a windowed splash call over ``n`` positions: the
+    largest candidate that divides ``n`` and is no longer than the window (or
+    the smallest candidate). A block twice the window computes four times
+    the window's scores; at the window's own length a query block visits two
+    key blocks, its own and the one before."""
+    fits = [b for b in BLOCK_CANDIDATES if n % b == 0
+            and b <= max(window, BLOCK_CANDIDATES[-1])
+            and (itemsize <= 2 or b <= 512)]
+    return fits[0] if fits else _pick_block(n, itemsize)
+
+
+def splash_mask(T: int, S: int, causal: bool = True, window: int = 0):
+    """The splash mask of one head for [T, S] scores: ``CausalMask`` /
+    ``FullMask``, or for ``window`` > 0 the causal ``LocalMask`` of the window
+    (key j visible iff 0 <= i - j < window) whose empty blocks the kernels
+    skip, forward and both backward kernels."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
+
+    if window:
+        if not causal:
+            raise ValueError("a window is causal: itself and the keys before it")
+        return sa.LocalMask((T, S), window_size=(window - 1, 0), offset=S - T)
+    return sa.CausalMask((T, S)) if causal else sa.FullMask((T, S))
+
+
+def block_visit_share(T: int, window: int, itemsize: int = 2) -> float:
+    """Of the causal (query block, key block) pairs of self-attention over
+    ``T`` positions at the windowed call's block, the percentage the kernel's
+    mask visits: read from the mask info the forward kernel is built with
+    (``fwd_mask_info.block_mask``, non-zero = partly or wholly visible). 100
+    says the window did not reach the kernel."""
+    import numpy as np
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
+
+    blk = window_block(T, window, itemsize) if window else _pick_block(T, itemsize)
+    kernel = sa.make_splash_mqa_single_device(
+        sa.MultiHeadMask([splash_mask(T, T, True, window)]),
+        block_sizes=sa.BlockSizes(block_q=blk, block_kv=blk, block_kv_compute=blk))
+    visited = int((np.asarray(kernel.fwd_mask_info.block_mask) > 0).sum())
+    n = T // blk
+    return 100.0 * visited / (n * (n + 1) // 2)
+
+
 def splash_attention_gqa(q, k, v, causal: bool = True, segment_ids=None,
-                         interpret: bool = False, mask_np=None):
+                         interpret: bool = False, mask_np=None, window: int = 0):
     """GQA/MQA flash attention with UNEXPANDED KV (splash MQA kernel).
 
     The stock flash kernel needs KV repeated to H heads; splash's MQA form
     takes one kv head per group natively, so HBM reads of K/V stay
     n_kv-sized — the structural fix for VERDICT r2 weak #5 (the `_repeat_kv`
-    broadcast claim no longer needs XLA's cooperation). q [B,T,H,D],
+    broadcast claim no longer needs XLA's cooperation). ``window`` > 0: the
+    causal local mask of ``splash_mask`` at ``window_block``'s blocks, in
+    the forward and both backward kernels. q [B,T,H,D],
     k [B,S,KV,D], v [B,S,KV,Dv] with H % KV == 0; q heads group g of kv head
     j is h = j * G + g (the `_repeat_kv` convention). ``Dv`` may differ from
     ``D`` (latent attention: scores 192 wide, values 128): the kernels take
@@ -149,6 +199,8 @@ def splash_attention_gqa(q, k, v, causal: bool = True, segment_ids=None,
     G = H // KV
 
     bq, bkv = _pick_block(T, q.dtype.itemsize), _pick_block(S, q.dtype.itemsize)
+    if window:
+        bq, bkv = (window_block(n, window, q.dtype.itemsize) for n in (T, S))
     # Backward blocks are independently tunable: the dkv/dq passes hold
     # extra residual tiles in VMEM, so their sweet spot can sit below the
     # forward's (the VERDICT r3 MFU item names attention-backward blocks as
@@ -182,7 +234,7 @@ def splash_attention_gqa(q, k, v, causal: bool = True, segment_ids=None,
         # fully-masked blocks — real block skipping, not just masking
         head_mask = sa.NumpyMask(mask_np)
     else:
-        head_mask = (sa.CausalMask((T, S)) if causal else sa.FullMask((T, S)))
+        head_mask = splash_mask(T, S, causal, window)
     mask = sa.MultiHeadMask([head_mask for _ in range(G)])
     kernel = sa.make_splash_mqa_single_device(
         mask, block_sizes=block_sizes, interpret=interpret,
@@ -232,11 +284,16 @@ def _pallas_ok(q, k, causal: bool = True) -> bool:
     return causal or (t % 128 == 0 and s % 128 == 0)
 
 
-def _pallas_kernel(q, k, v) -> str:
+def _pallas_kernel(q, k, v, window: int = 0) -> str:
     """Which Pallas kernel ``pallas_attention`` runs for these shapes:
     "splash" (GQA/MQA with unexpanded KV), "splash_own_v" (values of another
-    width than the scores: latent attention) or "stock_flash" (MHA)."""
+    width than the scores: latent attention), "splash_window" (a window: the
+    splash kernels under a local causal mask, whatever the head counts; the
+    stock kernel has no mask to skip blocks by) or "stock_flash" (MHA)."""
     import os
+
+    if window:
+        return "splash_window"
 
     # the stock kernel reads ONE head size from q and reshapes v with it:
     # values of another width than the scores go through splash, which takes
@@ -249,20 +306,22 @@ def _pallas_kernel(q, k, v) -> str:
     return "stock_flash"
 
 
-def attention_route(q, k, v, causal: bool = True, impl: str = "auto") -> str:
+def attention_route(q, k, v, causal: bool = True, impl: str = "auto",
+                    window: int = 0) -> str:
     """The route ``flash_attention`` takes for q, k, v of these shapes
     (anything with ``.shape``; no ALiBi, no segment ids), by name:
     "reference", "chunked", or a Pallas kernel of ``_pallas_kernel``. The
     dispatcher below asks the same question, so a reader who prints this
-    prints what runs."""
-    if impl in ("reference", "chunked"):
+    prints what runs. ``window`` > 0 has no "chunked" form: the reference."""
+    if impl == "reference" or (impl == "chunked" and not window):
         return impl
     if impl == "pallas" or (impl == "auto" and _pallas_ok(q, k, causal)):
-        return _pallas_kernel(q, k, v)
+        return _pallas_kernel(q, k, v, window)
     return "reference"
 
 
-def pallas_attention(q, k, v, causal: bool = True, segment_ids=None):
+def pallas_attention(q, k, v, causal: bool = True, segment_ids=None,
+                     window: int = 0):
     """Blocked flash attention via the Pallas TPU kernels (jax.experimental).
 
     Input [B,T,H,D]; the kernel's layout is [B,H,T,D]. GQA goes through the
@@ -277,7 +336,7 @@ def pallas_attention(q, k, v, causal: bool = True, segment_ids=None):
     )
 
     n_rep = q.shape[2] // k.shape[2]
-    use_splash = _pallas_kernel(q, k, v) != "stock_flash"
+    use_splash = _pallas_kernel(q, k, v, window) != "stock_flash"
     if not use_splash:
         k = _repeat_kv(k, n_rep)
         v = _repeat_kv(v, n_rep)
@@ -302,7 +361,8 @@ def pallas_attention(q, k, v, causal: bool = True, segment_ids=None):
                                    constant_values=-1)
 
     if use_splash:
-        out = splash_attention_gqa(q, k, v, causal=causal, segment_ids=segment_ids)
+        out = splash_attention_gqa(q, k, v, causal=causal, segment_ids=segment_ids,
+                                   window=window)
         return out[:, :t0] if t_pad else out
 
     qt = q.transpose(0, 2, 1, 3)
@@ -366,11 +426,16 @@ def flash_attention_remat(q, k, v, causal: bool = True, interpret: bool = False)
 
 
 def flash_attention(q, k, v, causal: bool = True, impl: str = "auto", segment_ids=None,
-                    alibi_slopes=None):
+                    alibi_slopes=None, window: int = 0):
     """q [B,T,H,D], k/v [B,S,Hkv,D] -> [B,T,H,D].
 
     impl: auto | pallas | reference | chunked (FPDT-style scan, long-context
-    memory bound — see ops/chunked_attention.py)."""
+    memory bound — see ops/chunked_attention.py). ``window`` > 0: key j is
+    visible to query i iff 0 <= i - j < window (``attention_route`` names the
+    route: the splash kernels under a local mask, else the reference)."""
+    if window and (alibi_slopes is not None or not causal):
+        raise NotImplementedError("a window with ALiBi, or without the causal "
+                                  "mask, is not implemented")
     if alibi_slopes is not None:
         # Fused ALiBi kernel (ops/alibi_attention.py): the per-head bias is
         # added to the score tile in VMEM inside a from-scratch flash
@@ -388,8 +453,9 @@ def flash_attention(q, k, v, causal: bool = True, impl: str = "auto", segment_id
         return reference_attention(q, k, v, causal=causal, segment_ids=segment_ids,
                                    alibi_slopes=alibi_slopes)
     if impl == "reference":
-        return reference_attention(q, k, v, causal=causal, segment_ids=segment_ids)
-    if impl == "chunked":
+        return reference_attention(q, k, v, causal=causal, segment_ids=segment_ids,
+                                   window=window)
+    if impl == "chunked" and not window:
         from .chunked_attention import chunked_attention
 
         if segment_ids is not None:
@@ -401,15 +467,17 @@ def flash_attention(q, k, v, causal: bool = True, impl: str = "auto", segment_id
             if chunk < 16:
                 return reference_attention(q, k, v, causal=causal, segment_ids=segment_ids)
         return chunked_attention(q, k, v, chunk_size=chunk, causal=causal)
-    if attention_route(q, k, v, causal, impl) != "reference":
+    if attention_route(q, k, v, causal, impl, window) != "reference":
         # selected means it runs or raises: a broken kernel must not turn
         # into a slow correct run on the reference that nobody notices
         if segment_ids is None:
             return _per_shard(lambda q, k, v: pallas_attention(
-                q, k, v, causal=causal), q, k, v)
+                q, k, v, causal=causal, window=window), q, k, v)
         return _per_shard(lambda q, k, v, seg: pallas_attention(
-            q, k, v, causal=causal, segment_ids=seg), q, k, v, segment_ids)
-    return reference_attention(q, k, v, causal=causal, segment_ids=segment_ids)
+            q, k, v, causal=causal, segment_ids=seg, window=window),
+            q, k, v, segment_ids)
+    return reference_attention(q, k, v, causal=causal, segment_ids=segment_ids,
+                               window=window)
 
 
 def _per_shard(kernel, q, k, v, *rows, shard_heads: bool = True):

@@ -64,14 +64,19 @@ PREFIX = "sxt:"
 # "mla_kv_down", "mla_kv_norm", "mla_kv_up" and "mla_rope" (rotation, the one
 # rotary key's broadcast over the heads, the concatenations) nest inside
 # "attn_qkv" (its attention kernel takes scores and values at their own
-# widths: nothing is padded, so there is no padding scope).
+# widths: nothing is padded, so there is no padding scope). A window layer
+# (mixer "swa") opens "swa_qkv" and "swa_rope" inside "attn_qkv", "swa_core"
+# inside "attn_core" and "swa_out" inside "attn_out"; a full layer of such a
+# stack that rotates by a YaRN table does so under "rope_yarn" inside
+# "attn_qkv".
 # "plumbing" is what belongs to no layer of the model: the layer scan's own
 # slicing and stacking, the masters' cast to the compute dtype, the
 # gradients' cast back and normalization
 SCOPES = {
     "attn": ("attn_norm", "attn_qkv", "attn_qk_norm", "attn_core", "attn_out",
              "attn_gate", "gdn_conv", "gdn_gates", "gdn_scan", "gdn_out_norm",
-             "mla_q", "mla_kv_down", "mla_kv_norm", "mla_kv_up", "mla_rope"),
+             "mla_q", "mla_kv_down", "mla_kv_norm", "mla_kv_up", "mla_rope",
+             "swa_qkv", "swa_rope", "swa_core", "swa_out", "rope_yarn"),
     "mlp": ("mlp_norm", "mlp", "moe", "moe_router", "moe_dispatch",
             "moe_experts", "moe_combine", "moe_shared"),
     "loss": ("embed", "final_norm", "loss", "head_logits", "head_softmax",

@@ -41,6 +41,8 @@ CASES = {
     "odd_buffer_k10": (_drawn(4, 30, 10, 16), 3, 5, 37),
     "every_choice_held_several_blocks": (_drawn(6, 130, 6, 6), 0, 6, 777),
     "k1": (_drawn(7, 50, 1, 4), 1, 2, 24),
+    # a 256-wide router with top 8, rank 2 of the 8 that hold 32 experts each
+    "k8_of_256_32_held": (_drawn(8, 64, 8, 256), 64, 32, 96),
 }
 
 

@@ -521,6 +521,23 @@ def test_flash_attention_fwd_bwd_compiles(chip_compile, model, T):
         q, kv, kv)
 
 
+@pytest.mark.parametrize("heads, window", [(64, 512), (48, 0)],
+                         ids=["window-64-heads", "full-48-heads"])
+def test_splash_attention_at_16k_compiles(chip_compile, heads, window):
+    """The two attention kinds of a window / full hybrid stack at 16,384
+    positions over 8 KV heads of 128 (PR 39): the splash kernels under the
+    local causal mask of a 512-key window at 512-blocks (groups of 8), and
+    under the causal mask at 1024-blocks (groups of 6), forward and both
+    backward kernels."""
+    from shuffle_exchange_tpu.ops.flash_attention import pallas_attention
+
+    q = ((1, 16384, heads, 128), _BF16)
+    kv = ((1, 16384, 8, 128), _BF16)
+    chip_compile(jax.grad(lambda q, k, v: pallas_attention(
+        q, k, v, causal=True, window=window).astype(_F32).sum(), argnums=(0, 1, 2)),
+        q, kv, kv)
+
+
 @pytest.mark.parametrize("rows", [(2, 2048), (8, 1)],
                          ids=["train-rows", "decode-rows"])
 def test_rmsnorm_compiles(chip_compile, rows):
