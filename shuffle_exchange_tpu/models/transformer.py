@@ -1200,16 +1200,20 @@ class Transformer:
         Hk key heads of dk, Hv value heads of dv, Hv a multiple of Hk.
         Under the outer scopes of an attention layer (``attn_qkv``,
         ``attn_core``, ``attn_out``), so that a reader's sums by layer hold,
-        with its own nested inside: ``gdn_conv``, ``gdn_gates`` (beta, the
-        log-decay g, the l2 norms), ``gdn_scan`` (the chunked rule),
-        ``gdn_out_norm``. g, beta, the norms and the rule's state are
-        float32; the projections, the convolution's result and the rule's
-        matmul operands are the compute dtype."""
+        with its own nested inside: ``gdn_conv`` (``gdn_prologue``: the
+        convolution, SiLU, the l2 norms of q and k, the repeat to Hv heads
+        and z's channels handed on, one pass forward and one backward where
+        the kernels run),
+        ``gdn_gates`` (beta and the log-decay g), ``gdn_scan`` (the chunked
+        rule), ``gdn_out_norm``. g, beta, the norms and the rule's state are
+        float32; the projections and the rule's matmul operands are the
+        compute dtype."""
         import jax
         import jax.numpy as jnp
+        from jax.sharding import PartitionSpec
 
-        from ..ops.gated_delta import (causal_conv1d, gated_delta_chunked,
-                                       l2norm)
+        from ..ops.gated_delta import gated_delta_chunked, gdn_prologue
+        from ..parallel.mesh import kernel_activation_spec, shard_kernel
 
         cfg = self.config
         B, T = y.shape[:2]
@@ -1218,34 +1222,32 @@ class Transformer:
         rep = Hv // Hk
         f32 = jnp.float32
         with trace.scope("attn_qkv"):
-            qkvz = (y @ lw["w_qkvz"]).reshape(B, T, Hk, 2 * dk + 2 * rep * dv)
+            # a key head's channels side by side: q, k, its rep v's, their z's
+            qkvz = y @ lw["w_qkvz"]
             ba = (y @ lw["w_ba"]).reshape(B, T, Hk, 2 * rep)
-            q, k, v, z = jnp.split(qkvz, [dk, 2 * dk, 2 * dk + rep * dv], axis=-1)
             b, a = ba[..., :rep].reshape(B, T, Hv), ba[..., rep:].reshape(B, T, Hv)
             with trace.scope("gdn_conv"):
-                # the source's channel order: all q, all k, all v
-                mixed = jnp.concatenate(
-                    [q.reshape(B, T, Hk * dk), k.reshape(B, T, Hk * dk),
-                     v.reshape(B, T, Hv * dv)], axis=-1)
-                mixed = jax.nn.silu(causal_conv1d(mixed, lw["conv_w"]))
-                q, k, v = jnp.split(mixed, [Hk * dk, 2 * Hk * dk], axis=-1)
+                # convolution, SiLU, the l2 norms and the repeat to Hv heads:
+                # one pass over q, k, v where they lie (``gdn_prologue``),
+                # per device on its own rows like the rule below (all heads:
+                # ``conv_w`` keeps the checkpoint's channel order, which no
+                # split over heads cuts whole)
+                rows = kernel_activation_spec(qkvz.shape)
+                heads = kernel_activation_spec((B, T, Hv, dk))
+                q, k, v, z = shard_kernel(
+                    functools.partial(gdn_prologue, key_heads=Hk, dk=dk, dv=dv),
+                    (rows, PartitionSpec()), (heads,) * 4,
+                )(qkvz, lw["conv_w"])
             with trace.scope("gdn_gates"):
                 beta = jax.nn.sigmoid(b.astype(f32))
                 g = -jnp.exp(lw["A_log"].astype(f32)) * jax.nn.softplus(
                     a.astype(f32) + lw["dt_bias"].astype(f32))
-                # each key head serves ``rep`` value heads
-                heads = lambda x: jnp.repeat(x.reshape(B, T, Hk, dk), rep, axis=2)
-                q = (l2norm(heads(q)) * dk ** -0.5).astype(y.dtype)
-                k = l2norm(heads(k)).astype(y.dtype)
-                v = v.reshape(B, T, Hv, dv)
         with trace.scope("attn_core"):
             with trace.scope("gdn_scan"):
                 # each device runs the rule on its own rows and heads, like a
                 # kernel (they are independent): left to XLA's partitioner
                 # under ZeRO-3 weights and full remat, the 8-device CPU mesh
                 # computed a wrong forward (loss off by 5e-3, PR 33)
-                from ..parallel.mesh import kernel_activation_spec, shard_kernel
-
                 wide = kernel_activation_spec(q.shape, heads_dim=2)
                 flat = kernel_activation_spec(g.shape, heads_dim=2)
                 o = shard_kernel(
@@ -1257,7 +1259,7 @@ class Transformer:
                 o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
                                       + cfg.norm_eps)
                 o = (lw["gdn_norm_w"].astype(f32) * o * jax.nn.silu(
-                    z.reshape(B, T, Hv, dv).astype(f32))).astype(y.dtype)
+                    z.astype(f32))).astype(y.dtype)
             return o.reshape(B, T, Hv * dv) @ lw["w_out"]
 
     def _ffn(self, lw, h, y2, attn_out, moe_on, ffn=None):

@@ -1,6 +1,8 @@
 """The gated delta rule (Gated DeltaNet, arXiv:2412.06464; the linear-attention
 mixer of Qwen3-Next) and what surrounds it in a layer: the causal depthwise
-convolution and the l2 norm of q and k.
+convolution and the l2 norm of q and k, and ``gdn_prologue``, which takes a
+layer from its projection to the rule's q, k, v in one pass (the end of this
+file).
 
 Per head, with a state ``S`` [dk, dv] that starts at 0, a log-decay ``g_t`` <= 0
 and a write strength ``beta_t`` in [0, 1]::
@@ -671,3 +673,362 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, s0_ref, do_ref,
         dlast = total(dtail) + dcarry[h] * x.carry
         dgamma_ref[0, 0, 0, h:h + 1, :] = (
             row(dgcol) - total(ddiff[h]) + jnp.where(at_last, dlast, 0.0))
+
+
+# ----------------------------------------------------------------------
+# The mixer's prologue: from the projection to what the rule takes
+# ----------------------------------------------------------------------
+
+# Rows (tokens) a grid step of the prologue's kernels, and rows a trip of
+# the loop inside one (8 float32 vregs a lane tile: the trip's values stay
+# in registers). ``_HALO``: the rows before a block that a step also reads,
+# one bf16 tile (the convolution looks back K - 1 <= 7 of them).
+ROWS = 512
+_SUB = 64
+_HALO = 16
+
+
+def _prologue_shapes(qkvz, conv_w, key_heads, dk, dv):
+    """(W, rep): the channels of one key head's group in ``qkvz``
+    [B, T, key_heads * W] (q dk, k dk, v rep * dv, z rep * dv) and the value
+    heads a key head serves."""
+    W = qkvz.shape[-1] // key_heads
+    rep = (W - 2 * dk) // (2 * dv)
+    assert W * key_heads == qkvz.shape[-1] and 2 * dk + 2 * rep * dv == W, (
+        qkvz.shape, key_heads, dk, dv)
+    assert conv_w.shape[1] == key_heads * (W - rep * dv), (conv_w.shape, qkvz.shape)
+    return W, rep
+
+
+def prologue_route(qkvz, conv_w, dk: int, dv: int) -> str:
+    """Which form :func:`gdn_prologue` runs, from what it can observe, as
+    :func:`kernel_route` does for the rule: "pallas" on a TPU backend at an
+    eligible shape (dk and dv whole lane tiles, a convolution no wider than
+    one 8-row sublane tile, bf16 or float32 activations), "interpret" at
+    such a shape under ``SXT_FUSED_INTERPRET=1``, else "xla"."""
+    import jax.numpy as jnp
+
+    from .dispatch import interpret_forced, pallas_enabled
+
+    eligible = (dk % 128 == 0 and dv % 128 == 0 and conv_w.shape[0] <= 8
+                and qkvz.dtype in (jnp.bfloat16, jnp.float32))
+    if not eligible:
+        return "xla"
+    if interpret_forced():
+        return "interpret"
+    return "pallas" if pallas_enabled() else "xla"
+
+
+def gdn_prologue(qkvz, conv_w, key_heads: int, dk: int, dv: int,
+                 eps: float = 1e-6, rows: int = ROWS):
+    """Everything of a DeltaNet layer between its projection and the rule:
+    ``qkvz`` [B, T, Hk * W] as the projection wrote it (a key head's group
+    of W channels is its q [dk], k [dk], v [rep * dv] and z [rep * dv]) and
+    ``conv_w`` [K, Hk dk + Hk dk + Hv dv] in the checkpoint's channel order
+    (all q, all k, all v) -> q, k [B, T, Hv, dk] and v, z [B, T, Hv, dv] in
+    ``qkvz``'s dtype: the causal depthwise convolution, SiLU, the l2 norm of
+    q (times ``dk ** -0.5``) and of k, and each key head repeated to its
+    ``rep`` value heads; z (the output gate's input) is handed on as it is.
+
+    Two bodies, chosen by :func:`prologue_route`. The kernels
+    (``gdn_prologue_fwd`` / ``gdn_prologue_bwd`` behind one
+    ``jax.custom_vjp``) read ``qkvz`` once forward, and once more with the
+    three cotangents backward; the convolution's accumulator, SiLU, the sum
+    of squares and the rsqrt are float32 and the result is rounded to the
+    compute dtype ONCE, at the write. They write q, k, v as [B, Hv, T, d],
+    which is what the rule's kernels read: the transpose back to
+    [B, T, Hv, d] here and the rule's own to [B, Hv, T, d] cancel in XLA.
+    z goes through the kernels too, and its cotangent into d``qkvz``'s z
+    channels: sliced out of ``qkvz`` by XLA, the layout the output norm
+    wants for z cost a copy of all of ``qkvz`` a pass (PR 40).
+    The XLA body is ``silu(causal_conv1d)`` -> split -> ``l2norm`` -> repeat
+    (the convolution's result rounded to the compute dtype before SiLU, the
+    norm's after it): the off-TPU path and the kernels' oracle."""
+    route = prologue_route(qkvz, conv_w, dk, dv)
+    if route == "xla":
+        return _gdn_prologue_xla(qkvz, conv_w, key_heads, dk, dv, eps)
+    return _gdn_prologue_pallas(qkvz, conv_w, key_heads, dk, dv, eps, rows,
+                                interpret=route == "interpret")
+
+
+def _gdn_prologue_xla(qkvz, conv_w, Hk, dk, dv, eps=1e-6):
+    """``gdn_prologue`` as XLA ops: the channels gathered into the
+    checkpoint's order, ``silu(causal_conv1d)``, the split, ``l2norm`` of the
+    repeated heads."""
+    import jax
+    import jax.numpy as jnp
+
+    B, T, _ = qkvz.shape
+    W, rep = _prologue_shapes(qkvz, conv_w, Hk, dk, dv)
+    x = qkvz.reshape(B, T, Hk, W)
+    mixed = jnp.concatenate(
+        [x[..., :dk].reshape(B, T, Hk * dk),
+         x[..., dk:2 * dk].reshape(B, T, Hk * dk),
+         x[..., 2 * dk:2 * dk + rep * dv].reshape(B, T, Hk * rep * dv)], axis=-1)
+    mixed = jax.nn.silu(causal_conv1d(mixed, conv_w))
+    q, k, v = jnp.split(mixed, [Hk * dk, 2 * Hk * dk], axis=-1)
+    # each key head serves ``rep`` value heads
+    heads = lambda a: jnp.repeat(a.reshape(B, T, Hk, dk), rep, axis=2)
+    q = (l2norm(heads(q), eps) * dk ** -0.5).astype(qkvz.dtype)
+    k = l2norm(heads(k), eps).astype(qkvz.dtype)
+    wide = lambda a: a.reshape(B, T, Hk * rep, dv)
+    return q, k, wide(v), wide(x[..., 2 * dk + rep * dv:])
+
+
+def _gdn_prologue_pallas(qkvz, conv_w, Hk, dk, dv, eps=1e-6, rows=ROWS,
+                         interpret: bool = False):
+    """``gdn_prologue`` through the kernels. ``conv_w`` goes in as
+    [Hk, 8, 2 dk + rep dv] float32: permuted to the order the projection
+    left the channels in, one key head's [q | k | v] a row, K padded to a
+    sublane tile. T is padded to whole blocks of rows with zeros (nothing
+    where ``rows`` divides it); the padding, the permutation and the
+    transposes back to [B, T, Hv, d] are XLA's, and so are their gradients."""
+    import jax.numpy as jnp
+
+    B, T, _ = qkvz.shape
+    K = conv_w.shape[0]
+    _, rep = _prologue_shapes(qkvz, conv_w, Hk, dk, dv)
+    assert rows % _SUB == 0, rows
+    wq, wk, wv = jnp.split(conv_w.astype(jnp.float32), [Hk * dk, 2 * Hk * dk], axis=1)
+    by_head = lambda w: w.reshape(K, Hk, -1)
+    w = jnp.concatenate([by_head(wq), by_head(wk), by_head(wv)], axis=-1)
+    w = jnp.pad(jnp.swapaxes(w, 0, 1), ((0, 0), (0, 8 - K), (0, 0)))
+    R = min(rows, -(-T // _SUB) * _SUB)
+    x = jnp.pad(qkvz, ((0, 0), (0, -T % R), (0, 0)))
+    core = _prologue_core(K, dk, dv, rep, float(eps), R, interpret)
+    return tuple(jnp.swapaxes(a[:, :, :T], 1, 2) for a in core(x, w))
+
+
+@functools.lru_cache(maxsize=None)
+def _prologue_core(K, dk, dv, rep, eps, R, interpret):
+    """The prologue on whole blocks of R rows as one ``jax.custom_vjp``:
+    (x [B, T, Hk * W], w [Hk, 8, 2 dk + rep dv] float32) -> q, k, v, z
+    [B, Hv, T, d]. The input is the only residual; each launch under its own
+    jit, built once (see ``_delta_core``)."""
+    import jax
+
+    static = dict(K=K, dk=dk, dv=dv, rep=rep, eps=eps, R=R, interpret=interpret)
+    forward = jax.jit(functools.partial(_prologue_forward, **static))
+    backward = jax.jit(functools.partial(_prologue_backward, **static))
+
+    @jax.custom_vjp
+    def core(x, w):
+        return tuple(forward(x, w))
+
+    def fwd(x, w):
+        return tuple(forward(x, w)), (x, w)
+
+    def bwd(kept, cotangents):
+        return tuple(backward(*kept, *cotangents))
+
+    core.defvjp(fwd, bwd)
+    return core
+
+
+def _prologue_blocks(R, W, Cw, rep, block_at):
+    """The block specs of a grid step (row b, key head h, step n) that works
+    on rows ``block_at(n) * R`` onward: ``rows`` of x [B, T, Hk * W] (all W
+    channels of the head: whole lane tiles wherever the head's group
+    starts), ``halo`` the ``_HALO`` rows before them (the first block reads
+    its own and masks them), ``weights``, and ``wide(d)`` for q, k, v, z
+    [B, Hv, T, d]."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    rows = pl.BlockSpec((1, R, W), lambda b, h, n: (b, block_at(n), h))
+    halo = pl.BlockSpec((1, _HALO, W), lambda b, h, n: (
+        b, jnp.maximum(block_at(n) * (R // _HALO) - 1, 0), h))
+    weights = pl.BlockSpec((1, 8, Cw), lambda b, h, n: (h, 0, 0))
+    wide = lambda d: pl.BlockSpec((1, rep, R, d), lambda b, h, n: (b, h, block_at(n), 0))
+    return rows, halo, weights, wide
+
+
+def _prologue_forward(x, w, K, dk, dv, rep, eps, R, interpret):
+    """The forward kernel's launch -> [q, k, v, z]."""
+    import jax
+    from jax.experimental import pallas as pl
+
+    B, Tp, _ = x.shape
+    Hk, _, Cw = w.shape
+    W = x.shape[-1] // Hk
+    rows, halo, weights, wide = _prologue_blocks(R, W, Cw, rep, lambda n: n)
+    out = lambda d: jax.ShapeDtypeStruct((B, Hk * rep, Tp, d), x.dtype)
+    return pl.pallas_call(
+        functools.partial(_prologue_fwd_kernel, K=K, dk=dk, dv=dv, rep=rep, eps=eps),
+        grid=(B, Hk, Tp // R),
+        in_specs=[rows, halo, weights],
+        out_specs=[wide(dk), wide(dk), wide(dv), wide(dv)],
+        out_shape=[out(dk), out(dk), out(dv), out(dv)],
+        compiler_params=_compiler_params(), interpret=interpret,
+        name="gdn_prologue_fwd",
+    )(x, x, w)
+
+
+def _prologue_backward(x, w, dq, dk_, dv_, dz, K, dk, dv, rep, eps, R, interpret):
+    """The backward kernel's launch -> [dx, dw]. The sweep runs over the row
+    blocks from the last to the first; dw comes out as [B, Hk, 8 K, Cw]
+    partial sums (one a batch row and sublane) and is summed here."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    B, Tp, _ = x.shape
+    Hk, _, Cw = w.shape
+    W = x.shape[-1] // Hk
+    N = Tp // R
+    rows, halo, weights, wide = _prologue_blocks(R, W, Cw, rep, lambda n: N - 1 - n)
+    dx, dw = pl.pallas_call(
+        functools.partial(_prologue_bwd_kernel, K=K, dk=dk, dv=dv, rep=rep, eps=eps),
+        grid=(B, Hk, N),
+        in_specs=[rows, halo, weights, wide(dk), wide(dk), wide(dv), wide(dv)],
+        out_specs=[rows, pl.BlockSpec((1, 1, 8 * K, Cw), lambda b, h, n: (b, h, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct((B, Hk, 8 * K, Cw), f32)],
+        scratch_shapes=[pltpu.VMEM((8, Cw), f32)],
+        compiler_params=_compiler_params(), interpret=interpret,
+        name="gdn_prologue_bwd",
+    )(x, x, w, dq, dk_, dv_, dz)
+    dw = jnp.sum(dw.reshape(B, Hk, K, 8, Cw), axis=(0, 3))
+    return dx, jnp.pad(dw, ((0, 0), (0, 8 - K), (0, 0)))
+
+
+def _segments(dk, dv, rep):
+    """(start, width) of each head of a key head's [q | k | v] channels: q,
+    k, then the ``rep`` value heads."""
+    return [(0, dk), (dk, dk)] + [(2 * dk + r * dv, dv) for r in range(rep)]
+
+
+def _rows_before(halo_ref, Cw, at_start):
+    """float32 [8, Cw]: the 8 rows before a block's first, zeros where the
+    block is the sequence's first (``at_start``)."""
+    import jax.numpy as jnp
+
+    return jnp.where(at_start, 0.0, halo_ref[0, _HALO - 8:, :Cw].astype(jnp.float32))
+
+
+def _chunk_rows(x_ref, before, c, lanes):
+    """float32 [8 + _SUB, n]: the 8 rows before trip ``c``'s rows of a
+    block (``before``'s ahead of the block's first), then its rows, of the
+    lanes ``lanes``; and the slice of the trip's rows."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    c0 = pl.multiple_of(c * _SUB, _SUB)
+    back = pl.multiple_of(jnp.maximum(c0 - _HALO, 0), _HALO)
+    prev = x_ref[0, pl.ds(back, _HALO), lanes].astype(f32)[_HALO - 8:]
+    at = pl.ds(c0, _SUB)
+    return jnp.concatenate([jnp.where(c == 0, before[:, lanes], prev),
+                            x_ref[0, at, lanes].astype(f32)], axis=0), at
+
+
+def _taps(ext, K):
+    """[ext's rows 8 - s onward, _SUB of them, for s = K - 1 .. 0]: what
+    tap j of the convolution multiplies (s = K - 1 - j rows back)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return [ext[8:] if s == 0 else pltpu.roll(ext, s, 0)[8:]
+            for s in range(K - 1, -1, -1)]
+
+
+def _prologue_fwd_kernel(x_ref, halo_ref, w_ref, q_ref, k_ref, v_ref, z_ref, *,
+                         K, dk, dv, rep, eps):
+    """R rows of one key head of one batch row: per head of the group the
+    convolution over the K rows that end at a row, SiLU and (q, k) the l2
+    norm, float32 until the write; q and k are written to the key head's
+    ``rep`` value heads, z's channels are copied."""
+    import jax
+    import jax.numpy as jnp
+
+    from jax.experimental import pallas as pl
+
+    segs = _segments(dk, dv, rep)
+    w = [[w_ref[0, j:j + 1, a:a + n] for j in range(K)] for a, n in segs]
+    before = _rows_before(halo_ref, w_ref.shape[2], pl.program_id(2) == 0)
+
+    def trip(c, carry):
+        for i, (a, n) in enumerate(segs):
+            ext, at = _chunk_rows(x_ref, before, c, slice(a, a + n))
+            pre = sum(wj * xj for wj, xj in zip(w[i], _taps(ext, K)))
+            act = pre * jax.nn.sigmoid(pre)
+            if i < 2:
+                unit = jax.lax.rsqrt(jnp.sum(act * act, axis=-1, keepdims=True) + eps)
+                out = (act * (unit * dk ** -0.5 if i == 0 else unit)).astype(q_ref.dtype)
+                for r in range(rep):
+                    (q_ref if i == 0 else k_ref)[0, r, at, :] = out
+            else:
+                v_ref[0, i - 2, at, :] = act.astype(v_ref.dtype)
+                z_ref[0, i - 2, at, :] = x_ref[0, at, pl.ds(a + rep * dv, dv)]
+        return carry
+
+    jax.lax.fori_loop(0, x_ref.shape[1] // _SUB, trip, 0)
+
+
+def _prologue_bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dz_ref,
+                         dx_ref, dw_ref, ahead, *, K, dk, dv, rep, eps):
+    """The same block's gradients; the grid's last axis walks the row blocks
+    from the last to the first, and so do the trips inside a block. The
+    pre-activation is computed again in float32; the norm's, SiLU's and the
+    repeat's transposes (a sum over the ``rep`` heads) stay in registers.
+    The convolution's transpose needs the pre-activation's cotangent of the
+    K - 1 rows AFTER a row: ``ahead`` [8, Cw] carries the first rows' of
+    the block after this one (zeros at the end), and each trip hands its
+    own first 8 rows' to the trip before it. dw sums over all rows: 8
+    partial sums (one a sublane) a tap, accumulated in the output block
+    over the walk. z's cotangent is copied into its channels of dx."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    segs = _segments(dk, dv, rep)
+    trips = x_ref.shape[1] // _SUB
+    w = [[w_ref[0, j:j + 1, a:a + n] for j in range(K)] for a, n in segs]
+    before = _rows_before(halo_ref, w_ref.shape[2],
+                          pl.program_id(2) == pl.num_programs(2) - 1)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ahead[...] = jnp.zeros_like(ahead)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    def trip(t, after):
+        c = trips - 1 - t
+        first = []
+        for i, (a, n) in enumerate(segs):
+            lanes = slice(a, a + n)
+            ext, at = _chunk_rows(x_ref, before, c, lanes)
+            taps = _taps(ext, K)
+            pre = sum(wj * xj for wj, xj in zip(w[i], taps))
+            sig = jax.nn.sigmoid(pre)
+            if i < 2:
+                ref = dq_ref if i == 0 else dk_ref
+                g = sum(ref[0, r, at, :].astype(f32) for r in range(rep))
+                act = pre * sig
+                unit = jax.lax.rsqrt(jnp.sum(act * act, axis=-1, keepdims=True) + eps)
+                y = act * unit
+                dact = (g - y * jnp.sum(g * y, axis=-1, keepdims=True)) * (
+                    unit * dk ** -0.5 if i == 0 else unit)
+            else:
+                dact = dv_ref[0, i - 2, at, :].astype(f32)
+                dx_ref[0, at, pl.ds(a + rep * dv, dv)] = dz_ref[0, i - 2, at, :]
+            dpre = dact * (sig * (1.0 + pre * (1.0 - sig)))
+            for j, xj in enumerate(taps):
+                p = dpre * xj
+                dw_ref[0, 0, 8 * j:8 * j + 8, lanes] += sum(
+                    p[s:s + 8] for s in range(0, _SUB, 8))
+            # dx[t] = sum_j w[j] dpre[t + K - 1 - j]
+            ext = jnp.concatenate([dpre, after[i]], axis=0)
+            dx = sum(wj * (dpre if u == 0 else pltpu.roll(ext, _SUB + 8 - u, 0)[:_SUB])
+                     for wj, u in zip(w[i], range(K - 1, -1, -1)))
+            dx_ref[0, at, lanes] = dx.astype(dx_ref.dtype)
+            first.append(dpre[:8])
+        return tuple(first)
+
+    after = jax.lax.fori_loop(
+        0, trips, trip, tuple(ahead[:, a:a + n] for a, n in segs))
+    for (a, n), rows in zip(segs, after):
+        ahead[:, a:a + n] = rows

@@ -388,10 +388,42 @@ def _gated_delta(rng) -> Iterator[dict]:
             yield _check(f"gated-delta-{jnp.dtype(dtype).name}-{part}", a, b, tol)
 
 
+def _gdn_prologue(rng) -> Iterator[dict]:
+    """The DeltaNet mixer's prologue kernels (``ops/gated_delta.py``:
+    ``gdn_prologue_fwd`` / ``gdn_prologue_bwd``) against the composition they
+    replace (``silu(causal_conv1d)`` -> split -> ``l2norm`` -> repeat): q, k,
+    v, z and the gradients of ``qkvz`` and ``conv_w`` under seeded cotangents.
+    700 rows: a block of 512 and a padded second one. float32 agrees to
+    rounding; in bf16 the composition rounds the convolution's result before
+    SiLU, the kernels once at the write."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.gated_delta import _gdn_prologue_pallas, _gdn_prologue_xla
+
+    B, T, Hk, rep, d, K = 2, 700, 4, 2, 128, 4
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    qkvz = normal(B, T, Hk * (2 * d + 2 * rep * d))
+    conv_w = 0.5 * normal(K, Hk * (2 * d + rep * d))
+    cotangents = tuple(normal(B, T, Hk * rep, d) for _ in range(4))
+
+    def answers(fn, qkvz, conv_w, cotangents):
+        out, back = jax.vjp(lambda x, w: fn(x, w, Hk, d, d), qkvz, conv_w)
+        return out + back(cotangents)
+
+    for dtype, tols in [(jnp.float32, (1e-5, 1e-5, 1e-5, 0.0, 1e-4, 5e-3)),
+                        (jnp.bfloat16, (2e-3, 2e-2, 1e-1, 0.0, 3e-1, 4.0))]:
+        args = (qkvz.astype(dtype), conv_w, tuple(c.astype(dtype) for c in cotangents))
+        got = jax.jit(lambda *a: answers(_gdn_prologue_pallas, *a))(*args)
+        want = jax.jit(lambda *a: answers(_gdn_prologue_xla, *a))(*args)
+        for part, a, b, tol in zip(("q", "k", "v", "z", "dqkvz", "dconv_w"), got, want, tols):
+            yield _check(f"gdn-prologue-{jnp.dtype(dtype).name}-{part}", a, b, tol)
+
+
 def run(seed: int = 0) -> Iterator[dict]:
     """All parity checks, one record each. One seeded generator feeds them
     in this order, so a record's inputs do not depend on which passed."""
     rng = np.random.default_rng(seed)
     for group in (_attention, _rmsnorm, _paged, _matmuls,
-                  _alibi_flash, _fused_decode, _gated_delta):
+                  _alibi_flash, _fused_decode, _gated_delta, _gdn_prologue):
         yield from group(rng)

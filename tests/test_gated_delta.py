@@ -290,3 +290,109 @@ def test_l2norm():
     np.testing.assert_allclose(
         gd.l2norm(x), x / np.sqrt((np.asarray(x) ** 2).sum(-1, keepdims=True) + 1e-6),
         rtol=1e-6)
+
+
+# ----------------------------------------------------------------------
+# The mixer's prologue (``gdn_prologue``): kernels against the composition
+# ----------------------------------------------------------------------
+
+PROLOGUE_PARTS = ("q", "k", "v", "z", "dqkvz", "dconv_w")
+
+
+def prologue_inputs(K, rep, dtype, T=150, B=2, Hk=2, d=128):
+    """(qkvz, conv_w) as a mixer has them and cotangents of q, k, v, z: 150
+    tokens in blocks of 64 rows is a first block (zeros before position 0),
+    one with a block on both sides, and a ragged last one."""
+    W = 2 * d + 2 * rep * d
+    ks = jax.random.split(jax.random.PRNGKey(10 * K + rep), 6)
+    qkvz = jax.random.normal(ks[0], (B, T, Hk * W)).astype(dtype)
+    conv_w = 0.5 * jax.random.normal(ks[1], (K, 2 * Hk * d + Hk * rep * d))
+    cotangents = tuple(jax.random.normal(k, (B, T, Hk * rep, d)).astype(dtype)
+                       for k in ks[2:])
+    return (qkvz, conv_w), cotangents
+
+
+def prologue_answers(args, cotangents, exact=False):
+    """(q, k, v, z, dqkvz, dconv_w) of ``gdn_prologue`` (route chosen while
+    tracing) in float32; ``exact``: the composition on the same numbers held
+    in float32 throughout."""
+    def both(args, cotangents):
+        qkvz, conv_w = args
+        if exact:
+            qkvz = qkvz.astype(jnp.float32)
+            cotangents = tuple(c.astype(jnp.float32) for c in cotangents)
+        out, back = jax.vjp(lambda x, w: gd.gdn_prologue(x, w, 2, 128, 128, rows=64),
+                            qkvz, conv_w)
+        return tuple(a.astype(jnp.float32) for a in out + back(cotangents))
+    return jax.jit(both)(args, cotangents)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+@pytest.mark.parametrize("rep", [1, 2])
+@pytest.mark.parametrize("K", [2, 4])
+def test_prologue_kernels_equal_the_composition(monkeypatch, K, rep, dtype):
+    """``silu(causal_conv1d)`` -> split -> ``l2norm`` -> repeat against the
+    one pass, outputs and the gradients of ``qkvz`` and ``conv_w``. float32:
+    the same numbers to rounding. bf16: the pass rounds once, at the write,
+    where the composition rounds the convolution's result and again after
+    the norm: no part is further from the float32 composition than the
+    composition in bf16 is."""
+    args, cotangents = prologue_inputs(K, rep, dtype)
+    want = prologue_answers(args, cotangents)
+    monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
+    got = prologue_answers(args, cotangents)
+    monkeypatch.delenv("SXT_FUSED_INTERPRET")
+    gap = lambda a, b: float(jnp.linalg.norm((a - b).ravel()) / jnp.linalg.norm(b.ravel()))
+    for a, b, part in zip(got, want, PROLOGUE_PARTS):
+        assert a.shape == b.shape and bool(jnp.all(jnp.isfinite(a))), part
+    if dtype == jnp.float32:
+        for a, b, part in zip(got, want, PROLOGUE_PARTS):
+            assert gap(a, b) < 2e-6, (part, gap(a, b))
+    else:
+        exact = prologue_answers(args, cotangents, exact=True)
+        for a, b, c, part in zip(got, want, exact, PROLOGUE_PARTS):
+            assert gap(a, c) <= 1.02 * gap(b, c) and gap(a, c) < 4e-3, (
+                part, gap(a, c), gap(b, c))
+    # z's channels of a key head's group go through as they are, both ways
+    W = 256 + 2 * rep * 128
+    z_of = lambda x: x.reshape(2, 150, 2, W)[..., 256 + rep * 128:].reshape(got[3].shape)
+    np.testing.assert_array_equal(got[3], z_of(args[0].astype(jnp.float32)))
+    np.testing.assert_array_equal(z_of(got[4]), cotangents[3].astype(jnp.float32))
+
+
+def test_prologue_reaches_nothing_later_and_names_its_kernels(interpreted):
+    """A bump at position 70 (the second block's 7th row) moves no output
+    before it; differentiated, the two launches carry their own names."""
+    (qkvz, conv_w), cotangents = prologue_inputs(4, 2, jnp.bfloat16)
+    run = lambda x: gd.gdn_prologue(x, conv_w, 2, 128, 128, rows=64)
+    plain, bumped = run(qkvz), run(qkvz.at[:, 70].add(1.0))
+    for a, b in zip(plain, bumped):
+        np.testing.assert_array_equal(a[:, :70], b[:, :70])
+        assert bool(jnp.any(a[:, 70:74] != b[:, 70:74]))
+    assert calls(run, qkvz) == [("gdn_prologue_fwd", 4)]
+    both = lambda x, w: jax.vjp(lambda x, w: gd.gdn_prologue(
+        x, w, 2, 128, 128, rows=64), x, w)[1](cotangents)
+    assert calls(both, qkvz, conv_w) == [("gdn_prologue_fwd", 4), ("gdn_prologue_bwd", 2)]
+
+
+@pytest.mark.parametrize("why, dk, dv, K, dtype, forced, want", [
+    ("eligible", 128, 128, 4, jnp.bfloat16, True, "interpret"),
+    ("float32", 128, 128, 4, jnp.float32, True, "interpret"),
+    ("wider_heads", 256, 128, 2, jnp.bfloat16, True, "interpret"),
+    ("a_sublane_tile_of_taps", 128, 128, 8, jnp.bfloat16, True, "interpret"),
+    ("off_a_tpu", 128, 128, 4, jnp.bfloat16, False, "xla"),
+    ("narrow_keys", 16, 128, 4, jnp.bfloat16, True, "xla"),
+    ("narrow_values", 128, 64, 4, jnp.bfloat16, True, "xla"),
+    ("more_taps_than_a_sublane_tile", 128, 128, 9, jnp.bfloat16, True, "xla"),
+    ("float16", 128, 128, 4, jnp.float16, True, "xla"),
+])
+def test_the_prologue_is_chosen_by_backend_and_shape(monkeypatch, why, dk, dv, K,
+                                                     dtype, forced, want):
+    if forced:
+        monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
+    qkvz = jnp.zeros((1, 64, 2 * (2 * dk + 2 * dv)), dtype)
+    conv_w = jnp.zeros((K, 2 * (2 * dk + dv)), jnp.float32)
+    assert gd.prologue_route(qkvz, conv_w, dk, dv) == want
+    if want == "xla":
+        # an ineligible shape runs the composition: no kernel in the program
+        assert calls(lambda x, w: gd.gdn_prologue(x, w, 2, dk, dv), qkvz, conv_w) == []

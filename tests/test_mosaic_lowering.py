@@ -328,6 +328,30 @@ def test_gated_delta_rule_lowers(dtype):
                jnp.zeros((B, T, H, d), jnp.float32))
 
 
+def _gdn_prologue_vjp(qkvz, conv_w, dq, dk, dv, dz, heads=(16, 128, 128)):
+    """q, k, v, z and the two gradients through the prologue's kernels
+    themselves (the dispatching entry takes the XLA form off a TPU)."""
+    from shuffle_exchange_tpu.ops.gated_delta import _gdn_prologue_pallas
+
+    out, back = jax.vjp(lambda x, w: _gdn_prologue_pallas(x, w, *heads), qkvz, conv_w)
+    return out + back((dq, dk, dv, dz))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_gdn_prologue_lowers(dtype):
+    """The forward and the backward launch; a ragged last block of rows;
+    bf16 and float32 activations; one value head a key head and two."""
+    import functools
+
+    for rep, T in ((2, 600), (1, 100)):
+        Hk, d, K = 2, 128, 4
+        qkvz = jnp.zeros((2, T, Hk * (2 * d + 2 * rep * d)), dtype)
+        conv_w = jnp.zeros((K, Hk * (2 * d + rep * d)), jnp.float32)
+        wide = jnp.zeros((2, T, Hk * rep, d), dtype)
+        _tpu_lower(functools.partial(_gdn_prologue_vjp, heads=(Hk, d, d)),
+                   qkvz, conv_w, wide, wide, wide, wide)
+
+
 @pytest.mark.parametrize("store", [jnp.int8, jnp.float8_e4m3fn])
 def test_paged_kernels_quantized_kv_lower(store):
     """kv_cache_dtype int8/fp8 (ISSUE 6): every streaming kernel that
@@ -612,6 +636,18 @@ def test_gated_delta_rule_compiles(chip_compile):
                             ((2, 8192, 32, 128), _F32))
     text = compiled.as_text()
     assert "gdn_rule_fwd_keep" in text and "gdn_rule_bwd" in text
+
+
+def test_gdn_prologue_compiles(chip_compile):
+    """The prologue's two kernels at the shape ``qwen3next-train`` runs
+    them: two rows of 8,192 tokens, 16 key heads and 32 value heads of 128,
+    a convolution over 4 tokens, bf16 with a float32 ``conv_w``."""
+    qkvz = ((2, 8192, 16 * 768), _BF16)
+    wide = ((2, 8192, 32, 128), _BF16)
+    compiled = chip_compile(_gdn_prologue_vjp, qkvz, ((4, 8192), _F32),
+                            wide, wide, wide, wide)
+    text = compiled.as_text()
+    assert "gdn_prologue_fwd" in text and "gdn_prologue_bwd" in text
 
 
 def test_tracer_reads_a_chip_compiled_programs_peak_and_passes(

@@ -160,10 +160,11 @@ def test_the_trainer_through_initialize(case):
 
 def test_the_trainer_runs_the_rules_kernels_where_the_heads_are_lane_wide(monkeypatch):
     """DeltaNet heads of 128 (the published width): under
-    ``SXT_FUSED_INTERPRET=1`` the train step's rule is the Pallas kernels
-    (interpreted), inside the period scan, the half-block's remat, ZeRO-3 and
-    the 8-device mesh's ``shard_kernel``; its first loss and first gradient
-    are the XLA form's. 80 tokens: a ragged second chunk."""
+    ``SXT_FUSED_INTERPRET=1`` the train step's prologue and rule are the
+    Pallas kernels (interpreted), inside the period scan, the half-block's
+    remat, ZeRO-3 and the 8-device mesh's ``shard_kernel``; its first loss
+    and first gradient are the XLA form's. 80 tokens: a ragged second chunk
+    of the rule, a padded block of the prologue's rows."""
     hf = dict(HF, num_hidden_layers=4, linear_num_key_heads=1,
               linear_num_value_heads=2, linear_key_head_dim=128,
               linear_value_head_dim=128)
@@ -185,11 +186,41 @@ def test_the_trainer_runs_the_rules_kernels_where_the_heads_are_lane_wide(monkey
     monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
     loss, moment, text = first_step()
     assert "gdn_rule_bwd" in text and "gdn_rule_bwd" not in xla_text
+    assert "gdn_prologue_bwd" in text and "gdn_prologue_bwd" not in xla_text
     assert abs(loss - xla_loss) < 1e-5
     # (a leaf no token reached has a zero gradient in both)
     worst = {k: v for k, v in gaps(moment, xla_moment).items() if v == v}
     assert len(worst) > 20 and max(worst.values()) < 1e-3, worst
     assert all(not np.any(np.asarray(moment[k])) for k in set(moment) - set(worst))
+
+
+def test_the_prologues_kernels_give_the_xla_routes_loss_and_gradients(monkeypatch):
+    """The same stack on one device, no remat: ``Transformer.loss`` and every
+    gradient leaf with the mixer's prologue and rule as kernels (interpreted)
+    against the XLA route. 80 tokens: a ragged second chunk of the rule, a
+    padded block of the prologue's rows."""
+    hf = dict(HF, num_hidden_layers=4, linear_num_key_heads=1,
+              linear_num_value_heads=2, linear_key_head_dim=128,
+              linear_value_head_dim=128)
+    ids = np.random.default_rng(12).integers(0, 256, (2, 81)).astype(np.int32)
+    model = Transformer(config_from_hf(hf))
+    params = driver.initial_params(model, 8)
+
+    def first_step():
+        # traced anew each time: the route is chosen while tracing
+        step = jax.value_and_grad(lambda p: model.loss(p, {"input_ids": ids}))
+        loss, grads = jax.jit(step)(params)
+        return float(loss), driver.flat_tree(grads), str(jax.make_jaxpr(step)(params))
+
+    xla_loss, xla_grads, xla_text = first_step()
+    monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
+    loss, grads, text = first_step()
+    for kernel in ("gdn_prologue_fwd", "gdn_prologue_bwd", "gdn_rule_fwd_keep",
+                   "gdn_rule_bwd"):
+        assert kernel in text and kernel not in xla_text, kernel
+    assert abs(loss - xla_loss) < 1e-5
+    worst = {k: v for k, v in gaps(grads, xla_grads).items() if v == v}
+    assert len(worst) > 20 and max(worst.values()) < 1e-3, worst
 
 
 def _patched(monkeypatch, name, fn):
