@@ -167,6 +167,15 @@ class InferenceEngine:
                 "keep ONE uniform KV pool (one head count, one table, every "
                 "key kept) for one kind of layer, with no window to evict by "
                 "(training through sxt.initialize is; ROADMAP R-M3)")
+        if "sconv" in mixers:
+            raise NotImplementedError(
+                "serving a stack with gated short-convolution layers (mixer "
+                "'sconv' beside 'attn': layer_pattern, LFM2) is not "
+                "implemented: a convolution layer carries the last "
+                "sconv_taps - 1 rows of its gated input as its state, a tail "
+                "the inference engines hold beside no KV block, and their "
+                "attention has no per-head q/k norm (training through "
+                "sxt.initialize is; ROADMAP R-M10)")
         if getattr(self._mcfg, "recurrent", False) or len(
                 getattr(self._mcfg, "pattern", ((),))) > 1:
             # the cached paths scan ONE kind of layer over a KV cache: a
@@ -198,7 +207,8 @@ class InferenceEngine:
             # the cached decode paths project q and k without the whole-
             # projection RMSNorm: serving such a model would be silently wrong
             raise NotImplementedError(
-                "serving a model with q/k RMSNorm (OLMoE) is not implemented "
+                "serving a model with q/k RMSNorm (OLMoE's over the whole "
+                "projection, LFM2's per head) is not implemented "
                 "yet: the inference engines' attention has no q/k norm "
                 "(training through sxt.initialize is; ROADMAP R1)")
         if self._mcfg.position == "alibi":

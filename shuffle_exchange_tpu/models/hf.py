@@ -55,6 +55,7 @@ _ARCH_FAMILIES = {
     "OlmoeForCausalLM": "olmoe",
     "Qwen3NextForCausalLM": "qwen3next",
     "DeepseekV3ForCausalLM": "deepseekv3",
+    "Lfm2MoeForCausalLM": "lfm2moe",
 }
 
 
@@ -68,6 +69,7 @@ _MODEL_TYPE_FAMILIES = {"llama": "llama", "mistral": "llama", "qwen2": "qwen2",
                         "qwen3_next": "qwen3next",
                         "deepseek_v3": "deepseekv3",
                         "laguna": "laguna",
+                        "lfm2_moe": "lfm2moe",
                         "megatron": "megatron",
                         "megatron-gpt": "megatron", "megatron_gpt": "megatron"}
 
@@ -96,6 +98,76 @@ def _held_share(cfg: Dict[str, Any], family: str) -> Dict[str, Any]:
     return dict(n_experts_held=int(cfg["num_experts_held"]),
                 expert_first=int(cfg.get("expert_first", 0)),
                 moe_held_rows_factor=float(cfg["expert_buffer_factor"]))
+
+
+def _lead_and_period(kinds, family: str):
+    """(the number of leading dense layers, the period of the layers after
+    them) of a stack given layer by layer as (mixer, ffn): the leading layers
+    are the dense ones before the first routed one, all of one kind; the
+    period is the shortest the rest repeats. A stack that ends part of the way
+    into its pattern (Laguna-XS.2's published 40 layers, LFM2-8B-A1B's 24) is
+    ONE period of its whole length: it runs, unrolled, at a compile time that
+    grows with the depth (ROADMAP R-M3)."""
+    lead = next((i for i, (_, ffn) in enumerate(kinds) if ffn != "mlp"), len(kinds))
+    if lead >= len(kinds) or len(set(kinds[:lead])) > 1:
+        raise ValueError(f"{family}: {lead} leading dense layer(s) of kinds "
+                         f"{sorted(set(kinds[:lead]))} in a stack of {len(kinds)}: the "
+                         "leading layers are of one kind and routed layers follow")
+    rest = kinds[lead:]
+    period = next(p for p in range(1, len(rest) + 1) if len(rest) % p == 0
+                  and rest[:p] * (len(rest) // p) == rest)
+    return lead, tuple(rest[:period])
+
+
+def _lfm2_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerConfig:
+    """LiquidAI's ``model_type: lfm2_moe`` as LFM2-8B-A1B ships it: gated
+    short-convolution layers (``layer_types`` "conv": mixer "sconv",
+    ``conv_L_cache`` taps, no bias) beside grouped-query attention layers
+    ("full_attention": mixer "attn" with a per-HEAD q/k RMSNorm before RoPE,
+    ``qk_norm`` "head"), ``num_dense_layers`` leading layers with a dense
+    SwiGLU of ``intermediate_size`` and then routed ones: a sigmoid router
+    whose ``expert_bias`` (``use_expert_bias``) selects and is not weighed,
+    the chosen scores renormalised (``norm_topk_prob``) and scaled by
+    ``routed_scaling_factor``, dropless ("ragged"), no shared expert, no
+    balancing loss; plain-gain RMSNorms at ``norm_eps``; the head tied to the
+    embedding unless ``tie_word_embeddings`` says otherwise. Not the source's
+    keys: ``layers_held`` (a cut in depth: the indices into ``layer_types`` of
+    the ``num_hidden_layers`` layers held here, in order; without it the
+    first that many), ``num_experts_held`` / ``expert_first`` /
+    ``expert_buffer_factor`` as for qwen3_next, ``bias_update_speed`` as for
+    deepseek_v3. What is not written here is refused by name."""
+    L = int(cfg["num_hidden_layers"])
+    types = list(cfg.get("layer_types") or [])
+    held = [int(i) for i in cfg.get("layers_held") or range(L)]
+    if cfg.get("conv_bias"):
+        raise ValueError("lfm2_moe with conv_bias=true is not supported (written "
+                         "down: the projections and the taps of a conv layer have no bias)")
+    if len(held) != L or held != sorted(set(held)) or (held and held[-1] >= len(types)):
+        raise ValueError(f"lfm2_moe: layers_held={held} does not name num_hidden_layers="
+                         f"{L} distinct layers of the {len(types)} in layer_types, in order")
+    mixers = {"conv": "sconv", "full_attention": "attn"}
+    for i in held:
+        if types[i] not in mixers:
+            raise ValueError(f"lfm2_moe with layer_types[{i}]={types[i]!r} is not supported "
+                             "(written down: 'conv' and 'full_attention')")
+    dense = int(cfg.get("num_dense_layers", 0))
+    kinds = [(mixers[types[i]], "mlp" if i < dense else "moe") for i in held]
+    lead, period = _lead_and_period(kinds, "lfm2_moe")
+    common.update(d_ff=cfg["moe_intermediate_size"], norm_eps=cfg.get("norm_eps", 1e-5),
+                  tie_embeddings=bool(cfg.get("tie_word_embeddings", True)))
+    return TransformerConfig(
+        qk_norm="head", sconv_taps=int(cfg.get("conv_L_cache", 3)),
+        layer_pattern=period, lead_layers=lead, lead_kind=kinds[0] if lead else (),
+        dense_ff=cfg["intermediate_size"],
+        n_experts=cfg["num_experts"], **_held_share(cfg, "lfm2_moe"),
+        moe_top_k=cfg["num_experts_per_tok"],
+        moe_norm_topk=bool(cfg.get("norm_topk_prob", True)),
+        moe_score="sigmoid", moe_select_bias=bool(cfg.get("use_expert_bias", True)),
+        moe_weight_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+        # the aux-free update's speed is a training setting, in no published
+        # config.json: a key of this repository's, 0 (held fixed) without it
+        moe_bias_update_rate=float(cfg.get("bias_update_speed", 0.0)),
+        moe_impl="ragged", moe_aux="none", **common)
 
 
 def _laguna_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerConfig:
@@ -152,19 +224,9 @@ def _laguna_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerCo
                 "'default', and 'yarn' on the full-attention layers)")
     kinds = [("attn" if t == "full_attention" else "swa",
               "mlp" if f == "dense" else "moe") for t, f in zip(types, ffns)]
-    lead = next((i for i, f in enumerate(ffns) if f != "dense"), L)
-    if lead >= L or len(set(kinds[:lead])) > 1:
-        raise ValueError(f"laguna: {lead} leading dense layer(s) of kinds "
-                         f"{sorted(set(kinds[:lead]))} in a stack of {L}: the leading "
-                         "layers are of one kind and routed layers follow")
-    rest = kinds[lead:]
-    # the shortest period the layers after the leading ones repeat; a stack
-    # that ends part of the way into its pattern (the published 40 layers: the
-    # leading one, nine periods of four and three window layers) is ONE period
-    # of its whole length: it runs, unrolled, at a compile time that grows
-    # with the depth (ROADMAP R-M3)
-    period = next(p for p in range(1, len(rest) + 1) if len(rest) % p == 0
-                  and rest[:p] * (len(rest) // p) == rest)
+    # the published 40 layers: the leading one, nine periods of four and three
+    # window layers, so ONE period of 39
+    lead, period = _lead_and_period(kinds, "laguna")
     per_mixer = {m: {h for (mm, _), h in zip(kinds, heads) if mm == m}
                  for m in ("attn", "swa")}
     if any(len(h) > 1 for h in per_mixer.values()):
@@ -196,7 +258,7 @@ def _laguna_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerCo
         rotary_dim=int(head * float(full.get(
             "partial_rotary_factor", cfg.get("partial_rotary_factor", 1.0)))),
         rope_yarn=yarn, **swa,
-        layer_pattern=tuple(rest[:period]),
+        layer_pattern=period,
         lead_layers=lead, lead_kind=kinds[0] if lead else (),
         dense_ff=cfg["intermediate_size"],
         n_experts=cfg["num_experts"], **_held_share(cfg, "laguna"),
@@ -532,6 +594,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
             aux_loss_coef=alpha, **common)
     if family == "laguna":
         return _laguna_config(cfg, common)
+    if family == "lfm2moe":
+        return _lfm2_config(cfg, common)
     if family == "mixtral":
         return TransformerConfig(
             n_experts=cfg["num_local_experts"], moe_top_k=cfg.get("num_experts_per_tok", 2),
@@ -1077,7 +1141,7 @@ def params_from_state_dict(sd: Dict[str, Any], config: TransformerConfig,
             layers["b_v"] = _stack(sd, "layers.{}.self_attn.v_proj.bias", L)
         if config.attn_out_bias:   # internlm v1 bias=True
             layers["b_o"] = _stack(sd, "layers.{}.self_attn.o_proj.bias", L)
-        if config.qk_norm:         # olmoe: gains over the whole projection
+        if config.qk_norm is True:  # olmoe: gains over the whole projection
             layers["q_norm_w"] = _stack(sd, "layers.{}.self_attn.q_norm.weight", L)
             layers["k_norm_w"] = _stack(sd, "layers.{}.self_attn.k_norm.weight", L)
         if family in ("mixtral", "qwen2moe", "olmoe"):

@@ -51,8 +51,15 @@ class TransformerConfig:
     attn_qkv_bias: bool = False                # Qwen2-style q/k/v biases
     attn_out_bias: bool = False                # GPT-2/OPT-style out-proj bias
     pos_offset: int = 0                        # OPT offsets positions by 2
-    qk_norm: bool = False                      # OLMoE: RMSNorm (learned gain) over the WHOLE
-                                               # q and k projections, before heads and RoPE
+    qk_norm: Any = False                       # True (OLMoE's): RMSNorm (learned gain) over
+                                               # the WHOLE q and k projections, before heads
+                                               # and RoPE: a one-kind model's "attn".
+                                               # "head" (LFM2's): RMSNorm per HEAD over its
+                                               # head_dim, one gain [head_dim] each for q and
+                                               # k shared by the heads, before RoPE: mixer
+                                               # "attn" of a stack of several kinds (``_gqa``).
+                                               # (mixer "gated_attn" always norms per head,
+                                               # by the block norm's kind: Qwen3-Next's.)
     # Family structure flags (round 3, HF import breadth — reference
     # module_inject/containers/{gptj,gptneox,bloom}.py + falcon in
     # inference/v2/engine_factory.py):
@@ -164,6 +171,15 @@ class TransformerConfig:
     #                       gate from one projection, per-HEAD q/k RMSNorm
     #                       (the block norm's kind) before RoPE
     #         "gdn"         Gated DeltaNet (ops/gated_delta.py), gdn_* below
+    #         "sconv"       LFM2's gated short convolution (ops/short_conv.py):
+    #                       one projection to [gate B | gate C | x], a causal
+    #                       depthwise convolution of ``sconv_taps`` taps over
+    #                       B * x, C * that, one projection back; no heads, no
+    #                       RoPE, no state but the last taps - 1 rows
+    #         "mla"         latent attention, mla_* below
+    #   q/k norm: "attn" takes ``qk_norm`` (True = the whole projection, a
+    #   one-kind model's; "head" = per head, among several kinds); "gated_attn"
+    #   norms per head always; "swa", "mla", "gdn" and "sconv" have none.
     #   ffn   "mlp" | "moe"
     # ``attention_pattern`` (GPT-Neo) and ``moe_layer_pattern`` (Megatron)
     # are older and stay as they are: they vary a FLAG of one kind whose
@@ -176,6 +192,7 @@ class TransformerConfig:
     gdn_key_dim: int = 0                       # per key head (q and k)
     gdn_value_dim: int = 0                     # per value head
     gdn_conv_kernel: int = 4
+    sconv_taps: int = 3                        # mixer "sconv": the convolution's taps
     # One expert-parallel rank's share of a routed layer: the router scores
     # all ``n_experts``, this model holds ``n_experts_held`` of them (0 = all)
     # starting at ``expert_first`` and computes only the token-choices that
@@ -761,6 +778,14 @@ class Transformer:
                 "w_out": stack(next(keys), (Hv * dv, D), Hv * dv,
                                scale=1.0 / math.sqrt(2 * L)),
             })
+        elif mixer == "sconv":
+            layer.update({
+                # three blocks of D columns: [gate before B | gate after C | x]
+                "sconv_w_in": stack(next(keys), (D, 3 * D), D),
+                # the taps [K, D]: tap j weighs position t - (K - 1) + j
+                "sconv_w": stack(next(keys), (cfg.sconv_taps, D), cfg.sconv_taps),
+                "sconv_w_out": stack(next(keys), (D, D), D, scale=1.0 / math.sqrt(2 * L)),
+            })
         elif mixer == "mla":
             r, dc, dr, dv = (cfg.mla_kv_rank, cfg.mla_qk_content_dim,
                              cfg.mla_qk_rope_dim, cfg.mla_v_dim)
@@ -792,6 +817,10 @@ class Transformer:
                 layer["b_o"] = zeros(D)
             if gated:                          # per head, over its Dh
                 layer["q_norm_w"], layer["k_norm_w"] = gain(Dh), gain(Dh)
+            elif cfg.qk_norm == "head":        # per head too, a plain gain
+                if mixer != "attn":
+                    raise ValueError(f"qk_norm='head' is mixer 'attn''s; {mixer!r} has no q/k norm")
+                layer["q_norm_w"], layer["k_norm_w"] = ones(Dh), ones(Dh)
             elif cfg.qk_norm:
                 layer["q_norm_w"] = ones(H * Dh)
                 layer["k_norm_w"] = ones(KV * Dh)
@@ -875,8 +904,12 @@ class Transformer:
             if name in ("wo", "w_down", "w_out", "mla_wo"):
                 return P(*lead, "tensor", None)       # row parallel
             if name in ("b_up", "b_q", "b_k", "b_v") or (
-                    name in ("q_norm_w", "k_norm_w") and cfg.qk_norm):
+                    name in ("q_norm_w", "k_norm_w") and cfg.qk_norm is True):
                 return P(*lead, "tensor")  # column-parallel biases and gains
+            if name.startswith("sconv_"):
+                # the convolution mixer is not split over "tensor": its input
+                # projection's three blocks would each need their own columns
+                return P(*((None,) * leaf.ndim))
             if name == "embed":
                 return P("tensor", None)              # vocab parallel
             if name == "unembed":
@@ -974,7 +1007,7 @@ class Transformer:
             # ``gdn`` has no such kernel; "stock_flash", "reference" and the
             # ring's hop kernels name nothing and recompute
             mix = {"gdn": self._gdn, "gated_attn": self._gated_attention,
-                   "mla": self._mla, "attn": self._gqa,
+                   "mla": self._mla, "attn": self._gqa, "sconv": self._sconv,
                    "swa": functools.partial(self._gqa, mixer="swa")}[mixer]
 
             def mixer_half(lw, h):
@@ -1000,6 +1033,11 @@ class Transformer:
         else:
             with trace.scope("attn_norm"):
                 y = _norm(h, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm, eps=cfg.norm_eps)
+        if cfg.qk_norm == "head":
+            raise NotImplementedError(
+                "qk_norm='head' (per-head q/k RMSNorm) is the form of mixer 'attn' "
+                "in a stack of several kinds (Transformer._gqa); a one-kind model's "
+                "attention norms the whole projection (qk_norm=True) or nothing")
         with trace.scope("attn_qkv"):
             q, k = y @ lw["wq"], y @ lw["wk"]
             if cfg.qk_norm:
@@ -1072,15 +1110,19 @@ class Transformer:
         query's own, with its own scopes nested in the attention layer's
         (``swa_qkv`` and ``swa_rope`` in ``attn_qkv``, ``swa_core`` in
         ``attn_core``, ``swa_out`` in ``attn_out``); an "attn" layer that
-        rotates by a YaRN table does so under ``rope_yarn``. None of the
-        softmax family's flags reaches this form (biases, q/k norm, ALiBi,
+        rotates by a YaRN table does so under ``rope_yarn``. With ``qk_norm``
+        "head" an "attn" layer norms q and k per head over ``head_dim`` (a
+        plain gain [head_dim] each, the block norm's eps) BEFORE the rotation,
+        under ``attn_qk_norm`` (LFM2). None of the softmax family's other flags
+        reaches this form (biases, the whole-projection q/k norm, ALiBi,
         post-LN, a parallel block: a one-kind model's, ``layer_apply``)."""
         from jax.ad_checkpoint import checkpoint_name
 
         cfg = self.config
-        flags = [f for f in ("attn_qkv_bias", "attn_out_bias", "qk_norm", "post_ln",
+        head_norm = cfg.qk_norm == "head" and mixer == "attn"
+        flags = [f for f in ("attn_qkv_bias", "attn_out_bias", "post_ln",
                              "parallel_block", "attn_scale", "local_attention_window")
-                 if getattr(cfg, f)]
+                 if getattr(cfg, f)] + ["qk_norm"] * (cfg.qk_norm is True)
         if flags or cfg.position != "rope" or not cfg.causal:
             raise NotImplementedError(
                 f"a stack of several kinds runs mixer {mixer!r} as plain causal "
@@ -1099,6 +1141,10 @@ class Transformer:
                 q = (y @ lw["wq"]).reshape(B, T, H, Dh)
                 k = (y @ lw["wk"]).reshape(B, T, KV, Dh)
                 v = (y @ lw["wv"]).reshape(B, T, KV, Dh)
+            if head_norm:
+                with trace.scope("attn_qk_norm"):
+                    q = _head_norm(q, lw["q_norm_w"], "rmsnorm", cfg.norm_eps)
+                    k = _head_norm(k, lw["k_norm_w"], "rmsnorm", cfg.norm_eps)
             with own("swa_rope" if windowed else "rope_yarn" if cfg.rope_yarn else None):
                 q = apply_rope(q, cos, sin, interleaved=cfg.rope_interleaved)
                 k = apply_rope(k, cos, sin, interleaved=cfg.rope_interleaved)
@@ -1145,6 +1191,36 @@ class Transformer:
             with trace.scope("attn_gate"):
                 attn = attn * jax.nn.sigmoid(gate)
             return attn.reshape(B, T, H * Dh) @ lw["wo"]
+
+    def _sconv(self, lw, y, rope):
+        """The gated short-convolution mixer (LFM2's ``conv`` layers;
+        ``ops/short_conv.py``) on the normed block input y [B, T, D] ->
+        [B, T, D]; ``rope`` is not used (the taps carry the order):
+        ``[B | C | x] = y W_in`` (three blocks of D), ``u = B * x``, a causal
+        depthwise convolution of ``sconv_taps`` taps over u along each
+        sequence (zeros before its first position), ``(C * that) W_out``. No
+        bias, no activation. Under the outer scopes of an attention layer so
+        that a reader's sums by layer hold, its own nested inside:
+        ``sconv_in`` (in ``attn_qkv``), ``sconv_mix`` (in ``attn_core``: the
+        pass between the projections, ``sconv_mix``) and ``sconv_out`` (in
+        ``attn_out``). A sequence-parallel mesh is refused: the taps of a
+        shard's first rows reach the last ``sconv_taps - 1`` rows of the
+        shard before it, a halo nothing carries."""
+        from ..ops.short_conv import sconv_mix
+
+        del rope
+        if self._sp_mesh()[0] > 1:
+            raise NotImplementedError(
+                "the gated short convolution (mixer 'sconv') under a "
+                "sequence-parallel mesh: a shard's first rows need the last "
+                f"{self.config.sconv_taps - 1} rows of the shard before it, a "
+                "halo nothing carries; run it with seq = 1")
+        with trace.scope("attn_qkv"), trace.scope("sconv_in"):
+            bcx = y @ lw["sconv_w_in"]
+        with trace.scope("attn_core"), trace.scope("sconv_mix"):
+            mixed = sconv_mix(bcx, lw["sconv_w"])
+        with trace.scope("attn_out"), trace.scope("sconv_out"):
+            return mixed @ lw["sconv_w_out"]
 
     def _mla(self, lw, y, rope):
         """The latent-attention mixer (DeepSeek-V2/V3 MLA without query
@@ -1980,22 +2056,37 @@ class Transformer:
         cfg = self.config
         if not cfg.moe_select_bias:
             return new
-        if len(cfg.pattern) > 1:
-            raise NotImplementedError(
-                "a selection bias in a pattern of several layer kinds: "
-                "update_buffers maps the routing counters' rows to a one-kind "
-                "stack (with leading layers) only")
         lead = cfg.lead_layers if cfg.lead_layers and cfg.lead_kind[1] == "moe" else 0
+        moved = cfg.moe_bias_update_rate and "moe_expert_tokens" in stats
+
+        def carried(bias, rows_of):
+            """``bias`` after the step; ``rows_of`` picks its layers' rows out
+            of the step's ``moe_expert_tokens`` [routed layers, E]."""
+            if not moved:
+                return bias
+            load = rows_of(stats["moe_expert_tokens"]).astype(jnp.float32).reshape(bias.shape)
+            return bias + cfg.moe_bias_update_rate * jnp.sign(
+                load.mean(axis=-1, keepdims=True) - load).astype(bias.dtype)
+
         out = dict(new)
-        for top, rows in (("lead", slice(0, lead)), ("layers", slice(lead, None))):
-            if "moe_select_bias" not in new.get(top, {}):
-                continue
-            bias = old[top]["moe_select_bias"]
-            if cfg.moe_bias_update_rate and "moe_expert_tokens" in stats:
-                load = stats["moe_expert_tokens"][rows].astype(jnp.float32)
-                bias = bias + cfg.moe_bias_update_rate * jnp.sign(
-                    load.mean(axis=-1, keepdims=True) - load).astype(bias.dtype)
-            out[top] = {**new[top], "moe_select_bias": bias}
+        if "moe_select_bias" in new.get("lead", {}):
+            out["lead"] = {**new["lead"], "moe_select_bias": carried(
+                old["lead"]["moe_select_bias"], lambda rows: rows[:lead])}
+        if len(cfg.pattern) == 1:
+            if "moe_select_bias" in new["layers"]:
+                out["layers"] = {**new["layers"], "moe_select_bias": carried(
+                    old["layers"]["moe_select_bias"], lambda rows: rows[lead:])}
+            return out
+        # several kinds: the counters' rows run period by period over the
+        # period's ROUTED slots in order; a kind's bias is [periods, layers of
+        # the kind a period, E]
+        routed = [name for name, _, (_, ffn) in self.slots() if ffn == "moe"]
+        out["layers"] = dict(new["layers"])
+        for name in dict.fromkeys(routed):
+            at = jnp.asarray([j for j, n in enumerate(routed) if n == name])
+            out["layers"][name] = {**new["layers"][name], "moe_select_bias": carried(
+                old["layers"][name]["moe_select_bias"],
+                lambda rows: rows[lead:].reshape((-1, len(routed)) + rows.shape[1:])[:, at])}
         return out
 
     def loss(self, params, batch, rng=None):

@@ -105,7 +105,9 @@ def l2norm(x, eps: float = 1e-6):
 
 
 def causal_conv1d(x, w):
-    """Depthwise causal convolution, no bias. x [B, T, C], w [K, C]:
+    """Depthwise causal convolution, no bias (the DeltaNet prologue's XLA form,
+    ``_gdn_prologue_xla``, and the gated short convolution's,
+    ``short_conv.sconv_mix``, call it; the oracle of both). x [B, T, C], w [K, C]:
     ``y[t] = sum_j w[j] * x[t - (K - 1) + j]`` with x zero before position 0
     (torch's ``Conv1d(C, C, K, groups=C, padding=K-1)`` cut to T outputs).
     Accumulates in float32; returns x's dtype."""

@@ -68,7 +68,9 @@ PREFIX = "sxt:"
 # (mixer "swa") opens "swa_qkv" and "swa_rope" inside "attn_qkv", "swa_core"
 # inside "attn_core" and "swa_out" inside "attn_out"; a full layer of such a
 # stack that rotates by a YaRN table does so under "rope_yarn" inside
-# "attn_qkv".
+# "attn_qkv". A gated short-convolution layer (mixer "sconv") opens "sconv_in"
+# inside "attn_qkv", "sconv_mix" (the pass between its projections) inside
+# "attn_core" and "sconv_out" inside "attn_out".
 # "plumbing" is what belongs to no layer of the model: the layer scan's own
 # slicing and stacking, the masters' cast to the compute dtype, the
 # gradients' cast back and normalization
@@ -76,7 +78,8 @@ SCOPES = {
     "attn": ("attn_norm", "attn_qkv", "attn_qk_norm", "attn_core", "attn_out",
              "attn_gate", "gdn_conv", "gdn_gates", "gdn_scan", "gdn_out_norm",
              "mla_q", "mla_kv_down", "mla_kv_norm", "mla_kv_up", "mla_rope",
-             "swa_qkv", "swa_rope", "swa_core", "swa_out", "rope_yarn"),
+             "swa_qkv", "swa_rope", "swa_core", "swa_out", "rope_yarn",
+             "sconv_in", "sconv_mix", "sconv_out"),
     "mlp": ("mlp_norm", "mlp", "moe", "moe_router", "moe_dispatch",
             "moe_experts", "moe_combine", "moe_shared"),
     "loss": ("embed", "final_norm", "loss", "head_logits", "head_softmax",

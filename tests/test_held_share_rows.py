@@ -43,6 +43,10 @@ CASES = {
     "k1": (_drawn(7, 50, 1, 4), 1, 2, 24),
     # a 256-wide router with top 8, rank 2 of the 8 that hold 32 experts each
     "k8_of_256_32_held": (_drawn(8, 64, 8, 256), 64, 32, 96),
+    # a 32-wide router with top 4, rank 1 of the 4 that hold 8 experts each: a
+    # token lands on one held expert on average (the tie of the four shares to
+    # the uncut layer is tests/test_lfm2.py's)
+    "k4_of_32_8_held": (_drawn(9, 96, 4, 32), 8, 8, 288),
 }
 
 
