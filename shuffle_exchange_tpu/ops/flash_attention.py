@@ -143,11 +143,19 @@ def window_block(n: int, window: int, itemsize: int = 2) -> int:
     return fits[0] if fits else _pick_block(n, itemsize)
 
 
+def _splash_blocks(T: int, S: int, window: int, itemsize: int):
+    """The (query, key) blocks of a splash call over [T, S] scores."""
+    if window:
+        return tuple(window_block(n, window, itemsize) for n in (T, S))
+    return _pick_block(T, itemsize), _pick_block(S, itemsize)
+
+
 def splash_mask(T: int, S: int, causal: bool = True, window: int = 0):
     """The splash mask of one head for [T, S] scores: ``CausalMask`` /
     ``FullMask``, or for ``window`` > 0 the causal ``LocalMask`` of the window
     (key j visible iff 0 <= i - j < window) whose empty blocks the kernels
-    skip, forward and both backward kernels."""
+    skip, forward and backward (``ops/splash_backward.visited_pairs`` lists
+    the same pairs by arithmetic for the fused backward)."""
     from jax.experimental.pallas.ops.tpu import splash_attention as sa
 
     if window:
@@ -184,11 +192,18 @@ def splash_attention_gqa(q, k, v, causal: bool = True, segment_ids=None,
     n_kv-sized — the structural fix for VERDICT r2 weak #5 (the `_repeat_kv`
     broadcast claim no longer needs XLA's cooperation). ``window`` > 0: the
     causal local mask of ``splash_mask`` at ``window_block``'s blocks, in
-    the forward and both backward kernels. q [B,T,H,D],
+    the forward and the backward. q [B,T,H,D],
     k [B,S,KV,D], v [B,S,KV,Dv] with H % KV == 0; q heads group g of kv head
     j is h = j * G + g (the `_repeat_kv` convention). ``Dv`` may differ from
     ``D`` (latent attention: scores 192 wide, values 128): the kernels take
     the value width from ``v`` and the result is [B,T,H,Dv].
+
+    The forward is the library's kernel on every route. The backward is what
+    ``attention_backward_route`` answers for the call: "fused_resident_dkv",
+    ``ops/splash_backward``'s one kernel at the forward's blocks (causal or a
+    window, 2-byte inputs, no segment ids, no ``mask_np``), else
+    "splash_two_kernels", the library's ``dkv`` and ``dq`` kernels. The
+    ``SXT_ATTN_BLOCK_BWD`` override reaches the two-kernel route only.
     """
     import jax
     import jax.numpy as jnp
@@ -198,9 +213,26 @@ def splash_attention_gqa(q, k, v, causal: bool = True, segment_ids=None,
     S, KV = k.shape[1], k.shape[2]
     G = H // KV
 
-    bq, bkv = _pick_block(T, q.dtype.itemsize), _pick_block(S, q.dtype.itemsize)
-    if window:
-        bq, bkv = (window_block(n, window, q.dtype.itemsize) for n in (T, S))
+    bq, bkv = _splash_blocks(T, S, window, q.dtype.itemsize)
+    if mask_np is not None:
+        # arbitrary [T, S] bool mask (blocksparse layouts): splash skips
+        # fully-masked blocks — real block skipping, not just masking
+        head_mask = sa.NumpyMask(mask_np)
+    else:
+        head_mask = splash_mask(T, S, causal, window)
+    mask = sa.MultiHeadMask([head_mask for _ in range(G)])
+
+    scale = D ** -0.5
+    q5 = (q * scale).reshape(B, T, KV, G, D).transpose(0, 2, 3, 1, 4)  # [B,KV,G,T,D]
+    k4 = k.transpose(0, 2, 1, 3)                                       # [B,KV,S,D]
+    v4 = v.transpose(0, 2, 1, 3)
+    back = lambda out5: out5.transpose(0, 3, 1, 2, 4).reshape(
+        B, T, H, v.shape[-1]).astype(q.dtype)
+
+    if attention_backward_route(q, k, v, causal, window, segment_ids,
+                                mask_np) == "fused_resident_dkv":
+        return back(_with_fused_backward(mask, bq, bkv, window, interpret)(q5, k4, v4))
+
     # Backward blocks are independently tunable: the dkv/dq passes hold
     # extra residual tiles in VMEM, so their sweet spot can sit below the
     # forward's (the VERDICT r3 MFU item names attention-backward blocks as
@@ -229,22 +261,9 @@ def splash_attention_gqa(q, k, v, causal: bool = True, segment_ids=None,
         block_q=bq, block_kv=bkv, block_kv_compute=bkv,
         block_q_dkv=bq_b, block_kv_dkv=bkv_b, block_kv_dkv_compute=bkv_b,
         block_q_dq=bq_b, block_kv_dq=bkv_b)
-    if mask_np is not None:
-        # arbitrary [T, S] bool mask (blocksparse layouts): splash skips
-        # fully-masked blocks — real block skipping, not just masking
-        head_mask = sa.NumpyMask(mask_np)
-    else:
-        head_mask = splash_mask(T, S, causal, window)
-    mask = sa.MultiHeadMask([head_mask for _ in range(G)])
     kernel = sa.make_splash_mqa_single_device(
         mask, block_sizes=block_sizes, interpret=interpret,
         residual_checkpoint_name=SPLASH_RESIDUALS)
-
-    scale = D ** -0.5
-    q5 = (q * scale).reshape(B, T, KV, G, D).transpose(0, 2, 3, 1, 4)  # [B,KV,G,T,D]
-    k4 = k.transpose(0, 2, 1, 3)                                       # [B,KV,S,D]
-    v4 = v.transpose(0, 2, 1, 3)
-
     if segment_ids is not None:
         seg = sa.SegmentIds(q=segment_ids, kv=segment_ids)
         per_kv = jax.vmap(kernel, in_axes=(0, 0, 0, None))
@@ -252,7 +271,74 @@ def splash_attention_gqa(q, k, v, causal: bool = True, segment_ids=None,
     else:
         per_kv = jax.vmap(kernel, in_axes=(0, 0, 0))
         out5 = jax.vmap(per_kv, in_axes=(0, 0, 0))(q5, k4, v4)
-    return out5.transpose(0, 3, 1, 2, 4).reshape(B, T, H, v.shape[-1]).astype(q.dtype)
+    return back(out5)
+
+
+def _with_fused_backward(mask, bq: int, bkv: int, window: int, interpret: bool):
+    """Attention over [B,KV,G,T,D] queries and [B,KV,S,D] keys / values as one
+    ``jax.custom_vjp``: the library's forward kernel, whose ``out`` and
+    ``logsumexp`` the forward rule names ``SPLASH_RESIDUALS`` (so a policy
+    that lists the name enters the backward from saved state, as with the
+    library's own rule), and ``ops/splash_backward``'s one backward kernel at
+    the same blocks."""
+    import jax
+    from jax.ad_checkpoint import checkpoint_name
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
+
+    from .splash_backward import fused_backward
+
+    def forward(keep: bool):
+        # (the library's own naming would sit inside ITS custom_vjp call,
+        # where no policy looks: the rule below names them)
+        kernel = sa.make_splash_mqa_single_device(
+            mask, block_sizes=sa.BlockSizes(block_q=bq, block_kv=bkv,
+                                            block_kv_compute=bkv),
+            save_residuals=keep, interpret=interpret)
+        return jax.vmap(jax.vmap(kernel))
+
+    @jax.custom_vjp
+    def attend(q5, k4, v4):
+        return forward(False)(q5, k4, v4)
+
+    def fwd(q5, k4, v4):
+        out, (logsumexp,) = forward(True)(q5, k4, v4)
+        if SPLASH_RESIDUALS is not None:
+            out, logsumexp = (checkpoint_name(x, SPLASH_RESIDUALS)
+                              for x in (out, logsumexp))
+        return out, (q5, k4, v4, out, logsumexp)
+
+    def bwd(kept, do):
+        return tuple(fused_backward(*kept, do, bq=bq, bkv=bkv, window=window,
+                                    interpret=interpret))
+
+    attend.defvjp(fwd, bwd)
+    return attend
+
+
+def attention_backward_route(q, k, v, causal: bool = True, window: int = 0,
+                             segment_ids=None, mask_np=None) -> str:
+    """Which backward a ``splash_attention_gqa`` call of these inputs
+    (anything with ``.shape`` and ``.dtype``; q [B,T,H,D], k [B,S,KV,D],
+    v [B,S,KV,Dv]) differentiates through, read off what the call can see:
+    "fused_resident_dkv" (``ops/splash_backward``: one kernel, dk and dv of a
+    key head resident in VMEM) under the causal mask or a window, with 2-byte
+    inputs, no segment ids, no ``mask_np``, every query in sight of a key,
+    and the resident buffers and tiles within the kernel's VMEM budget; else
+    "splash_two_kernels" (the library's ``dkv`` and ``dq`` kernels)."""
+    import numpy as np
+
+    from . import splash_backward as sb
+
+    T, S = q.shape[1], k.shape[1]
+    itemsize = np.dtype(q.dtype).itemsize
+    if not (causal and segment_ids is None and mask_np is None
+            and itemsize == 2 and S >= T):
+        return "splash_two_kernels"
+    bq, bkv = _splash_blocks(T, S, window, itemsize)
+    if sb.vmem_bytes(S, q.shape[-1], v.shape[-1], bq, bkv,
+                     itemsize) > sb.VMEM_BUDGET_BYTES:
+        return "splash_two_kernels"
+    return "fused_resident_dkv"
 
 
 def _pallas_ok(q, k, causal: bool = True) -> bool:
@@ -264,18 +350,23 @@ def _pallas_ok(q, k, causal: bool = True) -> bool:
     s = k.shape[1]
     # Verified on-chip: head_dim 64 and 128 (fwd+bwd parity vs the jnp
     # oracle), and head_dim 256 with 16 query heads over 2 KV heads at 8192
-    # positions (PR 33, the cell qwen3next-train: the splash MQA kernels'
-    # forward and both backward kernels; the attention layer's q/k/v/o
+    # positions (PR 33, the cell qwen3next-train: the splash MQA kernels,
+    # since PR 43 the forward and the fused backward; the attention layer's q/k/v/o
     # gradients sit with every other leaf inside the float32 reference's bf16
     # band, PERF.md section 6), and scores 192 wide over values 128 wide with
     # 32 heads at 8192 positions (PR 35, the cell kanana2-train: the splash
-    # kernels with the values' OWN width, "splash_own_v": 17.1 ms forward and
-    # 61.3 ms forward + backward at batch 2; padded to 256 they read 18.1 /
-    # 62.0, Mosaic pads the lanes itself; the stock kernel refuses 192
-    # ("should be a multiple of 128 if larger") and at 256 its dkv kernel
-    # does not fit VMEM at 1024 blocks: the compiler for a described v5e;
-    # the score as two XLA contractions a query block 1.6 s). Other multiples
-    # of 64 are admitted untried.
+    # kernels with the values' OWN width, "splash_own_v"; since PR 43 the
+    # forward kernel and the ONE fused backward kernel of ops/splash_backward:
+    # 16.5 ms forward and 46.1 ms forward + backward at batch 2 where the
+    # library's two backward kernels read 59.4 and its own fused flag 49.2;
+    # at 16,384 positions over 8 KV heads of 128, PR 39's cell laguna-train,
+    # 48 heads causal 28.4 / 77.8 against 103.1 and 64 heads under a window
+    # of 512 7.7 / 19.3 against 24.1: my chip run, PR 43; Mosaic pads 192 to
+    # 256 lanes itself; the stock kernel refuses 192 ("should be a multiple
+    # of 128 if larger") and at 256 its dkv kernel does not fit VMEM at 1024
+    # blocks: the compiler for a described v5e; the score as two XLA
+    # contractions a query block 1.6 s). Other multiples of 64 are admitted
+    # untried.
     # Ragged seq lengths are padded up to the
     # 128-wide block inside pallas_attention — but only the causal path can
     # do that mask-free, so non-causal keeps the exact-multiple requirement.

@@ -73,7 +73,8 @@ def _attention(rng) -> Iterator[dict]:
     import jax
     import jax.numpy as jnp
 
-    from ..ops.flash_attention import pallas_attention, reference_attention
+    from ..ops.flash_attention import (attention_backward_route,
+                                       pallas_attention, reference_attention)
 
     # stock flash (MHA) and splash (GQA, unexpanded KV), forward and dk
     for H, KV, label in [(8, 8, "flash-mha"), (8, 2, "splash-gqa")]:
@@ -87,6 +88,31 @@ def _attention(rng) -> Iterator[dict]:
         g_r = jax.grad(lambda q, k, v: (reference_attention(q, k, v) ** 2).sum(),
                        argnums=1)(q, k, v)
         yield _check(label + "-dk", g_p, g_r, 5e-1)
+
+    # the splash routes' backward as one kernel (ops/splash_backward: bf16
+    # inputs take it, the float32 rows above keep the library's two), causal
+    # over several blocks and under a window that leaves blocks unvisited:
+    # forward and dq, dk, dv against the oracle in float32 on the same
+    # rounded inputs, each as a share of the oracle's largest value
+    for KV, D, Dv, window, label in [(2, 128, 128, 0, "splash-fused-bwd"),
+                                     (8, 192, 128, 0, "splash-fused-bwd-192-128"),
+                                     (1, 128, 128, 512, "splash-fused-bwd-window")]:
+        q, k, v, do = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+                       for shape in [(1, 2048, 8, D), (1, 2048, KV, D),
+                                     (1, 2048, KV, Dv), (1, 2048, 8, Dv)])
+        assert attention_backward_route(q, k, v, True, window) == "fused_resident_dkv"
+        wide = [x.astype(jnp.float32) for x in (q, k, v)]
+        loss = lambda attend: lambda q, k, v: jnp.sum(
+            attend(q, k, v, causal=True, window=window).astype(jnp.float32)
+            * do.astype(jnp.float32))
+        got = jax.grad(loss(pallas_attention), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(*wide)
+        pairs = [("", pallas_attention(q, k, v, causal=True, window=window),
+                  reference_attention(*wide, causal=True, window=window))]
+        pairs += [("-d" + n, a, b) for n, a, b in zip("qkv", got, want)]
+        for suffix, a, b in pairs:
+            top = float(jnp.max(jnp.abs(b)))
+            yield _check(label + suffix, _f32(a) / top, _f32(b) / top, 2e-2)
 
 
 def _rmsnorm(rng) -> Iterator[dict]:

@@ -551,8 +551,8 @@ def test_splash_attention_at_16k_compiles(chip_compile, heads, window):
     """The two attention kinds of a window / full hybrid stack at 16,384
     positions over 8 KV heads of 128 (PR 39): the splash kernels under the
     local causal mask of a 512-key window at 512-blocks (groups of 8), and
-    under the causal mask at 1024-blocks (groups of 6), forward and both
-    backward kernels."""
+    under the causal mask at 1024-blocks (groups of 6), forward and
+    backward."""
     from shuffle_exchange_tpu.ops.flash_attention import pallas_attention
 
     q = ((1, 16384, heads, 128), _BF16)
@@ -560,6 +560,77 @@ def test_splash_attention_at_16k_compiles(chip_compile, heads, window):
     chip_compile(jax.grad(lambda q, k, v: pallas_attention(
         q, k, v, causal=True, window=window).astype(_F32).sum(), argnums=(0, 1, 2)),
         q, kv, kv)
+
+
+# the softmax cells' attention calls at their REAL shapes (PR 43):
+# batch, T, query heads, key heads, score width, value width, window
+_CELL_ATTENTION = {
+    "kanana2-train": (2, 8192, 32, 32, 192, 128, 0),
+    "laguna-train-full": (1, 16384, 48, 8, 128, 128, 0),
+    "laguna-train-window": (1, 16384, 64, 8, 128, 128, 512),
+    "qwen3next-train": (1, 8192, 16, 2, 256, 256, 0),
+    "lfm2-train": (8, 4096, 32, 8, 64, 64, 0),
+    "mistral7b-zero3-x4": (1, 4096, 32, 8, 128, 128, 0),
+}
+
+
+def _kernel_launches(compiled):
+    """kernel name -> custom calls of it in a compiled program's text."""
+    import collections
+    import re
+
+    return collections.Counter(re.findall(
+        r'%(\w+?)(?:\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"',
+        compiled.as_text()))
+
+
+@pytest.mark.parametrize("cell", list(_CELL_ATTENTION))
+def test_fused_attention_backward_compiles_at_the_cells_shapes(chip_compile, cell):
+    """The splash routes' backward as one kernel (``ops/splash_backward``):
+    dk and dv of a key head resident in VMEM in float32 (12-16 MiB at
+    these shapes) beside the tiles of a block pair, within what the kernel
+    asks Mosaic for: an overflow fails here, before any chip time. The
+    compiled gradient holds the library's forward kernel, the fused backward
+    and neither of the library's backward kernels."""
+    from shuffle_exchange_tpu.ops.flash_attention import (
+        attention_backward_route, pallas_attention)
+    from shuffle_exchange_tpu.ops.splash_backward import KERNEL_NAME
+
+    B, T, H, KV, D, Dv, window = _CELL_ATTENTION[cell]
+    q, k, v = ((B, T, H, D), _BF16), ((B, T, KV, D), _BF16), ((B, T, KV, Dv), _BF16)
+    assert attention_backward_route(*(jax.ShapeDtypeStruct(*x) for x in (q, k, v)),
+                                    True, window) == "fused_resident_dkv"
+    compiled = chip_compile(jax.grad(lambda q, k, v: pallas_attention(
+        q, k, v, causal=True, window=window).astype(_F32).sum(), argnums=(0, 1, 2)),
+        q, k, v)
+    assert _kernel_launches(compiled) == {
+        "splash_mqa_fwd_residuals": 1, KERNEL_NAME: 1}
+
+
+@pytest.mark.parametrize("route", ["fused_resident_dkv", "splash_two_kernels"])
+def test_full_remat_replays_no_forward_kernel_before_either_backward(
+        chip_compile, route, monkeypatch):
+    """A mixer half's checkpoint under "full" remat with
+    ``_keeping_splash_residuals`` (PR 36), compiled for the chip: the forward
+    kernel is in the program once, not again in the replay, whichever
+    backward the call takes."""
+    import importlib
+
+    from shuffle_exchange_tpu.models.transformer import (
+        _keeping_splash_residuals, _remat_policy)
+    from shuffle_exchange_tpu.ops.splash_backward import KERNEL_NAME
+
+    fa = importlib.import_module("shuffle_exchange_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "attention_backward_route", lambda *a, **kw: route)
+    half = jax.checkpoint(
+        lambda q, k, v: fa.pallas_attention(q, k, v, causal=True),
+        policy=_keeping_splash_residuals(_remat_policy("full")))
+    q, kv = ((1, 4096, 32, 128), _BF16), ((1, 4096, 8, 128), _BF16)
+    compiled = chip_compile(jax.grad(lambda q, k, v: half(q, k, v).astype(
+        _F32).sum(), argnums=(0, 1, 2)), q, kv, kv)
+    backward = ({KERNEL_NAME: 1} if route == "fused_resident_dkv" else
+                {"splash_mqa_dkv_no_residuals": 1, "splash_mqa_dq_no_residuals": 1})
+    assert _kernel_launches(compiled) == {"splash_mqa_fwd_residuals": 1, **backward}
 
 
 @pytest.mark.parametrize("rows", [(2, 2048), (8, 1)],

@@ -305,13 +305,15 @@ def test_the_splash_residuals_name_is_inert_elsewhere(around, monkeypatch):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("bf16", [False, True], ids=["float32", "bf16"])
 @pytest.mark.parametrize("mixer", ["mla", "gated_attn"])
 def test_the_trainer_runs_the_forward_kernel_once_a_layer(
-        mixer, splash_on_cpu, devices8):
+        mixer, bf16, splash_on_cpu, devices8):
     """The same through ``sxt.initialize``: ``activation_checkpointing`` with
     policy "full" from the train_config, ZeRO-3, the kernels inside the
     8-device mesh's ``shard_kernel``: the policy reads the name through the
-    shard_map, and the step trains."""
+    shard_map, and the step trains. In bf16 the backward is the one fused
+    kernel (``ops/splash_backward``, PR 43), in float32 the library's two."""
     import shuffle_exchange_tpu as sxt
 
     block = {k: v for k, v in _BLOCK.items() if not k.startswith("remat")}
@@ -322,6 +324,7 @@ def test_the_trainer_runs_the_forward_kernel_once_a_layer(
         config={"train_batch_size": 8, "steps_per_print": 10 ** 9,
                 "optimizer": {"type": "FusedAdam", "params": {"lr": 1e-3}},
                 "activation_checkpointing": {"enabled": True, "policy": "full"},
+                "bf16": {"enabled": bf16},
                 "zero_optimization": {"stage": 3}}, seed=0)[0]
     assert model.config.remat and model.config.remat_policy == "full"
     jaxpr = jax.make_jaxpr(engine._train_step)(
@@ -329,7 +332,9 @@ def test_the_trainer_runs_the_forward_kernel_once_a_layer(
         engine._mix_matrix(), engine._next_rng_peek(),
         np.asarray(1.0, np.float32)).jaxpr
     assert any(e.primitive.name == "shard_map" for e in _equations(jaxpr))
-    for kernel in ("splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals",
-                   "splash_mqa_dkv_no_residuals"):
-        assert _launches(jaxpr, kernel) == 1, kernel
+    backward = {"sxt_splash_bwd_fused": int(bf16),
+                "splash_mqa_dq_no_residuals": int(not bf16),
+                "splash_mqa_dkv_no_residuals": int(not bf16)}
+    for kernel, launches in {"splash_mqa_fwd_residuals": 1, **backward}.items():
+        assert _launches(jaxpr, kernel) == launches, kernel
     assert np.isfinite(float(engine.train_batch({"input_ids": ids})))
