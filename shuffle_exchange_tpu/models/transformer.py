@@ -201,7 +201,10 @@ class TransformerConfig:
     # (tokens x k x held / n_experts); a row that does not fit is dropped and
     # counted (``moe_overflow_rows``). 3 is what ``qwen3next-train`` runs: an
     # untrained router over Zipf ids reads up to 1.1 x the balanced share
-    # there and nothing overflows (PERF.md section 6, PR 33).
+    # there and nothing overflows (PERF.md section 6, PR 33). The factor buys
+    # room in memory (the buffer's rows, and a write of zeros a pass), not
+    # time in the row passes: those walk the blocks that hold a row
+    # (``moe_visited_rows``; PERF.md section 6, PR 44).
     n_experts_held: int = 0
     expert_first: int = 0
     moe_held_rows_factor: float = 3.0
@@ -439,10 +442,11 @@ def _head_norm(x, weight, kind: str, eps: float):
     return rmsnorm_reference(x, 1.0 + gain if kind == "rmsnorm_zc" else gain, eps)
 
 
-def _no_routing_stats(n_experts: int, weights: bool = False) -> dict:
+def _no_routing_stats(n_experts: int, weights: bool = False, share: bool = False) -> dict:
     """What a layer that routes nothing reports (a dense layer among routed
     ones), shaped as a routed layer's stats (``weights``: of a router with a
-    selection bias, which also reports ``expert_weight``)."""
+    selection bias, which also reports ``expert_weight``; ``share``: of a
+    rank's share of the experts, which also reports ``visited_rows``)."""
     import jax.numpy as jnp
 
     out = {"expert_tokens": jnp.zeros((n_experts,), jnp.int32),
@@ -451,6 +455,8 @@ def _no_routing_stats(n_experts: int, weights: bool = False) -> dict:
            "overflow_rows": jnp.zeros((), jnp.int32)}
     if weights:
         out["expert_weight"] = jnp.zeros((n_experts,), jnp.float32)
+    if share:
+        out["visited_rows"] = jnp.zeros((), jnp.int32)
     return out
 
 
@@ -1346,11 +1352,12 @@ class Transformer:
         [E] (mean router probability), ``held_rows`` (the token-choices the
         experts held here computed: all of them unless this is a rank's
         share) and ``overflow_rows`` (held rows that did not fit the buffer
-        and were dropped). ``moe_aux`` is the layer's own balancing loss;
-        for ``moe_aux="all_choices"`` it is divided by the layer count, which
-        is HF's loss exactly at one layer and a per-layer form of it
-        otherwise (``loss_and_stats`` computes the exact cross-layer form
-        from the stats where it has them)."""
+        and were dropped); a rank's share also ``visited_rows`` (positions of
+        its buffer the row passes walked). ``moe_aux`` is the layer's own
+        balancing loss; for ``moe_aux="all_choices"`` it is divided by the
+        layer count, which is HF's loss exactly at one layer and a per-layer
+        form of it otherwise (``loss_and_stats`` computes the exact
+        cross-layer form from the stats where it has them)."""
         import jax
         import jax.numpy as jnp
         from jax.ad_checkpoint import checkpoint_name
@@ -1395,6 +1402,9 @@ class Transformer:
                         "overflow_rows", 0), jnp.int32)}
                 if "expert_weight" in res.metadata:
                     stats["expert_weight"] = res.metadata["expert_weight"]
+                if "visited_rows" in res.metadata:
+                    stats["visited_rows"] = jnp.asarray(
+                        res.metadata["visited_rows"], jnp.int32)
                 return res.output, aux, stats
 
             if moe_on is None:
@@ -1417,7 +1427,8 @@ class Transformer:
                     if "b_down" in expert_params:
                         out = out + expert_params["b_down"][0].astype(dtype)
                     return out, jnp.zeros((), jnp.float32), _no_routing_stats(
-                        cfg.n_experts, cfg.moe_select_bias)
+                        cfg.n_experts, cfg.moe_select_bias,
+                        cfg.experts_held != cfg.n_experts)
 
                 from ..parallel.mesh import inside_manual_region
 
@@ -2105,11 +2116,15 @@ class Transformer:
         the experts held here computed (all, unless ``n_experts_held`` makes
         this one rank's share), and ``moe_overflow_rows`` [routed layers],
         held rows that did not fit the share's buffer and were dropped (0 for
-        a model that holds every expert); a router with a selection bias also
-        ``moe_expert_weight`` [routed layers, E] float32, the sum of the
-        weights of each expert's token-choices; a chunked loss ``loss_chunks``, the
-        trips of its scan, and ``loss_rows``, the rows they held, pad rows
-        too, on the device that scanned them (rows a chunk: the quotient)."""
+        a model that holds every expert); a rank's share also
+        ``moe_visited_rows`` [routed layers], the positions of the share's
+        buffer that its row passes walked (``moe.layer.held_rows_visited``:
+        over the buffer's rows the share of it that costs time); a router
+        with a selection bias also ``moe_expert_weight`` [routed layers, E]
+        float32, the sum of the weights of each expert's token-choices; a
+        chunked loss ``loss_chunks``, the trips of its scan, and
+        ``loss_rows``, the rows they held, pad rows too, on the device that
+        scanned them (rows a chunk: the quotient)."""
         import jax.numpy as jnp
 
         ids = batch["input_ids"]
@@ -2157,6 +2172,8 @@ class Transformer:
             stats["moe_overflow_rows"] = routed["overflow_rows"]
             if "expert_weight" in routed:
                 stats["moe_expert_weight"] = routed["expert_weight"]
+            if "visited_rows" in routed:
+                stats["moe_visited_rows"] = routed["visited_rows"]
             if cfg.moe_aux == "all_choices":
                 # HF load_balancing_loss_func: the router probabilities and
                 # choices of ALL layers concatenated over tokens, so both

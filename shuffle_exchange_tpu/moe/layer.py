@@ -173,12 +173,57 @@ def _rows_or_zeros(x, index):
 # Rows of the token-ordered buffer one banded product sums at a time
 # (_run_sums). On a v5e 128 to 512 read the same to 5% (PERF.md section 6, PR 38).
 _RUN_BLOCK = 256
+# Positions of the buffer one trip of a row pass moves (_held_blocks): a
+# multiple of _RUN_BLOCK. A pass costs the blocks up to ``fit``, so a smaller
+# block wastes fewer empty rows of the last one and pays more trips
+# (PERF.md section 6, PR 44: the sizes tried on a v5e).
+_ROW_BLOCK = 2048
 
 
 def _run_halo(k: int) -> int:
     """Rows of the next block a block's product reads too: a token's run of at
     most k rows that starts in a block ends within k - 1 rows of the next."""
     return -(-(k - 1) // 8) * 8
+
+
+def _blocks_below(filled, extent: int, block: int):
+    """How a row pass walks [0, ``extent``) up to ``filled`` (traced): (items a
+    block, blocks that start below ``filled``)."""
+    import jax.numpy as jnp
+
+    block = max(1, min(block, extent))
+    return block, -(-jnp.asarray(filled, jnp.int32) // block)
+
+
+def _held_blocks(filled, extent: int, block: int, body, init):
+    """``body(start, size, carry)`` over the blocks of ``size`` items of [0,
+    ``extent``) that start below ``filled`` (traced), first to last: the trip
+    count is read from the input, so the blocks past what the buffer holds
+    cost nothing and ``init`` (zeros) is what the carry keeps there. The last
+    block of an extent that is no whole number of blocks starts early and
+    overlaps its neighbour, which therefore gets the same values twice (every
+    body here writes a pure function of the position). Only ever called
+    inside the hand-written rules of a ``custom_vjp``: a loop of traced length
+    has no transpose."""
+    import jax
+    import jax.numpy as jnp
+
+    size, trips = _blocks_below(filled, extent, block)
+
+    def trip(i, carry):
+        return body(jnp.minimum(i * size, extent - size), size, carry)
+
+    return jax.lax.fori_loop(0, trips, trip, init)
+
+
+def held_rows_visited(fit, buffer_rows: int):
+    """Positions of a share's buffer its row passes visit: the blocks of
+    _ROW_BLOCK that start below ``fit``, whole; over ``buffer_rows`` the share
+    of the buffer that costs time."""
+    import jax.numpy as jnp
+
+    size, trips = _blocks_below(fit, buffer_rows, _ROW_BLOCK)
+    return jnp.minimum(trips * size, buffer_rows)
 
 
 def _held_runs(order, inverse, fit):
@@ -189,12 +234,14 @@ def _held_runs(order, inverse, fit):
     nothing); ``inverse`` [S, k]: the position of each token-choice (R: none);
     ``fit``: positions that hold something. Sorting the R positions by their
     token-choice makes a token's held choices ONE contiguous run of at most k
-    rows. Returns ``runs`` [blocks, B + H] int32, the positions in that order
-    cut into blocks of B rows, each with the first H rows of the next (R where
-    there is no position: a row of zeros), and ``read`` [S]: the row of the
-    run sums (:func:`_run_sums`) where token s's run starts; for a token with
-    no held choice a row whose run is empty. One block more than the positions
-    fill, so that such a row exists."""
+    rows, and the positions that hold nothing sort last: the first ``fit`` rows
+    of the token order hold something too. Returns ``runs`` [blocks, B + H]
+    int32, the positions in that order cut into blocks of B rows, each with
+    the first H rows of the next (R where there is no position: a row of
+    zeros), and ``read`` [S]: the row of the run sums (:func:`_run_sums`)
+    where token s's run starts; for a token with no held choice a row whose
+    run is empty. One block more than the positions fill, so that such a row
+    exists."""
     import jax.numpy as jnp
 
     R = order.shape[0]
@@ -212,18 +259,23 @@ def _held_runs(order, inverse, fit):
     return runs, read
 
 
-def _run_sums(rows, runs, read, order, k: int, weights=None):
+def _run_sums(rows, runs, read, order, k: int, fit, weights=None):
     """out[s] = the sum of ``rows`` [R, M] over the positions of token s's held
     choices, each times its choice's weight where ``weights`` [S * k] is given:
-    [S, M]. ``runs``, ``read``: :func:`_held_runs`.
+    [S, M]. ``runs``, ``read``: :func:`_held_runs`; ``fit``: the positions
+    that hold something, the first ``fit`` rows of the token order.
 
-    R row lookups bring the rows into token order (R x (1 + H / B), with the
-    halo), a banded 0/1 (or weight) matrix a block sums every run from each of
-    its rows on in ONE product (float32 accumulation, rounded once; bf16
-    operands are exact in it), and S lookups read each token's sum where its
-    run starts. On a v5e at R 30,720, S 16,384, width 2048, k 10: 2.1-2.4 ms
-    against 7.6 for k lookups a token; a k-row window a token 35-42, log2(k)
-    shifted adds 6-10, k shifted adds 7-13 (PERF.md section 6, PR 38)."""
+    Row lookups bring the rows into token order, a banded 0/1 (or weight)
+    matrix a block sums every run from each of its rows on in ONE product
+    (float32 accumulation, rounded once; bf16 operands are exact in it), and S
+    lookups read each token's sum where its run starts. The lookups into token
+    order and the products walk the run blocks below ``fit`` only, _ROW_BLOCK
+    rows a trip (:func:`_held_blocks`): ``fit`` x (1 + H / B) rows with the
+    halo, up to a whole trip, not R x (1 + H / B); the sums past them are
+    zeros nobody reads but a token with no held choice. On a v5e at R 30,720
+    ALL visited, S 16,384, width 2048, k 10: 2.1-2.4 ms against 7.6 for k
+    lookups a token; a k-row window a token 35-42, log2(k) shifted adds 6-10,
+    k shifted adds 7-13 (PERF.md section 6, PR 38)."""
     import jax
     import jax.numpy as jnp
 
@@ -231,84 +283,115 @@ def _run_sums(rows, runs, read, order, k: int, weights=None):
     B = BH - _run_halo(k)
     M = rows.shape[1]
     dtype = rows.dtype
-    # S * k where there is no position, as ``order`` has it: "token" S, weight 0
-    choice = jnp.take(order, runs, mode="fill", fill_value=read.shape[0] * k)
-    token = choice // k
-    # band[b, i, j]: row j of block b (and its halo) holds the token of row i, at or after it
-    band = ((token[:, :B, None] == token[:, None, :])
-            & (jnp.arange(B)[:, None] <= jnp.arange(BH)[None, :]))
-    if weights is None:
-        band = band.astype(dtype)
-    else:
-        w = jnp.take(weights, choice, mode="fill", fill_value=0)
-        band = jnp.where(band, w[:, None, :], 0).astype(dtype)
-    # what the grouped GEMM leaves at a position that holds nothing is not
-    # zeros: those rows are read as zeros, not multiplied by zero
-    ordered = _rows_or_zeros(rows, runs.reshape(-1)).reshape(nb, BH, M)
-    sums = jnp.einsum(
-        "bij,bjm->bim", band, ordered, preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None)
-    return jnp.take(sums.astype(dtype).reshape(nb * B, M), read, axis=0, mode="clip")
+    ahead = jnp.arange(B)[:, None] <= jnp.arange(BH)[None, :]
+
+    def some_blocks(start, size, sums):
+        run = jax.lax.dynamic_slice_in_dim(runs, start, size)
+        # S * k where there is no position, as ``order`` has it: "token" S, weight 0
+        choice = jnp.take(order, run, mode="fill", fill_value=read.shape[0] * k)
+        token = choice // k
+        # band[b, i, j]: row j of block b (and its halo) holds the token of row i, at or after it
+        band = (token[:, :B, None] == token[:, None, :]) & ahead
+        if weights is None:
+            band = band.astype(dtype)
+        else:
+            w = jnp.take(weights, choice, mode="fill", fill_value=0)
+            band = jnp.where(band, w[:, None, :], 0).astype(dtype)
+        # what the grouped GEMM leaves at a position that holds nothing is not
+        # zeros: those rows are read as zeros, not multiplied by zero
+        ordered = _rows_or_zeros(rows, run.reshape(-1)).reshape(run.shape + (M,))
+        part = jnp.einsum(
+            "bij,bjm->bim", band, ordered, preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None)
+        return jax.lax.dynamic_update_slice_in_dim(sums, part.astype(dtype), start, axis=0)
+
+    sums = _held_blocks(-(-jnp.asarray(fit, jnp.int32) // B), nb, _ROW_BLOCK // B,
+                        some_blocks, jnp.zeros((nb, B, M), dtype))
+    return jnp.take(sums.reshape(nb * B, M), read, axis=0, mode="clip")
 
 
-def _held_dispatch(xs, order, k: int, runs, read):
+def _held_dispatch(xs, order, k: int, fit, runs, read):
     """One rank's share, the way in: xs [S, M] -> [R, M], row ``order[r] // k``
     of xs at position r (zeros where ``order[r]`` is S * k: the position holds
-    nothing). The forward looks up R rows. The backward sums the gradient rows
-    of a token's held choices over the token-ordered positions
-    (:func:`_run_sums`: R + S lookups), never k lookups a token."""
+    nothing). The forward looks up the rows of the position blocks below
+    ``fit`` (:func:`_held_blocks`); past them the result is the zeros it
+    started as. The backward sums the gradient rows of a token's held choices
+    over the token-ordered positions (:func:`_run_sums`: the visited rows + S
+    lookups), never k lookups a token."""
     import jax
+    import jax.numpy as jnp
+
+    R = order.shape[0]
 
     @jax.custom_vjp
-    def dispatch(xs, order, runs, read):
-        return _rows_or_zeros(xs, order // k)
+    def dispatch(xs, order, fit, runs, read):
+        def block(start, size, out):
+            index = jax.lax.dynamic_slice_in_dim(order, start, size) // k
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, _rows_or_zeros(xs, index), start, axis=0)
 
-    def fwd(xs, order, runs, read):
-        return dispatch(xs, order, runs, read), (order, runs, read)
+        return _held_blocks(fit, R, _ROW_BLOCK, block, jnp.zeros((R,) + xs.shape[1:], xs.dtype))
+
+    def fwd(xs, order, fit, runs, read):
+        return dispatch(xs, order, fit, runs, read), (order, fit, runs, read)
 
     def bwd(res, g):
-        order, runs, read = res
-        return _run_sums(g, runs, read, order, k), None, None, None
+        order, fit, runs, read = res
+        return _run_sums(g, runs, read, order, k, fit), None, None, None, None
 
     dispatch.defvjp(fwd, bwd)
-    return dispatch(xs, order, runs, read)
+    return dispatch(xs, order, fit, runs, read)
 
 
-def _held_combine(out_sorted, weights, order, inverse, runs, read):
+def _held_combine(out_sorted, weights, order, inverse, fit, runs, read):
     """One rank's share, the way back: out[s] = sum over the token's k choices
     of ``weights[s, j]`` x row ``inverse[s, j]`` of out_sorted [R, M] (nothing
     for a choice with no position), summed over the token-ordered positions
-    (:func:`_run_sums`: R + S row lookups). The backward looks up R rows of the
-    cotangent ONCE (row ``order[r] // k``): scaled by the position's weight
-    they are out_sorted's gradient, and their float32 dot with the position's
-    own row of out_sorted is that choice's weight gradient, which goes to
-    [S, k] as a lookup of R scalars (zero where a choice has no position)."""
+    (:func:`_run_sums`: the visited rows + S row lookups). The backward looks
+    up the rows of the cotangent ONCE a position of the blocks below ``fit``
+    (row ``order[r] // k``; :func:`_held_blocks`): scaled by the position's
+    weight they are out_sorted's gradient (zeros past the visited blocks), and
+    their float32 dot with the position's own row of out_sorted is that
+    choice's weight gradient, which goes to [S, k] as a lookup of R scalars
+    (zero where a choice has no position)."""
     import jax
     import jax.numpy as jnp
 
     k = inverse.shape[1]
+    R, M = out_sorted.shape
     dtype = out_sorted.dtype
 
     @jax.custom_vjp
-    def combine(out_sorted, weights, order, inverse, runs, read):
-        return _run_sums(out_sorted, runs, read, order, k, weights.reshape(-1))
+    def combine(out_sorted, weights, order, inverse, fit, runs, read):
+        return _run_sums(out_sorted, runs, read, order, k, fit, weights.reshape(-1))
 
-    def fwd(out_sorted, weights, order, inverse, runs, read):
-        return (combine(out_sorted, weights, order, inverse, runs, read),
-                (out_sorted, weights, order, inverse))
+    def fwd(out_sorted, weights, order, inverse, fit, runs, read):
+        return (combine(out_sorted, weights, order, inverse, fit, runs, read),
+                (out_sorted, weights, order, inverse, fit))
 
     def bwd(res, g):
-        out_sorted, weights, order, inverse = res
-        # a position that holds nothing reads weight 0 and a row of zeros
-        w_sorted = jnp.take(weights.reshape(-1), order, mode="fill", fill_value=0)
-        g_rows = _rows_or_zeros(g, order // k)
-        d_sorted = w_sorted[:, None].astype(dtype) * g_rows
-        d_w_sorted = jnp.sum(g_rows.astype(jnp.float32) * out_sorted.astype(jnp.float32), axis=-1)
+        out_sorted, weights, order, inverse, fit = res
+
+        def block(start, size, grads):
+            d_sorted, d_w_sorted = grads
+            choice = jax.lax.dynamic_slice_in_dim(order, start, size)
+            # a position that holds nothing reads weight 0 and a row of zeros
+            w = jnp.take(weights.reshape(-1), choice, mode="fill", fill_value=0)
+            g_rows = _rows_or_zeros(g, choice // k)
+            own = jax.lax.dynamic_slice_in_dim(out_sorted, start, size)
+            d_w = jnp.sum(g_rows.astype(jnp.float32) * own.astype(jnp.float32), axis=-1)
+            return (jax.lax.dynamic_update_slice_in_dim(
+                        d_sorted, w[:, None].astype(dtype) * g_rows, start, axis=0),
+                    jax.lax.dynamic_update_slice_in_dim(d_w_sorted, d_w, start, axis=0))
+
+        d_sorted, d_w_sorted = _held_blocks(
+            fit, R, _ROW_BLOCK, block,
+            (jnp.zeros((R, M), dtype), jnp.zeros((R,), jnp.float32)))
         d_weights = jnp.take(d_w_sorted, inverse, mode="fill", fill_value=0)
-        return d_sorted, d_weights.astype(weights.dtype), None, None, None, None
+        return d_sorted, d_weights.astype(weights.dtype), None, None, None, None, None
 
     combine.defvjp(fwd, bwd)
-    return combine(out_sorted, weights, order, inverse, runs, read)
+    return combine(out_sorted, weights, order, inverse, fit, runs, read)
 
 
 def held_buffer_rows(tokens: int, k: int, held: int, n_experts: int,
@@ -341,13 +424,19 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu",
     past the buffer are DROPPED, last experts first, and counted. There is
     no exchange: a rank alone computes its own part of the layer's result.
 
-    The share moves the rows it holds, R = ``buffer_rows`` positions or S
-    tokens a pass, never k lookups a token: of rows of width M, the way in R
-    (and R again where the layer is replayed); the way back R (1.06 R with the
-    runs' halo) into token order + S; backward R for the combine (one lookup
-    serves ``out_sorted``'s gradient and the weights') and R + S for the
-    dispatch: 5 R + 2 S where the parent's per-choice form looked up
-    3 R + 3 k S (:func:`_held_runs`, :func:`_run_sums`).
+    The share moves the rows it HOLDS, never k lookups a token and never the
+    buffer's empty tail: the held rows sort into the prefix [0, ``fit``) of
+    the R = ``buffer_rows`` positions (and of their token order), and every
+    row pass walks the blocks of _ROW_BLOCK positions that start below ``fit``
+    (:func:`_held_blocks`; V = :func:`held_rows_visited`, ``fit`` up to a whole
+    block, R when the buffer overflows). Of rows of width M: the way in V (and
+    V again where the layer is replayed); the way back V (1.06 V with the
+    runs' halo) into token order + S; backward V for the combine (one lookup
+    serves ``out_sorted``'s gradient and the weights') and V + S for the
+    dispatch: 5 V + 2 S, and a write of R rows of zeros a pass for what the
+    passes skip, where PR 38's form looked up 5 R + 2 S and its parent's
+    per-choice form 3 R + 3 k S (:func:`_held_runs`, :func:`_run_sums`). The
+    buffer's factor buys room in memory, not time in the passes.
     """
     import jax
     import jax.numpy as jnp
@@ -382,7 +471,7 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu",
             order = jnp.where(jnp.arange(R) < fit, order[:R], S * k)
             inverse = jnp.where(inverse < fit, inverse, R).reshape(S, k)
             runs, read = _held_runs(order, inverse, fit)
-            xsort = _held_dispatch(xs, order, k, runs, read)         # [R, M]
+            xsort = _held_dispatch(xs, order, k, fit, runs, read)    # [R, M]
         else:
             xsort = _permuted_rows(xs, order, inverse, k)    # [S*k, M]
         # expert per row, for the bias epilogue (a position that holds
@@ -416,7 +505,7 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu",
         out_sorted = b("b_down", grouped_matmul(h, w("w_down"), group_sizes))
     with trace.scope("moe_combine"):
         if share:
-            return (_held_combine(out_sorted, topk_w, order, inverse, runs, read),
+            return (_held_combine(out_sorted, topk_w, order, inverse, fit, runs, read),
                     fit, held - fit)
         out_flat = _permuted_rows(out_sorted, inverse, order)   # unsort
         out = (out_flat.reshape(S, k, M) * topk_w[..., None].astype(dtype)).sum(axis=1)
@@ -487,7 +576,9 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
     the part of the layer's result that those give
     (:func:`expert_mlp_ragged`; "ragged" only: the capacity paths dispatch
     into slots of every expert). ``metadata`` then also carries ``held_rows``
-    (token-choices computed here) and ``overflow_rows`` (held rows dropped).
+    (token-choices computed here), ``overflow_rows`` (held rows dropped) and
+    ``visited_rows`` (buffer positions the row passes walked:
+    :func:`held_rows_visited`).
 
     ``aux``: which balancing loss ``aux_loss`` is (``gating.topk_select``).
     ``score`` ("softmax" | "sigmoid"), ``select_bias`` [E] (selects, is not
@@ -588,6 +679,7 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
             expert_first=expert_first, buffer_rows=buffer_rows)
         if buffer_rows is not None:
             meta.update(held_rows=rows, overflow_rows=dropped,
+                        visited_rows=held_rows_visited(rows, buffer_rows),
                         drop_fraction=dropped / (S * k), capacity=buffer_rows)
         return MoEResult(out.reshape(orig_shape), aux_loss, meta)
 

@@ -2441,10 +2441,11 @@ class Engine:
         [routed layers], those the experts held here computed (all of them
         unless the model is one expert-parallel rank's share,
         ``n_experts_held``), ``moe_overflow_rows`` [routed layers], held rows
-        that did not fit the share's buffer and were dropped, and of a router
-        with a selection bias ``moe_expert_weight`` [routed layers, E]
-        float32, the summed weights of each expert's token-choices; a chunked
-        loss's ``loss_chunks`` and
+        that did not fit the share's buffer and were dropped, of such a share
+        ``moe_visited_rows`` [routed layers], the buffer positions its row
+        passes walked, and of a router with a selection bias
+        ``moe_expert_weight`` [routed layers, E] float32, the summed weights
+        of each expert's token-choices; a chunked loss's ``loss_chunks`` and
         ``loss_rows`` (the scan's trips and the rows they held on one device,
         summed over the step's microbatches). {} before the first step and
         for models that report nothing."""
