@@ -18,7 +18,7 @@ python -m shuffle_exchange_tpu.analysis shuffle_exchange_tpu/ "$@"
 
 if command -v ruff >/dev/null 2>&1; then
     echo "== ruff (baseline: ruff.toml) =="
-    ruff check shuffle_exchange_tpu/ tests/ scripts/ bench.py
+    ruff check shuffle_exchange_tpu/ tests/ scripts/
 else
     echo "== ruff not installed; skipping the baseline lint (config: ruff.toml) =="
 fi

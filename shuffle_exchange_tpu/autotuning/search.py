@@ -226,7 +226,7 @@ class SuccessiveHalving:
 
 
 # ---------------------------------------------------------------------------
-# The serving-search driver (bench row + scripts/autotune_serving.py)
+# The serving-search driver (scripts/autotune_serving.py)
 # ---------------------------------------------------------------------------
 
 
@@ -250,7 +250,7 @@ def default_serving_axes(icfg) -> Dict[str, list]:
 
 @dataclasses.dataclass
 class ServingSearchOutcome:
-    """Everything the bench row / CLI publishes: the search result, the
+    """Everything the CLI publishes: the search result, the
     default-config baseline measured on the SAME full-fidelity paired
     trace, and the trace itself."""
 
@@ -275,11 +275,10 @@ class ServingSearchOutcome:
 
     def knob_effects(self) -> Dict[str, Dict[str, float]]:
         """Best SCREENING-round metric per knob value, per searched axis
-        — the knob ranking BASELINE.md records (which lever moved
-        goodput, and by how much). Round 0 is the one round where EVERY
-        measured candidate faced the same trace prefix, so these numbers
-        are like-for-like; mixing in finals metrics would compare
-        goodput across different trace lengths."""
+        (which lever moved goodput, and by how much). Round 0 is the one
+        round where EVERY measured candidate faced the same trace prefix,
+        so these numbers are like-for-like; mixing in finals metrics would
+        compare goodput across different trace lengths."""
         by_cand: Dict[str, float] = {}
         for t in self.result.trials:
             if t.status == "ok" and t.metric is not None and t.round == 0:
@@ -465,7 +464,7 @@ def run_serving_search(model, params, icfg, *, trace: PoissonTrace,
 
     # the baseline at full fidelity: if the default survived to the
     # finals its trial already exists — reuse it (in-memory first, so
-    # journal-less bench runs do not re-serve the full trace; then the
+    # journal-less runs do not re-serve the full trace; then the
     # journal for resumed runs); only a default screened out early pays
     # a fresh measurement
     base_key = f"{key_ns}{default_cand.name}@r{rounds - 1}n{len(trace)}"

@@ -1,23 +1,19 @@
 """Seeded, paired Poisson request traces for serving measurement.
 
-Every serving perf number this repo publishes — the bench config-5
-``serving_*`` rows and every autotuner trial — scores a scheduler against
-a Poisson arrival trace. Candidate comparisons are only meaningful when
-the candidates face the SAME trace: same prompts, same arrival offsets,
+Every autotuner trial (``scripts/autotune_serving.py``) scores a scheduler
+against a Poisson arrival trace. Candidate comparisons are only meaningful
+when the candidates face the SAME trace: same prompts, same arrival offsets,
 same per-request token budgets. This module makes that pairing explicit:
 a :class:`PoissonTrace` is generated from one RNG seed, carries its seed
 in every serialization, and every derived view (``head`` screening
 subsets, ``with_load`` arrival calibration) is a pure function of the
 parent — so two processes holding the same seed measure against
 bit-identical workloads (the variance-control half of the ISSUE 14
-successive-halving design, and the reproducibility half of the bench
-rows' ``trace`` field).
+successive-halving design).
 
-Arrival offsets reproduce the bench rows' historical construction
-exactly (``np.cumsum(rng.exponential(span / n, size=n))`` — a Poisson
-process whose EXPECTED span offers ``load``× the measured capacity), so
-routing the rows through :func:`poisson_arrivals` changed no published
-number.
+Arrival offsets are ``np.cumsum(rng.exponential(span / n, size=n))`` — a
+Poisson process whose EXPECTED span offers ``load``× the measured capacity
+(:func:`poisson_arrivals`).
 """
 
 from __future__ import annotations
@@ -32,10 +28,7 @@ __all__ = ["PoissonTrace", "poisson_arrivals"]
 
 def poisson_arrivals(rng: np.random.Generator, n: int, span: float) -> List[float]:
     """Cumulative Poisson-process arrival offsets: ``n`` exponential
-    interarrivals with mean ``span / n`` (expected total span ``span``).
-    The bench rows' historical construction, extracted verbatim so the
-    autotuner's paired traces and the published rows draw from one
-    implementation."""
+    interarrivals with mean ``span / n`` (expected total span ``span``)."""
     if n < 1:
         raise ValueError(f"need n >= 1 arrivals, got {n}")
     if span < 0:
@@ -65,9 +58,9 @@ class PoissonTrace:
                  prompt_lo: int, prompt_hi: int, max_new: int,
                  period: Optional[int] = None) -> "PoissonTrace":
         """Random-token prompts with lengths uniform in [prompt_lo,
-        prompt_hi] (the bench rows' construction). ``period`` makes the
-        prompts cycle every ``period`` tokens — the repetitive-suffix
-        regime the speculative row measures in."""
+        prompt_hi]. ``period`` makes the prompts cycle every ``period``
+        tokens — the repetitive-suffix regime prompt-lookup speculation
+        drafts in."""
         if not 1 <= prompt_lo <= prompt_hi:
             raise ValueError(
                 f"need 1 <= prompt_lo <= prompt_hi, got [{prompt_lo}, {prompt_hi}]")
@@ -131,9 +124,9 @@ class PoissonTrace:
         return list(self.arrivals) if self.arrivals is not None else None
 
     def describe(self) -> dict:
-        """Machine-readable trace record for bench rows / trial logs —
-        enough to reproduce the exact workload (seed + shape) and to
-        audit the offsets actually offered."""
+        """Machine-readable trace record for trial logs — enough to
+        reproduce the exact workload (seed + shape) and to audit the
+        offsets actually offered."""
         return {
             "seed": self.seed,
             "n_requests": len(self.prompts),

@@ -1,7 +1,7 @@
 """Paged multi-tenant LoRA adapter pool (ISSUE 18).
 
-ROADMAP item 4's "millions of users" means per-tenant fine-tunes, and the
-hybrid-engine answer — fuse ONE adapter into the base weights
+Serving many tenants means per-tenant fine-tunes, and the hybrid-engine
+answer — fuse ONE adapter into the base weights
 (``linear/optimized_linear.py``, SURVEY §2.3) — serializes the fleet per
 tenant. This module is the S-LoRA/Punica-shaped alternative: a fixed-slot
 HBM pool of rank-padded LoRA factor pairs that a mixed-adapter batch

@@ -159,8 +159,8 @@ class InferenceEngineV2(InferenceEngine):
                                          kv_cache_dtype=cfg.kv_cache_dtype)
         self.allocator = BlockedAllocator(cfg.num_kv_blocks)
         # prefix-cache observability (the scheduler's prefix_cache/* group
-        # and bench's hit-rate read these; cow_copies also counts fork
-        # divergence with prefix_caching off)
+        # reads these; cow_copies also counts fork divergence with
+        # prefix_caching off)
         self.prefix_hit_tokens = 0
         self.prefix_miss_tokens = 0
         self.cow_copies = 0
@@ -196,9 +196,6 @@ class InferenceEngineV2(InferenceEngine):
         # ladder's footprint. Serving tests assert this stays bounded by
         # the ladder while ticks grow unbounded.
         self._program_keys: set = set()
-        # table width of the most recent decode dispatch (bench.py uses it
-        # to count the KV bytes the kernels actually stream)
-        self._last_decode_table_width = self._max_blocks
         # versioned serving weights (ISSUE 11): the RLHF train->serve flip
         # stamps every publication so rollout replay logs can name the
         # exact weights a token was sampled under. ``_staged_weights``
@@ -741,17 +738,15 @@ class InferenceEngineV2(InferenceEngine):
                            apool=None, aslots=None):
         """tok [B], pos [B] (next slot), btables [B, max_blocks].
 
-        Cache structure note (round 5, all three measured on-chip): this
-        xs/ys layer scan rewrites the KV pool into stacked outputs every
-        token (~22% of decode device time in the trace), yet it is the
-        FASTEST of the structures tried — an unrolled layer loop with
-        per-layer carry buffers measured 6-15% slower, and carrying the
-        stacked pool through the scan with the pooled Pallas kernel
-        (``paged_decode_attention(..., layer=i)``) measured 2x slower
-        (XLA double-buffers a carry that is both a custom-call input and
-        scatter-updated in the same iteration). Details in ROUND5_NOTES.
+        Cache structure: the layers run as one ``lax.scan`` that takes the
+        stacked weights and the KV pool as ``xs`` and restacks the updated
+        pool as ``ys``, so one layer body is traced whatever the depth and
+        the pool is never both a scan carry and a kernel's aliased input.
+        The price is that every token rewrites the pool into the stacked
+        outputs; no record compares this with a carried or a per-layer
+        pool at real widths (PERF.md section 7: no serving cell yet).
 
-        Round 6: with ``decode_kernel`` resolved to "pallas" each layer
+        With ``decode_kernel`` resolved to "pallas" each layer
         runs the FUSED path (``_fused_paged_layer``): one kernel for
         QKV+RoPE+pool-append (``input_output_aliases`` on the layer's pool
         slice — the scatter that used to be an XLA whole-slice update is an
@@ -1282,9 +1277,7 @@ class InferenceEngineV2(InferenceEngine):
         max_seq_len//block). Serving paths bin the width to the smallest
         power of two covering the batch's allocated blocks: the decode
         kernels stream EVERY table entry's block through VMEM, padding
-        included, so table width is directly per-step HBM read traffic
-        (the r5 engine_decode_sweep "hbm_util falls with batch" artifact —
-        see BASELINE.md)."""
+        included, so table width is directly per-step HBM read traffic."""
         width = self._max_blocks if width is None else width
         assert len(desc.blocks) <= width, (desc.uid, len(desc.blocks), width)
         t = np.full((width,), self._scratch, dtype=np.int32)
@@ -1308,7 +1301,6 @@ class InferenceEngineV2(InferenceEngine):
         for i, (d, t) in enumerate(zip(descs, toks)):
             tok[i], pos[i] = t, d.seen_tokens
             tables[i] = self._table(d, W)
-        self._last_decode_table_width = W
         return B, W, tok, pos, tables
 
     def _pack_chunks(self, batch: List[Tuple[SequenceDescriptor, List[int]]],
@@ -1335,9 +1327,8 @@ class InferenceEngineV2(InferenceEngine):
 
     def _pack_prefill(self, prefills: List[Tuple[SequenceDescriptor, List[int]]]):
         """(P, tpad, ids, plen, btables) for the batched flash-prefill
-        program — shared by put() and bench.py's one-dispatch compiled-
-        prefill measurement (the decode_loop discipline applied to
-        prefill). Allocates each descriptor's blocks."""
+        program that put() dispatches. Allocates each descriptor's
+        blocks."""
         bs = self.cache.block_size
         tmax = max(len(toks) for _, toks in prefills)
         tpad = max(bs, _bucket(tmax, minimum=bs))
@@ -2503,7 +2494,6 @@ class InferenceEngineV2(InferenceEngine):
         # loop can touch, rounded up a power of two to bound compiles
         W = self._binned_width(max(len(d.blocks) for d in descs))
         btables = np.stack([self._table(d, W) for d in descs]).astype(np.int32)
-        self._last_decode_table_width = W
         pos = np.asarray([d.seen_tokens for d in descs], np.int32)
         tok0 = np.asarray(tokens, np.int32)
         fn = self._decode_loop_fn((len(uids), int(n_steps)))

@@ -67,7 +67,7 @@ class HostKVTier:
     ``store`` / ``load`` / ``drop`` are the engine's spill/fetch halves;
     ``prefetch`` stages a uid's bytes into pinned buffers ahead of its
     fetch (a fetch that finds its staging ready is a *prefetch hit* —
-    the ``kv_tier/hit_rate`` the bench row publishes)."""
+    the scheduler's ``kv_tier/hit_rate``)."""
 
     _next_tier_id = itertools.count()
 
@@ -361,8 +361,8 @@ class HostKVTier:
     def reset_counters(self) -> None:
         """Zero the traffic counters (spills/fetches/prefetch hits and
         misses) without touching resident entries — a measurement epoch
-        (e.g. the bench row's measured pass after its warm pass) starts
-        from a clean count."""
+        (e.g. a measured pass after its warm pass) starts from a clean
+        count."""
         with self._mu:
             self.spills = self.fetches = self.prefetches = 0
             self.prefetch_hits = self.prefetch_misses = 0

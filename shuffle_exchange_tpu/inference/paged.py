@@ -385,8 +385,8 @@ class PagedKVCache(NamedTuple):
 
     def pool_nbytes(self) -> int:
         """Resident bytes of the KV pool including scale planes — the
-        figure the kv_cache_dtype modes halve (pool-size tests + the
-        BASELINE.md resident-batch arithmetic pin this)."""
+        figure the kv_cache_dtype modes halve (tests/test_kv_quant.py pins
+        the ratio)."""
         total = 0
         for x in self:
             if not isinstance(x, tuple):

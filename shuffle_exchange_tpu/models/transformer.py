@@ -345,7 +345,7 @@ class TransformerConfig:
 
 
 # ---------------------------------------------------------------------------
-# Presets (sizes match the reference's benchmark configs, BASELINE.md)
+# Presets (sizes match the capability configs BASELINE.json lists)
 # ---------------------------------------------------------------------------
 
 def gpt2_small() -> TransformerConfig:  # 125M — capability config #1

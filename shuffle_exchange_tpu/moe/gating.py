@@ -27,9 +27,9 @@ class GateOutput(NamedTuple):
 class GateCompact(NamedTuple):
     """Index-form capacity assignment (same semantics as GateOutput's dense
     masks, O(S·k) instead of O(S·E·C)): the dense dispatch/combine einsums
-    are one-hot MATMULS costing 2·S·E·C·M flops each — 4x the expert
-    compute itself at bench shapes — while gather/scatter dispatch moves
-    the same rows for free (round-5 on-chip profile)."""
+    are one-hot MATMULS costing 2·S·E·C·M flops each, against the expert
+    MLP's own 2·E·C·M·F a matrix, while gather/scatter dispatch moves the
+    same rows with no matmul at all."""
 
     eidx: "jax.Array"       # [S, k] i32  expert id per choice
     loc: "jax.Array"        # [S, k] i32  slot within the expert's buffer

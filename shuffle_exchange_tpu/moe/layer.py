@@ -561,8 +561,8 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
         inserts the all-to-all pair).
       - "capacity_einsum": the dense [S, E, C] one-hot einsum dispatch —
         identical semantics, kept as the parity oracle (the one-hot
-        matmuls cost 2·S·E·C·M flops each, ~4x the expert compute at
-        bench shapes — round-5 on-chip profile).
+        matmuls cost 2·S·E·C·M flops each, more than the expert compute
+        once S exceeds the expert width).
       - "ragged": dropless grouped-GEMM (``expert_mlp_ragged``) — no
         capacity padding FLOPs, no drops; the single-device/data-parallel
         path (reference cutlass moe_gemm).
@@ -686,7 +686,7 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
     if impl == "capacity_einsum":
         # the GShard dense-mask contract, kept as the parity oracle: the
         # one-hot dispatch/combine einsums are real matmuls costing
-        # 2·S·E·C·M flops EACH — ~4x the expert compute at bench shapes
+        # 2·S·E·C·M flops EACH (the expert matrices cost 2·E·C·M·F each)
         gate = topk_gating(logits, k=k, capacity_factor=capacity_factor, train=train,
                            rng=rng, noise_std=noise_std, min_capacity=min_capacity,
                            normalize_weights=normalize_weights, aux=aux)

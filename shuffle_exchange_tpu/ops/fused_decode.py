@@ -4,12 +4,10 @@ TPU replacement for the reference FastGen per-layer decode fusion
 (``inference/v2/kernels/ragged_ops/linear_blocked_kv_rotary`` +
 ``blocked_flash`` + the core-ops gated MLP, driven from
 ``model_implementations/llama_v2/model.py:133-175``, SURVEY.md §2.10/§2.13).
-Round-5 verification measured the XLA decode step at ~4 ms/token with HBM
-bandwidth utilization 0.18 against a weight-bandwidth-bound roofline
-(BASELINE.json ``engine_decode_sweep``); the layer body lowered to many
-small dispatches, each bouncing [B, D]-sized activations through HBM and
-re-reading weights per op. The three kernels here stream every weight
-matrix through VMEM exactly once per step:
+A one-token decode step is bound by the bytes of the weights it reads; left
+to XLA the layer body lowers to many small ops, each bouncing [B, D]-sized
+activations through HBM and re-reading weights per op. The three kernels
+here stream every weight matrix through VMEM exactly once per step:
 
   1. :func:`fused_qkv_rope` — QKV projection + bias + RoPE + (optionally)
      the paged-KV append, writing the new token's K/V straight into the

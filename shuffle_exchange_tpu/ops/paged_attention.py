@@ -56,15 +56,14 @@ def paged_decode_attention_pallas(q, ck, cv, block_table, kv_len, *,
     the per-layer [B,S,KV,Dh] cache gather the bias-free kernel forced —
     reference ds_attention.py:16 applies ALiBi in its fused softmax).
 
-    Stacked-pool mode (round 5): passing the full multi-layer pool plus a
+    Stacked-pool mode: passing the full multi-layer pool plus a
     scalar-prefetched ``layer`` index means the caller never slices the
     cache — the index map adds the layer offset and the kernel DMAs only
-    the pages the block table names. This is what lets the decode layer
-    loop carry ONE pool buffer and update it in place (a per-layer
-    ``cache.k[i]`` slice would read/write the whole layer pool each step;
-    the round-5 decode trace measured those copies at ~22% of device
-    time). Reference: blocked_flash reads the shared multi-layer pool the
-    same way (kv_cache.py:40).
+    the pages the block table names. A decode layer loop can then carry
+    ONE pool buffer and update it in place (a per-layer ``cache.k[i]``
+    slice reads and writes the whole layer pool each step). Reference:
+    blocked_flash reads the shared multi-layer pool the same way
+    (kv_cache.py:40).
     """
     import jax
     import jax.numpy as jnp

@@ -1,6 +1,6 @@
 """The generate->score->train driver: rollout records, replay log, losses.
 
-The workload class HybridEngine v2 exists for (ROADMAP item 2): RLHF-style
+The workload class HybridEngine v2 exists for: RLHF-style
 loops where one process alternates between fleet-served rollout generation
 and ZeRO training steps on the same weights. Two concrete trainers ride
 the EXISTING jitted train step (the engine's ``train_batch`` machinery is
@@ -11,7 +11,7 @@ reused verbatim — only the loss function differs, passed to
   log-probability of sampled rollout tokens weighted by their
   (advantage-normalized) reward. Online distillation is this loss with
   the teacher's preference as the reward — including distilling the
-  draft models the speculative decoder wants (ROADMAP item 1).
+  draft models the speculative decoder wants.
 - :func:`dpo_loss_fn` — Direct Preference Optimization over
   (chosen, rejected) pairs, with the frozen reference policy's sequence
   log-probs precomputed OUTSIDE the step (the reference policy never

@@ -15,9 +15,9 @@ stack — each replica is an ``InferenceEngineV2`` +
   - **pins sticky sessions**: a ``session_id``'s later turns return to the
     replica already holding that conversation's blocks (the multi-turn
     prefix-cache win), until that replica drains;
-  - **preserves the bench contract**: ``serve(requests, arrivals=...)`` is
+  - **keeps the scheduler's front**: ``serve(requests, arrivals=...)`` is
     the same Poisson-trace front the single-engine scheduler exposes, so
-    bench rows compare 1-replica and N-replica fleets on identical traces;
+    1-replica and N-replica fleets can be compared on identical traces;
   - **drains elastically**: ``drain(replica_id)`` stops admission on one
     replica, preempts its running sequences, and front-requeues every
     unfinished request on the surviving replicas — token-identical replay

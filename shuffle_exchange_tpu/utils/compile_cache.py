@@ -4,7 +4,7 @@ The directory is part of what a deployment decides, so it is placed from
 outside: where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
 this code sets no directory. Where it is not, the cache is
 ``<checkout>/.cache/jax`` - one fixed directory shared by the tests, the
-fleet workers, ``bench.py``, the scripts and ``chip_smoke.py``, never
+fleet workers, the scripts, ``chip_smoke.py`` and ``chipbench``, never
 derived from a temporary name, a pid or the time (the path is part of the
 cache key's lookup: a directory that moves never hits).
 """

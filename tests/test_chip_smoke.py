@@ -156,10 +156,9 @@ def test_compile_cache_is_placed_from_outside(monkeypatch):
 
 
 @pytest.mark.parametrize("entry", [
-    "bench.py", "__graft_entry__.py", "chip_smoke.py", "tests/conftest.py",
-    "scripts/profile_config1.py", "scripts/profile_config2.py",
-    "scripts/tune_config2.py", "chipbench/harness.py",
-    "scripts/chaos_drill.py", "shuffle_exchange_tpu/serving/worker.py"])
+    "__graft_entry__.py", "chip_smoke.py", "tests/conftest.py",
+    "chipbench/harness.py", "scripts/chaos_drill.py",
+    "shuffle_exchange_tpu/serving/worker.py"])
 def test_entry_points_leave_the_cache_directory_to_the_helper(entry):
     with open(os.path.join(_REPO, entry)) as f:
         src = f.read()

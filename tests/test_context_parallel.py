@@ -186,16 +186,32 @@ def test_ring_save_flash_lse_skips_forward_recompute(monkeypatch, devices8):
     assert saved < default, (saved, default)
 
 
+def _jaxpr_peak_var_bytes(jaxpr) -> int:
+    """Largest single array (bytes) among the variables of the jaxpr's
+    shard_map body, subjaxprs included: there the shapes are one chip's,
+    where the outer jaxpr's operands keep their global [B, T, ...] shapes
+    at every CP degree."""
+    def walk(jx):
+        yield jx
+        for sub in jax.core.subjaxprs(jx):
+            yield from walk(sub)
+
+    regions = [eqn.params["jaxpr"] for jx in walk(jaxpr.jaxpr)
+               for eqn in jx.eqns if eqn.primitive.name == "shard_map"]
+    assert regions, "no shard_map region in the jaxpr"
+    avals = (getattr(var, "aval", None)
+             for jx in walk(getattr(regions[0], "jaxpr", regions[0]))
+             for eqn in jx.eqns for var in (*eqn.outvars, *eqn.invars))
+    return max(int(np.prod(a.shape)) * a.dtype.itemsize for a in avals
+               if hasattr(a, "shape") and hasattr(a, "dtype"))
+
+
 def test_ring_attention_peak_memory_scales_inverse_with_cp(devices8):
     """The per-chip attention working set is O(seq/CP): the largest
     intermediate in the local ring region halves as the degree doubles
     (score tiles never materialize past the hop chunk)."""
-    import sys
-
     from jax.sharding import PartitionSpec as P
 
-    sys.path.insert(0, __file__.rsplit("/tests/", 1)[0])
-    from bench import _jaxpr_peak_var_bytes
     from shuffle_exchange_tpu.config.config import MeshConfig
     from shuffle_exchange_tpu.parallel.mesh import MeshTopology, shard_map
     from shuffle_exchange_tpu.parallel.sequence import ring_attention

@@ -8,8 +8,7 @@ Pinned here:
   - quantize/dequantize roundtrip error bounds (int8 rel ~1/127, fp8
     e4m3 rel ~2^-3) and the zero-row guard;
   - pool bytes: int8/fp8 pools are <= 0.55x the bf16 pool and <= 0.3x
-    the fp32 pool, scale planes included (the resident-batch arithmetic
-    in BASELINE.md builds on this);
+    the fp32 pool, scale planes included;
   - kernel parity: decode / extend / fused split-K kernels over a
     quantized pool match the gather-dequant oracle on the SAME stored
     bytes (interpret mode, float-epsilon);

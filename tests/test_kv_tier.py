@@ -23,7 +23,7 @@ from shuffle_exchange_tpu.testing.faults import InjectedFault
 
 @pytest.fixture(scope="module")
 def model_and_params():
-    # same fixture shape as test_disagg / test_bench_smoke — the compile
+    # same fixture shape as test_disagg — the compile
     # cache reuses the prefill/decode programs across these files
     cfg = tiny(vocab=97, d=32, layers=2, heads=4, seq=128,
                activation="swiglu", norm="rmsnorm", position="rope",

@@ -208,7 +208,7 @@ def test_fused_mlp_lowers(bits):
 
 
 @pytest.mark.parametrize("geom", [
-    # bench config-5 ladder entry the TPU box actually serves
+    # llama-style serving: GQA 12 over 3 KV heads at Dh=128, gated MLP
     dict(D=1536, H=12, KV=3, Dh=128, F=4096, bs=64, rope=True, bias=False,
          gated=True),
     # gpt2-style HF serving: Dh=64, MHA, biases, no rope

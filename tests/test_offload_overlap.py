@@ -122,6 +122,26 @@ def test_overlap_ordering_counters(devices8):
         assert mm.latest(label) is not None
 
 
+def test_overlap_composes_with_bf16_and_the_kept_flash_residuals(devices8):
+    """The cpu tier + ``offload_overlap`` under a bf16 step whose layers are
+    checkpointed with ``save_flash_lse``: the host optimizer and the
+    pipeline engage, two steps give finite losses and the joined step
+    reaches the monitor. The three options meet in no other test."""
+    model = Transformer(tiny(vocab=128, d=64, layers=2, heads=4, seq=32,
+                             position="rope", remat=True,
+                             remat_policy="save_flash_lse"))
+    cfg = dict(_config(device="cpu", offload_overlap=True),
+               bf16={"enabled": True})
+    reset_topology()
+    eng, *_ = sxt.initialize(model=model, config=cfg)
+    assert eng._host_opt is not None, "host-resident optimizer not engaged"
+    assert eng._host_pipeline is not None, "overlap pipeline not engaged"
+    losses = [float(eng.train_batch(_batch(seed))) for seed in (0, 1)]
+    assert np.all(np.isfinite(losses)), losses
+    eng.module_weights()    # joins the in-flight overlapped step
+    assert eng.monitor.memory_monitor.latest("offload/overlap_steps") >= 1
+
+
 def test_overlap_checkpoint_roundtrip(tmp_path, devices8):
     """save -> train -> load -> retrain reproduces the trajectory (the save
     joins the in-flight step first — never a half-applied checkpoint)."""

@@ -212,6 +212,35 @@ class TestScheduling:
         subs = sorted(r.submitted_at for r in sched.requests.values())
         assert subs[2] - subs[0] >= 0.04
 
+    def test_poisson_trace_at_twice_capacity_keeps_parity_and_goodput(
+            self, model_and_params):
+        """A seeded Poisson trace offered at 2x the capacity an all-at-once
+        pass measured: every request's tokens are the sequential
+        reference's and ``stats()`` reports the goodput group."""
+        from shuffle_exchange_tpu.autotuning import PoissonTrace
+
+        model, params = model_and_params
+        trace = PoissonTrace.generate(11, vocab=90, n_requests=6,
+                                      prompt_lo=4, prompt_hi=20, max_new=5)
+        prompts = trace.prompt_lists()
+        want = [_reference(model, params, p, trace.max_new) for p in prompts]
+        eng = InferenceEngineV2(model, params, _icfg())
+        first = ContinuousBatchingScheduler(eng)
+        first.serve(prompts, max_new_tokens=trace.max_new)
+        capacity = first.stats()["sustained_tokens_per_sec"]
+        assert capacity > 0
+        trace = trace.with_load(capacity, 2.0)
+        sched = ContinuousBatchingScheduler(eng)
+        out = sched.serve(prompts, max_new_tokens=trace.max_new,
+                          arrivals=trace.arrival_list())
+        assert [out[u] for u in out] == want
+        st = sched.stats()
+        assert st["requests"] == 6 and st["generated_tokens"] == 30
+        assert st["sustained_tokens_per_sec"] > 0
+        assert st["ttft_p95_s"] >= st["ttft_p50_s"] > 0 and st["tpot_p50_s"] > 0
+        fill = sched.memory_monitor.values("serving/budget_fill")
+        assert fill and all(0 < f <= 1 for f in fill)
+
 
 class TestAdmissionErrors:
     def test_put_kv_exhaustion_names_numbers(self, model_and_params):
