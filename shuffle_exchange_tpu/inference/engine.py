@@ -176,6 +176,18 @@ class InferenceEngine:
                 "the inference engines hold beside no KV block, and their "
                 "attention has no per-head q/k norm (training through "
                 "sxt.initialize is; ROADMAP R-M10)")
+        ffns = {ffn for _, ffn in getattr(self._mcfg, "kinds_used", ())}
+        if "ssm" in mixers or "none" in ffns:
+            raise NotImplementedError(
+                "serving a stack with Mamba-2 state-space layers (mixer 'ssm') "
+                "or layers that are a mixer alone (ffn 'none': layer_pattern, "
+                "Nemotron-H) is not implemented: a state-space layer carries "
+                "the last ssm_conv_kernel - 1 rows of its convolution's input "
+                "and a [head_dim, state] matrix a head as its state, which the "
+                "inference engines hold beside no KV block, they have no "
+                "one-token step of the scan, and they scan whole (mixer, ffn) "
+                "blocks of one kind (training through sxt.initialize is; "
+                "ROADMAP R-M11)")
         if getattr(self._mcfg, "recurrent", False) or len(
                 getattr(self._mcfg, "pattern", ((),))) > 1:
             # the cached paths scan ONE kind of layer over a KV cache: a

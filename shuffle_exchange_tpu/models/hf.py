@@ -56,6 +56,7 @@ _ARCH_FAMILIES = {
     "Qwen3NextForCausalLM": "qwen3next",
     "DeepseekV3ForCausalLM": "deepseekv3",
     "Lfm2MoeForCausalLM": "lfm2moe",
+    "NemotronHForCausalLM": "nemotronh",
 }
 
 
@@ -70,6 +71,7 @@ _MODEL_TYPE_FAMILIES = {"llama": "llama", "mistral": "llama", "qwen2": "qwen2",
                         "deepseek_v3": "deepseekv3",
                         "laguna": "laguna",
                         "lfm2_moe": "lfm2moe",
+                        "nemotron_h": "nemotronh",
                         "megatron": "megatron",
                         "megatron-gpt": "megatron", "megatron_gpt": "megatron"}
 
@@ -105,7 +107,8 @@ def _lead_and_period(kinds, family: str):
     them) of a stack given layer by layer as (mixer, ffn): the leading layers
     are the dense ones before the first routed one, all of one kind; the
     period is the shortest the rest repeats. A stack that ends part of the way
-    into its pattern (Laguna-XS.2's published 40 layers, LFM2-8B-A1B's 24) is
+    into its pattern (Laguna-XS.2's published 40 layers, LFM2-8B-A1B's 24,
+    Nemotron-3-Nano's 29 blocks of 52 half-layers) is
     ONE period of its whole length: it runs, unrolled, at a compile time that
     grows with the depth (ROADMAP R-M3)."""
     lead = next((i for i, (_, ffn) in enumerate(kinds) if ffn != "mlp"), len(kinds))
@@ -168,6 +171,118 @@ def _lfm2_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerConf
         # config.json: a key of this repository's, 0 (held fixed) without it
         moe_bias_update_rate=float(cfg.get("bias_update_speed", 0.0)),
         moe_impl="ragged", moe_aux="none", **common)
+
+
+def _nemotron_h_pairs(pattern: str):
+    """``hybrid_override_pattern`` -> [(mixer, ffn)]: each letter is ONE
+    pre-norm residual step, a mixer (``M`` Mamba-2, ``*`` attention) or a
+    feed-forward part (``E`` routed experts) alone; a mixer and the
+    feed-forward part right after it are this repository's (mixer, ffn) block,
+    a mixer followed by another mixer (or by nothing) a block whose ffn is
+    "none". Anything else is refused by name."""
+    mixers = {"M": "ssm", "*": "attn"}
+    pairs, i = [], 0
+    while i < len(pattern):
+        here, after = pattern[i], pattern[i + 1:i + 2]
+        if here == "-" or after == "-":
+            raise ValueError(
+                f"nemotron_h with '-' in hybrid_override_pattern (position "
+                f"{i if here == '-' else i + 1}: a DENSE feed-forward layer, the "
+                "family's other models') is not supported (written down: 'M', '*' and 'E')")
+        if here not in mixers:
+            if here == "E":
+                raise ValueError(
+                    f"nemotron_h: hybrid_override_pattern[{i}] is 'E' with no mixer "
+                    "before it (the first layer, or after another 'E'): a "
+                    "feed-forward part alone has no (mixer, ffn) block to be the ffn of")
+            raise ValueError(f"nemotron_h with hybrid_override_pattern[{i}]={here!r} is "
+                             "not supported (written down: 'M', '*' and 'E')")
+        if after == "E":
+            pairs.append((mixers[here], "moe"))
+            i += 2
+        else:
+            pairs.append((mixers[here], "none"))
+            i += 1
+    return pairs
+
+
+def _nemotron_h_config(cfg: Dict[str, Any]) -> TransformerConfig:
+    """NVIDIA's ``model_type: nemotron_h`` as Nemotron-3-Nano-30B-A3B ships
+    it: Mamba-2 state-space layers (``mamba_num_heads`` heads of
+    ``mamba_head_dim``, ``n_groups`` groups of ``ssm_state_size``,
+    ``conv_kernel`` taps with a bias; ``expand`` is not read: the inner width is
+    heads x head_dim), grouped-query attention that rotates NOTHING (position
+    "none": ``rope_theta`` and ``partial_rotary_factor`` are inert keys), and
+    routed feed-forward parts of UNGATED squared-ReLU experts
+    (``mlp_hidden_act`` relu2) under the DeepSeek-V3 family's router (sigmoid
+    scores, ``e_score_correction_bias`` selects and is not weighed, one group,
+    the chosen renormalised and scaled by ``routed_scaling_factor``,
+    dropless) plus ``n_shared_experts`` shared ones as ONE ungated MLP of
+    ``moe_shared_expert_intermediate_size``, added as it is; plain-gain
+    RMSNorms at ``layer_norm_epsilon``; an untied head. The first
+    ``num_hidden_layers`` letters of ``hybrid_override_pattern`` are read (a
+    cut in depth keeps the published pattern whole) and paired by
+    :func:`_nemotron_h_pairs`. Not the source's keys: ``num_experts_held`` /
+    ``expert_first`` / ``expert_buffer_factor`` as for qwen3_next,
+    ``bias_update_speed`` / ``aux_loss_alpha`` / ``seq_aux`` as for
+    deepseek_v3. What is not written here is refused by name."""
+    refused = {
+        "n_group": int(cfg.get("n_group") or 1) > 1 or int(cfg.get("topk_group") or 1) > 1,
+        "attention_bias": bool(cfg.get("attention_bias")),
+        "mlp_bias": bool(cfg.get("mlp_bias")),
+        "use_bias": bool(cfg.get("use_bias")),
+        "mamba_proj_bias": bool(cfg.get("mamba_proj_bias")),
+        "use_conv_bias": not cfg.get("use_conv_bias", True),
+        "mamba_hidden_act": cfg.get("mamba_hidden_act", "silu") != "silu",
+        "mlp_hidden_act": cfg.get("mlp_hidden_act", "relu2") != "relu2",
+        "sliding_window": cfg.get("sliding_window") is not None,
+        "tie_word_embeddings": bool(cfg.get("tie_word_embeddings", False)),
+    }
+    for key, bad in refused.items():
+        if bad:
+            raise ValueError(
+                f"nemotron_h with {key}={cfg.get(key)!r} (topk_group="
+                f"{cfg.get('topk_group')!r}) is not supported (written down: one "
+                "router group, no bias but the convolution's, silu in the "
+                "state-space layers, relu2 in the feed-forward parts, full "
+                "attention, an untied head)")
+    letters = str(cfg["hybrid_override_pattern"])
+    L = int(cfg["num_hidden_layers"])
+    if not 0 < L <= len(letters):
+        raise ValueError(f"nemotron_h: num_hidden_layers={L} of the {len(letters)} "
+                         "letters in hybrid_override_pattern")
+    kinds = _nemotron_h_pairs(letters[:L])
+    # the published 52 layers end part of the way into their pattern (five
+    # times MEMEM*E, then MEMEMEM*E and MEMEMEME): ONE unrolled period
+    lead, period = _lead_and_period(kinds, "nemotron_h")
+    alpha = float(cfg.get("aux_loss_alpha") or 0.0)
+    shared = int(cfg.get("n_shared_experts") or 0)
+    return TransformerConfig(
+        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
+        n_layers=len(kinds), n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg.get("num_key_value_heads"),
+        head_size=int(cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]),
+        d_ff=cfg["moe_intermediate_size"],
+        max_seq_len=cfg.get("max_position_embeddings", 4096),
+        activation="relu2", mlp_bias=False, norm="rmsnorm", position="none",
+        norm_eps=cfg.get("layer_norm_epsilon", cfg.get("norm_eps", 1e-5)),
+        tie_embeddings=False,
+        ssm_heads=cfg["mamba_num_heads"], ssm_head_dim=cfg["mamba_head_dim"],
+        ssm_groups=int(cfg.get("n_groups", 1)), ssm_state=cfg["ssm_state_size"],
+        ssm_conv_kernel=int(cfg.get("conv_kernel", 4)),
+        layer_pattern=period, lead_layers=lead, lead_kind=kinds[0] if lead else (),
+        dense_ff=cfg.get("intermediate_size") or 0,
+        n_experts=cfg["n_routed_experts"], **_held_share(cfg, "nemotron_h"),
+        moe_top_k=cfg["num_experts_per_tok"],
+        moe_norm_topk=bool(cfg.get("norm_topk_prob", True)),
+        moe_score="sigmoid", moe_select_bias=True,
+        moe_weight_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+        moe_bias_update_rate=float(cfg.get("bias_update_speed", 0.0)),
+        moe_shared_expert_ff=(int(cfg.get("moe_shared_expert_intermediate_size") or 0)
+                              if shared else 0),
+        moe_shared_gate="none", moe_impl="ragged",
+        moe_aux="sequence" if alpha and cfg.get("seq_aux", True) else "none",
+        aux_loss_coef=alpha)
 
 
 def _laguna_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerConfig:
@@ -275,6 +390,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
     cfg = hf_config if isinstance(hf_config, dict) else hf_config.to_dict()
     family = _family(cfg)
 
+    if family == "nemotronh":
+        return _nemotron_h_config(cfg)
     if family == "gpt2":
         return TransformerConfig(
             vocab_size=cfg["vocab_size"], d_model=cfg["n_embd"], n_layers=cfg["n_layer"],

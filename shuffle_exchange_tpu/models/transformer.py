@@ -43,7 +43,9 @@ class TransformerConfig:
     max_seq_len: int = 2048
     activation: str = "gelu"                   # "gelu" | "swiglu"
     norm: str = "layernorm"                    # "layernorm" | "rmsnorm"
-    position: str = "learned"                  # "learned" | "rope"
+    position: str = "learned"                  # "learned" | "rope" | "alibi" | "none" (no
+                                               # position signal at all: attention among
+                                               # mixers that carry the order, ``_gqa``)
     rope_theta: float = 500000.0
     tie_embeddings: bool = True
     dropout: float = 0.0
@@ -177,10 +179,20 @@ class TransformerConfig:
     #                       B * x, C * that, one projection back; no heads, no
     #                       RoPE, no state but the last taps - 1 rows
     #         "mla"         latent attention, mla_* below
+    #         "ssm"         the Mamba-2 state-space layer (ops/ssd.py), ssm_*
+    #                       below: one projection to [z | x B C | dt], a causal
+    #                       depthwise convolution with a bias and SiLU over
+    #                       x B C, the scan, a gated grouped RMSNorm, one
+    #                       projection back; no RoPE
     #   q/k norm: "attn" takes ``qk_norm`` (True = the whole projection, a
     #   one-kind model's; "head" = per head, among several kinds); "gated_attn"
-    #   norms per head always; "swa", "mla", "gdn" and "sconv" have none.
-    #   ffn   "mlp" | "moe"
+    #   norms per head always; "swa", "mla", "gdn", "sconv" and "ssm" have none.
+    #   rotation: among several kinds "attn" rotates by the model's table, or
+    #   by nothing where ``position`` is "none" (Nemotron-H: the state-space
+    #   layers carry the order).
+    #   ffn   "mlp" | "moe" | "none": a layer that is a mixer ALONE (one norm,
+    #   one residual step, no ffn leaves: Nemotron-H's ``M*``, a state-space
+    #   layer followed at once by attention)
     # ``attention_pattern`` (GPT-Neo) and ``moe_layer_pattern`` (Megatron)
     # are older and stay as they are: they vary a FLAG of one kind whose
     # layers share every shape (the other variant lives in the same arrays),
@@ -193,6 +205,16 @@ class TransformerConfig:
     gdn_value_dim: int = 0                     # per value head
     gdn_conv_kernel: int = 4
     sconv_taps: int = 3                        # mixer "sconv": the convolution's taps
+    # mixer "ssm": ``ssm_heads`` heads of ``ssm_head_dim`` channels (the inner
+    # width is their product, whatever d_model is), B and C of ``ssm_groups``
+    # groups of ``ssm_state`` (head h reads group h // (heads / groups)), the
+    # convolution's taps over the inner width + 2 x groups x state channels
+    # (the scan's chunk is the algorithm's, not the model's: ``ops/ssd.CHUNK``)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv_kernel: int = 4
     # One expert-parallel rank's share of a routed layer: the router scores
     # all ``n_experts``, this model holds ``n_experts_held`` of them (0 = all)
     # starting at ``expert_first`` and computes only the token-choices that
@@ -312,7 +334,7 @@ class TransformerConfig:
     @property
     def recurrent(self) -> bool:
         """Some layer carries a recurrent state instead of a KV cache."""
-        return any(mixer == "gdn" for mixer, _ in self.kinds_used)
+        return any(mixer in ("gdn", "ssm") for mixer, _ in self.kinds_used)
 
     @property
     def latent(self) -> bool:
@@ -328,6 +350,14 @@ class TransformerConfig:
         periods = (self.n_layers - self.lead_layers) // len(period)
         lead = self.lead_layers if self.lead_layers and self.lead_kind[1] == "moe" else 0
         return lead + periods * sum(1 for _, ffn in period if ffn == "moe")
+
+    @property
+    def ssm_layers(self) -> int:
+        """Layers whose mixer is the state-space one: the scans a step walks."""
+        period = self.pattern
+        periods = (self.n_layers - self.lead_layers) // len(period)
+        lead = self.lead_layers if self.lead_layers and self.lead_kind[0] == "ssm" else 0
+        return lead + periods * sum(1 for mixer, _ in period if mixer == "ssm")
 
     @property
     def dense_ff_dim(self) -> int:
@@ -407,10 +437,12 @@ def activation_fn(name: str):
     try:
         return {"gelu": _ft.partial(jax.nn.gelu, approximate=False),
                 "relu": jax.nn.relu, "silu": jax.nn.silu,
+                # squared ReLU (Nemotron-H's ``relu2``): ungated, W2 relu(W1 y)^2
+                "relu2": lambda x: jax.numpy.square(jax.nn.relu(x)),
                 "gelu_new": _ft.partial(jax.nn.gelu, approximate=True),
                 "gelu_pytorch_tanh": _ft.partial(jax.nn.gelu, approximate=True)}[name]
     except KeyError:
-        raise ValueError(f"Unsupported activation {name!r}; use swiglu/gelu/relu/silu/gelu_new")
+        raise ValueError(f"Unsupported activation {name!r}; use swiglu/gelu/relu/relu2/silu/gelu_new")
 
 
 def _norm(x, weight, bias, kind: str, eps: float = 1e-5):
@@ -792,6 +824,30 @@ class Transformer:
                 "sconv_w": stack(next(keys), (cfg.sconv_taps, D), cfg.sconv_taps),
                 "sconv_w_out": stack(next(keys), (D, D), D, scale=1.0 / math.sqrt(2 * L)),
             })
+        elif mixer == "ssm":
+            Hs, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
+            inner, conv = Hs * P, Hs * P + 2 * G * N
+            ka, kd, kb = jax.random.split(next(keys), 3)
+            # the family's own draws: A = U[1, 16], the step's bias the inverse
+            # softplus of a log-uniform step in [0.001, 0.1] floored at 1e-4
+            step = jnp.maximum(jnp.exp(jax.random.uniform(
+                kd, lead + (Hs,), jnp.float32, math.log(1e-3), math.log(1e-1))), 1e-4)
+            layer.update({
+                # [z inner | x inner | B G N | C G N | dt heads]
+                "ssm_w_in": stack(next(keys), (D, inner + conv + Hs), D),
+                # the taps [K, conv]: tap j weighs position t - (K - 1) + j
+                "ssm_conv_w": stack(next(keys), (cfg.ssm_conv_kernel, conv),
+                                    cfg.ssm_conv_kernel),
+                # torch's Conv1d default: U(+-1/sqrt(taps)); at 0 a model
+                # without the bias computes the same function
+                "ssm_conv_b": jax.random.uniform(
+                    kb, lead + (conv,), jnp.float32, -1.0, 1.0) / math.sqrt(cfg.ssm_conv_kernel),
+                "ssm_A_log": jnp.log(jax.random.uniform(ka, lead + (Hs,), jnp.float32, 1.0, 16.0)),
+                "ssm_dt_bias": step + jnp.log(-jnp.expm1(-step)),
+                "ssm_D": ones(Hs),
+                "ssm_norm_w": ones(inner),
+                "ssm_w_out": stack(next(keys), (inner, D), inner, scale=1.0 / math.sqrt(2 * L)),
+            })
         elif mixer == "mla":
             r, dc, dr, dv = (cfg.mla_kv_rank, cfg.mla_qk_content_dim,
                              cfg.mla_qk_rope_dim, cfg.mla_v_dim)
@@ -830,6 +886,11 @@ class Transformer:
             elif cfg.qk_norm:
                 layer["q_norm_w"] = ones(H * Dh)
                 layer["k_norm_w"] = ones(KV * Dh)
+        if ffn == "none":
+            # a mixer alone: no second norm, no ffn leaves
+            return layer
+        if ffn not in ("mlp", "moe"):
+            raise ValueError(f"a layer's ffn is 'mlp', 'moe' or 'none'; got {ffn!r}")
         if not (cfg.parallel_block and cfg.parallel_shared_ln):
             layer["ln2_w"] = gain(D)
             if biased_norm:
@@ -852,7 +913,8 @@ class Transformer:
                 layer[f"moe_{name}"] = held.reshape(lead + held.shape[1:])
             if cfg.moe_shared_expert_ff > 0:
                 Fs = cfg.moe_shared_expert_ff
-                layer["moe_shared_w_gate"] = stack(next(keys), (D, Fs), D)
+                if cfg.activation == "swiglu":     # an ungated one has no gate matrix
+                    layer["moe_shared_w_gate"] = stack(next(keys), (D, Fs), D)
                 layer["moe_shared_w_up"] = stack(next(keys), (D, Fs), D)
                 layer["moe_shared_w_down"] = stack(next(keys), (Fs, D), Fs)
                 if cfg.moe_shared_gate == "sigmoid":
@@ -904,6 +966,11 @@ class Transformer:
                 return P(*lead, *base)
             if name == "moe_gate":
                 return P(*lead, None, None)
+            if name.startswith("ssm_"):
+                # the state-space mixer is not split over "tensor": its input
+                # projection's blocks and the taps over them keep the
+                # checkpoint's channel order, which no even split cuts whole
+                return P(*((None,) * leaf.ndim))
             if name in ("wq", "wk", "wv", "w_gate", "w_up", "w_qkvz", "w_ba",
                         "mla_wq", "mla_wkv_b"):
                 return P(*lead, None, "tensor")       # column parallel
@@ -954,7 +1021,7 @@ class Transformer:
             # the shared placement is exact for both)
             x = _norm(x, params["embed_ln_w"], params["embed_ln_b"], cfg.norm,
                       eps=cfg.norm_eps)
-        if cfg.position in ("learned", "alibi"):
+        if cfg.position in ("learned", "alibi", "none"):
             return x, (None, None)
         return x, self.rope_for("attn", T)
 
@@ -1014,6 +1081,7 @@ class Transformer:
             # ring's hop kernels name nothing and recompute
             mix = {"gdn": self._gdn, "gated_attn": self._gated_attention,
                    "mla": self._mla, "attn": self._gqa, "sconv": self._sconv,
+                   "ssm": self._ssm,
                    "swa": functools.partial(self._gqa, mixer="swa")}[mixer]
 
             def mixer_half(lw, h):
@@ -1032,6 +1100,9 @@ class Transformer:
                 mixer_half = jax.checkpoint(
                     mixer_half, policy=_keeping_splash_residuals(policy))
                 ffn_half = jax.checkpoint(ffn_half, policy=policy)
+            if ffn == "none":
+                # a mixer alone: one residual step, nothing routed
+                return mixer_half(lw, h), (jnp.zeros((), jnp.float32), None)
             h, aux, stats = ffn_half(lw, mixer_half(lw, h))
             return h, (aux, stats)
         if cfg.post_ln:
@@ -1119,7 +1190,9 @@ class Transformer:
         rotates by a YaRN table does so under ``rope_yarn``. With ``qk_norm``
         "head" an "attn" layer norms q and k per head over ``head_dim`` (a
         plain gain [head_dim] each, the block norm's eps) BEFORE the rotation,
-        under ``attn_qk_norm`` (LFM2). None of the softmax family's other flags
+        under ``attn_qk_norm`` (LFM2). With ``position`` "none" nothing is
+        rotated and nothing else marks a position (Nemotron-H: the state-space
+        layers beside it carry the order; ``rope`` is then (None, None)). None of the softmax family's other flags
         reaches this form (biases, the whole-projection q/k norm, ALiBi,
         post-LN, a parallel block: a one-kind model's, ``layer_apply``)."""
         from jax.ad_checkpoint import checkpoint_name
@@ -1129,11 +1202,15 @@ class Transformer:
         flags = [f for f in ("attn_qkv_bias", "attn_out_bias", "post_ln",
                              "parallel_block", "attn_scale", "local_attention_window")
                  if getattr(cfg, f)] + ["qk_norm"] * (cfg.qk_norm is True)
-        if flags or cfg.position != "rope" or not cfg.causal:
+        if flags or cfg.position not in ("rope", "none") or not cfg.causal:
             raise NotImplementedError(
                 f"a stack of several kinds runs mixer {mixer!r} as plain causal "
-                f"RoPE GQA; this configuration sets {flags or cfg.position!r}")
+                f"GQA, rotated (position 'rope') or not at all ('none'); this "
+                f"configuration sets {flags or cfg.position!r}")
         windowed = mixer == "swa"
+        rotated = cfg.position == "rope"
+        if windowed and not rotated:
+            raise NotImplementedError("mixer 'swa' rotates by its own table: position 'rope'")
         if windowed and cfg.swa_window <= 0:
             raise ValueError("mixer 'swa' needs swa_window > 0")
         B, T = y.shape[:2]
@@ -1151,9 +1228,10 @@ class Transformer:
                 with trace.scope("attn_qk_norm"):
                     q = _head_norm(q, lw["q_norm_w"], "rmsnorm", cfg.norm_eps)
                     k = _head_norm(k, lw["k_norm_w"], "rmsnorm", cfg.norm_eps)
-            with own("swa_rope" if windowed else "rope_yarn" if cfg.rope_yarn else None):
-                q = apply_rope(q, cos, sin, interleaved=cfg.rope_interleaved)
-                k = apply_rope(k, cos, sin, interleaved=cfg.rope_interleaved)
+            if rotated:
+                with own("swa_rope" if windowed else "rope_yarn" if cfg.rope_yarn else None):
+                    q = apply_rope(q, cos, sin, interleaved=cfg.rope_interleaved)
+                    k = apply_rope(k, cos, sin, interleaved=cfg.rope_interleaved)
         q = checkpoint_name(q, "q")
         k = checkpoint_name(k, "kv")
         v = checkpoint_name(v, "kv")
@@ -1227,6 +1305,77 @@ class Transformer:
             mixed = sconv_mix(bcx, lw["sconv_w"])
         with trace.scope("attn_out"), trace.scope("sconv_out"):
             return mixed @ lw["sconv_w_out"]
+
+    def _ssm(self, lw, y, rope):
+        """The Mamba-2 state-space mixer (Nemotron-H's ``M`` layers;
+        ``ops/ssd.py``) on the normed block input y [B, T, D] -> [B, T, D];
+        ``rope`` is not used (the convolution and the decay carry the order).
+        Shapes from ``ssm_*``: H heads of P, G groups of a state of N.
+        ``[z | xBC | dt] = y W_in`` (inner = H P, inner + 2 G N, H wide);
+        ``xBC = silu(conv(xBC) + b)``, causal depthwise taps a channel, zero
+        before position 0; ``dt = softplus(dt + dt_bias)`` unclamped, ``A =
+        -exp(A_log)``, both float32; the scan (``ssd_chunked``) with the skip
+        ``D``; ``o * silu(z)`` and THEN an RMSNorm over each of the G groups
+        of inner / G channels under one gain [inner]; ``W_out``. Under the
+        outer scopes of an attention layer so that a reader's sums by layer
+        hold, its own nested inside: ``ssm_in``, ``ssm_conv`` and
+        ``ssm_gates`` (in ``attn_qkv``), ``ssm_scan`` (in ``attn_core``),
+        ``ssm_out_norm`` and ``ssm_out`` (in ``attn_out``). A
+        sequence-parallel mesh is refused: a shard's scan starts from the
+        state the shard before it ends in, which nothing carries."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import PartitionSpec
+
+        from ..ops.gated_delta import causal_conv1d
+        from ..ops.ssd import ssd_chunked
+        from ..parallel.mesh import kernel_activation_spec, shard_kernel
+
+        del rope
+        cfg = self.config
+        if self._sp_mesh()[0] > 1:
+            raise NotImplementedError(
+                "the state-space scan (mixer 'ssm') under a sequence-parallel "
+                "mesh: a shard's scan and convolution start from the state and "
+                "the tail of the shard before it, which nothing carries; run "
+                "it with seq = 1")
+        B, T = y.shape[:2]
+        H, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
+        inner = H * P
+        f32 = jnp.float32
+        with trace.scope("attn_qkv"):
+            with trace.scope("ssm_in"):
+                zxbcdt = y @ lw["ssm_w_in"]
+                z = zxbcdt[..., :inner]
+                xbc = zxbcdt[..., inner:2 * inner + 2 * G * N]
+                dt = zxbcdt[..., 2 * inner + 2 * G * N:]
+            with trace.scope("ssm_conv"):
+                xbc = jax.nn.silu(causal_conv1d(
+                    xbc.astype(f32), lw["ssm_conv_w"], lw["ssm_conv_b"])).astype(y.dtype)
+                x = xbc[..., :inner].reshape(B, T, H, P)
+                Bm = xbc[..., inner:inner + G * N].reshape(B, T, G, N)
+                Cm = xbc[..., inner + G * N:].reshape(B, T, G, N)
+            with trace.scope("ssm_gates"):
+                dt = jax.nn.softplus(dt.astype(f32) + lw["ssm_dt_bias"].astype(f32))
+                A = -jnp.exp(lw["ssm_A_log"].astype(f32))
+        with trace.scope("attn_core"), trace.scope("ssm_scan"):
+            # each device runs the scan on its own rows, like a kernel (the
+            # sequences are independent), as the delta rule's is (``_gdn``)
+            wide = kernel_activation_spec(x.shape)
+            rows = kernel_activation_spec(dt.shape)
+            o = shard_kernel(
+                ssd_chunked,
+                (wide, rows, PartitionSpec(), wide, wide, PartitionSpec()), wide,
+            )(x, dt, A, Bm, Cm, lw["ssm_D"].astype(f32))
+        with trace.scope("attn_out"):
+            with trace.scope("ssm_out_norm"):
+                # the gate FIRST, then a norm over each group's channels
+                o = (o.reshape(B, T, inner).astype(f32) * jax.nn.silu(z.astype(f32))
+                     ).reshape(B, T, G, inner // G)
+                o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps)
+                o = (o.reshape(B, T, inner) * lw["ssm_norm_w"].astype(f32)).astype(y.dtype)
+            with trace.scope("ssm_out"):
+                return o @ lw["ssm_w_out"]
 
     def _mla(self, lw, y, rope):
         """The latent-attention mixer (DeepSeek-V2/V3 MLA without query
@@ -1443,12 +1592,16 @@ class Transformer:
                 else:
                     ff, aux, stats = jax.lax.cond(moe_on, moe_branch, dense_branch, y2)
             if cfg.moe_shared_expert_ff > 0:
-                # the shared expert: a dense swiglu MLP every token runs,
+                # the shared expert: a dense MLP of the experts' form every token runs,
                 # added through a per-token sigmoid gate (Qwen2-MoE,
                 # Qwen3-Next) or as it is (DeepSeek-V3: moe_shared_gate "none")
                 with trace.scope("moe_shared"):
-                    shared = (jax.nn.silu(y2 @ lw["moe_shared_w_gate"])
-                              * (y2 @ lw["moe_shared_w_up"])) @ lw["moe_shared_w_down"]
+                    if cfg.activation == "swiglu":
+                        inner = jax.nn.silu(y2 @ lw["moe_shared_w_gate"]) * (
+                            y2 @ lw["moe_shared_w_up"])
+                    else:       # ungated (Nemotron-H's relu2): W2 act(W1 y)
+                        inner = activation_fn(cfg.activation)(y2 @ lw["moe_shared_w_up"])
+                    shared = inner @ lw["moe_shared_w_down"]
                     if cfg.moe_shared_gate == "sigmoid":
                         shared = jax.nn.sigmoid(
                             y2 @ lw["moe_shared_gate"]).astype(ff.dtype) * shared
@@ -2166,6 +2319,13 @@ class Transformer:
                                           ltd_mask=ltd_mask, layer_keep=layer_keep,
                                           with_stats=True, **self._lead_of(params))
         stats = {}
+        if cfg.ssm_layers:
+            from ..ops.ssd import ssd_chunks
+
+            # the chunks the state-space scans of this batch walk: a sequence's
+            # chunks x sequences x state-space layers
+            stats["ssm_scan_chunks"] = jnp.asarray(
+                ssd_chunks(T) * B * cfg.ssm_layers, jnp.int32)
         if routed is not None:
             stats["moe_expert_tokens"] = routed["expert_tokens"]
             stats["moe_held_rows"] = routed["held_rows"]

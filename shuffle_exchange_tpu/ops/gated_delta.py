@@ -104,12 +104,14 @@ def l2norm(x, eps: float = 1e-6):
     return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + eps)
 
 
-def causal_conv1d(x, w):
-    """Depthwise causal convolution, no bias (the DeltaNet prologue's XLA form,
-    ``_gdn_prologue_xla``, and the gated short convolution's,
-    ``short_conv.sconv_mix``, call it; the oracle of both). x [B, T, C], w [K, C]:
+def causal_conv1d(x, w, bias=None):
+    """Depthwise causal convolution (the DeltaNet prologue's XLA form,
+    ``_gdn_prologue_xla``, the gated short convolution's,
+    ``short_conv.sconv_mix``, and the state-space mixer's,
+    ``Transformer._ssm``, call it; the oracle of all). x [B, T, C], w [K, C]:
     ``y[t] = sum_j w[j] * x[t - (K - 1) + j]`` with x zero before position 0
-    (torch's ``Conv1d(C, C, K, groups=C, padding=K-1)`` cut to T outputs).
+    (torch's ``Conv1d(C, C, K, groups=C, padding=K-1)`` cut to T outputs),
+    plus ``bias`` [C] where there is one (the state-space mixer's alone).
     Accumulates in float32; returns x's dtype."""
     import jax.numpy as jnp
 
@@ -117,6 +119,8 @@ def causal_conv1d(x, w):
     xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0))).astype(jnp.float32)
     w32 = w.astype(jnp.float32)
     y = sum(xp[:, j:j + T] * w32[j] for j in range(K))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return y.astype(x.dtype)
 
 

@@ -21,10 +21,14 @@ from __future__ import annotations
 
 
 def _gmm_ok(x, w) -> bool:
-    """megablox tiling wants lane-aligned K/F; row padding handles N."""
+    """megablox tiles are whole lane tiles: K and F are multiples of 128, or
+    wider than their tile of ``_GMM_TILE`` (the kernels mask the contraction's
+    last, partial tile and clip the output's: experts of 1856, 14.5 lane
+    tiles); row padding handles N."""
     N, K = x.shape
     E, K2, F = w.shape
-    return K % 128 == 0 and F % 128 == 0
+    _, tk, tn = _GMM_TILE
+    return (K % 128 == 0 or K > tk) and (F % 128 == 0 or F > tn)
 
 
 def grouped_matmul(x, w, group_sizes):

@@ -70,7 +70,12 @@ PREFIX = "sxt:"
 # stack that rotates by a YaRN table does so under "rope_yarn" inside
 # "attn_qkv". A gated short-convolution layer (mixer "sconv") opens "sconv_in"
 # inside "attn_qkv", "sconv_mix" (the pass between its projections) inside
-# "attn_core" and "sconv_out" inside "attn_out".
+# "attn_core" and "sconv_out" inside "attn_out". A Mamba-2 state-space layer
+# (mixer "ssm") opens "ssm_in" (its input projection), "ssm_conv" (taps, bias,
+# SiLU) and "ssm_gates" (the step and the decay) inside "attn_qkv", "ssm_scan"
+# inside "attn_core", "ssm_out_norm" (the gate and the grouped norm) and
+# "ssm_out" (the projection back) inside "attn_out". A layer that is a mixer
+# alone (ffn "none") opens no scope of the "mlp" layer.
 # "plumbing" is what belongs to no layer of the model: the layer scan's own
 # slicing and stacking, the masters' cast to the compute dtype, the
 # gradients' cast back and normalization
@@ -79,7 +84,8 @@ SCOPES = {
              "attn_gate", "gdn_conv", "gdn_gates", "gdn_scan", "gdn_out_norm",
              "mla_q", "mla_kv_down", "mla_kv_norm", "mla_kv_up", "mla_rope",
              "swa_qkv", "swa_rope", "swa_core", "swa_out", "rope_yarn",
-             "sconv_in", "sconv_mix", "sconv_out"),
+             "sconv_in", "sconv_mix", "sconv_out",
+             "ssm_in", "ssm_conv", "ssm_gates", "ssm_scan", "ssm_out_norm", "ssm_out"),
     "mlp": ("mlp_norm", "mlp", "moe", "moe_router", "moe_dispatch",
             "moe_experts", "moe_combine", "moe_shared"),
     "loss": ("embed", "final_norm", "loss", "head_logits", "head_softmax",

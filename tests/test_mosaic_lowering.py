@@ -352,6 +352,31 @@ def test_gdn_prologue_lowers(dtype):
                    qkvz, conv_w, wide, wide, wide, wide)
 
 
+def _ssd_vjp(x, dt, A, B, C, cotangent):
+    """o and the five gradients through the scan's kernels themselves (the
+    dispatching entry takes the XLA form off a TPU)."""
+    from shuffle_exchange_tpu.ops.ssd import CHUNK, _ssd_pallas
+
+    o, back = jax.vjp(lambda *a: _ssd_pallas(*a, CHUNK, False), x, dt, A, B, C)
+    return (o,) + back(cotangent)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("P", [64, 128])
+def test_ssd_scan_lowers(dtype, P):
+    """Forward (o alone), the forward that keeps the
+    chunks' states, and the backward; a ragged tail; two heads a lane tile and
+    one; bf16 and float32 operands."""
+    from shuffle_exchange_tpu.ops.ssd import CHUNK, _ssd_pallas
+
+    Bt, T, H, G, N = 2, 300, 8, 2, 128
+    x = jnp.zeros((Bt, T, H, P), dtype)
+    dt, A = jnp.zeros((Bt, T, H), jnp.float32), jnp.zeros((H,), jnp.float32)
+    group = jnp.zeros((Bt, T, G, N), dtype)
+    _tpu_lower(lambda *a: _ssd_pallas(*a, CHUNK, False), x, dt, A, group, group)
+    _tpu_lower(_ssd_vjp, x, dt, A, group, group, x)
+
+
 @pytest.mark.parametrize("store", [jnp.int8, jnp.float8_e4m3fn])
 def test_paged_kernels_quantized_kv_lower(store):
     """kv_cache_dtype int8/fp8 (ISSUE 6): every streaming kernel that
@@ -707,6 +732,33 @@ def test_gated_delta_rule_compiles(chip_compile):
                             ((2, 8192, 32, 128), _F32))
     text = compiled.as_text()
     assert "gdn_rule_fwd_keep" in text and "gdn_rule_bwd" in text
+
+
+def test_ssd_scan_compiles(chip_compile):
+    """The three kernels at the shape ``nemotron3-train`` runs them: two rows
+    of 8,192 tokens, 64 heads of 64 in 8 groups of a state of 128, bf16 with a
+    float32 step."""
+    wide, group = ((2, 8192, 64, 64), _BF16), ((2, 8192, 8, 128), _BF16)
+    compiled = chip_compile(_ssd_vjp, wide, ((2, 8192, 64), _F32), ((64,), _F32),
+                            group, group, wide)
+    text = compiled.as_text()
+    assert "ssd_fwd_keep" in text and "ssd_bwd" in text
+
+
+def test_grouped_gemm_compiles_at_a_width_of_half_lane_tiles(chip_compile):
+    """megablox gmm at ``nemotron3-train``'s expert geometry: 8 held experts
+    of 2688 x 1856 (14.5 lane tiles: the contraction's last tile masked, the
+    output's clipped), forward and both backward kernels, both matrices."""
+    from shuffle_exchange_tpu.ops.grouped_gemm import _gmm_ok, _grouped_matmul_gmm
+
+    E, D, F, N = 8, 2688, 1856, 18432
+    for K, W in ((D, F), (F, D)):
+        assert _gmm_ok(jnp.zeros((N, K), _BF16), jnp.zeros((E, K, W), _BF16))
+        compiled = chip_compile(jax.grad(lambda x, w, gs: _grouped_matmul_gmm(
+            x, w, gs).astype(_F32).sum() ** 2, argnums=(0, 1)),
+            ((N, K), _BF16), ((E, K, W), _BF16), ((E,), _I32))
+        assert "tgmm" in compiled.as_text()
+    assert not _gmm_ok(jnp.zeros((N, 200), _BF16), jnp.zeros((E, 200, 1024), _BF16))
 
 
 def test_gdn_prologue_compiles(chip_compile):
