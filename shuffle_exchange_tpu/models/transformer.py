@@ -1312,8 +1312,8 @@ class Transformer:
         ``rope`` is not used (the convolution and the decay carry the order).
         Shapes from ``ssm_*``: H heads of P, G groups of a state of N.
         ``[z | xBC | dt] = y W_in`` (inner = H P, inner + 2 G N, H wide);
-        ``xBC = silu(conv(xBC) + b)``, causal depthwise taps a channel, zero
-        before position 0; ``dt = softplus(dt + dt_bias)`` unclamped, ``A =
+        ``xBC = silu(conv(xBC) + b)`` (``ops/ssm_conv.py``), causal taps a channel,
+        zero before position 0; ``dt = softplus(dt + dt_bias)`` unclamped, ``A =
         -exp(A_log)``, both float32; the scan (``ssd_chunked``) with the skip
         ``D``; ``o * silu(z)`` and THEN an RMSNorm over each of the G groups
         of inner / G channels under one gain [inner]; ``W_out``. Under the
@@ -1327,8 +1327,8 @@ class Transformer:
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec
 
-        from ..ops.gated_delta import causal_conv1d
         from ..ops.ssd import ssd_chunked
+        from ..ops.ssm_conv import ssm_conv
         from ..parallel.mesh import kernel_activation_spec, shard_kernel
 
         del rope
@@ -1346,15 +1346,15 @@ class Transformer:
         with trace.scope("attn_qkv"):
             with trace.scope("ssm_in"):
                 zxbcdt = y @ lw["ssm_w_in"]
-                z = zxbcdt[..., :inner]
-                xbc = zxbcdt[..., inner:2 * inner + 2 * G * N]
-                dt = zxbcdt[..., 2 * inner + 2 * G * N:]
             with trace.scope("ssm_conv"):
-                xbc = jax.nn.silu(causal_conv1d(
-                    xbc.astype(f32), lw["ssm_conv_w"], lw["ssm_conv_b"])).astype(y.dtype)
-                x = xbc[..., :inner].reshape(B, T, H, P)
-                Bm = xbc[..., inner:inner + G * N].reshape(B, T, G, N)
-                Cm = xbc[..., inner + G * N:].reshape(B, T, G, N)
+                # xBC where it lies, z and dt through; per device like the scan
+                rows = kernel_activation_spec(zxbcdt.shape)
+                z, x, Bm, Cm, dt = shard_kernel(
+                    functools.partial(ssm_conv, start=inner, widths=(inner, G * N, G * N)),
+                    (rows, PartitionSpec(), PartitionSpec()), (rows,) * 5,
+                )(zxbcdt, lw["ssm_conv_w"], lw["ssm_conv_b"])
+                x = x.reshape(B, T, H, P)
+                Bm, Cm = Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N)
             with trace.scope("ssm_gates"):
                 dt = jax.nn.softplus(dt.astype(f32) + lw["ssm_dt_bias"].astype(f32))
                 A = -jnp.exp(lw["ssm_A_log"].astype(f32))

@@ -105,14 +105,14 @@ def l2norm(x, eps: float = 1e-6):
 
 
 def causal_conv1d(x, w, bias=None):
-    """Depthwise causal convolution (the DeltaNet prologue's XLA form,
-    ``_gdn_prologue_xla``, the gated short convolution's,
-    ``short_conv.sconv_mix``, and the state-space mixer's,
-    ``Transformer._ssm``, call it; the oracle of all). x [B, T, C], w [K, C]:
-    ``y[t] = sum_j w[j] * x[t - (K - 1) + j]`` with x zero before position 0
+    """Depthwise causal convolution: the oracle, and the XLA form, of the
+    DeltaNet prologue (``_gdn_prologue_xla``), of the gated short convolution
+    (``short_conv.sconv_mix``) and, since PR 47, of the state-space mixer's
+    convolution (``ssm_conv._ssm_conv_xla``: its off-TPU path, NOT its TPU
+    path, which is ``ops/ssm_conv.py``'s kernels). x [B, T, C], w [K, C]:
+    ``y[t] = sum_j w[j] * x[t - (K - 1) + j]``, x zero before position 0
     (torch's ``Conv1d(C, C, K, groups=C, padding=K-1)`` cut to T outputs),
-    plus ``bias`` [C] where there is one (the state-space mixer's alone).
-    Accumulates in float32; returns x's dtype."""
+    plus ``bias`` [C] where given. Accumulates in float32; returns x's dtype."""
     import jax.numpy as jnp
 
     K, T = w.shape[0], x.shape[1]

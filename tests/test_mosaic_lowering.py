@@ -377,6 +377,33 @@ def test_ssd_scan_lowers(dtype, P):
     _tpu_lower(_ssd_vjp, x, dt, A, group, group, x)
 
 
+def _ssm_conv_vjp(zxbcdt, conv_w, conv_b, dz, dx, dB, dC, ddt, start=4096,
+                  widths=(4096, 1024, 1024)):
+    """z, x, B, C, dt and the three gradients through the convolution's
+    kernels themselves (the dispatching entry takes the XLA form off a TPU)."""
+    from shuffle_exchange_tpu.ops.ssm_conv import _ssm_conv_pallas
+
+    out, back = jax.vjp(lambda *a: _ssm_conv_pallas(*a, start, widths),
+                        zxbcdt, conv_w, conv_b)
+    return out + back((dz, dx, dB, dC, ddt))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_ssm_conv_lowers(dtype):
+    """The forward and the backward launch of every segment; a ragged last
+    block of rows; bf16 and float32 activations; segments one lane tile wide
+    and three (a lane block of 384), four taps and two."""
+    import functools
+
+    for (start, widths), T, K in (((256, (256, 128, 128)), 1100, 4),
+                                  ((384, (384, 384, 768)), 100, 2)):
+        zxbcdt = jnp.zeros((2, T, start + sum(widths) + 8), dtype)
+        cotangents = [jnp.zeros((2, T, n), dtype) for n in (start, *widths, 8)]
+        _tpu_lower(functools.partial(_ssm_conv_vjp, start=start, widths=widths),
+                   zxbcdt, jnp.zeros((K, sum(widths)), jnp.float32),
+                   jnp.zeros((sum(widths),), jnp.float32), *cotangents)
+
+
 @pytest.mark.parametrize("store", [jnp.int8, jnp.float8_e4m3fn])
 def test_paged_kernels_quantized_kv_lower(store):
     """kv_cache_dtype int8/fp8 (ISSUE 6): every streaming kernel that
@@ -743,6 +770,20 @@ def test_ssd_scan_compiles(chip_compile):
                             group, group, wide)
     text = compiled.as_text()
     assert "ssd_fwd_keep" in text and "ssd_bwd" in text
+
+
+def test_ssm_conv_compiles(chip_compile):
+    """The convolution's two kernels at the shape ``nemotron3-train`` runs
+    them: two rows of 8,192 tokens, the projection's 10,304 columns of which
+    x (4096), B and C (1024 each) start at 4096, four taps and a bias, bf16
+    with bf16 weights; a launch a segment, forward and backward."""
+    rows = lambda n: ((2, 8192, n), _BF16)
+    compiled = chip_compile(_ssm_conv_vjp, rows(10304), ((4, 6144), _BF16),
+                            ((6144,), _BF16), rows(4096), rows(4096), rows(1024),
+                            rows(1024), rows(64))
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    assert "ssm_conv_fwd" in text and "ssm_conv_bwd" in text
 
 
 def test_grouped_gemm_compiles_at_a_width_of_half_lane_tiles(chip_compile):

@@ -38,6 +38,7 @@ from shuffle_exchange_tpu.models import Transformer  # noqa: E402
 from shuffle_exchange_tpu.models import reference_nemotron3 as ref  # noqa: E402
 from shuffle_exchange_tpu.models.hf import _nemotron_h_pairs, config_from_hf  # noqa: E402
 from shuffle_exchange_tpu.models.transformer import activation_fn  # noqa: E402
+from shuffle_exchange_tpu.profiling import trace  # noqa: E402
 
 PUBLISHED = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 HF = {"model_type": "nemotron_h", "hidden_size": 64, "hybrid_override_pattern": PUBLISHED,
@@ -347,6 +348,48 @@ def test_the_state_space_mixer_alone_and_its_scopes(case):
         assert f"{outer}/{own}" in text, (outer, own)
 
 
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_the_mixers_convolution_kernels_are_the_xla_form(remat, monkeypatch):
+    """The state-space mixer alone with its convolution as XLA's
+    ``silu(causal_conv1d)`` and as the interpreted kernels
+    (``ops/ssm_conv.py``; 4 heads of 64 and 2 groups of a state of 64 put z,
+    x, B and C on whole lane tiles and leave the scan its XLA form, so the
+    convolution is all that differs): the same output and the same gradient
+    of every leaf and of the input, with the mixer replayed under
+    ``jax.checkpoint`` (what per-half remat does to it) and without. 200
+    tokens: ONE ragged block of rows."""
+    from shuffle_exchange_tpu.ops import ssm_conv as sc
+
+    hf = dict(HF, num_hidden_layers=5, mamba_num_heads=4, mamba_head_dim=64,
+              ssm_state_size=64)
+    model = Transformer(config_from_hf(hf))
+    params = driver.initial_params(model, 9, BIAS_STD)
+    lw = jax.tree.map(lambda a: a[0, 1], {k: params["layers"]["ssm_moe"][k]
+                                          for k in driver._MIXER["ssm"]})
+    x = jax.random.normal(jax.random.PRNGKey(4), (BATCH, 200, 64), jnp.float32)
+    push = jax.random.normal(jax.random.PRNGKey(5), x.shape, jnp.float32)
+
+    def answers():
+        mixer = lambda lw, x: model._ssm(lw, x, None)
+        if remat:
+            mixer = jax.checkpoint(mixer)
+        # (a new function each time: the route is chosen while tracing)
+        out, back = jax.vjp(lambda lw, x: mixer(lw, x), lw, x)
+        dlw, dx = back(push)
+        return dict(dlw, out=out, x=dx)
+
+    zxbcdt = jnp.zeros((BATCH, 200, 2 * 256 + 2 * 128 + 4))
+    route = lambda: sc.ssm_conv_route(zxbcdt, lw["ssm_conv_w"], 256, (256, 128, 128))
+    assert route() == "xla"
+    want = answers()
+    monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
+    assert route() == "interpret"
+    got = answers()
+    worst = gaps(got, want)
+    assert len(worst) == len(driver._MIXER["ssm"]) + 2
+    assert max(worst.values()) < 1e-5, worst
+
+
 def test_a_block_without_an_ffn_opens_no_ffn_scope(case):
     lw = jax.tree.map(lambda a: a[0, 0], case["params"]["layers"]["ssm_none"])
     x = jax.random.normal(jax.random.PRNGKey(4), (BATCH, 16, 64), jnp.float32)
@@ -399,13 +442,21 @@ def test_the_trainer_runs_the_scans_kernels_where_the_heads_fill_lane_tiles(monk
                     "activation_checkpointing": {"enabled": True, "policy": "full"},
                     "zero_optimization": {"stage": 3}}, seed=0)[0]
         text = engine.compile({"input_ids": ids}).as_text()
+        scopes = {op.scope for op in trace.registered_ops("train_step").values()}
         loss = float(engine.train_batch({"input_ids": ids}))
-        return loss, driver.first_moment(engine.state.opt_state), text
+        return loss, driver.first_moment(engine.state.opt_state), text, scopes
 
-    xla_loss, xla_moment, xla_text = first_step()
+    xla_loss, xla_moment, xla_text, xla_scopes = first_step()
     monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
-    loss, moment, text = first_step()
+    loss, moment, text, scopes = first_step()
     assert "ssd_bwd" in text and "ssd_fwd_keep" in text and "ssd_bwd" not in xla_text
+    # the convolution's two kernels too (``ops/ssm_conv.py``), under the
+    # mixer's scope ``ssm_conv``, the backward's in the backward pass
+    for kernel in ("ssm_conv_fwd", "ssm_conv_bwd"):
+        under = [s for s in scopes if "/ssm_conv/" in s and f"/{kernel}/" in s]
+        assert under and not any(kernel in s for s in xla_scopes), kernel
+    assert any("rematted_computation" in s for s in scopes if "/ssm_conv_fwd/" in s)
+    assert all("transpose(" in s for s in scopes if "/ssm_conv_bwd/" in s)
     assert abs(loss - xla_loss) < 1e-5
     # (a leaf no token reached has a zero gradient in both)
     worst = {k: v for k, v in gaps(moment, xla_moment).items() if v == v}
