@@ -1314,13 +1314,13 @@ class Transformer:
         ``[z | xBC | dt] = y W_in`` (inner = H P, inner + 2 G N, H wide);
         ``xBC = silu(conv(xBC) + b)`` (``ops/ssm_conv.py``), causal taps a channel,
         zero before position 0; ``dt = softplus(dt + dt_bias)`` unclamped, ``A =
-        -exp(A_log)``, both float32; the scan (``ssd_chunked``) with the skip
-        ``D``; ``o * silu(z)`` and THEN an RMSNorm over each of the G groups
-        of inner / G channels under one gain [inner]; ``W_out``. Under the
-        outer scopes of an attention layer so that a reader's sums by layer
-        hold, its own nested inside: ``ssm_in``, ``ssm_conv`` and
-        ``ssm_gates`` (in ``attn_qkv``), ``ssm_scan`` (in ``attn_core``),
-        ``ssm_out_norm`` and ``ssm_out`` (in ``attn_out``). A
+        -exp(A_log)``, both float32; the scan (``ssd_chunked``); its epilogue
+        (``ops/ssm_gate_norm.py``): the skip ``o + D x``, ``* silu(z)`` and
+        THEN an RMSNorm over each of the G groups of inner / G channels under
+        one gain; ``W_out``. Under the outer scopes of an attention layer so
+        that a reader's sums by layer hold, its own nested inside: ``ssm_in``,
+        ``ssm_conv`` and ``ssm_gates`` (in ``attn_qkv``), ``ssm_scan`` (in
+        ``attn_core``), ``ssm_out_norm`` and ``ssm_out`` (in ``attn_out``). A
         sequence-parallel mesh is refused: a shard's scan starts from the
         state the shard before it ends in, which nothing carries."""
         import jax
@@ -1329,6 +1329,7 @@ class Transformer:
 
         from ..ops.ssd import ssd_chunked
         from ..ops.ssm_conv import ssm_conv
+        from ..ops.ssm_gate_norm import ssm_gate_norm
         from ..parallel.mesh import kernel_activation_spec, shard_kernel
 
         del rope
@@ -1353,27 +1354,26 @@ class Transformer:
                     functools.partial(ssm_conv, start=inner, widths=(inner, G * N, G * N)),
                     (rows, PartitionSpec(), PartitionSpec()), (rows,) * 5,
                 )(zxbcdt, lw["ssm_conv_w"], lw["ssm_conv_b"])
-                x = x.reshape(B, T, H, P)
-                Bm, Cm = Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N)
             with trace.scope("ssm_gates"):
                 dt = jax.nn.softplus(dt.astype(f32) + lw["ssm_dt_bias"].astype(f32))
                 A = -jnp.exp(lw["ssm_A_log"].astype(f32))
         with trace.scope("attn_core"), trace.scope("ssm_scan"):
-            # each device runs the scan on its own rows, like a kernel (the
-            # sequences are independent), as the delta rule's is (``_gdn``)
-            wide = kernel_activation_spec(x.shape)
+            # each device runs the scan on its own rows (the skip is the epilogue's)
+            wide = kernel_activation_spec((B, T, H, P))
             rows = kernel_activation_spec(dt.shape)
-            o = shard_kernel(
-                ssd_chunked,
-                (wide, rows, PartitionSpec(), wide, wide, PartitionSpec()), wide,
-            )(x, dt, A, Bm, Cm, lw["ssm_D"].astype(f32))
+            o = shard_kernel(ssd_chunked, (wide, rows, PartitionSpec(), wide, wide), wide)(
+                x.reshape(B, T, H, P), dt, A, Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N))
         with trace.scope("attn_out"):
             with trace.scope("ssm_out_norm"):
-                # the gate FIRST, then a norm over each group's channels
-                o = (o.reshape(B, T, inner).astype(f32) * jax.nn.silu(z.astype(f32))
-                     ).reshape(B, T, G, inner // G)
-                o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps)
-                o = (o.reshape(B, T, inner) * lw["ssm_norm_w"].astype(f32)).astype(y.dtype)
+                # the skip, the gate FIRST, then a norm over each group's
+                # channels; z's values read where the projection left them
+                flat = kernel_activation_spec(z.shape)
+                o = shard_kernel(
+                    lambda o, x, z, zxbcdt, D, gain: ssm_gate_norm(
+                        o, x, z, D, gain, G, cfg.norm_eps, z_in=zxbcdt),
+                    (flat,) * 4 + (PartitionSpec(),) * 2, flat,
+                )(o.reshape(B, T, inner), x, z, zxbcdt, lw["ssm_D"].astype(f32),
+                  lw["ssm_norm_w"].astype(f32))
             with trace.scope("ssm_out"):
                 return o @ lw["ssm_w_out"]
 

@@ -10,6 +10,9 @@ groups of a state of N; head h reads group ``h // (H / G)``) and the skip
     S_t[h] = exp(dt_t[h] A[h]) S_{t-1}[h] + dt_t[h] x_t[h] (outer) B_t[g]
     o_t[h] = S_t[h] C_t[g] + D[h] x_t[h]              S_{-1} = 0, S [P, N]
 
+(the mixer leaves the skip to its epilogue, ``ops/ssm_gate_norm.py``, and
+calls the scan without ``D``)
+
 Nothing erases: the state only decays and is written to, which is why the
 gated DELTA rule's chunked form (``ops/gated_delta.py``: a triangular solve a
 chunk) does not compute it.
@@ -72,7 +75,7 @@ def ssd_chunks(seq: int, chunk: int = CHUNK) -> int:
     return -(-int(seq) // chunk)
 
 
-def _grouped(x, dt, A, B, D):
+def _grouped(x, dt, A, B):
     """Heads as [G, R] (R = H / G heads a group), so that a group's B and C
     meet their heads by broadcasting."""
     Bt, T, H, P = x.shape
@@ -80,8 +83,7 @@ def _grouped(x, dt, A, B, D):
     if H % G:
         raise ValueError(f"ssd: {H} heads do not divide into {G} groups")
     R = H // G
-    return (x.reshape(Bt, T, G, R, P), dt.reshape(Bt, T, G, R),
-            A.reshape(G, R), D.reshape(G, R))
+    return x.reshape(Bt, T, G, R, P), dt.reshape(Bt, T, G, R), A.reshape(G, R)
 
 
 def ssd_recurrent(x, dt, A, B, C, D):
@@ -93,9 +95,9 @@ def ssd_recurrent(x, dt, A, B, C, D):
     f32 = jnp.float32
     Bt, T, H, P = x.shape
     G, N = B.shape[2:]
-    xg, dtg, Ag, Dg = _grouped(x.astype(f32), dt.astype(f32), A.astype(f32),
-                               B, D.astype(f32))
+    xg, dtg, Ag = _grouped(x.astype(f32), dt.astype(f32), A.astype(f32), B)
     R = H // G
+    Dg = D.astype(f32).reshape(G, R)
 
     def step(S, row):
         xt, dtt, Bt_, Ct = row                  # [Bt,G,R,P] [Bt,G,R] [Bt,G,N] x 2
@@ -160,19 +162,22 @@ def _chunks_xla(x, dt, A, B, C):
         "bcqgn,bcgrpn->bcqgrp", C.astype(f32), starts, precision=high)
 
 
-def ssd_chunked(x, dt, A, B, C, D, chunk: int = CHUNK):
+def ssd_chunked(x, dt, A, B, C, D=None, chunk: int = CHUNK):
     """The chunked form -> o [B, T, H, P] in x's dtype. T is padded to whole
-    chunks with steps of 0, which neither decay nor write."""
+    chunks with steps of 0, which neither decay nor write. ``D`` None: no
+    skip, and the kernels' output leaves as it is (the mixer adds the skip in
+    its epilogue, ``ops/ssm_gate_norm.py``, where the sum is not rounded on
+    its way to the gate); given, ``D x`` is added in float32 and the sum
+    rounded to x's dtype."""
     import jax.numpy as jnp
 
     f32 = jnp.float32
     Bt, T, H, P = x.shape
-    xg, dtg, Ag, Dg = _grouped(x, dt.astype(f32), A.astype(f32), B, D.astype(f32))
     route = ssd_route(x, B, chunk)
     if route != "xla":
         o = _ssd_pallas(x, dt, A, B, C, chunk, interpret=route == "interpret")
-        o = o.reshape(xg.shape).astype(f32)
     else:
+        xg, dtg, Ag = _grouped(x, dt.astype(f32), A.astype(f32), B)
         pad = -T % chunk
         n = (T + pad) // chunk
 
@@ -182,9 +187,10 @@ def ssd_chunked(x, dt, A, B, C, D, chunk: int = CHUNK):
             return a.reshape((Bt, n, chunk) + a.shape[2:])
 
         o = _chunks_xla(cut(xg), cut(dtg), Ag, cut(B), cut(C))
-        o = o.reshape((Bt, T + pad) + o.shape[3:])[:, :T]
-    o = o + Dg[..., None] * xg.astype(f32)
-    return o.reshape(Bt, T, H, P).astype(x.dtype)
+        o = o.reshape(Bt, T + pad, H, P)[:, :T]
+    if D is not None:
+        o = o.astype(f32) + D.astype(f32)[:, None] * x.astype(f32)
+    return o.astype(x.dtype)
 
 
 # ----------------------------------------------------------------------

@@ -73,8 +73,8 @@ PREFIX = "sxt:"
 # "attn_core" and "sconv_out" inside "attn_out". A Mamba-2 state-space layer
 # (mixer "ssm") opens "ssm_in" (its input projection), "ssm_conv" (taps, bias,
 # SiLU) and "ssm_gates" (the step and the decay) inside "attn_qkv", "ssm_scan"
-# inside "attn_core", "ssm_out_norm" (the gate and the grouped norm) and
-# "ssm_out" (the projection back) inside "attn_out". A layer that is a mixer
+# (without its skip) inside "attn_core", "ssm_out_norm" (the skip "D x", the
+# gate and the grouped norm) and "ssm_out" inside "attn_out". A layer that is a mixer
 # alone (ffn "none") opens no scope of the "mlp" layer.
 # "plumbing" is what belongs to no layer of the model: the layer scan's own
 # slicing and stacking, the masters' cast to the compute dtype, the

@@ -452,6 +452,28 @@ def test_paged_kernels_quantized_kv_lower(store):
 
 GPT2 = dict(D=768, H=12, KV=12, Dh=64, F=3072, V=50257, rope=False,
             bias=True, gated=False)
+def _ssm_gate_norm_vjp(o, x, z, D, gain, dout, groups=8):
+    """The output and the five gradients through the epilogue's kernels
+    themselves (the dispatching entry takes the XLA form off a TPU)."""
+    from shuffle_exchange_tpu.ops.ssm_gate_norm import _ssm_gate_norm_pallas
+
+    out, back = jax.vjp(lambda *a: _ssm_gate_norm_pallas(*a, groups, 1e-5), o, x, z, D, gain)
+    return (out,) + back(dout)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_ssm_gate_norm_lowers(dtype):
+    """The forward and the backward launch; a ragged last block of rows;
+    bf16 and float32 activations; groups of one lane tile (two a lane block)
+    and of three (a lane block of 384)."""
+    import functools
+
+    for inner, groups, H, T in ((512, 4, 8, 1100), (768, 2, 6, 100)):
+        rows = jnp.zeros((2, T, inner), dtype)
+        _tpu_lower(functools.partial(_ssm_gate_norm_vjp, groups=groups), rows, rows, rows,
+                   jnp.zeros((H,), jnp.float32), jnp.zeros((inner,), jnp.float32), rows)
+
+
 LLAMA = dict(D=4096, H=32, KV=8, Dh=128, F=14336, V=128256, rope=True,
              bias=False, gated=True)
 GEOMS = {"gpt2-125m": GPT2, "llama3-8b": LLAMA}
@@ -784,6 +806,18 @@ def test_ssm_conv_compiles(chip_compile):
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 6
     assert "ssm_conv_fwd" in text and "ssm_conv_bwd" in text
+
+
+def test_ssm_gate_norm_compiles(chip_compile):
+    """The epilogue's two kernels at the shape ``nemotron3-train`` runs them:
+    two rows of 8,192 tokens, 4096 channels in 8 groups of 512 and 64 heads of
+    64, bf16 with a float32 skip and gain; one launch forward, one backward."""
+    rows = ((2, 8192, 4096), _BF16)
+    compiled = chip_compile(_ssm_gate_norm_vjp, rows, rows, rows, ((64,), _F32),
+                            ((4096,), _F32), rows)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "ssm_gate_norm_fwd" in text and "ssm_gate_norm_bwd" in text
 
 
 def test_grouped_gemm_compiles_at_a_width_of_half_lane_tiles(chip_compile):

@@ -349,19 +349,26 @@ def test_the_state_space_mixer_alone_and_its_scopes(case):
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_the_mixers_convolution_kernels_are_the_xla_form(remat, monkeypatch):
-    """The state-space mixer alone with its convolution as XLA's
-    ``silu(causal_conv1d)`` and as the interpreted kernels
-    (``ops/ssm_conv.py``; 4 heads of 64 and 2 groups of a state of 64 put z,
-    x, B and C on whole lane tiles and leave the scan its XLA form, so the
-    convolution is all that differs): the same output and the same gradient
-    of every leaf and of the input, with the mixer replayed under
-    ``jax.checkpoint`` (what per-half remat does to it) and without. 200
-    tokens: ONE ragged block of rows."""
+@pytest.mark.parametrize("state, scan", [(64, "xla"), (128, "interpret")],
+                         ids=["xla_scan", "scan_kernels"])
+def test_the_mixers_kernels_are_the_xla_forms(remat, state, scan, monkeypatch):
+    """The state-space mixer alone on XLA's bodies and on the interpreted
+    kernels: the convolution (``ops/ssm_conv.py``), the epilogue
+    (``ops/ssm_gate_norm.py``: the skip, the gate, the grouped norm) and, at a
+    state of 128, the scan (``ops/ssd.py``, called without its skip); 4 heads
+    of 64 and 2 groups put z, x, B and C and a group's 128 channels on whole
+    lane tiles, and a state of 64 leaves the scan its XLA form, so that the
+    convolution and the epilogue are all that differs. The same output and
+    the same gradient of every leaf and of the input, with the mixer replayed
+    under ``jax.checkpoint`` (what per-half remat does to it) and without.
+    200 tokens: ONE ragged block of rows."""
+    from shuffle_exchange_tpu.ops import ssd
     from shuffle_exchange_tpu.ops import ssm_conv as sc
+    from shuffle_exchange_tpu.ops import ssm_gate_norm as gn
+    from tests.test_ssm_conv import calls
 
     hf = dict(HF, num_hidden_layers=5, mamba_num_heads=4, mamba_head_dim=64,
-              ssm_state_size=64)
+              ssm_state_size=state)
     model = Transformer(config_from_hf(hf))
     params = driver.initial_params(model, 9, BIAS_STD)
     lw = jax.tree.map(lambda a: a[0, 1], {k: params["layers"]["ssm_moe"][k]
@@ -378,16 +385,24 @@ def test_the_mixers_convolution_kernels_are_the_xla_form(remat, monkeypatch):
         dlw, dx = back(push)
         return dict(dlw, out=out, x=dx)
 
-    zxbcdt = jnp.zeros((BATCH, 200, 2 * 256 + 2 * 128 + 4))
-    route = lambda: sc.ssm_conv_route(zxbcdt, lw["ssm_conv_w"], 256, (256, 128, 128))
-    assert route() == "xla"
+    wide = 256 + 2 * 2 * state
+    zxbcdt = jnp.zeros((BATCH, 200, 256 + wide + 4))
+    routes = lambda: (
+        sc.ssm_conv_route(zxbcdt, lw["ssm_conv_w"], 256, (256, 2 * state, 2 * state)),
+        gn.ssm_gate_norm_route(zxbcdt[..., :256], 2),
+        ssd.ssd_route(jnp.zeros((BATCH, 200, 4, 64)), jnp.zeros((BATCH, 200, 2, state))))
+    assert routes() == ("xla",) * 3
     want = answers()
+    launches = lambda: [name for name, _ in calls(lambda lw, x: model._ssm(lw, x, None), lw, x)]
+    assert launches() == []
     monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
-    assert route() == "interpret"
+    assert routes() == ("interpret", "interpret", scan)
     got = answers()
+    assert launches() == (
+        ["ssm_conv_fwd"] * 3 + ["ssd_fwd"] * (scan != "xla") + ["ssm_gate_norm_fwd"])
     worst = gaps(got, want)
     assert len(worst) == len(driver._MIXER["ssm"]) + 2
-    assert max(worst.values()) < 1e-5, worst
+    assert max(worst.values()) < (1e-5 if scan == "xla" else 2e-4), worst
 
 
 def test_a_block_without_an_ffn_opens_no_ffn_scope(case):
@@ -450,13 +465,20 @@ def test_the_trainer_runs_the_scans_kernels_where_the_heads_fill_lane_tiles(monk
     monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
     loss, moment, text, scopes = first_step()
     assert "ssd_bwd" in text and "ssd_fwd_keep" in text and "ssd_bwd" not in xla_text
-    # the convolution's two kernels too (``ops/ssm_conv.py``), under the
-    # mixer's scope ``ssm_conv``, the backward's in the backward pass
-    for kernel in ("ssm_conv_fwd", "ssm_conv_bwd"):
-        under = [s for s in scopes if "/ssm_conv/" in s and f"/{kernel}/" in s]
+    # the convolution's two kernels too (``ops/ssm_conv.py``) and the
+    # epilogue's (``ops/ssm_gate_norm.py``), under the mixer's scopes
+    # ``ssm_conv`` and ``ssm_out_norm``, the backward's in the backward pass
+    for own, kernel in (("ssm_conv", "ssm_conv_fwd"), ("ssm_conv", "ssm_conv_bwd"),
+                        ("ssm_out_norm", "ssm_gate_norm_fwd"),
+                        ("ssm_out_norm", "ssm_gate_norm_bwd")):
+        under = [s for s in scopes if f"/{own}/" in s and f"/{kernel}/" in s]
         assert under and not any(kernel in s for s in xla_scopes), kernel
-    assert any("rematted_computation" in s for s in scopes if "/ssm_conv_fwd/" in s)
-    assert all("transpose(" in s for s in scopes if "/ssm_conv_bwd/" in s)
+    # (behind ``optimize_remat`` one interpreted op of a backward launch
+    # carries the launch's own name and no path: read those under the scope)
+    for own, fwd, bwd in (("/ssm_conv/", "ssm_conv_fwd", "ssm_conv_bwd"),
+                          ("/ssm_out_norm/", "ssm_gate_norm_fwd", "ssm_gate_norm_bwd")):
+        assert any("rematted_computation" in s for s in scopes if f"/{fwd}/" in s)
+        assert all("transpose(" in s for s in scopes if own in s and f"/{bwd}/" in s)
     assert abs(loss - xla_loss) < 1e-5
     # (a leaf no token reached has a zero gradient in both)
     worst = {k: v for k, v in gaps(moment, xla_moment).items() if v == v}

@@ -118,6 +118,36 @@ def test_the_kernels_round_bf16_operands_as_the_xla_form_does(interpreted, monke
         assert gap(ours, exact) <= max(2 * gap(xla, exact), 1e-3), name
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+@pytest.mark.parametrize("route", ["xla", "interpret"])
+def test_without_the_skip_the_scan_is_that_less_d_x(monkeypatch, route, dtype):
+    """``D`` None (the mixer's call: its epilogue adds the skip): the output
+    in x's dtype is the scan's with ``D`` less ``D x`` (to one rounding of the
+    sum in bf16), and x's gradient lacks ``D`` times the cotangent; with zeros
+    for ``D`` the two calls are the same numbers."""
+    if route == "interpret":
+        monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
+    shape = dict(T=140, H=4, P=64, G=2, N=128) if route == "interpret" else dict(T=50)
+    x, dt, A, B, C, D = drawn(dtype=dtype, **shape)
+    assert ssd_route(x, B, CHUNK if route == "interpret" else 16) == route
+    chunk = CHUNK if route == "interpret" else 16
+    f32 = jnp.float32
+    bare, back = jax.vjp(lambda *a: ssd_chunked(*a, chunk=chunk), x, dt, A, B, C)
+    full, back_full = jax.vjp(lambda *a: ssd_chunked(*a, chunk=chunk), x, dt, A, B, C, D)
+    zero = ssd_chunked(x, dt, A, B, C, jnp.zeros_like(D), chunk=chunk)
+    assert bare.dtype == full.dtype == dtype
+    np.testing.assert_array_equal(bare.astype(f32), zero.astype(f32))
+    skip = D[:, None] * x.astype(f32)
+    tol = 1e-5 if dtype == f32 else 2e-2
+    np.testing.assert_allclose(full.astype(f32), bare.astype(f32) + skip, rtol=tol, atol=tol)
+    push = jnp.ones_like(bare)
+    got, want = back(push), back_full(push)
+    np.testing.assert_allclose(got[0].astype(f32) + D[:, None] * push.astype(f32),
+                               want[0].astype(f32), rtol=tol, atol=tol)
+    for a, b in zip(got[1:], want[1:5]):
+        np.testing.assert_allclose(a.astype(f32), b.astype(f32), rtol=tol, atol=tol)
+
+
 def test_route_and_chunk_count(monkeypatch):
     x, _, _, B, *_ = drawn(8)
     assert ssd_route(x, B) == "xla"
