@@ -188,6 +188,16 @@ class InferenceEngine:
                 "one-token step of the scan, and they scan whole (mixer, ffn) "
                 "blocks of one kind (training through sxt.initialize is; "
                 "ROADMAP R-M11)")
+        if getattr(self._mcfg, "norm_order", "input") != "input":
+            raise NotImplementedError(
+                "serving the Olmo Hybrid family (model_type olmo_hybrid: blocks "
+                "that norm each sublayer's OUTPUT, Gated DeltaNet layers whose "
+                "write strength is 2 sigmoid beside full attention under a "
+                "whole-projection q/k norm; norm_order 'output') is not "
+                "implemented: the inference engines' cached paths norm a "
+                "block's input, keep one kind of state, a KV cache, for one "
+                "kind of layer, and have no one-token step of the delta rule "
+                "(training through sxt.initialize is; ROADMAP R-M12)")
         if getattr(self._mcfg, "recurrent", False) or len(
                 getattr(self._mcfg, "pattern", ((),))) > 1:
             # the cached paths scan ONE kind of layer over a KV cache: a

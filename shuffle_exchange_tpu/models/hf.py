@@ -57,6 +57,7 @@ _ARCH_FAMILIES = {
     "DeepseekV3ForCausalLM": "deepseekv3",
     "Lfm2MoeForCausalLM": "lfm2moe",
     "NemotronHForCausalLM": "nemotronh",
+    "OlmoHybridForCausalLM": "olmohybrid",
 }
 
 
@@ -72,6 +73,7 @@ _MODEL_TYPE_FAMILIES = {"llama": "llama", "mistral": "llama", "qwen2": "qwen2",
                         "laguna": "laguna",
                         "lfm2_moe": "lfm2moe",
                         "nemotron_h": "nemotronh",
+                        "olmo_hybrid": "olmohybrid",
                         "megatron": "megatron",
                         "megatron-gpt": "megatron", "megatron_gpt": "megatron"}
 
@@ -285,6 +287,74 @@ def _nemotron_h_config(cfg: Dict[str, Any]) -> TransformerConfig:
         aux_loss_coef=alpha)
 
 
+def _olmo_hybrid_config(cfg: Dict[str, Any]) -> TransformerConfig:
+    """allenai's ``model_type: olmo_hybrid`` as Olmo-Hybrid-7B ships it:
+    ``layer_types`` gives the period (``linear_attention`` layers: FLA's Gated
+    DeltaNet at ``linear_num_key_heads`` / ``linear_num_value_heads`` heads of
+    ``linear_key_head_dim`` / ``linear_value_head_dim`` with
+    ``linear_conv_kernel_dim`` taps, beta = 2 sigmoid where
+    ``linear_allow_neg_eigval``; ``full_attention`` layers: causal attention
+    under an RMSNorm of q and k over the WHOLE projection, rotated by nothing
+    where ``rope_parameters.rope_theta`` is null), every block in the Olmo 2 / 3
+    order (each sublayer's OUTPUT normed, no norm on the way in), a dense
+    SwiGLU of ``intermediate_size`` in every layer, plain-gain RMSNorms at
+    ``rms_norm_eps``, an untied head. Only the first ``num_hidden_layers``
+    entries of ``layer_types`` are read (a cut in depth keeps the published
+    list whole). What is not written here is refused by name."""
+    rope = cfg.get("rope_parameters") or {}
+    theta = rope.get("rope_theta", cfg.get("rope_theta"))
+    refused = {
+        "attention_bias": bool(cfg.get("attention_bias")),
+        "hidden_act": cfg.get("hidden_act", "silu") != "silu",
+        "tie_word_embeddings": bool(cfg.get("tie_word_embeddings", False)),
+        "rope_parameters": theta is not None or any(
+            rope.get(k) not in (None, "default") for k in rope if k != "rope_theta"),
+        "sliding_window": cfg.get("sliding_window") is not None,
+        "num_experts": bool(cfg.get("num_experts")),
+    }
+    for key, bad in refused.items():
+        if bad:
+            raise ValueError(
+                f"olmo_hybrid with {key}={cfg.get(key)!r} is not supported (written "
+                "down: no bias, silu, an untied head, full attention that rotates "
+                "nothing (rope_parameters.rope_theta null), dense feed-forward parts)")
+    L = int(cfg["num_hidden_layers"])
+    types = list(cfg["layer_types"])
+    if not 0 < L <= len(types):
+        raise ValueError(f"olmo_hybrid: num_hidden_layers={L} of the {len(types)} "
+                         "entries in layer_types")
+    mixers = {"linear_attention": "gdn", "full_attention": "attn"}
+    for i, kind in enumerate(types[:L]):
+        if kind not in mixers:
+            raise ValueError(f"olmo_hybrid with layer_types[{i}]={kind!r} is not "
+                             f"supported (written down: {sorted(mixers)})")
+    kinds = [(mixers[kind], "mlp") for kind in types[:L]]
+    period = next(p for p in range(1, L + 1)
+                  if L % p == 0 and kinds[:p] * (L // p) == kinds)
+    if len(set(kinds[:period])) < 2:
+        raise ValueError(f"olmo_hybrid: the {L} layers read are all "
+                         f"{types[0]!r}: the family is a stack of both kinds")
+    Hk, Hv = cfg["linear_num_key_heads"], cfg["linear_num_value_heads"]
+    if Hv % Hk:
+        raise ValueError(f"olmo_hybrid: linear_num_value_heads={Hv} is not a "
+                         f"multiple of linear_num_key_heads={Hk}")
+    return TransformerConfig(
+        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"], n_layers=L,
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg.get("num_key_value_heads"),
+        head_size=int(cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]),
+        d_ff=cfg["intermediate_size"],
+        max_seq_len=cfg.get("max_position_embeddings", 4096),
+        activation="swiglu", norm="rmsnorm", position="none",
+        norm_eps=cfg.get("rms_norm_eps", 1e-6), tie_embeddings=False,
+        qk_norm=True, norm_order="output", layer_pattern=tuple(kinds[:period]),
+        gdn_key_heads=Hk, gdn_value_heads=Hv,
+        gdn_key_dim=cfg["linear_key_head_dim"],
+        gdn_value_dim=cfg["linear_value_head_dim"],
+        gdn_conv_kernel=int(cfg.get("linear_conv_kernel_dim", 4)),
+        gdn_beta_scale=2.0 if cfg.get("linear_allow_neg_eigval", False) else 1.0)
+
+
 def _laguna_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerConfig:
     """poolside's ``model_type: laguna`` as Laguna-XS.2 ships it: window
     (``sliding_attention``) and full-attention layers in one stack
@@ -392,6 +462,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
 
     if family == "nemotronh":
         return _nemotron_h_config(cfg)
+    if family == "olmohybrid":
+        return _olmo_hybrid_config(cfg)
     if family == "gpt2":
         return TransformerConfig(
             vocab_size=cfg["vocab_size"], d_model=cfg["n_embd"], n_layers=cfg["n_layer"],

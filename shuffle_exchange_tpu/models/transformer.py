@@ -53,9 +53,11 @@ class TransformerConfig:
     attn_qkv_bias: bool = False                # Qwen2-style q/k/v biases
     attn_out_bias: bool = False                # GPT-2/OPT-style out-proj bias
     pos_offset: int = 0                        # OPT offsets positions by 2
-    qk_norm: Any = False                       # True (OLMoE's): RMSNorm (learned gain) over
-                                               # the WHOLE q and k projections, before heads
-                                               # and RoPE: a one-kind model's "attn".
+    qk_norm: Any = False                       # True (OLMoE's, Olmo 2 / 3's): RMSNorm
+                                               # (learned gain) over the WHOLE q and k
+                                               # projections, before heads and RoPE: a
+                                               # one-kind model's "attn", and mixer "attn"
+                                               # of a stack of several kinds (``_gqa``).
                                                # "head" (LFM2's): RMSNorm per HEAD over its
                                                # head_dim, one gain [head_dim] each for q and
                                                # k shared by the heads, before RoPE: mixer
@@ -184,8 +186,8 @@ class TransformerConfig:
     #                       depthwise convolution with a bias and SiLU over
     #                       x B C, the scan, a gated grouped RMSNorm, one
     #                       projection back; no RoPE
-    #   q/k norm: "attn" takes ``qk_norm`` (True = the whole projection, a
-    #   one-kind model's; "head" = per head, among several kinds); "gated_attn"
+    #   q/k norm: "attn" takes ``qk_norm`` (True = the whole projection;
+    #   "head" = per head, among several kinds only); "gated_attn"
     #   norms per head always; "swa", "mla", "gdn", "sconv" and "ssm" have none.
     #   rotation: among several kinds "attn" rotates by the model's table, or
     #   by nothing where ``position`` is "none" (Nemotron-H: the state-space
@@ -204,6 +206,18 @@ class TransformerConfig:
     gdn_key_dim: int = 0                       # per key head (q and k)
     gdn_value_dim: int = 0                     # per value head
     gdn_conv_kernel: int = 4
+    # The write strength is ``gdn_beta_scale`` x sigmoid: 1.0 keeps the
+    # transition ``I - beta k k^T``'s eigenvalues in (0, 1) (Qwen3-Next), 2.0
+    # lets them reach (-1, 1) (FLA's ``allow_neg_eigval``, Olmo Hybrid's
+    # ``linear_allow_neg_eigval``).
+    gdn_beta_scale: float = 1.0
+    # Where a block of a stack of several kinds norms: "input" (pre-norm,
+    # ``h + mix(norm(h))``: every model before PR 50) or "output" (the Olmo
+    # 2 / 3 order: ``h + norm(mix(h))``, ``h + norm(ffn(h))``, nothing normed
+    # on the way in). The same gains (``ln1_w`` / ``ln2_w``) and scopes
+    # (``attn_norm`` / ``mlp_norm``) either way. (``post_ln`` is BERT's
+    # ``norm(h + f(h))``, a one-kind model's.)
+    norm_order: str = "input"
     sconv_taps: int = 3                        # mixer "sconv": the convolution's taps
     # mixer "ssm": ``ssm_heads`` heads of ``ssm_head_dim`` channels (the inner
     # width is their product, whatever d_model is), B and C of ``ssm_groups``
@@ -351,13 +365,22 @@ class TransformerConfig:
         lead = self.lead_layers if self.lead_layers and self.lead_kind[1] == "moe" else 0
         return lead + periods * sum(1 for _, ffn in period if ffn == "moe")
 
+    def layers_of(self, mixer: str) -> int:
+        """Layers whose mixer is ``mixer``, the leading ones counted."""
+        period = self.pattern
+        periods = (self.n_layers - self.lead_layers) // len(period)
+        lead = self.lead_layers if self.lead_layers and self.lead_kind[0] == mixer else 0
+        return lead + periods * sum(1 for m, _ in period if m == mixer)
+
     @property
     def ssm_layers(self) -> int:
         """Layers whose mixer is the state-space one: the scans a step walks."""
-        period = self.pattern
-        periods = (self.n_layers - self.lead_layers) // len(period)
-        lead = self.lead_layers if self.lead_layers and self.lead_kind[0] == "ssm" else 0
-        return lead + periods * sum(1 for mixer, _ in period if mixer == "ssm")
+        return self.layers_of("ssm")
+
+    @property
+    def gdn_layers(self) -> int:
+        """Layers whose mixer is the Gated DeltaNet: the rules a step walks."""
+        return self.layers_of("gdn")
 
     @property
     def dense_ff_dim(self) -> int:
@@ -1095,6 +1118,29 @@ class Transformer:
                 with trace.scope("moe" if ffn == "moe" else "mlp"):
                     return self._ffn(lw, h, y2, None, moe_on, ffn)
 
+            if cfg.norm_order == "output":
+                # the Olmo 2 / 3 order: the sublayer reads h as it is and its
+                # OUTPUT is normed before the residual add, under the same
+                # scopes and gains as the input order's norms
+                def mixer_half(lw, h):
+                    out = mix(lw, h, rope)
+                    with trace.scope("attn_norm"):
+                        out = _norm(out, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm,
+                                    eps=cfg.norm_eps)
+                    return h + out
+
+                def ffn_half(lw, h):
+                    with trace.scope("moe" if ffn == "moe" else "mlp"):
+                        ff, aux, stats = self._ffn(lw, h, h, None, moe_on, ffn,
+                                                   residual=False)
+                    with trace.scope("mlp_norm"):
+                        ff = _norm(ff, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm,
+                                   eps=cfg.norm_eps)
+                    return h + ff, aux, stats
+            elif cfg.norm_order != "input":
+                raise ValueError("norm_order is 'input' or 'output'; got "
+                                 f"{cfg.norm_order!r}")
+
             if remat_halves:
                 policy = _remat_policy(cfg.remat_policy)
                 mixer_half = jax.checkpoint(
@@ -1105,6 +1151,11 @@ class Transformer:
                 return mixer_half(lw, h), (jnp.zeros((), jnp.float32), None)
             h, aux, stats = ffn_half(lw, mixer_half(lw, h))
             return h, (aux, stats)
+        if cfg.norm_order != "input":
+            raise NotImplementedError(
+                f"norm_order={cfg.norm_order!r} (a block that norms its sublayers' "
+                "OUTPUT) is the form of a stack of several kinds (layer_pattern); "
+                "a one-kind model norms the input, or the sum (post_ln)")
         if cfg.post_ln:
             y = h   # BERT: sublayer input is unnormalized; LN follows the add
         else:
@@ -1192,16 +1243,21 @@ class Transformer:
         plain gain [head_dim] each, the block norm's eps) BEFORE the rotation,
         under ``attn_qk_norm`` (LFM2). With ``position`` "none" nothing is
         rotated and nothing else marks a position (Nemotron-H: the state-space
-        layers beside it carry the order; ``rope`` is then (None, None)). None of the softmax family's other flags
-        reaches this form (biases, the whole-projection q/k norm, ALiBi,
+        layers beside it carry the order; ``rope`` is then (None, None)). With
+        ``qk_norm`` True an "attn" layer norms q and k over the WHOLE
+        projection (gains [H x Dh] and [KV x Dh], a float32 statistic) before
+        the split into heads, under ``attn_qk_norm`` (Olmo Hybrid). None of
+        the softmax family's other flags reaches this form (biases, ALiBi,
         post-LN, a parallel block: a one-kind model's, ``layer_apply``)."""
         from jax.ad_checkpoint import checkpoint_name
 
         cfg = self.config
         head_norm = cfg.qk_norm == "head" and mixer == "attn"
+        whole_norm = cfg.qk_norm is True and mixer == "attn"
         flags = [f for f in ("attn_qkv_bias", "attn_out_bias", "post_ln",
                              "parallel_block", "attn_scale", "local_attention_window")
-                 if getattr(cfg, f)] + ["qk_norm"] * (cfg.qk_norm is True)
+                 if getattr(cfg, f)] + ["qk_norm"] * (cfg.qk_norm is True
+                                                    and not whole_norm)
         if flags or cfg.position not in ("rope", "none") or not cfg.causal:
             raise NotImplementedError(
                 f"a stack of several kinds runs mixer {mixer!r} as plain causal "
@@ -1220,10 +1276,21 @@ class Transformer:
         own = lambda name: trace.scope(name) if name else contextlib.nullcontext()
         swa = lambda part: own("swa_" + part if windowed else None)
         with trace.scope("attn_qkv"):
-            with swa("qkv"):
-                q = (y @ lw["wq"]).reshape(B, T, H, Dh)
-                k = (y @ lw["wk"]).reshape(B, T, KV, Dh)
+            if whole_norm:
+                # Olmo 2 / 3: RMSNorm over the WHOLE projection (all heads
+                # together, a gain a column, a float32 statistic), before the
+                # split into heads
+                q, k = y @ lw["wq"], y @ lw["wk"]
+                with trace.scope("attn_qk_norm"):
+                    q = _norm(q, lw["q_norm_w"], 0, "rmsnorm", eps=cfg.norm_eps)
+                    k = _norm(k, lw["k_norm_w"], 0, "rmsnorm", eps=cfg.norm_eps)
+                q, k = q.reshape(B, T, H, Dh), k.reshape(B, T, KV, Dh)
                 v = (y @ lw["wv"]).reshape(B, T, KV, Dh)
+            else:
+                with swa("qkv"):
+                    q = (y @ lw["wq"]).reshape(B, T, H, Dh)
+                    k = (y @ lw["wk"]).reshape(B, T, KV, Dh)
+                    v = (y @ lw["wv"]).reshape(B, T, KV, Dh)
             if head_norm:
                 with trace.scope("attn_qk_norm"):
                     q = _head_norm(q, lw["q_norm_w"], "rmsnorm", cfg.norm_eps)
@@ -1471,6 +1538,9 @@ class Transformer:
                 )(qkvz, lw["conv_w"])
             with trace.scope("gdn_gates"):
                 beta = jax.nn.sigmoid(b.astype(f32))
+                if cfg.gdn_beta_scale != 1.0:
+                    # eigenvalues of I - beta k k^T down to -1
+                    beta = cfg.gdn_beta_scale * beta
                 g = -jnp.exp(lw["A_log"].astype(f32)) * jax.nn.softplus(
                     a.astype(f32) + lw["dt_bias"].astype(f32))
         with trace.scope("attn_core"):
@@ -1493,9 +1563,10 @@ class Transformer:
                     z.astype(f32))).astype(y.dtype)
             return o.reshape(B, T, Hv * dv) @ lw["w_out"]
 
-    def _ffn(self, lw, h, y2, attn_out, moe_on, ffn=None):
+    def _ffn(self, lw, h, y2, attn_out, moe_on, ffn=None, residual=True):
         """The block's second half: (MoE or dense) feed-forward on ``y2`` and
-        the residual add. Returns (h, moe_aux, stats): ``stats`` is None for
+        the residual add (``residual=False``: the feed-forward's output alone,
+        for a block that norms it first). Returns (h, moe_aux, stats): ``stats`` is None for
         a dense model, else this layer's ``expert_tokens`` [E] int32 (the
         token-choices the router gave each of ALL its experts), ``router_prob``
         [E] (mean router probability), ``held_rows`` (the token-choices the
@@ -1619,6 +1690,8 @@ class Transformer:
         else:
             act = activation_fn(cfg.activation)
             ff = act(y2 @ lw["w_up"]) @ lw["w_down"]
+        if not residual:
+            return ff, aux, stats
         if cfg.post_ln:
             h = _norm(h + ff, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm,
                       eps=cfg.norm_eps)
@@ -2326,6 +2399,15 @@ class Transformer:
             # chunks x sequences x state-space layers
             stats["ssm_scan_chunks"] = jnp.asarray(
                 ssd_chunks(T) * B * cfg.ssm_layers, jnp.int32)
+        if cfg.gdn_layers:
+            from ..ops.gated_delta import CHUNK
+            from ..parallel.mesh import batch_rows_a_device
+
+            # the chunks the delta rules of this batch walk on ONE device (the
+            # rule runs per device on its own rows, ``_gdn``): a sequence's
+            # chunks x the device's sequences x DeltaNet layers
+            stats["gdn_scan_chunks"] = jnp.asarray(
+                -(-T // CHUNK) * batch_rows_a_device(B) * cfg.gdn_layers, jnp.int32)
         if routed is not None:
             stats["moe_expert_tokens"] = routed["expert_tokens"]
             stats["moe_held_rows"] = routed["held_rows"]

@@ -25,6 +25,9 @@ with the same arithmetic, chosen by what the code can observe
                   ``gdn_rule_bwd`` behind one ``jax.custom_vjp``. The CPU
                   suite drives the same kernels through the interpreter
                   (``SXT_FUSED_INTERPRET=1``). Selected, they run or raise.
+                  Heads that are nearly whole tiles (96 / 192, Olmo
+                  Hybrid's) take the same kernels on zero-padded lanes
+                  (route "pallas_padded"; the pads and o's slice are XLA's).
   XLA ops         everywhere else (the CPU, the 8-device CPU mesh, narrow
                   heads, other chunk sizes): einsums and a ``lax.scan`` over
                   the chunks. The off-TPU path, and the kernels' oracle
@@ -210,7 +213,8 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = CHUNK):
 
     route = kernel_route(q, k, v, chunk)
     if route != "xla":
-        return _gated_delta_pallas(q, k, v, g, beta, interpret=route == "interpret")
+        return _gated_delta_pallas(q, k, v, g, beta,
+                                   interpret=route.startswith("interpret"))
     f32 = jnp.float32
     mxu = q.dtype
     B, T, H, dk = q.shape
@@ -289,6 +293,9 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = CHUNK):
 # (row, head) pairs a grid step: their chains of small products are
 # independent, so the MXU works on one while another's result drains
 _HEADS_A_STEP = 8
+# what a grid step takes where the heads do not divide by that: the first
+# that divides them (30 heads: 6)
+_HEAD_GROUPS = (_HEADS_A_STEP, 6, 4, 2, 1)
 
 
 def kernel_route(q, k, v, chunk: int = CHUNK) -> str:
@@ -297,7 +304,9 @@ def kernel_route(q, k, v, chunk: int = CHUNK) -> str:
     eligible shape (dk and dv whole lane tiles, chunk ``CHUNK``, q, k and v
     all bf16 or all float32), "interpret" at such a shape under
     ``SXT_FUSED_INTERPRET=1`` (the CPU suite's way to the same kernels),
-    else "xla"."""
+    "pallas_padded" / "interpret_padded" where dk or dv is not whole tiles
+    but pads to them cheaply (``_pads_cheaply``: the same kernels on
+    zero-padded lanes), else "xla"."""
     import jax.numpy as jnp
 
     from .dispatch import interpret_forced, pallas_enabled
@@ -306,10 +315,31 @@ def kernel_route(q, k, v, chunk: int = CHUNK) -> str:
                 and v.shape[-1] % 128 == 0 and q.dtype == k.dtype == v.dtype
                 and q.dtype in (jnp.bfloat16, jnp.float32))
     if not eligible:
-        return "xla"
+        # heads that are not whole lane tiles but nearly (Olmo Hybrid's 96 /
+        # 192: 0.75 and 1.5 tiles): the same kernels on zero-padded lanes
+        padded = (chunk == CHUNK and _pads_cheaply(q.shape[-1])
+                  and _pads_cheaply(v.shape[-1]) and q.dtype == k.dtype == v.dtype
+                  and q.dtype in (jnp.bfloat16, jnp.float32))
+        if not padded:
+            return "xla"
+        if interpret_forced():
+            return "interpret_padded"
+        return "pallas_padded" if pallas_enabled() else "xla"
     if interpret_forced():
         return "interpret"
     return "pallas" if pallas_enabled() else "xla"
+
+
+def _lane_tiles(d: int) -> int:
+    """``d`` rounded up to whole lane tiles (128)."""
+    return -(-d // 128) * 128
+
+
+def _pads_cheaply(d: int) -> bool:
+    """A head width the kernels take on zero-padded lanes: whole tiles as
+    they are, else at most half as many lanes again (96 -> 128, 192 -> 256;
+    not the tests' 16 -> 128, which stays XLA's)."""
+    return 2 * _lane_tiles(d) <= 3 * d
 
 
 def _gated_delta_pallas(q, k, v, g, beta, interpret: bool = False):
@@ -323,11 +353,21 @@ def _gated_delta_pallas(q, k, v, g, beta, interpret: bool = False):
     import jax.numpy as jnp
 
     f32 = jnp.float32
-    B, T, H, _ = q.shape
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    if dk % 128 or dv % 128:
+        # route "pallas_padded": zero lanes up to whole tiles, AFTER the l2
+        # norm (the caller's), add nothing to any product (K K^T, Q K^T, the
+        # rows and columns of S they would write stay 0) and o's are cut off.
+        # Exact, and the pad and slice passes are the rule's own cost
+        lanes = lambda a, d: jnp.pad(a, ((0, 0),) * 3 + ((0, _lane_tiles(d) - d),))
+        o = _gated_delta_pallas(lanes(q, dk), lanes(k, dk), lanes(v, dv), g, beta,
+                                interpret)
+        return o[..., :dv]
     C = CHUNK
     q, k, v, g, beta = _whole_chunks(q, k, v, g, beta, C)
     N = q.shape[1] // C
-    G = next(n for n in (_HEADS_A_STEP, 4, 2, 1) if H % n == 0)
+    G = next(n for n in _HEAD_GROUPS if H % n == 0)
     rows = lambda a: a.reshape(B, N, C, H // G, G).transpose(0, 3, 1, 4, 2)
     gamma = jnp.cumsum(g.astype(f32).reshape(B, N, C, H), axis=2)
     wide = lambda a: jnp.swapaxes(a, 1, 2)                      # [B, H, T, d]

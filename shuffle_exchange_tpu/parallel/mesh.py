@@ -327,6 +327,18 @@ def kernel_activation_spec(shape, seq_dim: Optional[int] = None,
     return PartitionSpec(*spec)
 
 
+def batch_rows_a_device(batch: int) -> int:
+    """Rows of a batch-major activation of ``batch`` rows that one device
+    holds inside a kernel's shard_map (:func:`kernel_activation_spec`'s
+    dim 0): all of them outside :func:`kernel_mesh`."""
+    axes = kernel_activation_spec((batch, 1))[0]
+    if not axes:
+        return batch
+    mesh = _KERNEL_MESH.get()
+    axes = (axes,) if isinstance(axes, str) else axes
+    return batch // int(np.prod([mesh.shape[ax] for ax in axes]))
+
+
 def shard_kernel(fn, in_specs, out_specs):
     """``fn`` (a Pallas kernel call), made safe inside a program that spans
     several devices. XLA cannot partition a Mosaic kernel: there the call

@@ -404,16 +404,19 @@ def test_per_head_qk_norm_before_rope_against_the_closed_form(case):
 
 def test_which_qk_norm_is_whose(case):
     """``qk_norm`` "head" is mixer "attn"'s among several kinds; a one-kind
-    model's attention norms the whole projection (True) or nothing."""
+    model's attention norms the whole projection (True) or nothing. Since
+    PR 50 mixer "attn" among several kinds takes the whole-projection norm
+    too (Olmo Hybrid: ``tests/test_olmohybrid.py``); the window kind has
+    none and still refuses it."""
     one_kind = Transformer(dataclasses.replace(
         case["cfg"], layer_pattern=(), lead_layers=0, lead_kind=(), n_experts=0,
         n_experts_held=0, moe_select_bias=False, n_layers=1))
     with pytest.raises((NotImplementedError, ValueError), match="head"):
         params = one_kind.init(jax.random.PRNGKey(0))
         one_kind.apply(params, np.zeros((1, 4), np.int32))
-    whole = Transformer(dataclasses.replace(case["cfg"], qk_norm=True))
+    whole = Transformer(dataclasses.replace(case["cfg"], qk_norm=True, swa_window=4))
     with pytest.raises(NotImplementedError, match="qk_norm"):
-        whole._gqa({}, jnp.zeros((1, 8, 64)), whole.rope_for("attn", 8))
+        whole._gqa({}, jnp.zeros((1, 8, 64)), whole.rope_for("attn", 8), mixer="swa")
 
 
 # -- refusals ---------------------------------------------------------------------------

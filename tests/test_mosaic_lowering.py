@@ -783,6 +783,20 @@ def test_gated_delta_rule_compiles(chip_compile):
     assert "gdn_rule_fwd_keep" in text and "gdn_rule_bwd" in text
 
 
+def test_gated_delta_rule_compiles_on_padded_lanes(chip_compile):
+    """The same kernels at the shape ``olmohybrid-zero3-x4`` runs them on
+    each chip: two rows of 8,192 tokens, 30 heads of 96 / 192 padded to
+    128 / 256 lanes (route "pallas_padded"), six heads a grid step."""
+    from shuffle_exchange_tpu.ops.gated_delta import _gated_delta_pallas
+
+    keys, values = ((2, 8192, 30, 96), _BF16), ((2, 8192, 30, 192), _BF16)
+    flat = ((2, 8192, 30), _F32)
+    compiled = chip_compile(_gated_delta_vjp, keys, keys, values, flat, flat,
+                            ((2, 8192, 30, 192), _F32))
+    text = compiled.as_text()
+    assert "gdn_rule_fwd_keep" in text and "gdn_rule_bwd" in text
+
+
 def test_ssd_scan_compiles(chip_compile):
     """The three kernels at the shape ``nemotron3-train`` runs them: two rows
     of 8,192 tokens, 64 heads of 64 in 8 groups of a state of 128, bf16 with a
