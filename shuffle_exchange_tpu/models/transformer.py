@@ -1501,7 +1501,8 @@ class Transformer:
         with its own nested inside: ``gdn_conv`` (``gdn_prologue``: the
         convolution, SiLU, the l2 norms of q and k, the repeat to Hv heads
         and z's channels handed on, one pass forward and one backward where
-        the kernels run),
+        the kernels run: on a TPU wherever some group of key heads is whole
+        lane tiles of ``qkvz``, a key head at 128 / 128, a pair at 96 / 192),
         ``gdn_gates`` (beta and the log-decay g), ``gdn_scan`` (the chunked
         rule), ``gdn_out_norm``. g, beta, the norms and the rule's state are
         float32; the projections and the rule's matmul operands are the

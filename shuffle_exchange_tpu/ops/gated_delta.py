@@ -91,6 +91,7 @@ parts that are parallel over chunks are computed again under
 from __future__ import annotations
 
 import functools
+import math
 
 # Tokens a chunk of the chunked form: 64, where T = (I + A)^-1 is six products
 # of 64 x 64 and the scan over chunks has 128 trips at 8k tokens (ISSUE 33
@@ -746,17 +747,48 @@ def _prologue_shapes(qkvz, conv_w, key_heads, dk, dv):
     return W, rep
 
 
+# The widest group of key heads a grid step of the prologue's kernels takes,
+# in lanes of ``qkvz``: 9 tiles, Olmo-Hybrid's pair of key heads and the
+# widest that was compiled and run. At ``ROWS`` rows the backward launch then
+# holds 4.1 MB a buffer, each twice: the x block and d``qkvz``'s 1.18 MB of
+# bf16 each, the four cotangents' 1.57 MB (their minor axes padded to whole
+# lane tiles), the halo, the weights and dw's [8 K, Cw] float32 block 0.2 MB.
+_GROUP_LANES = 1152
+
+
+def _prologue_group(W: int, key_heads: int) -> int:
+    """G, the key heads a grid step of the prologue's kernels takes: the
+    fewest whose channels G x W are whole lane tiles (1 where W is, as
+    Qwen3-Next's 768; 2 at Olmo-Hybrid's 576: 1152 = 9 tiles). 0 where the
+    key heads do not come in such groups (15 heads of 576) or the group is
+    wider than ``_GROUP_LANES``."""
+    G = 128 // math.gcd(W, 128)
+    if key_heads % G or G * W > _GROUP_LANES:
+        return 0
+    return G
+
+
 def prologue_route(qkvz, conv_w, dk: int, dv: int) -> str:
     """Which form :func:`gdn_prologue` runs, from what it can observe, as
     :func:`kernel_route` does for the rule: "pallas" on a TPU backend at an
-    eligible shape (dk and dv whole lane tiles, a convolution no wider than
-    one 8-row sublane tile, bf16 or float32 activations), "interpret" at
-    such a shape under ``SXT_FUSED_INTERPRET=1``, else "xla"."""
+    eligible shape, "interpret" at such a shape under
+    ``SXT_FUSED_INTERPRET=1``, else "xla". Eligible: some group of key heads
+    is whole lane tiles of ``qkvz`` and no wider than a grid step takes
+    (``_prologue_group``; the key heads follow from the shapes: ``qkvz``'s
+    width less ``conv_w``'s is Hv dv),
+    dk and dv fill the lane tiles a head's segment takes as far as the rule
+    asks (``_pads_cheaply``: 128 / 128 and 96 / 192, not the tests' 16), a
+    convolution no wider than one 8-row sublane tile, bf16 or float32
+    activations."""
     import jax.numpy as jnp
 
     from .dispatch import interpret_forced, pallas_enabled
 
-    eligible = (dk % 128 == 0 and dv % 128 == 0 and conv_w.shape[0] <= 8
+    key_heads = (2 * conv_w.shape[1] - qkvz.shape[-1]) // (2 * dk)
+    eligible = (_pads_cheaply(dk) and _pads_cheaply(dv) and key_heads > 0
+                and _prologue_group(
+                    _prologue_shapes(qkvz, conv_w, key_heads, dk, dv)[0], key_heads) > 0
+                and conv_w.shape[0] <= 8
                 and qkvz.dtype in (jnp.bfloat16, jnp.float32))
     if not eligible:
         return "xla"
@@ -781,7 +813,12 @@ def gdn_prologue(qkvz, conv_w, key_heads: int, dk: int, dv: int,
     ``jax.custom_vjp``) read ``qkvz`` once forward, and once more with the
     three cotangents backward; the convolution's accumulator, SiLU, the sum
     of squares and the rsqrt are float32 and the result is rounded to the
-    compute dtype ONCE, at the write. They write q, k, v as [B, Hv, T, d],
+    compute dtype ONCE, at the write. A grid step takes ``rows`` rows of the
+    fewest key heads whose channels are whole lane tiles (``_prologue_group``:
+    one at 128 / 128, where W = 768; two at Olmo-Hybrid's 96 / 192, where
+    W = 576 and a pair is 1152 = 9 tiles) and takes each head's segment
+    where it lies in the block, on a tile's boundary or not. They write q,
+    k, v as [B, Hv, T, d] (d as it is: 96 and 192 are not padded here),
     which is what the rule's kernels read: the transpose back to
     [B, T, Hv, d] here and the rule's own to [B, Hv, T, d] cancel in XLA.
     z goes through the kernels too, and its cotangent into d``qkvz``'s z
@@ -823,21 +860,29 @@ def _gdn_prologue_xla(qkvz, conv_w, Hk, dk, dv, eps=1e-6):
 
 def _gdn_prologue_pallas(qkvz, conv_w, Hk, dk, dv, eps=1e-6, rows=ROWS,
                          interpret: bool = False):
-    """``gdn_prologue`` through the kernels. ``conv_w`` goes in as
-    [Hk, 8, 2 dk + rep dv] float32: permuted to the order the projection
-    left the channels in, one key head's [q | k | v] a row, K padded to a
-    sublane tile. T is padded to whole blocks of rows with zeros (nothing
-    where ``rows`` divides it); the padding, the permutation and the
-    transposes back to [B, T, Hv, d] are XLA's, and so are their gradients."""
+    """``gdn_prologue`` through the kernels, G key heads a grid step
+    (``_prologue_group``). ``conv_w`` goes in as [Hk / G, 8, G W - rep dv]
+    float32: permuted to the order the projection left the channels in, a
+    group's [q | k | v] of each key head at the lanes they have in the
+    group's G W channels of ``qkvz`` (zeros at the z's between two heads;
+    the last head's z's, which end the group, are left off: at G = 1 a row
+    is one key head's [q | k | v]), K padded to a sublane tile. T is padded
+    to whole blocks of rows with zeros (nothing where ``rows`` divides it);
+    the padding, the permutation and the transposes back to [B, T, Hv, d]
+    are XLA's, and so are their gradients."""
     import jax.numpy as jnp
 
     B, T, _ = qkvz.shape
     K = conv_w.shape[0]
-    _, rep = _prologue_shapes(qkvz, conv_w, Hk, dk, dv)
-    assert rows % _SUB == 0, rows
+    W, rep = _prologue_shapes(qkvz, conv_w, Hk, dk, dv)
+    G = _prologue_group(W, Hk)
+    assert G and rows % _SUB == 0, (W, Hk, rows)
     wq, wk, wv = jnp.split(conv_w.astype(jnp.float32), [Hk * dk, 2 * Hk * dk], axis=1)
     by_head = lambda w: w.reshape(K, Hk, -1)
     w = jnp.concatenate([by_head(wq), by_head(wk), by_head(wv)], axis=-1)
+    if G > 1:
+        w = jnp.pad(w, ((0, 0), (0, 0), (0, rep * dv)))
+        w = w.reshape(K, Hk // G, G * W)[..., :G * W - rep * dv]
     w = jnp.pad(jnp.swapaxes(w, 0, 1), ((0, 0), (0, 8 - K), (0, 0)))
     R = min(rows, -(-T // _SUB) * _SUB)
     x = jnp.pad(qkvz, ((0, 0), (0, -T % R), (0, 0)))
@@ -848,9 +893,10 @@ def _gdn_prologue_pallas(qkvz, conv_w, Hk, dk, dv, eps=1e-6, rows=ROWS,
 @functools.lru_cache(maxsize=None)
 def _prologue_core(K, dk, dv, rep, eps, R, interpret):
     """The prologue on whole blocks of R rows as one ``jax.custom_vjp``:
-    (x [B, T, Hk * W], w [Hk, 8, 2 dk + rep dv] float32) -> q, k, v, z
-    [B, Hv, T, d]. The input is the only residual; each launch under its own
-    jit, built once (see ``_delta_core``)."""
+    (x [B, T, Hk * W], w [Hk / G, 8, G W - rep dv] float32) -> q, k, v, z
+    [B, Hv, T, d]; G follows from the two shapes. The input is the only
+    residual; each launch under its own jit, built once (see
+    ``_delta_core``)."""
     import jax
 
     static = dict(K=K, dk=dk, dv=dv, rep=rep, eps=eps, R=R, interpret=interpret)
@@ -871,22 +917,28 @@ def _prologue_core(K, dk, dv, rep, eps, R, interpret):
     return core
 
 
-def _prologue_blocks(R, W, Cw, rep, block_at):
-    """The block specs of a grid step (row b, key head h, step n) that works
-    on rows ``block_at(n) * R`` onward: ``rows`` of x [B, T, Hk * W] (all W
-    channels of the head: whole lane tiles wherever the head's group
-    starts), ``halo`` the ``_HALO`` rows before them (the first block reads
-    its own and masks them), ``weights``, and ``wide(d)`` for q, k, v, z
-    [B, Hv, T, d]."""
+def _prologue_blocks(x, w, dk, dv, rep, R, block_at):
+    """(groups, heads, rows, halo, weights, wide) of a launch on x
+    [B, T, Hk * W] and w [Hk / G, 8, Cw]: the ``groups`` of G key heads, the
+    ``heads`` = G rep value heads of one, and the block specs of a grid step
+    (row b, group h, step n) that works on rows ``block_at(n) * R`` onward:
+    ``rows`` of x (the G W channels of the group: whole lane tiles wherever
+    the group starts), ``halo`` the ``_HALO`` rows before them (the first
+    block reads its own and masks them), ``weights``, and ``wide(d)`` for the
+    group's heads of q, k, v, z [B, Hv, T, d] (d is the array's whole minor
+    axis, a lane tile or not)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    rows = pl.BlockSpec((1, R, W), lambda b, h, n: (b, block_at(n), h))
-    halo = pl.BlockSpec((1, _HALO, W), lambda b, h, n: (
+    groups, _, Cw = w.shape
+    lanes = x.shape[-1] // groups
+    heads = lanes // (2 * dk + 2 * rep * dv) * rep
+    rows = pl.BlockSpec((1, R, lanes), lambda b, h, n: (b, block_at(n), h))
+    halo = pl.BlockSpec((1, _HALO, lanes), lambda b, h, n: (
         b, jnp.maximum(block_at(n) * (R // _HALO) - 1, 0), h))
     weights = pl.BlockSpec((1, 8, Cw), lambda b, h, n: (h, 0, 0))
-    wide = lambda d: pl.BlockSpec((1, rep, R, d), lambda b, h, n: (b, h, block_at(n), 0))
-    return rows, halo, weights, wide
+    wide = lambda d: pl.BlockSpec((1, heads, R, d), lambda b, h, n: (b, h, block_at(n), 0))
+    return groups, heads, rows, halo, weights, wide
 
 
 def _prologue_forward(x, w, K, dk, dv, rep, eps, R, interpret):
@@ -895,13 +947,12 @@ def _prologue_forward(x, w, K, dk, dv, rep, eps, R, interpret):
     from jax.experimental import pallas as pl
 
     B, Tp, _ = x.shape
-    Hk, _, Cw = w.shape
-    W = x.shape[-1] // Hk
-    rows, halo, weights, wide = _prologue_blocks(R, W, Cw, rep, lambda n: n)
-    out = lambda d: jax.ShapeDtypeStruct((B, Hk * rep, Tp, d), x.dtype)
+    groups, heads, rows, halo, weights, wide = _prologue_blocks(
+        x, w, dk, dv, rep, R, lambda n: n)
+    out = lambda d: jax.ShapeDtypeStruct((B, groups * heads, Tp, d), x.dtype)
     return pl.pallas_call(
         functools.partial(_prologue_fwd_kernel, K=K, dk=dk, dv=dv, rep=rep, eps=eps),
-        grid=(B, Hk, Tp // R),
+        grid=(B, groups, Tp // R),
         in_specs=[rows, halo, weights],
         out_specs=[wide(dk), wide(dk), wide(dv), wide(dv)],
         out_shape=[out(dk), out(dk), out(dv), out(dv)],
@@ -912,7 +963,7 @@ def _prologue_forward(x, w, K, dk, dv, rep, eps, R, interpret):
 
 def _prologue_backward(x, w, dq, dk_, dv_, dz, K, dk, dv, rep, eps, R, interpret):
     """The backward kernel's launch -> [dx, dw]. The sweep runs over the row
-    blocks from the last to the first; dw comes out as [B, Hk, 8 K, Cw]
+    blocks from the last to the first; dw comes out as [B, Hk / G, 8 K, Cw]
     partial sums (one a batch row and sublane) and is summed here."""
     import jax
     import jax.numpy as jnp
@@ -921,29 +972,39 @@ def _prologue_backward(x, w, dq, dk_, dv_, dz, K, dk, dv, rep, eps, R, interpret
 
     f32 = jnp.float32
     B, Tp, _ = x.shape
-    Hk, _, Cw = w.shape
-    W = x.shape[-1] // Hk
+    Cw = w.shape[2]
     N = Tp // R
-    rows, halo, weights, wide = _prologue_blocks(R, W, Cw, rep, lambda n: N - 1 - n)
+    groups, _, rows, halo, weights, wide = _prologue_blocks(
+        x, w, dk, dv, rep, R, lambda n: N - 1 - n)
     dx, dw = pl.pallas_call(
         functools.partial(_prologue_bwd_kernel, K=K, dk=dk, dv=dv, rep=rep, eps=eps),
-        grid=(B, Hk, N),
+        grid=(B, groups, N),
         in_specs=[rows, halo, weights, wide(dk), wide(dk), wide(dv), wide(dv)],
         out_specs=[rows, pl.BlockSpec((1, 1, 8 * K, Cw), lambda b, h, n: (b, h, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct((B, Hk, 8 * K, Cw), f32)],
+                   jax.ShapeDtypeStruct((B, groups, 8 * K, Cw), f32)],
         scratch_shapes=[pltpu.VMEM((8, Cw), f32)],
         compiler_params=_compiler_params(), interpret=interpret,
         name="gdn_prologue_bwd",
     )(x, x, w, dq, dk_, dv_, dz)
-    dw = jnp.sum(dw.reshape(B, Hk, K, 8, Cw), axis=(0, 3))
+    dw = jnp.sum(dw.reshape(B, groups, K, 8, Cw), axis=(0, 3))
     return dx, jnp.pad(dw, ((0, 0), (0, 8 - K), (0, 0)))
 
 
-def _segments(dk, dv, rep):
-    """(start, width) of each head of a key head's [q | k | v] channels: q,
-    k, then the ``rep`` value heads."""
-    return [(0, dk), (dk, dk)] + [(2 * dk + r * dv, dv) for r in range(rep)]
+def _segments(dk, dv, rep, lanes):
+    """(start, width, kind, head) of every head's channels among the
+    ``lanes`` of ``qkvz`` a grid step takes, a group of key heads side by
+    side, W = 2 dk + 2 rep dv each: a key head's q and k (``head``: the
+    first of the ``rep`` value heads it serves), then its ``rep`` value
+    heads' v; each v's z lies ``rep * dv`` lanes on. Starts are where the
+    projection put them: lane tiles' boundaries at 128 / 128, any multiple
+    of 32 at 96 / 192."""
+    W = 2 * dk + 2 * rep * dv
+    segs = []
+    for g in range(lanes // W):
+        segs += [(g * W, dk, "q", g * rep), (g * W + dk, dk, "k", g * rep)]
+        segs += [(g * W + 2 * dk + r * dv, dv, "v", g * rep + r) for r in range(rep)]
+    return segs
 
 
 def _rows_before(halo_ref, Cw, at_start):
@@ -981,32 +1042,33 @@ def _taps(ext, K):
 
 def _prologue_fwd_kernel(x_ref, halo_ref, w_ref, q_ref, k_ref, v_ref, z_ref, *,
                          K, dk, dv, rep, eps):
-    """R rows of one key head of one batch row: per head of the group the
-    convolution over the K rows that end at a row, SiLU and (q, k) the l2
-    norm, float32 until the write; q and k are written to the key head's
-    ``rep`` value heads, z's channels are copied."""
+    """R rows of one group of key heads of one batch row: per head's
+    segment (``_segments``) the convolution over the K rows that end at a
+    row, SiLU and (q, k) the l2 norm, float32 until the write; q and k are
+    written to the key head's ``rep`` value heads, z's channels are
+    copied."""
     import jax
     import jax.numpy as jnp
 
     from jax.experimental import pallas as pl
 
-    segs = _segments(dk, dv, rep)
-    w = [[w_ref[0, j:j + 1, a:a + n] for j in range(K)] for a, n in segs]
+    segs = _segments(dk, dv, rep, x_ref.shape[2])
+    w = [[w_ref[0, j:j + 1, a:a + n] for j in range(K)] for a, n, _, _ in segs]
     before = _rows_before(halo_ref, w_ref.shape[2], pl.program_id(2) == 0)
 
     def trip(c, carry):
-        for i, (a, n) in enumerate(segs):
+        for i, (a, n, kind, h) in enumerate(segs):
             ext, at = _chunk_rows(x_ref, before, c, slice(a, a + n))
             pre = sum(wj * xj for wj, xj in zip(w[i], _taps(ext, K)))
             act = pre * jax.nn.sigmoid(pre)
-            if i < 2:
+            if kind != "v":
                 unit = jax.lax.rsqrt(jnp.sum(act * act, axis=-1, keepdims=True) + eps)
-                out = (act * (unit * dk ** -0.5 if i == 0 else unit)).astype(q_ref.dtype)
+                out = (act * (unit * dk ** -0.5 if kind == "q" else unit)).astype(q_ref.dtype)
                 for r in range(rep):
-                    (q_ref if i == 0 else k_ref)[0, r, at, :] = out
+                    (q_ref if kind == "q" else k_ref)[0, h + r, at, :] = out
             else:
-                v_ref[0, i - 2, at, :] = act.astype(v_ref.dtype)
-                z_ref[0, i - 2, at, :] = x_ref[0, at, pl.ds(a + rep * dv, dv)]
+                v_ref[0, h, at, :] = act.astype(v_ref.dtype)
+                z_ref[0, h, at, :] = x_ref[0, at, pl.ds(a + rep * dv, dv)]
         return carry
 
     jax.lax.fori_loop(0, x_ref.shape[1] // _SUB, trip, 0)
@@ -1030,9 +1092,9 @@ def _prologue_bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dz_ref,
     from jax.experimental.pallas import tpu as pltpu
 
     f32 = jnp.float32
-    segs = _segments(dk, dv, rep)
+    segs = _segments(dk, dv, rep, x_ref.shape[2])
     trips = x_ref.shape[1] // _SUB
-    w = [[w_ref[0, j:j + 1, a:a + n] for j in range(K)] for a, n in segs]
+    w = [[w_ref[0, j:j + 1, a:a + n] for j in range(K)] for a, n, _, _ in segs]
     before = _rows_before(halo_ref, w_ref.shape[2],
                           pl.program_id(2) == pl.num_programs(2) - 1)
 
@@ -1044,23 +1106,23 @@ def _prologue_bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dz_ref,
     def trip(t, after):
         c = trips - 1 - t
         first = []
-        for i, (a, n) in enumerate(segs):
+        for i, (a, n, kind, h) in enumerate(segs):
             lanes = slice(a, a + n)
             ext, at = _chunk_rows(x_ref, before, c, lanes)
             taps = _taps(ext, K)
             pre = sum(wj * xj for wj, xj in zip(w[i], taps))
             sig = jax.nn.sigmoid(pre)
-            if i < 2:
-                ref = dq_ref if i == 0 else dk_ref
-                g = sum(ref[0, r, at, :].astype(f32) for r in range(rep))
+            if kind != "v":
+                ref = dq_ref if kind == "q" else dk_ref
+                g = sum(ref[0, h + r, at, :].astype(f32) for r in range(rep))
                 act = pre * sig
                 unit = jax.lax.rsqrt(jnp.sum(act * act, axis=-1, keepdims=True) + eps)
                 y = act * unit
                 dact = (g - y * jnp.sum(g * y, axis=-1, keepdims=True)) * (
-                    unit * dk ** -0.5 if i == 0 else unit)
+                    unit * dk ** -0.5 if kind == "q" else unit)
             else:
-                dact = dv_ref[0, i - 2, at, :].astype(f32)
-                dx_ref[0, at, pl.ds(a + rep * dv, dv)] = dz_ref[0, i - 2, at, :]
+                dact = dv_ref[0, h, at, :].astype(f32)
+                dx_ref[0, at, pl.ds(a + rep * dv, dv)] = dz_ref[0, h, at, :]
             dpre = dact * (sig * (1.0 + pre * (1.0 - sig)))
             for j, xj in enumerate(taps):
                 p = dpre * xj
@@ -1075,6 +1137,6 @@ def _prologue_bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dz_ref,
         return tuple(first)
 
     after = jax.lax.fori_loop(
-        0, trips, trip, tuple(ahead[:, a:a + n] for a, n in segs))
-    for (a, n), rows in zip(segs, after):
+        0, trips, trip, tuple(ahead[:, a:a + n] for a, n, _, _ in segs))
+    for (a, n, _, _), rows in zip(segs, after):
         ahead[:, a:a + n] = rows

@@ -414,36 +414,53 @@ def _gated_delta(rng) -> Iterator[dict]:
             yield _check(f"gated-delta-{jnp.dtype(dtype).name}-{part}", a, b, tol)
 
 
+# tag: (T, key heads, value heads a key head, dk, dv); 700 rows: a block of
+# 512 and a padded second one; 80: one ragged block of 128; 512: a whole one
+_PROLOGUE_CASES = {
+    "": (700, 4, 2, 128, 128),
+    "-2x96x192-ragged": (80, 2, 1, 96, 192),
+    "-2x96x192": (512, 2, 1, 96, 192),
+    "-30x96x192-ragged": (80, 30, 1, 96, 192),
+    "-30x96x192": (512, 30, 1, 96, 192),
+    # one key head a grid step whose segments start between lane tiles
+    "-2x192x192-ragged": (80, 2, 1, 192, 192),
+}
+
+
 def _gdn_prologue(rng) -> Iterator[dict]:
     """The DeltaNet mixer's prologue kernels (``ops/gated_delta.py``:
     ``gdn_prologue_fwd`` / ``gdn_prologue_bwd``) against the composition they
     replace (``silu(causal_conv1d)`` -> split -> ``l2norm`` -> repeat): q, k,
-    v, z and the gradients of ``qkvz`` and ``conv_w`` under seeded cotangents.
-    700 rows: a block of 512 and a padded second one. float32 agrees to
-    rounding; in bf16 the composition rounds the convolution's result before
-    SiLU, the kernels once at the write."""
+    v, z and the gradients of ``qkvz`` and ``conv_w`` under seeded cotangents,
+    at heads of 128 / 128 (a key head a grid step), of 96 / 192 (two: the
+    segments start between lane tiles; 2 key heads and Olmo-Hybrid's 30) and
+    of 192 / 192 (one, its segments between lane tiles too).
+    float32 agrees to rounding; in bf16 the composition rounds the
+    convolution's result before SiLU, the kernels once at the write."""
     import jax
     import jax.numpy as jnp
 
     from ..ops.gated_delta import _gdn_prologue_pallas, _gdn_prologue_xla
 
-    B, T, Hk, rep, d, K = 2, 700, 4, 2, 128, 4
+    B, K = 2, 4
     normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
-    qkvz = normal(B, T, Hk * (2 * d + 2 * rep * d))
-    conv_w = 0.5 * normal(K, Hk * (2 * d + rep * d))
-    cotangents = tuple(normal(B, T, Hk * rep, d) for _ in range(4))
+    for tag, (T, Hk, rep, dk, dv) in _PROLOGUE_CASES.items():
+        qkvz = normal(B, T, Hk * (2 * dk + 2 * rep * dv))
+        conv_w = 0.5 * normal(K, Hk * (2 * dk + rep * dv))
+        cotangents = tuple(normal(B, T, Hk * rep, d) for d in (dk, dk, dv, dv))
 
-    def answers(fn, qkvz, conv_w, cotangents):
-        out, back = jax.vjp(lambda x, w: fn(x, w, Hk, d, d), qkvz, conv_w)
-        return out + back(cotangents)
+        def answers(fn, qkvz, conv_w, cotangents):
+            out, back = jax.vjp(lambda x, w: fn(x, w, Hk, dk, dv), qkvz, conv_w)
+            return out + back(cotangents)
 
-    for dtype, tols in [(jnp.float32, (1e-5, 1e-5, 1e-5, 0.0, 1e-4, 5e-3)),
-                        (jnp.bfloat16, (2e-3, 2e-2, 1e-1, 0.0, 3e-1, 4.0))]:
-        args = (qkvz.astype(dtype), conv_w, tuple(c.astype(dtype) for c in cotangents))
-        got = jax.jit(lambda *a: answers(_gdn_prologue_pallas, *a))(*args)
-        want = jax.jit(lambda *a: answers(_gdn_prologue_xla, *a))(*args)
-        for part, a, b, tol in zip(("q", "k", "v", "z", "dqkvz", "dconv_w"), got, want, tols):
-            yield _check(f"gdn-prologue-{jnp.dtype(dtype).name}-{part}", a, b, tol)
+        for dtype, tols in [(jnp.float32, (1e-5, 1e-5, 1e-5, 0.0, 1e-4, 5e-3)),
+                            (jnp.bfloat16, (2e-3, 2e-2, 1e-1, 0.0, 3e-1, 4.0))]:
+            args = (qkvz.astype(dtype), conv_w, tuple(c.astype(dtype) for c in cotangents))
+            got = jax.jit(lambda *a: answers(_gdn_prologue_pallas, *a))(*args)
+            want = jax.jit(lambda *a: answers(_gdn_prologue_xla, *a))(*args)
+            for part, a, b, tol in zip(("q", "k", "v", "z", "dqkvz", "dconv_w"),
+                                       got, want, tols):
+                yield _check(f"gdn-prologue{tag}-{jnp.dtype(dtype).name}-{part}", a, b, tol)
 
 
 def run(seed: int = 0) -> Iterator[dict]:

@@ -299,48 +299,62 @@ def test_l2norm():
 PROLOGUE_PARTS = ("q", "k", "v", "z", "dqkvz", "dconv_w")
 
 
-def prologue_inputs(K, rep, dtype, T=150, B=2, Hk=2, d=128):
+def prologue_inputs(K, rep, dtype, T=150, B=2, Hk=2, dk=128, dv=128):
     """(qkvz, conv_w) as a mixer has them and cotangents of q, k, v, z: 150
     tokens in blocks of 64 rows is a first block (zeros before position 0),
     one with a block on both sides, and a ragged last one."""
-    W = 2 * d + 2 * rep * d
+    W = 2 * dk + 2 * rep * dv
     ks = jax.random.split(jax.random.PRNGKey(10 * K + rep), 6)
     qkvz = jax.random.normal(ks[0], (B, T, Hk * W)).astype(dtype)
-    conv_w = 0.5 * jax.random.normal(ks[1], (K, 2 * Hk * d + Hk * rep * d))
+    conv_w = 0.5 * jax.random.normal(ks[1], (K, 2 * Hk * dk + Hk * rep * dv))
     cotangents = tuple(jax.random.normal(k, (B, T, Hk * rep, d)).astype(dtype)
-                       for k in ks[2:])
+                       for k, d in zip(ks[2:], (dk, dk, dv, dv)))
     return (qkvz, conv_w), cotangents
 
 
-def prologue_answers(args, cotangents, exact=False):
+def prologue_answers(args, cotangents, exact=False, heads=(2, 128, 128)):
     """(q, k, v, z, dqkvz, dconv_w) of ``gdn_prologue`` (route chosen while
     tracing) in float32; ``exact``: the composition on the same numbers held
-    in float32 throughout."""
+    in float32 throughout. ``heads``: (key heads, dk, dv)."""
     def both(args, cotangents):
         qkvz, conv_w = args
         if exact:
             qkvz = qkvz.astype(jnp.float32)
             cotangents = tuple(c.astype(jnp.float32) for c in cotangents)
-        out, back = jax.vjp(lambda x, w: gd.gdn_prologue(x, w, 2, 128, 128, rows=64),
+        out, back = jax.vjp(lambda x, w: gd.gdn_prologue(x, w, *heads, rows=64),
                             qkvz, conv_w)
         return tuple(a.astype(jnp.float32) for a in out + back(cotangents))
     return jax.jit(both)(args, cotangents)
 
 
+# (key heads, dk, dv, value heads a key head, taps, tokens): heads of whole
+# lane tiles (a key head a grid step), then Olmo-Hybrid's 96 / 192 (two key
+# heads a grid step, their segments between lane tiles), 2 key heads and the
+# model's 30, 80 tokens (a ragged second block of 64 rows) and whole blocks;
+# last 192 / 192: ONE key head a grid step with its segments between lane tiles
+PROLOGUE_CASES = [(2, 128, 128, rep, K, 150) for K in (2, 4) for rep in (1, 2)] + [
+    (Hk, 96, 192, 1, 4, T) for Hk in (2, 30) for T in (80, 128)] + [
+    (2, 192, 192, 1, 4, 80)]
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
-@pytest.mark.parametrize("rep", [1, 2])
-@pytest.mark.parametrize("K", [2, 4])
-def test_prologue_kernels_equal_the_composition(monkeypatch, K, rep, dtype):
+@pytest.mark.parametrize("Hk, dk, dv, rep, K, T", PROLOGUE_CASES,
+                         ids=lambda v: str(v))
+def test_prologue_kernels_equal_the_composition(monkeypatch, Hk, dk, dv, rep, K, T,
+                                                dtype):
     """``silu(causal_conv1d)`` -> split -> ``l2norm`` -> repeat against the
     one pass, outputs and the gradients of ``qkvz`` and ``conv_w``. float32:
     the same numbers to rounding. bf16: the pass rounds once, at the write,
     where the composition rounds the convolution's result and again after
     the norm: no part is further from the float32 composition than the
     composition in bf16 is."""
-    args, cotangents = prologue_inputs(K, rep, dtype)
-    want = prologue_answers(args, cotangents)
+    heads = (Hk, dk, dv)
+    args, cotangents = prologue_inputs(K, rep, dtype, T=T, Hk=Hk, dk=dk, dv=dv)
+    assert gd.prologue_route(*args, dk, dv) == "xla"
+    want = prologue_answers(args, cotangents, heads=heads)
     monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
-    got = prologue_answers(args, cotangents)
+    assert gd.prologue_route(*args, dk, dv) == "interpret"
+    got = prologue_answers(args, cotangents, heads=heads)
     monkeypatch.delenv("SXT_FUSED_INTERPRET")
     gap = lambda a, b: float(jnp.linalg.norm((a - b).ravel()) / jnp.linalg.norm(b.ravel()))
     for a, b, part in zip(got, want, PROLOGUE_PARTS):
@@ -349,13 +363,13 @@ def test_prologue_kernels_equal_the_composition(monkeypatch, K, rep, dtype):
         for a, b, part in zip(got, want, PROLOGUE_PARTS):
             assert gap(a, b) < 2e-6, (part, gap(a, b))
     else:
-        exact = prologue_answers(args, cotangents, exact=True)
+        exact = prologue_answers(args, cotangents, exact=True, heads=heads)
         for a, b, c, part in zip(got, want, exact, PROLOGUE_PARTS):
             assert gap(a, c) <= 1.02 * gap(b, c) and gap(a, c) < 4e-3, (
                 part, gap(a, c), gap(b, c))
     # z's channels of a key head's group go through as they are, both ways
-    W = 256 + 2 * rep * 128
-    z_of = lambda x: x.reshape(2, 150, 2, W)[..., 256 + rep * 128:].reshape(got[3].shape)
+    W = 2 * dk + 2 * rep * dv
+    z_of = lambda x: x.reshape(2, T, Hk, W)[..., 2 * dk + rep * dv:].reshape(got[3].shape)
     np.testing.assert_array_equal(got[3], z_of(args[0].astype(jnp.float32)))
     np.testing.assert_array_equal(z_of(got[4]), cotangents[3].astype(jnp.float32))
 
@@ -375,24 +389,39 @@ def test_prologue_reaches_nothing_later_and_names_its_kernels(interpreted):
     assert calls(both, qkvz, conv_w) == [("gdn_prologue_fwd", 4), ("gdn_prologue_bwd", 2)]
 
 
-@pytest.mark.parametrize("why, dk, dv, K, dtype, forced, want", [
-    ("eligible", 128, 128, 4, jnp.bfloat16, True, "interpret"),
-    ("float32", 128, 128, 4, jnp.float32, True, "interpret"),
-    ("wider_heads", 256, 128, 2, jnp.bfloat16, True, "interpret"),
-    ("a_sublane_tile_of_taps", 128, 128, 8, jnp.bfloat16, True, "interpret"),
-    ("off_a_tpu", 128, 128, 4, jnp.bfloat16, False, "xla"),
-    ("narrow_keys", 16, 128, 4, jnp.bfloat16, True, "xla"),
-    ("narrow_values", 128, 64, 4, jnp.bfloat16, True, "xla"),
-    ("more_taps_than_a_sublane_tile", 128, 128, 9, jnp.bfloat16, True, "xla"),
-    ("float16", 128, 128, 4, jnp.float16, True, "xla"),
+@pytest.mark.parametrize("why, dk, dv, K, dtype, forced, want, Hk", [
+    ("eligible", 128, 128, 4, jnp.bfloat16, True, "interpret", 2),
+    ("float32", 128, 128, 4, jnp.float32, True, "interpret", 2),
+    ("wider_heads", 256, 128, 2, jnp.bfloat16, True, "interpret", 2),
+    ("a_sublane_tile_of_taps", 128, 128, 8, jnp.bfloat16, True, "interpret", 2),
+    ("off_a_tpu", 128, 128, 4, jnp.bfloat16, False, "xla", 2),
+    ("narrow_keys", 16, 128, 4, jnp.bfloat16, True, "xla", 2),
+    ("narrow_values", 128, 64, 4, jnp.bfloat16, True, "xla", 2),
+    ("more_taps_than_a_sublane_tile", 128, 128, 9, jnp.bfloat16, True, "xla", 2),
+    ("float16", 128, 128, 4, jnp.float16, True, "xla", 2),
+    # Olmo-Hybrid's heads: W = 576 is 4.5 lane tiles, two key heads are 9
+    ("pairs_of_30_key_heads", 96, 192, 4, jnp.bfloat16, True, "interpret", 30),
+    ("pairs_of_30_off_a_tpu", 96, 192, 4, jnp.bfloat16, False, "xla", 30),
+    # 15 key heads do not come in pairs: no group is whole lane tiles
+    ("15_key_heads_have_no_even_group", 96, 192, 4, jnp.bfloat16, True, "xla", 15),
+    # the tests' 16-wide heads: 8 key heads of W = 64 would be whole tiles,
+    # each segment a sixth of one
+    ("narrow_heads_in_whole_tiles", 16, 16, 4, jnp.float32, True, "xla", 8),
+    # 8 key heads of 96 / 200 (W = 592) are 37 lane tiles: wider than a step takes
+    ("a_group_wider_than_a_step_takes", 96, 200, 4, jnp.bfloat16, True, "xla", 8),
+    # and so is ONE key head of 256 / 384 (W = 1280 = 10 tiles): the bound is
+    # the block's, whatever the group
+    ("a_key_head_wider_than_a_step_takes", 256, 384, 4, jnp.bfloat16, True, "xla", 2),
+    # one key head a step with its segments between lane tiles (W = 768)
+    ("a_key_head_of_unaligned_segments", 192, 192, 4, jnp.bfloat16, True, "interpret", 2),
 ])
 def test_the_prologue_is_chosen_by_backend_and_shape(monkeypatch, why, dk, dv, K,
-                                                     dtype, forced, want):
+                                                     dtype, forced, want, Hk):
     if forced:
         monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
-    qkvz = jnp.zeros((1, 64, 2 * (2 * dk + 2 * dv)), dtype)
-    conv_w = jnp.zeros((K, 2 * (2 * dk + dv)), jnp.float32)
+    qkvz = jnp.zeros((1, 64, Hk * (2 * dk + 2 * dv)), dtype)
+    conv_w = jnp.zeros((K, Hk * (2 * dk + dv)), jnp.float32)
     assert gd.prologue_route(qkvz, conv_w, dk, dv) == want
     if want == "xla":
         # an ineligible shape runs the composition: no kernel in the program
-        assert calls(lambda x, w: gd.gdn_prologue(x, w, 2, dk, dv), qkvz, conv_w) == []
+        assert calls(lambda x, w: gd.gdn_prologue(x, w, Hk, dk, dv), qkvz, conv_w) == []

@@ -340,16 +340,19 @@ def _gdn_prologue_vjp(qkvz, conv_w, dq, dk, dv, dz, heads=(16, 128, 128)):
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_gdn_prologue_lowers(dtype):
     """The forward and the backward launch; a ragged last block of rows;
-    bf16 and float32 activations; one value head a key head and two."""
+    bf16 and float32 activations; one value head a key head and two; heads
+    of whole lane tiles and Olmo-Hybrid's 30 of 96 / 192 (two key heads a
+    grid step, segments between lane tiles)."""
     import functools
 
-    for rep, T in ((2, 600), (1, 100)):
-        Hk, d, K = 2, 128, 4
-        qkvz = jnp.zeros((2, T, Hk * (2 * d + 2 * rep * d)), dtype)
-        conv_w = jnp.zeros((K, Hk * (2 * d + rep * d)), jnp.float32)
-        wide = jnp.zeros((2, T, Hk * rep, d), dtype)
-        _tpu_lower(functools.partial(_gdn_prologue_vjp, heads=(Hk, d, d)),
-                   qkvz, conv_w, wide, wide, wide, wide)
+    for Hk, dk, dv, rep, T in ((2, 128, 128, 2, 600), (2, 128, 128, 1, 100),
+                               (30, 96, 192, 1, 600)):
+        K = 4
+        qkvz = jnp.zeros((2, T, Hk * (2 * dk + 2 * rep * dv)), dtype)
+        conv_w = jnp.zeros((K, Hk * (2 * dk + rep * dv)), jnp.float32)
+        wide = lambda d: jnp.zeros((2, T, Hk * rep, d), dtype)
+        _tpu_lower(functools.partial(_gdn_prologue_vjp, heads=(Hk, dk, dv)),
+                   qkvz, conv_w, wide(dk), wide(dk), wide(dv), wide(dv))
 
 
 def _ssd_vjp(x, dt, A, B, C, cotangent):
@@ -850,14 +853,26 @@ def test_grouped_gemm_compiles_at_a_width_of_half_lane_tiles(chip_compile):
     assert not _gmm_ok(jnp.zeros((N, 200), _BF16), jnp.zeros((E, 200, 1024), _BF16))
 
 
-def test_gdn_prologue_compiles(chip_compile):
-    """The prologue's two kernels at the shape ``qwen3next-train`` runs
-    them: two rows of 8,192 tokens, 16 key heads and 32 value heads of 128,
-    a convolution over 4 tokens, bf16 with a float32 ``conv_w``."""
-    qkvz = ((2, 8192, 16 * 768), _BF16)
-    wide = ((2, 8192, 32, 128), _BF16)
-    compiled = chip_compile(_gdn_prologue_vjp, qkvz, ((4, 8192), _F32),
-                            wide, wide, wide, wide)
+@pytest.mark.parametrize("Hk, rep, dk, dv", [(16, 2, 128, 128), (30, 1, 96, 192),
+                                             (4, 1, 192, 192)],
+                         ids=["qwen3next", "olmohybrid", "one_unaligned_key_head"])
+def test_gdn_prologue_compiles(chip_compile, Hk, rep, dk, dv):
+    """The prologue's two kernels at the shapes the cells run them: two rows
+    of 8,192 tokens, a convolution over 4 tokens, bf16 with a float32
+    ``conv_w``; ``qwen3next-train``'s 16 key heads and 32 value heads of 128
+    (``qkvz`` [2, 8192, 12288], a key head a grid step) and
+    ``olmohybrid-zero3-x4``'s 30 heads of 96 / 192 ([2, 8192, 17280], two key
+    heads = 9 lane tiles a grid step, every segment's loads and stores at
+    lanes between tiles: the widest block ``prologue_route`` admits); and
+    the other shape the route admits that no cell runs, ONE key head a grid
+    step whose segments lie between lane tiles (192 / 192, W = 768)."""
+    import functools
+
+    qkvz = ((2, 8192, Hk * (2 * dk + 2 * rep * dv)), _BF16)
+    wide = lambda d: ((2, 8192, Hk * rep, d), _BF16)
+    compiled = chip_compile(functools.partial(_gdn_prologue_vjp, heads=(Hk, dk, dv)),
+                            qkvz, ((4, Hk * (2 * dk + rep * dv)), _F32),
+                            wide(dk), wide(dk), wide(dv), wide(dv))
     text = compiled.as_text()
     assert "gdn_prologue_fwd" in text and "gdn_prologue_bwd" in text
 

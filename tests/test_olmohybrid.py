@@ -406,13 +406,15 @@ def test_zero3_over_fsdp_4_is_the_one_device_step():
     assert len(worst) > 20 and max(worst.values()) < 1e-3, worst
 
 
-def test_the_trainer_runs_the_padded_kernels_at_96_192(monkeypatch):
+def test_the_trainer_runs_the_rules_and_the_prologues_kernels_at_96_192(monkeypatch):
     """DeltaNet heads of 96 / 192 (the published widths): under
     ``SXT_FUSED_INTERPRET=1`` the train step's rule is the Pallas kernels over
-    padded lanes (interpreted) inside the period scan, the half-block's remat,
-    ZeRO-3 over fsdp 4 and ``shard_kernel``; the prologue stays XLA's. Its
-    first loss and first gradient are the XLA form's. 80 tokens: a ragged
-    second chunk."""
+    padded lanes and its prologue the two Pallas kernels on a group of two
+    key heads (1152 channels = 9 lane tiles; both interpreted) inside the
+    period scan, the half-block's remat, ZeRO-3 over fsdp 4 and
+    ``shard_kernel``. Its first loss and first gradient are the XLA form's.
+    80 tokens: a ragged second chunk of the rule, a padded block of the
+    prologue's rows."""
     hf = dict(HF, linear_num_key_heads=2, linear_num_value_heads=2,
               linear_key_head_dim=96, linear_value_head_dim=192)
     ids = np.random.default_rng(11).integers(0, 256, (8, 81)).astype(np.int32)
@@ -424,8 +426,8 @@ def test_the_trainer_runs_the_padded_kernels_at_96_192(monkeypatch):
     xla_loss, xla_moment, xla_text = step()
     monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
     loss, moment, text = step()
-    assert "gdn_rule_bwd" in text and "gdn_rule_bwd" not in xla_text
-    assert "gdn_prologue_bwd" not in text
+    for kernel in ("gdn_rule_bwd", "gdn_prologue_fwd", "gdn_prologue_bwd"):
+        assert kernel in text and kernel not in xla_text, kernel
     assert abs(loss - xla_loss) < 1e-5
     worst = {k: v for k, v in gaps(moment, xla_moment).items() if v == v}
     assert len(worst) > 20 and max(worst.values()) < 1e-3, worst
