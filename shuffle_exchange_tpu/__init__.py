@@ -10,15 +10,24 @@ methods (RR / shuffle / H-RR / Gossip) are first-class
 
 from __future__ import annotations
 
+import time as _time
+
+_import_began = _time.perf_counter()
+
 from typing import Any, Callable, Optional
 
 __version__ = "0.1.0"
 __version_major__, __version_minor__, __version_patch__ = 0, 1, 0
 
-from . import zero  # noqa: F401  (reference deepspeed.zero surface: Init, GatheredParameters)
-from .config import SXConfig, ConfigError
-from .parallel import comm  # noqa: F401  (dist facade: sxt.comm.psum etc.)
-from .parallel.mesh import MeshTopology, get_topology, initialize_topology, topology_is_initialized
+from .profiling import trace as _trace
+
+# the package's own import is the first phase of start-up (profiling/trace.py)
+with _trace.phase("init/import", t0=_import_began):
+    from . import zero  # noqa: F401  (reference deepspeed.zero surface: Init, GatheredParameters)
+    from .config import SXConfig, ConfigError
+    from .parallel import comm  # noqa: F401  (dist facade: sxt.comm.psum etc.)
+    from .parallel.mesh import (MeshTopology, get_topology, initialize_topology,
+                                topology_is_initialized)
 
 # Reference exposes `deepspeed.dist` after init; our facade is importable always.
 dist = comm
@@ -71,52 +80,55 @@ def initialize(
     if config is None and args is not None and getattr(args, "deepspeed_config", None) is not None:
         config = args.deepspeed_config
 
-    n_devices = len(jax.devices())
-    comm.init_distributed(dist_init_required=dist_init_required)
+    # the backend's first touch, the config as loaded for this world, the mesh
+    with _trace.phase("init/config"):
+        n_devices = len(jax.devices())
+        comm.init_distributed(dist_init_required=dist_init_required)
 
-    cfg = SXConfig.load(config, world_size=n_devices)
+        cfg = SXConfig.load(config, world_size=n_devices)
 
-    # Fork kwargs override/enable the shuffle_exchange config section.
-    if method is not None:
-        cfg.shuffle_exchange.method = method
-        cfg.shuffle_exchange.enabled = True
-    if shuffle_step is not None:
-        cfg.shuffle_exchange.shuffle_step = int(shuffle_step)
-        cfg.shuffle_exchange.enabled = True
-    if rings is not None:
-        cfg.shuffle_exchange.rings = int(rings)
-        cfg.shuffle_exchange.enabled = True
-    if slice_count is not None:
-        cfg.shuffle_exchange.slice_count = int(slice_count)
-    cfg.shuffle_exchange._validate()
-    if cfg.shuffle_exchange.enabled:
-        sc = cfg.shuffle_exchange.slice_count
-        if n_devices % sc:
-            raise ConfigError(f"slice_count {sc} must divide device count {n_devices} "
-                              "(reference: 'slice_count cannot be divided by real world size')")
-        # slice group = fsdp axis; logical nodes = data axis.
-        if cfg.mesh.fsdp == 1:
-            cfg.mesh.fsdp = sc
+        # Fork kwargs override/enable the shuffle_exchange config section.
+        if method is not None:
+            cfg.shuffle_exchange.method = method
+            cfg.shuffle_exchange.enabled = True
+        if shuffle_step is not None:
+            cfg.shuffle_exchange.shuffle_step = int(shuffle_step)
+            cfg.shuffle_exchange.enabled = True
+        if rings is not None:
+            cfg.shuffle_exchange.rings = int(rings)
+            cfg.shuffle_exchange.enabled = True
+        if slice_count is not None:
+            cfg.shuffle_exchange.slice_count = int(slice_count)
+        cfg.shuffle_exchange._validate()
+        if cfg.shuffle_exchange.enabled:
+            sc = cfg.shuffle_exchange.slice_count
+            if n_devices % sc:
+                raise ConfigError(f"slice_count {sc} must divide device count {n_devices} "
+                                  "(reference: 'slice_count cannot be divided by real world size')")
+            # slice group = fsdp axis; logical nodes = data axis.
+            if cfg.mesh.fsdp == 1:
+                cfg.mesh.fsdp = sc
+                cfg.mesh.data = -1
+
+        # ZeRO++ hpZ / MiCS: both express "shard over a small fast group,
+        # replicate across groups" (reference zero_hpz_partition_size /
+        # mics_shard_size, runtime/zero/config.py + mics.py). On the mesh this is
+        # an fsdp axis of the group size with the remaining DP factor on data —
+        # param all-gathers then ride the (ICI-contiguous) fsdp axis only.
+        z = cfg.zero_optimization
+        group = None
+        if z.mics_shard_size and z.mics_shard_size > 0:
+            group = z.mics_shard_size
+        elif z.stage == 3 and z.zero_hpz_partition_size > 1:
+            group = z.zero_hpz_partition_size
+        if group is not None and cfg.mesh.fsdp == 1:
+            if n_devices % group:
+                raise ConfigError(f"hpZ/MiCS shard group {group} must divide "
+                                  f"device count {n_devices}")
+            cfg.mesh.fsdp = group
             cfg.mesh.data = -1
 
-    # ZeRO++ hpZ / MiCS: both express "shard over a small fast group,
-    # replicate across groups" (reference zero_hpz_partition_size /
-    # mics_shard_size, runtime/zero/config.py + mics.py). On the mesh this is
-    # an fsdp axis of the group size with the remaining DP factor on data —
-    # param all-gathers then ride the (ICI-contiguous) fsdp axis only.
-    z = cfg.zero_optimization
-    group = None
-    if z.mics_shard_size and z.mics_shard_size > 0:
-        group = z.mics_shard_size
-    elif z.stage == 3 and z.zero_hpz_partition_size > 1:
-        group = z.zero_hpz_partition_size
-    if group is not None and cfg.mesh.fsdp == 1:
-        if n_devices % group:
-            raise ConfigError(f"hpZ/MiCS shard group {group} must divide device count {n_devices}")
-        cfg.mesh.fsdp = group
-        cfg.mesh.data = -1
-
-    topology = initialize_topology(cfg.mesh, force=True)
+        topology = initialize_topology(cfg.mesh, force=True)
 
     # Context parallelism (ISSUE 15): ``context_parallel.degree`` maps
     # onto the mesh "seq" axis (config._map_parallel_sizes) and ring

@@ -1,5 +1,5 @@
 """The program's one tracer: what trainer, model, mesh and scheduler report
-about themselves. Four primitives, no config field, no environment variable.
+about themselves. Five primitives, no config field, no environment variable.
 
 ``span(name)``
     A host span: ``jax.profiler.TraceAnnotation("sxt:" + name)``. Whenever
@@ -10,6 +10,19 @@ about themselves. Four primitives, no config field, no environment variable.
     nothing. Only after ``keep_spans(True)`` (the engine's
     ``wall_clock_breakdown``) does a span also append ``(name, t0, t1)`` on
     ``time.perf_counter`` to a bounded in-memory list.
+``phase(name)``
+    A span for what runs once per engine or once per program, never per step:
+    the package's import, ``sxt.initialize``, the sections of
+    ``Engine.__init__``, ``Engine.compile()``'s lowering and compiling, an
+    engine's first ``train_batch``. It is a ``span`` (same annotation, same
+    open-span stack: a compilation inside it is stamped with it) that is ALSO
+    always appended, on ``time.perf_counter``, to a bounded start-up log:
+    ``phases()`` hands out the rows (``name``, ``program``, ``t0``, ``t1``,
+    ``parent``: the open phase it nested in) with no profiler session and
+    without ``keep_spans``, so start-up can be read after the fact. A phase
+    is HOST time: what the host spent between two lines, device work it
+    waited for included, device work it only enqueued not. It synchronises
+    nothing, and it never goes on the hot path: a step opens ``span``s.
 ``scope(name)``
     ``jax.named_scope``, for code under ``jit``: it changes the ``op_name``
     metadata of the ops traced inside it and nothing else, so the device
@@ -18,10 +31,14 @@ about themselves. Four primitives, no config field, no environment variable.
     ``jax.profiler.StepTraceAnnotation``: one per ``train_batch`` and one per
     scheduler tick, so the profiler groups device work by step.
 ``compile_events()``
-    The program's own ``jax.monitoring`` listener: one record per backend
-    compilation (or persistent-cache read), stamped with the innermost open
-    span, the program that span was opened for, seconds, cache hit or not and
-    the ``perf_counter`` time. Bounded; always on.
+    The program's own ``jax.monitoring`` listener: one record per program
+    built, stamped with the innermost open phase or span and the program it
+    was opened for: ``trace_s`` (tracing to a jaxpr), ``lower_s`` (jaxpr to
+    an MLIR module: the two parts no cache serves), ``seconds`` (the backend
+    compilation, or the persistent cache's read), cache hit or not and the
+    ``perf_counter`` time. By default the records that ended in a backend
+    compilation or a cache read; ``every=True`` adds those of a lowering
+    alone. Bounded; always on.
 
 ``program_ops`` reads a compiled program's HLO text into instruction name ->
 (scope path, opcode, contains a collective): the join for traces whose device
@@ -103,13 +120,20 @@ PHASES = ("forward", "recompute", "backward", "update", "other")
 _BACKWARD_SCOPES = ("head_dx", "head_dw")
 
 _KEEP_MAX = 4096
-_EVENTS_MAX = 256
+_PHASES_MAX = 512
+# a run's programs: 160 in one training cell, 324 up a serving ladder
+_EVENTS_MAX = 1024
 
 _kept: Optional[collections.deque] = None       # (name, t0, t1) when kept
+_phases: collections.deque = collections.deque(maxlen=_PHASES_MAX)
 _events: collections.deque = collections.deque(maxlen=_EVENTS_MAX)
-_open = threading.local()                       # .stack: [(name, program)]
+# .stack: [(name, program)] of open spans and phases; .phases: open phases
+_open = threading.local()
 _listening = False
-_cache_hit = threading.local()
+# what this thread is building: .hit (the persistent cache answered),
+# .traced ({fun_name: seconds} since its last lowering), .lowered (the record
+# of that lowering, which a backend compilation may complete)
+_build = threading.local()
 
 
 def _stack() -> list:
@@ -155,6 +179,56 @@ class span:
         self._note.__exit__(*exc)
         _stack().pop()
         return False
+
+
+def _open_phases() -> list:
+    try:
+        return _open.phases
+    except AttributeError:
+        _open.phases = []
+        return _open.phases
+
+
+class phase(span):
+    """``with phase("init/params"):`` - a ``span`` whose row always goes to
+    the start-up log that ``phases()`` reads, and never to the per-step list
+    of ``keep_spans``. For code that runs once per engine or per program
+    (module docstring). ``t0`` backdates the start on ``perf_counter`` for a
+    phase that cannot be opened where it begins (the package's import opens
+    its own after importing this module)."""
+
+    __slots__ = ("parent", "_began")
+
+    def __init__(self, name: str, program: Optional[str] = None,
+                 t0: Optional[float] = None):
+        super().__init__(name, program)
+        self._began = t0
+
+    def __enter__(self):
+        if self._began is None:
+            self._began = time.perf_counter()
+        opened = _open_phases()
+        self.parent = opened[-1] if opened else None
+        opened.append(self.name)
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        # not ``span.__exit__``: that one feeds the per-step list
+        ended = time.perf_counter()
+        self._note.__exit__(*exc)
+        _stack().pop()
+        _open_phases().pop()
+        _phases.append({"name": self.name, "program": self.program,
+                        "t0": self._began, "t1": ended, "parent": self.parent})
+        return False
+
+
+def phases(since: float = 0.0) -> List[dict]:
+    """The start-up log: the phases closed so far that began at or after
+    ``since`` on ``perf_counter``, oldest first (a parent before its
+    children)."""
+    rows = [dict(r) for r in list(_phases) if r["t0"] >= since]
+    return sorted(rows, key=lambda r: (r["t0"], -r["t1"]))
 
 
 def scope(name: str):
@@ -226,27 +300,68 @@ def breakdown_line(rows, batch_size: int, step_span: str) -> str:
 # Compile events
 # ---------------------------------------------------------------------------
 
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _COMPILE = "/jax/core/compile/backend_compile_duration"
 _HIT = "/jax/compilation_cache/cache_hits"
 
 
 def _on_duration(event: str, secs: float, **kw) -> None:
-    if event != _COMPILE:
+    """One record per program built, from the three durations jax reports on
+    the building thread in this order: tracing (``fun_name`` "f"; every jit
+    traced inside it reports first, ``jax.eval_shape(f)`` reports one too,
+    and a call that finds the jaxpr cached reports ~0 s), lowering
+    ("jit(f)") and the backend's compilation or cache read ("jit(f)").
+    Tracings add up by name until a lowering claims its own and drops the
+    rest (they ran inside it), so a cached call leaves nothing; a lowering
+    opens the record; a backend compilation completes the record its thread
+    lowered last if that was the same program under the same span (a ``jit``
+    call), and is a record of its own otherwise (``lowered.compile()`` under
+    another phase than ``.lower()``; a compilation jax reports no lowering
+    for)."""
+    if event == _TRACE:
+        try:
+            traced = _build.traced
+        except AttributeError:
+            traced = _build.traced = {}
+        fun = kw.get("fun_name")
+        traced[fun] = traced.get(fun, 0.0) + float(secs)
+        return
+    if event != _COMPILE and event != _LOWER:
         return
     stack = _stack()
     name, program = stack[-1] if stack else (None, None)
-    hit = getattr(_cache_hit, "seen", False)
-    _cache_hit.seen = False
-    _events.append({"span": name, "program": program or kw.get("fun_name"),
-                    "fun_name": kw.get("fun_name"), "seconds": float(secs),
-                    "cache_hit": bool(hit), "at": time.perf_counter()})
+    fun = kw.get("fun_name")
+    now = time.perf_counter()
+    if event == _LOWER:
+        traced = getattr(_build, "traced", {})
+        inner = fun[fun.find("(") + 1:-1] if fun and fun.endswith(")") else fun
+        record = {"span": name, "program": program or fun, "fun_name": fun,
+                  "trace_s": traced.get(inner, 0.0), "lower_s": float(secs),
+                  "seconds": 0.0, "cache_hit": False, "compiled": False,
+                  "at": now}
+        traced.clear()          # what it traced inside is in its seconds
+        _build.lowered = record
+        _events.append(record)
+        return
+    hit = getattr(_build, "hit", False)
+    _build.hit = False
+    record = getattr(_build, "lowered", None)
+    _build.lowered = None
+    done = {"seconds": float(secs), "cache_hit": bool(hit), "compiled": True,
+            "at": now}
+    if record is not None and record["fun_name"] == fun and record["span"] == name:
+        record.update(done)
+    else:
+        _events.append({"span": name, "program": program or fun,
+                        "fun_name": fun, "trace_s": 0.0, "lower_s": 0.0, **done})
 
 
 def _on_event(event: str, **_) -> None:
     # the backend-compile duration wraps the persistent-cache lookup, so the
     # hit is seen before the duration it belongs to
     if event == _HIT:
-        _cache_hit.seen = True
+        _build.hit = True
 
 
 def _listen() -> None:
@@ -259,10 +374,15 @@ def _listen() -> None:
         jax.monitoring.register_event_listener(_on_event)
 
 
-def compile_events(since: float = 0.0) -> List[dict]:
-    """Compilations seen so far (``at`` >= ``since`` on ``perf_counter``)."""
+def compile_events(since: float = 0.0, every: bool = False) -> List[dict]:
+    """Programs built so far (``at`` >= ``since`` on ``perf_counter``, the
+    time of a record's last part): those that ended in a backend compilation
+    or a read of the persistent cache; with ``every`` also those of a tracing
+    and lowering alone (``compiled`` false, ``seconds`` 0: an AOT
+    ``.lower()``, a lowering whose executable jax already held)."""
     _listen()
-    return [dict(e) for e in _events if e["at"] >= since]
+    return [dict(e) for e in list(_events)
+            if e["at"] >= since and (every or e["compiled"])]
 
 
 # ---------------------------------------------------------------------------

@@ -107,6 +107,31 @@ def _tree_select(pred, a_tree, b_tree):
     return jax.tree_util.tree_map(lambda a, b: jnp.where(pred, a, b), a_tree, b_tree)
 
 
+class _Sections:
+    """Consecutive ``trace.phase``s of one block: calling it with a name
+    closes the section that is open and opens the next, leaving the block
+    (an exception too) closes the last. Lets ``Engine.__init__`` name its
+    sections where its comments already divide it."""
+
+    def __init__(self, first: str):
+        self._first = first
+        self._open = None
+
+    def __call__(self, name: str) -> None:
+        self.__exit__(None, None, None)
+        self._open = trace.phase(name).__enter__()
+
+    def __enter__(self):
+        self(self._first)
+        return self
+
+    def __exit__(self, *exc):
+        closing, self._open = self._open, None
+        if closing is not None:
+            closing.__exit__(*exc)
+        return False
+
+
 class Engine:
     def __init__(
         self,
@@ -127,6 +152,16 @@ class Engine:
         collate_fn=None,
         seed: int = 0,
     ):
+        # start-up is read by phase (profiling/trace.py): the sections below
+        # are the children of one ``init/engine`` and cover it end to end
+        with trace.phase("init/engine"), _Sections("init/rest") as section:
+            self._init(section, config, topology, loss_fn, params, params_init_fn,
+                       optimizer, lr_scheduler, model_partition_specs,
+                       training_data, collate_fn, seed)
+
+    def _init(self, section, config, topology, loss_fn, params, params_init_fn,
+              optimizer, lr_scheduler, model_partition_specs, training_data,
+              collate_fn, seed):
         import jax
         import jax.numpy as jnp
 
@@ -135,6 +170,7 @@ class Engine:
         self.loss_fn = loss_fn
         self._rng = np.random.default_rng(seed)
         self.global_steps = 0
+        self._first_step_ahead = True    # this engine has not stepped yet
         self.global_samples = 0
         self.skipped_steps = 0
         self.micro_steps = 0
@@ -286,6 +322,7 @@ class Engine:
                 model_partition_specs, self._lora_frozen_specs = _ol.split_specs(
                     model_partition_specs, frozen_template)
 
+        section("init/shardings")
         # --- sharding policy -------------------------------------------
         # MiCS (reference runtime/zero/mics.py): optimizer/master shards stay
         # inside the fsdp sub-group; replicas across "data" are plain DP.
@@ -356,6 +393,7 @@ class Engine:
                 self.frozen_shardings = fro_specs(
                     frozen_template, self._lora_frozen_specs or {})
 
+        section("init/params")
         # --- place master params ---------------------------------------
         frozen = ()
         if params_init_fn is not None:
@@ -412,6 +450,7 @@ class Engine:
                 frozen = jax.jit(self._encode_frozen,
                                  out_shardings=self.frozen_shardings)(frozen_host)
 
+        section("init/optimizer")
         # --- optimizer --------------------------------------------------
         self.client_optimizer = optimizer is not None
         base_lr = get_base_lr(config.optimizer)
@@ -514,6 +553,7 @@ class Engine:
         if self._host_opt_wanted:
             self._setup_host_optimizer()
 
+        section("init/rest")
         # --- tracer / monitors -----------------------------------------
         # wall_clock_breakdown: the tracer's spans are also kept in memory
         # and each step span closes on a loss that is ready (profiling/trace.py)
@@ -680,6 +720,7 @@ class Engine:
         # configs before the first collective deadlocks on them) ---------
         self._assert_cross_host_config()
 
+        section("init/programs")
         # --- jitted programs -------------------------------------------
         self._build_programs()
 
@@ -1747,7 +1788,17 @@ class Engine:
         ``train/place``, ``train/dispatch`` and ``train/post``
         (profiling/trace.py); under ``wall_clock_breakdown`` the step also
         waits for its loss (``train/wait``), so its span is a step's time and
-        not a dispatch's."""
+        not a dispatch's. An engine's first call is the phase
+        ``train/first_step``: what remains of bringing the step's program up
+        (tracing, lowering, the compilation or the cache's read) and one
+        dispatch."""
+        if self._first_step_ahead:
+            self._first_step_ahead = False
+            with trace.phase("train/first_step", program="train_step"):
+                return self._train_batch(batch, data_iter)
+        return self._train_batch(batch, data_iter)
+
+    def _train_batch(self, batch, data_iter):
         with trace.step("train", self.global_steps), trace.span("train/batch"):
             with trace.span("train/fetch"):
                 batch, lr_mult, n_samples = self._fetch_batch(batch, data_iter)
@@ -2045,13 +2096,15 @@ class Engine:
         if self._host_opt is not None or batch is None:
             return None  # nothing to pre-warm without an example batch
         shaped = self._reshape_batch(batch)
-        lowered = self._train_step.lower(self.state, shaped, self._mix_matrix(),
-                                         self._next_rng_peek(),
-                                         np.asarray(1.0, np.float32))
-        with trace.span("train/compile", program="train_step"):
+        with trace.phase("train/lower", program="train_step"):
+            lowered = self._train_step.lower(self.state, shaped, self._mix_matrix(),
+                                             self._next_rng_peek(),
+                                             np.asarray(1.0, np.float32))
+        with trace.phase("train/compile", program="train_step"):
             compiled = lowered.compile()
         # instruction -> scope for readers of a device trace
-        trace.register_program("train_step", compiled)
+        with trace.phase("train/register", program="train_step"):
+            trace.register_program("train_step", compiled)
         log_dist("engine.compile(): train step AOT-compiled", ranks=[0])
         return compiled
 

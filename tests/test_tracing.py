@@ -112,6 +112,271 @@ def test_spans_nest_on_the_profilers_clock(tmp_path):
     assert all(inner[i][1] <= inner[i + 1][0] for i in range(3))   # in order
 
 
+# -- phase: start-up, always kept ----------------------------------------
+
+
+def fresh_jit(seed):
+    """A jitted function nothing has compiled before (its constant is new)."""
+    import jax
+
+    salt = float(np.random.default_rng(seed).random()) + os.getpid()
+
+    def brand_new(x):
+        return x * salt + 1.0
+
+    return jax.jit(brand_new)
+
+
+def test_phase_is_kept_with_no_session_and_without_keep_spans():
+    import time
+
+    mark = time.perf_counter()
+    with trace.phase("init/params") as ph:
+        assert trace._stack() == [("init/params", None)]
+    row, = trace.phases(since=mark)
+    assert row == {"name": "init/params", "program": None, "t0": ph._began,
+                   "t1": row["t1"], "parent": None}
+    assert mark <= row["t0"] <= row["t1"] <= time.perf_counter()
+    # the per-step list is another thing: a phase never goes there
+    assert trace._kept is None and trace.kept_spans() == []
+    trace.keep_spans(True)
+    with trace.phase("init/params"):
+        pass
+    assert trace.kept_spans() == []
+    assert trace._stack() == [] and trace._open_phases() == []
+
+
+def test_a_plain_span_leaves_no_phase_row():
+    import time
+
+    mark = time.perf_counter()
+    with trace.span("train/place"), trace.span("train/dispatch"):
+        pass
+    assert trace.phases(since=mark) == []
+
+
+def test_phases_nest_name_their_parent_and_hand_down_their_program():
+    import time
+
+    mark = time.perf_counter()
+    with trace.phase("init/engine", program="p"):
+        with trace.phase("init/shardings"):
+            with trace.span("inner") as sp:
+                assert sp.program == "p"
+        with trace.span("not a phase"):
+            with trace.phase("init/params") as ph:
+                assert ph.parent == "init/engine"    # spans are not parents
+    rows = trace.phases(since=mark)
+    assert [(r["name"], r["parent"], r["program"]) for r in rows] == [
+        ("init/engine", None, "p"), ("init/shardings", "init/engine", "p"),
+        ("init/params", "init/engine", "p")]            # oldest first
+    outer, first, second = rows
+    assert outer["t0"] <= first["t0"] <= first["t1"] <= second["t0"]
+    assert second["t1"] <= outer["t1"]
+    assert trace.phases(since=second["t0"]) == [second]
+
+
+def test_a_backdated_phase_starts_where_it_is_told():
+    import time
+
+    t0 = time.perf_counter() - 5.0
+    with trace.phase("init/import", t0=t0):
+        pass
+    row = [r for r in trace.phases(since=t0) if r["name"] == "init/import"][0]
+    assert row["t0"] == t0 and row["t1"] - row["t0"] >= 5.0
+
+
+def test_the_packages_import_is_the_first_phase():
+    import subprocess
+    import sys
+
+    code = ("import json, time; t = time.perf_counter(); "
+            "import shuffle_exchange_tpu; "
+            "from shuffle_exchange_tpu.profiling import trace; "
+            "print(json.dumps([t, time.perf_counter(), trace.phases()]))")
+    out = subprocess.run(
+        [sys.executable, "-c", code], check=True, capture_output=True,
+        text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    before, after, rows = json.loads(out.stdout.splitlines()[-1])
+    row, = rows                                  # importing opens no other
+    assert (row["name"], row["parent"]) == ("init/import", None)
+    # first line to last line of the package's __init__
+    assert before <= row["t0"] < row["t1"] <= after
+    assert row["t1"] - row["t0"] >= 0.5 * (after - before)
+
+
+ENGINE_SECTIONS = ["init/rest", "init/shardings", "init/params",
+                   "init/optimizer", "init/rest", "init/programs"]
+
+
+def test_engine_init_is_covered_by_its_sections():
+    import time
+
+    mark = time.perf_counter()
+    make_engine()
+    rows = trace.phases(since=mark)
+    assert [r["name"] for r in rows] == ["init/config", "init/engine"] + ENGINE_SECTIONS
+    config, engine = rows[:2]
+    assert config["parent"] is None and engine["parent"] is None
+    assert config["t1"] <= engine["t0"]
+    children = rows[2:]
+    assert all(r["parent"] == "init/engine" for r in children)
+    assert all(a["t1"] <= b["t0"] for a, b in zip(children, children[1:]))
+    covered = sum(r["t1"] - r["t0"] for r in children)
+    assert abs(covered - (engine["t1"] - engine["t0"])) < 1e-3
+    # placing the masters is where a jitted init compiles
+    placed = [e for e in trace.compile_events(since=mark)
+              if e["fun_name"] == "jit(init_master)"]
+    assert [e["span"] for e in placed] == ["init/params"]
+
+
+def test_an_init_that_raises_closes_its_phases():
+    import time
+
+    from shuffle_exchange_tpu.config import ConfigError
+
+    mark = time.perf_counter()
+    with pytest.raises(ConfigError, match="sparse_gradients"):
+        make_engine(sparse_gradients=True)
+    assert trace._stack() == [] and trace._open_phases() == []
+    assert [r["name"] for r in trace.phases(since=mark)] == [
+        "init/config", "init/engine", "init/rest"]
+
+
+def test_compile_leaves_lower_and_compile_and_the_first_step_one_phase():
+    import time
+
+    engine = make_engine()
+    mark = time.perf_counter()
+    assert engine.compile(ids()) is not None
+    names = [r["name"] for r in trace.phases(since=mark)]
+    assert names == ["train/lower", "train/compile", "train/register"]
+    assert all(r["program"] == "train_step" for r in trace.phases(since=mark))
+    # tracing and lowering belong to train/lower, the backend to train/compile
+    lowered, = [e for e in trace.compile_events(since=mark, every=True)
+                if e["span"] == "train/lower" and e["fun_name"] == "jit(train_step)"]
+    assert not lowered["compiled"] and lowered["seconds"] == 0.0
+    assert lowered["trace_s"] > 0 and lowered["lower_s"] > 0
+    built, = [e for e in trace.compile_events(since=mark)
+              if e["span"] == "train/compile"]
+    assert built["compiled"] and built["seconds"] > 0
+    assert built["program"] == "train_step"
+    assert built["trace_s"] == 0.0 and built["lower_s"] == 0.0
+
+    mark = time.perf_counter()
+    engine.train_batch(ids())
+    first, = trace.phases(since=mark)
+    assert (first["name"], first["program"], first["parent"]) == (
+        "train/first_step", "train_step", None)
+    mark = time.perf_counter()
+    engine.train_batch(ids(seed=1))
+    assert trace.phases(since=mark) == []            # the hot path opens none
+    assert trace.compile_events(since=mark, every=True) == []
+
+
+def test_first_step_without_compile_holds_the_whole_pipeline():
+    import time
+
+    engine = make_engine()
+    mark = time.perf_counter()
+    engine.train_batch(ids(seq=13))
+    first, = trace.phases(since=mark)
+    assert first["name"] == "train/first_step"
+    step, = [e for e in trace.compile_events(since=mark)
+             if e["program"] == "train_step" and e["fun_name"] == "jit(train_step)"]
+    assert step["span"] == "train/dispatch"          # innermost: a span
+    assert first["t0"] <= step["at"] <= first["t1"]
+    assert step["trace_s"] > 0 and step["lower_s"] > 0 and step["seconds"] > 0
+    assert step["trace_s"] + step["lower_s"] + step["seconds"] <= first["t1"] - first["t0"]
+
+
+# -- compile records ----------------------------------------------------
+
+OLD_KEYS = {"span", "program", "fun_name", "seconds", "cache_hit", "at"}
+
+
+def test_a_compile_inside_a_phase_is_stamped_with_it_and_carries_the_pipeline():
+    import time
+
+    import jax.numpy as jnp
+
+    f, x = fresh_jit(1), jnp.ones((4,))
+    mark = time.perf_counter()
+    with trace.phase("init/programs", program="probe"):
+        f(x)
+    event, = trace.compile_events(since=mark)
+    assert OLD_KEYS <= set(event)
+    assert event["span"] == "init/programs" and event["program"] == "probe"
+    assert event["fun_name"] == "jit(brand_new)"
+    assert event["trace_s"] > 0 and event["lower_s"] > 0 and event["seconds"] > 0
+    assert event["compiled"] is True and isinstance(event["cache_hit"], bool)
+    assert event["at"] >= mark
+    # the same call again: jax holds the executable, nothing is built
+    mark = time.perf_counter()
+    f(x)
+    assert trace.compile_events(since=mark, every=True) == []
+
+
+def test_a_lowering_alone_leaves_a_record_only_on_request():
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    f, x = fresh_jit(2), jnp.ones((4,))
+    mark = time.perf_counter()
+    lowered = f.lower(x)
+    assert trace.compile_events(since=mark) == []    # the old meaning
+    record, = trace.compile_events(since=mark, every=True)
+    assert OLD_KEYS <= set(record) and record["compiled"] is False
+    assert record["lower_s"] > 0 and record["trace_s"] > 0
+    assert record["seconds"] == 0.0 and record["cache_hit"] is False
+    # compiled under the same (no) span it completes that record
+    lowered.compile()
+    done, = trace.compile_events(since=mark, every=True)
+    assert done["compiled"] and done["seconds"] > 0
+    assert done["trace_s"] == record["trace_s"] and done["at"] > record["at"]
+    # what eval_shape traced is the tracing of the program built from it
+    g = fresh_jit(3)
+    jax.eval_shape(g, x)
+    mark = time.perf_counter()
+    g(x)
+    built, = trace.compile_events(since=mark)
+    assert built["trace_s"] > 1e-4
+
+
+@pytest.mark.parametrize("every", [False, True], ids=["compiled", "every"])
+def test_the_ring_holds_three_hundred_programs(every):
+    import time
+
+    import jax
+
+    assert trace._EVENTS_MAX >= 1024
+    trace.compile_events()                           # listening
+    mark = time.perf_counter()
+    with trace.phase("serve/warmup", program="ladder"):
+        for i in range(300):
+            name = f"rung{i}"
+            jax.monitoring.record_event_duration_secs(
+                trace._TRACE, 0.25, fun_name=name)
+            jax.monitoring.record_event_duration_secs(
+                trace._LOWER, 0.5, fun_name=f"jit({name})")
+            if i % 3:                                # a third is lowered only
+                jax.monitoring.record_event(trace._HIT)
+                jax.monitoring.record_event_duration_secs(
+                    trace._COMPILE, 1.0, fun_name=f"jit({name})")
+    records = trace.compile_events(since=mark, every=every)
+    assert len(records) == (300 if every else 200)
+    assert [r["fun_name"] for r in records][:2] == (
+        ["jit(rung0)", "jit(rung1)"] if every else ["jit(rung1)", "jit(rung2)"])
+    assert all(r["span"] == "serve/warmup" and r["program"] == "ladder"
+               and r["trace_s"] == 0.25 and r["lower_s"] == 0.5
+               for r in records)
+    assert all(r["cache_hit"] and r["seconds"] == 1.0
+               for r in records if r["compiled"])
+
+
 # -- scopes on the compiled step ----------------------------------------
 
 
