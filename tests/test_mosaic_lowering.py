@@ -407,6 +407,24 @@ def test_ssm_conv_lowers(dtype):
                    jnp.zeros((sum(widths),), jnp.float32), *cotangents)
 
 
+def _sconv_mix_vjp(bcx, w, dy):
+    """The pass and its three gradients through the kernels themselves (the
+    dispatching entry takes the XLA form off a TPU)."""
+    from shuffle_exchange_tpu.ops.short_conv import _sconv_mix_pallas
+
+    out, back = jax.vjp(_sconv_mix_pallas, bcx, w)
+    return (out,) + back(dy)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_sconv_mix_lowers(dtype):
+    """The forward and the backward launch; one row block a sequence and
+    three; bf16 and float32 activations; three taps and two."""
+    for T, C, K in ((1536, 256, 3), (64, 384, 2)):
+        _tpu_lower(_sconv_mix_vjp, jnp.zeros((2, T, 3 * C), dtype),
+                   jnp.zeros((K, C), dtype), jnp.zeros((2, T, C), dtype))
+
+
 @pytest.mark.parametrize("store", [jnp.int8, jnp.float8_e4m3fn])
 def test_paged_kernels_quantized_kv_lower(store):
     """kv_cache_dtype int8/fp8 (ISSUE 6): every streaming kernel that
@@ -823,6 +841,20 @@ def test_ssm_conv_compiles(chip_compile):
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 6
     assert "ssm_conv_fwd" in text and "ssm_conv_bwd" in text
+
+
+@pytest.mark.parametrize("B, T, C, K, dtype", [(8, 4096, 2048, 3, _BF16), (2, 1536, 384, 4, _F32)],
+                         ids=["lfm2", "float32_three_lane_tiles"])
+def test_sconv_mix_compiles(chip_compile, B, T, C, K, dtype):
+    """The pass's two kernels at the shape ``lfm2-train`` runs them (eight
+    sequences of 4,096 tokens, the projection's 3 x 2048 columns, three taps,
+    bf16 with bf16 taps) and in float32 at blocks of 512 rows of three lane
+    tiles and four taps; one launch forward, one backward."""
+    rows = lambda n: ((B, T, n), dtype)
+    compiled = chip_compile(_sconv_mix_vjp, rows(3 * C), ((K, C), dtype), rows(C))
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "sconv_mix_fwd" in text and "sconv_mix_bwd" in text
 
 
 def test_ssm_gate_norm_compiles(chip_compile):
