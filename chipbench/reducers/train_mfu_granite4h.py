@@ -1,0 +1,18 @@
+"""Model FLOP/s utilization of a dense state-space / attention hybrid
+training cell (Granite-4.0-H shaped), in percent: the operations one token's
+forward and backward passes require (``arith_granite4h.train_flops_per_token``
+at the cell's sequence length, which the driver computed:
+``facts["granite4h_flops_per_token"]``), times tokens per second per chip from
+the median blocked step of the traced run, over the chip's published bf16
+peak: the cell's share of the whole step's peak. None where the driver kept no
+steps or no such count (a program without the configuration)."""
+
+import statistics
+
+
+def reduce(ctx):
+    f = ctx["result"].get("facts", {})
+    if not f.get("step_s") or not f.get("granite4h_flops_per_token"):
+        return None
+    rate = f["tokens_per_step"] / statistics.median(f["step_s"]) / f["chips"]
+    return 100.0 * f["granite4h_flops_per_token"] * rate / ctx["peaks"]["bf16_flops_per_s"]

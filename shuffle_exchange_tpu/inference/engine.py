@@ -188,6 +188,16 @@ class InferenceEngine:
                 "one-token step of the scan, and they scan whole (mixer, ffn) "
                 "blocks of one kind (training through sxt.initialize is; "
                 "ROADMAP R-M11)")
+        scaled = [f"{name}={getattr(self._mcfg, name)}"
+                  for name in ("embed_scale", "residual_scale", "logit_divisor")
+                  if getattr(self._mcfg, name, 1.0) != 1.0]
+        if scaled:
+            raise NotImplementedError(
+                f"serving a model with the Granite family's multipliers ({', '.join(scaled)}"
+                ": embedding_multiplier, residual_multiplier, logits_scaling) is "
+                "not implemented: the inference engines' cached paths embed, add "
+                "sublayers and read logits unscaled (training through "
+                "sxt.initialize is; ROADMAP R-M11)")
         if getattr(self._mcfg, "norm_order", "input") != "input":
             raise NotImplementedError(
                 "serving the Olmo Hybrid family (model_type olmo_hybrid: blocks "

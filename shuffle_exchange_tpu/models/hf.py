@@ -58,6 +58,7 @@ _ARCH_FAMILIES = {
     "Lfm2MoeForCausalLM": "lfm2moe",
     "NemotronHForCausalLM": "nemotronh",
     "OlmoHybridForCausalLM": "olmohybrid",
+    "GraniteMoeHybridForCausalLM": "granitemoehybrid",
 }
 
 
@@ -74,6 +75,7 @@ _MODEL_TYPE_FAMILIES = {"llama": "llama", "mistral": "llama", "qwen2": "qwen2",
                         "lfm2_moe": "lfm2moe",
                         "nemotron_h": "nemotronh",
                         "olmo_hybrid": "olmohybrid",
+                        "granitemoehybrid": "granitemoehybrid",
                         "megatron": "megatron",
                         "megatron-gpt": "megatron", "megatron_gpt": "megatron"}
 
@@ -355,6 +357,80 @@ def _olmo_hybrid_config(cfg: Dict[str, Any]) -> TransformerConfig:
         gdn_beta_scale=2.0 if cfg.get("linear_allow_neg_eigval", False) else 1.0)
 
 
+def _granite_hybrid_config(cfg: Dict[str, Any]) -> TransformerConfig:
+    """IBM's ``model_type: granitemoehybrid`` as granite-4.0-h-micro ships it
+    (the DENSE members of the family): ``layer_types`` gives the stack
+    ("mamba": a Mamba-2 state-space layer of ``mamba_n_heads`` heads of
+    ``mamba_d_head``, ``mamba_n_groups`` groups of ``mamba_d_state``,
+    ``mamba_d_conv`` taps with a bias, its gated RMSNorm over each group's
+    channels; "attention": grouped-query attention that rotates NOTHING,
+    ``position_embedding_type`` "nope"), every block a mixer AND a gated SiLU
+    MLP of ``shared_intermediate_size``, plain-gain RMSNorms at
+    ``rms_norm_eps`` on each sublayer's input, the head tied to the embedding,
+    and the family's four multipliers: ``embedding_multiplier`` on the
+    looked-up rows, ``residual_multiplier`` on each sublayer's output,
+    ``attention_multiplier`` in place of 1 / sqrt(head size),
+    ``logits_scaling`` a divisor of the logits. ``mamba_expand`` and
+    ``mamba_chunk_size`` are not read (the inner width is heads x head size;
+    the scan's chunk is ``ops/ssd.CHUNK``). Not the source's key:
+    ``layers_held`` (a cut in depth: the indices into ``layer_types`` of the
+    ``num_hidden_layers`` layers held here, in order; without it the first
+    that many). What is not written here is refused by name."""
+    H, G = int(cfg["mamba_n_heads"]), int(cfg.get("mamba_n_groups", 1))
+    refused = {
+        "num_local_experts": int(cfg.get("num_local_experts") or 0) > 0,
+        "attention_bias": bool(cfg.get("attention_bias")),
+        "mamba_proj_bias": bool(cfg.get("mamba_proj_bias")),
+        "mamba_conv_bias": not cfg.get("mamba_conv_bias", True),
+        "position_embedding_type": cfg.get("position_embedding_type", "nope") != "nope",
+        "hidden_act": cfg.get("hidden_act", "silu") != "silu",
+        "normalization_function": cfg.get("normalization_function", "rmsnorm") != "rmsnorm",
+        "mamba_n_groups": G < 1 or H % G != 0,
+    }
+    for key, bad in refused.items():
+        if bad:
+            raise ValueError(
+                f"granitemoehybrid with {key}={cfg.get(key)!r} is not supported "
+                "(written down: no routed experts (num_local_experts 0: routed "
+                "experts beside the shared MLP are this block form's open part, "
+                "ROADMAP R-M11), no bias but the convolution's, attention that "
+                "rotates nothing (position_embedding_type 'nope', not 'rope'), "
+                "silu, RMSNorm, mamba_n_groups that divides mamba_n_heads)")
+    L = int(cfg["num_hidden_layers"])
+    types = list(cfg.get("layer_types") or cfg.get("layers_block_type") or [])
+    held = [int(i) for i in cfg.get("layers_held") or range(L)]
+    if len(held) != L or held != sorted(set(held)) or (held and held[-1] >= len(types)):
+        raise ValueError(f"granitemoehybrid: layers_held={held} does not name "
+                         f"num_hidden_layers={L} distinct layers of the {len(types)} in "
+                         "layer_types, in order")
+    mixers = {"mamba": "ssm", "attention": "attn"}
+    for i in held:
+        if types[i] not in mixers:
+            raise ValueError(f"granitemoehybrid with layer_types[{i}]={types[i]!r} is not "
+                             f"supported (written down: {sorted(mixers)})")
+    kinds = [(mixers[types[i]], "mlp") for i in held]
+    period = next(p for p in range(1, L + 1)
+                  if L % p == 0 and kinds[:p] * (L // p) == kinds)
+    heads = int(cfg["num_attention_heads"])
+    return TransformerConfig(
+        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"], n_layers=L,
+        n_heads=heads, n_kv_heads=cfg.get("num_key_value_heads"),
+        head_size=int(cfg.get("head_dim") or cfg["hidden_size"] // heads),
+        d_ff=cfg["shared_intermediate_size"],
+        max_seq_len=cfg.get("max_position_embeddings", 4096),
+        activation="swiglu", norm="rmsnorm", position="none",
+        norm_eps=cfg.get("rms_norm_eps", 1e-5),
+        tie_embeddings=bool(cfg.get("tie_word_embeddings", True)),
+        ssm_heads=H, ssm_head_dim=int(cfg["mamba_d_head"]), ssm_groups=G,
+        ssm_state=int(cfg["mamba_d_state"]),
+        ssm_conv_kernel=int(cfg.get("mamba_d_conv", 4)),
+        layer_pattern=tuple(kinds[:period]),
+        embed_scale=float(cfg.get("embedding_multiplier", 1.0)),
+        residual_scale=float(cfg.get("residual_multiplier", 1.0)),
+        attn_scale=float(cfg.get("attention_multiplier") or 0.0),
+        logit_divisor=float(cfg.get("logits_scaling", 1.0)))
+
+
 def _laguna_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerConfig:
     """poolside's ``model_type: laguna`` as Laguna-XS.2 ships it: window
     (``sliding_attention``) and full-attention layers in one stack
@@ -464,6 +540,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
         return _nemotron_h_config(cfg)
     if family == "olmohybrid":
         return _olmo_hybrid_config(cfg)
+    if family == "granitemoehybrid":
+        return _granite_hybrid_config(cfg)
     if family == "gpt2":
         return TransformerConfig(
             vocab_size=cfg["vocab_size"], d_model=cfg["n_embd"], n_layers=cfg["n_layer"],

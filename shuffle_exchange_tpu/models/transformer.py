@@ -93,7 +93,9 @@ class TransformerConfig:
     mlm_head: bool = False                     # BertForMaskedLM cls head
     # GPT-Neo structure (reference module_inject/containers/gptneo.py):
     # unscaled attention + alternating global/local layers.
-    attn_scale: float = 0.0                    # 0 = 1/sqrt(Dh); GPT-Neo: 1.0
+    attn_scale: float = 0.0                    # 0 = 1/sqrt(Dh); GPT-Neo: 1.0;
+                                               # Granite's attention_multiplier
+                                               # (mixer "attn" of a pattern too)
     local_attention_window: int = 0            # window for "local" layers
     attention_pattern: Tuple[str, ...] = ()    # per-layer "global"/"local",
                                                # cycled over n_layers: a flag
@@ -307,6 +309,19 @@ class TransformerConfig:
     # transformers' ``_compute_yarn_parameters`` builds over the ``rotary_dims``
     # rotated dims, and cos and sin carry the attention factor.
     rope_yarn: Tuple[float, ...] = ()
+    # The Granite family's multipliers (with ``attn_scale``, its
+    # attention_multiplier, the four of them); 1 = none, and a neutral one
+    # emits no operation. ``embed_scale`` multiplies the looked-up rows
+    # (embedding_multiplier; a tied head reads the rows unscaled),
+    # ``residual_scale`` each sublayer's output before it is added to the
+    # stream (residual_multiplier: ``h + r * mix(norm(h))``, ``h + r *
+    # ffn(norm(h))``; the blocks of a layer_pattern), ``logit_divisor`` divides
+    # the logits (logits_scaling), in ``head`` and inside the chunked loss,
+    # forward and backward. Each is applied in float32 to the value as its
+    # producer left it, and the result rounded once.
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
 
     @property
     def kv_heads(self) -> int:
@@ -1033,6 +1048,8 @@ class Transformer:
         cfg = self.config
         T = input_ids.shape[-1]
         x = jnp.take(params["embed"], input_ids, axis=0)
+        if cfg.embed_scale != 1.0:
+            x = (cfg.embed_scale * x.astype(jnp.float32)).astype(x.dtype)
         if cfg.position == "learned":
             x = x + params["pos_embed"][cfg.pos_offset:cfg.pos_offset + T].astype(x.dtype)
         if cfg.type_vocab_size > 0:
@@ -1107,15 +1124,31 @@ class Transformer:
                    "ssm": self._ssm,
                    "swa": functools.partial(self._gqa, mixer="swa")}[mixer]
 
+            scaled = cfg.residual_scale != 1.0
+
+            def step(h, out, scope):
+                """``h + residual_scale * out``: the sum formed in float32
+                and rounded once, under the scope of the sublayer whose
+                output it scales."""
+                with trace.scope(scope):
+                    return (h.astype(jnp.float32) + cfg.residual_scale
+                            * out.astype(jnp.float32)).astype(h.dtype)
+
             def mixer_half(lw, h):
                 with trace.scope("attn_norm"):
                     y = _norm(h, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm, eps=cfg.norm_eps)
+                if scaled:
+                    return step(h, mix(lw, y, rope), "attn_out")
                 return h + mix(lw, y, rope)
 
             def ffn_half(lw, h):
                 with trace.scope("mlp_norm"):
                     y2 = _norm(h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm, eps=cfg.norm_eps)
                 with trace.scope("moe" if ffn == "moe" else "mlp"):
+                    if scaled:
+                        ff, aux, stats = self._ffn(lw, h, y2, None, moe_on, ffn,
+                                                   residual=False)
+                        return step(h, ff, "mlp"), aux, stats
                     return self._ffn(lw, h, y2, None, moe_on, ffn)
 
             if cfg.norm_order == "output":
@@ -1137,6 +1170,11 @@ class Transformer:
                         ff = _norm(ff, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm,
                                    eps=cfg.norm_eps)
                     return h + ff, aux, stats
+                if scaled:
+                    raise NotImplementedError(
+                        f"residual_scale={cfg.residual_scale} in a block that norms "
+                        "its sublayers' OUTPUT (norm_order 'output'): the Granite "
+                        "family's blocks norm the input")
             elif cfg.norm_order != "input":
                 raise ValueError("norm_order is 'input' or 'output'; got "
                                  f"{cfg.norm_order!r}")
@@ -1156,6 +1194,11 @@ class Transformer:
                 f"norm_order={cfg.norm_order!r} (a block that norms its sublayers' "
                 "OUTPUT) is the form of a stack of several kinds (layer_pattern); "
                 "a one-kind model norms the input, or the sum (post_ln)")
+        if cfg.residual_scale != 1.0:
+            raise NotImplementedError(
+                f"residual_scale={cfg.residual_scale} (the Granite family's "
+                "residual_multiplier) is applied by the blocks of a stack of several "
+                "kinds (layer_pattern); a one-kind model adds its sublayers unscaled")
         if cfg.post_ln:
             y = h   # BERT: sublayer input is unnormalized; LN follows the add
         else:
@@ -1248,14 +1291,17 @@ class Transformer:
         projection (gains [H x Dh] and [KV x Dh], a float32 statistic) before
         the split into heads, under ``attn_qk_norm`` (Olmo Hybrid). None of
         the softmax family's other flags reaches this form (biases, ALiBi,
-        post-LN, a parallel block: a one-kind model's, ``layer_apply``)."""
+        post-LN, a parallel block: a one-kind model's, ``layer_apply``) but
+        ``attn_scale`` (Granite's attention_multiplier in place of 1 /
+        sqrt(head_dim)), which q carries after the rotation."""
+        import jax.numpy as jnp
         from jax.ad_checkpoint import checkpoint_name
 
         cfg = self.config
         head_norm = cfg.qk_norm == "head" and mixer == "attn"
         whole_norm = cfg.qk_norm is True and mixer == "attn"
         flags = [f for f in ("attn_qkv_bias", "attn_out_bias", "post_ln",
-                             "parallel_block", "attn_scale", "local_attention_window")
+                             "parallel_block", "local_attention_window")
                  if getattr(cfg, f)] + ["qk_norm"] * (cfg.qk_norm is True
                                                     and not whole_norm)
         if flags or cfg.position not in ("rope", "none") or not cfg.causal:
@@ -1299,6 +1345,10 @@ class Transformer:
                 with own("swa_rope" if windowed else "rope_yarn" if cfg.rope_yarn else None):
                     q = apply_rope(q, cos, sin, interleaved=cfg.rope_interleaved)
                     k = apply_rope(k, cos, sin, interleaved=cfg.rope_interleaved)
+            if cfg.attn_scale:
+                # the kernels always divide by sqrt(Dh): q carries the rest,
+                # as a one-kind model's (``layer_apply``)
+                q = q * jnp.asarray(cfg.attn_scale * math.sqrt(Dh), q.dtype)
         q = checkpoint_name(q, "q")
         k = checkpoint_name(k, "kv")
         v = checkpoint_name(v, "kv")
@@ -2118,7 +2168,9 @@ class Transformer:
             logits = jnp.matmul(x, w, preferred_element_type=jnp.float32)
             if cfg.mlm_head:
                 logits = logits + params["mlm_bias"].astype(jnp.float32)
-            return logits if bias is None else logits + bias
+            if bias is not None:
+                logits = logits + bias
+            return logits if cfg.logit_divisor == 1.0 else logits / cfg.logit_divisor
 
     @staticmethod
     def token_loss(logits, labels):
@@ -2241,7 +2293,8 @@ class Transformer:
             extra = bias
         nll_sum, cnt = _head_scan(
             cfg.norm, cfg.norm_eps, bias is not None,
-            params["ln_f_w"], params["ln_f_b"], w, w_acc, extra, xc, lc)
+            params["ln_f_w"], params["ln_f_b"], w, w_acc, extra, xc, lc,
+            divisor=cfg.logit_divisor)
         return nll_sum, cnt, (n_chunks, B * chunk)
 
     def _vocab_pad(self) -> int:
@@ -2460,19 +2513,23 @@ def auto_loss_chunk(B: int, T: int, vocab: int) -> int:
     return min(T, 1 << (positions.bit_length() - 1))
 
 
-def _head_logits(xn, w, extra):
+def _head_logits(xn, w, extra, divisor=1.0):
     import jax.numpy as jnp
 
     with trace.scope("head_logits"):
         logits = jnp.matmul(xn, w, preferred_element_type=jnp.float32)
-        return logits if extra is None else logits + extra
+        if extra is not None:
+            logits = logits + extra
+        return logits if divisor == 1.0 else logits / divisor
 
 
-def _head_scan(kind, eps, biased, ln_w, ln_b, w, w_acc, extra, xc, lc):
+def _head_scan(kind, eps, biased, ln_w, ln_b, w, w_acc, extra, xc, lc, divisor=1.0):
     """(nll_sum, count) of final norm + unembed + CE over the chunks ``xc``
     [n, B, c, D], ``lc`` [n, B, c]; ``w`` [D, Vp] in the compute dtype,
     ``extra`` [Vp] float32 (bias and pad mask) or None. ``kind``, ``eps``: the
-    final norm's; ``biased``: ``extra`` holds a bias that wants a gradient.
+    final norm's; ``biased``: ``extra`` holds a bias that wants a gradient;
+    ``divisor``: what the logits are divided by (``logit_divisor``; 1 = as
+    they are), and with them their cotangent on its way to dx and dw.
 
     Not under ``grad``: the plain scan of norm, logits and ``token_loss``.
     Under ``grad`` (a ``custom_vjp``) the forward scan does the head's whole
@@ -2495,7 +2552,7 @@ def _head_scan(kind, eps, biased, ln_w, ln_b, w, w_acc, extra, xc, lc):
         def body(carry, xl):
             xch, lch = xl
             nll, cnt = Transformer.token_loss(
-                _head_logits(norm(xch, ln_w, ln_b), w, extra), lch)
+                _head_logits(norm(xch, ln_w, ln_b), w, extra, divisor), lch)
             return (carry[0] + nll, carry[1] + cnt), None
 
         return jax.lax.scan(body, (jnp.zeros((), f32), jnp.zeros((), jnp.int32)),
@@ -2515,7 +2572,7 @@ def _head_scan(kind, eps, biased, ln_w, ln_b, w, w_acc, extra, xc, lc):
             # where autodiff rounds dxn and then dx
             xn32, norm_vjp = jax.vjp(norm, xch.astype(f32), *ln32)
             xn = xn32.astype(xch.dtype)
-            logits = _head_logits(xn, w, extra)
+            logits = _head_logits(xn, w, extra, divisor)
             with trace.scope("head_softmax"):
                 mask = lch >= 0
                 label = jnp.where(mask, lch, 0)[..., None]
@@ -2526,6 +2583,9 @@ def _head_scan(kind, eps, biased, ln_w, ln_b, w, w_acc, extra, xc, lc):
                        )[..., 0]
                 hot = jnp.arange(logits.shape[-1]) == label
                 dlogits = jnp.where(mask[..., None], e / total - hot, 0.0)
+                if divisor != 1.0:
+                    # of the undivided logits, the matmul's own output
+                    dlogits = dlogits / divisor
                 # the MXU's operand in both matmuls below: what a
                 # default-precision matmul makes of the float32 cotangent on
                 # the chip. Behind a barrier, so that it is written once: XLA

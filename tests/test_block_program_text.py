@@ -1,13 +1,14 @@
 """The programs of the hybrid cells' blocks do not move: the jaxpr text of
 ``Transformer._gdn`` and of one block of every kind (``layer_apply``, per-half
 remat on, as ``stack_apply`` calls it) at the tiny presets of
-``qwen3next-train``, ``nemotron3-train`` and ``lfm2-train`` (the HF dicts of
-their own test files), and the bits of their outputs and gradients at a fixed
+``qwen3next-train``, ``nemotron3-train``, ``lfm2-train`` and
+``granite4h-train`` (the HF dicts of their own test files), and the bits of their outputs and gradients at a fixed
 seed, against ``tests/data/block_program_text.json``, which was written from
 the commit BEFORE the output-normed block, the doubled beta and the
 whole-projection q/k norm among several kinds came in (PR 50). The halves'
 seam (``mixer_half`` / ``ffn_half``) is shared by all three; a multiply by 1.0
-or a branch XLA has to fold would change the text.
+or a branch XLA has to fold would change the text: the Granite family's
+multipliers (PR 55) are neutral in the three older cells and emit nothing there.
 
 The text is jaxpr text of this container's JAX: after a JAX upgrade that
 changes the printer, write the file again from a commit that is known good
@@ -89,7 +90,9 @@ def readings(cell: str) -> dict:
     return out
 
 
-CELLS = ("qwen3next", "nemotron3", "lfm2")
+# (``granite4h``: written from the commit that brought the block, PR 55; it
+# holds LATER changes to the multipliers' seams)
+CELLS = ("qwen3next", "nemotron3", "lfm2", "granite4h")
 
 
 @pytest.mark.parametrize("cell", CELLS)

@@ -818,12 +818,16 @@ def test_gated_delta_rule_compiles_on_padded_lanes(chip_compile):
     assert "gdn_rule_fwd_keep" in text and "gdn_rule_bwd" in text
 
 
-def test_ssd_scan_compiles(chip_compile):
-    """The three kernels at the shape ``nemotron3-train`` runs them: two rows
-    of 8,192 tokens, 64 heads of 64 in 8 groups of a state of 128, bf16 with a
-    float32 step."""
-    wide, group = ((2, 8192, 64, 64), _BF16), ((2, 8192, 8, 128), _BF16)
-    compiled = chip_compile(_ssd_vjp, wide, ((2, 8192, 64), _F32), ((64,), _F32),
+@pytest.mark.parametrize("rows, seq, groups", [(2, 8192, 8), (1, 8192, 1)],
+                         ids=["nemotron3_8_groups", "granite4h_one_group"])
+def test_ssd_scan_compiles(chip_compile, rows, seq, groups):
+    """The three kernels at the shapes ``nemotron3-train`` and
+    ``granite4h-train`` run them: 64 heads of 64 and a state of 128, bf16 with
+    a float32 step; two rows of 8,192 tokens in 8 groups, and one row in ONE
+    group (a grid step then holds all 64 heads: 32 lane tiles walked in turn,
+    a 2 MB state scratch; Mosaic takes it, PR 55)."""
+    wide, group = ((rows, seq, 64, 64), _BF16), ((rows, seq, groups, 128), _BF16)
+    compiled = chip_compile(_ssd_vjp, wide, ((rows, seq, 64), _F32), ((64,), _F32),
                             group, group, wide)
     text = compiled.as_text()
     assert "ssd_fwd_keep" in text and "ssd_bwd" in text
