@@ -202,17 +202,16 @@ def reference_loss(model_cfg, params, batch, device=None, dtype=None):
 
 
 def attention_route(model_cfg, seq: int) -> str:
-    """Which attention the trainer's forward resolves to at this shape."""
+    """Which attention the trainer's forward resolves to at this shape on
+    one chip, as the program itself names it."""
     import jax
     import jax.numpy as jnp
 
-    from shuffle_exchange_tpu.ops.flash_attention import _pallas_ok
+    from shuffle_exchange_tpu.ops.flash_attention import attention_route as route
 
-    q = jax.ShapeDtypeStruct(
-        (1, seq, model_cfg.n_heads, model_cfg.head_dim), jnp.bfloat16)
-    if model_cfg.attention_impl not in ("auto", "pallas") or not _pallas_ok(q, q):
-        return "reference"
-    return "splash" if model_cfg.kv_heads < model_cfg.n_heads else "flash"
+    q, kv = (jax.ShapeDtypeStruct((1, seq, heads, model_cfg.head_dim), jnp.bfloat16)
+             for heads in (model_cfg.n_heads, model_cfg.kv_heads))
+    return route(q, kv, kv, impl=model_cfg.attention_impl)
 
 
 def phase_trainer(meter: CompileMeter, model_cfg, *, model_name: str,

@@ -1117,8 +1117,10 @@ class Transformer:
             # twice what it kept for these mixers (kanana-2 at 48 layers and
             # 16,384 tokens a chip: 13.0 GB where it kept 6.4); policy "none"
             # (``jax.checkpoint``'s default) keeps nothing and recomputes.
-            # ``gdn`` has no such kernel; "stock_flash", "reference" and the
-            # ring's hop kernels name nothing and recompute
+            # ``gdn`` has no such kernel; MHA takes "splash" on one device
+            # (PR 56) and keeps them like any other; "stock_flash" (MHA per
+            # shard of a kernel mesh), "reference" and the ring's hop
+            # kernels name nothing and recompute
             mix = {"gdn": self._gdn, "gated_attn": self._gated_attention,
                    "mla": self._mla, "attn": self._gqa, "sconv": self._sconv,
                    "ssm": self._ssm,

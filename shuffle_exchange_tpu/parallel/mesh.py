@@ -339,6 +339,15 @@ def batch_rows_a_device(batch: int) -> int:
     return batch // int(np.prod([mesh.shape[ax] for ax in axes]))
 
 
+def kernel_mesh_devices() -> int:
+    """Devices of the mesh :func:`shard_kernel` lays a kernel call out over:
+    1 outside :func:`kernel_mesh`. The mesh's size, not whether
+    ``shard_kernel`` wraps: inside a region that is manual over every axis
+    it hands the kernel back as it is, and the call still runs per shard."""
+    mesh = _KERNEL_MESH.get()
+    return 1 if mesh is None else int(mesh.size)
+
+
 def shard_kernel(fn, in_specs, out_specs):
     """``fn`` (a Pallas kernel call), made safe inside a program that spans
     several devices. XLA cannot partition a Mosaic kernel: there the call
@@ -348,12 +357,12 @@ def shard_kernel(fn, in_specs, out_specs):
     is. Inside an enclosing (partial-)manual region only the remaining axes
     are taken, on the region's own mesh, and the specs lose the axes that
     are manual already (their blocks are local by then)."""
-    mesh = _KERNEL_MESH.get()
-    if mesh is None or mesh.size == 1:
+    if kernel_mesh_devices() == 1:
         return fn
     import jax
     from jax.sharding import PartitionSpec
 
+    mesh = _KERNEL_MESH.get()
     taken = _manual_axes()
     free = [ax for ax in mesh.axis_names if ax not in taken]
     if not free:

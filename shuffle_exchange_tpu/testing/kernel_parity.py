@@ -76,8 +76,9 @@ def _attention(rng) -> Iterator[dict]:
     from ..ops.flash_attention import (attention_backward_route,
                                        pallas_attention, reference_attention)
 
-    # stock flash (MHA) and splash (GQA, unexpanded KV), forward and dk
-    for H, KV, label in [(8, 8, "flash-mha"), (8, 2, "splash-gqa")]:
+    # splash on this one device: MHA (a group of one, PR 56) and GQA
+    # (unexpanded KV), forward and dk
+    for H, KV, label in [(8, 8, "splash-mha"), (8, 2, "splash-gqa")]:
         q = jnp.asarray(rng.standard_normal((2, 256, H, 128)), jnp.float32)
         k = jnp.asarray(rng.standard_normal((2, 256, KV, 128)), jnp.float32)
         v = jnp.asarray(rng.standard_normal((2, 256, KV, 128)), jnp.float32)
@@ -96,7 +97,8 @@ def _attention(rng) -> Iterator[dict]:
     # rounded inputs, each as a share of the oracle's largest value
     for KV, D, Dv, window, label in [(2, 128, 128, 0, "splash-fused-bwd"),
                                      (8, 192, 128, 0, "splash-fused-bwd-192-128"),
-                                     (1, 128, 128, 512, "splash-fused-bwd-window")]:
+                                     (1, 128, 128, 512, "splash-fused-bwd-window"),
+                                     (8, 64, 64, 0, "splash-fused-bwd-mha-64")]:
         q, k, v, do = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
                        for shape in [(1, 2048, 8, D), (1, 2048, KV, D),
                                      (1, 2048, KV, Dv), (1, 2048, 8, Dv)])

@@ -27,6 +27,8 @@ GEOMETRY = {
     "6-over-1-at-128": (6, 1, 128, 128, 0),    # laguna-train, full layers
     "8-over-1-window": (8, 1, 128, 128, 200),  # laguna-train, window layers
     "8-over-1-at-256": (8, 1, 256, 256, 0),    # qwen3next-train
+    "mha-at-64": (4, 4, 64, 64, 0),            # gpt2m-train (PR 56)
+    "mha-at-128": (4, 4, 128, 128, 0),         # olmoe-train (PR 56)
 }
 
 
@@ -139,6 +141,9 @@ CELLS = {
     "laguna-train-full": (_call(16384, 48, 8, 128), 0, "splash"),
     "laguna-train-window": (_call(16384, 64, 8, 128), 512, "splash_window"),
     "lfm2-train": (_call(4096, 32, 8, 64, B=8), 0, "splash"),
+    # MHA on one device: the same kernels at a group of one (PR 56)
+    "gpt2m-train": (_call(1024, 16, 16, 64, B=4), 0, "splash"),
+    "olmoe-train": (_call(4096, 16, 16, 128, B=4), 0, "splash"),
 }
 
 
@@ -152,27 +157,47 @@ def test_the_cells_real_shapes_take_the_fused_backward(cell):
         q.shape[1], k.shape[1], window, 2)) <= sb.VMEM_BUDGET_BYTES
 
 
+def test_the_per_shard_mha_call_keeps_the_stock_kernels(devices8):
+    """``olmohybrid-zero3-x4``'s attention layer, 30 heads of 128 at 8,192 a
+    shard of ZeRO-3's mesh over four devices: the stock family, whose
+    backward nothing here names; the same call off the mesh is "splash"."""
+    from shuffle_exchange_tpu.config.config import MeshConfig
+    from shuffle_exchange_tpu.parallel.mesh import MeshTopology, kernel_mesh
+
+    q, k, v = _call(8192, 30, 30, 128, B=2)
+    assert fa.attention_route(q, k, v, True, "pallas") == "splash"
+    with kernel_mesh(MeshTopology.build(MeshConfig(fsdp=4),
+                                        devices=devices8[:4]).mesh):
+        assert fa.attention_route(q, k, v, True, "pallas") == "stock_flash"
+        # GQA on the same mesh: what it was (mistral7b-zero3-x4)
+        assert fa.attention_route(*CELLS["mistral7b-zero3-x4"][0], True,
+                                  "pallas") == "splash"
+
+
+@pytest.mark.parametrize("heads", [(32, 8, 128), (16, 16, 64), (16, 16, 128)],
+                         ids=["gqa", "mha-at-64", "mha-at-128"])
 @pytest.mark.parametrize("why", ["segment_ids", "mask_np", "float32",
                                  "non_causal", "beyond_vmem", "queries_past_keys"])
-def test_what_the_fused_backward_does_not_take_keeps_the_two_kernels(why):
+def test_what_the_fused_backward_does_not_take_keeps_the_two_kernels(why, heads):
     """Read off the call, not set: segment ids, a blocksparse layout's
     ``mask_np``, 4-byte inputs, a non-causal call, a sequence whose dk and dv
     would not fit VMEM, and more queries than keys keep the library's two
-    kernels."""
-    q, k, v = _call(4096, 32, 8, 128)
+    kernels; GQA, and MHA off a kernel mesh (PR 56) alike."""
+    call = lambda T, **kw: _call(T, *heads, **kw)
+    q, k, v = call(4096)
     causal, extra = True, {}
     if why == "segment_ids":
         extra["segment_ids"] = np.zeros((1, 4096), np.int32)
     elif why == "mask_np":
         extra["mask_np"] = np.ones((4096, 4096), bool)
     elif why == "float32":
-        q, k, v = _call(4096, 32, 8, 128, dtype=jnp.float32)
+        q, k, v = call(4096, dtype=jnp.float32)
     elif why == "non_causal":
         causal = False
     elif why == "beyond_vmem":
-        q, k, v = _call(65536, 32, 8, 128)
+        q, k, v = call(65536 * (2 if heads[2] == 64 else 1))
     else:
-        k = v = jax.ShapeDtypeStruct((1, 2048, 8, 128), _BF16)
+        k = v = call(2048)[1]
     assert fa.attention_backward_route(
         q, k, v, causal, 0, **extra) == "splash_two_kernels"
     assert fa.attention_route(q, k, v, causal, "pallas") == "splash"

@@ -142,7 +142,10 @@ def test_the_kept_kernel_route_in_interpret_mode():
 
 @pytest.mark.parametrize("shapes, impl, enabled, want", [
     (((2, 256, 4, 192), (2, 256, 4, 192), (2, 256, 4, 128)), "auto", True, "splash_own_v"),
-    (((2, 256, 4, 128), (2, 256, 4, 128), (2, 256, 4, 128)), "auto", True, "stock_flash"),
+    # MHA off a kernel mesh: the splash kernels at a group of one (PR 56;
+    # on a mesh of several devices "stock_flash": tests/test_mha_route.py)
+    (((2, 256, 4, 128), (2, 256, 4, 128), (2, 256, 4, 128)), "auto", True, "splash"),
+    (((2, 256, 4, 64), (2, 256, 4, 64), (2, 256, 4, 64)), "auto", True, "splash"),
     (((2, 256, 8, 128), (2, 256, 2, 128), (2, 256, 2, 128)), "auto", True, "splash"),
     (((2, 256, 4, 192), (2, 256, 4, 192), (2, 256, 4, 128)), "auto", False, "reference"),
     (((2, 256, 4, 192), (2, 256, 4, 192), (2, 256, 4, 128)), "reference", True, "reference"),

@@ -628,8 +628,9 @@ def test_paged_decode_and_extend_compile(chip_compile, model):
 
 @pytest.mark.parametrize("model,T", [("gpt2-125m", 1024), ("llama3-8b", 2048)])
 def test_flash_attention_fwd_bwd_compiles(chip_compile, model, T):
-    """The trainer's attention at the smoke's sequence lengths: stock flash
-    for GPT-2's MHA, the splash MQA kernel for Llama's 32/8 GQA."""
+    """The trainer's attention at the smoke's sequence lengths: the splash
+    MQA kernel for Llama's 32/8 GQA and, at a group of one, for GPT-2's MHA
+    (PR 56: the call is made on one device)."""
     from shuffle_exchange_tpu.ops.flash_attention import pallas_attention
 
     g = GEOMS[model]
@@ -666,6 +667,9 @@ _CELL_ATTENTION = {
     "qwen3next-train": (1, 8192, 16, 2, 256, 256, 0),
     "lfm2-train": (8, 4096, 32, 8, 64, 64, 0),
     "mistral7b-zero3-x4": (1, 4096, 32, 8, 128, 128, 0),
+    # MHA on one device, a group of one (PR 56)
+    "gpt2m-train": (4, 1024, 16, 16, 64, 64, 0),
+    "olmoe-train": (4, 4096, 16, 16, 128, 128, 0),
 }
 
 
@@ -700,6 +704,28 @@ def test_fused_attention_backward_compiles_at_the_cells_shapes(chip_compile, cel
         q, k, v)
     assert _kernel_launches(compiled) == {
         "splash_mqa_fwd_residuals": 1, KERNEL_NAME: 1}
+
+
+def test_the_per_shard_mha_call_compiles_the_stock_kernels(chip_compile, topo):
+    """``olmohybrid-zero3-x4``'s attention layer as one shard of ZeRO-3's
+    mesh over the four described chips runs it, 2 x 8192 x 30 heads of 128:
+    MHA on a kernel mesh of several devices keeps the stock flash family
+    (``_pallas_kernel``), forward and its two backward kernels."""
+    from shuffle_exchange_tpu.config.config import MeshConfig
+    from shuffle_exchange_tpu.ops.flash_attention import pallas_attention
+    from shuffle_exchange_tpu.parallel.mesh import MeshTopology, kernel_mesh
+
+    mesh = MeshTopology.build(MeshConfig(fsdp=4), devices=list(topo.devices)).mesh
+    q = ((2, 8192, 30, 128), _BF16)
+
+    def grads(q, k, v):
+        with kernel_mesh(mesh):
+            return jax.grad(lambda q, k, v: pallas_attention(
+                q, k, v, causal=True).astype(_F32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    launches = _kernel_launches(chip_compile(grads, q, q, q))
+    assert sum(launches.values()) == 3 and not any(
+        "splash" in name for name in launches), launches
 
 
 @pytest.mark.parametrize("route", ["fused_resident_dkv", "splash_two_kernels"])
