@@ -159,6 +159,17 @@ class InferenceEngine:
         self.config = config or InferenceConfig()
         self._mcfg = model.config
         mixers = {mixer for mixer, _ in getattr(self._mcfg, "kinds_used", ())}
+        if (getattr(self._mcfg, "moe_router_input", "ffn") != "ffn"
+                or getattr(self._mcfg, "unrotated_mixers", ())):
+            raise NotImplementedError(
+                "serving a stack whose router reads the block's input "
+                "(moe_router_input 'block') or whose full layers rotate nothing "
+                "beside window layers that do (unrotated_mixers: SmallThinker) is "
+                "not implemented: the inference engines' one-kind decode scan "
+                "routes on what the experts read, rotates every layer by one "
+                "table and keeps one uniform KV pool with no window to evict by; "
+                "nothing fetches experts while attention runs (training through "
+                "sxt.initialize is; ROADMAP R-M3, R-M15)")
         if "swa" in mixers:
             raise NotImplementedError(
                 "serving a stack of window and full attention kinds (mixer "

@@ -59,6 +59,7 @@ _ARCH_FAMILIES = {
     "NemotronHForCausalLM": "nemotronh",
     "OlmoHybridForCausalLM": "olmohybrid",
     "GraniteMoeHybridForCausalLM": "granitemoehybrid",
+    "SmallThinkerForCausalLM": "smallthinker",
 }
 
 
@@ -76,6 +77,7 @@ _MODEL_TYPE_FAMILIES = {"llama": "llama", "mistral": "llama", "qwen2": "qwen2",
                         "nemotron_h": "nemotronh",
                         "olmo_hybrid": "olmohybrid",
                         "granitemoehybrid": "granitemoehybrid",
+                        "smallthinker": "smallthinker",
                         "megatron": "megatron",
                         "megatron-gpt": "megatron", "megatron_gpt": "megatron"}
 
@@ -531,6 +533,76 @@ def _laguna_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerCo
         moe_aux="sequence" if alpha else "none", aux_loss_coef=alpha, **common)
 
 
+def _smallthinker_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerConfig:
+    """PowerInfer's SmallThinker (``model_type: smallthinker``) as
+    SmallThinker-21BA3B-Instruct ships it: two per-layer layouts,
+    ``rope_layout`` and ``sliding_window_layout`` (a layer with 0 in both is a
+    FULL causal layer that rotates nothing and marks no position, "attn"; with
+    1 in both a window layer of ``sliding_window_size`` keys, the query's own
+    included, rotated by the model's ``rope_theta`` over the whole head,
+    "swa"; the two mixed in one layer are refused by name), every layer routed
+    with no shared expert and no dense layer: ``moe_num_primary_experts``
+    experts of ``moe_ffn_hidden_size``, ReLU-gated ("reglu"),
+    ``moe_num_active_primary_experts`` a token, a softmax over the chosen
+    logits (``moe_primary_router_apply_softmax`` with ``norm_topk_prob``),
+    dropless ("ragged"), and a router that reads the BLOCK'S INPUT, un-normed,
+    ahead of attention (``moe_router_input`` "block"). Only the first
+    ``num_hidden_layers`` entries of the layouts are read (a cut in depth
+    keeps them whole; a depth that ends inside a period runs as ONE period of
+    its whole length, ``_lead_and_period``). ``num_experts_held`` / ``expert_first`` /
+    ``expert_buffer_factor`` as for qwen3_next; ``router_aux_loss_coef`` (not
+    the source's key: its config.json states no balancing loss): HF's
+    all-choices loss at that coefficient, none without it. Secondary experts
+    (the family's description names a second level; the config has primary
+    keys only) and what else is not written here are refused by name."""
+    L = int(cfg["num_hidden_layers"])
+    ropes = list(cfg.get("rope_layout") or [1] * L)
+    windows = list(cfg.get("sliding_window_layout") or [0] * L)
+    refused = {
+        "moe_primary_router_apply_softmax":
+            cfg.get("moe_primary_router_apply_softmax", True) is not True,
+        "norm_topk_prob": cfg.get("norm_topk_prob", True) is not True,
+        "moe_enable_early_router": cfg.get("moe_enable_early_router", True) is not True,
+        "rope_scaling": cfg.get("rope_scaling") is not None,
+        "attention_bias": bool(cfg.get("attention_bias")),
+        "tie_word_embeddings": bool(cfg.get("tie_word_embeddings")),
+        "rope_layout": len(ropes) < L or not set(ropes) <= {0, 1},
+        "sliding_window_layout": len(windows) < L or not set(windows) <= {0, 1},
+        **{key: True for key in cfg if "secondary" in key and cfg[key]},
+    }
+    for key, bad in refused.items():
+        if bad:
+            raise ValueError(
+                f"smallthinker with {key}={cfg.get(key)!r} is not supported (written "
+                "down: primary experts only, a softmax over the chosen logits, the "
+                "router ahead of attention, no RoPE scaling, no bias, an untied head, "
+                "rope_layout and sliding_window_layout of 0 / 1 for every layer)")
+    for i, (r, w) in enumerate(zip(ropes, windows)):
+        if r != w:
+            raise ValueError(
+                f"smallthinker: layer {i} has rope_layout={r} and "
+                f"sliding_window_layout={w}: a window layer rotates and a full "
+                "layer does not (a rotated full layer or an unrotated window "
+                "layer is not written down)")
+    kind_of = lambda w: ("swa" if w else "attn", "moe")
+    kinds = [kind_of(w) for w in windows[:L]]
+    lead, period = _lead_and_period(kinds, "smallthinker")
+    head = int(cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"])
+    swa = {}
+    if any(windows[:L]):
+        swa = dict(swa_window=int(cfg["sliding_window_size"]),
+                   swa_heads=int(cfg["num_attention_heads"]))
+    alpha = float(cfg.get("router_aux_loss_coef") or 0.0)
+    common.update(d_ff=cfg["moe_ffn_hidden_size"], activation="reglu")
+    return TransformerConfig(
+        head_size=head, layer_pattern=period, lead_layers=lead, **swa,
+        unrotated_mixers=("attn",) if not all(windows[:L]) else (),
+        n_experts=cfg["moe_num_primary_experts"], **_held_share(cfg, "smallthinker"),
+        moe_top_k=cfg["moe_num_active_primary_experts"], moe_norm_topk=True,
+        moe_score="softmax", moe_impl="ragged", moe_router_input="block",
+        moe_aux="all_choices" if alpha else "none", aux_loss_coef=alpha, **common)
+
+
 def config_from_hf(hf_config) -> TransformerConfig:
     """Map an HF config object/dict to a TransformerConfig."""
     cfg = hf_config if isinstance(hf_config, dict) else hf_config.to_dict()
@@ -861,6 +933,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
             aux_loss_coef=alpha, **common)
     if family == "laguna":
         return _laguna_config(cfg, common)
+    if family == "smallthinker":
+        return _smallthinker_config(cfg, common)
     if family == "lfm2moe":
         return _lfm2_config(cfg, common)
     if family == "mixtral":

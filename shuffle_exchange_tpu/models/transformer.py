@@ -41,7 +41,8 @@ class TransformerConfig:
     n_kv_heads: Optional[int] = None          # None = MHA; < n_heads = GQA
     d_ff: Optional[int] = None                 # default 4*d (gelu) or 8/3*d (swiglu)
     max_seq_len: int = 2048
-    activation: str = "gelu"                   # "gelu" | "swiglu"
+    activation: str = "gelu"                   # "gelu" | "swiglu" | "reglu" | ... (gate_fn,
+                                               # activation_fn)
     norm: str = "layernorm"                    # "layernorm" | "rmsnorm"
     position: str = "learned"                  # "learned" | "rope" | "alibi" | "none" (no
                                                # position signal at all: attention among
@@ -302,6 +303,19 @@ class TransformerConfig:
     swa_heads: int = 0
     swa_rope_theta: float = 0.0
     swa_rotary_dim: int = 0
+    # Mixers of a layer_pattern that rotate NOTHING in a model whose
+    # ``position`` is "rope" (SmallThinker: ("attn",), full layers that mark no
+    # position beside window layers that rotate by the model's own table).
+    # Such a layer is handed ``rope`` (None, None) (``rope_for``) and opens the
+    # scopes ``nope_qkv`` / ``nope_core`` / ``nope_out`` inside the attention
+    # layer's. (``position`` "none" is the same for the WHOLE model.)
+    unrotated_mixers: Tuple[str, ...] = ()
+    # What a routed block's router reads: "ffn" = what its experts read, the
+    # post-attention norm (every model before PR 57); "block" = the block's
+    # INPUT, un-normed, as it was before the mixer (SmallThinker: the choice
+    # can be made, and the experts fetched, while attention runs). The blocks
+    # of a stack of several kinds only, on the dropless "ragged" impl.
+    moe_router_input: str = "ffn"
     # YaRN on the model's own RoPE table (the "attn" layers'; ``rope_table``):
     # (factor, original_max_position_embeddings, beta_fast, beta_slow,
     # attention_factor), () = unscaled. The inverse frequencies are blended
@@ -464,7 +478,8 @@ def tiny_moe(vocab=256, d=64, layers=2, heads=4, seq=64, experts=4, **kw) -> Tra
 
 
 def activation_fn(name: str):
-    """Non-gated activation dispatch ("swiglu" is handled structurally).
+    """Non-gated activation dispatch (the gated "swiglu" and "reglu" are
+    handled structurally: ``gate_fn``).
 
     "gelu" is the exact (erf) form as in HF; "gelu_new"/"gelu_pytorch_tanh"
     are the tanh approximation (GPT-2 lineage)."""
@@ -480,7 +495,17 @@ def activation_fn(name: str):
                 "gelu_new": _ft.partial(jax.nn.gelu, approximate=True),
                 "gelu_pytorch_tanh": _ft.partial(jax.nn.gelu, approximate=True)}[name]
     except KeyError:
-        raise ValueError(f"Unsupported activation {name!r}; use swiglu/gelu/relu/relu2/silu/gelu_new")
+        raise ValueError(f"Unsupported activation {name!r}; use swiglu/reglu/gelu/relu/relu2/silu/gelu_new")
+
+
+def gate_fn(name: str):
+    """The nonlinearity on the gate of a GATED feed-forward unit, ``act(x Wg)
+    * (x Wu)``: SiLU for "swiglu", ReLU for "reglu" (SmallThinker's experts);
+    None for an ungated activation (``activation_fn``'s). A gated unit has a
+    third matrix, so this decides shapes as well as arithmetic."""
+    import jax
+
+    return {"swiglu": jax.nn.silu, "reglu": jax.nn.relu}.get(name)
 
 
 def _norm(x, weight, bias, kind: str, eps: float = 1e-5):
@@ -951,7 +976,7 @@ class Transformer:
                 layer[f"moe_{name}"] = held.reshape(lead + held.shape[1:])
             if cfg.moe_shared_expert_ff > 0:
                 Fs = cfg.moe_shared_expert_ff
-                if cfg.activation == "swiglu":     # an ungated one has no gate matrix
+                if gate_fn(cfg.activation):        # an ungated one has no gate matrix
                     layer["moe_shared_w_gate"] = stack(next(keys), (D, Fs), D)
                 layer["moe_shared_w_up"] = stack(next(keys), (D, Fs), D)
                 layer["moe_shared_w_down"] = stack(next(keys), (Fs, D), Fs)
@@ -960,7 +985,7 @@ class Transformer:
                 elif cfg.moe_shared_gate != "none":
                     raise ValueError("moe_shared_gate must be 'sigmoid' or 'none'; "
                                      f"got {cfg.moe_shared_gate!r}")
-        elif cfg.activation == "swiglu":
+        elif gate_fn(cfg.activation):
             F = cfg.dense_ff_dim
             layer["w_gate"] = stack(next(keys), (D, F), D)
             layer["w_up"] = stack(next(keys), (D, F), D)
@@ -1068,8 +1093,11 @@ class Transformer:
     def rope_for(self, mixer: str, seq_len: int):
         """The (cos, sin) table the layers of mixer ``mixer`` rotate by: the
         model's own (``rope_theta``, ``rotary_dim``, ``rope_yarn``) or the
-        window kind's (``swa_rope_theta``, ``swa_rotary_dim``, unscaled)."""
+        window kind's (``swa_rope_theta``, ``swa_rotary_dim``, unscaled);
+        (None, None) for a kind that rotates nothing (``unrotated_mixers``)."""
         cfg = self.config
+        if mixer in cfg.unrotated_mixers:
+            return None, None
         if mixer == "swa":
             return rope_table(seq_len, cfg.swa_rotary_dim or cfg.head_dim,
                               cfg.swa_rope_theta or cfg.rope_theta)
@@ -1143,15 +1171,27 @@ class Transformer:
                     return step(h, mix(lw, y, rope), "attn_out")
                 return h + mix(lw, y, rope)
 
-            def ffn_half(lw, h):
+            # a router that reads the block's INPUT (``moe_router_input``
+            # "block"): the feed-forward half takes (x, h), x the block's input
+            # as the mixer half got it. Under per-half remat x is the mixer
+            # half's kept input already, so a layer keeps nothing more between
+            # the passes; the routing is replayed with the half, as it is for
+            # a router that reads y2, and the router's gradient reaches x
+            if cfg.moe_router_input not in ("ffn", "block"):
+                raise ValueError("moe_router_input is 'ffn' or 'block'; got "
+                                 f"{cfg.moe_router_input!r}")
+            block_router = cfg.moe_router_input == "block" and ffn == "moe"
+
+            def ffn_half(lw, h, x=None):
+                route = {} if x is None else {"router_x": x}
                 with trace.scope("mlp_norm"):
                     y2 = _norm(h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm, eps=cfg.norm_eps)
                 with trace.scope("moe" if ffn == "moe" else "mlp"):
                     if scaled:
                         ff, aux, stats = self._ffn(lw, h, y2, None, moe_on, ffn,
-                                                   residual=False)
+                                                   residual=False, **route)
                         return step(h, ff, "mlp"), aux, stats
-                    return self._ffn(lw, h, y2, None, moe_on, ffn)
+                    return self._ffn(lw, h, y2, None, moe_on, ffn, **route)
 
             if cfg.norm_order == "output":
                 # the Olmo 2 / 3 order: the sublayer reads h as it is and its
@@ -1177,6 +1217,11 @@ class Transformer:
                         f"residual_scale={cfg.residual_scale} in a block that norms "
                         "its sublayers' OUTPUT (norm_order 'output'): the Granite "
                         "family's blocks norm the input")
+                if block_router:
+                    raise NotImplementedError(
+                        "moe_router_input='block' (a router that reads the block's "
+                        "input) in a block that norms its sublayers' OUTPUT "
+                        "(norm_order 'output'): SmallThinker's blocks norm the input")
             elif cfg.norm_order != "input":
                 raise ValueError("norm_order is 'input' or 'output'; got "
                                  f"{cfg.norm_order!r}")
@@ -1189,7 +1234,10 @@ class Transformer:
             if ffn == "none":
                 # a mixer alone: one residual step, nothing routed
                 return mixer_half(lw, h), (jnp.zeros((), jnp.float32), None)
-            h, aux, stats = ffn_half(lw, mixer_half(lw, h))
+            if block_router:
+                h, aux, stats = ffn_half(lw, mixer_half(lw, h), h)
+            else:
+                h, aux, stats = ffn_half(lw, mixer_half(lw, h))
             return h, (aux, stats)
         if cfg.norm_order != "input":
             raise NotImplementedError(
@@ -1201,6 +1249,12 @@ class Transformer:
                 f"residual_scale={cfg.residual_scale} (the Granite family's "
                 "residual_multiplier) is applied by the blocks of a stack of several "
                 "kinds (layer_pattern); a one-kind model adds its sublayers unscaled")
+        if cfg.moe_router_input != "ffn":
+            raise NotImplementedError(
+                f"moe_router_input={cfg.moe_router_input!r} (a router that reads the "
+                "block's input) is the form of a routed block in a stack of several "
+                "kinds (layer_pattern); a one-kind model's router reads what its "
+                "experts read")
         if cfg.post_ln:
             y = h   # BERT: sublayer input is unnormalized; LN follows the add
         else:
@@ -1288,7 +1342,10 @@ class Transformer:
         plain gain [head_dim] each, the block norm's eps) BEFORE the rotation,
         under ``attn_qk_norm`` (LFM2). With ``position`` "none" nothing is
         rotated and nothing else marks a position (Nemotron-H: the state-space
-        layers beside it carry the order; ``rope`` is then (None, None)). With
+        layers beside it carry the order; ``rope`` is then (None, None)); a
+        mixer named in ``unrotated_mixers`` likewise, in a model whose other
+        kind rotates (SmallThinker's full layers), under its own scopes
+        ``nope_qkv`` / ``nope_core`` / ``nope_out``. With
         ``qk_norm`` True an "attn" layer norms q and k over the WHOLE
         projection (gains [H x Dh] and [KV x Dh], a float32 statistic) before
         the split into heads, under ``attn_qk_norm`` (Olmo Hybrid). None of
@@ -1312,7 +1369,8 @@ class Transformer:
                 f"GQA, rotated (position 'rope') or not at all ('none'); this "
                 f"configuration sets {flags or cfg.position!r}")
         windowed = mixer == "swa"
-        rotated = cfg.position == "rope"
+        nope = mixer in cfg.unrotated_mixers
+        rotated = cfg.position == "rope" and not nope
         if windowed and not rotated:
             raise NotImplementedError("mixer 'swa' rotates by its own table: position 'rope'")
         if windowed and cfg.swa_window <= 0:
@@ -1322,7 +1380,9 @@ class Transformer:
         cos, sin = rope
         # a window layer's own scope inside each of the attention layer's
         own = lambda name: trace.scope(name) if name else contextlib.nullcontext()
-        swa = lambda part: own("swa_" + part if windowed else None)
+        # (and an unrotated layer's, in a model whose others rotate)
+        swa = lambda part: own("swa_" + part if windowed else
+                               "nope_" + part if nope else None)
         with trace.scope("attn_qkv"):
             if whole_norm:
                 # Olmo 2 / 3: RMSNorm over the WHOLE projection (all heads
@@ -1616,10 +1676,13 @@ class Transformer:
                     z.astype(f32))).astype(y.dtype)
             return o.reshape(B, T, Hv * dv) @ lw["w_out"]
 
-    def _ffn(self, lw, h, y2, attn_out, moe_on, ffn=None, residual=True):
+    def _ffn(self, lw, h, y2, attn_out, moe_on, ffn=None, residual=True,
+             router_x=None):
         """The block's second half: (MoE or dense) feed-forward on ``y2`` and
         the residual add (``residual=False``: the feed-forward's output alone,
-        for a block that norms it first). Returns (h, moe_aux, stats): ``stats`` is None for
+        for a block that norms it first). ``router_x``: what a routed layer's
+        router reads where that is not ``y2`` (``moe_router_input`` "block":
+        the block's input; ``moe.layer.moe_layer``). Returns (h, moe_aux, stats): ``stats`` is None for
         a dense model, else this layer's ``expert_tokens`` [E] int32 (the
         token-choices the router gave each of ALL its experts), ``router_prob``
         [E] (mean router probability), ``held_rows`` (the token-choices the
@@ -1639,6 +1702,7 @@ class Transformer:
         dtype = h.dtype
         aux = jnp.zeros((), jnp.float32)
         stats = None
+        gate_act = gate_fn(cfg.activation)      # None: an ungated unit
         if (ffn or cfg.pattern[0][1]) == "moe":
             from ..moe.layer import held_buffer_rows, moe_layer
 
@@ -1661,7 +1725,8 @@ class Transformer:
                                 impl=cfg.moe_impl, normalize_weights=cfg.moe_norm_topk,
                                 scanned=True, aux=cfg.moe_aux, score=cfg.moe_score,
                                 select_bias=lw.get("moe_select_bias"),
-                                weight_scale=cfg.moe_weight_scale, **share)
+                                weight_scale=cfg.moe_weight_scale, router_x=router_x,
+                                **share)
                 aux = res.aux_loss
                 if cfg.moe_aux == "all_choices":
                     aux = aux / cfg.n_layers
@@ -1682,6 +1747,11 @@ class Transformer:
 
             if moe_on is None:
                 ff, aux, stats = moe_branch(y2)
+            elif router_x is not None:
+                raise NotImplementedError(
+                    "a router that reads the block's input (router_x) under "
+                    "moe_layer_pattern's per-layer flag: that flag varies ONE kind "
+                    "of layer, whose router reads what its experts read")
             else:
                 def dense_branch(y2):
                     # expert slot 0 carries the dense FFN of interleaved
@@ -1689,11 +1759,11 @@ class Transformer:
                     up = y2 @ expert_params["w_up"][0].astype(dtype)
                     if "b_up" in expert_params:
                         up = up + expert_params["b_up"][0].astype(dtype)
-                    if cfg.activation == "swiglu":
+                    if gate_act:
                         g = y2 @ expert_params["w_gate"][0].astype(dtype)
                         if "b_gate" in expert_params:
                             g = g + expert_params["b_gate"][0].astype(dtype)
-                        hh = jax.nn.silu(g) * up
+                        hh = gate_act(g) * up
                     else:
                         hh = activation_fn(cfg.activation)(up)
                     out = hh @ expert_params["w_down"][0].astype(dtype)
@@ -1720,8 +1790,8 @@ class Transformer:
                 # added through a per-token sigmoid gate (Qwen2-MoE,
                 # Qwen3-Next) or as it is (DeepSeek-V3: moe_shared_gate "none")
                 with trace.scope("moe_shared"):
-                    if cfg.activation == "swiglu":
-                        inner = jax.nn.silu(y2 @ lw["moe_shared_w_gate"]) * (
+                    if gate_act:
+                        inner = gate_act(y2 @ lw["moe_shared_w_gate"]) * (
                             y2 @ lw["moe_shared_w_up"])
                     else:       # ungated (Nemotron-H's relu2): W2 act(W1 y)
                         inner = activation_fn(cfg.activation)(y2 @ lw["moe_shared_w_up"])
@@ -1730,13 +1800,13 @@ class Transformer:
                         shared = jax.nn.sigmoid(
                             y2 @ lw["moe_shared_gate"]).astype(ff.dtype) * shared
                     ff = ff + shared
-        elif cfg.activation == "swiglu":
+        elif gate_act:
             # Tagged so remat_policy="save_ffn" can keep the two big FFN
             # projections (the bulk of layer FLOPs) out of the backward
             # recompute; the elementwise silu/mul re-derives from them free.
             gate = checkpoint_name(y2 @ lw["w_gate"], "ffn_gate")
             up = checkpoint_name(y2 @ lw["w_up"], "ffn_up")
-            ff = (jax.nn.silu(gate) * up) @ lw["w_down"]
+            ff = (gate_act(gate) * up) @ lw["w_down"]
         elif cfg.mlp_bias:
             act = activation_fn(cfg.activation)
             ff = act(y2 @ lw["w_up"] + lw["b_up"].astype(dtype)) @ lw["w_down"] + lw["b_down"].astype(dtype)
@@ -2039,6 +2109,8 @@ class Transformer:
             # a RoPE table a kind, built here, once, outside the scans
             ropes = {"swa": self.rope_for("swa", x.shape[-2])} if any(
                 mixer == "swa" for mixer, _ in cfg.kinds_used) else {}
+            ropes.update({mixer: self.rope_for(mixer, x.shape[-2])
+                          for mixer in cfg.unrotated_mixers})
 
             def run(kind):
                 # a layer of the softmax-attention family is checkpointed

@@ -17,6 +17,7 @@ moe_gemm → the per-expert matmul is a single batched einsum on the MXU).
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import NamedTuple, Optional
 
@@ -27,7 +28,7 @@ def init_expert_mlp(rng, n_experts: int, d_model: int, d_ff: int, activation: st
                     bias: bool = False):
     """Stacked expert FFN weights: leading dim E (shard over "expert").
 
-    ``bias=True`` adds per-expert b_up/b_down (+ b_gate for swiglu) leaves —
+    ``bias=True`` adds per-expert b_up/b_down (+ b_gate for a gated unit) leaves —
     the classic Megatron/DeepSpeed-MoE expert layout (reference
     module_inject/containers/megatron_gpt_moe.py imports biased experts)."""
     import jax
@@ -40,12 +41,15 @@ def init_expert_mlp(rng, n_experts: int, d_model: int, d_ff: int, activation: st
         "w_up": jax.random.normal(k2, (n_experts, d_model, d_ff), jnp.float32) * scale_in,
         "w_down": jax.random.normal(k3, (n_experts, d_ff, d_model), jnp.float32) * scale_out,
     }
-    if activation == "swiglu":
+    from ..models.transformer import gate_fn
+
+    gated = gate_fn(activation) is not None      # "swiglu", "reglu": a third matrix
+    if gated:
         params["w_gate"] = jax.random.normal(k1, (n_experts, d_model, d_ff), jnp.float32) * scale_in
     if bias:
         params["b_up"] = jnp.zeros((n_experts, d_ff), jnp.float32)
         params["b_down"] = jnp.zeros((n_experts, d_model), jnp.float32)
-        if activation == "swiglu":
+        if gated:
             params["b_gate"] = jnp.zeros((n_experts, d_ff), jnp.float32)
     return params
 
@@ -85,19 +89,19 @@ def expert_mlp(params, x, activation: str = "swiglu"):
     Optional per-expert biases (b_gate/b_up/b_down) add as [E, 1, F]
     broadcasts — the Megatron biased-expert layout. Expert weights may be
     int8/fp8 ``QuantizedMatrix`` leaves (see :func:`_dense_w`)."""
-    import jax
     import jax.numpy as jnp
 
     def b(key, t):
         return t + params[key].astype(t.dtype)[:, None, :] if key in params else t
 
-    up = b("b_up", jnp.einsum("ecm,emf->ecf", x, _dense_w(params["w_up"], x.dtype)))
-    if activation == "swiglu":
-        gate = b("b_gate", jnp.einsum("ecm,emf->ecf", x, _dense_w(params["w_gate"], x.dtype)))
-        h = jax.nn.silu(gate) * up
-    else:
-        from ..models.transformer import activation_fn
+    from ..models.transformer import activation_fn, gate_fn
 
+    gate_act = gate_fn(activation)
+    up = b("b_up", jnp.einsum("ecm,emf->ecf", x, _dense_w(params["w_up"], x.dtype)))
+    if gate_act:
+        gate = b("b_gate", jnp.einsum("ecm,emf->ecf", x, _dense_w(params["w_gate"], x.dtype)))
+        h = gate_act(gate) * up
+    else:
         h = activation_fn(activation)(up)
     return b("b_down", jnp.einsum("ecf,efm->ecm", h, _dense_w(params["w_down"], x.dtype)))
 
@@ -438,7 +442,6 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu",
     per-choice form 3 R + 3 k S (:func:`_held_runs`, :func:`_run_sums`). The
     buffer's factor buys room in memory, not time in the passes.
     """
-    import jax
     import jax.numpy as jnp
 
     from ..ops.grouped_gemm import grouped_matmul
@@ -494,13 +497,14 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu",
         return wt if isinstance(wt, QuantizedMatrix) else wt.astype(dtype)
 
     with trace.scope("moe_experts"):
-        up = b("b_up", grouped_matmul(xsort, w("w_up"), group_sizes))
-        if activation == "swiglu":
-            gate = b("b_gate", grouped_matmul(xsort, w("w_gate"), group_sizes))
-            h = jax.nn.silu(gate) * up
-        else:
-            from ..models.transformer import activation_fn
+        from ..models.transformer import activation_fn, gate_fn
 
+        gate_act = gate_fn(activation)
+        up = b("b_up", grouped_matmul(xsort, w("w_up"), group_sizes))
+        if gate_act:
+            gate = b("b_gate", grouped_matmul(xsort, w("w_gate"), group_sizes))
+            h = gate_act(gate) * up
+        else:
             h = activation_fn(activation)(up)
         out_sorted = b("b_down", grouped_matmul(h, w("w_down"), group_sizes))
     with trace.scope("moe_combine"):
@@ -551,7 +555,7 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
               scanned: bool = False, aux: str = "first_choice",
               expert_first: int = 0, buffer_rows: Optional[int] = None,
               score: str = "softmax", select_bias=None,
-              weight_scale: float = 1.0) -> MoEResult:
+              weight_scale: float = 1.0, router_x=None) -> MoEResult:
     """x [..., M] -> MoEResult. gate_w [M, E].
 
     impl:
@@ -586,6 +590,13 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
     (``gating.topk_select``; DeepSeek-V3's is sigmoid, biased, scaled, with
     ``aux="sequence"`` or ``"none"``), on the dropless "ragged" impl only: the capacity paths
     renormalise after their drops, which these forms have not been held to.
+    ``router_x`` [..., M] (x's leading shape): what the ROUTER reads where that
+    is not what the experts read (SmallThinker: the block's input, taken before
+    attention, while the experts read the post-attention norm ``x``): the
+    logits, the mean score and the balancing loss come from it, the experts'
+    rows from ``x``, and the router's gradient reaches ``router_x``. On the
+    "ragged" impl, a rank's share too; the router's scopes then nest in
+    ``pre_router``. None: the router reads ``x``.
     Named scopes inside the caller's ``moe``: ``moe_router`` (router matmul,
     softmax, top-k, aux), ``moe_dispatch`` (sort / slot assignment, gather,
     group sizes), ``moe_experts`` (the expert matmuls and activation),
@@ -613,18 +624,30 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
             "a rank's share of the experts (buffer_rows) runs the dropless "
             f"'ragged' impl only; got impl={impl!r}")
     plain_router = (score == "softmax" and select_bias is None
-                    and weight_scale == 1.0 and aux != "sequence")
+                    and weight_scale == 1.0 and aux != "sequence"
+                    and router_x is None)
     if not plain_router and impl != "ragged":
         raise ValueError(
-            "a sigmoid router, a selection bias, a weight scale or the "
-            "sequence-wise balance loss run the dropless 'ragged' impl only; "
-            f"got impl={impl!r}")
+            "a sigmoid router, a selection bias, a weight scale, the "
+            "sequence-wise balance loss or a router input of its own (router_x) "
+            f"run the dropless 'ragged' impl only; got impl={impl!r}")
     orig_shape = x.shape
     M = orig_shape[-1]
     xs = x.reshape(-1, M)
     S = xs.shape[0]
-    with trace.scope("moe_router"):
-        logits = (xs.astype(jnp.float32)) @ gate_w.astype(jnp.float32)   # [S, E]
+    if router_x is not None and router_x.shape != orig_shape:
+        raise ValueError(f"router_x {router_x.shape} is not x's shape {orig_shape}")
+    rs = xs if router_x is None else router_x.reshape(-1, M)
+
+    @contextlib.contextmanager
+    def router_scope():
+        """``moe_router``, inside ``pre_router`` where the router reads an
+        input of its own."""
+        outer = contextlib.nullcontext() if router_x is None else trace.scope("pre_router")
+        with outer, trace.scope("moe_router"):
+            yield
+    with router_scope():
+        logits = (rs.astype(jnp.float32)) @ gate_w.astype(jnp.float32)   # [S, E]
         # mean router score per expert (the gating's own softmax or sigmoid
         # again: XLA computes it once)
         prob = (jax.nn.sigmoid(logits) if score == "sigmoid"
@@ -659,7 +682,7 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
     if impl == "ragged":
         from .gating import topk_select
 
-        with trace.scope("moe_router"):
+        with router_scope():
             idx, w, aux_loss, masks = topk_select(
                 logits, k, normalize_weights=normalize_weights, train=train,
                 rng=rng, noise_std=noise_std, aux=aux, score=score,
@@ -671,7 +694,7 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
         if select_bias is not None:
             # what each expert's choices weigh in all: a bias that entered the
             # weights as well as the choice shows here and in no count
-            with trace.scope("moe_router"):
+            with router_scope():
                 meta["expert_weight"] = jax.lax.stop_gradient(sum(
                     (m * w[:, j:j + 1]).sum(axis=0) for j, m in enumerate(masks)))
         out, rows, dropped = expert_mlp_ragged(

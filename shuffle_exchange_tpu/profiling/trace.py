@@ -85,7 +85,11 @@ PREFIX = "sxt:"
 # (mixer "swa") opens "swa_qkv" and "swa_rope" inside "attn_qkv", "swa_core"
 # inside "attn_core" and "swa_out" inside "attn_out"; a full layer of such a
 # stack that rotates by a YaRN table does so under "rope_yarn" inside
-# "attn_qkv". A gated short-convolution layer (mixer "sconv") opens "sconv_in"
+# "attn_qkv"; a full layer that rotates NOTHING in a model whose window layers
+# rotate (``unrotated_mixers``) opens "nope_qkv", "nope_core" and "nope_out" as
+# a window layer opens its "swa_*". A router that reads an input of its own
+# (``moe_router_input`` "block") opens "pre_router" AROUND "moe_router".
+# A gated short-convolution layer (mixer "sconv") opens "sconv_in"
 # inside "attn_qkv", "sconv_mix" (the pass between its projections) inside
 # "attn_core" and "sconv_out" inside "attn_out". A Mamba-2 state-space layer
 # (mixer "ssm") opens "ssm_in" (its input projection), "ssm_conv" (taps, bias,
@@ -101,9 +105,10 @@ SCOPES = {
              "attn_gate", "gdn_conv", "gdn_gates", "gdn_scan", "gdn_out_norm",
              "mla_q", "mla_kv_down", "mla_kv_norm", "mla_kv_up", "mla_rope",
              "swa_qkv", "swa_rope", "swa_core", "swa_out", "rope_yarn",
+             "nope_qkv", "nope_core", "nope_out",
              "sconv_in", "sconv_mix", "sconv_out",
              "ssm_in", "ssm_conv", "ssm_gates", "ssm_scan", "ssm_out_norm", "ssm_out"),
-    "mlp": ("mlp_norm", "mlp", "moe", "moe_router", "moe_dispatch",
+    "mlp": ("mlp_norm", "mlp", "moe", "pre_router", "moe_router", "moe_dispatch",
             "moe_experts", "moe_combine", "moe_shared"),
     "loss": ("embed", "final_norm", "loss", "head_logits", "head_softmax",
              "head_dx", "head_dw"),

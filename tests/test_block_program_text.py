@@ -1,8 +1,8 @@
 """The programs of the hybrid cells' blocks do not move: the jaxpr text of
 ``Transformer._gdn`` and of one block of every kind (``layer_apply``, per-half
 remat on, as ``stack_apply`` calls it) at the tiny presets of
-``qwen3next-train``, ``nemotron3-train``, ``lfm2-train`` and
-``granite4h-train`` (the HF dicts of their own test files), and the bits of their outputs and gradients at a fixed
+``qwen3next-train``, ``nemotron3-train``, ``lfm2-train``,
+``granite4h-train`` and ``smallthinker-train`` (the HF dicts of their own test files), and the bits of their outputs and gradients at a fixed
 seed, against ``tests/data/block_program_text.json``, which was written from
 the commit BEFORE the output-normed block, the doubled beta and the
 whole-projection q/k norm among several kinds came in (PR 50). The halves'
@@ -91,8 +91,10 @@ def readings(cell: str) -> dict:
 
 
 # (``granite4h``: written from the commit that brought the block, PR 55; it
-# holds LATER changes to the multipliers' seams)
-CELLS = ("qwen3next", "nemotron3", "lfm2", "granite4h")
+# holds LATER changes to the multipliers' seams. ``smallthinker``: likewise
+# from PR 57, the feed-forward half that takes (x, h) and the unrotated full
+# layer beside rotated window layers)
+CELLS = ("qwen3next", "nemotron3", "lfm2", "granite4h", "smallthinker")
 
 
 @pytest.mark.parametrize("cell", CELLS)

@@ -670,6 +670,10 @@ _CELL_ATTENTION = {
     # MHA on one device, a group of one (PR 56)
     "gpt2m-train": (4, 1024, 16, 16, 64, 64, 0),
     "olmoe-train": (4, 4096, 16, 16, 128, 128, 0),
+    # 7 query heads a KV head, the first odd group above 1, and a window of
+    # 4096 = 8 key blocks of 512 and the diagonal (PR 57)
+    "smallthinker-train-full": (1, 16384, 28, 4, 128, 128, 0),
+    "smallthinker-train-window": (1, 16384, 28, 4, 128, 128, 4096),
 }
 
 
