@@ -56,13 +56,12 @@ class TransformerConfig:
     pos_offset: int = 0                        # OPT offsets positions by 2
     qk_norm: Any = False                       # True (OLMoE's, Olmo 2 / 3's): RMSNorm
                                                # (learned gain) over the WHOLE q and k
-                                               # projections, before heads and RoPE: a
-                                               # one-kind model's "attn", and mixer "attn"
-                                               # of a stack of several kinds (``_gqa``).
+                                               # projections, before heads and RoPE
+                                               # (mixers "attn" and "swa": ``_gqa``).
                                                # "head" (LFM2's): RMSNorm per HEAD over its
                                                # head_dim, one gain [head_dim] each for q and
-                                               # k shared by the heads, before RoPE: mixer
-                                               # "attn" of a stack of several kinds (``_gqa``).
+                                               # k shared by the heads, before RoPE (mixer
+                                               # "attn": ``_gqa``).
                                                # (mixer "gated_attn" always norms per head,
                                                # by the block norm's kind: Qwen3-Next's.)
     # Family structure flags (round 3, HF import breadth — reference
@@ -96,7 +95,6 @@ class TransformerConfig:
     # unscaled attention + alternating global/local layers.
     attn_scale: float = 0.0                    # 0 = 1/sqrt(Dh); GPT-Neo: 1.0;
                                                # Granite's attention_multiplier
-                                               # (mixer "attn" of a pattern too)
     local_attention_window: int = 0            # window for "local" layers
     attention_pattern: Tuple[str, ...] = ()    # per-layer "global"/"local",
                                                # cycled over n_layers: a flag
@@ -169,9 +167,8 @@ class TransformerConfig:
     # (``params["layers"][kind]``, [periods, layers of the kind a period, ...];
     # a one-kind model keeps the flat ``params["layers"]`` [n_layers, ...]).
     #   mixer "attn"        softmax attention as every flag above shapes it
-    #                       (a one-kind model); among several kinds plain
-    #                       causal RoPE GQA (``_gqa``: n_heads, the model's
-    #                       table, no flag of the family)
+    #                       (``_gqa``: n_heads, the model's table), whatever
+    #                       the stack's other layers are
     #         "swa"         the same over a window, a kind of its own:
     #                       swa_window / swa_heads / swa_rope_* below
     #         "gated_attn"  Qwen3-Next full attention: q and a sigmoid output
@@ -190,10 +187,10 @@ class TransformerConfig:
     #                       x B C, the scan, a gated grouped RMSNorm, one
     #                       projection back; no RoPE
     #   q/k norm: "attn" takes ``qk_norm`` (True = the whole projection;
-    #   "head" = per head, among several kinds only); "gated_attn"
-    #   norms per head always; "swa", "mla", "gdn", "sconv" and "ssm" have none.
-    #   rotation: among several kinds "attn" rotates by the model's table, or
-    #   by nothing where ``position`` is "none" (Nemotron-H: the state-space
+    #   "head" = per head); "swa" the whole-projection one; "gated_attn"
+    #   norms per head always; "mla", "gdn", "sconv" and "ssm" have none.
+    #   rotation: "attn" rotates by the model's table where ``position`` is
+    #   "rope", and by nothing where it is "none" (Nemotron-H: the state-space
     #   layers carry the order).
     #   ffn   "mlp" | "moe" | "none": a layer that is a mixer ALONE (one norm,
     #   one residual step, no ffn leaves: Nemotron-H's ``M*``, a state-space
@@ -214,12 +211,13 @@ class TransformerConfig:
     # lets them reach (-1, 1) (FLA's ``allow_neg_eigval``, Olmo Hybrid's
     # ``linear_allow_neg_eigval``).
     gdn_beta_scale: float = 1.0
-    # Where a block of a stack of several kinds norms: "input" (pre-norm,
-    # ``h + mix(norm(h))``: every model before PR 50) or "output" (the Olmo
-    # 2 / 3 order: ``h + norm(mix(h))``, ``h + norm(ffn(h))``, nothing normed
-    # on the way in). The same gains (``ln1_w`` / ``ln2_w``) and scopes
-    # (``attn_norm`` / ``mlp_norm``) either way. (``post_ln`` is BERT's
-    # ``norm(h + f(h))``, a one-kind model's.)
+    # Where a block norms: "input" (pre-norm, ``h + mix(norm(h))``) or
+    # "output" (the Olmo 2 / 3 order: ``h + norm(mix(h))``, ``h +
+    # norm(ffn(h))``, nothing normed on the way in). The same gains (``ln1_w``
+    # / ``ln2_w``) and scopes (``attn_norm`` / ``mlp_norm``) either way.
+    # (``post_ln`` is BERT's ``norm(h + f(h))`` and ``parallel_block`` GPT-J's
+    # ``h + mix(norm(h)) + ffn(norm(h))``: a block has ONE of the forms,
+    # ``Transformer.__init__``.)
     norm_order: str = "input"
     sconv_taps: int = 3                        # mixer "sconv": the convolution's taps
     # mixer "ssm": ``ssm_heads`` heads of ``ssm_head_dim`` channels (the inner
@@ -313,8 +311,8 @@ class TransformerConfig:
     # What a routed block's router reads: "ffn" = what its experts read, the
     # post-attention norm (every model before PR 57); "block" = the block's
     # INPUT, un-normed, as it was before the mixer (SmallThinker: the choice
-    # can be made, and the experts fetched, while attention runs). The blocks
-    # of a stack of several kinds only, on the dropless "ragged" impl.
+    # can be made, and the experts fetched, while attention runs). On the
+    # dropless "ragged" impl.
     moe_router_input: str = "ffn"
     # YaRN on the model's own RoPE table (the "attn" layers'; ``rope_table``):
     # (factor, original_max_position_embeddings, beta_fast, beta_slow,
@@ -329,7 +327,7 @@ class TransformerConfig:
     # (embedding_multiplier; a tied head reads the rows unscaled),
     # ``residual_scale`` each sublayer's output before it is added to the
     # stream (residual_multiplier: ``h + r * mix(norm(h))``, ``h + r *
-    # ffn(norm(h))``; the blocks of a layer_pattern), ``logit_divisor`` divides
+    # ffn(norm(h))``), ``logit_divisor`` divides
     # the logits (logits_scaling), in ``head`` and inside the chunked loss,
     # forward and backward. Each is applied in float32 to the value as its
     # producer left it, and the result rounded once.
@@ -810,6 +808,20 @@ class Transformer:
 
     def __init__(self, config: TransformerConfig):
         self.config = config
+        if config.norm_order not in ("input", "output"):
+            raise ValueError(f"norm_order is 'input' or 'output'; got {config.norm_order!r}")
+        if config.moe_router_input not in ("ffn", "block"):
+            raise ValueError("moe_router_input is 'ffn' or 'block'; got "
+                             f"{config.moe_router_input!r}")
+        forms = [name for name, on in (("post_ln", config.post_ln),
+                                       ("parallel_block", config.parallel_block),
+                                       ("norm_order='output'", config.norm_order == "output"))
+                 if on]
+        if len(forms) > 1:
+            raise ValueError(
+                "a block norms its sublayers' input (in sequence, or in parallel: "
+                "parallel_block), their output (norm_order 'output') or the sum "
+                f"(post_ln), one of them; this configuration sets {' and '.join(forms)}")
 
     # -- parameters ----------------------------------------------------
 
@@ -1165,266 +1177,147 @@ class Transformer:
         """One transformer block. h [B, T, D] -> (h, (moe_aux, this layer's
         router stats or None)): a carry and an output, as ``lax.scan`` wants.
         ``kind``: the layer's (mixer, ffn) of ``cfg.pattern``; None = the
-        first (a one-kind model's only one).
+        first (a one-kind model's only one). Every kind runs this one
+        skeleton, whatever the stack's other layers are: a mixer half and a
+        feed-forward half, each ``h + f(h)`` with the block's norm where
+        ``place`` says.
 
         ``local`` (traced bool scalar, GPT-Neo): this layer restricts
         attention to the trailing ``local_attention_window`` positions.
         ``moe_on`` (traced bool scalar, Megatron --expert-interval): False
         routes this layer through the dense FFN stored in expert slot 0
         (the flag is replica-identical, so both lax.cond branches keep a
-        uniform collective schedule across devices)."""
+        uniform collective schedule across devices).
+
+        Under remat the two halves are checkpointed EACH (``remat_halves``,
+        set by stack_apply): the backward then holds the mixer's residuals or
+        the FFN's, never both, for the same recomputation as one checkpoint a
+        layer (Qwen3-Next at 16,384 tokens: 1.5 GB of a 16 GB chip, PR 33).
+        What a layer keeps between the passes: each half's input (B x T x D)
+        and, where the mixer's attention takes a splash route, the kernel's
+        own ``out`` and ``logsumexp`` (B x H x T x (Dv + 2) x 2 bytes in
+        bf16: 136 MB at kanana-2's 2 x 32 x 8192 x 128), the one result of the
+        half that its replay would only rebuild: the backward kernels start
+        from them and the forward kernel runs once a layer and step, not twice
+        (PR 36). That is under every policy, "full" too, which therefore keeps
+        twice what it kept for these mixers (kanana-2 at 48 layers and 16,384
+        tokens a chip: 13.0 GB where it kept 6.4); policy "none"
+        (``jax.checkpoint``'s default) keeps nothing and recomputes. ``gdn``
+        has no such kernel; MHA takes "splash" on one device (PR 56) and keeps
+        them like any other; "stock_flash" (MHA per shard of a kernel mesh),
+        "reference" and the ring's hop kernels name nothing and recompute."""
         import jax
         import jax.numpy as jnp
 
         cfg = self.config
         mixer, ffn = kind or cfg.pattern[0]
-        B, T = h.shape[:2]
-        H, KV, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-        cos, sin = rope
-        dtype = h.dtype
-        if mixer != "attn" or cfg.several_kinds:
-            # the pattern's other mixers, and "attn" itself in a stack of
-            # several kinds (plain GQA there: ``_gqa``): no flag of the
-            # softmax-attention family reaches them (config_from_hf builds
-            # them; a pre-LN sequential block). Under remat the block's two halves are
-            # checkpointed EACH (``remat_halves``, set by stack_apply): the
-            # backward then holds the mixer's residuals or the FFN's, never
-            # both, for the same recomputation as one checkpoint a layer
-            # (Qwen3-Next at 16,384 tokens: 1.5 GB of a 16 GB chip, PR 33).
-            # What a layer keeps between the passes: each half's input
-            # (B x T x D) and, where the mixer's attention takes a splash
-            # route, the kernel's own ``out`` and ``logsumexp`` (B x H x T
-            # x (Dv + 2) x 2 bytes in bf16: 136 MB at kanana-2's 2 x 32 x
-            # 8192 x 128), the one result of the half that its replay would
-            # only rebuild: the backward kernels start from them and the
-            # forward kernel runs once a layer and step, not twice (PR 36).
-            # That is under every policy, "full" too, which therefore keeps
-            # twice what it kept for these mixers (kanana-2 at 48 layers and
-            # 16,384 tokens a chip: 13.0 GB where it kept 6.4); policy "none"
-            # (``jax.checkpoint``'s default) keeps nothing and recomputes.
-            # ``gdn`` has no such kernel; MHA takes "splash" on one device
-            # (PR 56) and keeps them like any other; "stock_flash" (MHA per
-            # shard of a kernel mesh), "reference" and the ring's hop
-            # kernels name nothing and recompute
-            mix = {"gdn": self._gdn, "gated_attn": self._gated_attention,
-                   "mla": self._mla, "attn": self._gqa, "sconv": self._sconv,
-                   "ssm": self._ssm,
-                   "swa": functools.partial(self._gqa, mixer="swa")}[mixer]
+        mix = {"gdn": self._gdn, "gated_attn": self._gated_attention,
+               "mla": self._mla, "sconv": self._sconv, "ssm": self._ssm,
+               "attn": functools.partial(self._gqa, local=local),
+               "swa": functools.partial(self._gqa, mixer="swa")}[mixer]
+        # where the block norms (``Transformer.__init__`` admits one form):
+        # each sublayer's input (pre-LN), its output (the Olmo 2 / 3 order),
+        # the sum (BERT's post-LN), or the input of a parallel block (GPT-J /
+        # NeoX / Falcon: h + mix(ln1 h) + ffn(ln2 h, or ln1 h again)
+        place = ("parallel" if cfg.parallel_block else "sum" if cfg.post_ln
+                 else cfg.norm_order)
+        # what the feed-forward half reads of the block's first half, where it
+        # reads anything. x, the block's input as the mixer half got it: a
+        # router that reads the block's INPUT (``moe_router_input`` "block")
+        # and a parallel block's second norm (under per-half remat x is the
+        # mixer half's kept input already, so a layer keeps nothing more
+        # between the passes; the routing is replayed with the half, as it is
+        # for a router that reads y2, and the router's gradient reaches x).
+        # y2, the mixer half's normed input: a parallel block of ONE norm
+        block_router = cfg.moe_router_input == "block" and ffn == "moe"
+        shared = place == "parallel" and cfg.parallel_shared_ln and ffn != "none"
 
-            scaled = cfg.residual_scale != 1.0
+        def normed(lw, x, n, scope):
+            with trace.scope(scope):
+                return _norm(x, lw[f"ln{n}_w"], lw.get(f"ln{n}_b", 0), cfg.norm,
+                             eps=cfg.norm_eps)
 
-            def step(h, out, scope):
-                """``h + residual_scale * out``: the sum formed in float32
-                and rounded once, under the scope of the sublayer whose
-                output it scales."""
-                with trace.scope(scope):
-                    return (h.astype(jnp.float32) + cfg.residual_scale
-                            * out.astype(jnp.float32)).astype(h.dtype)
+        def add(h, out, scope):
+            """``h + residual_scale * out``: a scaled sum is formed in float32
+            and rounded once, under the scope of the sublayer whose output it
+            scales."""
+            if cfg.residual_scale == 1.0:
+                return h + out
+            with trace.scope(scope):
+                return (h.astype(jnp.float32) + cfg.residual_scale
+                        * out.astype(jnp.float32)).astype(h.dtype)
 
-            def mixer_half(lw, h):
-                with trace.scope("attn_norm"):
-                    y = _norm(h, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm, eps=cfg.norm_eps)
-                if scaled:
-                    return step(h, mix(lw, y, rope), "attn_out")
-                return h + mix(lw, y, rope)
+        def mixer_half(lw, h):
+            y = normed(lw, h, 1, "attn_norm") if place in ("input", "parallel") else h
+            out = mix(lw, y, rope)
+            if place == "output":
+                out = normed(lw, out, 1, "attn_norm")
+            h = add(h, out, "attn_out")
+            if place == "sum":
+                h = normed(lw, h, 1, "attn_norm")
+            return (h, y) if shared else h
 
-            # a router that reads the block's INPUT (``moe_router_input``
-            # "block"): the feed-forward half takes (x, h), x the block's input
-            # as the mixer half got it. Under per-half remat x is the mixer
-            # half's kept input already, so a layer keeps nothing more between
-            # the passes; the routing is replayed with the half, as it is for
-            # a router that reads y2, and the router's gradient reaches x
-            if cfg.moe_router_input not in ("ffn", "block"):
-                raise ValueError("moe_router_input is 'ffn' or 'block'; got "
-                                 f"{cfg.moe_router_input!r}")
-            block_router = cfg.moe_router_input == "block" and ffn == "moe"
-
-            def ffn_half(lw, h, x=None):
-                route = {} if x is None else {"router_x": x}
-                with trace.scope("mlp_norm"):
-                    y2 = _norm(h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm, eps=cfg.norm_eps)
-                with trace.scope("moe" if ffn == "moe" else "mlp"):
-                    if scaled:
-                        ff, aux, stats = self._ffn(lw, h, y2, None, moe_on, ffn,
-                                                   residual=False, **route)
-                        return step(h, ff, "mlp"), aux, stats
-                    return self._ffn(lw, h, y2, None, moe_on, ffn, **route)
-
-            if cfg.norm_order == "output":
-                # the Olmo 2 / 3 order: the sublayer reads h as it is and its
-                # OUTPUT is normed before the residual add, under the same
-                # scopes and gains as the input order's norms
-                def mixer_half(lw, h):
-                    out = mix(lw, h, rope)
-                    with trace.scope("attn_norm"):
-                        out = _norm(out, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm,
-                                    eps=cfg.norm_eps)
-                    return h + out
-
-                def ffn_half(lw, h):
-                    with trace.scope("moe" if ffn == "moe" else "mlp"):
-                        ff, aux, stats = self._ffn(lw, h, h, None, moe_on, ffn,
-                                                   residual=False)
-                    with trace.scope("mlp_norm"):
-                        ff = _norm(ff, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm,
-                                   eps=cfg.norm_eps)
-                    return h + ff, aux, stats
-                if scaled:
-                    raise NotImplementedError(
-                        f"residual_scale={cfg.residual_scale} in a block that norms "
-                        "its sublayers' OUTPUT (norm_order 'output'): the Granite "
-                        "family's blocks norm the input")
-                if block_router:
-                    raise NotImplementedError(
-                        "moe_router_input='block' (a router that reads the block's "
-                        "input) in a block that norms its sublayers' OUTPUT "
-                        "(norm_order 'output'): SmallThinker's blocks norm the input")
-            elif cfg.norm_order != "input":
-                raise ValueError("norm_order is 'input' or 'output'; got "
-                                 f"{cfg.norm_order!r}")
-
-            if remat_halves:
-                policy = _remat_policy(cfg.remat_policy)
-                mixer_half = jax.checkpoint(
-                    mixer_half, policy=_keeping_splash_residuals(policy))
-                ffn_half = jax.checkpoint(ffn_half, policy=policy)
-            if ffn == "none":
-                # a mixer alone: one residual step, nothing routed
-                return mixer_half(lw, h), (jnp.zeros((), jnp.float32), None)
-            if block_router:
-                h, aux, stats = ffn_half(lw, mixer_half(lw, h), h)
+        def ffn_half(lw, h, x=None, y2=None):
+            if place == "parallel":
+                y2 = normed(lw, x, 2, "mlp_norm") if y2 is None else y2
             else:
-                h, aux, stats = ffn_half(lw, mixer_half(lw, h))
-            return h, (aux, stats)
-        if cfg.norm_order != "input":
-            raise NotImplementedError(
-                f"norm_order={cfg.norm_order!r} (a block that norms its sublayers' "
-                "OUTPUT) is the form of a stack of several kinds (layer_pattern); "
-                "a one-kind model norms the input, or the sum (post_ln)")
-        if cfg.residual_scale != 1.0:
-            raise NotImplementedError(
-                f"residual_scale={cfg.residual_scale} (the Granite family's "
-                "residual_multiplier) is applied by the blocks of a stack of several "
-                "kinds (layer_pattern); a one-kind model adds its sublayers unscaled")
-        if cfg.moe_router_input != "ffn":
-            raise NotImplementedError(
-                f"moe_router_input={cfg.moe_router_input!r} (a router that reads the "
-                "block's input) is the form of a routed block in a stack of several "
-                "kinds (layer_pattern); a one-kind model's router reads what its "
-                "experts read")
-        if cfg.post_ln:
-            y = h   # BERT: sublayer input is unnormalized; LN follows the add
-        else:
-            with trace.scope("attn_norm"):
-                y = _norm(h, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm, eps=cfg.norm_eps)
-        if cfg.qk_norm == "head":
-            raise NotImplementedError(
-                "qk_norm='head' (per-head q/k RMSNorm) is the form of mixer 'attn' "
-                "in a stack of several kinds (Transformer._gqa); a one-kind model's "
-                "attention norms the whole projection (qk_norm=True) or nothing")
-        with trace.scope("attn_qkv"):
-            q, k = y @ lw["wq"], y @ lw["wk"]
-            if cfg.qk_norm:
-                # OLMoE: RMSNorm over the WHOLE projection (all heads
-                # together, one learned gain per column), before the split
-                # into heads and before RoPE. Not a per-head norm.
-                with trace.scope("attn_qk_norm"):
-                    q = _norm(q, lw["q_norm_w"], 0, "rmsnorm", eps=cfg.norm_eps)
-                    k = _norm(k, lw["k_norm_w"], 0, "rmsnorm", eps=cfg.norm_eps)
-            q = q.reshape(B, T, H, Dh)
-            k = k.reshape(B, T, KV, Dh)
-            v = (y @ lw["wv"]).reshape(B, T, KV, Dh)
-            if cfg.attn_qkv_bias:
-                q = q + lw["b_q"].astype(dtype).reshape(H, Dh)
-                k = k + lw["b_k"].astype(dtype).reshape(KV, Dh)
-                v = v + lw["b_v"].astype(dtype).reshape(KV, Dh)
-            if cfg.position == "rope":
-                q = apply_rope(q, cos, sin, interleaved=cfg.rope_interleaved)
-                k = apply_rope(k, cos, sin, interleaved=cfg.rope_interleaved)
-        # Name the KV residuals so remat_policy="offload_kv_host" can park
-        # them in host RAM between fwd and bwd (FPDT SequenceChunk offload,
-        # reference sequence/fpdt_layer.py:462; XLA schedules the transfers
-        # and double-buffers the prefetch). q joins for the selective-save
-        # policies (save_attn_seams / save_ffn). No-op under other policies.
-        from jax.ad_checkpoint import checkpoint_name
+                y2 = normed(lw, h, 2, "mlp_norm") if place == "input" else h
+            with trace.scope("moe" if ffn == "moe" else "mlp"):
+                ff, aux, stats = self._ffn(lw, y2, moe_on, ffn,
+                                           router_x=x if block_router else None)
+                if place != "output":
+                    h = add(h, ff, "mlp")
+            if place == "output":
+                h = add(h, normed(lw, ff, 2, "mlp_norm"), "mlp")
+            return normed(lw, h, 2, "mlp_norm") if place == "sum" else h, aux, stats
 
-        q = checkpoint_name(q, "q")
-        k = checkpoint_name(k, "kv")
-        v = checkpoint_name(v, "kv")
-        alibi = (alibi_slopes(H) * cfg.alibi_slope_scale
-                 if cfg.position == "alibi" else None)
-        if cfg.attn_scale:
-            # GPT-Neo omits the 1/sqrt(Dh) score scaling; the attention
-            # internals always divide, so pre-multiply q to net attn_scale
-            q = q * jnp.asarray(cfg.attn_scale * math.sqrt(Dh), q.dtype)
-        with trace.scope("attn_core"):
-            if cfg.local_attention_window and local is not None:
-                attn = _windowed_attention(q, k, v, cfg.local_attention_window,
-                                           local).reshape(B, T, H * Dh)
-            else:
-                attn = self._attention(q, k, v, alibi).reshape(B, T, H * Dh)
-        attn = checkpoint_name(attn, "attn")
-        with trace.scope("attn_out"):
-            attn_out = attn @ lw["wo"]
-            if cfg.attn_out_bias:
-                attn_out = attn_out + lw["b_o"].astype(dtype)
-        with trace.scope("mlp_norm"):
-            if cfg.post_ln:
-                h = _norm(h + attn_out, lw["ln1_w"], lw.get("ln1_b", 0), cfg.norm,
-                          eps=cfg.norm_eps)
-                y2 = h
-            elif cfg.parallel_block:
-                # GPT-J/NeoX/Falcon: h + attn(ln1 h) + mlp(ln2 h or ln1 h)
-                y2 = y if cfg.parallel_shared_ln else _norm(
-                    h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm, eps=cfg.norm_eps)
-            else:
-                h = h + attn_out
-                y2 = _norm(h, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm, eps=cfg.norm_eps)
-        with trace.scope("moe" if ffn == "moe" else "mlp"):
-            h, aux, stats = self._ffn(lw, h, y2, attn_out, moe_on, ffn)
+        if remat_halves:
+            policy = _remat_policy(cfg.remat_policy)
+            mixer_half = jax.checkpoint(
+                mixer_half, policy=_keeping_splash_residuals(policy))
+            ffn_half = jax.checkpoint(ffn_half, policy=policy)
+        if ffn == "none":
+            # a mixer alone: one residual step, nothing routed
+            return mixer_half(lw, h), (jnp.zeros((), jnp.float32), None)
+        x = h if block_router or place == "parallel" else None
+        h, y2 = mixer_half(lw, h) if shared else (mixer_half(lw, h), None)
+        h, aux, stats = ffn_half(lw, h, x, y2)
         return h, (aux, stats)
 
-    def _gqa(self, lw, y, rope, mixer="attn"):
-        """Plain GQA softmax attention as a mixer of a stack of several kinds,
-        on the normed block input y [B, T, D] -> [B, T, D]: ``q = y Wq``
-        [H x Dh], ``k = y Wk``, ``v = y Wv`` [KV x Dh], RoPE by ``rope`` (the
-        kind's own table: ``rope_for``), causal attention, ``o Wo``. Mixer
+    def _gqa(self, lw, y, rope, mixer="attn", local=None):
+        """Softmax attention, every model's, on the block's (normed) input
+        y [B, T, D] -> [B, T, D]: ``q = y Wq`` [H x Dh], ``k = y Wk``,
+        ``v = y Wv`` [KV x Dh] (plus ``b_q`` / ``b_k`` / ``b_v`` with
+        ``attn_qkv_bias``), RoPE by ``rope`` (the kind's own table:
+        ``rope_for``), attention (causal unless ``cfg.causal`` is False: the
+        encoders), ``o Wo`` (plus ``b_o`` with ``attn_out_bias``). Mixer
         "attn": the model's ``n_heads``, every earlier key visible. Mixer
         "swa": ``swa_heads`` heads and the ``swa_window`` keys up to the
         query's own, with its own scopes nested in the attention layer's
         (``swa_qkv`` and ``swa_rope`` in ``attn_qkv``, ``swa_core`` in
         ``attn_core``, ``swa_out`` in ``attn_out``); an "attn" layer that
         rotates by a YaRN table does so under ``rope_yarn``. With ``qk_norm``
-        "head" an "attn" layer norms q and k per head over ``head_dim`` (a
-        plain gain [head_dim] each, the block norm's eps) BEFORE the rotation,
-        under ``attn_qk_norm`` (LFM2). With ``position`` "none" nothing is
-        rotated and nothing else marks a position (Nemotron-H: the state-space
-        layers beside it carry the order; ``rope`` is then (None, None)); a
-        mixer named in ``unrotated_mixers`` likewise, in a model whose other
-        kind rotates (SmallThinker's full layers), under its own scopes
-        ``nope_qkv`` / ``nope_core`` / ``nope_out``. With
-        ``qk_norm`` True an "attn" layer norms q and k over the WHOLE
-        projection (gains [H x Dh] and [KV x Dh], a float32 statistic) before
-        the split into heads, under ``attn_qk_norm`` (Olmo Hybrid). None of
-        the softmax family's other flags reaches this form (biases, ALiBi,
-        post-LN, a parallel block: a one-kind model's, ``layer_apply``) but
-        ``attn_scale`` (Granite's attention_multiplier in place of 1 /
-        sqrt(head_dim)), which q carries after the rotation."""
+        "head" q and k are normed per head over ``head_dim`` (a plain gain
+        [head_dim] each, the block norm's eps) BEFORE the rotation, under
+        ``attn_qk_norm`` (LFM2). With ``qk_norm`` True they are normed over
+        the WHOLE projection (all heads together, gains [H x Dh] and
+        [KV x Dh], a float32 statistic) before the split into heads, under
+        ``attn_qk_norm`` (OLMoE, Olmo 2 / 3). With ``position`` "rope" the
+        layer rotates; with "alibi" the scores carry the heads' slopes
+        (``alibi_slope_scale``); with "learned" or "none" nothing here marks a
+        position (Nemotron-H: the state-space layers beside it carry the
+        order; ``rope`` is then (None, None)); a mixer named in
+        ``unrotated_mixers`` likewise, in a model whose other kind rotates
+        (SmallThinker's full layers), under its own scopes ``nope_qkv`` /
+        ``nope_core`` / ``nope_out``. ``attn_scale`` (Granite's
+        attention_multiplier, GPT-Neo's 1.0, in place of 1 / sqrt(head_dim))
+        q carries after the rotation. ``local`` (traced bool scalar, GPT-Neo):
+        the layer sees the trailing ``local_attention_window`` keys only, over
+        a dense mask (``_windowed_attention``)."""
         import jax.numpy as jnp
         from jax.ad_checkpoint import checkpoint_name
 
         cfg = self.config
-        head_norm = cfg.qk_norm == "head" and mixer == "attn"
-        whole_norm = cfg.qk_norm is True and mixer == "attn"
-        flags = [f for f in ("attn_qkv_bias", "attn_out_bias", "post_ln",
-                             "parallel_block", "local_attention_window")
-                 if getattr(cfg, f)] + ["qk_norm"] * (cfg.qk_norm is True
-                                                    and not whole_norm)
-        if flags or cfg.position not in ("rope", "none") or not cfg.causal:
-            raise NotImplementedError(
-                f"a stack of several kinds runs mixer {mixer!r} as plain causal "
-                f"GQA, rotated (position 'rope') or not at all ('none'); this "
-                f"configuration sets {flags or cfg.position!r}")
         windowed = mixer == "swa"
         nope = mixer in cfg.unrotated_mixers
         rotated = cfg.position == "rope" and not nope
@@ -1441,22 +1334,22 @@ class Transformer:
         swa = lambda part: own("swa_" + part if windowed else
                                "nope_" + part if nope else None)
         with trace.scope("attn_qkv"):
-            if whole_norm:
-                # Olmo 2 / 3: RMSNorm over the WHOLE projection (all heads
-                # together, a gain a column, a float32 statistic), before the
-                # split into heads
-                q, k = y @ lw["wq"], y @ lw["wk"]
-                with trace.scope("attn_qk_norm"):
-                    q = _norm(q, lw["q_norm_w"], 0, "rmsnorm", eps=cfg.norm_eps)
-                    k = _norm(k, lw["k_norm_w"], 0, "rmsnorm", eps=cfg.norm_eps)
-                q, k = q.reshape(B, T, H, Dh), k.reshape(B, T, KV, Dh)
-                v = (y @ lw["wv"]).reshape(B, T, KV, Dh)
-            else:
-                with swa("qkv"):
+            with swa("qkv"):
+                if cfg.qk_norm is True:
+                    q, k = y @ lw["wq"], y @ lw["wk"]
+                    with trace.scope("attn_qk_norm"):
+                        q = _norm(q, lw["q_norm_w"], 0, "rmsnorm", eps=cfg.norm_eps)
+                        k = _norm(k, lw["k_norm_w"], 0, "rmsnorm", eps=cfg.norm_eps)
+                    q, k = q.reshape(B, T, H, Dh), k.reshape(B, T, KV, Dh)
+                else:
                     q = (y @ lw["wq"]).reshape(B, T, H, Dh)
                     k = (y @ lw["wk"]).reshape(B, T, KV, Dh)
-                    v = (y @ lw["wv"]).reshape(B, T, KV, Dh)
-            if head_norm:
+                v = (y @ lw["wv"]).reshape(B, T, KV, Dh)
+                if cfg.attn_qkv_bias:
+                    q = q + lw["b_q"].astype(y.dtype).reshape(H, Dh)
+                    k = k + lw["b_k"].astype(y.dtype).reshape(KV, Dh)
+                    v = v + lw["b_v"].astype(y.dtype).reshape(KV, Dh)
+            if cfg.qk_norm == "head":
                 with trace.scope("attn_qk_norm"):
                     q = _head_norm(q, lw["q_norm_w"], "rmsnorm", cfg.norm_eps)
                     k = _head_norm(k, lw["k_norm_w"], "rmsnorm", cfg.norm_eps)
@@ -1465,18 +1358,28 @@ class Transformer:
                     q = apply_rope(q, cos, sin, interleaved=cfg.rope_interleaved)
                     k = apply_rope(k, cos, sin, interleaved=cfg.rope_interleaved)
             if cfg.attn_scale:
-                # the kernels always divide by sqrt(Dh): q carries the rest,
-                # as a one-kind model's (``layer_apply``)
+                # the kernels always divide by sqrt(Dh): q carries the rest
                 q = q * jnp.asarray(cfg.attn_scale * math.sqrt(Dh), q.dtype)
+        # Name the KV residuals so remat_policy="offload_kv_host" can park
+        # them in host RAM between fwd and bwd (FPDT SequenceChunk offload,
+        # reference sequence/fpdt_layer.py:462; XLA schedules the transfers
+        # and double-buffers the prefetch). q joins for the selective-save
+        # policies (save_attn_seams / save_ffn). No-op under other policies.
         q = checkpoint_name(q, "q")
         k = checkpoint_name(k, "kv")
         v = checkpoint_name(v, "kv")
+        alibi = (alibi_slopes(H) * cfg.alibi_slope_scale
+                 if cfg.position == "alibi" else None)
         with trace.scope("attn_core"), swa("core"):
-            attn = self._attention(q, k, v, None,
-                                   window=cfg.swa_window if windowed else 0)
+            if cfg.local_attention_window and local is not None:
+                attn = _windowed_attention(q, k, v, cfg.local_attention_window, local)
+            else:
+                attn = self._attention(q, k, v, alibi,
+                                       window=cfg.swa_window if windowed else 0)
         attn = checkpoint_name(attn, "attn")
         with trace.scope("attn_out"), swa("out"):
-            return attn.reshape(B, T, H * Dh) @ lw["wo"]
+            out = attn.reshape(B, T, H * Dh) @ lw["wo"]
+            return out + lw["b_o"].astype(y.dtype) if cfg.attn_out_bias else out
 
     def _gated_attention(self, lw, y, rope):
         """Qwen3-Next's full-attention mixer on the normed block input
@@ -1733,14 +1636,12 @@ class Transformer:
                     z.astype(f32))).astype(y.dtype)
             return o.reshape(B, T, Hv * dv) @ lw["w_out"]
 
-    def _ffn(self, lw, h, y2, attn_out, moe_on, ffn=None, residual=True,
-             router_x=None):
-        """The block's second half: (MoE or dense) feed-forward on ``y2`` and
-        the residual add (``residual=False``: the feed-forward's output alone,
-        for a block that norms it first). ``router_x``: what a routed layer's
+    def _ffn(self, lw, y2, moe_on, ffn=None, router_x=None):
+        """The (MoE or dense) feed-forward on ``y2``; the block's skeleton
+        (``layer_apply``) norms and adds. ``router_x``: what a routed layer's
         router reads where that is not ``y2`` (``moe_router_input`` "block":
-        the block's input; ``moe.layer.moe_layer``). Returns (h, moe_aux, stats): ``stats`` is None for
-        a dense model, else this layer's ``expert_tokens`` [E] int32 (the
+        the block's input; ``moe.layer.moe_layer``). Returns (ff, moe_aux,
+        stats): ``stats`` is None for a dense model, else this layer's ``expert_tokens`` [E] int32 (the
         token-choices the router gave each of ALL its experts), ``router_prob``
         [E] (mean router probability), ``held_rows`` (the token-choices the
         experts held here computed: all of them unless this is a rank's
@@ -1756,7 +1657,7 @@ class Transformer:
         from jax.ad_checkpoint import checkpoint_name
 
         cfg = self.config
-        dtype = h.dtype
+        dtype = y2.dtype
         aux = jnp.zeros((), jnp.float32)
         stats = None
         gate_act = gate_fn(cfg.activation)      # None: an ungated unit
@@ -1870,16 +1771,7 @@ class Transformer:
         else:
             act = activation_fn(cfg.activation)
             ff = act(y2 @ lw["w_up"]) @ lw["w_down"]
-        if not residual:
-            return ff, aux, stats
-        if cfg.post_ln:
-            h = _norm(h + ff, lw["ln2_w"], lw.get("ln2_b", 0), cfg.norm,
-                      eps=cfg.norm_eps)
-        elif cfg.parallel_block:
-            h = h + attn_out + ff
-        else:
-            h = h + ff
-        return h, aux, stats
+        return ff, aux, stats
 
     @staticmethod
     def _sp_mesh():
@@ -2170,9 +2062,12 @@ class Transformer:
                           for mixer in cfg.unrotated_mixers})
 
             def run(kind):
-                # a layer of the softmax-attention family is checkpointed
-                # whole; the pattern's other mixers (and "attn" among several
-                # kinds) checkpoint their two halves themselves (layer_apply)
+                # a one-kind stack of softmax attention is checkpointed
+                # whole (gpt2m-train's peak memory and the norms and GELU that
+                # save their inputs, PR 58, were sized under it); every other
+                # layer checkpoints its two halves itself (layer_apply). The
+                # forward path's only reader of ``several_kinds`` (ROADMAP
+                # D14 (2))
                 whole = kind[0] == "attn" and not cfg.several_kinds
 
                 def layer_fn(h, lw, loc):

@@ -283,14 +283,33 @@ def test_the_inference_engines_refuse_the_multipliers_by_name(case, engine):
             cls(plain, {})
 
 
-def test_a_one_kind_model_refuses_the_residual_multiplier_by_name(case):
-    cfg = dataclasses.replace(case["cfg"], layer_pattern=(), n_layers=1, residual_scale=0.5,
-                              embed_scale=1.0, logit_divisor=1.0, attn_scale=0.0,
-                              position="rope")
+def test_a_one_kind_model_takes_the_residual_multiplier():
+    """The stack cut to its attention layers is ONE kind of layer (a flat
+    ``params["layers"]``): its blocks scale their sublayers as the hybrid's do.
+    Loss and every gradient against the reference on the same two layers, and
+    not what the neutral multiplier reads."""
+    hf = dict(HF, num_hidden_layers=2, layer_types=["attention"] * 2)
+    cfg = config_from_hf(hf)
+    assert not cfg.several_kinds and cfg.residual_scale == 0.22
     model = Transformer(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    with pytest.raises(NotImplementedError, match="residual_scale=0.5"):
-        model.loss(params, {"input_ids": case["ids"]})
+    params = model.init(jax.random.PRNGKey(5))
+    assert params["layers"]["wq"].shape[0] == 2             # flat, [L, ...]
+    # the driver's mapping reads a kind's leaves [periods, layers a period, ...]
+    by_kind = lambda tree: dict(tree, layers={"attn_mlp": jax.tree.map(
+        lambda a: a[:, None], tree["layers"])})
+    weights = driver.to_source_names(by_kind(params), hf)
+    ids = np.random.default_rng(3).integers(0, 256, (BATCH, SEQ + 1)).astype(np.int32)
+    loss, grad = jax.jit(jax.value_and_grad(model.loss))(params, {"input_ids": ids})
+    want = float(jax.jit(lambda w, i: ref.loss(w, hf, i))(weights, ids))
+    assert abs(float(loss) - want) < 1e-5
+    want_grad = driver.from_source_names(
+        jax.jit(lambda w, i: ref.grads(w, hf, i))(weights, ids), hf)
+    got = driver.flat_tree(by_kind(grad))
+    assert {"layers/attn_mlp/wq", "layers/attn_mlp/w_down", "embed"} <= set(want_grad)
+    worst = gaps(got, want_grad)
+    assert max(worst.values()) < 2e-3, worst
+    neutral = Transformer(dataclasses.replace(cfg, residual_scale=1.0))
+    assert abs(float(jax.jit(neutral.loss)(params, {"input_ids": ids})) - want) > 1e-4
 
 
 def test_checkpoint_import_is_not_written():

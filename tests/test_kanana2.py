@@ -282,7 +282,7 @@ def test_the_shares_add_up_to_the_uncut_layer(case):
                                           moe_held_rows_factor=float(ranks))
                 lw = {k: (v[r * held:(r + 1) * held] if k.startswith("moe_w_") else v)
                       for k, v in row.items()}
-                h, _, stats = Transformer(cfg)._ffn(lw, jnp.zeros_like(y), y, None, None, "moe")
+                h, _, stats = Transformer(cfg)._ffn(lw, y, None, "moe")
                 assert int(stats["overflow_rows"]) == 0
                 total = total + h.reshape(-1, 64) - shared        # each part holds the shared once
             total = total + shared

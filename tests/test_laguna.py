@@ -332,7 +332,7 @@ def test_the_shares_add_up_to_the_uncut_layer(ranks):
                                       moe_held_rows_factor=float(ranks))
             lw = {k: (v[r * held:(r + 1) * held] if k.startswith("moe_w_") else v)
                   for k, v in row.items()}
-            h, _, stats = Transformer(cfg)._ffn(lw, jnp.zeros_like(y), y, None, None, "moe")
+            h, _, stats = Transformer(cfg)._ffn(lw, y, None, "moe")
             assert int(stats["overflow_rows"]) == 0
             total = total + h.reshape(-1, 64) - shared        # each part holds the shared once
         total = total + shared
@@ -510,9 +510,19 @@ def test_sequence_parallel_and_pipeline_paths_refuse_the_stack(case):
     with pytest.raises(NotImplementedError, match="plain stack"):
         model.stack_apply(params["layers"], x, rope, layer_keep=jnp.ones((1,), bool),
                           lead=params["lead"])
+    # the older families' flags reach a window layer too: a bias on v comes
+    # out of the layer as that bias through Wo (every row of softmax sums to 1)
     flagged = Transformer(dataclasses.replace(case["cfg"], attn_qkv_bias=True))
-    with pytest.raises(NotImplementedError, match="attn_qkv_bias"):
-        flagged._gqa({}, x, rope)
+    lw = jax.tree.map(lambda a: a[0, 0], params["layers"]["swa_moe"])
+    b_v = jax.random.normal(jax.random.PRNGKey(2), lw["wv"].shape[-1:])
+    biased = dict(lw, b_q=jnp.zeros(lw["wq"].shape[-1:]), b_k=jnp.zeros_like(b_v), b_v=b_v)
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 8, 64))
+    rope = model.rope_for("swa", 8)
+    H, KV = case["cfg"].heads_of("swa"), case["cfg"].kv_heads
+    through_wo = jnp.repeat(b_v.reshape(KV, -1), H // KV, axis=0).reshape(-1) @ lw["wo"]
+    np.testing.assert_allclose(
+        flagged._gqa(biased, x, rope, mixer="swa"),
+        model._gqa(lw, x, rope, mixer="swa") + through_wo, rtol=1e-4, atol=1e-5)
 
 
 def test_checkpoint_import_is_not_written():

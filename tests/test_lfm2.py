@@ -301,7 +301,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
                                       moe_held_rows_factor=4.0)
             lw = {k: (v[r * 8:(r + 1) * 8] if k.startswith("moe_w_") else v)
                   for k, v in row.items()}
-            h, _, stats = Transformer(cfg)._ffn(lw, jnp.zeros_like(y), y, None, None, "moe")
+            h, _, stats = Transformer(cfg)._ffn(lw, y, None, "moe")
             assert int(stats["overflow_rows"]) == 0
             total = total + h.reshape(-1, 64)
     err = float(jnp.linalg.norm(total - want) / jnp.linalg.norm(want))
@@ -402,21 +402,31 @@ def test_per_head_qk_norm_before_rope_against_the_closed_form(case):
     assert "attn_qkv/attn_qk_norm" in text
 
 
-def test_which_qk_norm_is_whose(case):
-    """``qk_norm`` "head" is mixer "attn"'s among several kinds; a one-kind
-    model's attention norms the whole projection (True) or nothing. Since
-    PR 50 mixer "attn" among several kinds takes the whole-projection norm
-    too (Olmo Hybrid: ``tests/test_olmohybrid.py``); the window kind has
-    none and still refuses it."""
-    one_kind = Transformer(dataclasses.replace(
-        case["cfg"], layer_pattern=(), lead_layers=0, lead_kind=(), n_experts=0,
-        n_experts_held=0, moe_select_bias=False, n_layers=1))
-    with pytest.raises((NotImplementedError, ValueError), match="head"):
-        params = one_kind.init(jax.random.PRNGKey(0))
-        one_kind.apply(params, np.zeros((1, 4), np.int32))
-    whole = Transformer(dataclasses.replace(case["cfg"], qk_norm=True, swa_window=4))
-    with pytest.raises(NotImplementedError, match="qk_norm"):
-        whole._gqa({}, jnp.zeros((1, 8, 64)), whole.rope_for("attn", 8), mixer="swa")
+def test_a_one_kind_model_takes_the_per_head_qk_norm(case):
+    """The stack cut to its attention layers is ONE kind of layer (a flat
+    ``params["layers"]``): it norms q and k per head as the hybrid's attention
+    layers do. Loss and every gradient against the reference on the same two
+    layers, and not what the unnormed model reads. (The window kind takes the
+    whole-projection norm: ``tests/test_olmohybrid.py``.)"""
+    hf = dict(HF, num_hidden_layers=2, layers_held=[2, 6], num_dense_layers=0)
+    cfg = config_from_hf(hf)
+    assert not cfg.several_kinds and cfg.qk_norm == "head" and cfg.lead_layers == 0
+    model = Transformer(cfg)
+    params = driver.initial_params(model, 5, BIAS_STD)
+    assert params["layers"]["q_norm_w"].shape == (2, 16)    # flat, [L, head_dim]
+    weights = driver.to_source_names(params, hf)
+    batch = {"input_ids": case["ids"]}
+    want = float(jax.jit(lambda w, i: ref.loss_parts(w, hf, i))(weights, case["ids"])["loss"])
+    loss, grad = jax.jit(jax.value_and_grad(model.loss))(params, batch)
+    assert abs(float(loss) - want) < 1e-5
+    want_grad = driver.from_source_names(
+        jax.jit(lambda w, i: ref.grads(w, hf, i))(weights, case["ids"]), hf)
+    want_grad = {k: v for k, v in want_grad.items() if not k.endswith("/moe_select_bias")}
+    assert {"layers/q_norm_w", "layers/k_norm_w", "layers/wq"} <= set(want_grad)
+    worst = gaps(driver.flat_tree(grad), want_grad)
+    assert max(worst.values()) < 2e-3, worst
+    bare = Transformer(dataclasses.replace(cfg, qk_norm=False))
+    assert abs(float(jax.jit(bare.loss)(params, batch)) - want) > 1e-4
 
 
 # -- refusals ---------------------------------------------------------------------------

@@ -320,7 +320,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
                                       moe_held_rows_factor=16.0)
             lw = {k: (v[r * 8:(r + 1) * 8] if k.startswith("moe_w_") else v)
                   for k, v in row.items()}
-            h, _, stats = Transformer(cfg)._ffn(lw, jnp.zeros_like(y), y, None, None, "moe")
+            h, _, stats = Transformer(cfg)._ffn(lw, y, None, "moe")
             assert int(stats["overflow_rows"]) == 0
             # a rank's result = its experts' part + the shared expert
             total = total + h.reshape(-1, 64) - shared
@@ -433,9 +433,10 @@ def test_attention_among_the_kinds_rotates_nothing(case):
     moved = case["model"]._gqa(lw, y[:, perm], (None, None), mixer="attn")
     np.testing.assert_allclose(moved[:, -1], got[:, -1], rtol=1e-5, atol=1e-6)
     assert case["model"].embed(case["params"], case["ids"][:, :-1])[1] == (None, None)
-    rotating = Transformer(dataclasses.replace(case["cfg"], position="alibi"))
-    with pytest.raises(NotImplementedError, match="alibi"):
-        rotating._gqa(lw, y, (None, None))
+    # ALiBi's slopes mark the positions: the invariance goes
+    sloped = Transformer(dataclasses.replace(case["cfg"], position="alibi"))
+    a, b = sloped._gqa(lw, y, (None, None)), sloped._gqa(lw, y[:, perm], (None, None))
+    assert float(jnp.max(jnp.abs(a[:, -1] - b[:, -1]))) > 1e-4
 
 
 def test_the_trainer_runs_the_scans_kernels_where_the_heads_fill_lane_tiles(monkeypatch):

@@ -268,22 +268,45 @@ def test_the_block_norms_each_sublayers_output(case, kind):
     assert float(jnp.max(jnp.abs(pre - want))) > 0.1
 
 
-def test_a_one_kind_model_refuses_the_output_order():
-    from shuffle_exchange_tpu.models.transformer import tiny
-
-    model = Transformer(tiny(norm_order="output", norm="rmsnorm", position="rope"))
-    params = model.init(jax.random.PRNGKey(0))
-    with pytest.raises(NotImplementedError, match="norm_order"):
-        model.apply(params, np.zeros((1, 8), np.int32))
-    bad = Transformer(dataclasses.replace(config_from_hf(HF), norm_order="sandwich"))
+def test_a_one_kind_model_takes_the_output_order():
+    """The stack cut to its attention layers (the Olmo 2 / 3 block without
+    DeltaNet layers) is ONE kind of layer: it norms its sublayers' output and
+    the whole q and k projections as the hybrid's attention layers do. Loss
+    and every gradient against the reference on the same two layers, and not
+    what the input order reads; a bad value is refused where the model is
+    made."""
+    # (the family's importer reads a stack of both kinds only)
+    hf = dict(HF, num_hidden_layers=2, layer_types=["full_attention"] * 2)
+    cfg = dataclasses.replace(config_from_hf(HF), layer_pattern=(), n_layers=2)
+    assert not cfg.several_kinds and (cfg.norm_order, cfg.qk_norm) == ("output", True)
+    model = Transformer(cfg)
+    params = model.init(jax.random.PRNGKey(5))
+    assert params["layers"]["wq"].shape[0] == 2             # flat, [L, ...]
+    # the driver's mapping reads a kind's leaves [periods, layers a period, ...]
+    by_kind = lambda tree: dict(tree, layers={"attn_mlp": jax.tree.map(
+        lambda a: a[:, None], tree["layers"])})
+    weights = driver.to_source_names(by_kind(params), hf)
+    ids = np.random.default_rng(3).integers(0, 256, (BATCH, SEQ + 1)).astype(np.int32)
+    loss, grad = jax.jit(jax.value_and_grad(model.loss))(params, {"input_ids": ids})
+    want = float(jax.jit(lambda w, i: ref.loss_parts(w, hf, i))(weights, ids)["loss"])
+    assert abs(float(loss) - want) < 1e-5
+    want_grad = driver.from_source_names(
+        jax.jit(lambda w, i: ref.grads(w, hf, i))(weights, ids), hf)
+    assert {"layers/attn_mlp/q_norm_w", "layers/attn_mlp/ln2_w", "embed"} <= set(want_grad)
+    worst = gaps(driver.flat_tree(by_kind(grad)), want_grad)
+    assert max(worst.values()) < 3e-3, worst
+    pre = Transformer(dataclasses.replace(cfg, norm_order="input"))
+    assert abs(float(jax.jit(pre.loss)(params, {"input_ids": ids})) - want) > 1e-4
     with pytest.raises(ValueError, match="norm_order"):
-        bad.apply(bad.init(jax.random.PRNGKey(0)), np.zeros((1, 8), np.int32))
+        Transformer(dataclasses.replace(config_from_hf(HF), norm_order="sandwich"))
+    with pytest.raises(ValueError, match="one of them.*post_ln and norm_order='output'"):
+        Transformer(dataclasses.replace(cfg, post_ln=True))
 
 
 def test_the_whole_projection_qk_norm_among_several_kinds(case):
     """``_gqa`` with ``qk_norm`` True: the statistic runs over ALL heads'
     channels (scaling one head's q moves every head's), the gains are a
-    column's own, and the window kind still refuses it."""
+    column's own, and the window kind takes it too."""
     model, cfg = case["model"], case["cfg"]
     lw = jax.tree.map(lambda x: x[0, 0], case["params"]["layers"]["attn_mlp"])
     assert lw["q_norm_w"].shape == (cfg.n_heads * cfg.head_dim,)
@@ -295,9 +318,14 @@ def test_the_whole_projection_qk_norm_among_several_kinds(case):
          "a.q_norm.weight": lw["q_norm_w"], "a.k_norm.weight": lw["k_norm_w"]},
         "a.", y, HF)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-4, atol=2e-5)
-    with pytest.raises(NotImplementedError, match="qk_norm"):
-        Transformer(dataclasses.replace(cfg, swa_window=8, position="rope"))._gqa(
-            lw, y, (None, None), mixer="swa")
+    # the window kind norms the same way: over a window that covers the
+    # sequence it is the full layer
+    windowed = Transformer(dataclasses.replace(cfg, swa_window=16, position="rope"))
+    rope = windowed.rope_for("swa", 16)
+    over_window = windowed._gqa(lw, y, rope, mixer="swa")
+    np.testing.assert_allclose(over_window, windowed._gqa(lw, y, rope), rtol=1e-5, atol=1e-6)
+    bare = Transformer(dataclasses.replace(windowed.config, qk_norm=False))
+    assert float(jnp.max(jnp.abs(bare._gqa(lw, y, rope, mixer="swa") - over_window))) > 1e-3
 
 
 # -- the rule at 96 / 192 with beta in (0, 2) --------------------------------

@@ -291,10 +291,9 @@ def test_the_shares_add_up_to_the_whole_layer():
     np.testing.assert_allclose(parts[0] + parts[1] + shared, full, atol=2e-6)
     # and the system's two shares, through Transformer._ffn
     lw = jax.tree.map(lambda a: a[0, 0], params["layers"]["gdn_moe"])
-    h0 = jnp.zeros_like(y)[None]
 
     def ffn(cfg, lw):
-        return Transformer(cfg)._ffn(lw, h0, y[None], None, None, "moe")[0][0]
+        return Transformer(cfg)._ffn(lw, y[None], None, "moe")[0][0]
 
     want = ffn(cfg_whole, lw)
     np.testing.assert_allclose(want, full, atol=2e-5)

@@ -355,17 +355,33 @@ def test_the_program_with_the_router_on_y2_is_another_model(case):
     assert abs(got - want) < 1e-5
 
 
-def test_a_one_kind_model_and_an_output_normed_block_refuse_the_block_router(case):
-    one = Transformer(dataclasses.replace(
-        case["cfg"], layer_pattern=(), unrotated_mixers=(), swa_window=0, swa_heads=0))
-    with pytest.raises(NotImplementedError, match="moe_router_input"):
-        one.loss(one.init(jax.random.PRNGKey(0)), {"input_ids": case["ids"]})
-    out = Transformer(dataclasses.replace(case["cfg"], norm_order="output"))
-    with pytest.raises(NotImplementedError, match="moe_router_input='block'"):
-        out.loss(case["params"], {"input_ids": case["ids"]})
-    bad = Transformer(dataclasses.replace(case["cfg"], moe_router_input="mixer"))
+def test_a_one_kind_model_takes_the_block_router(case):
+    """The stack cut to its full layers is ONE kind of layer (a flat
+    ``params["layers"]``): its router reads the block's input as the
+    two-kind stack's does. Loss, the experts' counts and every gradient
+    against the reference on the same two layers, and not what a router on
+    y2 reads; a bad value is refused where the model is made."""
+    hf = dict(HF, num_hidden_layers=2, rope_layout=[0] * 2, sliding_window_layout=[0] * 2)
+    cfg = config_from_hf(hf)
+    assert not cfg.several_kinds and cfg.moe_router_input == "block"
+    model = Transformer(cfg)
+    params = driver.initial_params(model, 5)
+    assert params["layers"]["wq"].shape[0] == 2             # flat, [L, ...]
+    weights = driver.to_source_names(params, hf)
+    batch = {"input_ids": case["ids"]}
+    want = jax.jit(lambda w, i: ref.loss_parts(w, hf, i))(weights, case["ids"])
+    loss, stats = jax.jit(model.loss_and_stats)(params, batch)
+    assert abs(float(loss) - float(want["loss"])) < 1e-5
+    np.testing.assert_array_equal(stats["moe_expert_tokens"], want["expert_tokens"])
+    want_grad = driver.from_source_names(
+        jax.jit(lambda w, i: ref.grads(w, hf, i))(weights, case["ids"]), hf)
+    assert {"layers/moe_gate", "layers/wq", "layers/moe_w_down"} <= set(want_grad)
+    worst = gaps(driver.flat_tree(jax.jit(jax.grad(model.loss))(params, batch)), want_grad)
+    assert max(worst.values()) < 2e-3, worst
+    on_y2 = Transformer(dataclasses.replace(cfg, moe_router_input="ffn"))
+    assert abs(float(jax.jit(on_y2.loss)(params, batch)) - float(want["loss"])) > 2e-5
     with pytest.raises(ValueError, match="'ffn' or 'block'"):
-        bad.loss(case["params"], {"input_ids": case["ids"]})
+        Transformer(dataclasses.replace(case["cfg"], moe_router_input="mixer"))
 
 
 # -- the gated ReLU unit ---------------------------------------------------------------
