@@ -439,11 +439,37 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu",
     serves ``out_sorted``'s gradient and the weights') and V + S for the
     dispatch: 5 V + 2 S, and a write of R rows of zeros a pass for what the
     passes skip, where PR 38's form looked up 5 R + 2 S and its parent's
-    per-choice form 3 R + 3 k S (:func:`_held_runs`, :func:`_run_sums`). The
+    per-choice form 3 R + 3 k S (:func:`_held_runs`, :func:`_run_sums`). Of
+    rows of the experts' width F, between the grouped GEMMs
+    (``ops/expert_act.py``; on its kernels' route, which is ``grouped_matmul``'s
+    megablox route at 2-byte rows): the activation reads ``gate`` and ``up``
+    and writes ``h``, 3 V forward and 3 V replayed, and its backward reads
+    those two and ``dh`` and writes ``dgate`` and ``dup``, 5 V: 11 V (7 V
+    ungated), V there the blocks of ``expert_act.ROWS`` that start below
+    ``fit``, where the plain text autodiff differentiated moved ~13 R and kept
+    the activation's intermediates beside ``gate``, ``up`` and ``h``. The
     buffer's factor buys room in memory, not time in the passes.
+
+    THE BUFFER'S TAIL IS NOBODY'S. Past ``fit`` a position holds nothing, and
+    what stands there is not zeros: megablox ``gmm`` writes the rows of its
+    groups and leaves the rest as it found them, the activation's kernels
+    write the blocks they visit. Every reader takes its groups, or its runs,
+    only: ``up`` / ``gate`` [R, F] (``gmm``'s) are read by the activation;
+    ``h`` by the down projection's ``gmm`` and, backward, by the ``tgmm`` of
+    ``w_down``'s gradient (groups only, both); ``dh`` (``gmm``'s) by the
+    activation's backward; ``dgate`` / ``dup`` by the projections' backward
+    ``gmm`` (the rows' gradient) and ``tgmm`` (the weights'), groups only;
+    ``out_sorted`` [R, M] by :func:`_held_combine` through ``runs`` (a
+    position that holds nothing is read as zeros, never multiplied by zero) and
+    by its backward beside a weight no token-choice looks up; the rows'
+    gradient d ``xsort`` by :func:`_held_dispatch`'s backward through ``runs``.
+    The bias epilogues alone pass over every row (and their gradients sum
+    every row): a share whose experts have biases would sum the tail into
+    them on a TPU, before the kernels as after; no model here has both.
     """
     import jax.numpy as jnp
 
+    from ..ops.expert_act import expert_act, expert_act_route
     from ..ops.grouped_gemm import grouped_matmul
     from ..ops.quant_matmul import QuantizedMatrix
     from ..profiling import trace
@@ -497,15 +523,15 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu",
         return wt if isinstance(wt, QuantizedMatrix) else wt.astype(dtype)
 
     with trace.scope("moe_experts"):
-        from ..models.transformer import activation_fn, gate_fn
+        from ..models.transformer import gate_fn
 
-        gate_act = gate_fn(activation)
-        up = b("b_up", grouped_matmul(xsort, w("w_up"), group_sizes))
-        if gate_act:
-            gate = b("b_gate", grouped_matmul(xsort, w("w_gate"), group_sizes))
-            h = gate_act(gate) * up
-        else:
-            h = activation_fn(activation)(up)
+        w_up = w("w_up")
+        up = b("b_up", grouped_matmul(xsort, w_up, group_sizes))
+        gate = (b("b_gate", grouped_matmul(xsort, w("w_gate"), group_sizes))
+                if gate_fn(activation) else None)
+        # the rows anybody reads: the held ones, every row where all are held
+        h = expert_act(gate, up, fit if share else S * k, activation,
+                       expert_act_route(xsort, w_up, activation))
         out_sorted = b("b_down", grouped_matmul(h, w("w_down"), group_sizes))
     with trace.scope("moe_combine"):
         if share:

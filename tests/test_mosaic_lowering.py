@@ -495,6 +495,30 @@ def test_ssm_gate_norm_lowers(dtype):
                    jnp.zeros((H,), jnp.float32), jnp.zeros((inner,), jnp.float32), rows)
 
 
+def _expert_act_vjp(fit, dh, *arrays, activation="swiglu"):
+    """The pass and its cotangents through the kernels themselves (the
+    dispatching entry takes the text off a TPU): ``arrays`` is (gate, up) or
+    (up,)."""
+    from shuffle_exchange_tpu.ops.expert_act import _expert_act_pallas
+
+    out, back = jax.vjp(lambda *a: _expert_act_pallas(a, fit, activation), *arrays)
+    return (out,) + back(dh)
+
+
+@pytest.mark.parametrize("activation, inputs", [("swiglu", 2), ("reglu", 2), ("relu2", 1),
+                                                ("silu", 1), ("relu", 1)])
+def test_expert_act_lowers(activation, inputs):
+    """The forward and the backward launch of every activation the kernels
+    take; whole lane tiles and a width of two and a half; whole row blocks
+    and a ragged last one."""
+    import functools
+
+    for rows, width in ((1024, 256), (1300, 320)):
+        block = jnp.zeros((rows, width), jnp.bfloat16)
+        _tpu_lower(functools.partial(_expert_act_vjp, activation=activation),
+                   jnp.zeros((), jnp.int32), block, *[block] * inputs)
+
+
 LLAMA = dict(D=4096, H=32, KV=8, Dh=128, F=14336, V=128256, rope=True,
              bias=False, gated=True)
 GEOMS = {"gpt2-125m": GPT2, "llama3-8b": LLAMA}
@@ -901,6 +925,31 @@ def test_ssm_gate_norm_compiles(chip_compile):
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "ssm_gate_norm_fwd" in text and "ssm_gate_norm_bwd" in text
+
+
+# cell -> (rows of the experts' buffer R, the experts' width F, activation)
+_CELL_EXPERTS = {"lfm2-train": (98304, 1792, "swiglu"), "smallthinker-train": (73728, 768, "reglu"),
+                 "qwen3next-train": (30720, 512, "swiglu"), "kanana2-train": (36864, 768, "swiglu"),
+                 "laguna-train": (49152, 512, "swiglu"), "nemotron3-train": (18432, 1856, "relu2"),
+                 "olmoe-train": (131072, 1024, "swiglu")}
+
+
+@pytest.mark.parametrize("cell", list(_CELL_EXPERTS))
+def test_expert_act_compiles_at_the_cells_shapes(chip_compile, cell):
+    """The activation pass's two kernels at the buffer each MoE cell runs
+    them on (the six held shares' R = 3 x the balanced share and
+    ``olmoe-train``'s every token-choice; bf16; ``nemotron3-train``'s 1856 is
+    14.5 lane tiles), ``fit`` a traced scalar: one launch forward, one
+    backward."""
+    import functools
+
+    R, F, activation = _CELL_EXPERTS[cell]
+    block = ((R, F), _BF16)
+    compiled = chip_compile(functools.partial(_expert_act_vjp, activation=activation),
+                            ((), _I32), block, *[block] * (1 if activation == "relu2" else 2))
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "sxt_expert_act_fwd" in text and "sxt_expert_act_bwd" in text
 
 
 def test_grouped_gemm_compiles_at_a_width_of_half_lane_tiles(chip_compile):
