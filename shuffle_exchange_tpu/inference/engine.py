@@ -170,6 +170,14 @@ class InferenceEngine:
                 "table and keeps one uniform KV pool with no window to evict by; "
                 "nothing fetches experts while attention runs (training through "
                 "sxt.initialize is; ROADMAP R-M3, R-M15)")
+        if "dsa" in mixers:
+            raise NotImplementedError(
+                "serving a learned sparse attention (mixer 'dsa': an indexer that "
+                "scores every cached key and a top-k selection a query, KeyeVL2's "
+                "sa_config) is not implemented: inference/paged.py keeps no "
+                "indexer key beside a KV block and paged attention has no "
+                "selection inside it (training through sxt.initialize is; "
+                "ROADMAP R-M16)")
         if "swa" in mixers:
             raise NotImplementedError(
                 "serving a stack of window and full attention kinds (mixer "

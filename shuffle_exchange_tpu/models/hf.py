@@ -78,6 +78,7 @@ _MODEL_TYPE_FAMILIES = {"llama": "llama", "mistral": "llama", "qwen2": "qwen2",
                         "olmo_hybrid": "olmohybrid",
                         "granitemoehybrid": "granitemoehybrid",
                         "smallthinker": "smallthinker",
+                        "KeyeVL2": "keyevl2",
                         "megatron": "megatron",
                         "megatron-gpt": "megatron", "megatron_gpt": "megatron"}
 
@@ -603,6 +604,71 @@ def _smallthinker_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> Transfo
         moe_aux="all_choices" if alpha else "none", aux_loss_coef=alpha, **common)
 
 
+_SA_CONFIG_KEYS = ("indexer_head_dim", "indexer_num_heads", "indexer_num_kv_heads",
+                   "q_chunk_size", "kv_chunk_size", "topk")
+
+
+def _keyevl2_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerConfig:
+    """The LANGUAGE MODEL of Kwai-Keye's Keye-VL-2.0 (``model_type: KeyeVL2``)
+    as Keye-VL-2.0-30B-A3B ships it: Qwen3-MoE's block (GQA with a per-head
+    q/k RMSNorm before the rotation, every layer routed: ``num_experts``
+    SiLU-gated experts of ``moe_intermediate_size``, ``num_experts_per_tok`` a
+    token of a softmax over all, renormalised, dropless, no shared expert)
+    whose attention is a learned sparse one (mixer "dsa", ``sa_config``: an
+    indexer of ``indexer_num_heads`` heads of ``indexer_head_dim`` over ONE key
+    head, ``topk`` keys a query; ``q_chunk_size`` / ``kv_chunk_size`` tile the
+    indexer's computation and are not a unit of selection) and whose rotation
+    is M-RoPE (``rope_scaling.mrope_section``). The vision tower is NOT built:
+    a ``vision_config`` is refused by name, and so is every ``sa_config`` key
+    not written here. ``num_experts_held`` / ``expert_first`` /
+    ``expert_buffer_factor`` as for qwen3_next; ``router_aux_loss_coef`` (HF's
+    all-choices balancing loss, none without it) is this repository's key."""
+    if cfg.get("vision_config") is not None:
+        raise ValueError(
+            "KeyeVL2 with vision_config: the vision tower is not built (no source "
+            "here states its shapes or equations); hand the language model's keys "
+            "alone, and position_ids [3, B, T] for what the tower leaves behind")
+    sa = dict(cfg.get("sa_config") or {})
+    unknown = sorted(set(sa) - set(_SA_CONFIG_KEYS))
+    if unknown or not sa:
+        raise ValueError(f"KeyeVL2: sa_config keys {unknown or 'missing'} are not "
+                         f"written down (known: {', '.join(_SA_CONFIG_KEYS)})")
+    scaling = dict(cfg.get("rope_scaling") or {})
+    refused = {
+        "sa_config.indexer_num_kv_heads": sa.get("indexer_num_kv_heads", 1) != 1,
+        "rope_scaling": (set(scaling) - {"mrope_section", "rope_type", "type"}
+                         or scaling.get("rope_type", "default") != "default"
+                         or scaling.get("type", "default") != "default"),
+        "mlp_only_layers": bool(cfg.get("mlp_only_layers")),
+        "decoder_sparse_step": cfg.get("decoder_sparse_step", 1) != 1,
+        "use_sliding_window": bool(cfg.get("use_sliding_window")),
+        "attention_bias": bool(cfg.get("attention_bias")),
+        "tie_word_embeddings": bool(cfg.get("tie_word_embeddings")),
+        "norm_topk_prob": cfg.get("norm_topk_prob", True) is not True,
+        "hidden_act": cfg.get("hidden_act", "silu") != "silu",
+        "shared_expert_intermediate_size": bool(cfg.get("shared_expert_intermediate_size")),
+    }
+    for key, bad in refused.items():
+        if bad:
+            raise ValueError(
+                f"KeyeVL2 with {key}={cfg.get(key.split('.')[0])!r} is not supported "
+                "(written down: every layer routed, SiLU-gated experts, renormalised "
+                "top-k of a softmax, no shared expert, no bias, an untied head, ONE "
+                "indexer key head, default RoPE with an mrope_section, no window)")
+    head = int(cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"])
+    alpha = float(cfg.get("router_aux_loss_coef") or 0.0)
+    common.update(d_ff=cfg["moe_intermediate_size"])
+    return TransformerConfig(
+        head_size=head, layer_pattern=(("dsa", "moe"),), qk_norm="head",
+        dsa_topk=int(sa["topk"]), dsa_index_heads=int(sa["indexer_num_heads"]),
+        dsa_index_dim=int(sa["indexer_head_dim"]),
+        mrope_section=tuple(int(n) for n in scaling.get("mrope_section") or ()),
+        n_experts=cfg["num_experts"], **_held_share(cfg, "KeyeVL2"),
+        moe_top_k=cfg["num_experts_per_tok"], moe_norm_topk=True,
+        moe_score="softmax", moe_impl="ragged",
+        moe_aux="all_choices" if alpha else "none", aux_loss_coef=alpha, **common)
+
+
 def config_from_hf(hf_config) -> TransformerConfig:
     """Map an HF config object/dict to a TransformerConfig."""
     cfg = hf_config if isinstance(hf_config, dict) else hf_config.to_dict()
@@ -935,6 +1001,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
         return _laguna_config(cfg, common)
     if family == "smallthinker":
         return _smallthinker_config(cfg, common)
+    if family == "keyevl2":
+        return _keyevl2_config(cfg, common)
     if family == "lfm2moe":
         return _lfm2_config(cfg, common)
     if family == "mixtral":

@@ -314,6 +314,26 @@ class TransformerConfig:
     # can be made, and the experts fetched, while attention runs). On the
     # dropless "ragged" impl.
     moe_router_input: str = "ffn"
+    # A learned sparse attention (mixer "dsa" of a layer_pattern: Keye-VL-2.0's
+    # ``sa_config``, DeepSeek-Sparse-Attention shaped; ``ops/dsa``): an indexer
+    # of ``dsa_index_heads`` heads of ``dsa_index_dim`` over ONE key head scores
+    # every earlier key from the DETACHED block input, each query keeps its
+    # ``dsa_topk`` best (one set for all its heads, no gradient) and the GQA
+    # softmax runs over those alone. ``loss`` adds the indexer's own loss at
+    # coefficient 1: the mean over layers and tokens of the KL from the main
+    # attention's head-averaged probabilities to the softmax of the indexer's
+    # scores over the chosen keys; it reaches the indexer's leaves only
+    # (``dsa_wq`` / ``dsa_wk`` / ``dsa_ww`` / ``dsa_k_norm_w`` / ``dsa_k_norm_b``)
+    # and the other losses reach every leaf but those.
+    dsa_topk: int = 0
+    dsa_index_heads: int = 0
+    dsa_index_dim: int = 0
+    # M-RoPE (``rope_scaling.mrope_section``, the chunked layout): the rotated
+    # pairs are split into len(mrope_section) runs and run i takes its angle
+    # from position stream i (temporal, height, width) of ``position_ids``
+    # [3, B, T]; () = one stream. On text the streams are equal and the table
+    # is ``rope_table``'s, bit for bit.
+    mrope_section: Tuple[int, ...] = ()
     # YaRN on the model's own RoPE table (the "attn" layers'; ``rope_table``):
     # (factor, original_max_position_embeddings, beta_fast, beta_slow,
     # attention_factor), () = unscaled. The inverse frequencies are blended
@@ -651,9 +671,38 @@ def rope_table(seq_len: int, head_dim: int, theta: float, yarn=()):
     return (cos * yarn[4], sin * yarn[4]) if yarn else (cos, sin)
 
 
+def mrope_table(positions, head_dim: int, theta: float, sections=()):
+    """(cos, sin) [B, T, head_dim / 2] from ``positions`` [streams, B, T]
+    (M-RoPE; ``TransformerConfig.mrope_section``): pair j turns by
+    ``theta ** (-2j / head_dim)`` times the position stream its section names
+    (``sections`` [16, 24, 24]: pairs 0-15 stream 0, 16-39 stream 1, 40-63
+    stream 2; () = stream 0 for every pair). Each angle is ``rope_table``'s
+    product of one float32 position and one frequency, so equal streams give
+    that table's values exactly."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    half = head_dim // 2
+    if sections and sum(sections) != half:
+        raise ValueError(f"mrope_section {tuple(sections)} does not add up to the "
+                         f"{half} rotated pairs of a head of {head_dim}")
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    pos = jnp.asarray(positions).astype(jnp.float32)
+    stream = np.repeat(np.arange(len(sections)), sections) if sections else np.zeros(half, int)
+    if pos.shape[0] <= int(stream.max()):
+        raise ValueError(f"position_ids of {pos.shape[0]} stream(s) for mrope_section "
+                         f"{tuple(sections)}")
+    angles = pos[0][..., None] * freqs
+    for i in range(1, int(stream.max()) + 1):
+        angles = jnp.where(jnp.asarray(stream == i), pos[i][..., None] * freqs, angles)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
 def apply_rope(x, cos, sin, interleaved: bool = False):
     """x: [B, T, H, D]. Rotates the first ``2 * cos.shape[-1]`` dims (partial
     rotary — GPT-NeoX rotary_pct / GPT-J rotary_dim); the rest pass through.
+    ``cos`` / ``sin`` [T, rd / 2], or [B, T, rd / 2] (a table a sequence:
+    ``mrope_table``).
 
     interleaved=False: llama/NeoX rotate-half pairing (dim i with i + rd/2).
     interleaved=True:  GPT-J rotate-every-two pairing (dim 2i with 2i+1).
@@ -662,8 +711,11 @@ def apply_rope(x, cos, sin, interleaved: bool = False):
 
     rd = 2 * cos.shape[-1]
     rot, rest = (x[..., :rd], x[..., rd:]) if rd < x.shape[-1] else (x, None)
-    c = cos[None, :, None, :].astype(x.dtype)
-    s = sin[None, :, None, :].astype(x.dtype)
+    if cos.ndim == 3:
+        c, s = cos[:, :, None, :].astype(x.dtype), sin[:, :, None, :].astype(x.dtype)
+    else:
+        c = cos[None, :, None, :].astype(x.dtype)
+        s = sin[None, :, None, :].astype(x.dtype)
     if interleaved:
         x1, x2 = rot[..., 0::2], rot[..., 1::2]
         out = jnp.stack([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
@@ -1012,12 +1064,25 @@ class Transformer:
             if gated:                          # per head, over its Dh
                 layer["q_norm_w"], layer["k_norm_w"] = gain(Dh), gain(Dh)
             elif cfg.qk_norm == "head":        # per head too, a plain gain
-                if mixer != "attn":
-                    raise ValueError(f"qk_norm='head' is mixer 'attn''s; {mixer!r} has no q/k norm")
+                if mixer not in ("attn", "dsa"):
+                    raise ValueError(f"qk_norm='head' is mixer 'attn''s and 'dsa''s; {mixer!r} "
+                                     "has no q/k norm")
                 layer["q_norm_w"], layer["k_norm_w"] = ones(Dh), ones(Dh)
             elif cfg.qk_norm:
                 layer["q_norm_w"] = ones(H * Dh)
                 layer["k_norm_w"] = ones(KV * Dh)
+            if mixer == "dsa":
+                # the indexer: its heads' queries, ONE key head with a
+                # LayerNorm of its own, a weight a head and token
+                Hi, Di = cfg.dsa_index_heads, cfg.dsa_index_dim
+                if min(Hi, Di, cfg.dsa_topk) <= 0:
+                    raise ValueError("mixer 'dsa' needs dsa_index_heads, dsa_index_dim "
+                                     "and dsa_topk > 0")
+                layer.update({
+                    "dsa_wq": stack(next(keys), (D, Hi * Di), D),
+                    "dsa_wk": stack(next(keys), (D, Di), D),
+                    "dsa_ww": stack(next(keys), (D, Hi), D),
+                    "dsa_k_norm_w": ones(Di), "dsa_k_norm_b": zeros(Di)})
         if ffn == "none":
             # a mixer alone: no second norm, no ffn leaves
             return layer
@@ -1131,12 +1196,15 @@ class Transformer:
 
     # -- forward pieces (shared by the plain and pipelined paths) ------
 
-    def embed(self, params, input_ids):
-        """ids [.., T] -> (x [.., T, D], rope (cos, sin) or (None, None))."""
+    def embed(self, params, input_ids, position_ids=None):
+        """ids [.., T] -> (x [.., T, D], rope (cos, sin) or (None, None)).
+        ``position_ids`` [streams, B, T] (M-RoPE: ``mrope_section``; None =
+        every stream 0..T-1, text). A model with a learned sparse attention
+        (mixer "dsa") gets (cos, sin, the indexer's cos, sin): ``rope_for``."""
         with trace.scope("embed"):
-            return self._embed(params, input_ids)
+            return self._embed(params, input_ids, position_ids)
 
-    def _embed(self, params, input_ids):
+    def _embed(self, params, input_ids, position_ids=None):
         import jax.numpy as jnp
 
         cfg = self.config
@@ -1157,14 +1225,36 @@ class Transformer:
                       eps=cfg.norm_eps)
         if cfg.position in ("learned", "alibi", "none"):
             return x, (None, None)
-        return x, self.rope_for("attn", T)
+        if position_ids is not None and not cfg.mrope_section:
+            raise NotImplementedError("position_ids are M-RoPE's (mrope_section); this "
+                                      "model rotates by the position in the sequence")
+        sparse = any(mixer == "dsa" for mixer, _ in cfg.kinds_used)
+        return x, self.rope_for("dsa" if sparse else "attn", T, position_ids)
 
-    def rope_for(self, mixer: str, seq_len: int):
+    def rope_for(self, mixer: str, seq_len: int, position_ids=None):
         """The (cos, sin) table the layers of mixer ``mixer`` rotate by: the
         model's own (``rope_theta``, ``rotary_dim``, ``rope_yarn``) or the
         window kind's (``swa_rope_theta``, ``swa_rotary_dim``, unscaled);
-        (None, None) for a kind that rotates nothing (``unrotated_mixers``)."""
+        (None, None) for a kind that rotates nothing (``unrotated_mixers``).
+        With ``mrope_section`` the model's own table is M-RoPE's, [B, T, .],
+        from ``position_ids`` [3, B, T] (None: every stream 0..T-1, under the
+        scope ``mrope``). Mixer "dsa": (cos, sin, the indexer's cos, sin), the
+        indexer's over its whole ``dsa_index_dim`` by the temporal stream."""
         cfg = self.config
+        if mixer == "dsa" or (mixer == "attn" and cfg.mrope_section):
+            import jax.numpy as jnp
+
+            with trace.scope("mrope"):
+                if position_ids is None:
+                    position_ids = jnp.broadcast_to(
+                        jnp.arange(seq_len, dtype=jnp.int32),
+                        (max(1, len(cfg.mrope_section)), 1, seq_len))
+                own = mrope_table(position_ids, cfg.rotary_dims, cfg.rope_theta,
+                                  cfg.mrope_section)
+                if mixer != "dsa":
+                    return own
+                return own + mrope_table(position_ids[:1], cfg.dsa_index_dim,
+                                         cfg.rope_theta)
         if mixer in cfg.unrotated_mixers:
             return None, None
         if mixer == "swa":
@@ -1214,7 +1304,8 @@ class Transformer:
         mix = {"gdn": self._gdn, "gated_attn": self._gated_attention,
                "mla": self._mla, "sconv": self._sconv, "ssm": self._ssm,
                "attn": functools.partial(self._gqa, local=local),
-               "swa": functools.partial(self._gqa, mixer="swa")}[mixer]
+               "swa": functools.partial(self._gqa, mixer="swa"),
+               "dsa": self._dsa}[mixer]
         # where the block norms (``Transformer.__init__`` admits one form):
         # each sublayer's input (pre-LN), its output (the Olmo 2 / 3 order),
         # the sum (BERT's post-LN), or the input of a parallel block (GPT-J /
@@ -1247,14 +1338,28 @@ class Transformer:
                 return (h.astype(jnp.float32) + cfg.residual_scale
                         * out.astype(jnp.float32)).astype(h.dtype)
 
+        # a "dsa" mixer hands out, beside its output, what the step reports of
+        # its selection and the indexer's own loss: they ride with the routed
+        # layer's stats (``sparse``)
+        sparse = mixer == "dsa"
+        if sparse and (shared or ffn != "moe"):
+            raise NotImplementedError(
+                "mixer 'dsa' (a learned sparse attention) is written for a "
+                "sequential block with routed experts: its counters and its "
+                "indexer's loss ride with the router's stats")
+
         def mixer_half(lw, h):
             y = normed(lw, h, 1, "attn_norm") if place in ("input", "parallel") else h
             out = mix(lw, y, rope)
+            if sparse:
+                out, found = out
             if place == "output":
                 out = normed(lw, out, 1, "attn_norm")
             h = add(h, out, "attn_out")
             if place == "sum":
                 h = normed(lw, h, 1, "attn_norm")
+            if sparse:
+                return h, found
             return (h, y) if shared else h
 
         def ffn_half(lw, h, x=None, y2=None):
@@ -1280,6 +1385,10 @@ class Transformer:
             # a mixer alone: one residual step, nothing routed
             return mixer_half(lw, h), (jnp.zeros((), jnp.float32), None)
         x = h if block_router or place == "parallel" else None
+        if sparse:
+            h, found = mixer_half(lw, h)
+            h, aux, stats = ffn_half(lw, h, x, None)
+            return h, (aux, {**stats, **found})
         h, y2 = mixer_half(lw, h) if shared else (mixer_half(lw, h), None)
         h, aux, stats = ffn_half(lw, h, x, y2)
         return h, (aux, stats)
@@ -1380,6 +1489,83 @@ class Transformer:
         with trace.scope("attn_out"), swa("out"):
             out = attn.reshape(B, T, H * Dh) @ lw["wo"]
             return out + lw["b_o"].astype(y.dtype) if cfg.attn_out_bias else out
+
+    @property
+    def dsa_index_scale(self) -> float:
+        """What the indexer's weighted sum is scaled by: heads^-1/2 x dim^-1/2."""
+        return (self.config.dsa_index_heads * self.config.dsa_index_dim) ** -0.5
+
+    def dsa_index(self, lw, y, rope):
+        """A learned sparse attention's indexer on the layer's normed input
+        y [B, T, D], DETACHED (it reads the stream and writes nothing back
+        into it): (qI [B, T, Hi, Di], kI [B, T, Di], w [B, T, Hi]), under the
+        scope ``dsa_index``. ``rope``: ``rope_for("dsa", T)``."""
+        import jax
+
+        cfg = self.config
+        B, T = y.shape[:2]
+        Hi, Di = cfg.dsa_index_heads, cfg.dsa_index_dim
+        cos_i, sin_i = rope[2:]
+        with trace.scope("dsa_index"):
+            yd = jax.lax.stop_gradient(y)
+            qi = apply_rope((yd @ lw["dsa_wq"]).reshape(B, T, Hi, Di), cos_i, sin_i)
+            ki = _norm(yd @ lw["dsa_wk"], lw["dsa_k_norm_w"], lw["dsa_k_norm_b"],
+                       "layernorm", eps=cfg.norm_eps)
+            ki = apply_rope(ki[:, :, None, :], cos_i, sin_i)[:, :, 0]
+            return qi, ki, yd @ lw["dsa_ww"]
+
+    def _dsa(self, lw, y, rope):
+        """A learned sparse attention on the block's normed input y [B, T, D]
+        -> (out [B, T, D], what the layer reports): GQA projections with the
+        per-head q/k RMSNorm (``qk_norm`` "head") and the model's rotation
+        (M-RoPE under ``mrope``), an indexer on the DETACHED input (``dsa_wq``:
+        ``dsa_index_heads`` queries of ``dsa_index_dim``; ``dsa_wk``: one key
+        with a LayerNorm, both rotated whole by the temporal stream;
+        ``dsa_ww``: a weight a head), and ``ops/dsa``'s ``select`` and
+        ``attend``: the ``dsa_topk`` best keys a query, the softmax over them,
+        the indexer's KL loss. ``rope`` = (cos, sin, the indexer's cos, sin)
+        (``rope_for``). Reports ``dsa_kl`` (the layer's loss, the mean over its tokens) and the
+        counters ``dsa_selected_min`` / ``dsa_selected_max`` / ``dsa_pairs`` /
+        ``dsa_block_visit_share``. Scopes: ``dsa_index`` and ``dsa_select``
+        inside ``attn_qkv``, ``dsa_core`` and ``dsa_kl`` inside ``attn_core``."""
+        import jax
+        from jax.ad_checkpoint import checkpoint_name
+
+        from ..ops import dsa
+
+        cfg = self.config
+        if cfg.qk_norm not in ("head", False) or cfg.position != "rope" or not cfg.causal:
+            raise NotImplementedError(
+                "mixer 'dsa' is causal GQA with a per-head q/k norm or none "
+                "(qk_norm 'head'), rotated (position 'rope')")
+        B, T = y.shape[:2]
+        H, KV, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+        cos, sin = rope[:2]
+        with trace.scope("attn_qkv"):
+            q = (y @ lw["wq"]).reshape(B, T, H, Dh)
+            k = (y @ lw["wk"]).reshape(B, T, KV, Dh)
+            v = (y @ lw["wv"]).reshape(B, T, KV, Dh)
+            if cfg.qk_norm == "head":
+                with trace.scope("attn_qk_norm"):
+                    q = _head_norm(q, lw["q_norm_w"], "rmsnorm", cfg.norm_eps)
+                    k = _head_norm(k, lw["k_norm_w"], "rmsnorm", cfg.norm_eps)
+            with trace.scope("mrope"):
+                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+            qi, ki, w = self.dsa_index(lw, y, rope)
+            # the scores and the search, a chunk of queries at a time
+            scale = self.dsa_index_scale
+            mask_t, found = dsa.select(qi, ki, w, cfg.dsa_topk, scale, scope=trace.scope)
+        q = checkpoint_name(q, "q")
+        k = checkpoint_name(k, "kv")
+        v = checkpoint_name(v, "kv")
+        with trace.scope("attn_core"):
+            attn, loss = dsa.attend(q, k, v, qi, ki, w, mask_t, index_scale=scale,
+                                    scope=trace.scope)
+        attn = checkpoint_name(attn, "attn")
+        with trace.scope("attn_out"):
+            out = attn.reshape(B, T, H * Dh) @ lw["wo"]
+        return out, {"dsa_kl": loss / (B * T),
+                     **{"dsa_" + name: x for name, x in found.items()}}
 
     def _gated_attention(self, lw, y, rope):
         """Qwen3-Next's full-attention mixer on the normed block input
@@ -2060,6 +2246,10 @@ class Transformer:
                 mixer == "swa" for mixer, _ in cfg.kinds_used) else {}
             ropes.update({mixer: self.rope_for(mixer, x.shape[-2])
                           for mixer in cfg.unrotated_mixers})
+            if len(rope) == 4:
+                # a learned sparse attention's tables (``embed``): the model's
+                # own and its indexer's
+                ropes["dsa"], rope = rope, rope[:2]
 
             def run(kind):
                 # a one-kind stack of softmax attention is checkpointed
@@ -2430,7 +2620,13 @@ class Transformer:
         float32, the sum of the weights of each expert's token-choices; a
         chunked loss ``loss_chunks``, the trips of its scan, and
         ``loss_rows``, the rows they held, pad rows too, on the device that
-        scanned them (rows a chunk: the quotient)."""
+        scanned them (rows a chunk: the quotient). A learned sparse attention
+        (mixer "dsa") adds its indexer's loss and gives
+        ``dsa_kl`` [layers] (each layer's, the mean over its tokens),
+        ``dsa_selected_min`` / ``dsa_selected_max`` (the keys a query past
+        position ``dsa_topk`` - 2 holds), ``dsa_pairs`` (the (t, s) chosen) and
+        ``dsa_block_visit_share``; ``batch["position_ids"]`` [3, B, T] are
+        M-RoPE's streams (``mrope_section``; absent: text, 0..T-1 in each)."""
         import jax.numpy as jnp
 
         ids = batch["input_ids"]
@@ -2467,7 +2663,9 @@ class Transformer:
             layer_keep = jax.random.uniform(sub, (L,)) < p_keep
         B, T = model_ids.shape
         cfg = self.config
-        x, rope = self.embed(params, model_ids)
+        positions = batch.get("position_ids")      # M-RoPE's streams [3, B, T]
+        x, rope = self.embed(params, model_ids,
+                             None if positions is None else positions[..., :T])
         x, aux, routed = self.stack_apply(params["layers"], x, rope,
                                           ltd_mask=ltd_mask, layer_keep=layer_keep,
                                           with_stats=True, **self._lead_of(params))
@@ -2504,6 +2702,10 @@ class Transformer:
                 f = (routed["expert_tokens"].sum(axis=0).astype(jnp.float32)
                      / (n_layers * B * T))
                 aux = cfg.n_experts * jnp.sum(f * routed["router_prob"].mean(axis=0))
+        if routed is not None:
+            # a learned sparse attention's counters and its indexer's loss, a
+            # row a layer
+            stats.update({name: x for name, x in routed.items() if name.startswith("dsa_")})
         with trace.scope("loss"):
             if self._loss_chunk(B, T):
                 # sized again on the rows the device that scans them holds
@@ -2513,7 +2715,11 @@ class Transformer:
             else:
                 nll_sum, count = self.token_loss(self.head(params, x), labels)
             ce = nll_sum / jnp.maximum(count, 1)
-            return ce + cfg.aux_loss_coef * aux, stats
+            loss = ce + cfg.aux_loss_coef * aux
+            if "dsa_kl" in stats:
+                # the indexer's own: the mean over layers (and, in each, tokens)
+                loss = loss + stats["dsa_kl"].mean()
+            return loss, stats
 
 
 # The float32 logits one chunk of the loss scan may hold, in bytes: what sizes

@@ -83,8 +83,10 @@ def visited_pairs(T: int, S: int, bq: int, bkv: int, window: int = 0) -> np.ndar
 
 
 def _kernel(qi_ref, kj_ref, flags_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-            di_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-            bq: int, bkv: int, off: int, window: int):
+            di_ref, *rest, bq: int, bkv: int, off: int, window: int):
+    # ``rest``: the outputs and scratch, after the tile of a mask that is
+    # data where the call has one (``fused_backward``'s ``mask``)
+    *mask_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -112,7 +114,11 @@ def _kernel(qi_ref, kj_ref, flags_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         # keys along sublanes, queries along lanes: dv and dk are plain
         # products of the tile, dq alone needs it transposed
         s = lax.dot_general(k, q, nt, preferred_element_type=f32)    # [bkv, bq]
-        if masked:
+        if mask_ref:
+            # (int8, keys along sublanes; it holds the causal mask too)
+            s = jnp.where(mask_ref[0][...].astype(jnp.int32) != 0, s,
+                          library.DEFAULT_MASK_VALUE)
+        elif masked:
             ahead = (i * bq + off - j * bkv
                      + lax.broadcasted_iota(jnp.int32, s.shape, 1)
                      - lax.broadcasted_iota(jnp.int32, s.shape, 0))
@@ -146,13 +152,16 @@ def _kernel(qi_ref, kj_ref, flags_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 
 def fused_backward(q, k, v, out, logsumexp, do, *, bq: int, bkv: int,
-                   window: int = 0, interpret: bool = False):
+                   window: int = 0, interpret: bool = False, mask=None):
     """(dq, dk, dv) of causal attention from the forward kernel's residuals.
 
     q [B, KV, G, T, D] (scaled), k [B, KV, S, D], v [B, KV, S, Dv], out and
     do [B, KV, G, T, Dv], logsumexp [B, KV, G, T] float32: query heads g of
     key head j share its k and v. ``window`` > 0: the causal local mask of
-    ``ops/flash_attention.splash_mask``."""
+    ``ops/flash_attention.splash_mask``. ``mask`` [B, S, T] int8 (keys-major,
+    inside the causal mask): the visible (key, query) pairs as DATA, one set
+    for every head (a learned sparse attention, ``ops/dsa_kernels``); every
+    causal pair of blocks is visited and the tile read beside the operands."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -181,7 +190,8 @@ def fused_backward(q, k, v, out, logsumexp, do, *, bq: int, bkv: int,
             pl.BlockSpec((None, None, None, bq, Dv), at_q),
             pl.BlockSpec((None, None, None, 1, bq), at_row),
             pl.BlockSpec((None, None, None, 1, bq), at_row),
-        ],
+        ] + ([] if mask is None else [pl.BlockSpec(
+            (None, bkv, bq), lambda b, h, g, s, qi, kj, fl: (b, kj[s], qi[s]))]),
         out_specs=[
             pl.BlockSpec((None, None, None, bq, D), at_q),
             pl.BlockSpec((None, None, S, D), whole),
@@ -203,4 +213,4 @@ def fused_backward(q, k, v, out, logsumexp, do, *, bq: int, bkv: int,
                 vmem_limit_bytes=VMEM_LIMIT_BYTES),
             name=KERNEL_NAME, interpret=interpret,
         )(*(jnp.asarray(pairs[:, c]) for c in range(3)), q, k, v, do,
-          row(logsumexp), row(di))
+          row(logsumexp), row(di), *(() if mask is None else (mask,)))

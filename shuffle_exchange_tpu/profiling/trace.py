@@ -89,6 +89,12 @@ PREFIX = "sxt:"
 # rotate (``unrotated_mixers``) opens "nope_qkv", "nope_core" and "nope_out" as
 # a window layer opens its "swa_*". A router that reads an input of its own
 # (``moe_router_input`` "block") opens "pre_router" AROUND "moe_router".
+# A learned sparse attention layer (mixer "dsa") rotates under "mrope" and opens
+# "dsa_index" (the indexer's projections, norm, rotation and scores) and
+# "dsa_select" (the k-th largest score a query, the mask) inside "attn_qkv",
+# "dsa_core" (the softmax over the chosen keys) and "dsa_kl" (the indexer's
+# loss: its target and the KL) inside "attn_core"; the M-RoPE table itself is
+# built under "mrope" inside "embed".
 # A gated short-convolution layer (mixer "sconv") opens "sconv_in"
 # inside "attn_qkv", "sconv_mix" (the pass between its projections) inside
 # "attn_core" and "sconv_out" inside "attn_out". A Mamba-2 state-space layer
@@ -106,6 +112,7 @@ SCOPES = {
              "mla_q", "mla_kv_down", "mla_kv_norm", "mla_kv_up", "mla_rope",
              "swa_qkv", "swa_rope", "swa_core", "swa_out", "rope_yarn",
              "nope_qkv", "nope_core", "nope_out",
+             "mrope", "dsa_index", "dsa_select", "dsa_core", "dsa_kl",
              "sconv_in", "sconv_mix", "sconv_out",
              "ssm_in", "ssm_conv", "ssm_gates", "ssm_scan", "ssm_out_norm", "ssm_out"),
     "mlp": ("mlp_norm", "mlp", "moe", "pre_router", "moe_router", "moe_dispatch",

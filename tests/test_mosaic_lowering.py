@@ -992,6 +992,37 @@ def test_gdn_prologue_compiles(chip_compile, Hk, rep, dk, dv):
     assert "gdn_prologue_fwd" in text and "gdn_prologue_bwd" in text
 
 
+def test_the_learned_sparse_attentions_kernels_compile_at_the_cells_shapes(chip_compile):
+    """``keyevl2-train``'s five kernels (PR 61) for a described v5e: the core's
+    forward and the fused backward under a mask that is data (32 heads over 4
+    KV heads of 128, 16,384 positions, int8 tiles of 1024 x 1024), the
+    head-averaged probabilities, and the indexer's scores of a chunk of 512
+    queries (16 heads of 64 over one key head) with their backward."""
+    from shuffle_exchange_tpu.ops import dsa_kernels
+
+    B, T, H, KV, D, C, Hi, Di = 1, 16384, 32, 4, 128, 512, 16, 64
+    q, kv = ((B, T, H, D), _BF16), ((B, T, KV, D), _BF16)
+    mask = ((B, T, T), jnp.int8)
+
+    def core(q, k, v, mask_t):
+        (out, lse), back = jax.vjp(lambda q, k, v: dsa_kernels.core(q, k, v, mask_t), q, k, v)
+        return back((out, jnp.zeros_like(lse))), lse
+
+    text = chip_compile(core, q, kv, kv, mask).as_text()
+    assert dsa_kernels.FWD_NAME in text and "sxt_splash_bwd_fused" in text
+    text = chip_compile(dsa_kernels.head_mean, q, kv, ((B, H, T), _F32), mask).as_text()
+    assert dsa_kernels.MEAN_NAME in text
+
+    def index(qi, ki, w, first, g):
+        scores, back = jax.vjp(
+            lambda qi, ki, w: dsa_kernels.index_scores(qi, ki, w, 0.03125, first), qi, ki, w)
+        return scores, back(g)
+
+    text = chip_compile(index, ((C, Hi, Di), _BF16), ((T, Di), _BF16), ((C, Hi), _BF16),
+                        ((), jnp.int32), ((T, C), _F32)).as_text()
+    assert dsa_kernels.INDEX_FWD_NAME in text and dsa_kernels.INDEX_BWD_NAME in text
+
+
 def test_tracer_reads_a_chip_compiled_programs_peak_and_passes(
         chip_compile, monkeypatch):
     """What ``trace.register_program`` keeps of a program compiled for the
