@@ -1526,7 +1526,7 @@ class Transformer:
         the indexer's KL loss. ``rope`` = (cos, sin, the indexer's cos, sin)
         (``rope_for``). Reports ``dsa_kl`` (the layer's loss, the mean over its tokens) and the
         counters ``dsa_selected_min`` / ``dsa_selected_max`` / ``dsa_pairs`` /
-        ``dsa_block_visit_share``. Scopes: ``dsa_index`` and ``dsa_select``
+        ``dsa_tied_chunks`` / ``dsa_block_visit_share``. Scopes: ``dsa_index`` and ``dsa_select``
         inside ``attn_qkv``, ``dsa_core`` and ``dsa_kl`` inside ``attn_core``."""
         import jax
         from jax.ad_checkpoint import checkpoint_name
@@ -2624,9 +2624,10 @@ class Transformer:
         (mixer "dsa") adds its indexer's loss and gives
         ``dsa_kl`` [layers] (each layer's, the mean over its tokens),
         ``dsa_selected_min`` / ``dsa_selected_max`` (the keys a query past
-        position ``dsa_topk`` - 2 holds), ``dsa_pairs`` (the (t, s) chosen) and
-        ``dsa_block_visit_share``; ``batch["position_ids"]`` [3, B, T] are
-        M-RoPE's streams (``mrope_section``; absent: text, 0..T-1 in each)."""
+        position ``dsa_topk`` - 2 holds), ``dsa_pairs`` (the (t, s) chosen),
+        ``dsa_block_visit_share`` and ``dsa_tied_chunks`` (the chunks of the
+        step, over all layers, whose tie rule ran its search);
+        ``batch["position_ids"]`` [3, B, T] are M-RoPE's streams (``mrope_section``; absent: text, 0..T-1 in each)."""
         import jax.numpy as jnp
 
         ids = batch["input_ids"]
@@ -2706,6 +2707,8 @@ class Transformer:
             # a learned sparse attention's counters and its indexer's loss, a
             # row a layer
             stats.update({name: x for name, x in routed.items() if name.startswith("dsa_")})
+            if "dsa_tied_chunks" in stats:
+                stats["dsa_tied_chunks"] = stats["dsa_tied_chunks"].sum()
         with trace.scope("loss"):
             if self._loss_chunk(B, T):
                 # sized again on the rows the device that scans them holds

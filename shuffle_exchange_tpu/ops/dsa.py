@@ -14,13 +14,20 @@ held whole but the loss's target on the kernel route (see ``attend``).
                the min(t + 1, topk) largest, ties to the earlier key
                (``lax.top_k``'s order), as ``mask_t`` [B, S, T] int8. The k-th
                largest is found by bisection on the scores' bits (32
-               compare-and-count passes a chunk: no sort, no ``approx_max_k``,
+               compare-and-count rounds a chunk: no sort, no ``approx_max_k``,
                no threshold that admits one key more); the tie rule by a second
                bisection on the position, run only in a chunk where the
-               threshold is shared. The mask goes on to the rest of the layer
-               through a bit-packed copy named ``KEPT``: a mixer half under
-               per-half remat keeps those 33 MB a layer (16,384 positions) and
-               its replay neither scores nor searches again.
+               threshold is shared. On a TPU (``select_route``) a chunk's
+               rounds are ONE kernel, ``ops/dsa_kernels.select_chunk``: the
+               keys stay in VMEM between the rounds, which walk the rows the
+               chunk can see and no other, and the kernel writes the chunk's
+               columns of ``mask_t`` in place and counts what it chose;
+               elsewhere ``topk_mask``, XLA's passes, which is also the tests'
+               oracle and, from the kernel's threshold, the tie rule of both.
+               The mask goes on to the rest of the layer through a bit-packed
+               copy named ``KEPT``: a mixer half under per-half remat keeps
+               those 33 MB a layer (16,384 positions) and its replay neither
+               scores nor searches again.
   ``attend``   the core (softmax over S_t of q . k / sqrt(Dh), times v) and
                the indexer's loss. On a TPU (``route``) ``ops/dsa_kernels``:
                a flash forward and the fused backward of
@@ -99,29 +106,47 @@ def _sort_key(scores):
     return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(0x80000000))
 
 
-def topk_mask(scores, valid, k: int):
-    """Of each COLUMN of ``scores`` [S, C] float32, the min(valid entries, k)
-    largest among the ``valid`` ones, ties to the earlier row: [S, C] bool.
-    Exact: the k-th largest key is built bit by bit (the largest value v with
-    at least k keys >= v), every key above it is taken, and of the keys equal
-    to it the earliest that still fit."""
+def _keys(scores, valid):
+    """The ``valid`` scores' sort keys; an invalid entry's is 0, below every
+    finite score's (key 0 is a NaN's)."""
+    import jax.numpy as jnp
+
+    return jnp.where(valid, _sort_key(scores), jnp.uint32(0))
+
+
+def _count(hit):
+    import jax.numpy as jnp
+
+    return jnp.sum(hit, axis=0, dtype=jnp.int32, keepdims=True)
+
+
+def _kth_largest(key, k: int):
+    """Of each column of ``key`` [S, C] uint32 the largest value v with at
+    least k keys >= v, built bit by bit: [1, C] uint32."""
     import jax
     import jax.numpy as jnp
 
-    u32, i32 = jnp.uint32, jnp.int32
-    S = scores.shape[0]
-    # an invalid entry sorts below every finite score (key 0 is a NaN's)
-    key = jnp.where(valid, _sort_key(scores), u32(0))
-    count = lambda hit: jnp.sum(hit, axis=0, dtype=i32, keepdims=True)
-    zeros = lambda dtype: jnp.zeros((1,) + key.shape[1:], dtype)
+    u32 = jnp.uint32
 
     def value_bit(i, thr):
         cand = thr | (u32(1) << (u32(31) - i.astype(u32)))
-        return jnp.where(count(key >= cand) >= k, cand, thr)
+        return jnp.where(_count(key >= cand) >= k, cand, thr)
 
-    thr = jax.lax.fori_loop(0, 32, value_bit, zeros(u32))
-    above, equal = key > thr, key == thr
-    room = k - count(above)                       # >= 1 keys equal to thr fit
+    return jax.lax.fori_loop(0, 32, value_bit, jnp.zeros((1,) + key.shape[1:], u32))
+
+
+def _at_threshold(key, thr, valid, k: int):
+    """The chosen entries under the threshold ``thr`` [1, C] (``_kth_largest``'s
+    of ``key``): every valid key above it and, of the valid keys equal to it,
+    the earliest that still fit -> ([S, C] bool, whether some column's
+    threshold was shared by more keys than fit: the tie rule's search ran)."""
+    import jax
+    import jax.numpy as jnp
+
+    i32 = jnp.int32
+    S = key.shape[0]
+    above, equal = key > thr, (key == thr) & valid
+    room = k - _count(above)                      # >= 1 keys equal to thr fit
     pos = jax.lax.broadcasted_iota(i32, key.shape, 0)
 
     def earliest(_):
@@ -131,14 +156,24 @@ def topk_mask(scores, valid, k: int):
 
         def position_bit(i, x):
             cand = x | (i32(1) << (i32(bits - 1) - i))
-            return jnp.where(count(equal & (pos < cand)) < room, cand, x)
+            return jnp.where(_count(equal & (pos < cand)) < room, cand, x)
 
-        last = jax.lax.fori_loop(0, bits, position_bit, zeros(i32))
+        last = jax.lax.fori_loop(0, bits, position_bit, jnp.zeros(room.shape, i32))
         return equal & (pos <= last)
 
-    tied = jnp.any(count(equal) > room)
+    tied = jnp.any(_count(equal) > room)
     ties = jax.lax.cond(tied, earliest, lambda _: equal, None)
-    return (above | ties) & valid
+    return above | ties, tied
+
+
+def topk_mask(scores, valid, k: int):
+    """Of each COLUMN of ``scores`` [S, C] float32, the min(valid entries, k)
+    largest among the ``valid`` ones, ties to the earlier row: [S, C] bool.
+    Exact: the k-th largest key is built bit by bit (the largest value v with
+    at least k keys >= v), every key above it is taken, and of the keys equal
+    to it the earliest that still fit."""
+    key = _keys(scores, valid)
+    return _at_threshold(key, _kth_largest(key, k), valid, k)[0]
 
 
 def _no_scope(name):
@@ -148,15 +183,19 @@ def _no_scope(name):
 
 
 def _packed(mask_t):
-    """[.., T] int8 of 0 / 1 -> [.., T / 8] uint8, eight queries a byte (a
-    last axis that is not whole bytes goes unpacked)."""
+    """[.., T] int8 of 0 / 1 -> [.., T / 8] int8, eight queries a byte: bit b
+    of byte j is query b x T / 8 + j, so the eight are whole slices of the
+    lane axis (a last axis that is not whole bytes goes unpacked)."""
+    import functools
+
     import jax.numpy as jnp
 
     T = mask_t.shape[-1]
     if T % 8:
         return mask_t
-    bits = mask_t.reshape(mask_t.shape[:-1] + (T // 8, 8)).astype(jnp.uint8)
-    return jnp.sum(bits << jnp.arange(8, dtype=jnp.uint8), axis=-1, dtype=jnp.uint8)
+    n = T // 8
+    return functools.reduce(jnp.bitwise_or,
+                            (mask_t[..., b * n:(b + 1) * n] << b for b in range(8)))
 
 
 def _unpacked(packed, T: int):
@@ -164,23 +203,33 @@ def _unpacked(packed, T: int):
 
     if packed.shape[-1] == T:
         return packed
-    bits = (packed[..., None] >> jnp.arange(8, dtype=jnp.uint8)) & jnp.uint8(1)
-    return bits.reshape(packed.shape[:-1] + (T,)).astype(jnp.int8)
+    return jnp.concatenate([(packed >> b) & 1 for b in range(8)], axis=-1)
 
 
-def select(qi, ki, w, topk: int, scale: float, scope=_no_scope):
-    """S_t for every query of qi [B, T, Hi, Di], ki [B, T, Di], w [B, T, Hi]
-    as ``mask_t`` [B, S, T] int8, KEYS-major (1 = key s is among query t's
-    chosen), and the step's counters: ``selected_min`` / ``selected_max``
-    (keys a query past position topk - 2 holds; T < topk: of the last query)
-    and ``pairs`` (the chosen (t, s) in all), int32, and
-    ``block_visit_share``. Nothing here carries a gradient. The scores run
-    under ``scope("dsa_index")``, the search under ``scope("dsa_select")``."""
+def select_route(scores_dtype, S: int, C: int) -> str:
+    """"pallas" (``ops/dsa_kernels.select_chunk``: on a TPU, float32 scores,
+    keys and a chunk of whole lane tiles whose column block fits the kernels'
+    VMEM budget) or "xla" (``topk_mask``) for the search of a chunk of C
+    queries over S keys."""
+    import numpy as np
+
+    from .dispatch import pallas_enabled
+
+    if not (pallas_enabled() and np.dtype(scores_dtype) == np.float32):
+        return "xla"
+    from . import dsa_kernels
+
+    return "pallas" if dsa_kernels.select_lanes(S, C) else "xla"
+
+
+def _select_xla(qi, ki, w, topk: int, scale: float, scope):
+    """``select``'s mask [B, S, T] int8, the chosen keys a (block of keys,
+    query) [B, S / C, T] int32 and the chunks that took the tie rule's search,
+    by ``topk_mask``'s pieces: the chunks' masks stacked and transposed, the
+    counts from a walk of the mask."""
     import jax
     import jax.numpy as jnp
-    from jax.ad_checkpoint import checkpoint_name
 
-    qi, ki, w = (jax.lax.stop_gradient(x) for x in (qi, ki, w))
     B, T = qi.shape[:2]
     C = chunk_of(T)
     rows = jnp.arange(T, dtype=jnp.int32)
@@ -195,26 +244,118 @@ def select(qi, ki, w, topk: int, scale: float, scope=_no_scope):
                 with scope("dsa_index"):
                     scores = index_scores(part(qi), ki, part(w), scale, c * C)
                 with scope("dsa_select"):
-                    return topk_mask(scores, valid, topk)
+                    key = _keys(scores, valid)
+                    return _at_threshold(key, _kth_largest(key, topk), valid, topk)
 
             # a chunk whose every query has topk keys or fewer keeps them all
-            mask = jax.lax.cond((c + 1) * C <= topk, lambda _: valid, search, None)
+            mask, tied = jax.lax.cond((c + 1) * C <= topk, lambda _: (valid, False),
+                                      search, None)
             with scope("dsa_select"):
-                return mask.astype(jnp.int8)
+                return mask.astype(jnp.int8), tied
 
-        chunks = jax.lax.map(body, jnp.arange(T // C))               # [n, S, C]
+        chunks, tied = jax.lax.map(body, jnp.arange(T // C))         # [n, S, C]
         with scope("dsa_select"):
-            return chunks.transpose(1, 0, 2).reshape(T, T)
+            return chunks.transpose(1, 0, 2).reshape(T, T), jnp.sum(tied, dtype=jnp.int32)
 
-    mask_t = jnp.stack([sequence(qi[b], ki[b], w[b]) for b in range(B)])
+    masks, tied = zip(*(sequence(qi[b], ki[b], w[b]) for b in range(B)))
+    mask_t = jnp.stack(masks)
+    with scope("dsa_select"):
+        counts = jnp.sum(mask_t.reshape(B, T // C, C, T), axis=2, dtype=jnp.int32)
+    return mask_t, counts, sum(tied)
+
+
+def _search_pallas(scores, mask_t, b, first, topk: int):
+    """The search of one chunk as ONE kernel (``ops/dsa_kernels.select_chunk``):
+    scores [S, C] float32 of the C queries from ``first`` of sequence ``b``
+    -> (``mask_t`` [B, S, T] int8 with the chunk's columns written in place,
+    the chosen keys a (block of ``select_rows`` keys, query) [S / rows, C]
+    int32, whether the tie rule ran its search). A chunk in which some query
+    shares its threshold with more keys than fit takes that search in XLA,
+    from the kernel's threshold."""
+    import jax
+    import jax.numpy as jnp
+
+    from . import dsa_kernels
+
+    S, C = scores.shape
+    mask_t, held, thr = dsa_kernels.select_chunk(scores, mask_t, b, first, topk)
+    tied = jnp.any(jnp.sum(held, axis=0) > topk)
+
+    def earliest(mask_t, held):
+        rows = jnp.arange(S, dtype=jnp.int32)
+        valid = rows[:, None] <= first + rows[None, :C]
+        chosen = _at_threshold(_keys(scores, valid), thr, valid, topk)[0]
+        held = jnp.sum(chosen.reshape(held.shape[0], -1, C), axis=1, dtype=jnp.int32)
+        return jax.lax.dynamic_update_slice(
+            mask_t, chosen.astype(jnp.int8)[None], (b, 0, first)), held
+
+    return jax.lax.cond(tied, earliest, lambda *kept: kept, mask_t, held) + (tied,)
+
+
+def _select_pallas(qi, ki, w, topk: int, scale: float, scope):
+    """``select``'s mask and tied chunks by ``_search_pallas``, a carry the
+    chunks' kernels write their columns of, and the chosen keys a (block of
+    keys, query) [B, S / rows, T] int32 from the kernels' counts."""
+    import jax
+    import jax.numpy as jnp
+
+    from . import dsa_kernels
+
+    B, T = qi.shape[:2]
+    C = chunk_of(T)
+
+    def chunk(i, carry):
+        mask_t, counts, tied_chunks = carry
+        b, first = i // (T // C), i % (T // C) * C
+        seq = lambda x: jax.lax.dynamic_index_in_dim(x, b, 0, keepdims=False)
+        part = lambda x: jax.lax.dynamic_slice_in_dim(seq(x), first, C, axis=0)
+        with scope("dsa_index"):
+            # (of a chunk whose every query has topk keys or fewer too: the
+            # kernel then keeps them all without a round. Zeros from a ``cond``
+            # cost more than those few scores: traced under the layer scan's
+            # derivative they become one [chunks, S, C] array made before the
+            # scan, and every chunk copies its slice of it: PERF.md, PR 62)
+            scores = index_scores(part(qi), seq(ki), part(w), scale, first)
+        with scope("dsa_select"):
+            mask_t, held, tied = _search_pallas(scores, mask_t, b, first, topk)
+            counts = jax.lax.dynamic_update_slice(counts, held[None], (b, 0, first))
+        return mask_t, counts, tied_chunks + tied
+
+    return jax.lax.fori_loop(0, B * (T // C), chunk, (
+        dsa_kernels.unwritten((B, T, T), jnp.int8, ki),     # every chunk writes its columns
+        jnp.zeros((B, T // dsa_kernels.select_rows(T, C), T), jnp.int32),
+        jnp.zeros((), jnp.int32)))
+
+
+def select(qi, ki, w, topk: int, scale: float, scope=_no_scope):
+    """S_t for every query of qi [B, T, Hi, Di], ki [B, T, Di], w [B, T, Hi]
+    as ``mask_t`` [B, S, T] int8, KEYS-major (1 = key s is among query t's
+    chosen), and the step's counters: ``selected_min`` / ``selected_max``
+    (keys a query past position topk - 2 holds; T < topk: of the last query),
+    ``pairs`` (the chosen (t, s) in all) and ``tied_chunks`` (the chunks whose
+    tie rule ran its search), int32, and ``block_visit_share``. Nothing here
+    carries a gradient. The scores run under ``scope("dsa_index")``, the
+    search under ``scope("dsa_select")``: by ``select_route`` one kernel a
+    chunk that writes its columns of the mask and counts what it chose
+    (``_select_pallas``), or ``topk_mask``'s passes in XLA and a walk of the
+    mask (``_select_xla``)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
+
+    qi, ki, w = (jax.lax.stop_gradient(x) for x in (qi, ki, w))
+    T = qi.shape[1]
+    kernels = select_route(jnp.float32, T, chunk_of(T)) == "pallas"
+    mask_t, counts, tied = (_select_pallas if kernels else _select_xla)(
+        qi, ki, w, topk, scale, scope)
     with scope("dsa_select"):
         # what the layer keeps of the selection between its passes: a bit a pair
         mask_t = _unpacked(checkpoint_name(_packed(mask_t), KEPT), T)
-        held = jnp.sum(mask_t, axis=1, dtype=jnp.int32)              # [B, T]
+        held = jnp.sum(counts, axis=1)                               # [B, T]
         bound = held[:, min(max(topk - 1, 0), T - 1):]
         return mask_t, {"selected_min": bound.min(), "selected_max": bound.max(),
-                        "pairs": held.sum(),
-                        "block_visit_share": block_visit_share(mask_t, CHUNK)}
+                        "pairs": held.sum(), "tied_chunks": tied,
+                        "block_visit_share": block_visit_share(counts, CHUNK)}
 
 
 def _grouped(q, kv_heads: int):
@@ -373,16 +514,18 @@ def route(q, k, T: int) -> str:
     return "pallas" if dsa_kernels.fits(T, q.shape[-1]) else "xla"
 
 
-def block_visit_share(mask_t, block: int):
-    """Of the causal (query block, key block) pairs of ``mask_t`` [B, S, T] at
-    ``block``, the percentage that hold a chosen key: what a core that skipped
-    empty blocks would visit (this one visits them all). float32 scalar."""
+def block_visit_share(counts, block: int):
+    """Of the causal (query block, key block) pairs at ``block``, the
+    percentage that hold a chosen key: what a core that skipped empty blocks
+    would visit (this one visits them all). ``counts`` [B, S / rows, T]: the
+    chosen keys a (``rows`` keys, query), ``rows`` a divisor of the block.
+    float32 scalar."""
     import jax.numpy as jnp
 
-    B, T = mask_t.shape[:2]
+    B, _, T = counts.shape
     blk = block if T % block == 0 else T
     n = T // blk
-    full = mask_t.reshape(B, n, blk, n, blk).max(axis=(2, 4))
+    full = counts.reshape(B, n, -1, n, blk).sum(axis=(2, 4)) > 0
     return 100.0 * jnp.sum(full, dtype=jnp.float32) / (B * n * (n + 1) // 2)
 
 

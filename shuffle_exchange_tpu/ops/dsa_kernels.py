@@ -31,6 +31,14 @@ a head's [keys, queries] products are weighed and summed in VMEM, so the
 back (512 MB a chunk of 512 queries at 16,384 keys and 16 heads: a step of the
 cell ``keyevl2-train`` read 1.58 s with it and 0.84 s without, my chip runs, PR
 61) never exists; the keys past the chunk's last query are skipped.
+
+The sixth is the selection's search of a chunk (``select_chunk``:
+``sxt_dsa_select``): a column block of the chunk's scores becomes sort keys in
+a VMEM scratch once, the 32 rounds of ``ops/dsa.topk_mask``'s bisection count
+there over the row blocks a query of the block can see (0.83 cycles a vector
+register by the compiler's bundles, where XLA's passes over HBM arrays read
+2.25 over all rows: PERF.md, PR 62), and the block's columns of the mask
+[B, S, T] are written where the three kernels above read them.
 """
 
 from __future__ import annotations
@@ -46,8 +54,11 @@ FWD_NAME = "sxt_dsa_attention_fwd"
 MEAN_NAME = "sxt_dsa_attention_head_mean"
 INDEX_FWD_NAME = "sxt_dsa_index_fwd"
 INDEX_BWD_NAME = "sxt_dsa_index_bwd"
+SELECT_NAME = "sxt_dsa_select"
 #: keys a grid step of the indexer's two kernels
 INDEX_KEYS = 512
+#: keys a trip of the selection's loops
+SELECT_ROWS = 512
 
 
 def block_of(T: int, itemsize: int = 2) -> int:
@@ -428,3 +439,142 @@ def index_scores(qi, ki, w, scale: float, first=None, interpret: bool = False):
     if first is None:
         first = ki.shape[0] - qi.shape[0]
     return _index_vjp(float(scale), interpret)(qi, ki, w, first)
+
+
+# -- the selection -------------------------------------------------------------------------
+
+def select_lanes(S: int, C: int) -> int:
+    """Queries a grid step of ``sxt_dsa_select`` holds (a column block of a
+    chunk's [S, C] scores: two float32 buffers, the int32 keys and two int8
+    mask buffers in VMEM), the widest within the kernels' budget; 0 where
+    the chunk or the keys are not whole lane tiles."""
+    if S % 128 or C % 128:
+        return 0
+    return next((n for n in (256, 128) if C % n == 0 and 14 * S * n <= VMEM_BUDGET_BYTES), 0)
+
+
+def select_rows(S: int, C: int) -> int:
+    """Keys a trip of the kernel's loops, and a row of its counts."""
+    return SELECT_ROWS if S % SELECT_ROWS == 0 and C % SELECT_ROWS == 0 else 128
+
+
+def _select_kernel(at_ref, s_ref, _, mask_ref, counts_ref, thr_ref, key_ref, *,
+                   k: int, rows: int):
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+
+    i32 = jnp.int32
+    S, L = key_ref.shape
+    low = -2 ** 31                                 # an invalid entry's key: below every score's
+    col0 = at_ref[1] + pl.program_id(0) * L        # the block's first query's position
+    whole = col0 // rows                           # row blocks every query of the block sees whole
+    live = (col0 + L + rows - 1) // rows           # ... and those some query sees
+    block = lambda r: pl.ds(pl.multiple_of(r * rows, rows), rows)
+    cols = col0 + lax.broadcasted_iota(i32, (rows, L), 1)
+    valid = lambda r: r * rows + lax.broadcasted_iota(i32, (rows, L), 0) <= cols
+
+    # the scores' bits as int32 keys whose SIGNED order is the floats'
+    # (``ops/dsa._sort_key`` with the top bit flipped)
+    def keys_of(r, masked):
+        bits = lax.bitcast_convert_type(s_ref[block(r), :], i32)
+        key = jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+        key_ref[block(r), :] = jnp.where(valid(r), key, low) if masked else key
+
+    def over_live_rows(visit):
+        lax.fori_loop(0, whole, lambda r, _: visit(r, False), None)
+        lax.fori_loop(whole, live, lambda r, _: visit(r, True), None)
+
+    over_live_rows(keys_of)
+
+    def at_least(cand):
+        """Keys >= cand [1, L] a query, over the live rows: four running
+        [8, L] sums a trip, added across sublanes once a round."""
+        def trip(r, sums):
+            hit = (key_ref[block(r), :] >= cand).astype(i32)
+            return tuple(sum((hit[t * 8:(t + 1) * 8] for t in range(j, rows // 8, 4)), s)
+                         for j, s in enumerate(sums))
+
+        sums = lax.fori_loop(0, live, trip, (jnp.zeros((8, L), i32),) * 4)
+        return jnp.sum(sums[0] + sums[1] + sums[2] + sums[3], axis=0, keepdims=True)
+
+    def value_bit(i, thr):
+        cand = thr ^ (i32(1) << (31 - i))          # round 0 flips the sign: low -> 0
+        return jnp.where(at_least(cand) >= k, cand, thr)
+
+    # the k-th largest key a query, bit by bit (``ops/dsa.topk_mask``'s); a
+    # block whose every query sees k keys or fewer keeps them all
+    rounds = jnp.where(col0 + L > k, 32, 0)
+    thr = lax.fori_loop(0, rounds, value_bit, jnp.full((1, L), low, i32))
+    thr_ref[...] = thr ^ low                       # in ``_sort_key``'s unsigned bits
+
+    counts_ref[...] = jnp.zeros_like(counts_ref)
+
+    def chosen(r, masked):
+        hit = key_ref[block(r), :] >= thr
+        hit = (hit & valid(r)) if masked else hit
+        ones = hit.astype(i32)
+        mask_ref[block(r), :] = ones.astype(jnp.int8)
+        counts_ref[pl.ds(r, 1), :] = jnp.sum(ones, axis=0, keepdims=True)
+
+    def dead(r, _):
+        mask_ref[block(r), :] = jnp.zeros((rows, L), jnp.int8)
+
+    over_live_rows(chosen)
+    lax.fori_loop(live, S // rows, dead, None)
+
+
+def unwritten(shape, dtype, after, interpret: bool = False):
+    """An array nobody has written: what a caller that goes on to write every
+    element starts from where zeros would cost a pass over it (the int8 mask
+    of 16,384 positions: 0.5 ms a layer, my chip run, PR 62). ``after``: an
+    array of the caller's, never read; it makes the result ITS layer's
+    (with no operand XLA makes one array before the layer scan and copies it
+    in every layer: 2.3 ms)."""
+    import jax
+    from jax.experimental import pallas as pl
+
+    return pl.pallas_call(
+        lambda after_ref, o_ref: None, out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY), name="sxt_unwritten",
+        interpret=interpret)(after)
+
+
+def select_chunk(scores, mask_t, b, first, k: int, interpret: bool = False):
+    """The selection of one chunk of queries of sequence ``b``: scores [S, C]
+    float32 (``index_scores``'s, keys-major), the chunk's first query at
+    ``first`` (both traced int32) -> (``mask_t`` [B, S, T] int8 with the chunk's
+    columns written IN PLACE: 1 where key s is a valid (s <= t) key of query
+    t at or above t's k-th largest, 0 on every other row; counts [S / rows, C]
+    int32, the chosen keys a query a block of ``select_rows`` keys; the
+    threshold [1, C] uint32 in ``ops/dsa._sort_key``'s bits). A query whose
+    counts add up to more than k shares its threshold with more keys than
+    fit: the caller's tie rule then decides among them."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, C = scores.shape
+    lanes, rows = select_lanes(S, C), select_rows(S, C)
+    at = jnp.stack([jnp.asarray(b, jnp.int32), jnp.asarray(first, jnp.int32)])
+    column = lambda j, at: (0, j)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(C // lanes,),
+        in_specs=[pl.BlockSpec((S, lanes), column), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[pl.BlockSpec((None, S, lanes), lambda j, at: (at[0], 0, at[1] // lanes + j)),
+                   pl.BlockSpec((S // rows, lanes), column),
+                   pl.BlockSpec((1, lanes), column)],
+        scratch_shapes=[pltpu.VMEM((S, lanes), jnp.int32)])
+    with jax.named_scope(SELECT_NAME):
+        mask_t, counts, thr = pl.pallas_call(
+            functools.partial(_select_kernel, k=k, rows=rows), grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct(mask_t.shape, jnp.int8),
+                       jax.ShapeDtypeStruct((S // rows, C), jnp.int32),
+                       jax.ShapeDtypeStruct((1, C), jnp.int32)],
+            input_output_aliases={2: 0},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",), vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            name=SELECT_NAME, interpret=interpret)(at, scores, mask_t)
+    return mask_t, counts, jax.lax.bitcast_convert_type(thr, jnp.uint32)

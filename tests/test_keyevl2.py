@@ -182,6 +182,10 @@ def test_losses_routing_and_selection_equal_the_reference(case):
     assert np.array_equal(np.asarray(stats["dsa_pairs"]), [BATCH * held.sum()] * 2)
     assert np.asarray(stats["dsa_selected_min"]).tolist() == [16, 16]
     assert np.asarray(stats["dsa_selected_max"]).tolist() == [16, 16]
+    # the chunks (of 4 a sequence a layer) whose tie rule searched: a count of
+    # the whole step; the relu's exact zeros tie at this size
+    assert stats["dsa_tied_chunks"].shape == () and stats["dsa_tied_chunks"].dtype == jnp.int32
+    assert 0 <= int(stats["dsa_tied_chunks"]) <= 2 * BATCH * 3
     assert BATCH * held.sum() == BATCH * arith_dsa.selected_pairs(SEQ, 16)
 
 
@@ -201,6 +205,62 @@ def test_per_half_remat_computes_the_same_gradient(case):
     a = jax.jit(jax.grad(lambda p: model.loss(p, batch)))(params)
     b = jax.jit(jax.grad(lambda p: again.loss(p, batch)))(params)
     assert max(gaps(driver.flat_tree(b), driver.flat_tree(a)).values()) < 1e-5
+
+
+def test_per_half_remats_replay_neither_scores_nor_searches(monkeypatch, devices8):
+    """The train step lowered for the TPU with the kernel routes chosen
+    (``testing/program_text``; heads of 128, three chunks of 128, bf16,
+    per-half remat): ONE search a layer a step. The forward's layer scan holds the
+    selection's kernel, both readings of the scores, their backward, the
+    target and the core's forward; the backward's holds the fused backward
+    and nothing of the selection: its replay unpacks the mask it kept (the
+    tie rule's rounds sit behind a ``cond`` on the selection kernel's counts:
+    where that kernel is not, they are not)."""
+    import collections
+    import re
+
+    import shuffle_exchange_tpu as sxt
+    from shuffle_exchange_tpu.ops import dispatch
+    from shuffle_exchange_tpu.testing import program_text
+
+    monkeypatch.setattr(dsa, "CHUNK", 128)
+    monkeypatch.setattr(dispatch, "pallas_enabled", lambda: True)
+    monkeypatch.setattr(jax, "devices", lambda *a, **kw: devices8[:1])
+    src = {**HF, "hidden_size": 256, "num_attention_heads": 2, "num_key_value_heads": 1,
+           "head_dim": 128, "moe_intermediate_size": 128,
+           "rope_scaling": {**HF["rope_scaling"], "mrope_section": [16, 24, 24]},
+           "sa_config": {**HF["sa_config"], "indexer_head_dim": 16, "indexer_num_heads": 2,
+                         "topk": 48}}
+    engine = sxt.initialize(model=Transformer(config_from_hf(src)), seed=7, config={
+        "train_batch_size": 1, "steps_per_print": 10 ** 9,
+        "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}, "bf16": {"enabled": True},
+        "activation_checkpointing": {"enabled": True, "policy": "full"}})[0]
+    assert dsa.select_route(jnp.float32, 384, 128) == "pallas"
+    text = program_text.train_step_lowered(
+        engine, {"input_ids": np.zeros((1, 385), np.int32)}, ("tpu",)).as_text()
+    launches = collections.Counter(re.findall(r'kernel_name = "(sxt_(?:dsa|splash)\w+)"', text))
+    assert launches == {"sxt_dsa_select": 1, "sxt_dsa_index_fwd": 2, "sxt_dsa_index_bwd": 1,
+                        "sxt_dsa_attention_fwd": 1, "sxt_dsa_attention_head_mean": 1,
+                        "sxt_splash_bwd_fused": 1}, launches
+    # no chunk's scores outlive its trip of the loop: nothing [chunks, S, C]
+    # is stacked for a later pass
+    assert "tensor<384x128xf32>" in text and "tensor<3x384x128xf32>" not in text
+
+
+def test_the_engine_hands_out_the_steps_tied_chunks(case, monkeypatch, devices8):
+    """``dsa_tied_chunks`` rides with the selection's other counters into
+    ``last_step_stats()``: one int32 for the whole step."""
+    import shuffle_exchange_tpu as sxt
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **kw: devices8[:1])
+    engine = sxt.initialize(model=case["model"], seed=7, config={
+        "train_batch_size": BATCH, "steps_per_print": 10 ** 9,
+        "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}})[0]
+    engine.train_batch({"input_ids": case["ids"]})
+    stats = engine.last_step_stats()
+    assert {"dsa_selected_min", "dsa_pairs", "dsa_tied_chunks"} <= set(stats)
+    assert stats["dsa_tied_chunks"].shape == () and stats["dsa_tied_chunks"].dtype == jnp.int32
+    assert stats["dsa_pairs"].shape == (2,)
 
 
 def test_each_loss_reaches_its_own_leaves_and_no_other(case):
@@ -391,6 +451,160 @@ def test_select_counts_and_the_chunks_agree_with_one_pass(monkeypatch):
     # what a layer keeps of it between its passes: a bit a pair
     assert dsa._packed(mask_t).shape == (B, T, T // 8)
     assert np.array_equal(np.asarray(dsa._unpacked(dsa._packed(mask_t), T)), np.asarray(mask_t))
+
+
+def steer_to_the_kernel_route(monkeypatch):
+    """The selection's kernel route on the CPU: ``select_route`` answers
+    "pallas" and ``sxt_dsa_select`` runs in the interpreter (the indexer's
+    scores stay XLA's: float32 inputs)."""
+    import functools
+
+    from shuffle_exchange_tpu.ops import dsa_kernels
+
+    monkeypatch.setattr(dsa, "CHUNK", 128)
+    monkeypatch.setattr(dsa, "select_route", lambda *a: "pallas")
+    for kernel in ("select_chunk", "unwritten"):
+        monkeypatch.setattr(dsa_kernels, kernel,
+                            functools.partial(getattr(dsa_kernels, kernel), interpret=True))
+    return dsa_kernels
+
+
+@pytest.fixture
+def kernel_route(monkeypatch):
+    return steer_to_the_kernel_route(monkeypatch)
+
+
+def crafted_scores(craft, S, C, rng):
+    """[S, C] float32, keys-major: ``test_exactly_min_t_plus_1_k_keys...``'s
+    crafts and the values the bit order has to get right."""
+    normal = rng.normal(size=(S, C))
+    scores = {
+        "all_equal": np.full((S, C), 0.25),
+        "blocks_of_ties": np.repeat(rng.normal(size=(S // 4, C)), 4, axis=0),
+        "zeros_and_negatives": np.where(rng.random((S, C)) < 0.6, 0.0, -rng.random((S, C))),
+        "threshold_shared_by_many": np.where(rng.random((S, C)) < 0.1, 1.0, 0.5),
+        "minus_zero": np.where(rng.random((S, C)) < 0.5, 0.0, -0.0),
+        "nan": np.where(rng.random((S, C)) < 0.02, np.nan, normal),
+    }.get(craft, normal).astype(np.float32)
+    if craft == "zeros_and_negatives":
+        scores[::7] = -0.0
+    if craft == "nan":
+        scores[::5] = np.where(np.isnan(scores[::5]), -np.float32(np.nan), scores[::5])
+        assert np.isnan(scores).any() and (np.signbit(scores) & np.isnan(scores)).any()
+    return scores
+
+
+@pytest.mark.parametrize("craft, S, first, k, tied", [
+    ("random", 256, 128, 48, False), ("all_equal", 256, 128, 7, True),
+    ("blocks_of_ties", 256, 128, 7, True), ("zeros_and_negatives", 256, 128, 7, True),
+    ("threshold_shared_by_many", 256, 128, 7, True), ("minus_zero", 256, 128, 48, True),
+    ("nan", 256, 128, 48, False), ("dead_rows", 512, 128, 48, False),
+    ("dead_rows_tied", 512, 256, 48, True), ("first_chunk", 256, 0, 48, False),
+    ("k_over_live", 512, 128, 300, False), ("k_is_the_live_rows", 256, 128, 256, False)])
+def test_the_selections_kernel_is_topk_mask_and_lax_top_k(kernel_route, craft, S, first, k, tied):
+    """``sxt_dsa_select`` in the interpreter with the tie rule behind it
+    (``_search_pallas``), a chunk of 128 queries from ``first`` over S keys in
+    row blocks of 128: the mask pair for pair ``topk_mask``'s and
+    ``lax.top_k``'s, the rows past the chunk written 0, the other chunks'
+    columns left alone, and the counts and the tie flag the mask's own."""
+    C, rows = 128, 128
+    scores = crafted_scores("all_equal" if craft == "dead_rows_tied" else craft, S, C,
+                            np.random.default_rng(1))
+    valid = jnp.arange(S)[:, None] <= first + jnp.arange(C)[None, :]
+    before = jnp.full((2, S, S), 7, jnp.int8)
+    mask_t, held, was_tied = jax.jit(
+        lambda s, m: dsa._search_pallas(s, m, 1, first, k))(jnp.asarray(scores), before)
+    got = np.asarray(mask_t[1, :, first:first + C])
+    oracle = np.asarray(dsa.topk_mask(jnp.asarray(scores), valid, k))
+    want = np.asarray(lax_topk_mask(jnp.asarray(scores).T, valid.T, k).T)
+    assert np.array_equal(oracle, want), craft
+    assert set(np.unique(got)) <= {0, 1} and np.array_equal(got != 0, want), craft
+    assert np.array_equal(want.sum(axis=0), np.minimum(np.asarray(valid).sum(axis=0), k))
+    assert not got[first + C:].any()                      # rows no query of the chunk sees
+    untouched = np.ones(S, bool)
+    untouched[first:first + C] = False
+    assert (np.asarray(mask_t[0]) == 7).all() and (np.asarray(mask_t[1])[:, untouched] == 7).all()
+    assert held.shape == (S // rows, C) and held.dtype == jnp.int32
+    assert np.array_equal(np.asarray(held), want.reshape(S // rows, rows, C).sum(axis=1))
+    key = dsa._keys(jnp.asarray(scores), valid)
+    assert bool(was_tied) == tied == bool(dsa._at_threshold(key, dsa._kth_largest(key, k),
+                                                             valid, k)[1])
+
+
+def test_the_kernels_threshold_is_the_kth_largest_key(kernel_route):
+    """What the tie rule starts from: the kernel's threshold in
+    ``_sort_key``'s bits, the k-th largest of a query's valid keys (key 0
+    where fewer are valid), on scores that hold NaNs of both signs."""
+    S, C, first, k = 256, 128, 128, 48
+    scores = jnp.asarray(crafted_scores("nan", S, C, np.random.default_rng(2)))
+    valid = jnp.arange(S)[:, None] <= first + jnp.arange(C)[None, :]
+    _, _, thr = kernel_route.select_chunk(scores, jnp.zeros((1, S, S), jnp.int8), 0, first, k)
+    assert thr.dtype == jnp.uint32 and np.array_equal(
+        np.asarray(thr), np.asarray(dsa._kth_largest(dsa._keys(scores, valid), k)))
+    _, held, thr = kernel_route.select_chunk(scores, jnp.zeros((1, S, S), jnp.int8), 0, first, 300)
+    assert not np.asarray(thr).any()
+    assert np.array_equal(np.asarray(held).sum(axis=0), np.asarray(valid).sum(axis=0))
+
+
+@pytest.mark.parametrize("scores", ["continuous", "tied"])
+def test_select_on_the_kernel_route_is_select_on_the_xla_route(monkeypatch, scores):
+    """Array for array, with ``found`` equal; ``tied_chunks`` counts the
+    chunks whose tie rule searched: none on continuous scores, some where the
+    indexer's inputs are whole numbers."""
+    monkeypatch.setattr(dsa, "CHUNK", 128)
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    B, T, Hi, Di, k = 2, 384, 4, 8, 48
+    qi = jax.random.normal(ks[0], (B, T, Hi, Di))
+    ki = jax.random.normal(ks[1], (B, T, Di))
+    w = 1.0 + jax.random.uniform(ks[2], (B, T, Hi))
+    if scores == "tied":
+        qi, ki, w = jnp.round(qi), jnp.round(ki), jnp.round(w)
+    else:
+        qi = jnp.abs(qi)                       # no score is the relu's exact 0
+        ki = jnp.abs(ki)
+    run = lambda: jax.jit(lambda *a: dsa.select(*a, k, 0.1))(qi, ki, w)
+    assert dsa.select_route(jnp.float32, T, 128) == "xla"            # no TPU here
+    mask_x, found_x = run()
+    steer_to_the_kernel_route(monkeypatch)
+    mask_p, found_p = run()
+    assert mask_p.dtype == mask_x.dtype == jnp.int8
+    assert np.array_equal(np.asarray(mask_p), np.asarray(mask_x))
+    assert set(found_p) == set(found_x) == {"selected_min", "selected_max", "pairs",
+                                            "tied_chunks", "block_visit_share"}
+    for name in found_x:
+        assert found_p[name].dtype == found_x[name].dtype, name
+        assert np.array_equal(np.asarray(found_p[name]), np.asarray(found_x[name])), name
+    assert int(found_p["pairs"]) == B * arith_dsa.selected_pairs(T, k)
+    assert (int(found_p["tied_chunks"]) > 0) == (scores == "tied")
+    assert found_p["tied_chunks"].dtype == jnp.int32
+
+
+def test_the_routes_blocks_and_what_the_kernel_declines():
+    from shuffle_exchange_tpu.ops import dsa_kernels
+
+    # the cell's: 256 queries a grid step, 58.7 MB of VMEM, loops of 512 keys
+    assert dsa_kernels.select_lanes(16384, 512) == 256
+    assert 14 * 16384 * 256 <= dsa_kernels.VMEM_BUDGET_BYTES < dsa_kernels.VMEM_LIMIT_BYTES
+    assert dsa_kernels.select_rows(16384, 512) == 512
+    assert dsa_kernels.select_lanes(32768, 512) == 128 and dsa_kernels.select_lanes(65536, 512) == 0
+    assert dsa_kernels.select_lanes(256, 128) == 128 and dsa_kernels.select_rows(256, 128) == 128
+    assert dsa_kernels.select_lanes(64, 16) == 0 and dsa_kernels.select_lanes(16384, 192) == 0
+
+
+def test_the_packed_copy_holds_query_b_x_t_over_8_plus_j_in_bit_b_of_byte_j():
+    """The bit layout of what a layer keeps: eight whole slices of the lane
+    axis OR-ed together, no last axis of 8."""
+    B, T = 2, 64
+    mask = jnp.asarray(np.random.default_rng(5).integers(0, 2, (B, T, T)), jnp.int8)
+    packed = dsa._packed(mask)
+    assert packed.shape == (B, T, T // 8) and packed.dtype == jnp.int8
+    bits = np.asarray(packed).view(np.uint8)
+    for b in range(8):
+        assert np.array_equal((bits >> b) & 1, np.asarray(mask)[..., b * 8:(b + 1) * 8])
+    again = dsa._unpacked(packed, T)
+    assert again.dtype == jnp.int8 and np.array_equal(np.asarray(again), np.asarray(mask))
+    odd = mask[..., :61]                               # not whole bytes: as it is
+    assert dsa._packed(odd) is odd and dsa._unpacked(odd, 61) is odd
 
 
 def test_the_mixer_alone_on_the_drivers_reading(case):

@@ -1023,6 +1023,35 @@ def test_the_learned_sparse_attentions_kernels_compile_at_the_cells_shapes(chip_
     assert dsa_kernels.INDEX_FWD_NAME in text and dsa_kernels.INDEX_BWD_NAME in text
 
 
+def test_the_selections_kernel_compiles_at_the_cells_shapes(chip_compile):
+    """``sxt_dsa_select`` at ``keyevl2-train``'s shapes (16,384 keys, a chunk
+    of 512 queries, the 256 a grid step that ``select_lanes`` picks, the mask
+    [1, 16384, 16384] written in place): what it asks of VMEM (two float32
+    score blocks, the int32 keys, two int8 mask blocks) is the route's
+    estimate and under the kernels' limit."""
+    import re
+
+    from shuffle_exchange_tpu.ops import dsa_kernels
+
+    T, C, k = 16384, 512, 2048
+    lanes = dsa_kernels.select_lanes(T, C)
+    assert lanes == 256
+
+    def search(scores, mask_t, first):
+        return dsa_kernels.select_chunk(scores, mask_t, 0, first, k)
+
+    compiled = chip_compile(search, ((T, C), _F32), ((1, T, T), jnp.int8), ((), _I32),
+                            donate=(1,))
+    call = re.search(r'%sxt_dsa_select[^\n]*custom_call_target="tpu_custom_call"[^\n]*',
+                     compiled.as_text()).group(0)
+    used = int(re.search(r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', call).group(1))
+    assert 14 * T * lanes <= used < 15 * T * lanes, used
+    assert used < dsa_kernels.VMEM_LIMIT_BYTES
+    # the mask goes in and comes out in one buffer
+    assert '"aliasing_operands":{"lists":[{"indices":["2","3"]}]}' in call
+    assert compiled.memory_analysis().temp_size_in_bytes < T * C
+
+
 def test_tracer_reads_a_chip_compiled_programs_peak_and_passes(
         chip_compile, monkeypatch):
     """What ``trace.register_program`` keeps of a program compiled for the
