@@ -347,34 +347,38 @@ def _held_dispatch(xs, order, k: int, fit, runs, read):
     return dispatch(xs, order, fit, runs, read)
 
 
-def _held_combine(out_sorted, weights, order, inverse, fit, runs, read):
+def _held_combine(out_sorted, weights, order, by_expert, fit, runs, read):
     """One rank's share, the way back: out[s] = sum over the token's k choices
-    of ``weights[s, j]`` x row ``inverse[s, j]`` of out_sorted [R, M] (nothing
-    for a choice with no position), summed over the token-ordered positions
-    (:func:`_run_sums`: the visited rows + S row lookups). The backward looks
-    up the rows of the cotangent ONCE a position of the blocks below ``fit``
-    (row ``order[r] // k``; :func:`_held_blocks`): scaled by the position's
-    weight they are out_sorted's gradient (zeros past the visited blocks), and
-    their float32 dot with the position's own row of out_sorted is that
-    choice's weight gradient, which goes to [S, k] as a lookup of R scalars
-    (zero where a choice has no position)."""
+    of ``weights[s, j]`` x the row of out_sorted [R, M] at that choice's
+    position (nothing for a choice with no position), summed over the
+    token-ordered positions (:func:`_run_sums`: the visited rows + S row
+    lookups). The backward looks up the rows of the cotangent ONCE a position
+    of the blocks below ``fit`` (row ``order[r] // k``; :func:`_held_blocks`):
+    scaled by the position's weight they are out_sorted's gradient (zeros past
+    the visited blocks), and their float32 dot with the position's own row of
+    out_sorted is that choice's weight gradient. It goes from the positions to
+    [S, k] by a SORT: ``by_expert`` [S * k] (:class:`RouteIndex`) names the
+    choice at every position of the unbounded order, a permutation, so sorting
+    the positions' numbers by it lays each at its choice (zeros at a position
+    that holds nothing, so at a choice that has none); a lookup of S * k
+    scalars took 7 ns each on a v5e (PERF.md section 6, PR 63)."""
     import jax
     import jax.numpy as jnp
 
-    k = inverse.shape[1]
+    S, k = weights.shape
     R, M = out_sorted.shape
     dtype = out_sorted.dtype
 
     @jax.custom_vjp
-    def combine(out_sorted, weights, order, inverse, fit, runs, read):
+    def combine(out_sorted, weights, order, by_expert, fit, runs, read):
         return _run_sums(out_sorted, runs, read, order, k, fit, weights.reshape(-1))
 
-    def fwd(out_sorted, weights, order, inverse, fit, runs, read):
-        return (combine(out_sorted, weights, order, inverse, fit, runs, read),
-                (out_sorted, weights, order, inverse, fit))
+    def fwd(out_sorted, weights, order, by_expert, fit, runs, read):
+        return (combine(out_sorted, weights, order, by_expert, fit, runs, read),
+                (out_sorted, weights, order, by_expert, fit))
 
     def bwd(res, g):
-        out_sorted, weights, order, inverse, fit = res
+        out_sorted, weights, order, by_expert, fit = res
 
         def block(start, size, grads):
             d_sorted, d_w_sorted = grads
@@ -391,11 +395,14 @@ def _held_combine(out_sorted, weights, order, inverse, fit, runs, read):
         d_sorted, d_w_sorted = _held_blocks(
             fit, R, _ROW_BLOCK, block,
             (jnp.zeros((R, M), dtype), jnp.zeros((R,), jnp.float32)))
-        d_weights = jnp.take(d_w_sorted, inverse, mode="fill", fill_value=0)
+        # the last visited block's rows past ``fit`` are nobody's: 0 x what stands there
+        d_w_sorted = jnp.where(jnp.arange(R) < fit, d_w_sorted, 0)
+        d_w_sorted = jnp.pad(d_w_sorted, (0, max(0, S * k - R)))[:S * k]
+        d_weights = jax.lax.sort((by_expert, d_w_sorted), num_keys=1)[1].reshape(S, k)
         return d_sorted, d_weights.astype(weights.dtype), None, None, None, None, None
 
     combine.defvjp(fwd, bwd)
-    return combine(out_sorted, weights, order, inverse, fit, runs, read)
+    return combine(out_sorted, weights, order, by_expert, fit, runs, read)
 
 
 def held_buffer_rows(tokens: int, k: int, held: int, n_experts: int,
@@ -406,6 +413,79 @@ def held_buffer_rows(tokens: int, k: int, held: int, n_experts: int,
     every token-choice."""
     balanced = tokens * k * held / n_experts
     return min(tokens * k, tile * max(1, math.ceil(factor * balanced / tile)))
+
+
+def _choices_per_expert(flat_e, n_experts: int):
+    """How many of the token-choices ``flat_e`` [S * k] fall on each of the
+    experts [0, ``n_experts``): a compare against each expert, summed over the
+    choices; a choice on no such expert counts nowhere. On a v5e 0.003-0.008
+    ms at 163,840 choices over 9 to 65 experts and 0.055 ms over 513, where
+    ``jnp.bincount``'s scatter-add took 1.43 ms whatever the width and however
+    the choices collide (PERF.md section 6, PR 63)."""
+    import jax.numpy as jnp
+
+    experts = jnp.arange(n_experts, dtype=flat_e.dtype)
+    return (flat_e[None, :] == experts[:, None]).sum(axis=1, dtype=jnp.int32)
+
+
+class RouteIndex(NamedTuple):
+    """The integer arrays of one dropless routing (:func:`_route_index`)."""
+    by_expert: "jax.Array"      # [S * k] the token-choices sorted by expert: a permutation
+    order: "jax.Array"          # [positions] the token-choice at each position
+    inverse: "jax.Array"        # the position of each token-choice
+    group_sizes: "jax.Array"    # [E] positions of each held expert
+    fit: "jax.Array"            # positions that hold something
+    held: "jax.Array"           # token-choices on held experts
+    runs: Optional["jax.Array"]     # :func:`_held_runs`, a rank's share only
+    read: Optional["jax.Array"]
+
+
+def _route_index(topk_idx, E: int, expert_first: int = 0,
+                 buffer_rows: Optional[int] = None) -> RouteIndex:
+    """Where each token-choice of ``topk_idx`` [S, k] goes, sorted by expert
+    (stable: a token's choices, and an expert's tokens, keep their order).
+
+    ``buffer_rows`` None: all E experts are held. ``order`` [S * k] is the
+    token-choice at each position (``by_expert`` itself), ``inverse`` [S * k]
+    the position of each token-choice, ``fit`` = ``held`` = S * k. Else one
+    rank's share, the experts [``expert_first``, ``expert_first`` + E): the
+    held token-choices take the positions [0, ``fit``) of R = ``buffer_rows``;
+    ``held`` - ``fit`` did not fit and are dropped, last experts first;
+    ``order`` [R] reads S * k at a position that holds nothing, ``inverse``
+    [S, k] reads R for a choice that has no position (absent or dropped).
+
+    Every array comes from sorts, compares, reductions and running sums:
+    nothing here is a scatter over the S * k token-choices, which a TPU runs
+    one index at a time. On a v5e at 163,840 choices the inverse by a scatter
+    took 0.76 ms and the counts by a scatter-add 1.43 (4.6 and 8.7 ns a
+    choice, whatever the bins and however the choices collide) of the 2.41 ms
+    this function took a layer and pass; a sort of as many (key, index) pairs
+    0.15 (PERF.md section 6, PR 63). ``by_expert`` is a permutation, so its
+    inverse is its argsort; the counts are :func:`_choices_per_expert`."""
+    import jax.numpy as jnp
+
+    S, k = topk_idx.shape
+    share = buffer_rows is not None
+    flat_e = topk_idx.reshape(-1)                        # [S*k]
+    if share:
+        # absent experts sort behind the held ones, as group E
+        local = flat_e - expert_first
+        flat_e = jnp.where((local >= 0) & (local < E), local, E)
+    by_expert = jnp.argsort(flat_e, stable=True)
+    inverse = jnp.argsort(by_expert)
+    group_sizes = _choices_per_expert(flat_e, E)
+    if not share:
+        return RouteIndex(by_expert, by_expert, inverse, group_sizes, S * k, S * k, None, None)
+    R = buffer_rows
+    ends = jnp.minimum(jnp.cumsum(group_sizes), R)
+    held, fit = group_sizes.sum(), ends[-1]
+    group_sizes = jnp.diff(ends, prepend=0)
+    # positions past the held rows hold nothing; absent and dropped
+    # choices have no position
+    order = jnp.where(jnp.arange(R) < fit, by_expert[:R], S * k)
+    inverse = jnp.where(inverse < fit, inverse, R).reshape(S, k)
+    runs, read = _held_runs(order, inverse, fit)
+    return RouteIndex(by_expert, order, inverse, group_sizes, fit, held, runs, read)
 
 
 def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu",
@@ -481,37 +561,22 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu",
     dtype = xs.dtype
     share = buffer_rows is not None
     with trace.scope("moe_dispatch"):
-        flat_e = topk_idx.reshape(-1)                        # [S*k]
+        by_expert, order, inverse, group_sizes, fit, held, runs, read = _route_index(
+            topk_idx, E, expert_first, buffer_rows)
         if share:
-            # absent experts sort behind the held ones, as group E
-            local = flat_e - expert_first
-            flat_e = jnp.where((local >= 0) & (local < E), local, E)
-        order = jnp.argsort(flat_e, stable=True)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(S * k, dtype=order.dtype), unique_indices=True)
-        group_sizes = jnp.bincount(flat_e, length=E + share)[:E].astype(jnp.int32)
-        if share:
-            R = buffer_rows
-            ends = jnp.minimum(jnp.cumsum(group_sizes), R)
-            held, fit = group_sizes.sum(), ends[-1]
-            group_sizes = jnp.diff(ends, prepend=0)
-            # positions past the held rows hold nothing; absent and dropped
-            # choices have no position
-            order = jnp.where(jnp.arange(R) < fit, order[:R], S * k)
-            inverse = jnp.where(inverse < fit, inverse, R).reshape(S, k)
-            runs, read = _held_runs(order, inverse, fit)
             xsort = _held_dispatch(xs, order, k, fit, runs, read)    # [R, M]
         else:
             xsort = _permuted_rows(xs, order, inverse, k)    # [S*k, M]
-        # expert per row, for the bias epilogue (a position that holds
-        # nothing reads some held expert's bias into a row nobody reads)
-        e_sorted = jnp.minimum(jnp.take(flat_e, order, mode="clip"), E - 1)
 
     def b(key, t):
-        # grouped-GEMM bias epilogue: gather each row's expert bias
+        # grouped-GEMM bias epilogue: gather each row's expert bias (a
+        # position that holds nothing reads some held expert's bias into a
+        # row nobody reads)
         if key not in params:
             return t
-        return t + jnp.take(params[key].astype(dtype), e_sorted, axis=0)
+        e_sorted = jnp.searchsorted(jnp.cumsum(group_sizes), jnp.arange(t.shape[0]),
+                                    side="right", method="compare_all")
+        return t + jnp.take(params[key].astype(dtype), jnp.minimum(e_sorted, E - 1), axis=0)
 
     def w(key):
         # int8/fp8 QuantizedMatrix expert stacks pass through UNCAST:
@@ -530,12 +595,12 @@ def expert_mlp_ragged(params, xs, topk_idx, topk_w, activation: str = "swiglu",
         gate = (b("b_gate", grouped_matmul(xsort, w("w_gate"), group_sizes))
                 if gate_fn(activation) else None)
         # the rows anybody reads: the held ones, every row where all are held
-        h = expert_act(gate, up, fit if share else S * k, activation,
+        h = expert_act(gate, up, fit, activation,
                        expert_act_route(xsort, w_up, activation))
         out_sorted = b("b_down", grouped_matmul(h, w("w_down"), group_sizes))
     with trace.scope("moe_combine"):
         if share:
-            return (_held_combine(out_sorted, topk_w, order, inverse, fit, runs, read),
+            return (_held_combine(out_sorted, topk_w, order, by_expert, fit, runs, read),
                     fit, held - fit)
         out_flat = _permuted_rows(out_sorted, inverse, order)   # unsort
         out = (out_flat.reshape(S, k, M) * topk_w[..., None].astype(dtype)).sum(axis=1)
@@ -714,7 +779,7 @@ def moe_layer(gate_w, expert_params, x, k: int = 2, capacity_factor: float = 1.0
                 rng=rng, noise_std=noise_std, aux=aux, score=score,
                 select_bias=select_bias, weight_scale=weight_scale,
                 sequences=math.prod(orig_shape[:-2]))
-            counts = jnp.bincount(idx.reshape(-1), length=gate_w.shape[1])
+            counts = _choices_per_expert(idx.reshape(-1), gate_w.shape[1])
         meta = {"expert_counts": counts, "drop_fraction": jnp.zeros(()),
                 "capacity": S, "router_prob": prob}
         if select_bias is not None:

@@ -10,6 +10,12 @@ seam (``mixer_half`` / ``ffn_half``) is shared by all three; a multiply by 1.0
 or a branch XLA has to fold would change the text: the Granite family's
 multipliers (PR 55) are neutral in the three older cells and emit nothing there.
 
+PR 63 made a routed layer's index arrays from sorts and compare-sums
+(``moe/layer.py`` ``_route_index``): the ``*_moe`` blocks' ``text`` and
+``grad_text`` were written again from that PR's tree, their ``bits`` and every
+entry of a block that routes nothing (``ssm_none``, ``*_mlp``, ``_gdn``) are
+the parent's (02174fc), unchanged.
+
 The text is jaxpr text of this container's JAX: after a JAX upgrade that
 changes the printer, write the file again from a commit that is known good
 (``SXT_WRITE_GOLDEN=1 pytest tests/test_block_program_text.py``) and say so.

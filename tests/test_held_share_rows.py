@@ -15,7 +15,7 @@ import pytest
 
 from shuffle_exchange_tpu.moe import layer
 from shuffle_exchange_tpu.moe.layer import (_held_combine, _held_dispatch,
-                                            _held_runs, expert_mlp_ragged,
+                                            _held_runs, _route_index, expert_mlp_ragged,
                                             held_rows_visited, init_expert_mlp)
 
 M = 24
@@ -91,7 +91,7 @@ def _positions(topk_idx, first, held, R):
     order[:fit] = by_expert[:fit]
     inverse = np.full(S * k, R, np.int32)
     inverse[order[:fit]] = np.arange(fit)
-    return order, inverse.reshape(S, k), fit
+    return order, inverse.reshape(S, k), fit, by_expert.astype(np.int32)
 
 
 def _case(name, monkeypatch, dtype=jnp.float32):
@@ -99,7 +99,7 @@ def _case(name, monkeypatch, dtype=jnp.float32):
     if len(CASES[name]) > 4:
         monkeypatch.setattr(layer, "_ROW_BLOCK", CASES[name][4])
     S, k = topk_idx.shape
-    order, inverse, fit = _positions(topk_idx, first, held, R)
+    order, inverse, fit, by_expert = _positions(topk_idx, first, held, R)
     rng = np.random.default_rng(11)
     weights = rng.random((S, k)).astype(np.float32) + 0.1
     xs = rng.standard_normal((S, M)).astype(np.float32)
@@ -112,7 +112,7 @@ def _case(name, monkeypatch, dtype=jnp.float32):
     runs, read = _held_runs(jnp.asarray(order), jnp.asarray(inverse), fit)
     return dict(S=S, k=k, R=R, fit=fit, order=order, inverse=inverse, weights=weights,
                 xs=xs, rows=rows, g_tokens=g_tokens, cast=as_dtype,
-                index=(jnp.asarray(order), jnp.asarray(inverse), runs, read))
+                index=(jnp.asarray(order), jnp.asarray(by_expert), runs, read))
 
 
 def _near(got, want):
@@ -122,7 +122,7 @@ def _near(got, want):
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_the_dispatch_brings_each_position_its_tokens_row_and_sums_them_back(name, monkeypatch):
     c = _case(name, monkeypatch)
-    order, inverse, runs, read = c["index"]
+    order, _, runs, read = c["index"]
     got, vjp = jax.vjp(lambda x: _held_dispatch(x, order, c["k"], c["fit"], runs, read),
                        jnp.asarray(c["xs"]))
     want = np.zeros((c["R"], M), np.float32)
@@ -155,8 +155,8 @@ def _combine_reference(c):
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_the_combine_weighs_and_sums_a_tokens_held_rows(name, monkeypatch):
     c = _case(name, monkeypatch)
-    order, inverse, runs, read = c["index"]
-    got, vjp = jax.vjp(lambda o, w: _held_combine(o, w, order, inverse, c["fit"], runs, read),
+    order, by_expert, runs, read = c["index"]
+    got, vjp = jax.vjp(lambda o, w: _held_combine(o, w, order, by_expert, c["fit"], runs, read),
                        jnp.asarray(c["rows"]), jnp.asarray(c["weights"]))
     d_rows, d_weights = vjp(jnp.asarray(c["g_tokens"]))
     want, d_rows_want, d_weights_want = _combine_reference(c)
@@ -174,12 +174,12 @@ def test_the_combine_weighs_and_sums_a_tokens_held_rows(name, monkeypatch):
                                   "a_third_of_seven_blocks", "two_run_blocks_a_trip"])
 def test_in_bfloat16_a_tokens_sum_is_rounded_once(name, monkeypatch):
     c = _case(name, monkeypatch, jnp.bfloat16)
-    order, inverse, runs, read = c["index"]
+    order, by_expert, runs, read = c["index"]
     rows = np.where(np.isnan(c["rows"]), 0, c["rows"])
     rounded = dict(c, rows=np.asarray(c["cast"](rows), np.float32),
                    weights=np.asarray(c["cast"](c["weights"]), np.float32))
     want = _combine_reference(rounded)[0]
-    got = _held_combine(c["cast"](c["rows"]), jnp.asarray(c["weights"]), order, inverse,
+    got = _held_combine(c["cast"](c["rows"]), jnp.asarray(c["weights"]), order, by_expert,
                         c["fit"], runs, read)
     assert got.dtype == jnp.bfloat16
     # the float32 sum of the rounded operands, rounded once: half a bf16 step
@@ -261,6 +261,141 @@ def test_no_pass_of_the_share_looks_up_k_rows_a_token(monkeypatch):
     assert 3 * R + 2 * S <= few <= 6 * (R + S)
     assert many <= 6 * (R + S) < 3 * 12 * S
     assert many - few < S
+
+
+def _moved(idx, expert, to):
+    """``idx`` with every choice of ``expert`` on expert ``to``."""
+    return np.where(idx == expert, to, idx)
+
+
+# name -> (topk_idx [S, k], first held expert, held experts, buffer rows R or
+# None: every expert of the router's is held)
+ROUTINGS = {
+    "balanced_k4": (_drawn(20, 96, 4, 32), 8, 8, 288),
+    "balanced_k10": (_drawn(21, 64, 10, 64), 0, 8, 192),
+    "k1": (_drawn(22, 80, 1, 8), 2, 4, 64),
+    "every_choice_on_one_expert": (np.full((80, 4), 5), 4, 4, 256),
+    "an_expert_that_gets_nothing": (_moved(_drawn(23, 64, 4, 16), 2, 9), 0, 6, 160),
+    "every_choice_absent": (_drawn(24, 32, 4, 16) % 8 + 8, 0, 8, 64),
+    "held_rows_past_the_buffer": (_drawn(25, 64, 4, 8), 0, 6, 100),
+    "one_expert_past_the_buffer_k10": (np.full((40, 10), 1), 0, 4, 128),
+    "a_buffer_of_every_choice": (_drawn(26, 32, 4, 8), 0, 8, 128),
+    "all_held_64_experts_k8": (_drawn(27, 96, 8, 64), 0, 64, None),
+    "all_held_k1": (_drawn(28, 70, 1, 4), 0, 4, None),
+    "all_held_an_expert_that_gets_nothing": (_drawn(29, 50, 4, 8) % 7, 0, 8, None),
+}
+
+
+def _index_by_a_loop(topk_idx, first, held, R):
+    """The index arrays of one routing, by a loop over the token-choices:
+    each held choice joins its expert's list in the order the choices come,
+    the lists are laid end to end over the positions, and what lies past the
+    buffer is dropped."""
+    S, k = topk_idx.shape
+    lists, absent = [[] for _ in range(held)], []
+    for choice, expert in enumerate(topk_idx.reshape(-1)):
+        (lists[expert - first] if first <= expert < first + held else absent).append(choice)
+    n_held = sum(len(of) for of in lists)
+    P = S * k if R is None else R               # positions
+    order = np.full(P, S * k, np.int64)
+    inverse = np.full(S * k, P, np.int64)
+    group_sizes = np.zeros(held, np.int64)
+    position = 0
+    for expert, of in enumerate(lists):
+        for choice in of:
+            if position < P:
+                order[position], inverse[choice] = choice, position
+                group_sizes[expert] += 1
+                position += 1
+    want = dict(by_expert=np.array([choice for of in lists + [absent] for choice in of]),
+                order=order, inverse=inverse, group_sizes=group_sizes, fit=position, held=n_held)
+    if R is None:
+        return want
+    # the positions in token order, cut into run blocks with their halo
+    H = layer._run_halo(k)
+    B = max(H, min(layer._RUN_BLOCK, -(-R // 8) * 8))
+    nb = R // B + 1
+    by_token = [inverse[choice] for choice in range(S * k) if inverse[choice] < R]
+    by_token += [R] * ((nb + 1) * B - len(by_token))
+    runs = np.array([[by_token[b * B + j] for j in range(B + H)] for b in range(nb)])
+    read, start = np.zeros(S, np.int64), 0
+    for s in range(S):
+        count = sum(inverse[s * k + j] < R for j in range(k))
+        read[s] = start if count else nb * B - 1
+        start += count
+    return dict(want, inverse=inverse.reshape(S, k), runs=runs, read=read)
+
+
+@pytest.mark.parametrize("name", sorted(ROUTINGS))
+def test_the_index_arrays_are_those_of_a_loop_over_the_token_choices(name):
+    topk_idx, first, held, R = ROUTINGS[name]
+    got = jax.jit(lambda t: _route_index(t, held, first, R))(jnp.asarray(topk_idx, jnp.int32))
+    want = _index_by_a_loop(topk_idx, first, held, R)
+    for key, value in want.items():
+        np.testing.assert_array_equal(np.asarray(getattr(got, key)), value, err_msg=key)
+    if R is None:
+        assert got.runs is None and got.read is None
+    S, k = topk_idx.shape
+    on_held = int(((topk_idx >= first) & (topk_idx < first + held)).sum())
+    assert want["held"] == on_held and want["fit"] == min(on_held, S * k if R is None else R)
+    if name == "every_choice_absent":
+        assert want["fit"] == 0
+    if "past_the_buffer" in name:
+        assert want["held"] > R == want["fit"]
+    if "gets_nothing" in name:
+        assert (want["group_sizes"] == 0).any()
+
+
+def _scatters(jaxpr):
+    """(primitive, elements of the updates) of every scatter of a traced program."""
+    return [(eqn.primitive.name, int(np.prod(eqn.invars[2].aval.shape)))
+            for eqn in _equations(jaxpr) if eqn.primitive.name.startswith("scatter")]
+
+
+@pytest.mark.parametrize("buffer", [True, False], ids=["a_ranks_share", "every_expert_held"])
+def test_no_scatter_over_the_token_choices_and_three_sorts_a_forward(buffer):
+    """The mechanism read off the program: value and gradient of one layer hold
+    no ``scatter`` / ``scatter-add`` whose updates are the S * k token-choices
+    or the R positions (a TPU runs those one index at a time) and no lookup of
+    S * k scalars; the forward's sorts are three (by expert, its inverse, and
+    the share's positions by token), the backward's one (the weights' gradient
+    from the positions to the choices: the one sort that carries floats)."""
+    S, R, k = 512, 256, 4
+    traced = _share_program(k, S, R, buffer=buffer)[0]
+    assert [s for s in _scatters(traced.jaxpr) if s[1] >= min(R, S * k)] == []
+    assert [eqn for eqn in _equations(traced.jaxpr) if eqn.primitive.name == "gather"
+            and eqn.outvars[0].aval.size == S * k] == []
+    sorts = _loops(traced.jaxpr, "sort")
+    backward = [eqn for eqn in sorts
+                if any(jnp.issubdtype(v.aval.dtype, jnp.floating) for v in eqn.invars)]
+    forward = [eqn for eqn in sorts if eqn not in backward]
+    assert sorted(eqn.invars[0].aval.shape[0] for eqn in forward) == \
+        ([R] if buffer else []) + [S * k, S * k]
+    assert [eqn.invars[0].aval.shape[0] for eqn in backward] == ([S * k] if buffer else [])
+
+
+@pytest.mark.parametrize("buffer", [True, False], ids=["a_ranks_share", "every_expert_held"])
+def test_biased_experts_add_each_rows_own_experts_bias(buffer):
+    """The bias epilogue reads a row's expert off the group sizes: against
+    ``expert_mlp`` run on one token at a time with its expert's leaves."""
+    S, k, width, n_experts = 48, 2, 16, 8
+    held, R = (4, 64) if buffer else (n_experts, None)
+    params = init_expert_mlp(jax.random.PRNGKey(0), held, width, 24, bias=True)
+    params = {key: (jax.random.normal(jax.random.PRNGKey(i), leaf.shape) if key.startswith("b_")
+                    else leaf) for i, (key, leaf) in enumerate(sorted(params.items()))}
+    xs = jax.random.normal(jax.random.PRNGKey(9), (S, width))
+    topk_idx = _drawn(30, S, k, n_experts)
+    weights = np.random.default_rng(31).random((S, k)).astype(np.float32)
+    got = expert_mlp_ragged(params, xs, jnp.asarray(topk_idx, jnp.int32), jnp.asarray(weights),
+                            buffer_rows=R)[0]
+    want = np.zeros((S, width), np.float32)
+    for s in range(S):
+        for j in range(k):
+            e = topk_idx[s, j]
+            if e < held:
+                one = {key: leaf[e:e + 1] for key, leaf in params.items()}
+                want[s] += weights[s, j] * np.asarray(layer.expert_mlp(one, xs[s][None, None]))[0, 0]
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
 
 
 def test_every_row_pass_is_a_loop_whose_trips_follow_the_rows_held(monkeypatch):
