@@ -170,6 +170,14 @@ class InferenceEngine:
                 "table and keeps one uniform KV pool with no window to evict by; "
                 "nothing fetches experts while attention runs (training through "
                 "sxt.initialize is; ROADMAP R-M3, R-M15)")
+        if getattr(self._mcfg, "loop_steps", 1) > 1:
+            raise NotImplementedError(
+                f"serving a looped stack (loop_steps {self._mcfg.loop_steps}: the layers "
+                "run several times over the same weights, an exit gate a token) is "
+                "not implemented: inference/paged.py keeps one KV block a layer, "
+                "not one a (loop step, layer), and no decode step stops some rows "
+                "early by their cumulated exit mass (training through "
+                "sxt.initialize is; ROADMAP R-M17)")
         if "dsa" in mixers:
             raise NotImplementedError(
                 "serving a learned sparse attention (mixer 'dsa': an indexer that "

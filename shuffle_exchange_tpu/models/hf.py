@@ -60,6 +60,7 @@ _ARCH_FAMILIES = {
     "OlmoHybridForCausalLM": "olmohybrid",
     "GraniteMoeHybridForCausalLM": "granitemoehybrid",
     "SmallThinkerForCausalLM": "smallthinker",
+    "OuroForCausalLM": "ouro",
 }
 
 
@@ -79,6 +80,7 @@ _MODEL_TYPE_FAMILIES = {"llama": "llama", "mistral": "llama", "qwen2": "qwen2",
                         "granitemoehybrid": "granitemoehybrid",
                         "smallthinker": "smallthinker",
                         "KeyeVL2": "keyevl2",
+                        "ouro": "ouro",
                         "megatron": "megatron",
                         "megatron-gpt": "megatron", "megatron_gpt": "megatron"}
 
@@ -669,6 +671,71 @@ def _keyevl2_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerC
         moe_aux="all_choices" if alpha else "none", aux_loss_coef=alpha, **common)
 
 
+def _ouro_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerConfig:
+    """ByteDance's ``model_type: ouro`` as Ouro-2.6B ships it (a looped
+    language model): llama wiring with plain multi-head attention at
+    ``head_dim``, SANDWICH-normed blocks (four gains: ``input_layernorm``,
+    ``input_layernorm_2``, ``post_attention_layernorm``,
+    ``post_attention_layernorm_2``), the stack run ``total_ut_steps`` times
+    over the same weights with the final norm inside the loop
+    (``loop_steps``), and one Linear(D, 1) exit gate with a bias
+    (``model.early_exit_gate``) whose exit distribution weighs the loss at
+    every exit. ``exit_entropy_beta`` (not the source's key: the report's
+    Stage-I beta, 0.1 without it) is the entropy's coefficient.
+    ``early_exit_threshold`` is serving's: the trainer never reads it, and
+    neither inference engine serves a looped stack. What is not written is
+    refused by name."""
+    refused = {
+        "use_sliding_window": bool(cfg.get("use_sliding_window")),
+        "rope_scaling": cfg.get("rope_scaling") is not None,
+        "attention_bias": bool(cfg.get("attention_bias")),
+        "layer_types": any(kind != "full_attention" for kind in (
+            cfg.get("layer_types") or [])[:cfg["num_hidden_layers"]]),
+        "hidden_act": cfg.get("hidden_act", "silu") != "silu",
+    }
+    for key, bad in refused.items():
+        if bad:
+            raise ValueError(
+                f"ouro with {key}={cfg.get(key)!r} is not supported (written down: "
+                "full causal attention in every layer, no window, no RoPE scaling, "
+                "bias-free projections, a SiLU-gated MLP)")
+    steps = int(cfg.get("total_ut_steps", 1))
+    return TransformerConfig(
+        head_size=int(cfg.get("head_dim") or 0), norm_order="sandwich",
+        loop_steps=steps, exit_gate=steps > 1,
+        exit_entropy_coef=float(cfg.get("exit_entropy_beta", 0.1)) if steps > 1 else 0.0,
+        **common)
+
+
+# the source's names of a block's leaves (ouro), each a torch Linear [out, in]
+# or a gain; ``ouro_state_dict`` and ``params_from_state_dict`` both read it
+_OURO_BLOCK = {
+    "ln1_w": "input_layernorm.weight", "ln1_post_w": "input_layernorm_2.weight",
+    "ln2_w": "post_attention_layernorm.weight",
+    "ln2_post_w": "post_attention_layernorm_2.weight",
+    "wq": "self_attn.q_proj.weight", "wk": "self_attn.k_proj.weight",
+    "wv": "self_attn.v_proj.weight", "wo": "self_attn.o_proj.weight",
+    "w_gate": "mlp.gate_proj.weight", "w_up": "mlp.up_proj.weight",
+    "w_down": "mlp.down_proj.weight"}
+
+
+def ouro_state_dict(params: Dict[str, Any], config: TransformerConfig) -> Dict[str, Any]:
+    """The program's tree of an ``ouro`` model -> a flat dict under the
+    source's names, each tensor as torch stores it (a matrix [out, in]; the
+    gate a Linear(D, 1): weight [1, D], bias [1]): ``params_from_state_dict``
+    back. The unused bias leaves of the plain RMSNorms are not exported."""
+    out = {"model.embed_tokens.weight": params["embed"],
+           "model.norm.weight": params["ln_f_w"],
+           "lm_head.weight": params["unembed"].T,
+           "model.early_exit_gate.weight": params["exit_gate_w"][None, :],
+           "model.early_exit_gate.bias": params["exit_gate_b"].reshape(1)}
+    for i in range(config.n_layers):
+        for leaf, name in _OURO_BLOCK.items():
+            x = params["layers"][leaf][i]
+            out[f"model.layers.{i}.{name}"] = x.T if x.ndim == 2 else x
+    return out
+
+
 def config_from_hf(hf_config) -> TransformerConfig:
     """Map an HF config object/dict to a TransformerConfig."""
     cfg = hf_config if isinstance(hf_config, dict) else hf_config.to_dict()
@@ -1005,6 +1072,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
         return _keyevl2_config(cfg, common)
     if family == "lfm2moe":
         return _lfm2_config(cfg, common)
+    if family == "ouro":
+        return _ouro_config(cfg, common)
     if family == "mixtral":
         return TransformerConfig(
             n_experts=cfg["num_local_experts"], moe_top_k=cfg.get("num_experts_per_tok", 2),
@@ -1519,6 +1588,19 @@ def params_from_state_dict(sd: Dict[str, Any], config: TransformerConfig,
         if not config.tie_embeddings:
             # --untie-embeddings-and-output-weights
             p["unembed"] = _np(sd["output_layer.weight"])[:config.vocab_size].T
+        return p
+
+    if family == "ouro":
+        layers = {leaf: _stack(sd, "layers.{}." + name, L, transpose=name.endswith("proj.weight"))
+                  for leaf, name in _OURO_BLOCK.items()}
+        layers["ln1_b"] = np.zeros_like(layers["ln1_w"])    # rmsnorm: tree parity
+        layers["ln2_b"] = np.zeros_like(layers["ln2_w"])
+        p = {"embed": _np(sd["embed_tokens.weight"]), "layers": layers,
+             "ln_f_w": _np(sd["norm.weight"]), "unembed": _np(sd["lm_head.weight"]).T}
+        p["ln_f_b"] = np.zeros_like(p["ln_f_w"])
+        if config.exit_gate:
+            p["exit_gate_w"] = _np(sd["early_exit_gate.weight"]).reshape(-1)
+            p["exit_gate_b"] = _np(sd["early_exit_gate.bias"]).reshape(())
         return p
 
     # rope/rmsnorm families: llama / mistral / qwen2 / phi3 / mixtral / internlm / olmoe

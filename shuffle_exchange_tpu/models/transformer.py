@@ -211,14 +211,36 @@ class TransformerConfig:
     # lets them reach (-1, 1) (FLA's ``allow_neg_eigval``, Olmo Hybrid's
     # ``linear_allow_neg_eigval``).
     gdn_beta_scale: float = 1.0
-    # Where a block norms: "input" (pre-norm, ``h + mix(norm(h))``) or
+    # Where a block norms: "input" (pre-norm, ``h + mix(norm(h))``),
     # "output" (the Olmo 2 / 3 order: ``h + norm(mix(h))``, ``h +
-    # norm(ffn(h))``, nothing normed on the way in). The same gains (``ln1_w``
-    # / ``ln2_w``) and scopes (``attn_norm`` / ``mlp_norm``) either way.
+    # norm(ffn(h))``, nothing normed on the way in) or "sandwich" (both at
+    # once, Ouro's: ``h + norm(mix(norm(h)))``, ``h + norm(ffn(norm(h)))``).
+    # The same gains (``ln1_w`` / ``ln2_w``) and scopes (``attn_norm`` /
+    # ``mlp_norm``) in the first two; a sandwich keeps them for the way in
+    # and has two more for the way out (``ln1_post_w`` / ``ln2_post_w``),
+    # under the same scopes.
     # (``post_ln`` is BERT's ``norm(h + f(h))`` and ``parallel_block`` GPT-J's
     # ``h + mix(norm(h)) + ffn(norm(h))``: a block has ONE of the forms,
     # ``Transformer.__init__``.)
     norm_order: str = "input"
+    # A stack that runs several times over the SAME weights (Ouro's
+    # ``total_ut_steps``): ``h_t = final_norm(stack(h_{t-1}))`` for t = 1 ..
+    # ``loop_steps``, the final norm INSIDE the loop (what the next step, the
+    # head and the gate read); 1 = once, the final norm in the head: every
+    # model before PR 64. ``Transformer.loop_apply`` is the outer scan; a
+    # layer's weight gradient is the sum over its ``loop_steps`` visits.
+    loop_steps: int = 1
+    # The exits of a looped stack (``loop_steps`` > 1). Without a gate the
+    # loss is the last exit's. ``exit_gate``: one Linear(D, 1) WITH bias
+    # (``exit_gate_w`` [D], ``exit_gate_b`` []) reads every exit's stream,
+    # ``lam_t = sigmoid(.)``, and each token leaves at exit t with
+    # ``p_t = lam_t prod_{j<t} (1 - lam_j)`` (the last exit takes what is
+    # left; its own lam is not used). The loss is then the mean over tokens
+    # of ``sum_t p_t CE_t - exit_entropy_coef x H(p)`` (the report's Stage-I
+    # objective: the expected task loss under the exit distribution, less
+    # ``beta`` x its entropy), in float32, gradients through p AND CE.
+    exit_gate: bool = False
+    exit_entropy_coef: float = 0.0
     sconv_taps: int = 3                        # mixer "sconv": the convolution's taps
     # mixer "ssm": ``ssm_heads`` heads of ``ssm_head_dim`` channels (the inner
     # width is their product, whatever d_model is), B and C of ``ssm_groups``
@@ -860,20 +882,34 @@ class Transformer:
 
     def __init__(self, config: TransformerConfig):
         self.config = config
-        if config.norm_order not in ("input", "output"):
-            raise ValueError(f"norm_order is 'input' or 'output'; got {config.norm_order!r}")
+        if config.norm_order not in ("input", "output", "sandwich"):
+            raise ValueError("norm_order is 'input', 'output' or 'sandwich'; got "
+                             f"{config.norm_order!r}")
+        if config.loop_steps < 1 or (config.exit_gate and config.loop_steps == 1):
+            raise ValueError(f"loop_steps is >= 1 (got {config.loop_steps}) and an "
+                             "exit_gate is a looped stack's (loop_steps > 1)")
+        if config.loop_steps > 1 and (config.post_ln or config.mlm_head or config.n_experts > 0
+                                      or config.recurrent):
+            raise NotImplementedError(
+                "a looped stack (loop_steps > 1) runs the final norm inside the loop "
+                "and counts nothing a visit: a post_ln / mlm_head encoder has no "
+                "final norm, routed experts' counters have a row a layer, not a row "
+                "a (loop step, layer), and the state-space / DeltaNet scans' chunk "
+                "counters count one pass")
         if config.moe_router_input not in ("ffn", "block"):
             raise ValueError("moe_router_input is 'ffn' or 'block'; got "
                              f"{config.moe_router_input!r}")
         forms = [name for name, on in (("post_ln", config.post_ln),
                                        ("parallel_block", config.parallel_block),
-                                       ("norm_order='output'", config.norm_order == "output"))
+                                       (f"norm_order={config.norm_order!r}",
+                                        config.norm_order != "input"))
                  if on]
         if len(forms) > 1:
             raise ValueError(
                 "a block norms its sublayers' input (in sequence, or in parallel: "
-                "parallel_block), their output (norm_order 'output') or the sum "
-                f"(post_ln), one of them; this configuration sets {' and '.join(forms)}")
+                "parallel_block), their output (norm_order 'output'), both (norm_order "
+                "'sandwich') or the sum (post_ln), one of them; this configuration "
+                f"sets {' and '.join(forms)}")
 
     # -- parameters ----------------------------------------------------
 
@@ -940,6 +976,11 @@ class Transformer:
             params["unembed"] = jax.random.normal(next(keys), (D, cfg.vocab_size), jnp.float32) * 0.02
             if cfg.unembed_bias:
                 params["unembed_b"] = jnp.zeros((cfg.vocab_size,))
+        if cfg.exit_gate:
+            # from a key of its own: the other leaves' draws do not move with it
+            params["exit_gate_w"] = jax.random.normal(
+                jax.random.fold_in(rng, 0xE817), (D,), jnp.float32) * 0.02
+            params["exit_gate_b"] = jnp.zeros(())
         return params
 
     def kinds(self) -> Dict[str, Tuple[str, str]]:
@@ -981,6 +1022,12 @@ class Transformer:
         layer = {"ln1_w": gain(D)}
         if biased_norm:
             layer["ln1_b"] = zeros(D)
+        sandwich = cfg.norm_order == "sandwich"
+        if sandwich:
+            # the way out of the mixer half (a plain gain, no unused bias leaf)
+            layer["ln1_post_w"] = gain(D)
+            if cfg.norm == "layernorm":
+                layer["ln1_post_b"] = zeros(D)
         if mixer == "gdn":
             Hk, Hv = cfg.gdn_key_heads, cfg.gdn_value_heads
             dk, dv = cfg.gdn_key_dim, cfg.gdn_value_dim
@@ -1092,6 +1139,10 @@ class Transformer:
             layer["ln2_w"] = gain(D)
             if biased_norm:
                 layer["ln2_b"] = zeros(D)
+        if sandwich:
+            layer["ln2_post_w"] = gain(D)
+            if cfg.norm == "layernorm":
+                layer["ln2_post_b"] = zeros(D)
         if ffn == "moe":
             import jax.random as jrandom
 
@@ -1308,6 +1359,7 @@ class Transformer:
                "dsa": self._dsa}[mixer]
         # where the block norms (``Transformer.__init__`` admits one form):
         # each sublayer's input (pre-LN), its output (the Olmo 2 / 3 order),
+        # both (Ouro's sandwich: the input's gain and a second, ``ln*_post_w``),
         # the sum (BERT's post-LN), or the input of a parallel block (GPT-J /
         # NeoX / Falcon: h + mix(ln1 h) + ffn(ln2 h, or ln1 h again)
         place = ("parallel" if cfg.parallel_block else "sum" if cfg.post_ln
@@ -1323,9 +1375,9 @@ class Transformer:
         block_router = cfg.moe_router_input == "block" and ffn == "moe"
         shared = place == "parallel" and cfg.parallel_shared_ln and ffn != "none"
 
-        def normed(lw, x, n, scope):
+        def normed(lw, x, n, scope, post=""):
             with trace.scope(scope):
-                return _norm(x, lw[f"ln{n}_w"], lw.get(f"ln{n}_b", 0), cfg.norm,
+                return _norm(x, lw[f"ln{n}{post}_w"], lw.get(f"ln{n}{post}_b", 0), cfg.norm,
                              eps=cfg.norm_eps)
 
         def add(h, out, scope):
@@ -1349,12 +1401,15 @@ class Transformer:
                 "indexer's loss ride with the router's stats")
 
         def mixer_half(lw, h):
-            y = normed(lw, h, 1, "attn_norm") if place in ("input", "parallel") else h
+            y = (normed(lw, h, 1, "attn_norm") if place in ("input", "parallel", "sandwich")
+                 else h)
             out = mix(lw, y, rope)
             if sparse:
                 out, found = out
             if place == "output":
                 out = normed(lw, out, 1, "attn_norm")
+            elif place == "sandwich":
+                out = normed(lw, out, 1, "attn_norm", "_post")
             h = add(h, out, "attn_out")
             if place == "sum":
                 h = normed(lw, h, 1, "attn_norm")
@@ -1366,14 +1421,16 @@ class Transformer:
             if place == "parallel":
                 y2 = normed(lw, x, 2, "mlp_norm") if y2 is None else y2
             else:
-                y2 = normed(lw, h, 2, "mlp_norm") if place == "input" else h
+                y2 = normed(lw, h, 2, "mlp_norm") if place in ("input", "sandwich") else h
             with trace.scope("moe" if ffn == "moe" else "mlp"):
                 ff, aux, stats = self._ffn(lw, y2, moe_on, ffn,
                                            router_x=x if block_router else None)
-                if place != "output":
+                if place not in ("output", "sandwich"):
                     h = add(h, ff, "mlp")
             if place == "output":
                 h = add(h, normed(lw, ff, 2, "mlp_norm"), "mlp")
+            elif place == "sandwich":
+                h = add(h, normed(lw, ff, 2, "mlp_norm", "_post"), "mlp")
             return normed(lw, h, 2, "mlp_norm") if place == "sum" else h, aux, stats
 
         if remat_halves:
@@ -2161,10 +2218,14 @@ class Transformer:
     def stack_apply(self, stacked_layers, x, rope, ltd_mask=None,
                     layer_keep=None, layer_ids=None, with_stats=False,
                     lead=None):
-        """Scan the (sub)stack of layers over x. Returns (x, summed aux), or
+        """Scan the (sub)stack of layers over x, ONCE (a looped stack,
+        ``cfg.loop_steps`` > 1, calls this once a step from ``loop_apply``'s
+        outer scan, over the same leaves). Returns (x, summed aux), or
         with ``with_stats`` (x, summed aux, the ROUTED layers' router stats
         stacked [routed layers, E], None for a dense model; a dense layer
-        among routed ones routes nothing and has no row).
+        among routed ones routes nothing and has no row). A block norms where
+        ``cfg.norm_order`` says ("input", "output" on the several-kinds path,
+        "sandwich": ``layer_apply``).
 
         ``lead``: the leading layers' parameters (``params["lead"]``, stacked
         [lead_layers, ...]) of a model that has them (``cfg.lead_layers``):
@@ -2185,6 +2246,13 @@ class Transformer:
         import jax.numpy as jnp
 
         cfg = self.config
+        if layer_ids is not None and cfg.loop_steps > 1:
+            raise NotImplementedError(
+                f"a looped stack (loop_steps {cfg.loop_steps}) under pipeline stages "
+                "(layer_ids) is not implemented: the stream has to go round the "
+                "stages loop_steps times (a ring) with the final norm after the "
+                "last stage of each round, and the pipe schedule hands each stage "
+                "its layers once (ROADMAP R-M17)")
         # Sequence-parallel activation layout: pin hidden states to
         # [batch over data+fsdp, seq over "seq"] so per-token compute and
         # activation memory split across the seq axis (the attention inside
@@ -2345,6 +2413,69 @@ class Transformer:
         aux = jnp.sum(aux_losses)
         return (x, aux, stats) if with_stats else (x, aux)
 
+    def loop_apply(self, params, x, rope):
+        """A looped stack (``cfg.loop_steps`` = T > 1): ``h_0 = x``, ``h_t =
+        final_norm(stack(h_{t-1}))`` over the SAME ``params["layers"]`` at
+        every t. Returns (exits [T, B, S, D], the T NORMED streams: what the
+        next step took in and what the head and the gate read; summed aux).
+
+        ONE outer ``lax.scan`` of T trips around ``stack_apply``'s scan: the
+        program holds one block's text. ``rope`` (``embed``'s) is built once,
+        outside both scans, and is the same table at every step. Each (step,
+        layer) visit is checkpointed as ``stack_apply`` checkpoints a layer
+        (``cfg.remat``): what a visit keeps for the backward, its input under
+        policy "full", is stacked [T, L, B, S, D] by the two scans, T x a
+        plain stack's, and the backward replays T x L blocks (and, under
+        ``cfg.remat``, the T final norms, which keep their input). The layers are
+        closed over by the outer body, so a layer's weight gradient is summed
+        over its T visits in the outer scan's cotangent carry, in the dtype
+        the leaves have HERE (an engine hands bf16 copies: a bf16 sum of T
+        bf16 gradients). Scopes: ``loop`` (the outer scan's own work: the
+        carry, slicing and stacking what the visits keep, the gradient sums),
+        ``loop_norm`` (the final norm, every pass)."""
+        import jax
+
+        cfg = self.config
+
+        def final_norm(h):
+            with trace.scope("loop_norm"):
+                return _norm(h, params["ln_f_w"], params["ln_f_b"], cfg.norm, eps=cfg.norm_eps)
+
+        if cfg.remat:
+            # outside the layers' checkpoints: kept as it is, the norm holds its
+            # float32 copy of the stream for the backward (B x S x D x 4 bytes a
+            # step); replayed, the stream as it arrived (half of that)
+            final_norm = jax.checkpoint(final_norm)
+
+        def step(h, _):
+            h, aux = self.stack_apply(params["layers"], h, rope, **self._lead_of(params))
+            h = final_norm(h)
+            return h, (h, aux)
+
+        with trace.scope("loop"):
+            _, (exits, aux) = jax.lax.scan(step, x, None, length=cfg.loop_steps)
+        return exits, aux.sum()
+
+    def exit_distribution(self, params, exits):
+        """The exit gate on the T normed streams ``exits`` [T, ..., D] ->
+        (p [T, ...], H [...]) in float32: ``lam_t = sigmoid(w_g . h_t +
+        b_g)``, ``p_t = lam_t prod_{j<t} (1 - lam_j)`` for t < T, ``p_T =
+        prod_{j<T} (1 - lam_j)`` (what is left; ``lam_T`` is not used), and the
+        entropy ``H = -sum_t p_t log p_t``. Formed from log-sigmoids, so that
+        a saturated gate gives p = 0 and 0 log 0 = 0, not NaN."""
+        import jax
+        import jax.numpy as jnp
+
+        f32 = jnp.float32
+        z = jnp.einsum("t...d,d->t...", exits.astype(f32), params["exit_gate_w"].astype(f32),
+                       precision=jax.lax.Precision.HIGHEST)
+        z = z + params["exit_gate_b"].astype(f32)
+        log_stay = jnp.cumsum(jax.nn.log_sigmoid(-z[:-1]), axis=0)   # log prod_{j<=t} (1 - lam_j)
+        before = jnp.concatenate([jnp.zeros_like(z[:1]), log_stay[:-1]])
+        log_p = jnp.concatenate([jax.nn.log_sigmoid(z[:-1]) + before, log_stay[-1:]])
+        p = jnp.exp(log_p)
+        return p, -jnp.sum(p * jnp.where(p > 0, log_p, 0.0), axis=0)
+
     def _unembed(self, params, dtype):
         """Single source of truth for the unembed projection: (w [D, V],
         bias [V] fp32 or None). Bias exists only on the untied path
@@ -2357,8 +2488,10 @@ class Transformer:
                 if self.config.unembed_bias else None)
         return params["unembed"].astype(dtype), bias
 
-    def head(self, params, x):
+    def head(self, params, x, normed=False):
         """Final norm + unembed: x [.., T, D] -> logits [.., T, vocab] fp32.
+        ``normed``: x is normed already (a looped stack's exits: the loop has
+        applied the final norm).
 
         The unembed matmul keeps operands in the compute dtype and
         accumulates in fp32 (``preferred_element_type``): on TPU a bf16
@@ -2369,7 +2502,7 @@ class Transformer:
         import jax.numpy as jnp
 
         cfg = self.config
-        if not cfg.post_ln:
+        if not cfg.post_ln and not normed:
             with trace.scope("final_norm"):
                 x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm,
                           eps=cfg.norm_eps)
@@ -2394,11 +2527,21 @@ class Transformer:
         import jax
         import jax.numpy as jnp
 
+        nll, mask = Transformer.token_nll(logits, labels)
+        return nll.sum(), mask.sum()
+
+    @staticmethod
+    def token_nll(logits, labels):
+        """(nll [..] float32 per row, 0 where ignored; mask [..]): ``token_loss``
+        before its sums."""
+        import jax
+        import jax.numpy as jnp
+
         logp = jax.nn.log_softmax(logits, axis=-1)
         mask = (labels >= 0)
         safe_labels = jnp.where(mask, labels, 0)
         nll = -jnp.take_along_axis(logp, safe_labels[..., None], axis=-1)[..., 0]
-        return (nll * mask).sum(), mask.sum()
+        return nll * mask, mask
 
     def _pad_vocab(self) -> bool:
         """Pad the unembed to a 128-multiple vocab inside the chunked loss?
@@ -2445,9 +2588,17 @@ class Transformer:
         """
         return self._chunked_loss(params, x, labels, chunk)[:2]
 
-    def _chunked_loss(self, params, x, labels, chunk):
-        """:meth:`chunked_loss` and, third, (chunks, rows a chunk) of the
-        scan as the device that runs it sees them."""
+    def _chunked_loss(self, params, x, labels, chunk, weights=None, normed=False):
+        """:meth:`chunked_loss` and, last, (chunks, rows a chunk) of the
+        scan as the device that runs it sees them. ``normed``: x is normed
+        already (a looped stack's exits) and the scan applies no final norm.
+        ``weights`` [B, T] float32 (a gated looped stack's exit distribution,
+        x then holding loop_steps x the batch's rows): each row's loss is
+        weighed by its own, the weights get the rows' losses for a cotangent,
+        and the sums are (the weighed sum, the count, the rows' losses [B, T],
+        which carry no gradient). That scan runs OUTSIDE the ZeRO region (its
+        rows' losses are no sum over devices): on a mesh XLA's partitioner
+        places the head's gather."""
         from jax.sharding import PartitionSpec
 
         from ..parallel import mesh as mesh_lib
@@ -2458,12 +2609,13 @@ class Transformer:
         scanned = []        # noted while the scan is traced, in the region or not
 
         def scan(head, grad_acc, x, labels):
-            *sums, shape = self._chunked_loss_scan(head, grad_acc, x, labels, chunk)
+            *sums, shape = self._chunked_loss_scan(head, grad_acc, x, labels, chunk,
+                                                   weights, normed)
             scanned.append(shape)
             return tuple(sums)
 
         zero_axes = mesh_lib.zero_batch_axes(x.shape[0])
-        if zero_axes:
+        if zero_axes and weights is None:
             sums = mesh_lib.zero_region(scan, (unembed,), zero_axes)(head, x, labels)
         else:
             # no axis to gather over: the stand-in only casts the sum
@@ -2471,10 +2623,12 @@ class Transformer:
             sums = scan(head, {unembed: whole}, x, labels)
         return (*sums, scanned[0])
 
-    def _chunked_loss_scan(self, params, grad_acc, x, labels, chunk):
+    def _chunked_loss_scan(self, params, grad_acc, x, labels, chunk, weights=None,
+                           normed=False):
         """:meth:`chunked_loss` on whole weights and the rows at hand.
         ``grad_acc``: a float32 stand-in for the unembed's leaf, to whose
-        cotangent the scan's float32 sum of the weight's gradient goes."""
+        cotangent the scan's float32 sum of the weight's gradient goes.
+        ``weights`` / ``normed``: :meth:`_chunked_loss`'s."""
         import jax.numpy as jnp
 
         cfg = self.config
@@ -2507,11 +2661,17 @@ class Transformer:
                 extra = extra + jnp.pad(bias, (0, vpad))
         elif bias is not None:
             extra = bias
-        nll_sum, cnt = _head_scan(
-            cfg.norm, cfg.norm_eps, bias is not None,
-            params["ln_f_w"], params["ln_f_b"], w, w_acc, extra, xc, lc,
-            divisor=cfg.logit_divisor)
-        return nll_sum, cnt, (n_chunks, B * chunk)
+        ln = (None, None) if normed else (params["ln_f_w"], params["ln_f_b"])
+        wc = None
+        if weights is not None:
+            wc = jnp.pad(weights.astype(jnp.float32), ((0, 0), (0, pad))
+                         ).reshape(B, n_chunks, chunk).transpose(1, 0, 2)
+        nll_sum, cnt, *rows = _head_scan(
+            cfg.norm, cfg.norm_eps, bias is not None, *ln, w, w_acc, extra, xc, lc,
+            divisor=cfg.logit_divisor, wc=wc)
+        # the rows' losses back in the rows' order, the pad dropped
+        rows = [r.transpose(1, 0, 2).reshape(B, n_chunks * chunk)[:, :T] for r in rows]
+        return (nll_sum, cnt, *rows, (n_chunks, B * chunk))
 
     def _vocab_pad(self) -> int:
         """Columns the chunked loss adds to reach the 128 lane tile."""
@@ -2520,7 +2680,11 @@ class Transformer:
     def _loss_chunk(self, B: int, T: int) -> int:
         """Positions a chunk for a loss over ``B`` sequences of ``T``
         positions; 0 = full logits. ``loss_chunk`` >= 0 is that, in
-        positions; auto sizes the chunk by its rows (``auto_loss_chunk``)."""
+        positions; auto sizes the chunk by its rows (``auto_loss_chunk``). A
+        gated looped stack asks with ``B`` = loop_steps x the batch's
+        sequences (``_exit_loss``: every exit's rows in ONE scan), so its
+        chunk holds a loop_steps-th of the positions a plain model's would
+        and ``loss_rows`` counts loop_steps x the tokens."""
         if self.config.post_ln or self.config.mlm_head:
             # chunked_loss runs ln_f + plain unembed per chunk; the encoder
             # head shape (no final norm / MLM transform) isn't wired there
@@ -2545,9 +2709,21 @@ class Transformer:
     def apply_with_aux(self, params, input_ids, ltd_mask=None, layer_keep=None):
         """Returns (logits, moe_aux_loss) — aux is 0 for dense models."""
         x, rope = self.embed(params, input_ids)
+        if self.config.loop_steps > 1:
+            # a looped stack: the LAST exit's logits (``loop_apply`` hands out
+            # all of them)
+            self._plain_stack_only(ltd_mask, layer_keep)
+            exits, aux = self.loop_apply(params, x, rope)
+            return self.head(params, exits[-1], normed=True), aux
         x, aux = self.stack_apply(params["layers"], x, rope, ltd_mask=ltd_mask,
                                   layer_keep=layer_keep, **self._lead_of(params))
         return self.head(params, x), aux
+
+    def _plain_stack_only(self, ltd_mask, layer_keep):
+        if ltd_mask is not None or layer_keep is not None:
+            raise NotImplementedError(
+                "a looped stack (loop_steps > 1) runs the plain stack only: random-LTD "
+                "and progressive layer drop pick layers of ONE pass")
 
     def update_buffers(self, old, new, stats):
         """The masters after a step, given those before it (``old``), the
@@ -2626,7 +2802,10 @@ class Transformer:
         ``dsa_selected_min`` / ``dsa_selected_max`` (the keys a query past
         position ``dsa_topk`` - 2 holds), ``dsa_pairs`` (the (t, s) chosen),
         ``dsa_block_visit_share`` and ``dsa_tied_chunks`` (the chunks of the
-        step, over all layers, whose tie rule ran its search);
+        step, over all layers, whose tie rule ran its search). A looped stack
+        (``loop_steps`` > 1) gives ``loop_layer_visits`` (loop_steps x layers,
+        static) and, gated, ``_exit_loss``'s four (and ``loss_rows`` then counts
+        loop_steps x the batch's rows: the head reads every exit);
         ``batch["position_ids"]`` [3, B, T] are M-RoPE's streams (``mrope_section``; absent: text, 0..T-1 in each)."""
         import jax.numpy as jnp
 
@@ -2667,10 +2846,18 @@ class Transformer:
         positions = batch.get("position_ids")      # M-RoPE's streams [3, B, T]
         x, rope = self.embed(params, model_ids,
                              None if positions is None else positions[..., :T])
-        x, aux, routed = self.stack_apply(params["layers"], x, rope,
-                                          ltd_mask=ltd_mask, layer_keep=layer_keep,
-                                          with_stats=True, **self._lead_of(params))
+        loops = cfg.loop_steps
         stats = {}
+        if loops > 1:
+            self._plain_stack_only(ltd_mask, layer_keep)
+            exits, aux = self.loop_apply(params, x, rope)
+            routed = None
+            # static: the blocks a token's forward pass goes through
+            stats["loop_layer_visits"] = jnp.asarray(loops * cfg.n_layers, jnp.int32)
+        else:
+            x, aux, routed = self.stack_apply(params["layers"], x, rope,
+                                              ltd_mask=ltd_mask, layer_keep=layer_keep,
+                                              with_stats=True, **self._lead_of(params))
         if cfg.ssm_layers:
             from ..ops.ssd import ssd_chunks
 
@@ -2709,20 +2896,71 @@ class Transformer:
             stats.update({name: x for name, x in routed.items() if name.startswith("dsa_")})
             if "dsa_tied_chunks" in stats:
                 stats["dsa_tied_chunks"] = stats["dsa_tied_chunks"].sum()
+        if loops > 1 and cfg.exit_gate:
+            with trace.scope("loss"):
+                return self._exit_loss(params, exits, labels, aux, stats)
+        if loops > 1:
+            x = exits[-1]           # no gate: the loss is the last exit's
         with trace.scope("loss"):
             if self._loss_chunk(B, T):
                 # sized again on the rows the device that scans them holds
-                nll_sum, count, scanned = self._chunked_loss(params, x, labels, None)
+                nll_sum, count, scanned = self._chunked_loss(params, x, labels, None,
+                                                             normed=loops > 1)
                 stats["loss_chunks"] = jnp.asarray(scanned[0], jnp.int32)
                 stats["loss_rows"] = jnp.asarray(scanned[0] * scanned[1], jnp.int32)
             else:
-                nll_sum, count = self.token_loss(self.head(params, x), labels)
+                nll_sum, count = self.token_loss(
+                    self.head(params, x, normed=loops > 1), labels)
             ce = nll_sum / jnp.maximum(count, 1)
             loss = ce + cfg.aux_loss_coef * aux
             if "dsa_kl" in stats:
                 # the indexer's own: the mean over layers (and, in each, tokens)
                 loss = loss + stats["dsa_kl"].mean()
             return loss, stats
+
+    def _exit_loss(self, params, exits, labels, aux, stats):
+        """A gated looped stack's loss (``TransformerConfig.exit_gate``) from
+        its T normed exits [T, B, S, D]: the mean over the tokens that count of
+        ``sum_t p_t CE_t - exit_entropy_coef x H(p)``, float32. The head reads
+        all T exits in ONE pass over T x B x S rows (one scan of the chunked
+        loss, each row weighed by its ``p_t``, which gets ``CE_t`` for a
+        cotangent: ``_head_scan``; or the full logits where the loss does not
+        chunk), the final norm applied already. Adds to ``stats``:
+        ``loop_exit_mass`` [T] (mean p_t), ``loop_exit_ce`` [T] (mean CE_t),
+        ``loop_exit_entropy`` (mean H), ``loop_expected_steps`` (mean of sum_t
+        t p_t), and with a chunked loss ``loss_chunks`` / ``loss_rows``, which
+        count the T x B x S rows."""
+        import jax
+        import jax.numpy as jnp
+
+        cfg = self.config
+        T, B, S, D = exits.shape
+        with trace.scope("loop_exit"):
+            p, entropy = self.exit_distribution(params, exits)        # [T, B, S], [B, S]
+        labels_t = jnp.broadcast_to(labels, (T, B, S))
+        if self._loss_chunk(T * B, S):
+            weighed, _, nll, scanned = self._chunked_loss(
+                params, exits.reshape(T * B, S, D), labels_t.reshape(T * B, S), None,
+                weights=p.reshape(T * B, S), normed=True)
+            stats["loss_chunks"] = jnp.asarray(scanned[0], jnp.int32)
+            stats["loss_rows"] = jnp.asarray(scanned[0] * scanned[1], jnp.int32)
+            nll = nll.reshape(T, B, S)
+        else:
+            nll, _ = self.token_nll(self.head(params, exits, normed=True), labels_t)
+            with trace.scope("loop_exit"):
+                weighed = jnp.sum(p * nll)
+        with trace.scope("loop_exit"):
+            mask = (labels >= 0).astype(jnp.float32)
+            count = jnp.maximum(mask.sum(), 1.0)
+            mean = lambda a: jnp.sum(a * mask, axis=(-2, -1)) / count
+            loss = (weighed / count - cfg.exit_entropy_coef * mean(entropy)
+                    + cfg.aux_loss_coef * aux)
+            steps = jnp.arange(1, T + 1, dtype=jnp.float32)[:, None, None]
+            stats.update(jax.lax.stop_gradient({
+                "loop_exit_mass": mean(p), "loop_exit_ce": mean(nll),
+                "loop_exit_entropy": mean(entropy),
+                "loop_expected_steps": mean(jnp.sum(steps * p, axis=0))}))
+        return loss, stats
 
 
 # The float32 logits one chunk of the loss scan may hold, in bytes: what sizes
@@ -2758,55 +2996,74 @@ def _head_logits(xn, w, extra, divisor=1.0):
         return logits if divisor == 1.0 else logits / divisor
 
 
-def _head_scan(kind, eps, biased, ln_w, ln_b, w, w_acc, extra, xc, lc, divisor=1.0):
+def _head_scan(kind, eps, biased, ln_w, ln_b, w, w_acc, extra, xc, lc, divisor=1.0,
+               wc=None):
     """(nll_sum, count) of final norm + unembed + CE over the chunks ``xc``
     [n, B, c, D], ``lc`` [n, B, c]; ``w`` [D, Vp] in the compute dtype,
     ``extra`` [Vp] float32 (bias and pad mask) or None. ``kind``, ``eps``: the
-    final norm's; ``biased``: ``extra`` holds a bias that wants a gradient;
+    final norm's (``ln_w`` None: the rows are normed already, a looped
+    stack's exits, and no norm is applied); ``biased``: ``extra`` holds a bias
+    that wants a gradient;
     ``divisor``: what the logits are divided by (``logit_divisor``; 1 = as
     they are), and with them their cotangent on its way to dx and dw.
+    ``wc`` [n, B, c] float32: per-row weights (a gated looped stack's exit
+    distribution over T x the batch's rows). The result is then (the sum of
+    weight x nll, the count, the rows' own nll [n, B, c], 0 where ignored),
+    the weights get ``nll x mask`` (x the sum's cotangent) for a gradient,
+    and the rows' losses are statistics: no gradient goes through them.
 
     Not under ``grad``: the plain scan of norm, logits and ``token_loss``.
     Under ``grad`` (a ``custom_vjp``) the forward scan does the head's whole
     work once a chunk: from the same logits it takes ``dlogits = (softmax -
-    onehot) * mask``, unscaled, and with it ``dx`` (stacked, the scan's
-    output) and the sums of the weights' gradients in float32 carries. The
-    backward rule only multiplies them by ``nll_sum``'s cotangent. ``w`` gets
-    no cotangent: its gradient [D, Vp] float32 is ``w_acc``'s, a stand-in
-    that hands it to the leaf (``parallel.mesh.grad_accumulator``)."""
+    onehot) * mask`` (x the row's weight), unscaled, and with it ``dx``
+    (stacked, the scan's output) and the sums of the weights' gradients in
+    float32 carries. The backward rule only multiplies them by ``nll_sum``'s
+    cotangent. ``w`` gets no cotangent: its gradient [D, Vp] float32 is
+    ``w_acc``'s, a stand-in that hands it to the leaf
+    (``parallel.mesh.grad_accumulator``). Without ``wc`` and with a norm the
+    traced program is what it was before either existed, op for op."""
     import jax
     import jax.numpy as jnp
 
     f32 = jnp.float32
+    normed = ln_w is None
+    weighed = wc is not None
 
     def norm(x, ln_w, ln_b):
         with trace.scope("final_norm"):
             return _norm(x, ln_w, ln_b, kind, eps=eps)
 
-    def primal(ln_w, ln_b, w, w_acc, extra, xc, lc):
+    def primal(ln_w, ln_b, w, w_acc, extra, xc, lc, wc):
         def body(carry, xl):
-            xch, lch = xl
-            nll, cnt = Transformer.token_loss(
-                _head_logits(norm(xch, ln_w, ln_b), w, extra, divisor), lch)
+            xch, lch, *wch = xl
+            logits = _head_logits(xch if normed else norm(xch, ln_w, ln_b), w, extra, divisor)
+            if weighed:
+                nll, mask = Transformer.token_nll(logits, lch)
+                return (carry[0] + (wch[0] * nll).sum(), carry[1] + mask.sum()), nll
+            nll, cnt = Transformer.token_loss(logits, lch)
             return (carry[0] + nll, carry[1] + cnt), None
 
-        return jax.lax.scan(body, (jnp.zeros((), f32), jnp.zeros((), jnp.int32)),
-                            (xc, lc))[0]
+        sums, rows = jax.lax.scan(body, (jnp.zeros((), f32), jnp.zeros((), jnp.int32)),
+                                  (xc, lc, wc) if weighed else (xc, lc))
+        return (*sums, rows) if weighed else sums
 
-    def fwd(ln_w, ln_b, w, w_acc, extra, xc, lc):
+    def fwd(ln_w, ln_b, w, w_acc, extra, xc, lc, wc):
         # float32 norm weights (what ``_norm`` computes with), so that the
         # norm's vjp hands their gradients out unrounded
-        ln32 = (ln_w.astype(f32), ln_b.astype(f32))
+        ln32 = () if normed else (ln_w.astype(f32), ln_b.astype(f32))
 
         def body(carry, xl):
             nll_sum, cnt, dw, dln, dextra = carry
-            xch, lch = xl
+            xch, lch, *wch = xl
             # the norm takes and gives float32 here (what it computes in):
             # its vjp then takes dxn as the matmul gave it. dx is rounded to
             # x's dtype twice, stacked and after the backward rule's scaling,
             # where autodiff rounds dxn and then dx
-            xn32, norm_vjp = jax.vjp(norm, xch.astype(f32), *ln32)
-            xn = xn32.astype(xch.dtype)
+            if normed:
+                xn = xch
+            else:
+                xn32, norm_vjp = jax.vjp(norm, xch.astype(f32), *ln32)
+                xn = xn32.astype(xch.dtype)
             logits = _head_logits(xn, w, extra, divisor)
             with trace.scope("head_softmax"):
                 mask = lch >= 0
@@ -2818,6 +3075,8 @@ def _head_scan(kind, eps, biased, ln_w, ln_b, w, w_acc, extra, xc, lc, divisor=1
                        )[..., 0]
                 hot = jnp.arange(logits.shape[-1]) == label
                 dlogits = jnp.where(mask[..., None], e / total - hot, 0.0)
+                if weighed:
+                    dlogits = dlogits * wch[0][..., None]
                 if divisor != 1.0:
                     # of the undivided logits, the matmul's own output
                     dlogits = dlogits / divisor
@@ -2832,34 +3091,42 @@ def _head_scan(kind, eps, biased, ln_w, ln_b, w, w_acc, extra, xc, lc, divisor=1
             with trace.scope("head_dx"):
                 dxn = jax.lax.dot_general(dl, w, (((2,), (1,)), ((), ())),
                                           preferred_element_type=f32)
-                dx, *dln_chunk = norm_vjp(dxn)
+                dx, *dln_chunk = (dxn,) if normed else norm_vjp(dxn)
             with trace.scope("head_dw"):
                 dw = dw + jax.lax.dot_general(xn, dl, (((0, 1), (0, 1)), ((), ())),
                                               preferred_element_type=f32)
                 dln = tuple(a + b for a, b in zip(dln, dln_chunk))
                 if biased:
                     dextra = dextra + dlogits.sum(axis=(0, 1))
-            return ((nll_sum + (nll * mask).sum(), cnt + mask.sum(), dw, dln,
-                     dextra), dx.astype(xch.dtype))
+            nll = nll * mask
+            return ((nll_sum + (nll * wch[0] if weighed else nll).sum(), cnt + mask.sum(),
+                     dw, dln, dextra),
+                    (dx.astype(xch.dtype), nll) if weighed else dx.astype(xch.dtype))
 
         zeros = lambda a: jnp.zeros(a.shape, f32)
-        (nll_sum, cnt, dw, dln, dextra), dxc = jax.lax.scan(
+        (nll_sum, cnt, dw, dln, dextra), out = jax.lax.scan(
             body, (jnp.zeros((), f32), jnp.zeros((), jnp.int32), zeros(w),
                    tuple(zeros(a) for a in ln32), zeros(extra) if biased else None),
-            (xc, lc))
-        return (nll_sum, cnt), (dxc, dw, dln, dextra, ln_w, ln_b)
+            (xc, lc, wc) if weighed else (xc, lc))
+        dxc, rows = out if weighed else (out, None)
+        return ((nll_sum, cnt, rows) if weighed else (nll_sum, cnt),
+                (dxc, dw, dln, dextra, ln_w, ln_b, rows))
 
     def bwd(res, cotangents):
         g = cotangents[0]               # of nll_sum; the count's is float0
-        dxc, dw, dln, dextra, ln_w, ln_b = res
+        dxc, dw, dln, dextra, ln_w, ln_b, rows = res
         scaled = lambda a, dtype: (a.astype(f32) * g).astype(dtype)
-        return (scaled(dln[0], ln_w.dtype), scaled(dln[1], ln_b.dtype), None,
+        return (None if normed else scaled(dln[0], ln_w.dtype),
+                None if normed else scaled(dln[1], ln_b.dtype), None,
                 scaled(dw, f32), scaled(dextra, f32) if biased else None,
-                scaled(dxc, dxc.dtype), None)
+                scaled(dxc, dxc.dtype), None,
+                # d (sum of weight x nll) / d weight: the rows' own losses
+                scaled(rows, f32) if weighed else None)
 
     scan = jax.custom_vjp(primal)
     scan.defvjp(fwd, bwd)
-    return scan(ln_w, ln_b, w, w_acc, extra, xc, lc)
+    out = scan(ln_w, ln_b, w, w_acc, extra, xc, lc, wc)
+    return (*out[:2], jax.lax.stop_gradient(out[2])) if weighed else out
 
 
 def _keeping_splash_residuals(policy):

@@ -103,6 +103,14 @@ PREFIX = "sxt:"
 # (without its skip) inside "attn_core", "ssm_out_norm" (the skip "D x", the
 # gate and the grouped norm) and "ssm_out" inside "attn_out". A layer that is a mixer
 # alone (ffn "none") opens no scope of the "mlp" layer.
+# A looped stack (``loop_steps`` > 1) opens "loop" AROUND its outer scan (every
+# op of the layers inside carries it as an outer component; what has "loop"
+# for its INNERMOST scope is the outer scan's own work: the carry, slicing
+# and stacking what the visits keep, the sums of the weights' gradients over
+# the visits), "loop_norm" inside it (the final norm after each pass of the
+# stack, in every pass: the head's scan opens no "final_norm" then) and
+# "loop_exit" inside "loss" (the exit gate, the exit distribution, its
+# entropy, the weighing of the exits' losses and their backward).
 # "plumbing" is what belongs to no layer of the model: the layer scan's own
 # slicing and stacking, the masters' cast to the compute dtype, the
 # gradients' cast back and normalization
@@ -117,11 +125,11 @@ SCOPES = {
              "ssm_in", "ssm_conv", "ssm_gates", "ssm_scan", "ssm_out_norm", "ssm_out"),
     "mlp": ("mlp_norm", "mlp", "moe", "pre_router", "moe_router", "moe_dispatch",
             "moe_experts", "moe_combine", "moe_shared"),
-    "loss": ("embed", "final_norm", "loss", "head_logits", "head_softmax",
-             "head_dx", "head_dw"),
+    "loss": ("embed", "final_norm", "loop_norm", "loss", "loop_exit", "head_logits",
+             "head_softmax", "head_dx", "head_dw"),
     "optimizer": ("optimizer", "grad_clip", "weight_mix"),
     "mesh": ("zero3_gather", "zero3_reduce_scatter"),
-    "plumbing": ("layers", "weight_cast", "grad_normalize"),
+    "plumbing": ("layers", "loop", "weight_cast", "grad_normalize"),
 }
 
 # the passes of a train step, as ``phase_of`` reads them off an op_name path

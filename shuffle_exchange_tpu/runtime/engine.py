@@ -2500,8 +2500,14 @@ class Engine:
         ``moe_expert_weight`` [routed layers, E] float32, the summed weights
         of each expert's token-choices; a chunked loss's ``loss_chunks`` and
         ``loss_rows`` (the scan's trips and the rows they held on one device,
-        summed over the step's microbatches). {} before the first step and
-        for models that report nothing."""
+        summed over the step's microbatches; under a gated looped stack they
+        count loop_steps x the batch's rows). A looped stack (``loop_steps``
+        > 1) gives ``loop_layer_visits`` (loop_steps x layers) and, gated,
+        ``loop_exit_mass`` [loop_steps] (mean p_t over tokens),
+        ``loop_exit_ce`` [loop_steps] (mean CE_t), ``loop_exit_entropy`` and
+        ``loop_expected_steps`` (mean of sum_t t p_t), each a microbatch's
+        mean summed like the rest: divide by gradient_accumulation_steps. {}
+        before the first step and for models that report nothing."""
         return dict(getattr(self, "_last_step_stats", None) or {})
 
     @property

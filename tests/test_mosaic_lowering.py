@@ -698,6 +698,9 @@ _CELL_ATTENTION = {
     # 4096 = 8 key blocks of 512 and the diagonal (PR 57)
     "smallthinker-train-full": (1, 16384, 28, 4, 128, 128, 0),
     "smallthinker-train-window": (1, 16384, 28, 4, 128, 128, 4096),
+    # olmoe-train's route (MHA, 16 heads of 128, a group of one) at one
+    # sequence of 8,192: every visit of the looped stack (PR 64)
+    "ouro-train": (1, 8192, 16, 16, 128, 128, 0),
 }
 
 

@@ -298,7 +298,7 @@ def test_a_one_kind_model_takes_the_output_order():
     pre = Transformer(dataclasses.replace(cfg, norm_order="input"))
     assert abs(float(jax.jit(pre.loss)(params, {"input_ids": ids})) - want) > 1e-4
     with pytest.raises(ValueError, match="norm_order"):
-        Transformer(dataclasses.replace(config_from_hf(HF), norm_order="sandwich"))
+        Transformer(dataclasses.replace(config_from_hf(HF), norm_order="between"))
     with pytest.raises(ValueError, match="one of them.*post_ln and norm_order='output'"):
         Transformer(dataclasses.replace(cfg, post_ln=True))
 
