@@ -629,7 +629,8 @@ def resolve_moe_impl(impl: str, ep_size: int, scanned: bool = False) -> str:
       alike. On a v5e at 131,072 rows x 64 experts x 2048 x 1024 the nine
       grouped GEMMs of a layer take 480 ms alone and 481 ms under a scan at
       that tile (5% of the bf16 peak), 35.7 ms and 36.5 ms at
-      ``ops/grouped_gemm._GMM_TILE`` (70%) (chip runs of PR 28, PERF.md 6);
+      (512, 1024, 1024) (70%) (chip runs of PR 28, PERF.md 6; since PR 66
+      ``ops/grouped_gemm._gmm_tiling`` gives each product its own tile);
     - otherwise -> "ragged" (dropless grouped-GEMM).
     """
     if impl != "auto":

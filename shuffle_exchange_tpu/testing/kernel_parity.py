@@ -205,25 +205,27 @@ def _matmuls(rng) -> Iterator[dict]:
         yield _check(tag, _quant_matmul_pallas(xq, qm), xq @ qm.dequantize(),
                      5e-3)
 
-    # megablox gmm vs ragged_dot, uneven groups, N not a tile multiple, one
-    # empty group; then its custom-VJP backward (dx via transposed gmm, dw
-    # via tgmm), which MoE training runs
-    E, K, F, N = 4, 256, 384, 1000
-    xg = jnp.asarray(rng.standard_normal((N, K)), jnp.bfloat16)
-    wg = jnp.asarray(rng.standard_normal((E, K, F)) * K ** -0.5, jnp.bfloat16)
-    gs = jnp.asarray([300, 0, 450, 250], jnp.int32)
-    yield _check("grouped-gemm", _grouped_matmul_gmm(xg, wg, gs),
-                 jax.lax.ragged_dot(xg, wg, gs), 5e-2)
+    # megablox gmm vs ragged_dot, uneven groups, one empty group; then its
+    # custom-VJP backward (dx via transposed gmm, dw via tgmm), which MoE
+    # training runs. First N not a tile multiple; then an expert's widths (PR
+    # 66): the whole contraction 2048 one k-step forward, the whole 2048 x 512
+    # output one tgmm tile
+    for tag, (N, K, F), sizes in (("grouped-gemm", (1000, 256, 384), [300, 0, 450, 250]),
+                                  ("grouped-gemm-wide", (640, 2048, 512), [130, 0, 401, 97, 12])):
+        xg = jnp.asarray(rng.standard_normal((N, K)), jnp.bfloat16)
+        wg = jnp.asarray(rng.standard_normal((len(sizes), K, F)) * K ** -0.5, jnp.bfloat16)
+        gs = jnp.asarray(sizes, jnp.int32)
+        yield _check(tag, _grouped_matmul_gmm(xg, wg, gs), jax.lax.ragged_dot(xg, wg, gs), 5e-2)
 
-    def loss(fn, xx, ww):
-        return (fn(xx, ww, gs).astype(jnp.float32) ** 2).mean()
+        def loss(fn, xx, ww):
+            return (fn(xx, ww, gs).astype(jnp.float32) ** 2).mean()
 
-    gx, gw = jax.grad(lambda a, b: loss(_grouped_matmul_gmm, a, b),
-                      argnums=(0, 1))(xg, wg)
-    rx, rw = jax.grad(lambda a, b: loss(jax.lax.ragged_dot, a, b),
-                      argnums=(0, 1))(xg, wg)
-    yield _check("grouped-gemm-dx", gx, rx, 5e-2)
-    yield _check("grouped-gemm-dw", gw, rw, 5e-2)
+        gx, gw = jax.grad(lambda a, b: loss(_grouped_matmul_gmm, a, b),
+                          argnums=(0, 1))(xg, wg)
+        rx, rw = jax.grad(lambda a, b: loss(jax.lax.ragged_dot, a, b),
+                          argnums=(0, 1))(xg, wg)
+        yield _check(f"{tag}-dx", gx, rx, 5e-2)
+        yield _check(f"{tag}-dw", gw, rw, 5e-2)
 
     # multi-tenant LoRA pool-gather kernel: slot 0 is the all-zeros adapter
     S, D, R, Nn = 5, 256, 8, 128

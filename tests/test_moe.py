@@ -199,6 +199,65 @@ def test_grouped_matmul_matches_pergroup_einsum():
     assert np.isfinite(np.asarray(g)).all()
 
 
+def _gmm_against_ragged_dot(K, F, sizes, dtype, tol, pad=0, interpret=True):
+    """megablox under the tile rule against ``lax.ragged_dot``, value and
+    both gradients, on rows the groups hold (what stands past them is
+    nobody's: ``moe/layer.expert_mlp_ragged``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from shuffle_exchange_tpu.ops.grouped_gemm import _grouped_matmul_gmm
+
+    rng = np.random.default_rng(K + F)
+    sizes = np.asarray(sizes, np.int32)
+    held, N, E = int(sizes.sum()), int(sizes.sum()) + pad, len(sizes)
+    x = jnp.asarray(rng.standard_normal((N, K)), dtype)
+    w = jnp.asarray(rng.standard_normal((E, K, F)) * K ** -0.5, dtype)
+    gs = jnp.asarray(sizes)
+
+    def loss(mm):
+        return lambda x, w: (mm(x, w)[:held].astype(jnp.float32) ** 2).mean()
+
+    got = lambda x, w: _grouped_matmul_gmm(x, w, gs, interpret=interpret)
+    want = lambda x, w: jax.lax.ragged_dot(x, w, gs)
+    f32 = lambda a: np.asarray(a, np.float32)
+    np.testing.assert_allclose(f32(got(x, w))[:held], f32(want(x, w))[:held], rtol=tol, atol=tol)
+    (dx, dw), (rx, rw) = (jax.grad(loss(mm), argnums=(0, 1))(x, w) for mm in (got, want))
+    for g, r in ((f32(dx)[:held], f32(rx)[:held]), (f32(dw), f32(rw))):
+        np.testing.assert_allclose(g, r, rtol=tol, atol=tol * float(np.abs(r).max()))
+
+
+@pytest.mark.parametrize("K, F, sizes, pad", [
+    (256, 384, [300, 0, 450, 250], 0),          # rows padded 1000 -> 1024, four row tiles
+    (2048, 512, [130, 0, 401, 97, 12], 0),      # the whole contraction one k-step; tgmm's whole output
+    (1856, 256, [200, 0, 263], 0),              # 14.5 lane tiles whole as k, in tiles of 640 as n (clipped)
+    (768, 2560, [100, 330, 0, 210], 128),       # the whole output one tile forward, tgmm's in two; an empty tail
+    (14336, 256, [90, 0, 166], 0),              # no one k-step fits: the cap's k-steps of 1024
+], ids=["small", "one-k-step", "half-lane-tile", "buffer-tail", "cap"])
+def test_megablox_under_the_tile_rule_matches_ragged_dot(K, F, sizes, pad):
+    """The kernel route's NUMBERS under the tile rule (PR 66), in the library's own
+    interpreter (the dispatch seam never sends a CPU call there): uneven
+    groups, an empty group, a partial last row tile; float32 operands, so the
+    comparison holds the tiling (pads, masks, clipped tiles) and not a
+    rounding."""
+    import jax.numpy as jnp
+
+    _gmm_against_ragged_dot(K, F, sizes, jnp.float32, 2e-4, pad=pad)
+
+
+def test_megablox_under_the_tile_rule_matches_ragged_dot_on_the_chip():
+    """The same at bf16 through the compiled kernels (on the chip
+    ``testing/kernel_parity.py`` runs it for ``chip_smoke.py`` too)."""
+    import jax
+    import jax.numpy as jnp
+
+    if jax.default_backend() != "tpu":
+        pytest.skip("the compiled megablox kernels need a TPU")
+
+    _gmm_against_ragged_dot(2048, 512, [130, 0, 401, 97, 12], jnp.bfloat16, 5e-2,
+                            interpret=False)
+
+
 @pytest.mark.slow   # 10s: impl parity; nightly via ci_full (ISSUE 13 tier-1 budget)
 def test_index_dispatch_matches_einsum_dispatch():
     """The round-5 index-form capacity path (scalar slot scatter + row

@@ -28,6 +28,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from tests.test_grouped_gemm_tiles import CELLS as _EXPERT_CELLS    # pure Python: shapes only
+
 
 def _tpu_lower(fn, *args):
     """Lower ``fn`` for the TPU platform (no TPU backend needed)."""
@@ -275,6 +277,19 @@ def test_rmsnorm_lowers():
     _tpu_lower(jax.grad(lambda x, w: rmsnorm(x, w).sum(), argnums=(0, 1)), x, w)
 
 
+def _expert_layer_products(cell):
+    """Value and gradient of a cell's two expert products, [R, M] x [E, M, F]
+    and [R, F] x [E, F, M], each kernel under its own product's tile: forward
+    ``gmm``, backward ``gmm(transpose_rhs)``, ``tgmm``."""
+    from shuffle_exchange_tpu.ops.grouped_gemm import _grouped_matmul_gmm
+
+    R, E, M, F = _EXPERT_CELLS[cell]
+    for K, W in ((M, F), (F, M)):
+        yield (jax.value_and_grad(lambda x, w, gs: _grouped_matmul_gmm(
+            x, w, gs).astype(jnp.float32).sum() ** 2, argnums=(0, 1)),
+            ((R, K), jnp.bfloat16), ((E, K, W), jnp.bfloat16), ((E,), jnp.int32))
+
+
 def test_grouped_gemm_lowers():
     from shuffle_exchange_tpu.ops.grouped_gemm import _grouped_matmul_gmm
 
@@ -283,6 +298,16 @@ def test_grouped_gemm_lowers():
     gs = jnp.zeros((4,), jnp.int32)
     _tpu_lower(jax.grad(lambda x, w: _grouped_matmul_gmm(
         x, w, gs).astype(jnp.float32).sum() ** 2, argnums=(0, 1)), x, w)
+
+
+@pytest.mark.parametrize("cell", list(_EXPERT_CELLS))
+def test_grouped_gemm_lowers_at_the_expert_cells_shapes(cell):
+    """Value and gradient under the tile each product's own (m, k, n) gets
+    (PR 66): block shapes that are no whole (8, 128) tiles of their arrays, or
+    a row tile that does not divide the padded rows, are refused here."""
+    for fn, *specs in _expert_layer_products(cell):
+        exported = _tpu_lower(fn, *[jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in specs])
+        assert exported.mlir_module().count("tpu_custom_call") == 3
 
 
 def test_lora_grouped_gemm_lowers():
@@ -969,6 +994,19 @@ def test_grouped_gemm_compiles_at_a_width_of_half_lane_tiles(chip_compile):
             ((N, K), _BF16), ((E, K, W), _BF16), ((E,), _I32))
         assert "tgmm" in compiled.as_text()
     assert not _gmm_ok(jnp.zeros((N, 200), _BF16), jnp.zeros((E, 200, 1024), _BF16))
+
+
+@pytest.mark.parametrize("cell", ["lfm2-train", "smallthinker-train", "nemotron3-train",
+                                  "qwen3next-train"])
+def test_grouped_gemm_compiles_under_the_tile_rule(chip_compile, cell):
+    """The three kernels of both expert products at the two claimed cells',
+    the largest tiles' (14.03 MiB by the rule's count: ``tgmm`` (128, 896,
+    1856)) and the shortest groups' shapes, each under the tile its own
+    product gets (PR 66): a tile over the kernels' scoped VMEM fails here, not
+    on the chip."""
+    for fn, *specs in _expert_layer_products(cell):
+        text = chip_compile(fn, *specs).as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 3 and "tgmm" in text
 
 
 @pytest.mark.parametrize("Hk, rep, dk, dv", [(16, 2, 128, 128), (30, 1, 96, 192),
