@@ -159,6 +159,15 @@ class InferenceEngine:
         self.config = config or InferenceConfig()
         self._mcfg = model.config
         mixers = {mixer for mixer, _ in getattr(self._mcfg, "kinds_used", ())}
+        if "kda" in mixers:
+            raise NotImplementedError(
+                "serving a delta rule with a decay a key channel (mixer 'kda': Kimi "
+                "Delta Attention) beside latent attention that rotates nothing is "
+                "not implemented: a layer's state is a [dk, dv] matrix a head and "
+                "three convolution tails, which no cache or scheduler holds, the "
+                "rule has no one-token step here, and the latent layers would need "
+                "a latent cache WITHOUT rotated keys (training through "
+                "sxt.initialize is; ROADMAP R-M5, R-M4)")
         if (getattr(self._mcfg, "moe_router_input", "ffn") != "ffn"
                 or getattr(self._mcfg, "unrotated_mixers", ())):
             raise NotImplementedError(

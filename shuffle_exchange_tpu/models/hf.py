@@ -22,6 +22,7 @@ a local checkpoint directory — no network access is assumed.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Tuple
 
@@ -61,6 +62,7 @@ _ARCH_FAMILIES = {
     "GraniteMoeHybridForCausalLM": "granitemoehybrid",
     "SmallThinkerForCausalLM": "smallthinker",
     "OuroForCausalLM": "ouro",
+    "KimiLinearForCausalLM": "kimilinear",
 }
 
 
@@ -81,6 +83,7 @@ _MODEL_TYPE_FAMILIES = {"llama": "llama", "mistral": "llama", "qwen2": "qwen2",
                         "smallthinker": "smallthinker",
                         "KeyeVL2": "keyevl2",
                         "ouro": "ouro",
+                        "kimi_linear": "kimilinear",
                         "megatron": "megatron",
                         "megatron-gpt": "megatron", "megatron_gpt": "megatron"}
 
@@ -707,6 +710,230 @@ def _ouro_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerConf
         **common)
 
 
+def _kimi_linear_config(cfg: Dict[str, Any], common: Dict[str, Any]) -> TransformerConfig:
+    """Moonshot's ``model_type: kimi_linear`` as Kimi-Linear-48B-A3B ships it:
+    ``linear_attn_config`` names, counting from 1, the layers whose mixer is
+    Kimi Delta Attention (``kda_layers``: ``num_heads`` heads of ``head_dim``
+    for q, k and v alike, a short convolution of ``short_conv_kernel_size``
+    taps, a decay for every key channel and a sigmoid output gate each through
+    a low-rank pair as wide as a head: mixer "kda") and those whose mixer is
+    latent attention (``full_attn_layers``: deepseek_v3's MLA without query
+    compression which, under ``mla_use_nope``, rotates NOTHING: mixer "mla"
+    under ``unrotated_mixers``); ``first_k_dense_replace`` leading layers with
+    a dense FFN of ``intermediate_size``, every layer after them routed
+    (``moe_layer_freq`` 1): ``num_experts`` experts of
+    ``moe_intermediate_size``, sigmoid scores, the top
+    ``num_experts_per_token`` of score + ``e_score_correction_bias`` (chosen
+    by, not weighed by), weights renormalised and scaled by
+    ``routed_scaling_factor``, ``num_shared_experts`` ungated shared experts
+    as ONE SwiGLU, dropless ("ragged"). The two lists are CHECKED against the
+    stack they give: every layer in exactly one, the leading layers of one
+    mixer, the rest whole periods. The published 27 layers are not (the last
+    period is cut to two layers) and are refused by name: ``stack_apply`` has
+    no scan for a period cut short (ROADMAP R-M18); a cut in depth that ends
+    on a period runs. ``num_experts_held`` / ``expert_first`` /
+    ``expert_buffer_factor`` as for qwen3_next; ``bias_update_speed`` /
+    ``aux_loss_alpha`` / ``seq_aux`` as for deepseek_v3 (the config states no
+    balancing). What is not written here is refused by name."""
+    L = int(cfg["num_hidden_layers"])
+    linear = dict(cfg.get("linear_attn_config") or {})
+    kda = [int(i) for i in linear.get("kda_layers") or []]
+    full = [int(i) for i in linear.get("full_attn_layers") or []]
+    refused = {
+        "q_lora_rank": cfg.get("q_lora_rank") is not None,
+        "rope_scaling": cfg.get("rope_scaling") is not None,
+        "mla_use_nope": cfg.get("mla_use_nope", False) is not True,
+        "num_expert_group": int(cfg.get("num_expert_group") or 1) > 1
+        or int(cfg.get("topk_group") or 1) > 1,
+        "moe_router_activation_func":
+            cfg.get("moe_router_activation_func", "sigmoid") != "sigmoid",
+        "moe_renormalize": cfg.get("moe_renormalize", True) is not True,
+        "moe_layer_freq": int(cfg.get("moe_layer_freq", 1)) != 1,
+        "num_nextn_predict_layers": int(cfg.get("num_nextn_predict_layers") or 0) != 0,
+        "hidden_act": cfg.get("hidden_act", "silu") != "silu",
+        "tie_word_embeddings": bool(cfg.get("tie_word_embeddings")),
+        "linear_attn_config": not kda or sorted(
+            i for i in kda + full if i <= L) != list(range(1, L + 1)),
+    }
+    for key, bad in refused.items():
+        if bad:
+            raise ValueError(
+                f"kimi_linear with {key}={cfg.get(key)!r} is not supported (written "
+                "down: no query compression, no RoPE scaling, latent attention that "
+                "rotates nothing, one router group over sigmoid scores renormalised "
+                "over the chosen, every layer after the leading dense ones routed, "
+                "no multi-token prediction, SiLU-gated FFNs, an untied head, and "
+                "linear_attn_config's kda_layers / full_attn_layers naming every "
+                "layer from 1 to num_hidden_layers exactly once)")
+    lead = int(cfg.get("first_k_dense_replace", 0))
+    if not 0 <= lead < L:
+        raise ValueError(f"kimi_linear: first_k_dense_replace={lead} leaves no "
+                         f"routed layer of {L}")
+    mixer_of = lambda i: "kda" if i in kda else "mla"
+    kinds = [(mixer_of(i + 1), "mlp" if i < lead else "moe") for i in range(L)]
+    # the period: the shortest that the routed layers repeat at least twice
+    # (or once, as the whole of them); what is left over is a period cut short
+    rest = kinds[lead:]
+    period = next(n for n in range(1, len(rest) + 1)
+                  if rest[:n] * (len(rest) // n) == rest[:len(rest) // n * n]
+                  and (len(rest) // n >= 2 or n == len(rest)))
+    if len(set(kinds[:lead])) > 1 or len(rest) % period:
+        raise ValueError(
+            f"kimi_linear: kda_layers={kda} / full_attn_layers={full} over "
+            f"{L} layers are not {lead} leading layer(s) of one mixer and whole "
+            f"periods of {period} after them: the stack ends part of the way into "
+            "its period (the published 27 layers: the last period is cut to "
+            "two), and stack_apply has no scan for a period cut short (ROADMAP "
+            "R-M18); cut num_hidden_layers to end on a period (5, 9, 13, ...)")
+    dc, dr = cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"]
+    head = int(linear.get("head_dim", 128))
+    alpha = float(cfg.get("aux_loss_alpha") or 0.0)
+    common.update(d_ff=cfg["moe_intermediate_size"], n_kv_heads=None,
+                  max_seq_len=cfg.get("model_max_length",
+                                      cfg.get("max_position_embeddings", 4096)))
+    return TransformerConfig(
+        head_size=dc + dr, rotary_dim=dr,
+        mla_kv_rank=cfg["kv_lora_rank"], mla_qk_content_dim=dc,
+        mla_qk_rope_dim=dr, mla_v_dim=cfg["v_head_dim"],
+        unrotated_mixers=("mla",),
+        kda_heads=int(linear["num_heads"]), kda_key_dim=head, kda_value_dim=head,
+        kda_conv_kernel=int(linear.get("short_conv_kernel_size", 4)),
+        # the low-rank pairs are as wide as a head in the modelling code
+        kda_gate_rank=head,
+        layer_pattern=tuple(rest[:period]),
+        lead_layers=lead, lead_kind=kinds[0] if lead else (),
+        dense_ff=cfg["intermediate_size"],
+        n_experts=cfg["num_experts"], **_held_share(cfg, "kimi_linear"),
+        moe_top_k=cfg["num_experts_per_token"], moe_norm_topk=True,
+        moe_score="sigmoid", moe_select_bias=True,
+        moe_weight_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+        moe_bias_update_rate=float(cfg.get("bias_update_speed", 0.0)),
+        moe_shared_expert_ff=(cfg["moe_intermediate_size"]
+                              * int(cfg.get("num_shared_experts") or 0)),
+        moe_shared_gate="none", moe_impl="ragged",
+        moe_aux="sequence" if alpha and cfg.get("seq_aux", True) else "none",
+        aux_loss_coef=alpha, **common)
+
+
+# kimi_linear: the source's names of a layer's leaves, by what the layer is
+# (``modeling_kimi.py`` as ISSUE 67 recalls it; ``chipbench/KIMILINEAR.md``).
+# Each a torch Linear [out, in], a gain or a vector; the three projections
+# and the three depthwise convolutions ([C, 1, K] each) of a KDA layer are ONE
+# leaf each here (``_KIMI_FUSED``), the experts one leaf a matrix.
+_KIMI_BLOCK = {"ln1_w": "input_layernorm.weight", "ln2_w": "post_attention_layernorm.weight"}
+_KIMI_MIXER = {
+    "kda": {"kda_w_beta": "b_proj.weight", "kda_w_fa": "f_a_proj.weight",
+            "kda_w_fb": "f_b_proj.weight", "kda_w_ga": "g_a_proj.weight",
+            "kda_w_gb": "g_b_proj.weight", "kda_A_log": "A_log", "kda_dt_bias": "dt_bias",
+            "kda_norm_w": "o_norm.weight", "kda_w_out": "o_proj.weight"},
+    "mla": {"mla_wq": "q_proj.weight", "mla_wkv_a": "kv_a_proj_with_mqa.weight",
+            "mla_kv_norm_w": "kv_a_layernorm.weight", "mla_wkv_b": "kv_b_proj.weight",
+            "mla_wo": "o_proj.weight"}}
+_KIMI_FUSED = {"kda_w_qkv": ("q_proj.weight", "k_proj.weight", "v_proj.weight"),
+               "kda_conv_w": ("q_conv1d.weight", "k_conv1d.weight", "v_conv1d.weight")}
+_KIMI_FFN = {
+    "mlp": {"w_gate": "mlp.gate_proj.weight", "w_up": "mlp.up_proj.weight",
+            "w_down": "mlp.down_proj.weight"},
+    "moe": {"moe_gate": "block_sparse_moe.gate.weight",
+            "moe_select_bias": "block_sparse_moe.gate.e_score_correction_bias",
+            "moe_shared_w_gate": "block_sparse_moe.shared_experts.gate_proj.weight",
+            "moe_shared_w_up": "block_sparse_moe.shared_experts.up_proj.weight",
+            "moe_shared_w_down": "block_sparse_moe.shared_experts.down_proj.weight"}}
+_KIMI_EXPERT = {"moe_w_gate": "w1", "moe_w_up": "w3", "moe_w_down": "w2"}
+
+
+def _kimi_layers(config: TransformerConfig):
+    """[(the subtree's path under params, the row's index in it, (mixer,
+    ffn))] for layers 0 .. n_layers - 1 of a lead + periods stack."""
+    kinds = list(Transformer(config).slots())
+    rows = [(("lead",), (i,), tuple(config.lead_kind)) for i in range(config.lead_layers)]
+    periods = (config.n_layers - config.lead_layers) // len(kinds)
+    flat = len(kinds) == 1
+    for p in range(periods):
+        rows += [(("layers",) if flat else ("layers", name), (p,) if flat else (p, j), kind)
+                 for name, j, kind in kinds]
+    return rows
+
+
+def _kimi_dims(config: TransformerConfig):
+    """Where the k and the v columns of a KDA layer's fused leaves start."""
+    return [config.kda_heads * config.kda_key_dim, 2 * config.kda_heads * config.kda_key_dim]
+
+
+def kimi_linear_state_dict(params: Dict[str, Any], config: TransformerConfig) -> Dict[str, Any]:
+    """The program's tree of a ``kimi_linear`` model -> a flat dict under the
+    source's names, each tensor as torch stores it (a matrix [out, in], a
+    depthwise convolution [C, 1, K]; the experts held here under their own
+    numbers, ``expert_first`` onward): ``params_from_state_dict`` back. The
+    unused bias leaves of the plain RMSNorms are not exported."""
+    t = lambda x: x.T if x.ndim == 2 else x
+    out = {"model.embed_tokens.weight": params["embed"],
+           "model.norm.weight": params["ln_f_w"], "lm_head.weight": params["unembed"].T}
+    for i, (path, at, (mixer, ffn)) in enumerate(_kimi_layers(config)):
+        tree = functools.reduce(lambda d, k: d[k], path, params)
+        pre = f"model.layers.{i}."
+        leaf = lambda name: tree[name][at]
+        for name, theirs in _KIMI_BLOCK.items():
+            out[pre + theirs] = leaf(name)
+        for name, theirs in _KIMI_MIXER[mixer].items():
+            out[pre + "self_attn." + theirs] = t(leaf(name))
+        if mixer == "kda":
+            for theirs, w in zip(_KIMI_FUSED["kda_w_qkv"],
+                                 np.split(leaf("kda_w_qkv"), _kimi_dims(config), axis=1)):
+                out[pre + "self_attn." + theirs] = w.T
+            for theirs, w in zip(_KIMI_FUSED["kda_conv_w"],
+                                 np.split(leaf("kda_conv_w"), _kimi_dims(config), axis=1)):
+                out[pre + "self_attn." + theirs] = w.T[:, None, :]
+        for name, theirs in _KIMI_FFN[ffn].items():
+            out[pre + theirs] = t(leaf(name))
+        if ffn == "moe":
+            for name, theirs in _KIMI_EXPERT.items():
+                for e in range(config.experts_held):
+                    out[pre + f"block_sparse_moe.experts.{config.expert_first + e}."
+                        f"{theirs}.weight"] = leaf(name)[e].T
+    return out
+
+
+def _kimi_linear_params(sd: Dict[str, Any], config: TransformerConfig) -> Dict[str, Any]:
+    """``kimi_linear_state_dict``'s inverse (``sd``: names without the
+    ``model.`` prefix)."""
+    t = lambda x: x.T if x.ndim == 2 else x
+    rows: Dict[tuple, Dict[tuple, Dict[str, np.ndarray]]] = {}
+    for i, (path, at, (mixer, ffn)) in enumerate(_kimi_layers(config)):
+        pre = f"layers.{i}."
+        get = lambda name: _np(sd[pre + name])
+        layer = {name: get(theirs) for name, theirs in _KIMI_BLOCK.items()}
+        layer.update({name: t(get("self_attn." + theirs))
+                      for name, theirs in _KIMI_MIXER[mixer].items()})
+        if mixer == "kda":
+            layer["kda_A_log"] = layer["kda_A_log"].reshape(-1)
+            layer["kda_w_qkv"] = np.concatenate(
+                [get("self_attn." + n).T for n in _KIMI_FUSED["kda_w_qkv"]], axis=1)
+            layer["kda_conv_w"] = np.concatenate(
+                [get("self_attn." + n)[:, 0, :].T for n in _KIMI_FUSED["kda_conv_w"]], axis=1)
+        layer.update({name: t(get(theirs)) for name, theirs in _KIMI_FFN[ffn].items()})
+        if ffn == "moe":
+            for name, theirs in _KIMI_EXPERT.items():
+                layer[name] = np.stack([
+                    get(f"block_sparse_moe.experts.{config.expert_first + e}.{theirs}.weight").T
+                    for e in range(config.experts_held)])
+        # rmsnorm: tree parity
+        layer["ln1_b"], layer["ln2_b"] = (np.zeros_like(layer[n]) for n in ("ln1_w", "ln2_w"))
+        rows.setdefault(path, {})[at] = layer
+    p: Dict[str, Any] = {"embed": _np(sd["embed_tokens.weight"]), "ln_f_w": _np(sd["norm.weight"]),
+                         "unembed": _np(sd["lm_head.weight"]).T}
+    p["ln_f_b"] = np.zeros_like(p["ln_f_w"])
+    for path, by_at in rows.items():
+        shape = tuple(max(at[d] for at in by_at) + 1 for d in range(len(next(iter(by_at)))))
+        tree = {name: np.stack([by_at[at][name] for at in sorted(by_at)]).reshape(
+            shape + by_at[next(iter(by_at))][name].shape) for name in next(iter(by_at.values()))}
+        node = p
+        for key in path[:-1]:
+            node = node.setdefault(key, {})
+        node[path[-1]] = tree
+    return p
+
+
 # the source's names of a block's leaves (ouro), each a torch Linear [out, in]
 # or a gain; ``ouro_state_dict`` and ``params_from_state_dict`` both read it
 _OURO_BLOCK = {
@@ -1074,6 +1301,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
         return _lfm2_config(cfg, common)
     if family == "ouro":
         return _ouro_config(cfg, common)
+    if family == "kimilinear":
+        return _kimi_linear_config(cfg, common)
     if family == "mixtral":
         return TransformerConfig(
             n_experts=cfg["num_local_experts"], moe_top_k=cfg.get("num_experts_per_tok", 2),
@@ -1108,6 +1337,9 @@ def _stack(sd: Dict[str, Any], fmt: str, L: int, transpose: bool = False) -> np.
 def params_from_state_dict(sd: Dict[str, Any], config: TransformerConfig,
                            family: str, megatron_v2: bool = True) -> Dict[str, Any]:
     """Re-lay an HF state dict into the zoo Transformer's stacked format."""
+    if family == "kimilinear":
+        return _kimi_linear_params(
+            {k.removeprefix("model."): v for k, v in sd.items()}, config)
     if config.latent or config.lead_layers:
         raise NotImplementedError(
             f"importing {family} weights is not implemented: the latent-"

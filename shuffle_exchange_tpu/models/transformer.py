@@ -175,6 +175,8 @@ class TransformerConfig:
     #                       gate from one projection, per-HEAD q/k RMSNorm
     #                       (the block norm's kind) before RoPE
     #         "gdn"         Gated DeltaNet (ops/gated_delta.py), gdn_* below
+    #         "kda"         the delta rule with a decay a key channel (Kimi
+    #                       Delta Attention, ops/kda.py), kda_* below
     #         "sconv"       LFM2's gated short convolution (ops/short_conv.py):
     #                       one projection to [gate B | gate C | x], a causal
     #                       depthwise convolution of ``sconv_taps`` taps over
@@ -188,7 +190,7 @@ class TransformerConfig:
     #                       projection back; no RoPE
     #   q/k norm: "attn" takes ``qk_norm`` (True = the whole projection;
     #   "head" = per head); "swa" the whole-projection one; "gated_attn"
-    #   norms per head always; "mla", "gdn", "sconv" and "ssm" have none.
+    #   norms per head always; "mla", "gdn", "kda", "sconv" and "ssm" have none.
     #   rotation: "attn" rotates by the model's table where ``position`` is
     #   "rope", and by nothing where it is "none" (Nemotron-H: the state-space
     #   layers carry the order).
@@ -211,6 +213,17 @@ class TransformerConfig:
     # lets them reach (-1, 1) (FLA's ``allow_neg_eigval``, Olmo Hybrid's
     # ``linear_allow_neg_eigval``).
     gdn_beta_scale: float = 1.0
+    # mixer "kda" (Kimi Linear's ``linear_attn_config``): ``kda_heads`` heads
+    # of ``kda_key_dim`` (q and k) and ``kda_value_dim``, as many value heads
+    # as key heads; three projections (one leaf, [q | k | v]) each through a
+    # causal depthwise convolution of ``kda_conv_kernel`` taps without bias;
+    # the decay, a log-decay for every KEY CHANNEL, and the output gate (a
+    # sigmoid) each through a low-rank pair of ``kda_gate_rank`` columns.
+    kda_heads: int = 0
+    kda_key_dim: int = 0
+    kda_value_dim: int = 0
+    kda_conv_kernel: int = 4
+    kda_gate_rank: int = 0
     # Where a block norms: "input" (pre-norm, ``h + mix(norm(h))``),
     # "output" (the Olmo 2 / 3 order: ``h + norm(mix(h))``, ``h +
     # norm(ffn(h))``, nothing normed on the way in) or "sandwich" (both at
@@ -417,7 +430,7 @@ class TransformerConfig:
     @property
     def recurrent(self) -> bool:
         """Some layer carries a recurrent state instead of a KV cache."""
-        return any(mixer in ("gdn", "ssm") for mixer, _ in self.kinds_used)
+        return any(mixer in ("gdn", "kda", "ssm") for mixer, _ in self.kinds_used)
 
     @property
     def latent(self) -> bool:
@@ -450,6 +463,11 @@ class TransformerConfig:
     def gdn_layers(self) -> int:
         """Layers whose mixer is the Gated DeltaNet: the rules a step walks."""
         return self.layers_of("gdn")
+
+    @property
+    def kda_layers(self) -> int:
+        """Layers whose mixer is the delta rule with a decay a key channel."""
+        return self.layers_of("kda")
 
     @property
     def dense_ff_dim(self) -> int:
@@ -632,6 +650,14 @@ def _head_norm(x, weight, kind: str, eps: float):
 
     gain = weight.astype(jnp.float32)
     return rmsnorm_reference(x, 1.0 + gain if kind == "rmsnorm_zc" else gain, eps)
+
+
+def _join_rows(rows, join) -> dict:
+    """One dict of several layers' stats, each key joined over the rows that
+    have it: the layers of a period need not hand out the same (a routed
+    layer's counters; what a "dsa" or a "kda" mixer found)."""
+    return {key: join([row[key] for row in rows if key in row])
+            for key in sorted(set().union(*rows))}
 
 
 def _no_routing_stats(n_experts: int, weights: bool = False, share: bool = False) -> dict:
@@ -1047,6 +1073,36 @@ class Transformer:
                 "w_out": stack(next(keys), (Hv * dv, D), Hv * dv,
                                scale=1.0 / math.sqrt(2 * L)),
             })
+        elif mixer == "kda":
+            Hk, dk, dv, r = (cfg.kda_heads, cfg.kda_key_dim, cfg.kda_value_dim,
+                             cfg.kda_gate_rank)
+            if min(Hk, dk, dv, r) <= 0:
+                raise ValueError("mixer 'kda' needs kda_heads, kda_key_dim, "
+                                 "kda_value_dim and kda_gate_rank > 0")
+            ka, kd = jax.random.split(next(keys))
+            # the published modelling code's draws (fla's KimiDeltaAttention):
+            # A = U[1, 16] a head, the decay's bias the inverse softplus of a
+            # log-uniform step in [0.001, 0.1] a channel floored at 1e-4
+            step = jnp.maximum(jnp.exp(jax.random.uniform(
+                kd, lead + (Hk * dk,), jnp.float32, math.log(1e-3), math.log(1e-1))), 1e-4)
+            layer.update({
+                # [q Hk dk | k Hk dk | v Hk dv], the checkpoint's three
+                # projections side by side, and the taps over them in that order
+                "kda_w_qkv": stack(next(keys), (D, 2 * Hk * dk + Hk * dv), D),
+                "kda_conv_w": stack(next(keys), (cfg.kda_conv_kernel, 2 * Hk * dk + Hk * dv),
+                                    cfg.kda_conv_kernel),
+                "kda_w_beta": stack(next(keys), (D, Hk), D),
+                # the decay's low-rank pair, and the output gate's
+                "kda_w_fa": stack(next(keys), (D, r), D),
+                "kda_w_fb": stack(next(keys), (r, Hk * dk), r),
+                "kda_w_ga": stack(next(keys), (D, r), D),
+                "kda_w_gb": stack(next(keys), (r, Hk * dv), r),
+                "kda_A_log": jnp.log(jax.random.uniform(ka, lead + (Hk,), jnp.float32, 1.0, 16.0)),
+                "kda_dt_bias": step + jnp.log(-jnp.expm1(-step)),
+                "kda_norm_w": ones(dv),
+                "kda_w_out": stack(next(keys), (Hk * dv, D), Hk * dv,
+                                   scale=1.0 / math.sqrt(2 * L)),
+            })
         elif mixer == "sconv":
             layer.update({
                 # three blocks of D columns: [gate before B | gate after C | x]
@@ -1214,6 +1270,10 @@ class Transformer:
                 return P(*lead, *base)
             if name == "moe_gate":
                 return P(*lead, None, None)
+            if name.startswith("kda_"):
+                # nor is the KDA mixer: one leaf holds its three projections
+                # side by side, and the taps keep that order
+                return P(*((None,) * leaf.ndim))
             if name.startswith("ssm_"):
                 # the state-space mixer is not split over "tensor": its input
                 # projection's blocks and the taps over them keep the
@@ -1352,7 +1412,7 @@ class Transformer:
 
         cfg = self.config
         mixer, ffn = kind or cfg.pattern[0]
-        mix = {"gdn": self._gdn, "gated_attn": self._gated_attention,
+        mix = {"gdn": self._gdn, "kda": self._kda, "gated_attn": self._gated_attention,
                "mla": self._mla, "sconv": self._sconv, "ssm": self._ssm,
                "attn": functools.partial(self._gqa, local=local),
                "swa": functools.partial(self._gqa, mixer="swa"),
@@ -1390,21 +1450,26 @@ class Transformer:
                 return (h.astype(jnp.float32) + cfg.residual_scale
                         * out.astype(jnp.float32)).astype(h.dtype)
 
-        # a "dsa" mixer hands out, beside its output, what the step reports of
-        # its selection and the indexer's own loss: they ride with the routed
-        # layer's stats (``sparse``)
-        sparse = mixer == "dsa"
-        if sparse and (shared or ffn != "moe"):
+        # a "dsa" and a "kda" mixer hand out, beside their output, what the
+        # step reports of them (the selection's counters and the indexer's own
+        # loss; what the rule's states keep over a chunk): ``found`` rides
+        # with the layer's stats
+        finds = mixer in ("dsa", "kda")
+        if mixer == "dsa" and (shared or ffn != "moe"):
             raise NotImplementedError(
                 "mixer 'dsa' (a learned sparse attention) is written for a "
                 "sequential block with routed experts: its counters and its "
                 "indexer's loss ride with the router's stats")
+        if finds and shared:
+            raise NotImplementedError(
+                f"mixer {mixer!r} hands its statistics out of the mixer half, "
+                "where a parallel block of one norm hands out its normed input")
 
         def mixer_half(lw, h):
             y = (normed(lw, h, 1, "attn_norm") if place in ("input", "parallel", "sandwich")
                  else h)
             out = mix(lw, y, rope)
-            if sparse:
+            if finds:
                 out, found = out
             if place == "output":
                 out = normed(lw, out, 1, "attn_norm")
@@ -1413,7 +1478,7 @@ class Transformer:
             h = add(h, out, "attn_out")
             if place == "sum":
                 h = normed(lw, h, 1, "attn_norm")
-            if sparse:
+            if finds:
                 return h, found
             return (h, y) if shared else h
 
@@ -1440,12 +1505,13 @@ class Transformer:
             ffn_half = jax.checkpoint(ffn_half, policy=policy)
         if ffn == "none":
             # a mixer alone: one residual step, nothing routed
-            return mixer_half(lw, h), (jnp.zeros((), jnp.float32), None)
+            h, found = mixer_half(lw, h) if finds else (mixer_half(lw, h), None)
+            return h, (jnp.zeros((), jnp.float32), found)
         x = h if block_router or place == "parallel" else None
-        if sparse:
+        if finds:
             h, found = mixer_half(lw, h)
             h, aux, stats = ffn_half(lw, h, x, None)
-            return h, (aux, {**stats, **found})
+            return h, (aux, {**(stats or {}), **found})
         h, y2 = mixer_half(lw, h) if shared else (mixer_half(lw, h), None)
         h, aux, stats = ffn_half(lw, h, x, y2)
         return h, (aux, stats)
@@ -1769,7 +1835,13 @@ class Transformer:
         is ``[k_c | k_r]``, scores are dc + dr wide (scaled by its root),
         values ``mla_v_dim``. Its own scopes nest in ``attn_qkv``: ``mla_q``,
         ``mla_kv_down``, ``mla_kv_norm``, ``mla_kv_up``, ``mla_rope`` (the
-        rotation, k_r's broadcast over the heads, the concatenations)."""
+        rotation, k_r's broadcast over the heads, the concatenations). Under
+        ``unrotated_mixers`` (Kimi Linear's ``mla_use_nope``: the KDA layers'
+        convolutions and decays carry the order) NOTHING is rotated: q's
+        ``dr`` dims and k_r stay as projected, ``mla_rope`` keeps the
+        broadcast and the concatenation alone, and the layer opens
+        ``nope_qkv`` / ``nope_core`` / ``nope_out`` as ``_gqa``'s unrotated
+        layers do."""
         import jax.numpy as jnp
         from jax.ad_checkpoint import checkpoint_name
 
@@ -1779,7 +1851,9 @@ class Transformer:
         r, dc, dr, dv = (cfg.mla_kv_rank, cfg.mla_qk_content_dim,
                          cfg.mla_qk_rope_dim, cfg.mla_v_dim)
         cos, sin = rope
-        with trace.scope("attn_qkv"):
+        nope = cos is None          # ``rope_for`` hands this kind no table
+        own = lambda part: trace.scope("nope_" + part) if nope else contextlib.nullcontext()
+        with trace.scope("attn_qkv"), own("qkv"):
             with trace.scope("mla_q"):
                 q = (y @ lw["mla_wq"]).reshape(B, T, H, dc + dr)
             with trace.scope("mla_kv_down"):
@@ -1791,20 +1865,111 @@ class Transformer:
                 kv = (c @ lw["mla_wkv_b"]).reshape(B, T, H, dc + dv)
                 k_c, v = kv[..., :dc], kv[..., dc:]
             with trace.scope("mla_rope"):
-                q_r = apply_rope(q[..., dc:], cos, sin, interleaved=cfg.rope_interleaved)
-                k_r = apply_rope(k_r[:, :, None, :], cos, sin,
-                                 interleaved=cfg.rope_interleaved)
-                q = jnp.concatenate([q[..., :dc], q_r], axis=-1)
+                if nope:
+                    k_r = k_r[:, :, None, :]
+                else:
+                    q_r = apply_rope(q[..., dc:], cos, sin, interleaved=cfg.rope_interleaved)
+                    k_r = apply_rope(k_r[:, :, None, :], cos, sin,
+                                     interleaved=cfg.rope_interleaved)
+                    q = jnp.concatenate([q[..., :dc], q_r], axis=-1)
                 k = jnp.concatenate(
                     [k_c, jnp.broadcast_to(k_r, (B, T, H, dr))], axis=-1)
         q = checkpoint_name(q, "q")
         k = checkpoint_name(k, "kv")
         v = checkpoint_name(v, "kv")
-        with trace.scope("attn_core"):
+        with trace.scope("attn_core"), own("core"):
             attn = self._attention(q, k, v, None)            # [B, T, H, dv]
         attn = checkpoint_name(attn, "attn")
-        with trace.scope("attn_out"):
+        with trace.scope("attn_out"), own("out"):
             return attn.reshape(B, T, H * dv) @ lw["mla_wo"]
+
+    def _kda(self, lw, y, rope):
+        """The Kimi Delta Attention mixer (``ops/kda.py``) on the normed
+        block input y [B, T, D] -> ([B, T, D], ``found``: the mean and the
+        least, over one chunk in 16, the heads and the key channels, of what
+        a state's row keeps over one chunk of the rule);
+        ``rope`` is not used. Shapes from ``kda_*``: H heads of dk (q, k) and
+        dv. ``[q | k | v] = y W_qkv``, each through a causal depthwise
+        convolution of ``kda_conv_kernel`` taps without bias and SiLU
+        (``ops/ssm_conv.py``'s kernels, the columns read where they lie); q
+        and k l2-normed per head, q times ``dk ** -0.5``; ``beta =
+        sigmoid(y W_beta)`` [H]; ``g = -exp(A_log[h]) softplus(y W_fa W_fb +
+        dt_bias)`` [H, dk], a log-decay for every key channel; the chunked
+        rule; ``w o / rms(o) sigmoid(y W_ga W_gb)`` per head; ``W_out``. Under
+        the outer scopes of an attention layer with its own nested inside:
+        ``kda_conv`` (the convolution, SiLU and the l2 norms), ``kda_gates``
+        (beta, g, the statistics of g), ``kda_scan``, ``kda_out_norm``. g,
+        beta, the norms and the rule's state are float32; the projections and
+        the rule's matmul operands are the compute dtype."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import PartitionSpec
+
+        from ..ops.gated_delta import l2norm
+        from ..ops.kda import chunk_decay, chunk_sample, kda_chunked
+        from ..ops.ssm_conv import ssm_conv
+        from ..parallel.mesh import kernel_activation_spec, shard_kernel
+
+        del rope
+        cfg = self.config
+        if self._sp_mesh()[0] > 1:
+            raise NotImplementedError(
+                "the KDA rule (mixer 'kda') under a sequence-parallel mesh: a "
+                "shard's rule and convolution start from the state and the tail "
+                "of the shard before it, which nothing carries; run it with seq = 1")
+        B, T = y.shape[:2]
+        H, dk, dv = cfg.kda_heads, cfg.kda_key_dim, cfg.kda_value_dim
+        f32 = jnp.float32
+        widths = (H * dk, H * dk, H * dv)
+        with trace.scope("attn_qkv"):
+            qkv = y @ lw["kda_w_qkv"]
+            with trace.scope("kda_conv"):
+                # the three convolutions where the projection left their
+                # columns, per device on its own rows like the rule below
+                rows = kernel_activation_spec(qkv.shape)
+                q, k, v = shard_kernel(
+                    lambda x, w: ssm_conv(x, w, jnp.zeros((w.shape[1],), f32),
+                                          0, widths)[1:4],
+                    (rows, PartitionSpec()), (rows,) * 3)(qkv, lw["kda_conv_w"])
+                q = (l2norm(q.reshape(B, T, H, dk)) * dk ** -0.5).astype(y.dtype)
+                k = l2norm(k.reshape(B, T, H, dk)).astype(y.dtype)
+                v = v.reshape(B, T, H, dv)
+            with trace.scope("kda_gates"):
+                beta = jax.nn.sigmoid((y @ lw["kda_w_beta"]).astype(f32))
+
+                def log_decay(y, w_fa, w_fb, A_log, dt_bias):
+                    a = jnp.matmul(y @ w_fa, w_fb, preferred_element_type=f32)
+                    return -jnp.exp(A_log.astype(f32))[:, None] * jax.nn.softplus(
+                        (a + dt_bias.astype(f32)).reshape(a.shape[:2] + (H, dk)))
+
+                leaves = [lw[name] for name in
+                          ("kda_w_fa", "kda_w_fb", "kda_A_log", "kda_dt_bias")]
+                g = log_decay(y, *leaves)
+                # what a state's rows keep over a chunk, in the timed steps: on
+                # one chunk in 16, formed AGAIN from those tokens of y. A second
+                # reader of g, or of the low-rank product, moves XLA's layouts
+                # on the way to the rule: 14 and 10 ms of a 676 ms step (my
+                # chip runs, PR 67); nothing of the backward reads this
+                kept = chunk_decay(log_decay(
+                    *jax.lax.stop_gradient([chunk_sample(y)] + leaves)))
+                found = {"kda_decay_mean": jnp.mean(kept), "kda_decay_min": jnp.min(kept)}
+        with trace.scope("attn_core"):
+            with trace.scope("kda_scan"):
+                # each device runs the rule on its own rows and heads (``_gdn``)
+                wide = kernel_activation_spec(q.shape, heads_dim=2)
+                flat = kernel_activation_spec(beta.shape, heads_dim=2)
+                o = shard_kernel(
+                    kda_chunked, (wide, wide, wide, wide, flat), wide)(q, k, v, g, beta)
+        with trace.scope("attn_out"):
+            with trace.scope("kda_out_norm"):
+                # a plain gain per head and a SIGMOID gate from a low-rank pair
+                gate = jnp.matmul(y @ lw["kda_w_ga"], lw["kda_w_gb"],
+                                  preferred_element_type=f32).reshape(B, T, H, dv)
+                o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                                      + cfg.norm_eps)
+                o = (lw["kda_norm_w"].astype(f32) * o
+                     * jax.nn.sigmoid(gate)).astype(y.dtype)
+            return o.reshape(B, T, H * dv) @ lw["kda_w_out"], found
 
     def _gdn(self, lw, y, rope):
         """The Gated DeltaNet mixer (``ops/gated_delta.py``) on the normed
@@ -2341,15 +2506,15 @@ class Transformer:
                 rows, loc = xs if use_local else (xs, None)
                 if len(slots) == 1:
                     return run(slots[0][2])(h, rows, loc)
-                auxs, routed = [], []
+                auxs, found = [], []
                 for name, i, kind in slots:
                     h, (aux, stats) = run(kind)(
                         h, jax.tree.map(lambda a: a[i], rows[name]), None)
                     auxs.append(aux)
                     if stats is not None:
-                        routed.append(stats)
-                return h, (jnp.stack(auxs), jax.tree.map(
-                    lambda *a: jnp.stack(a), *routed) if routed else None)
+                        found.append(stats)
+                return h, (jnp.stack(auxs),
+                           _join_rows(found, jnp.stack) if found else None)
 
             if cfg.lead_layers:
                 # the leading layers first, in a scan of their own
@@ -2367,9 +2532,9 @@ class Transformer:
             aux = jnp.sum(aux_losses)
             if cfg.lead_layers:
                 aux = aux + jnp.sum(lead_aux)
-                if lead_stats is not None:           # routed leading layers
-                    stats = lead_stats if stats is None else jax.tree.map(
-                        lambda a, b: jnp.concatenate([a, b]), lead_stats, stats)
+                if lead_stats is not None:   # routed leading layers, or a "kda" one
+                    stats = lead_stats if stats is None else _join_rows(
+                        [lead_stats, stats], jnp.concatenate)
             return (x, aux, stats) if with_stats else (x, aux)
 
         if ltd_mask is not None:
@@ -2874,7 +3039,15 @@ class Transformer:
             # chunks x the device's sequences x DeltaNet layers
             stats["gdn_scan_chunks"] = jnp.asarray(
                 -(-T // CHUNK) * batch_rows_a_device(B) * cfg.gdn_layers, jnp.int32)
-        if routed is not None:
+        if cfg.kda_layers:
+            # static: the rules a step walks, and the layers ``rope_for`` hands
+            # a table (a softmax kind reads its own; the other mixers read none)
+            stats["kda_layers"] = jnp.asarray(cfg.kda_layers, jnp.int32)
+            softmax = ("attn", "swa", "gated_attn", "mla", "dsa") if cfg.position == "rope" else ()
+            stats["rope_layers_rotated"] = jnp.asarray(sum(
+                cfg.layers_of(mixer) for mixer in softmax
+                if cfg.layers_of(mixer) and self.rope_for(mixer, 1)[0] is not None), jnp.int32)
+        if routed is not None and "expert_tokens" in routed:
             stats["moe_expert_tokens"] = routed["expert_tokens"]
             stats["moe_held_rows"] = routed["held_rows"]
             stats["moe_overflow_rows"] = routed["overflow_rows"]
@@ -2891,11 +3064,16 @@ class Transformer:
                      / (n_layers * B * T))
                 aux = cfg.n_experts * jnp.sum(f * routed["router_prob"].mean(axis=0))
         if routed is not None:
-            # a learned sparse attention's counters and its indexer's loss, a
-            # row a layer
-            stats.update({name: x for name, x in routed.items() if name.startswith("dsa_")})
+            # what the mixers found, a row a layer: a learned sparse
+            # attention's counters and its indexer's loss; what the states of
+            # the rules with a decay a key channel keep over a chunk
+            stats.update({name: x for name, x in routed.items()
+                          if name.startswith(("dsa_", "kda_"))})
             if "dsa_tied_chunks" in stats:
                 stats["dsa_tied_chunks"] = stats["dsa_tied_chunks"].sum()
+            if "kda_decay_mean" in stats:
+                stats["kda_decay_mean"] = stats["kda_decay_mean"].mean()
+                stats["kda_decay_min"] = stats["kda_decay_min"].min()
         if loops > 1 and cfg.exit_gate:
             with trace.scope("loss"):
                 return self._exit_loss(params, exits, labels, aux, stats)

@@ -103,6 +103,13 @@ PREFIX = "sxt:"
 # (without its skip) inside "attn_core", "ssm_out_norm" (the skip "D x", the
 # gate and the grouped norm) and "ssm_out" inside "attn_out". A layer that is a mixer
 # alone (ffn "none") opens no scope of the "mlp" layer.
+# A KDA layer (mixer "kda", the delta rule with a decay a key channel) opens
+# "kda_conv" (its three convolutions, SiLU and the l2 norms of q and k) and
+# "kda_gates" (beta, the per-channel log-decay and its step statistics) inside
+# "attn_qkv", "kda_scan" (the chunked rule) inside "attn_core" and
+# "kda_out_norm" (the per-head norm and the low-rank sigmoid gate) inside
+# "attn_out"; a latent-attention layer under ``unrotated_mixers`` opens the
+# "nope_*" scopes around its "mla_*" ones and rotates nothing under "mla_rope".
 # A looped stack (``loop_steps`` > 1) opens "loop" AROUND its outer scan (every
 # op of the layers inside carries it as an outer component; what has "loop"
 # for its INNERMOST scope is the outer scan's own work: the carry, slicing
@@ -117,6 +124,7 @@ PREFIX = "sxt:"
 SCOPES = {
     "attn": ("attn_norm", "attn_qkv", "attn_qk_norm", "attn_core", "attn_out",
              "attn_gate", "gdn_conv", "gdn_gates", "gdn_scan", "gdn_out_norm",
+             "kda_conv", "kda_gates", "kda_scan", "kda_out_norm",
              "mla_q", "mla_kv_down", "mla_kv_norm", "mla_kv_up", "mla_rope",
              "swa_qkv", "swa_rope", "swa_core", "swa_out", "rope_yarn",
              "nope_qkv", "nope_core", "nope_out",

@@ -2506,7 +2506,12 @@ class Engine:
         ``loop_exit_mass`` [loop_steps] (mean p_t over tokens),
         ``loop_exit_ce`` [loop_steps] (mean CE_t), ``loop_exit_entropy`` and
         ``loop_expected_steps`` (mean of sum_t t p_t), each a microbatch's
-        mean summed like the rest: divide by gradient_accumulation_steps. {}
+        mean summed like the rest: divide by gradient_accumulation_steps. A
+        model with "kda" layers gives ``kda_layers`` and ``rope_layers_rotated``
+        (static counts) and ``kda_decay_mean`` / ``kda_decay_min``: the mean and
+        the least, over the rules' layers, one chunk in 16, the heads and key
+        channels, of exp(the sum of g over a chunk): what a state's row keeps over 64
+        tokens in the step that ran (a microbatch's, summed like the rest). {}
         before the first step and for models that report nothing."""
         return dict(getattr(self, "_last_step_stats", None) or {})
 

@@ -353,6 +353,30 @@ def test_gated_delta_rule_lowers(dtype):
                jnp.zeros((B, T, H, d), jnp.float32))
 
 
+def _kda_vjp(q, k, v, g, beta, cotangent):
+    """o and the five gradients (dg per channel) through the KDA rule's
+    kernels themselves."""
+    from shuffle_exchange_tpu.ops.kda import _kda_pallas
+
+    o, back = jax.vjp(_kda_pallas, q, k, v, g, beta)
+    return (o,) + back(cotangent)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kda_rule_lowers(dtype):
+    """The rule with a decay a key channel: forward (o alone), the forward
+    that keeps S0, and the backward; a ragged tail; bf16 and float32."""
+    from shuffle_exchange_tpu.ops.kda import _kda_pallas
+
+    B, T, H, d = 2, 150, 8, 128
+    wide = jnp.zeros((B, T, H, d), dtype)
+    g = jnp.zeros((B, T, H, d), jnp.float32)
+    flat = jnp.zeros((B, T, H), jnp.float32)
+    _tpu_lower(_kda_pallas, wide, wide, wide, g, flat)
+    _tpu_lower(_kda_vjp, wide, wide, wide, g, flat,
+               jnp.zeros((B, T, H, d), jnp.float32))
+
+
 def _gdn_prologue_vjp(qkvz, conv_w, dq, dk, dv, dz, heads=(16, 128, 128)):
     """q, k, v, z and the two gradients through the prologue's kernels
     themselves (the dispatching entry takes the XLA form off a TPU)."""
@@ -884,6 +908,21 @@ def test_gated_delta_rule_compiles(chip_compile):
                             ((2, 8192, 32, 128), _F32))
     text = compiled.as_text()
     assert "gdn_rule_fwd_keep" in text and "gdn_rule_bwd" in text
+
+
+def test_kda_rule_compiles(chip_compile):
+    """The KDA rule's three kernels at the shape ``kimilinear-train`` runs
+    them: one row of 16,384 tokens, 32 heads of 128 / 128, bf16 with float32
+    g (a decay a key channel) and beta."""
+    from shuffle_exchange_tpu.ops.kda import _kda_pallas
+
+    wide, g, flat = (((1, 16384, 32, 128), _BF16), ((1, 16384, 32, 128), _F32),
+                     ((1, 16384, 32), _F32))
+    chip_compile(_kda_pallas, wide, wide, wide, g, flat)
+    compiled = chip_compile(_kda_vjp, wide, wide, wide, g, flat,
+                            ((1, 16384, 32, 128), _F32))
+    text = compiled.as_text()
+    assert "kda_rule_fwd_keep" in text and "kda_rule_bwd" in text
 
 
 def test_gated_delta_rule_compiles_on_padded_lanes(chip_compile):
