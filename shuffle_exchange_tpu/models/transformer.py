@@ -1890,14 +1890,18 @@ class Transformer:
         a state's row keeps over one chunk of the rule);
         ``rope`` is not used. Shapes from ``kda_*``: H heads of dk (q, k) and
         dv. ``[q | k | v] = y W_qkv``, each through a causal depthwise
-        convolution of ``kda_conv_kernel`` taps without bias and SiLU
-        (``ops/ssm_conv.py``'s kernels, the columns read where they lie); q
-        and k l2-normed per head, q times ``dk ** -0.5``; ``beta =
+        convolution of ``kda_conv_kernel`` taps without bias and SiLU; q
+        and k l2-normed per head, q times ``dk ** -0.5`` (all of that is
+        ``ops/kda.py`` ``kda_prologue``: on a TPU at heads of whole lane tiles
+        one kernel a pass, ``kda_prologue_fwd`` / ``kda_prologue_bwd``, which
+        reads the projection's columns where they lie and writes q, k, v in
+        the layout the rule's kernels read); ``beta =
         sigmoid(y W_beta)`` [H]; ``g = -exp(A_log[h]) softplus(y W_fa W_fb +
         dt_bias)`` [H, dk], a log-decay for every key channel; the chunked
         rule; ``w o / rms(o) sigmoid(y W_ga W_gb)`` per head; ``W_out``. Under
         the outer scopes of an attention layer with its own nested inside:
-        ``kda_conv`` (the convolution, SiLU and the l2 norms), ``kda_gates``
+        ``kda_conv`` (``kda_prologue``: the convolution, SiLU and the l2
+        norms, nothing else of q, k or v), ``kda_gates``
         (beta, g, the statistics of g), ``kda_scan``, ``kda_out_norm``. g,
         beta, the norms and the rule's state are float32; the projections and
         the rule's matmul operands are the compute dtype."""
@@ -1905,9 +1909,7 @@ class Transformer:
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec
 
-        from ..ops.gated_delta import l2norm
-        from ..ops.kda import chunk_decay, chunk_sample, kda_chunked
-        from ..ops.ssm_conv import ssm_conv
+        from ..ops.kda import chunk_decay, chunk_sample, kda_chunked, kda_prologue
         from ..parallel.mesh import kernel_activation_spec, shard_kernel
 
         del rope
@@ -1920,20 +1922,17 @@ class Transformer:
         B, T = y.shape[:2]
         H, dk, dv = cfg.kda_heads, cfg.kda_key_dim, cfg.kda_value_dim
         f32 = jnp.float32
-        widths = (H * dk, H * dk, H * dv)
         with trace.scope("attn_qkv"):
             qkv = y @ lw["kda_w_qkv"]
             with trace.scope("kda_conv"):
-                # the three convolutions where the projection left their
-                # columns, per device on its own rows like the rule below
+                # convolution, SiLU and the l2 norms: one pass over q, k, v
+                # where the projection left their columns (``kda_prologue``),
+                # per device on its own rows like the rule below
                 rows = kernel_activation_spec(qkv.shape)
+                heads = kernel_activation_spec((B, T, H, dk))
                 q, k, v = shard_kernel(
-                    lambda x, w: ssm_conv(x, w, jnp.zeros((w.shape[1],), f32),
-                                          0, widths)[1:4],
-                    (rows, PartitionSpec()), (rows,) * 3)(qkv, lw["kda_conv_w"])
-                q = (l2norm(q.reshape(B, T, H, dk)) * dk ** -0.5).astype(y.dtype)
-                k = l2norm(k.reshape(B, T, H, dk)).astype(y.dtype)
-                v = v.reshape(B, T, H, dv)
+                    functools.partial(kda_prologue, heads=H, dk=dk, dv=dv),
+                    (rows, PartitionSpec()), (heads,) * 3)(qkv, lw["kda_conv_w"])
             with trace.scope("kda_gates"):
                 beta = jax.nn.sigmoid((y @ lw["kda_w_beta"]).astype(f32))
 

@@ -428,11 +428,13 @@ def _blocks(G, chunk_at):
     return wide, flat, state
 
 
-def _compiler_params():
+def _compiler_params(axes: int = 3):
+    """The launches' parameters: a grid of ``axes`` axes, the first two
+    parallel and every later one walked in order."""
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", "parallel") + ("arbitrary",) * (axes - 2),
         vmem_limit_bytes=64 * 1024 * 1024)
 
 
@@ -826,7 +828,10 @@ def gdn_prologue(qkvz, conv_w, key_heads: int, dk: int, dv: int,
     wants for z cost a copy of all of ``qkvz`` a pass (PR 40).
     The XLA body is ``silu(causal_conv1d)`` -> split -> ``l2norm`` -> repeat
     (the convolution's result rounded to the compute dtype before SiLU, the
-    norm's after it): the off-TPU path and the kernels' oracle."""
+    norm's after it): the off-TPU path and the kernels' oracle.
+    The same two kernel bodies serve the KDA mixer, whose projection puts all
+    q | all k | all v (``ops/kda.py`` ``kda_prologue``; ``_prologue_core``'s
+    ``parts``)."""
     route = prologue_route(qkvz, conv_w, dk, dv)
     if route == "xla":
         return _gdn_prologue_xla(qkvz, conv_w, key_heads, dk, dv, eps)
@@ -891,15 +896,19 @@ def _gdn_prologue_pallas(qkvz, conv_w, Hk, dk, dv, eps=1e-6, rows=ROWS,
 
 
 @functools.lru_cache(maxsize=None)
-def _prologue_core(K, dk, dv, rep, eps, R, interpret):
+def _prologue_core(K, dk, dv, rep, eps, R, interpret, parts=1):
     """The prologue on whole blocks of R rows as one ``jax.custom_vjp``:
     (x [B, T, Hk * W], w [Hk / G, 8, G W - rep dv] float32) -> q, k, v, z
     [B, Hv, T, d]; G follows from the two shapes. The input is the only
     residual; each launch under its own jit, built once (see
-    ``_delta_core``)."""
+    ``_delta_core``). ``parts`` says where a head's segments lie in x
+    (``_segments``): 1, a key head's group side by side, as above; 3, all q |
+    all k | all v with no z (the KDA mixer's projection, ``ops/kda.py``
+    ``kda_prologue``): (x [B, T, 3 H d], w [3 H / G, 8, G d]) -> q, k, v."""
     import jax
 
-    static = dict(K=K, dk=dk, dv=dv, rep=rep, eps=eps, R=R, interpret=interpret)
+    static = dict(K=K, dk=dk, dv=dv, rep=rep, eps=eps, R=R, interpret=interpret,
+                  parts=parts)
     forward = jax.jit(functools.partial(_prologue_forward, **static))
     backward = jax.jit(functools.partial(_prologue_backward, **static))
 
@@ -917,7 +926,7 @@ def _prologue_core(K, dk, dv, rep, eps, R, interpret):
     return core
 
 
-def _prologue_blocks(x, w, dk, dv, rep, R, block_at):
+def _prologue_blocks(x, w, dk, dv, rep, R, block_at, parts=1):
     """(groups, heads, rows, halo, weights, wide) of a launch on x
     [B, T, Hk * W] and w [Hk / G, 8, Cw]: the ``groups`` of G key heads, the
     ``heads`` = G rep value heads of one, and the block specs of a grid step
@@ -926,45 +935,61 @@ def _prologue_blocks(x, w, dk, dv, rep, R, block_at):
     the group starts), ``halo`` the ``_HALO`` rows before them (the first
     block reads its own and masks them), ``weights``, and ``wide(d)`` for the
     group's heads of q, k, v, z [B, Hv, T, d] (d is the array's whole minor
-    axis, a lane tile or not)."""
+    axis, a lane tile or not). At ``parts`` = 3 (x [B, T, 3 H d], w
+    [3 H / G, 8, G d]) the grid has a fourth, innermost axis p, the part: a
+    step takes the G d lanes of its G heads out of part p's columns of x, and
+    ``wide`` blocks do not move with p (a step writes the one of q, k, v
+    that is its part's; the block leaves when the group's three are in)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    groups, _, Cw = w.shape
-    lanes = x.shape[-1] // groups
-    heads = lanes // (2 * dk + 2 * rep * dv) * rep
-    rows = pl.BlockSpec((1, R, lanes), lambda b, h, n: (b, block_at(n), h))
-    halo = pl.BlockSpec((1, _HALO, lanes), lambda b, h, n: (
-        b, jnp.maximum(block_at(n) * (R // _HALO) - 1, 0), h))
-    weights = pl.BlockSpec((1, 8, Cw), lambda b, h, n: (h, 0, 0))
-    wide = lambda d: pl.BlockSpec((1, heads, R, d), lambda b, h, n: (b, h, block_at(n), 0))
+    groups, Cw = w.shape[0] // parts, w.shape[2]
+    lanes = x.shape[-1] // w.shape[0]
+    heads = lanes // (2 * dk + 2 * rep * dv) * rep if parts == 1 else lanes // dk
+    # the lane block of group h (of part p)
+    at = lambda h, *p: h if not p else p[0] * groups + h
+    rows = pl.BlockSpec((1, R, lanes), lambda b, h, n, *p: (b, block_at(n), at(h, *p)))
+    halo = pl.BlockSpec((1, _HALO, lanes), lambda b, h, n, *p: (
+        b, jnp.maximum(block_at(n) * (R // _HALO) - 1, 0), at(h, *p)))
+    weights = pl.BlockSpec((1, 8, Cw), lambda b, h, n, *p: (at(h, *p), 0, 0))
+    wide = lambda d: pl.BlockSpec(
+        (1, heads, R, d), lambda b, h, n, *p: (b, h, block_at(n), 0))
     return groups, heads, rows, halo, weights, wide
 
 
-def _prologue_forward(x, w, K, dk, dv, rep, eps, R, interpret):
-    """The forward kernel's launch -> [q, k, v, z]."""
+# The launches' names by ``parts``: the DeltaNet mixer's, the KDA mixer's
+_PROLOGUE_STEM = {1: "gdn_prologue", 3: "kda_prologue"}
+
+
+def _prologue_forward(x, w, K, dk, dv, rep, eps, R, interpret, parts=1):
+    """The forward kernel's launch -> [q, k, v, z] ([q, k, v] at ``parts``
+    = 3)."""
     import jax
     from jax.experimental import pallas as pl
 
     B, Tp, _ = x.shape
     groups, heads, rows, halo, weights, wide = _prologue_blocks(
-        x, w, dk, dv, rep, R, lambda n: n)
+        x, w, dk, dv, rep, R, lambda n: n, parts)
     out = lambda d: jax.ShapeDtypeStruct((B, groups * heads, Tp, d), x.dtype)
+    widths = [dk, dk, dv, dv][:4 if parts == 1 else 3]
+    grid = (B, groups, Tp // R) + (() if parts == 1 else (parts,))
     return pl.pallas_call(
-        functools.partial(_prologue_fwd_kernel, K=K, dk=dk, dv=dv, rep=rep, eps=eps),
-        grid=(B, groups, Tp // R),
+        functools.partial(_prologue_fwd_kernel, K=K, dk=dk, dv=dv, rep=rep, eps=eps,
+                          parts=parts),
+        grid=grid,
         in_specs=[rows, halo, weights],
-        out_specs=[wide(dk), wide(dk), wide(dv), wide(dv)],
-        out_shape=[out(dk), out(dk), out(dv), out(dv)],
-        compiler_params=_compiler_params(), interpret=interpret,
-        name="gdn_prologue_fwd",
+        out_specs=[wide(d) for d in widths],
+        out_shape=[out(d) for d in widths],
+        compiler_params=_compiler_params(len(grid)), interpret=interpret,
+        name=_PROLOGUE_STEM[parts] + "_fwd",
     )(x, x, w)
 
 
-def _prologue_backward(x, w, dq, dk_, dv_, dz, K, dk, dv, rep, eps, R, interpret):
-    """The backward kernel's launch -> [dx, dw]. The sweep runs over the row
-    blocks from the last to the first; dw comes out as [B, Hk / G, 8 K, Cw]
-    partial sums (one a batch row and sublane) and is summed here."""
+def _prologue_backward(x, w, *cotangents, K, dk, dv, rep, eps, R, interpret, parts=1):
+    """The backward kernel's launch on the cotangents of q, k, v (and z) ->
+    [dx, dw]. The sweep runs over the row blocks from the last to the first;
+    dw comes out as [B, Hk / G, parts 8 K, Cw] partial sums (one a batch row
+    and sublane) and is summed here."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -975,36 +1000,70 @@ def _prologue_backward(x, w, dq, dk_, dv_, dz, K, dk, dv, rep, eps, R, interpret
     Cw = w.shape[2]
     N = Tp // R
     groups, _, rows, halo, weights, wide = _prologue_blocks(
-        x, w, dk, dv, rep, R, lambda n: N - 1 - n)
+        x, w, dk, dv, rep, R, lambda n: N - 1 - n, parts)
+    widths = [dk, dk, dv, dv][:len(cotangents)]
+    grid = (B, groups, N) + (() if parts == 1 else (parts,))
     dx, dw = pl.pallas_call(
-        functools.partial(_prologue_bwd_kernel, K=K, dk=dk, dv=dv, rep=rep, eps=eps),
-        grid=(B, groups, N),
-        in_specs=[rows, halo, weights, wide(dk), wide(dk), wide(dv), wide(dv)],
-        out_specs=[rows, pl.BlockSpec((1, 1, 8 * K, Cw), lambda b, h, n: (b, h, 0, 0))],
+        functools.partial(_prologue_bwd_kernel, K=K, dk=dk, dv=dv, rep=rep, eps=eps,
+                          parts=parts),
+        grid=grid,
+        in_specs=[rows, halo, weights] + [wide(d) for d in widths],
+        out_specs=[rows, pl.BlockSpec((1, 1, parts * 8 * K, Cw),
+                                      lambda b, h, n, *p: (b, h, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct((B, groups, 8 * K, Cw), f32)],
-        scratch_shapes=[pltpu.VMEM((8, Cw), f32)],
-        compiler_params=_compiler_params(), interpret=interpret,
-        name="gdn_prologue_bwd",
-    )(x, x, w, dq, dk_, dv_, dz)
-    dw = jnp.sum(dw.reshape(B, groups, K, 8, Cw), axis=(0, 3))
+                   jax.ShapeDtypeStruct((B, groups, parts * 8 * K, Cw), f32)],
+        scratch_shapes=[pltpu.VMEM((parts * 8, Cw), f32)],
+        compiler_params=_compiler_params(len(grid)), interpret=interpret,
+        name=_PROLOGUE_STEM[parts] + "_bwd",
+    )(x, x, w, *cotangents)
+    if parts == 1:
+        dw = jnp.sum(dw.reshape(B, groups, K, 8, Cw), axis=(0, 3))
+    else:       # [groups, parts, K, Cw] -> w's order, part-major
+        dw = jnp.sum(dw.reshape(B, groups, parts, K, 8, Cw), axis=(0, 4))
+        dw = jnp.swapaxes(dw, 0, 1).reshape(parts * groups, K, Cw)
     return dx, jnp.pad(dw, ((0, 0), (0, 8 - K), (0, 0)))
 
 
-def _segments(dk, dv, rep, lanes):
+def _segments(dk, dv, rep, lanes, part=None):
     """(start, width, kind, head) of every head's channels among the
     ``lanes`` of ``qkvz`` a grid step takes, a group of key heads side by
     side, W = 2 dk + 2 rep dv each: a key head's q and k (``head``: the
     first of the ``rep`` value heads it serves), then its ``rep`` value
     heads' v; each v's z lies ``rep * dv`` lanes on. Starts are where the
     projection put them: lane tiles' boundaries at 128 / 128, any multiple
-    of 32 at 96 / 192."""
+    of 32 at 96 / 192. ``part`` 0 / 1 / 2: the step's lanes are G heads' q,
+    k or v side by side (all q | all k | all v: every start a lane tile's).
+    The kernels unroll the segments inside a trip on purpose: a segment's
+    chain (taps, SiLU, row sum, rsqrt) waits on itself, and side by side
+    the chains overlap. A loop over the heads with one segment's body, the
+    lanes dynamic, read 3.2 / 5.5 ms a forward / backward launch at KDA's
+    shape whatever G, where four unrolled read 2.05 / 3.43 and ONE 3.55 /
+    6.24 (my chip runs, PR 68)."""
+    if part is not None:
+        d = (dk, dk, dv)[part]
+        return [(g * d, d, "qkv"[part], g) for g in range(lanes // d)]
     W = 2 * dk + 2 * rep * dv
     segs = []
     for g in range(lanes // W):
         segs += [(g * W, dk, "q", g * rep), (g * W + dk, dk, "k", g * rep)]
         segs += [(g * W + 2 * dk + r * dv, dv, "v", g * rep + r) for r in range(rep)]
     return segs
+
+
+def _each_part(parts, run):
+    """``run(part, step, steps)`` for the part a grid step works on:
+    ``part`` None where the segments lie side by side (one part), else in
+    the branch of the grid's innermost index. ``step()`` / ``steps()``: the
+    step's row block and their number, read outside the branches (the
+    interpreter resolves no grid index inside one)."""
+    from jax.experimental import pallas as pl
+
+    if parts == 1:
+        return run(None, lambda: pl.program_id(2), lambda: pl.num_programs(2))
+    step, steps = pl.program_id(2), pl.num_programs(2)
+    for part in range(parts):
+        pl.when(pl.program_id(3) == part)(
+            functools.partial(run, part, lambda: step, lambda: steps))
 
 
 def _rows_before(halo_ref, Cw, at_start):
@@ -1040,44 +1099,50 @@ def _taps(ext, K):
             for s in range(K - 1, -1, -1)]
 
 
-def _prologue_fwd_kernel(x_ref, halo_ref, w_ref, q_ref, k_ref, v_ref, z_ref, *,
-                         K, dk, dv, rep, eps):
+def _prologue_fwd_kernel(x_ref, halo_ref, w_ref, q_ref, k_ref, v_ref, z_ref=None, *,
+                         K, dk, dv, rep, eps, parts=1):
     """R rows of one group of key heads of one batch row: per head's
     segment (``_segments``) the convolution over the K rows that end at a
     row, SiLU and (q, k) the l2 norm, float32 until the write; q and k are
     written to the key head's ``rep`` value heads, z's channels are
-    copied."""
+    copied (``parts`` = 1; at 3 the step's segments are one part's, there is
+    no z, and the step writes that part's output alone)."""
     import jax
     import jax.numpy as jnp
 
     from jax.experimental import pallas as pl
 
-    segs = _segments(dk, dv, rep, x_ref.shape[2])
-    w = [[w_ref[0, j:j + 1, a:a + n] for j in range(K)] for a, n, _, _ in segs]
-    before = _rows_before(halo_ref, w_ref.shape[2], pl.program_id(2) == 0)
+    def run(part, step, steps):
+        segs = _segments(dk, dv, rep, x_ref.shape[2], part)
+        w = [[w_ref[0, j:j + 1, a:a + n] for j in range(K)] for a, n, _, _ in segs]
+        before = _rows_before(halo_ref, w_ref.shape[2], step() == 0)
 
-    def trip(c, carry):
-        for i, (a, n, kind, h) in enumerate(segs):
-            ext, at = _chunk_rows(x_ref, before, c, slice(a, a + n))
-            pre = sum(wj * xj for wj, xj in zip(w[i], _taps(ext, K)))
-            act = pre * jax.nn.sigmoid(pre)
-            if kind != "v":
-                unit = jax.lax.rsqrt(jnp.sum(act * act, axis=-1, keepdims=True) + eps)
-                out = (act * (unit * dk ** -0.5 if kind == "q" else unit)).astype(q_ref.dtype)
-                for r in range(rep):
-                    (q_ref if kind == "q" else k_ref)[0, h + r, at, :] = out
-            else:
-                v_ref[0, h, at, :] = act.astype(v_ref.dtype)
-                z_ref[0, h, at, :] = x_ref[0, at, pl.ds(a + rep * dv, dv)]
-        return carry
+        def trip(c, carry):
+            for i, (a, n, kind, h) in enumerate(segs):
+                ext, at = _chunk_rows(x_ref, before, c, slice(a, a + n))
+                pre = sum(wj * xj for wj, xj in zip(w[i], _taps(ext, K)))
+                act = pre * jax.nn.sigmoid(pre)
+                if kind != "v":
+                    unit = jax.lax.rsqrt(jnp.sum(act * act, axis=-1, keepdims=True) + eps)
+                    out = (act * (unit * dk ** -0.5 if kind == "q" else unit)).astype(
+                        q_ref.dtype)
+                    for r in range(rep):
+                        (q_ref if kind == "q" else k_ref)[0, h + r, at, :] = out
+                else:
+                    v_ref[0, h, at, :] = act.astype(v_ref.dtype)
+                    if z_ref is not None:
+                        z_ref[0, h, at, :] = x_ref[0, at, pl.ds(a + rep * dv, dv)]
+            return carry
 
-    jax.lax.fori_loop(0, x_ref.shape[1] // _SUB, trip, 0)
+        jax.lax.fori_loop(0, x_ref.shape[1] // _SUB, trip, 0)
+
+    _each_part(parts, run)
 
 
-def _prologue_bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dz_ref,
-                         dx_ref, dw_ref, ahead, *, K, dk, dv, rep, eps):
-    """The same block's gradients; the grid's last axis walks the row blocks
-    from the last to the first, and so do the trips inside a block. The
+def _prologue_bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, *rest,
+                         K, dk, dv, rep, eps, parts=1):
+    """The same block's gradients; the grid's row-block axis walks the row
+    blocks from the last to the first, and so do the trips inside a block. The
     pre-activation is computed again in float32; the norm's, SiLU's and the
     repeat's transposes (a sum over the ``rep`` heads) stay in registers.
     The convolution's transpose needs the pre-activation's cotangent of the
@@ -1085,58 +1150,69 @@ def _prologue_bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dz_ref,
     the block after this one (zeros at the end), and each trip hands its
     own first 8 rows' to the trip before it. dw sums over all rows: 8
     partial sums (one a sublane) a tap, accumulated in the output block
-    over the walk. z's cotangent is copied into its channels of dx."""
+    over the walk. z's cotangent is copied into its channels of dx. At
+    ``parts`` = 3 (``rest`` without dz) ``ahead`` and dw's block hold a part
+    after the other in their rows, each its own over the walk."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     f32 = jnp.float32
-    segs = _segments(dk, dv, rep, x_ref.shape[2])
+    dz_ref = rest[0] if len(rest) == 4 else None
+    dx_ref, dw_ref, ahead = rest[-3:]
     trips = x_ref.shape[1] // _SUB
-    w = [[w_ref[0, j:j + 1, a:a + n] for j in range(K)] for a, n, _, _ in segs]
-    before = _rows_before(halo_ref, w_ref.shape[2],
-                          pl.program_id(2) == pl.num_programs(2) - 1)
 
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        ahead[...] = jnp.zeros_like(ahead)
-        dw_ref[...] = jnp.zeros_like(dw_ref)
+    def run(part, step, steps):
+        segs = _segments(dk, dv, rep, x_ref.shape[2], part)
+        p = part or 0
+        mine = slice(8 * p, 8 * p + 8)              # the part's rows of ``ahead``
+        w = [[w_ref[0, j:j + 1, a:a + n] for j in range(K)] for a, n, _, _ in segs]
+        before = _rows_before(halo_ref, w_ref.shape[2], step() == steps() - 1)
 
-    def trip(t, after):
-        c = trips - 1 - t
-        first = []
-        for i, (a, n, kind, h) in enumerate(segs):
-            lanes = slice(a, a + n)
-            ext, at = _chunk_rows(x_ref, before, c, lanes)
-            taps = _taps(ext, K)
-            pre = sum(wj * xj for wj, xj in zip(w[i], taps))
-            sig = jax.nn.sigmoid(pre)
-            if kind != "v":
-                ref = dq_ref if kind == "q" else dk_ref
-                g = sum(ref[0, h + r, at, :].astype(f32) for r in range(rep))
-                act = pre * sig
-                unit = jax.lax.rsqrt(jnp.sum(act * act, axis=-1, keepdims=True) + eps)
-                y = act * unit
-                dact = (g - y * jnp.sum(g * y, axis=-1, keepdims=True)) * (
-                    unit * dk ** -0.5 if kind == "q" else unit)
-            else:
-                dact = dv_ref[0, h, at, :].astype(f32)
-                dx_ref[0, at, pl.ds(a + rep * dv, dv)] = dz_ref[0, h, at, :]
-            dpre = dact * (sig * (1.0 + pre * (1.0 - sig)))
-            for j, xj in enumerate(taps):
-                p = dpre * xj
-                dw_ref[0, 0, 8 * j:8 * j + 8, lanes] += sum(
-                    p[s:s + 8] for s in range(0, _SUB, 8))
-            # dx[t] = sum_j w[j] dpre[t + K - 1 - j]
-            ext = jnp.concatenate([dpre, after[i]], axis=0)
-            dx = sum(wj * (dpre if u == 0 else pltpu.roll(ext, _SUB + 8 - u, 0)[:_SUB])
-                     for wj, u in zip(w[i], range(K - 1, -1, -1)))
-            dx_ref[0, at, lanes] = dx.astype(dx_ref.dtype)
-            first.append(dpre[:8])
-        return tuple(first)
+        @pl.when(step() == 0)
+        def _():
+            ahead[mine, :] = jnp.zeros((8, ahead.shape[1]), f32)
+            dw_ref[:, :, 8 * K * p:8 * K * (p + 1), :] = jnp.zeros(
+                (1, 1, 8 * K, dw_ref.shape[3]), f32)
 
-    after = jax.lax.fori_loop(
-        0, trips, trip, tuple(ahead[:, a:a + n] for a, n, _, _ in segs))
-    for (a, n, _, _), rows in zip(segs, after):
-        ahead[:, a:a + n] = rows
+        def trip(t, after):
+            c = trips - 1 - t
+            first = []
+            for i, (a, n, kind, h) in enumerate(segs):
+                lanes = slice(a, a + n)
+                ext, at = _chunk_rows(x_ref, before, c, lanes)
+                taps = _taps(ext, K)
+                pre = sum(wj * xj for wj, xj in zip(w[i], taps))
+                sig = jax.nn.sigmoid(pre)
+                if kind != "v":
+                    ref = dq_ref if kind == "q" else dk_ref
+                    g = sum(ref[0, h + r, at, :].astype(f32) for r in range(rep))
+                    act = pre * sig
+                    unit = jax.lax.rsqrt(jnp.sum(act * act, axis=-1, keepdims=True) + eps)
+                    y = act * unit
+                    dact = (g - y * jnp.sum(g * y, axis=-1, keepdims=True)) * (
+                        unit * dk ** -0.5 if kind == "q" else unit)
+                else:
+                    dact = dv_ref[0, h, at, :].astype(f32)
+                    if dz_ref is not None:
+                        dx_ref[0, at, pl.ds(a + rep * dv, dv)] = dz_ref[0, h, at, :]
+                dpre = dact * (sig * (1.0 + pre * (1.0 - sig)))
+                for j, xj in enumerate(taps):
+                    o, prod = 8 * (K * p + j), dpre * xj
+                    dw_ref[0, 0, o:o + 8, lanes] += sum(
+                        prod[s:s + 8] for s in range(0, _SUB, 8))
+                # dx[t] = sum_j w[j] dpre[t + K - 1 - j]
+                ext = jnp.concatenate([dpre, after[i]], axis=0)
+                dx = sum(wj * (dpre if u == 0 else pltpu.roll(ext, _SUB + 8 - u, 0)[:_SUB])
+                         for wj, u in zip(w[i], range(K - 1, -1, -1)))
+                dx_ref[0, at, lanes] = dx.astype(dx_ref.dtype)
+                first.append(dpre[:8])
+            return tuple(first)
+
+        after = jax.lax.fori_loop(
+            0, trips, trip, tuple(ahead[mine, a:a + n] for a, n, _, _ in segs))
+        for (a, n, _, _), rows in zip(segs, after):
+            ahead[mine, a:a + n] = rows
+
+    _each_part(parts, run)

@@ -90,6 +90,11 @@ walks the chunks from the last to the first with dS in VMEM and computes the
 chunk's own matrices AGAIN. The decays' gradient needs no sum of its own:
 every place Gamma enters is ``x * exp(+-Gamma)``, so ``dGamma = sum x dx``
 over those places (``q dq - k dk`` for QK, the published kernels' identity).
+
+``kda_prologue`` (the end of this file) is what lies between the mixer's
+projection and the rule: the three causal convolutions, SiLU and the l2 norms
+of q and k in one pass forward and one backward, through
+``gated_delta.gdn_prologue``'s kernels told where KDA's segments lie.
 """
 
 from __future__ import annotations
@@ -97,9 +102,10 @@ from __future__ import annotations
 import functools
 import math
 
-from .gated_delta import (CHUNK, _NT, _TN, _compiler_params, _each,
-                          _inverse, _products, _unit_lower_inverse,
-                          _whole_chunks)
+from .gated_delta import (CHUNK, ROWS, _GROUP_LANES, _NT, _SUB, _TN,
+                          _compiler_params, _each, _inverse, _products,
+                          _prologue_core, _unit_lower_inverse, _whole_chunks,
+                          causal_conv1d, l2norm)
 
 # Rows of a sub-block of a chunk (module docstring): the published kernels' 16,
 # what the XLA form takes, and with it the benchmark's count
@@ -309,10 +315,15 @@ def chunk_decay(g, chunk: int = CHUNK):
 
 def _kda_pallas(q, k, v, g, beta, interpret: bool = False):
     """``kda_chunked`` through the kernels. q, k, v and g go in as
-    [B, H, T, d] and o comes out so (XLA folds the transposes into the
-    producing fusions, as for the scalar rule); beta goes in as
-    [B, H / G, N, G, C], a row of C numbers a chunk and head. The padding
-    and the transposes are XLA's, and so are their gradients."""
+    [B, H, T, d] and o comes out so; beta goes in as [B, H / G, N, G, C], a
+    row of C numbers a chunk and head. The padding and the transposes are
+    XLA's, and so are their gradients. XLA does NOT fold a transpose into
+    whatever produces the array: behind ``ssm_conv``'s kernels and its own
+    l2 norms it wrote q, k and v twice more on the way here (31 ms of
+    ``copy`` a step of ``kimilinear-train``, PR 67's trace). The transposes
+    of q, k, v and of their cotangents cancel against ``kda_prologue``'s,
+    whose kernels write and read [B, H, T, d] themselves (PR 68); those of
+    g, o and do are still XLA's passes."""
     import jax.numpy as jnp
 
     f32 = jnp.float32
@@ -716,3 +727,121 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, do_ref,
         # g's cotangent: dg_t = sum_{i >= t} dGamma_i
         dg_ref[0, h] = exact(x.ones, dgamma, _TN)
 
+
+
+# ----------------------------------------------------------------------
+# The mixer's prologue: from the projection to what the rule takes
+# ----------------------------------------------------------------------
+
+# Heads a grid step of the prologue's kernels takes at most (of ONE of q, k,
+# v: the step's block of ``qkv`` is that many heads' lanes side by side, and
+# the kernel bodies hold that many segments unrolled). Alone at
+# [1, 16384, 12288] a forward / backward launch read 3.55 / 6.24 ms at one
+# head a step, 2.58 / 4.32 at two, 2.05 / 3.43 at four, 1.85 / 3.25 at eight
+# (my chip run, PR 68): eight would take 2.3 ms more off the cell's step and
+# cost it twice four's seconds of ``setup_s`` (the bodies are lowered anew for
+# every program of a run that holds them: ~3.5 s at four)
+_PROLOGUE_HEADS = 4
+
+
+def _prologue_heads(heads: int, dk: int) -> int:
+    """G, the heads a grid step of the prologue's kernels takes: the most
+    that divide ``heads``, up to ``_PROLOGUE_HEADS`` and to the widest block
+    ``gated_delta``'s kernels were run at (``_GROUP_LANES``); 0 where one
+    head is wider than that."""
+    fit = min(_PROLOGUE_HEADS, _GROUP_LANES // dk)
+    return max((G for G in range(1, fit + 1) if heads % G == 0), default=0)
+
+
+def prologue_route(qkv, conv_w, dk: int, dv: int) -> str:
+    """Which form :func:`kda_prologue` runs, from what it can observe, as
+    ``gated_delta.prologue_route`` does for the scalar rule's mixer: "pallas"
+    on a TPU backend at an eligible shape, "interpret" at such a shape under
+    ``SXT_FUSED_INTERPRET=1``, else "xla". Eligible: q, k and v heads of one
+    width that is whole lane tiles (a grid step takes the same block of
+    lanes out of each of the three column ranges; the rule's kernels ask
+    for whole tiles too) and no wider than a step takes, ``qkv`` and
+    ``conv_w`` of the same 2 H dk + H dv columns, a convolution no wider than
+    one 8-row sublane tile, bf16 or float32 activations."""
+    import jax.numpy as jnp
+
+    from .dispatch import interpret_forced, pallas_enabled
+
+    heads = conv_w.shape[1] // (2 * dk + dv)
+    eligible = (dk == dv and dk % 128 == 0 and heads > 0
+                and qkv.shape[-1] == conv_w.shape[1] == heads * (2 * dk + dv)
+                and _prologue_heads(heads, dk) > 0
+                and conv_w.shape[0] <= 8
+                and qkv.dtype in (jnp.bfloat16, jnp.float32))
+    if not eligible:
+        return "xla"
+    if interpret_forced():
+        return "interpret"
+    return "pallas" if pallas_enabled() else "xla"
+
+
+def kda_prologue(qkv, conv_w, heads: int, dk: int, dv: int, eps: float = 1e-6,
+                 rows: int = ROWS):
+    """Everything of a KDA layer between its projection and the rule:
+    ``qkv`` [B, T, 2 H dk + H dv] as the projection wrote it (all q | all k |
+    all v, a head's channels side by side inside each) and ``conv_w``
+    [K, 2 H dk + H dv] over the same columns -> q, k [B, T, H, dk] and v
+    [B, T, H, dv] in ``qkv``'s dtype: the causal depthwise convolution
+    without bias, SiLU, the l2 norm of q (times ``dk ** -0.5``) and of k.
+
+    Two bodies, chosen by :func:`prologue_route`. The kernels are
+    ``gated_delta.gdn_prologue``'s, told that the segments lie in three
+    column ranges (``_prologue_core(..., parts=3)``; launches
+    ``kda_prologue_fwd`` / ``kda_prologue_bwd`` behind one
+    ``jax.custom_vjp`` whose residuals are its inputs): the grid is (row of
+    the batch, group of G heads, block of ``rows`` rows, part), the part
+    innermost, and a step reads the [rows, G d] block of ITS part of ``qkv``
+    where the projection wrote it (and the 16 rows before it), so ``qkv`` is
+    read once forward and once more, with the three cotangents, backward,
+    and d``qkv`` is written once. The convolution's accumulator, SiLU, the
+    sum of squares and the rsqrt are float32 and the result is rounded to
+    the compute dtype ONCE, at the write. They write q, k, v as [B, H, T, d],
+    which is what the rule's kernels read: the transpose back to
+    [B, T, H, d] here and the rule's own to [B, H, T, d] cancel in XLA.
+    The XLA body is ``silu(causal_conv1d)`` (float32 to one rounding, as
+    ``ops/ssm_conv.py``'s) -> split -> ``l2norm`` (rounded again): the
+    off-TPU path and the kernels' oracle."""
+    route = prologue_route(qkv, conv_w, dk, dv)
+    if route == "xla":
+        return _kda_prologue_xla(qkv, conv_w, heads, dk, dv, eps)
+    return _kda_prologue_pallas(qkv, conv_w, heads, dk, dv, eps, rows,
+                                interpret=route == "interpret")
+
+
+def _kda_prologue_xla(qkv, conv_w, H, dk, dv, eps=1e-6):
+    """``kda_prologue`` as XLA ops."""
+    import jax
+    import jax.numpy as jnp
+
+    B, T, _ = qkv.shape
+    mixed = jax.nn.silu(causal_conv1d(qkv.astype(jnp.float32), conv_w)).astype(qkv.dtype)
+    q, k, v = jnp.split(mixed, [H * dk, 2 * H * dk], axis=-1)
+    q = (l2norm(q.reshape(B, T, H, dk), eps) * dk ** -0.5).astype(qkv.dtype)
+    k = l2norm(k.reshape(B, T, H, dk), eps).astype(qkv.dtype)
+    return q, k, v.reshape(B, T, H, dv)
+
+
+def _kda_prologue_pallas(qkv, conv_w, H, dk, dv, eps=1e-6, rows=ROWS,
+                         interpret: bool = False):
+    """``kda_prologue`` through the kernels, G heads a grid step
+    (``_prologue_heads``). ``conv_w`` goes in as [3 H / G, 8, G d] float32:
+    the columns as they are, a group of G heads of one part a row, K padded
+    to a sublane tile. T is padded to whole blocks of rows with zeros
+    (nothing where ``rows`` divides it); the padding and the transposes back
+    to [B, T, H, d] are XLA's, and so are their gradients."""
+    import jax.numpy as jnp
+
+    T, K = qkv.shape[1], conv_w.shape[0]
+    G = _prologue_heads(H, dk)
+    assert G and dk == dv and rows % _SUB == 0, (H, dk, dv, rows)
+    w = jnp.pad(conv_w.astype(jnp.float32), ((0, 8 - K), (0, 0)))
+    w = jnp.swapaxes(w.reshape(8, 3 * H // G, G * dk), 0, 1)
+    R = min(rows, -(-T // _SUB) * _SUB)
+    x = jnp.pad(qkv, ((0, 0), (0, -T % R), (0, 0)))
+    core = _prologue_core(K, dk, dv, 1, float(eps), R, interpret, parts=3)
+    return tuple(jnp.swapaxes(a[:, :, :T], 1, 2) for a in core(x, w))

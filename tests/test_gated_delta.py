@@ -425,3 +425,47 @@ def test_the_prologue_is_chosen_by_backend_and_shape(monkeypatch, why, dk, dv, K
     if want == "xla":
         # an ineligible shape runs the composition: no kernel in the program
         assert calls(lambda x, w: gd.gdn_prologue(x, w, Hk, dk, dv), qkvz, conv_w) == []
+
+
+# The two launches of ``gdn_prologue`` as the commit before PR 68 lowered them
+# for the TPU (4f76939; sha256 of the lowered text with every Mosaic body
+# printed without its source locations, ``testing/program_text.canonical``):
+# PR 68 taught the kernel bodies where the KDA mixer's segments lie, and the
+# DeltaNet cells' programs did not move. (Hk, rep, dk, dv), dtype, tokens.
+PROLOGUE_TEXTS = {
+    "qwen3next": ((16, 2, 128, 128), jnp.bfloat16, 8192,
+                  "554977398a05904252c49711c7d63c279d7784c73e178a41499a1efd557a10a9"),
+    "olmohybrid": ((30, 1, 96, 192), jnp.bfloat16, 8192,
+                   "ad28617db5b04908715680d58c3c1ccded0c09b192223c82660d0e1563606cb8"),
+    "one_unaligned_key_head": ((4, 1, 192, 192), jnp.bfloat16, 8192,
+                               "02493d04def02708c52def93484d614eaf77f759c13028957bb9ad305bc69af1"),
+    "float32_ragged": ((2, 2, 128, 128), jnp.float32, 600,
+                       "8499b1e0d2f905fe46c639580198cd0f48bc99232f837f2279e0344d35cdcf64"),
+}
+
+
+@pytest.mark.parametrize("case", list(PROLOGUE_TEXTS))
+def test_the_prologues_launches_lower_to_the_text_they_had(case):
+    """At the shapes ``qwen3next-train`` and ``olmohybrid-zero3-x4`` run the
+    prologue (a key head, a pair of them a grid step), at the third shape the
+    route admits and on a ragged float32 one. The text is this container's
+    JAX's: after an upgrade that changes the printer, take the hashes again
+    from a commit that is known good and say so."""
+    from shuffle_exchange_tpu.testing import program_text
+
+    (Hk, rep, dk, dv), dtype, T, want = PROLOGUE_TEXTS[case]
+
+    def both(qkvz, conv_w, *cotangents):
+        out, back = jax.vjp(lambda x, w: gd._gdn_prologue_pallas(x, w, Hk, dk, dv),
+                            qkvz, conv_w)
+        return out + back(cotangents)
+
+    shaped = jax.ShapeDtypeStruct
+    wide = lambda d: shaped((2, T, Hk * rep, d), dtype)
+    text = jax.jit(both).trace(
+        shaped((2, T, Hk * (2 * dk + 2 * rep * dv)), dtype),
+        shaped((4, Hk * (2 * dk + rep * dv)), jnp.float32),
+        wide(dk), wide(dk), wide(dv), wide(dv)).lower(lowering_platforms=("tpu",)).as_text()
+    got = program_text.hashes(text)
+    assert got["mosaic_bodies"] == 2
+    assert got["lowered_no_locations_sha"] == want

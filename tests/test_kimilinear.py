@@ -16,6 +16,7 @@ each leaf's norm.
 """
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -36,6 +37,7 @@ from shuffle_exchange_tpu.models import transformer as tr  # noqa: E402
 from shuffle_exchange_tpu.models.hf import (config_from_hf, kimi_linear_state_dict,  # noqa: E402
                                             params_from_state_dict)
 from shuffle_exchange_tpu.ops import gated_delta, kda  # noqa: E402
+from tests.test_gated_delta import calls  # noqa: E402
 
 HF = {"model_type": "kimi_linear", "architectures": ["KimiLinearForCausalLM"],
       "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 4,
@@ -318,6 +320,145 @@ def test_the_kernels_are_the_recurrence(monkeypatch, scale):
         assert float(jnp.abs(a - b).max()) <= 3e-4 * float(jnp.abs(b).max()) + 1e-7
 
 
+# ----------------------------------------------------------------------
+# The mixer's prologue (``kda_prologue``): kernels against the composition
+# ----------------------------------------------------------------------
+
+PROLOGUE_PARTS = ("q", "k", "v", "dqkv", "dconv_w")
+
+
+def prologue_inputs(K, dtype, T=150, B=2, H=4, d=128):
+    """(qkv, conv_w) as the mixer has them and cotangents of q, k, v: two
+    rows of 150 tokens in blocks of 64 rows is a first block (zeros before
+    position 0), one with a block on both sides, and a ragged last one."""
+    ks = jax.random.split(jax.random.PRNGKey(10 * K + H), 5)
+    qkv = jax.random.normal(ks[0], (B, T, 3 * H * d)).astype(dtype)
+    conv_w = 0.5 * jax.random.normal(ks[1], (K, 3 * H * d))
+    cotangents = tuple(jax.random.normal(k, (B, T, H, d)).astype(dtype) for k in ks[2:])
+    return (qkv, conv_w), cotangents
+
+
+def as_the_mixer_ran_it(qkv, conv_w, H, dk, dv):
+    """The prologue as ``_kda`` composed it before ``kda_prologue``:
+    ``ssm_conv`` on the three column ranges without bias, then ``l2norm``."""
+    from shuffle_exchange_tpu.ops.ssm_conv import ssm_conv
+
+    B, T, _ = qkv.shape
+    q, k, v = ssm_conv(qkv, conv_w, jnp.zeros((conv_w.shape[1],), jnp.float32),
+                       0, (H * dk, H * dk, H * dv))[1:4]
+    q = (gated_delta.l2norm(q.reshape(B, T, H, dk)) * dk ** -0.5).astype(qkv.dtype)
+    k = gated_delta.l2norm(k.reshape(B, T, H, dk)).astype(qkv.dtype)
+    return q, k, v.reshape(B, T, H, dv)
+
+
+def prologue_answers(fn, args, cotangents, heads, exact=False):
+    """(q, k, v, dqkv, dconv_w) of ``fn`` (a route is chosen while tracing)
+    in float32; ``exact``: on the same numbers held in float32 throughout."""
+    def both(args, cotangents):
+        qkv, conv_w = args
+        if exact:
+            qkv = qkv.astype(jnp.float32)
+            cotangents = tuple(c.astype(jnp.float32) for c in cotangents)
+        out, back = jax.vjp(lambda x, w: fn(x, w, *heads), qkv, conv_w)
+        return tuple(a.astype(jnp.float32) for a in out + back(cotangents))
+    return jax.jit(both)(args, cotangents)
+
+
+# (heads, head width, taps, tokens, heads a grid step): four heads a step and
+# two steps of them; 2 taps; 6 heads in threes; ONE head (the trainer test's
+# model); heads of two lane tiles; whole blocks of rows
+PROLOGUE_CASES = [(8, 128, 4, 150, 4), (4, 128, 2, 150, 4), (6, 128, 4, 80, 3),
+                  (1, 128, 4, 150, 1), (2, 256, 4, 80, 2), (4, 128, 4, 128, 4)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+@pytest.mark.parametrize("H, d, K, T, G", PROLOGUE_CASES, ids=lambda v: str(v))
+def test_prologue_kernels_equal_the_composition(monkeypatch, H, d, K, T, G, dtype):
+    """The one pass (interpreted; blocks of 64 rows) against the XLA body
+    and against ``ssm_conv`` + ``l2norm`` as the mixer ran them: q, k, v and
+    the gradients of ``qkv`` and ``conv_w``, two rows of the batch. float32:
+    the same numbers to rounding. bf16: the pass rounds once, at the write,
+    where the compositions round the convolution's result and again after the
+    norm: no part is further from the float32 composition than they are."""
+    heads = (H, d, d)
+    args, cotangents = prologue_inputs(K, dtype, T=T, H=H, d=d)
+    assert kda.prologue_route(*args, d, d) == "xla" and kda._prologue_heads(H, d) == G
+    want = prologue_answers(kda.kda_prologue, args, cotangents, heads)
+    older = prologue_answers(as_the_mixer_ran_it, args, cotangents, heads)
+    monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
+    assert kda.prologue_route(*args, d, d) == "interpret"
+    got = prologue_answers(functools.partial(kda.kda_prologue, rows=64), args, cotangents, heads)
+    monkeypatch.delenv("SXT_FUSED_INTERPRET")
+    gap = lambda a, b: float(jnp.linalg.norm((a - b).ravel()) / jnp.linalg.norm(b.ravel()))
+    for a, b, part in zip(got, want, PROLOGUE_PARTS):
+        assert a.shape == b.shape and bool(jnp.all(jnp.isfinite(a))), part
+    # the XLA body is the arithmetic the mixer had
+    for a, b, part in zip(want, older, PROLOGUE_PARTS):
+        assert gap(a, b) < 1e-6, (part, gap(a, b))
+    if dtype == jnp.float32:
+        for a, b, part in zip(got, want, PROLOGUE_PARTS):
+            assert gap(a, b) < 2e-6, (part, gap(a, b))
+    else:
+        exact = prologue_answers(kda.kda_prologue, args, cotangents, heads, exact=True)
+        for a, b, c, part in zip(got, want, exact, PROLOGUE_PARTS):
+            assert gap(a, c) <= 1.02 * gap(b, c) and gap(a, c) < 4e-3, (
+                part, gap(a, c), gap(b, c))
+
+
+def test_prologue_reads_its_own_sequence_and_nothing_later(monkeypatch):
+    """A bump at position 70 of row 0 (the second block's 7th row) moves no
+    output before it and nothing of row 1 (a grid step never reads another
+    sequence's rows); differentiated, the two launches carry their names."""
+    monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
+    (qkv, conv_w), cotangents = prologue_inputs(4, jnp.bfloat16)
+    run = lambda x: kda.kda_prologue(x, conv_w, 4, 128, 128, rows=64)
+    plain, bumped = run(qkv), run(qkv.at[0, 70].add(1.0))
+    for a, b in zip(plain, bumped):
+        np.testing.assert_array_equal(a[0, :70], b[0, :70])
+        np.testing.assert_array_equal(a[1], b[1])
+        assert bool(jnp.any(a[0, 70:74] != b[0, 70:74]))
+    assert calls(run, qkv) == [("kda_prologue_fwd", 3)]
+    both = lambda x, w: jax.vjp(lambda x, w: kda.kda_prologue(
+        x, w, 4, 128, 128, rows=64), x, w)[1](cotangents)
+    assert calls(both, qkv, conv_w) == [("kda_prologue_fwd", 3),
+                                              ("kda_prologue_bwd", 2)]
+
+
+@pytest.mark.parametrize("why, H, dk, dv, K, dtype, forced, want", [
+    ("eligible", 32, 128, 128, 4, jnp.bfloat16, True, "interpret"),
+    ("float32", 32, 128, 128, 4, jnp.float32, True, "interpret"),
+    ("a_sublane_tile_of_taps", 4, 128, 128, 8, jnp.bfloat16, True, "interpret"),
+    ("heads_of_two_lane_tiles", 4, 256, 256, 4, jnp.bfloat16, True, "interpret"),
+    ("off_a_tpu", 32, 128, 128, 4, jnp.bfloat16, False, "xla"),
+    # the tests' 16-wide heads; a width between lane tiles
+    ("narrow_heads", 2, 16, 16, 4, jnp.float32, True, "xla"),
+    ("heads_between_lane_tiles", 4, 192, 192, 4, jnp.bfloat16, True, "xla"),
+    # a step takes the same block of lanes out of each column range
+    ("values_wider_than_keys", 4, 128, 256, 4, jnp.bfloat16, True, "xla"),
+    ("more_taps_than_a_sublane_tile", 4, 128, 128, 9, jnp.bfloat16, True, "xla"),
+    ("float16", 4, 128, 128, 4, jnp.float16, True, "xla"),
+    # one head of 10 lane tiles: wider than the widest block that was run
+    ("a_head_wider_than_a_step_takes", 2, 1280, 1280, 4, jnp.bfloat16, True, "xla"),
+])
+def test_the_prologue_is_chosen_by_backend_and_shape(monkeypatch, why, H, dk, dv, K,
+                                                     dtype, forced, want):
+    if forced:
+        monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
+    qkv = jnp.zeros((1, 64, H * (2 * dk + dv)), dtype)
+    conv_w = jnp.zeros((K, H * (2 * dk + dv)), jnp.float32)
+    assert kda.prologue_route(qkv, conv_w, dk, dv) == want
+    if want == "xla":
+        # an ineligible shape runs the composition: no kernel in the program
+        assert calls(lambda x, w: kda.kda_prologue(x, w, H, dk, dv), qkv, conv_w) == []
+
+
+def test_the_prologue_takes_the_most_heads_a_step_that_divide_them():
+    """``_prologue_heads``: up to ``_PROLOGUE_HEADS`` and to the widest block
+    of lanes the shared kernels were run at."""
+    assert [kda._prologue_heads(H, 128) for H in (32, 6, 5, 1)] == [4, 3, 1, 1]
+    assert [kda._prologue_heads(4, d) for d in (256, 384, 1152, 1280)] == [4, 2, 1, 0]
+
+
 def test_the_statistics_read_one_chunk_in_sixteen():
     """``chunk_sample`` hands ``chunk_decay`` the first whole chunk of every
     run of 16 (all T where T is shorter than a chunk), so the step statistics
@@ -351,7 +492,8 @@ def test_constant_decay_over_the_channels_is_the_scalar_rule():
 
 def test_the_trainer_runs_the_rules_kernels(monkeypatch, case):
     """A model at 128 / 128 heads under ``SXT_FUSED_INTERPRET=1`` takes the
-    kernels (rule and convolutions) and reads the XLA forms' loss and
+    kernels (the prologue's two and the rule's, and no convolution
+    launch of ``ops/ssm_conv.py``'s) and reads the XLA forms' loss and
     gradients."""
     hf = {**HF, "hidden_size": 128, "num_hidden_layers": 5,
           "linear_attn_config": {**HF["linear_attn_config"], "num_heads": 1, "head_dim": 128}}
@@ -360,7 +502,12 @@ def test_the_trainer_runs_the_rules_kernels(monkeypatch, case):
     ids = case["ids"][:1, :41]
     plain = jax.jit(jax.value_and_grad(model.loss))(params, {"input_ids": ids})
     monkeypatch.setenv("SXT_FUSED_INTERPRET", "1")
-    fused = jax.jit(jax.value_and_grad(lambda p, b: model.loss(p, b)))(params, {"input_ids": ids})
+    step = jax.value_and_grad(lambda p, b: model.loss(p, b))
+    names = {name for name, _ in calls(step, params, {"input_ids": ids})}
+    # (nothing is checkpointed here: the forward that keeps the states alone)
+    assert names == {"kda_prologue_fwd", "kda_prologue_bwd", "kda_rule_fwd_keep",
+                     "kda_rule_bwd"}, names
+    fused = jax.jit(step)(params, {"input_ids": ids})
     assert abs(float(plain[0]) - float(fused[0])) < 1e-5
     worst = gaps(driver.flat_tree(fused[1]), {
         k: v for k, v in driver.flat_tree(plain[1]).items() if float(jnp.abs(v).max()) > 0})

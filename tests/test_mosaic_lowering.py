@@ -22,6 +22,8 @@ Keep every such test in THIS file (a second file can land on another worker,
 whose fixture then skips), and compile in the test's own process.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -402,6 +404,29 @@ def test_gdn_prologue_lowers(dtype):
         wide = lambda d: jnp.zeros((2, T, Hk * rep, d), dtype)
         _tpu_lower(functools.partial(_gdn_prologue_vjp, heads=(Hk, dk, dv)),
                    qkvz, conv_w, wide(dk), wide(dk), wide(dv), wide(dv))
+
+
+def _kda_prologue_vjp(qkv, conv_w, dq, dk, dv, heads=(32, 128, 128)):
+    """q, k, v and the two gradients through the KDA prologue's kernels
+    themselves (``gdn_prologue``'s bodies on three column ranges; the
+    dispatching entry takes the XLA form off a TPU)."""
+    from shuffle_exchange_tpu.ops.kda import _kda_prologue_pallas
+
+    out, back = jax.vjp(lambda x, w: _kda_prologue_pallas(x, w, *heads), qkv, conv_w)
+    return out + back((dq, dk, dv))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kda_prologue_lowers(dtype):
+    """The forward and the backward launch on the four-axis grid; a ragged
+    last block of rows; bf16 and float32 activations; four heads a grid
+    step, three (6 heads), one, and two heads of two lane tiles."""
+    for H, d, T in ((8, 128, 600), (6, 128, 100), (1, 128, 600), (2, 256, 100)):
+        qkv = jnp.zeros((2, T, 3 * H * d), dtype)
+        conv_w = jnp.zeros((4, 3 * H * d), jnp.float32)
+        wide = jnp.zeros((2, T, H, d), dtype)
+        _tpu_lower(functools.partial(_kda_prologue_vjp, heads=(H, d, d)),
+                   qkv, conv_w, wide, wide, wide)
 
 
 def _ssd_vjp(x, dt, A, B, C, cotangent):
@@ -1070,6 +1095,74 @@ def test_gdn_prologue_compiles(chip_compile, Hk, rep, dk, dv):
                             wide(dk), wide(dk), wide(dv), wide(dv))
     text = compiled.as_text()
     assert "gdn_prologue_fwd" in text and "gdn_prologue_bwd" in text
+
+
+def test_kda_prologue_compiles(chip_compile):
+    """The KDA prologue's two kernels at the shape ``kimilinear-train`` runs
+    them: one row of 16,384 tokens, ``qkv`` [1, 16384, 12288] (32 heads of
+    128 / 128, all q | all k | all v), 4 taps, bf16 with a float32
+    ``conv_w``; four heads of one part a grid step."""
+    wide = ((1, 16384, 32, 128), _BF16)
+    compiled = chip_compile(_kda_prologue_vjp, ((1, 16384, 12288), _BF16),
+                            ((4, 12288), _F32), wide, wide, wide)
+    text = compiled.as_text()
+    assert "kda_prologue_fwd" in text and "kda_prologue_bwd" in text
+
+
+def _moved_like_q(text):
+    """The names of the compiled program's ``copy`` / ``transpose``
+    instructions (fused or not) whose result has q's 16,384 x 32 x 128
+    elements, heads split off or not, in any order of the axes."""
+    import re
+
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]\S* (copy|transpose|fusion)\(", line)
+        if m and sorted(m.group(2).split(",")) in (["1", "128", "16384", "32"],
+                                                   ["1", "16384", "4096"]) and (
+                m.group(3) != "fusion" or "copy" in m.group(1) or "transpose" in m.group(1)):
+            found.append(m.group(1))
+    return found
+
+
+def test_nothing_moves_q_k_v_between_the_kda_prologue_and_the_rule(chip_compile):
+    """The layout witness: projection -> prologue -> rule and its gradient,
+    compiled for the described v5e at the cell's shape (g one row for every
+    token and o summed in float32, so nothing else of q's shape is in the
+    program). With ``kda_prologue``'s kernels the program holds NO copy or
+    transpose of an array of q's shape, forward or backward: the kernels
+    write q, k, v (read dq, dk, dv) as [B, H, T, d], the rule's own layout.
+    Composed as the mixer ran it before (``ssm_conv``'s kernels, then XLA's
+    l2 norms) the same compile shows XLA's copies (six forward, nine with
+    the gradient, when this was written), which is what the chip's trace
+    charged to ``copy`` under ``kda_conv`` (31.2 ms a step, PERF.md section
+    5): here the CPU-side compile reproduces the chip's choice (PR 67 found
+    a case, the decay statistic's second reader of g, where it did not)."""
+    from shuffle_exchange_tpu.ops import kda
+    from shuffle_exchange_tpu.ops.gated_delta import l2norm
+    from shuffle_exchange_tpu.ops.ssm_conv import _ssm_conv_pallas
+
+    B, T, H, d, D = 1, 16384, 32, 128, 2304
+
+    def as_before(qkv, conv_w, H, dk, dv):
+        q, k, v = _ssm_conv_pallas(qkv, conv_w, jnp.zeros((conv_w.shape[1],), _F32),
+                                   0, (H * dk, H * dk, H * dv))[1:4]
+        q = (l2norm(q.reshape(B, T, H, dk)) * dk ** -0.5).astype(qkv.dtype)
+        return q, l2norm(k.reshape(B, T, H, dk)).astype(qkv.dtype), v.reshape(B, T, H, dv)
+
+    def loss(prologue, y, w_qkv, conv_w, g, beta):
+        q, k, v = prologue(y @ w_qkv, conv_w, H, d, d)
+        return jnp.sum(kda._kda_pallas(q, k, v, jnp.broadcast_to(g, (B, T, H, d)), beta))
+
+    specs = (((B, T, D), _BF16), ((D, 3 * H * d), _BF16), ((4, 3 * H * d), _F32),
+             ((H, d), _F32), ((B, T, H), _F32))
+    moved = {}
+    for name, prologue in (("kernels", kda._kda_prologue_pallas), ("before", as_before)):
+        fn = functools.partial(loss, prologue)
+        moved[name] = [_moved_like_q(chip_compile(f, *specs).as_text())
+                       for f in (fn, jax.grad(fn, argnums=(0, 1, 2)))]
+    assert moved["kernels"] == [[], []], moved["kernels"]
+    assert all(len(found) >= 6 for found in moved["before"]), moved["before"]
 
 
 def test_the_learned_sparse_attentions_kernels_compile_at_the_cells_shapes(chip_compile):
