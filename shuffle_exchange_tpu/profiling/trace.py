@@ -7,9 +7,9 @@ about themselves. Five primitives, no config field, no environment variable.
     the span lands on the host plane of the same ``.xplane.pb`` as the device
     ops, on the same clock, so an idle gap of the device can be laid against
     what the host was doing. With no session it is a TraceMe that records
-    nothing. Only after ``keep_spans(True)`` (the engine's
-    ``wall_clock_breakdown``) does a span also append ``(name, t0, t1)`` on
-    ``time.perf_counter`` to a bounded in-memory list.
+    nothing; where a ``step`` is open on its thread its duration is added, on
+    ``time.perf_counter``, to that step's record, and outside a step it is
+    the profiler's alone.
 ``phase(name)``
     A span for what runs once per engine or once per program, never per step:
     the package's import, ``sxt.initialize``, the sections of
@@ -18,8 +18,8 @@ about themselves. Five primitives, no config field, no environment variable.
     open-span stack: a compilation inside it is stamped with it) that is ALSO
     always appended, on ``time.perf_counter``, to a bounded start-up log:
     ``phases()`` hands out the rows (``name``, ``program``, ``t0``, ``t1``,
-    ``parent``: the open phase it nested in) with no profiler session and
-    without ``keep_spans``, so start-up can be read after the fact. A phase
+    ``parent``: the open phase it nested in) with no profiler session, so
+    start-up can be read after the fact. A phase
     is HOST time: what the host spent between two lines, device work it
     waited for included, device work it only enqueued not. It synchronises
     nothing, and it never goes on the hot path: a step opens ``span``s.
@@ -27,9 +27,23 @@ about themselves. Five primitives, no config field, no environment variable.
     ``jax.named_scope``, for code under ``jit``: it changes the ``op_name``
     metadata of the ops traced inside it and nothing else, so the device
     ops of a trace can be summed by the layer of the program they belong to.
-``step(kind, n)``
+``step(kind, n, **numbers)``
     ``jax.profiler.StepTraceAnnotation``: one per ``train_batch`` and one per
-    scheduler tick, so the profiler groups device work by step.
+    scheduler tick, so the profiler groups device work by step. It is ALSO,
+    with or without a profiler session, the program's log of its own steps:
+    closing it appends one plain record to a bounded ring per ``kind``, which
+    ``steps(kind, since)`` hands out: ``kind``, ``n`` (the step number the
+    annotation carries: a record and the profiler's event of one step are
+    joined by it), ``t0`` and ``t1`` on ``time.perf_counter`` (the clock of
+    ``phases()`` and ``compile_events()``), ``spans`` (``{name: seconds}``:
+    the summed duration of every ``span`` closed inside the step on the
+    step's thread, a parent and its children each under their own name),
+    ``numbers`` (the caller's: ``train_batch`` passes ``samples``) and
+    ``compiles``, which ``steps`` fills in when it is read: how many of
+    ``compile_events()`` were stamped between ``t0`` and ``t1``. HOST time,
+    as a phase is: a step that only enqueues device work is as long as its
+    dispatch. No device array, no synchronisation. The rings are the
+    process's: two engines' steps are one kind's rows.
 ``compile_events()``
     The program's own ``jax.monitoring`` listener: one record per program
     built, stamped with the innermost open phase or span and the program it
@@ -59,6 +73,7 @@ View with TensorBoard's profile plugin pointed at the log dir, or read the
 
 from __future__ import annotations
 
+import bisect
 import collections
 import contextlib
 import functools
@@ -147,15 +162,19 @@ PHASES = ("forward", "recompute", "backward", "update", "other")
 # head's dx and dw in the pass of its loss (PR 32)
 _BACKWARD_SCOPES = ("head_dx", "head_dw")
 
-_KEEP_MAX = 4096
+# a 45 s window at 109 ms a step is 413 steps; a serving tick is a few ms
+_STEPS_MAX = 4096
 _PHASES_MAX = 512
 # a run's programs: 160 in one training cell, 324 up a serving ladder
 _EVENTS_MAX = 1024
 
-_kept: Optional[collections.deque] = None       # (name, t0, t1) when kept
+# kind -> ring of step records
+_steps: Dict[str, collections.deque] = collections.defaultdict(
+    lambda: collections.deque(maxlen=_STEPS_MAX))
 _phases: collections.deque = collections.deque(maxlen=_PHASES_MAX)
 _events: collections.deque = collections.deque(maxlen=_EVENTS_MAX)
-# .stack: [(name, program)] of open spans and phases; .phases: open phases
+# .stack: [(name, program)] of open spans and phases; .phases: open phases;
+# .step: the innermost open step of this thread
 _open = threading.local()
 _listening = False
 # what this thread is building: .hit (the persistent cache answered),
@@ -179,7 +198,7 @@ class span:
     the event's stats in a profiler session (``span("serve/first_schedule",
     wait_ms=3.2)``) and nowhere else."""
 
-    __slots__ = ("name", "program", "numbers", "_note", "_t0")
+    __slots__ = ("name", "program", "numbers", "_note", "_step", "_t0")
 
     def __init__(self, name: str, program: Optional[str] = None, **numbers):
         self.name = name
@@ -198,12 +217,14 @@ class span:
         self._note = jax.profiler.TraceAnnotation(PREFIX + self.name,
                                                   **self.numbers)
         self._note.__enter__()
-        self._t0 = time.perf_counter() if _kept is not None else 0.0
+        self._step = getattr(_open, "step", None)
+        self._t0 = time.perf_counter() if self._step is not None else 0.0
         return self
 
     def __exit__(self, *exc):
-        if _kept is not None:
-            _kept.append((self.name, self._t0, time.perf_counter()))
+        if self._step is not None:
+            took, spans = time.perf_counter() - self._t0, self._step.spans
+            spans[self.name] = spans.get(self.name, 0.0) + took
         self._note.__exit__(*exc)
         _stack().pop()
         return False
@@ -219,8 +240,8 @@ def _open_phases() -> list:
 
 class phase(span):
     """``with phase("init/params"):`` - a ``span`` whose row always goes to
-    the start-up log that ``phases()`` reads, and never to the per-step list
-    of ``keep_spans``. For code that runs once per engine or per program
+    the start-up log that ``phases()`` reads, and never to a step's record.
+    For code that runs once per engine or per program
     (module docstring). ``t0`` backdates the start on ``perf_counter`` for a
     phase that cannot be opened where it begins (the package's import opens
     its own after importing this module)."""
@@ -241,7 +262,7 @@ class phase(span):
         return super().__enter__()
 
     def __exit__(self, *exc):
-        # not ``span.__exit__``: that one feeds the per-step list
+        # not ``span.__exit__``: that one feeds the open step's record
         ended = time.perf_counter()
         self._note.__exit__(*exc)
         _stack().pop()
@@ -266,11 +287,76 @@ def scope(name: str):
     return jax.named_scope(name)
 
 
-def step(kind: str, n: int):
-    """One training step or scheduler tick, for the profiler's step view."""
-    import jax
+class step:
+    """``with step("train", n, samples=8):`` - one training step or scheduler
+    tick: the profiler's step annotation and, always, one record in the ring
+    of its ``kind`` (module docstring). ``numbers`` may be amended while the
+    step is open. Opens and closes on one thread; a step opened inside
+    another keeps its own record and hands the thread back to the outer one."""
 
-    return jax.profiler.StepTraceAnnotation(PREFIX + kind, step_num=int(n))
+    __slots__ = ("kind", "n", "numbers", "spans", "_note", "_outer", "_t0")
+
+    def __init__(self, kind: str, n: int, **numbers):
+        self.kind = kind
+        self.n = int(n)
+        self.numbers = numbers
+
+    def __enter__(self):
+        import jax
+
+        if not _listening:
+            _listen()       # a program built inside is in ``compiles``
+        self._note = jax.profiler.StepTraceAnnotation(PREFIX + self.kind,
+                                                      step_num=self.n)
+        self._note.__enter__()
+        self.spans = {}
+        self._outer = getattr(_open, "step", None)
+        _open.step = self
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        ended = time.perf_counter()
+        _open.step = self._outer
+        self._note.__exit__(*exc)
+        _steps[self.kind].append({
+            "kind": self.kind, "n": self.n, "t0": self._t0, "t1": ended,
+            "spans": self.spans, "numbers": self.numbers})
+        return False
+
+
+def steps(kind: str, since: float = 0.0) -> List[dict]:
+    """The program's log of its own steps: copies of the records of ``kind``
+    ("train", "serve") that began at or after ``since`` on ``perf_counter``,
+    oldest first, each with ``compiles``: the programs ``compile_events``
+    stamped inside it (a compilation on another thread counts where it falls;
+    the events' ring is bounded too). The newest ``_STEPS_MAX`` of a kind are
+    kept."""
+    built = sorted(e["at"] for e in compile_events(since))
+    rows = [dict(r, spans=dict(r["spans"]), numbers=dict(r["numbers"]),
+                 compiles=bisect.bisect_right(built, r["t1"])
+                 - bisect.bisect_left(built, r["t0"]))
+            for r in list(_steps.get(kind, ())) if r["t0"] >= since]
+    return sorted(rows, key=lambda r: r["t0"])
+
+
+def breakdown_line(records) -> str:
+    """The ``wall_clock_breakdown`` log line off step records: milliseconds
+    A STEP per span name (a name opened twice in a step counts twice; the
+    mean is over the records, not over the name's occurrences), and
+    samples/s from the records' ``numbers["samples"]`` over their
+    ``t1 - t0``."""
+    by: Dict[str, float] = {}
+    for r in records:
+        for name, took in r["spans"].items():
+            by[name] = by.get(name, 0.0) + took
+    parts = [f"{n}: {1e3 * by[n] / len(records):.2f}" for n in sorted(by)]
+    msg = "time (ms) | " + " | ".join(parts)
+    took = sum(r["t1"] - r["t0"] for r in records)
+    samples = sum(r["numbers"].get("samples", 0) for r in records)
+    if samples and took > 0:
+        msg += f" | samples/s: {samples / took:.2f}"
+    return msg
 
 
 @contextlib.contextmanager
@@ -284,44 +370,6 @@ def xla_trace(logdir: str):
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-# ---------------------------------------------------------------------------
-# Spans kept in memory (wall_clock_breakdown)
-# ---------------------------------------------------------------------------
-
-
-def keep_spans(on: bool) -> None:
-    """Start (or stop and drop) the in-memory span list."""
-    global _kept
-    if on and _kept is None:
-        _kept = collections.deque(maxlen=_KEEP_MAX)
-    elif not on:
-        _kept = None
-
-
-def kept_spans(clear: bool = False) -> List[Tuple[str, float, float]]:
-    """The spans kept since the last clear, oldest first."""
-    if _kept is None:
-        return []
-    rows = list(_kept)
-    if clear:
-        _kept.clear()
-    return rows
-
-
-def breakdown_line(rows, batch_size: int, step_span: str) -> str:
-    """The ``wall_clock_breakdown`` log line: mean milliseconds per span
-    name, and samples/s from the ``step_span`` rows."""
-    by: Dict[str, List[float]] = {}
-    for name, t0, t1 in rows:
-        by.setdefault(name, []).append(t1 - t0)
-    parts = [f"{n}: {1e3 * sum(v) / len(v):.2f}" for n, v in sorted(by.items())]
-    msg = "time (ms) | " + " | ".join(parts)
-    steps = by.get(step_span)
-    if steps and sum(steps) > 0:
-        msg += f" | samples/s: {batch_size * len(steps) / sum(steps):.2f}"
-    return msg
 
 
 # ---------------------------------------------------------------------------

@@ -555,10 +555,10 @@ class Engine:
 
         section("init/rest")
         # --- tracer / monitors -----------------------------------------
-        # wall_clock_breakdown: the tracer's spans are also kept in memory
-        # and each step span closes on a loss that is ready (profiling/trace.py)
-        if config.wall_clock_breakdown:
-            trace.keep_spans(True)
+        # wall_clock_breakdown: each train_batch waits for its loss and the
+        # line is read off the tracer's step records (profiling/trace.py)
+        # closed since the last one
+        self._breakdown_since = time.perf_counter()
         # monitor fan-out (reference monitor/monitor.py:30 MonitorMaster;
         # engine event writes runtime/engine.py:2200-2208)
         from ..monitor import MonitorMaster
@@ -1785,10 +1785,11 @@ class Engine:
         PipelineEngine.train_batch signature).
 
         Traced as one ``step("train", n)`` holding the spans ``train/fetch``,
-        ``train/place``, ``train/dispatch`` and ``train/post``
-        (profiling/trace.py); under ``wall_clock_breakdown`` the step also
-        waits for its loss (``train/wait``), so its span is a step's time and
-        not a dispatch's. An engine's first call is the phase
+        ``train/place``, ``train/dispatch`` and ``train/post``, whose record
+        ``trace.steps("train")`` keeps (profiling/trace.py); under
+        ``wall_clock_breakdown`` the step also waits for its loss
+        (``train/wait``), so its record is a step's time and not a
+        dispatch's. An engine's first call is the phase
         ``train/first_step``: what remains of bringing the step's program up
         (tracing, lowering, the compilation or the cache's read) and one
         dispatch."""
@@ -1799,9 +1800,13 @@ class Engine:
         return self._train_batch(batch, data_iter)
 
     def _train_batch(self, batch, data_iter):
-        with trace.step("train", self.global_steps), trace.span("train/batch"):
+        with trace.step("train", self.global_steps,
+                        samples=self.config.train_batch_size) as record, \
+                trace.span("train/batch"):
             with trace.span("train/fetch"):
                 batch, lr_mult, n_samples = self._fetch_batch(batch, data_iter)
+            if n_samples is not None:
+                record.numbers["samples"] = n_samples
             from ..testing import faults
 
             if faults.ACTIVE:
@@ -1816,6 +1821,13 @@ class Engine:
 
                 with trace.span("train/wait"):
                     jax.block_until_ready(loss)
+        if (self.config.wall_clock_breakdown
+                and self.global_steps % self.config.steps_per_print == 0):
+            # off the records closed since the last line, this step's own
+            # included (it has closed); the process's, as the ring is
+            rows = trace.steps("train", since=self._breakdown_since)
+            self._breakdown_since = time.perf_counter()
+            log_dist(trace.breakdown_line(rows), ranks=[0])
         return loss
 
     def _fetch_batch(self, batch, data_iter):
@@ -2036,12 +2048,6 @@ class Engine:
                      f"(loss scale -> {self.loss_scale()})", ranks=[0])
         if self.global_steps % self.config.steps_per_print == 0:
             log_dist(f"step={self.global_steps} lr={self.get_lr():.3e} loss_scale={self.loss_scale()}", ranks=[0])
-            if self.config.wall_clock_breakdown:
-                # the spans closed since the last line (this step's own
-                # train/batch is still open)
-                log_dist(trace.breakdown_line(
-                    trace.kept_spans(clear=True), self.config.train_batch_size,
-                    step_span="train/batch"), ranks=[0])
             if self.config.memory_breakdown:
                 # reference see_memory_usage breadcrumbs (runtime/utils.py)
                 log_dist(f"step={self.global_steps} {_memory_usage()}", ranks=[0])

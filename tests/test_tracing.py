@@ -22,10 +22,15 @@ KNOWN = {name for names in trace.SCOPES.values() for name in names}
 
 
 @pytest.fixture(autouse=True)
-def _no_kept_spans():
-    trace.keep_spans(False)
+def _no_open_step():
+    """A test leaves no step open on its thread: the next one's spans would
+    land in its record."""
     yield
-    trace.keep_spans(False)
+    assert getattr(trace._open, "step", None) is None
+
+
+def steps_since(mark, kind="train"):
+    return trace.steps(kind, since=mark)
 
 
 def make_engine(model=None, **extra):
@@ -66,23 +71,32 @@ def host_events(logdir, prefixes=("sxt:", "cb:")):
 
 
 def test_span_without_session_or_breakdown_keeps_nothing():
-    with trace.span("train/place"):
+    before = {k: len(v) for k, v in trace._steps.items()}
+    with trace.span("train/place") as outer:
         with trace.span("train/dispatch"):
             pass
-    assert trace.kept_spans() == []
-    assert trace._kept is None           # no list exists, nothing to append to
+    # outside a step a span is the profiler's alone: no clock is read and
+    # no ring grows
+    assert outer._step is None and outer._t0 == 0.0
+    assert {k: len(v) for k, v in trace._steps.items()} == before
     assert trace._stack() == []
 
 
-def test_kept_spans_are_bounded_and_cleared():
-    trace.keep_spans(True)
-    for _ in range(trace._KEEP_MAX + 10):
-        with trace.span("a"):
-            pass
-    rows = trace.kept_spans(clear=True)
-    assert len(rows) == trace._KEEP_MAX
-    assert all(n == "a" and t1 >= t0 for n, t0, t1 in rows)
-    assert trace.kept_spans() == []
+def test_the_step_ring_is_bounded_and_hands_out_copies():
+    import time
+
+    mark = time.perf_counter()
+    for i in range(trace._STEPS_MAX + 10):
+        with trace.step("bounded", i):
+            with trace.span("a"):
+                pass
+    rows = steps_since(mark, "bounded")
+    assert len(rows) == len(trace._steps["bounded"]) == trace._STEPS_MAX
+    assert [r["n"] for r in rows] == list(range(10, trace._STEPS_MAX + 10))
+    assert all(r["t1"] >= r["t0"] and list(r["spans"]) == ["a"] for r in rows)
+    rows[0]["spans"]["a"] = -1.0            # a copy: the ring's row stands
+    assert steps_since(mark, "bounded")[0]["spans"]["a"] >= 0.0
+    del trace._steps["bounded"]
 
 
 def test_spans_nest_on_the_profilers_clock(tmp_path):
@@ -127,7 +141,7 @@ def fresh_jit(seed):
     return jax.jit(brand_new)
 
 
-def test_phase_is_kept_with_no_session_and_without_keep_spans():
+def test_phase_is_kept_with_no_session_and_goes_to_no_step_record():
     import time
 
     mark = time.perf_counter()
@@ -137,12 +151,13 @@ def test_phase_is_kept_with_no_session_and_without_keep_spans():
     assert row == {"name": "init/params", "program": None, "t0": ph._began,
                    "t1": row["t1"], "parent": None}
     assert mark <= row["t0"] <= row["t1"] <= time.perf_counter()
-    # the per-step list is another thing: a phase never goes there
-    assert trace._kept is None and trace.kept_spans() == []
-    trace.keep_spans(True)
-    with trace.phase("init/params"):
-        pass
-    assert trace.kept_spans() == []
+    # a step's record is another thing: a phase never goes there, a span does
+    with trace.step("phased", 0):
+        with trace.phase("init/params"), trace.span("train/place"):
+            pass
+    record, = steps_since(mark, "phased")
+    assert list(record["spans"]) == ["train/place"]
+    assert len(trace.phases(since=mark)) == 2
     assert trace._stack() == [] and trace._open_phases() == []
 
 
@@ -706,7 +721,7 @@ def test_second_batch_shape_is_one_compile_event_with_program_and_span():
 # -- wall_clock_breakdown -----------------------------------------------
 
 
-def test_wall_clock_breakdown_logs_from_the_spans(caplog):
+def test_wall_clock_breakdown_logs_from_the_step_records(caplog):
     import logging
 
     engine = make_engine(wall_clock_breakdown=True, steps_per_print=3)
@@ -726,27 +741,190 @@ def test_wall_clock_breakdown_logs_from_the_spans(caplog):
     for part in ("train/batch:", "train/fetch:", "train/place:",
                  "train/dispatch:", "train/post:", "train/wait:", "samples/s:"):
         assert part in lines[0], lines[0]
-    # the fourth step's spans are kept for the next line
-    names = [n for n, _, _ in trace.kept_spans()]
-    assert names.count("train/batch") == 2 and names.count("train/wait") == 2
+    # the line held the three records closed by then; the fourth step's is
+    # kept for the next line
+    rest = steps_since(engine._breakdown_since)
+    assert [r["n"] for r in rest] == [3] and "train/wait" in rest[0]["spans"]
 
 
-def test_breakdown_line_counts_samples_over_step_spans():
-    rows = [("train/batch", 0.0, 0.5), ("train/batch", 0.5, 1.0),
-            ("train/place", 0.0, 0.002)]
-    line = trace.breakdown_line(rows, batch_size=8, step_span="train/batch")
+def test_breakdown_line_counts_samples_over_step_records():
+    def record(t0, t1, **spans):
+        return {"kind": "train", "n": 0, "t0": t0, "t1": t1, "spans": spans,
+                "compiles": 0, "numbers": {"samples": 8}}
+
+    rows = [record(0.0, 0.5, **{"train/batch": 0.5, "train/place": 0.004}),
+            record(0.5, 1.0, **{"train/batch": 0.5})]
+    line = trace.breakdown_line(rows)
     assert "train/batch: 500.00" in line and "train/place: 2.00" in line
     assert line.endswith("samples/s: 16.00")
+    assert trace.breakdown_line([]) == "time (ms) | "
 
 
-def test_staged_api_has_its_spans():
-    trace.keep_spans(True)
+def test_staged_api_has_its_spans_on_the_profilers_side(tmp_path):
+    import time
+
+    import jax
+
     engine = make_engine(train_batch_size=8)
-    loss = engine.forward(ids())
+    engine.backward(engine.forward(ids()))
+    engine.step()                               # compile outside the session
+    mark = time.perf_counter()
+    jax.profiler.start_trace(str(tmp_path))
+    loss = engine.forward(ids(seed=1))
     engine.backward(loss)
     engine.step()
-    names = [n for n, _, _ in trace.kept_spans()]
-    assert names == ["train/forward", "train/backward", "train/step"]
+    jax.block_until_ready(engine.state)
+    jax.profiler.stop_trace()
+    names = [n for n, _, _ in sorted(host_events(str(tmp_path), ("sxt:",)),
+                                     key=lambda e: e[1])]
+    assert names == ["sxt:train/forward", "sxt:train/backward",
+                     "sxt:train/step"]
+    # outside a step a span is the profiler's alone: the staged calls open
+    # no step, so they feed no record and leave none open behind them
+    assert steps_since(mark) == []
+
+
+def test_a_forward_alone_leaves_no_step_open_for_the_next_train_batch():
+    import time
+
+    engine = make_engine()
+    mark = time.perf_counter()
+    engine.forward(ids())                       # a loss evaluation: no step()
+    assert getattr(trace._open, "step", None) is None
+    engine.train_batch(ids(seed=1))
+    with pytest.raises(Exception):
+        engine.backward(batch=np.zeros((3, 5), np.int32))   # raises inside
+    assert getattr(trace._open, "step", None) is None
+    engine.train_batch(ids(seed=2))
+    rows = steps_since(mark)
+    assert [r["n"] for r in rows] == [0, 1]
+    for r in rows:
+        assert sorted(r["spans"]) == ["train/batch", "train/dispatch",
+                                      "train/fetch", "train/place",
+                                      "train/post"]
+
+
+# -- step: the trainer's log of its own steps -----------------------------
+
+
+def test_a_step_is_recorded_with_no_session_and_no_breakdown():
+    import time
+
+    engine = make_engine()
+    assert not engine.config.wall_clock_breakdown
+    mark = time.perf_counter()
+    for i in range(3):
+        engine.train_batch(ids(seed=i))
+    rows = steps_since(mark)
+    assert [r["n"] for r in rows] == [0, 1, 2]
+    for r in rows:
+        assert r["kind"] == "train" and mark <= r["t0"] <= r["t1"]
+        assert r["numbers"] == {"samples": 8}
+        for name in ("train/batch", "train/fetch", "train/place",
+                     "train/dispatch", "train/post"):
+            assert 0.0 <= r["spans"][name] <= r["t1"] - r["t0"], (name, r)
+        assert "train/wait" not in r["spans"]
+    assert all(a["t1"] <= b["t0"] for a, b in zip(rows, rows[1:]))
+    json.dumps(rows)                            # plain Python all the way down
+
+
+def test_since_cuts_the_step_log():
+    import time
+
+    mark = time.perf_counter()
+    with trace.step("cut", 0):
+        pass
+    middle = time.perf_counter()
+    with trace.step("cut", 1):
+        pass
+    assert [r["n"] for r in steps_since(mark, "cut")] == [0, 1]
+    assert [r["n"] for r in steps_since(middle, "cut")] == [1]
+    assert steps_since(time.perf_counter(), "cut") == []
+    assert trace.steps("a kind nobody fed") == []
+
+
+def test_a_steps_spans_sum_a_name_opened_twice():
+    import time
+
+    mark = time.perf_counter()
+    with trace.step("summed", 7, rows=3) as st:
+        for _ in range(2):
+            with trace.span("serve/launch"):
+                time.sleep(0.01)
+        with trace.span("serve/emit"):
+            pass
+        st.numbers["rows"] = 4                  # amended while open
+    record, = steps_since(mark, "summed")
+    assert sorted(record["spans"]) == ["serve/emit", "serve/launch"]
+    assert 0.02 <= record["spans"]["serve/launch"] <= record["t1"] - record["t0"]
+    assert record["n"] == 7 and record["numbers"] == {"rows": 4}
+
+
+def test_a_span_outside_any_step_reaches_no_record():
+    import time
+
+    mark = time.perf_counter()
+    with trace.span("train/place"):
+        with trace.step("inside", 0):
+            with trace.span("train/dispatch"):
+                pass
+    with trace.span("train/post"):
+        pass
+    record, = steps_since(mark, "inside")
+    # the span that was open before the step is not the step's either
+    assert list(record["spans"]) == ["train/dispatch"]
+
+
+def test_compiles_counts_the_step_that_built_a_program():
+    import time
+
+    f = fresh_jit(31)
+    mark = time.perf_counter()
+    for n in range(2):
+        with trace.step("built", n), trace.span("train/dispatch", program="p"):
+            f(np.float32(1.0))
+    first, second = steps_since(mark, "built")
+    assert first["compiles"] == 1 and second["compiles"] == 0
+    assert len(trace.compile_events(since=first["t0"])) == 1
+
+
+def test_two_threads_open_steps_do_not_mix():
+    import threading
+    import time
+
+    mark = time.perf_counter()
+    both_open = threading.Barrier(2)
+
+    def replica(name):
+        with trace.step("threads", 0, replica=name):
+            both_open.wait(timeout=10)
+            with trace.span("serve/" + name):
+                pass
+            both_open.wait(timeout=10)          # both close after both spans
+
+    threads = [threading.Thread(target=replica, args=(n,)) for n in "ab"]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    rows = steps_since(mark, "threads")
+    assert sorted((r["numbers"]["replica"], list(r["spans"])) for r in rows) \
+        == [("a", ["serve/a"]), ("b", ["serve/b"])]
+    assert getattr(trace._open, "step", None) is None
+
+
+def test_a_step_inside_a_step_hands_the_thread_back():
+    import time
+
+    mark = time.perf_counter()
+    with trace.step("outer", 0):
+        with trace.step("inner", 5):
+            with trace.span("b"):
+                pass
+        with trace.span("a"):
+            pass
+    assert list(steps_since(mark, "outer")[0]["spans"]) == ["a"]
+    assert list(steps_since(mark, "inner")[0]["spans"]) == ["b"]
 
 
 # -- scheduler ----------------------------------------------------------
@@ -829,6 +1007,27 @@ def test_tick_is_covered_by_its_three_spans(tmp_path):
     for inner in ("sxt:serve/pack", "sxt:serve/launch", "sxt:serve/readback"):
         (i0, i1), = by[inner]
         assert d0 <= i0 and i1 <= d1
+
+
+def test_a_scheduler_tick_lands_under_serve():
+    import time
+
+    sched = make_scheduler(Clock())
+    sched.submit([1, 2, 3, 4, 5], max_new_tokens=3)
+    mark = time.perf_counter()
+    first_tick = sched.ticks
+    while sched.tick():
+        pass
+    rows = steps_since(mark, "serve")
+    assert [r["n"] for r in rows] == list(range(first_tick, sched.ticks))
+    assert steps_since(mark, "train") == []
+    busy = [r for r in rows if "serve/launch" in r["spans"]]
+    assert busy and rows[0]["compiles"] >= 1
+    for r in busy:
+        for name in ("serve/admit", "serve/dispatch", "serve/pack",
+                     "serve/launch", "serve/readback", "serve/emit"):
+            assert name in r["spans"], (name, r)
+        assert r["spans"]["serve/launch"] <= r["spans"]["serve/dispatch"]
 
 
 def test_engine_v2_names_its_program_for_the_compile_listener():
